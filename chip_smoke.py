@@ -1,0 +1,508 @@
+#!/usr/bin/env python3
+"""On-chip smoke: the quickest proof that the system still starts on the TPU.
+
+    python chip_smoke.py
+
+One process, one import of JAX, no child that needs the chip. Drives the
+main paths once through the entry points a user calls —
+``kubernetes_simulator_tpu.cli.main`` (the function behind ``python -m
+kubernetes_simulator_tpu``) and the public ``api`` — at the sizes
+BASELINE.json names, and checks what comes out by the repo's own means
+(scenario 0 == the single replay; device placements == the host greedy
+reference, element-wise).
+
+Phases, in order; the first failure ends the run with a non-zero exit and
+no result line (nothing here catches a phase's exception):
+
+  device          platform must be "tpu" (else exit 1 before any heavy
+                  work), native packers built, compile cache on
+  whatif-config3  what-if examples/config3_whatif_256.yaml
+                  (256 scenarios x 5,000 nodes x 50,000 pods, one chip)
+  run-config2     run examples/config2_full_plugins_5k.yaml --strategy jax
+  parity          chip vs host reference: config-2 head, a Borg-shaped
+                  case with completions, and the same as a what-if batch
+                  through the device release program
+  serve           serve examples/config20_service.yaml < 4 defrag queries
+  mesh            only with >1 device: what-if
+                  examples/config5_multitenant_mesh.yaml over all devices
+                  vs the same file on one device
+
+Stdout is two JSON lines, written only after every phase passed. The
+first is the report (versions, compile-cache directory, per phase: cold
+wall, compile time, the engine's own wall, placements, any ``reduced``).
+The LAST is the result the driver reads, with exactly these keys and
+nothing else:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
+Progress goes to stderr. CPU debugging of this script itself is not a
+mode: without a TPU it refuses.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import io
+import json
+import os
+import sys
+import tempfile
+import time
+from pathlib import Path
+
+EXAMPLES = Path(__file__).resolve().parent / "examples"
+CONFIG2 = str(EXAMPLES / "config2_full_plugins_5k.yaml")
+CONFIG3 = str(EXAMPLES / "config3_whatif_256.yaml")
+CONFIG5 = str(EXAMPLES / "config5_multitenant_mesh.yaml")
+CONFIG20 = str(EXAMPLES / "config20_service.yaml")
+
+# Parity shapes (small on purpose: the host reference is numpy, per pod).
+PARITY_HEAD_PODS = 2000
+# Borg case: few nodes and long tasks, so the cluster runs full (some tasks
+# stay unschedulable) and fit decisions sit on what the releases freed.
+BORG_NODES, BORG_TASKS, BORG_MEAN_DURATION, BORG_CHUNK_WAVES = 12, 2048, 15000.0, 16
+
+
+def say(msg: str) -> None:
+    print(f"chip_smoke: {msg}", file=sys.stderr, flush=True)
+
+
+class CompileMeter:
+    """Sums JAX's own compile-time events (``jax.monitoring``) so each
+    phase can report how much of its cold wall was XLA."""
+
+    _KEYS = {
+        "/jax/core/compile/backend_compile_duration": "compile_s",
+        "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    }
+    _COUNTS = {
+        "/jax/compilation_cache/cache_hits": "cache_hits",
+        "/jax/compilation_cache/cache_misses": "cache_misses",
+    }
+
+    def __init__(self):
+        import jax
+
+        self.totals = {v: 0.0 for v in self._KEYS.values()}
+        self.totals.update({v: 0 for v in self._COUNTS.values()})
+        jax.monitoring.register_event_duration_secs_listener(self._on_dur)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_dur(self, name, dur, **kw):
+        key = self._KEYS.get(name)
+        if key:
+            self.totals[key] += dur
+
+    def _on_event(self, name, **kw):
+        key = self._COUNTS.get(name)
+        if key:
+            self.totals[key] += 1
+
+    def since(self, before: dict) -> dict:
+        return {
+            k: round(v - before[k], 3) if isinstance(v, float) else v - before[k]
+            for k, v in self.totals.items()
+        }
+
+
+def cli(argv) -> list:
+    """``python -m kubernetes_simulator_tpu <argv>`` in this process; returns
+    the JSONL rows it printed to stdout."""
+    from kubernetes_simulator_tpu.cli import main
+
+    buf = io.StringIO()
+    with contextlib.redirect_stdout(buf):
+        rc = main(list(argv))
+    if rc != 0:
+        raise RuntimeError(f"cli {argv} returned {rc}")
+    return [json.loads(ln) for ln in buf.getvalue().splitlines() if ln.strip()]
+
+
+def require(cond, msg: str) -> None:
+    if not cond:
+        raise AssertionError(msg)
+
+
+def split_whatif(rows):
+    agg = [r for r in rows if r["kind"] == "whatif-aggregate"]
+    scen = [r for r in rows if r["kind"] == "whatif-scenario"]
+    require(len(agg) == 1, f"expected one whatif-aggregate row, got {len(agg)}")
+    return agg[0], scen
+
+
+# -- phases -----------------------------------------------------------------
+
+
+def config_size(path: str):
+    """(scenarios, nodes, pods) a synthetic-workload config asks for."""
+    from kubernetes_simulator_tpu.utils.config import SimConfig
+
+    cfg = SimConfig.load(path)
+    return cfg.whatif.scenarios, cfg.cluster.nodes, cfg.workload.pods
+
+
+def phase_whatif_config3() -> dict:
+    S, N, P = config_size(CONFIG3)
+    agg, scen = split_whatif(cli(["what-if", CONFIG3]))
+    require(agg["engine"] == "v3", f"engine {agg['engine']!r}, want v3")
+    require(agg["scenarios"] == S == len(scen),
+            f"{agg['scenarios']} scenarios / {len(scen)} rows, want {S}")
+    require(all(r["placed"] + r["unschedulable"] == P for r in scen),
+            f"a scenario does not account for all {P} pods")
+    require(sum(r["placed"] for r in scen) == agg["total_placed"] > 0,
+            "scenario rows do not add up to total_placed")
+    return {
+        "size": f"{S} scenarios x {N} nodes x {P} pods",
+        "engine": agg["engine"],
+        "wall_clock_s": agg["wall_clock_s"],
+        "placements": agg["total_placed"],
+        "placements_per_sec": agg["placements_per_sec"],
+        "placed_scenario0": scen[0]["placed"],
+        "completions_on": agg["completions_on"],
+    }
+
+
+def phase_run_config2(placed_scenario0: int) -> dict:
+    _, N, P = config_size(CONFIG2)
+    (row,) = cli(["run", CONFIG2, "--strategy", "jax"])
+    require(row["kind"] == "replay-jax", f"row kind {row['kind']!r}")
+    require(row["placed"] + row["unschedulable"] == P,
+            f"replay does not account for all {P} pods")
+    require(row["placed"] == placed_scenario0,
+            f"single replay placed {row['placed']}, what-if scenario 0 "
+            f"placed {placed_scenario0} (same cluster and workload seeds)")
+    return {
+        "size": f"{N} nodes x {P} pods",
+        "engine": row["engine"],
+        "wall_clock_s": row["wall_clock_s"],
+        "placements": row["placed"],
+        "placements_per_sec": row["placements_per_sec"],
+        "unschedulable": row["unschedulable"],
+    }
+
+
+def phase_parity() -> dict:
+    import dataclasses
+
+    import numpy as np
+
+    from kubernetes_simulator_tpu.framework.framework import FrameworkConfig
+    from kubernetes_simulator_tpu.models.encode import encode
+    from kubernetes_simulator_tpu.sim.borg import BorgSpec, make_borg_encoded
+    from kubernetes_simulator_tpu.sim.greedy import greedy_replay
+    from kubernetes_simulator_tpu.sim.jax_runtime import JaxReplayEngine
+    from kubernetes_simulator_tpu.sim.whatif import (
+        Perturbation,
+        Scenario,
+        WhatIfEngine,
+    )
+    from kubernetes_simulator_tpu.utils.config import SimConfig, build_case
+
+    def same(dev, ref, what):
+        bad = np.nonzero(np.asarray(dev) != np.asarray(ref))[0]
+        require(bad.size == 0,
+                f"{what}: {bad.size} placements differ from the host "
+                f"reference, first at pod {bad[:5].tolist()}")
+
+    out = {}
+    # (a) the head of the config-2 case, full default plugin set.
+    cfg = SimConfig.load(CONFIG2)
+    cluster, pods = build_case(cfg)
+    ec, ep = encode(cluster, pods[:PARITY_HEAD_PODS])
+    host = greedy_replay(ec, ep, cfg.framework, wave_width=8)
+    dev = JaxReplayEngine(ec, ep, cfg.framework, wave_width=8).replay()
+    same(dev.assignments, host.assignments, "config2 head")
+    require(dev.placed == host.placed > 0, "config2 head: placed differs")
+    out["config2_head"] = {"pods": PARITY_HEAD_PODS, "placed": dev.placed}
+
+    # (b) Borg-shaped, gangs on, finite durations: 0.1-core requests (not
+    # bf16-exact) are bound AND released mid-replay.
+    ec, ep, meta = make_borg_encoded(
+        BorgSpec(nodes=BORG_NODES, tasks=BORG_TASKS, seed=0,
+                 mean_duration=BORG_MEAN_DURATION)
+    )
+    fw = FrameworkConfig()
+    kw = dict(wave_width=8, chunk_waves=BORG_CHUNK_WAVES,
+              granularity_guard=False)
+    host = greedy_replay(
+        ec, ep, fw, wave_width=8, completions_chunk_waves=BORG_CHUNK_WAVES
+    )
+    no_release = greedy_replay(ec, ep, fw, wave_width=8)
+    require(meta["num_gangs"] > 0, "borg case has no gangs")
+    require(0 < host.unschedulable < BORG_TASKS // 2,
+            "borg case: the cluster never runs full")
+    require((host.assignments != no_release.assignments).any(),
+            "borg case: releases change no placement — vacuous")
+    dev = JaxReplayEngine(ec, ep, fw, **kw).replay()
+    same(dev.assignments, host.assignments, "borg single replay")
+    require(dev.placed == host.placed, "borg single replay: placed differs")
+    out["borg_single"] = {
+        "nodes": BORG_NODES, "tasks": BORG_TASKS, "placed": dev.placed,
+        "unschedulable": dev.unschedulable,
+    }
+
+    # (c) the same case as a 4-scenario what-if, completions on, every
+    # scenario against the host reference on the equally perturbed
+    # cluster. Without collect_assignments the batch takes the DEVICE
+    # release program (WhatIfEngine._release_core/_release_fn); with it,
+    # the host pending-fold path, which also yields per-pod assignments.
+    cpu = ec.vocab._r["cpu"]
+    half, few = np.arange(BORG_NODES // 2), np.array([2, 5, 7])
+    scen = [
+        Scenario(),
+        Scenario([Perturbation("scale_capacity", nodes=half,
+                               resource="cpu", factor=0.75)]),
+        Scenario([Perturbation("scale_capacity", nodes=few,
+                               resource="cpu", factor=1.5)]),
+        Scenario([Perturbation("node_down", nodes=np.array([3]))]),
+    ]
+    refs = [host]
+    for sc in scen[1:]:
+        (pt,) = sc.perturbations
+        alloc = ec.allocatable.copy()
+        if pt.op == "node_down":
+            alloc[pt.nodes, :] = 0.0
+        else:
+            alloc[pt.nodes, cpu] = alloc[pt.nodes, cpu] * pt.factor
+        refs.append(greedy_replay(
+            dataclasses.replace(ec, allocatable=alloc), ep, fw,
+            wave_width=8, completions_chunk_waves=BORG_CHUNK_WAVES,
+        ))
+    require(len({r.placed for r in refs}) == 4,
+            "what-if: the perturbations do not change the outcome")
+    on_dev = WhatIfEngine(ec, ep, scen, fw, completions=True, **kw)
+    require(on_dev._completions_dev,
+            "what-if did not take the device release path")
+    r_dev = on_dev.run()
+    r_host = WhatIfEngine(
+        ec, ep, scen, fw, completions=True, collect_assignments=True, **kw
+    ).run()
+    require(r_dev.completions_on and r_host.completions_on,
+            "what-if ran arrivals-only")
+    for s, ref in enumerate(refs):
+        same(r_host.assignments[s], ref.assignments,
+             f"what-if scenario {s} (host-fold path)")
+        require(int(r_dev.placed[s]) == ref.placed,
+                f"what-if scenario {s}: device release path placed "
+                f"{int(r_dev.placed[s])}, host reference {ref.placed}")
+    # Both paths subtract the same sums in the same order, so the final
+    # cpu planes (seen through the per-scenario mean utilization) agree
+    # to the bit.
+    require(np.array_equal(r_dev.utilization_cpu, r_host.utilization_cpu),
+            f"release paths left different cpu planes: utilization "
+            f"{r_dev.utilization_cpu.tolist()} (device) vs "
+            f"{r_host.utilization_cpu.tolist()} (host-fold)")
+    out["borg_whatif"] = {
+        "scenarios": len(scen),
+        "placed": [int(x) for x in r_dev.placed],
+    }
+    return out
+
+
+SERVE_QUERIES = [
+    # q1-q3 fill config20's batch of 3 (the cold build); q4 repeats q1 in a
+    # second batch, which the resident executable must answer WARM and
+    # identically.
+    {"op": "defrag", "tenant": "team-a", "id": "q1",
+     "nodes": [3, 7], "drainAt": 5.0, "recoverAt": 12.0},
+    {"op": "defrag", "tenant": "team-b", "id": "q2",
+     "nodes": [11, 12], "drainAt": 6.0, "recoverAt": 14.0},
+    {"op": "defrag", "tenant": "team-a", "id": "q3",
+     "nodes": [20, 21, 22, 23], "drainAt": 8.0},
+    {"op": "defrag", "tenant": "team-c", "id": "q4",
+     "nodes": [3, 7], "drainAt": 5.0, "recoverAt": 12.0},
+]
+_SERVE_ANSWER_KEYS = (
+    "placed", "unschedulable", "placed_delta", "evictions",
+    "evict_rescheduled", "evict_stranded", "evict_latency_mean",
+    "stranded_cpu", "frag_index_cpu", "packing_efficiency",
+)
+
+
+def phase_serve() -> dict:
+    """``serve examples/config20_service.yaml < queries.ndjson``. config20
+    batches 3 queries (service.maxBatch), so q1-q3 are one cold batch and
+    q4 a second, warm one. The service's cold/warm counters reach only the
+    log, so they are read off the rows: every batch with ``warm: false``
+    was a cold build (a ValueError from set_scenarios quietly rebuilds —
+    sim/service.py — which would show here as a second cold batch)."""
+    prev_cwd, prev_stdin = os.getcwd(), sys.stdin
+    with tempfile.TemporaryDirectory(prefix="ksim_smoke_") as tmp:
+        queries = Path(tmp) / "queries.ndjson"
+        queries.write_text("".join(json.dumps(q) + "\n" for q in SERVE_QUERIES))
+        # config20 writes ./service_results.jsonl: run it from the temp dir.
+        os.chdir(tmp)
+        try:
+            with open(queries) as sys.stdin:
+                cli(["serve", CONFIG20])
+        finally:
+            sys.stdin = prev_stdin
+            os.chdir(prev_cwd)
+        rows = [
+            json.loads(ln)
+            for ln in (Path(tmp) / "service_results.jsonl").read_text().splitlines()
+        ]
+    errors = [r for r in rows if r["kind"] == "query-error"]
+    results = {r["query"]: r for r in rows if r["kind"] == "query-result"}
+    require(not errors, f"query-error rows: {errors}")
+    require(sorted(results) == ["q1", "q2", "q3", "q4"],
+            f"answered {sorted(results)}, want q1..q4")
+    cold = {r["batch"] for r in results.values() if not r["warm"]}
+    warm = {r["batch"] for r in results.values() if r["warm"]}
+    require(len(cold) == 1, f"cold_builds == {len(cold)}, want 1")
+    require(len(warm) >= 1, "warm_hits == 0: the resident engine was not reused")
+    require(results["q4"]["warm"], "the repeated query was not warm")
+    for k in _SERVE_ANSWER_KEYS:
+        require(results["q1"][k] == results["q4"][k],
+                f"q4 repeats q1 but {k}: {results['q1'][k]} != {results['q4'][k]}")
+    require(results["q1"]["evictions"] > 0, "the drain evicted nothing")
+    return {
+        "size": "64 nodes x 2048 pods (serving path, not a size claim)",
+        "engine": results["q1"]["engine"],
+        "queries": len(results),
+        "cold_builds": len(cold),
+        "warm_hits": len(warm),
+        "cold_latency_s": results["q1"]["latency_s"],
+        "warm_latency_s": results["q4"]["latency_s"],
+        "placements": sum(r["placed"] for r in results.values()),
+    }
+
+
+def device_peaks() -> list:
+    import jax
+
+    return [d.memory_stats()["peak_bytes_in_use"] for d in jax.devices()]
+
+
+def phase_mesh() -> dict:
+    """config 5 sharded over every visible device vs the same file on one
+    device, rows compared under KSIM_DETERMINISTIC_JSONL=1."""
+    import yaml
+
+    def strip(rows):
+        # The one-device run reads a copy of the file with mesh off: its
+        # provenance stamps differ by construction, its results must not.
+        drop = ("config", "config_hash", "mesh")
+        return [{k: v for k, v in r.items() if k not in drop} for r in rows]
+
+    before = device_peaks()
+    prev = os.environ.get("KSIM_DETERMINISTIC_JSONL")
+    os.environ["KSIM_DETERMINISTIC_JSONL"] = "1"
+    try:
+        rows_mesh = cli(["what-if", CONFIG5])
+        after = device_peaks()
+        with tempfile.TemporaryDirectory(prefix="ksim_smoke_") as tmp:
+            with open(CONFIG5) as f:
+                doc = yaml.safe_load(f)
+            doc["whatIf"]["mesh"] = False
+            one = Path(tmp) / "config5_one_device.yaml"
+            one.write_text(yaml.safe_dump(doc))
+            rows_one = cli(["what-if", str(one)])
+    finally:
+        if prev is None:
+            del os.environ["KSIM_DETERMINISTIC_JSONL"]
+        else:
+            os.environ["KSIM_DETERMINISTIC_JSONL"] = prev
+    S, N, P = config_size(CONFIG5)
+    agg, scen = split_whatif(rows_mesh)
+    require(agg["mesh"] is True and agg["scenarios"] == S == len(scen),
+            f"mesh run: mesh={agg['mesh']} scenarios={agg['scenarios']}")
+    # Devices past the first ran nothing in the earlier phases, so a peak
+    # that grew means the mesh run put data (its shard) there.
+    held = [a > b for a, b in zip(after, before)]
+    require(all(held[1:]) and after[0] > 0,
+            f"devices that held a shard: {held} (peak bytes {after})")
+    require(strip(rows_mesh) == strip(rows_one),
+            "mesh rows differ from the one-device run of the same file")
+    return {
+        "size": f"{S} scenarios x {N} nodes x {P} pods",
+        "engine": agg["engine"],
+        "n_devices": len(held),
+        "peak_bytes_per_device": after,
+        "placements": agg["total_placed"],
+        "rows_equal_one_device": True,
+    }
+
+
+def result_line(report: dict) -> str:
+    """The last stdout line: exactly ``ok`` and ``device`` {platform, kind,
+    count} as JAX reports them — the driver refuses any other key."""
+    dev = report["device"]
+    return json.dumps({
+        "ok": report["ok"],
+        "device": {
+            "platform": dev["platform"],
+            "kind": dev["kind"],
+            "count": dev["count"],
+        },
+    })
+
+
+def main() -> int:
+    t_start = time.perf_counter()
+    import jax
+
+    dev0 = jax.devices()[0]
+    if dev0.platform != "tpu":
+        say(f"no TPU: jax.devices()[0].platform == {dev0.platform!r}, "
+            f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r} — refusing "
+            "to run (this smoke has no CPU mode)")
+        return 1
+    meter = CompileMeter()
+
+    import importlib.metadata as md
+
+    import jaxlib
+
+    from kubernetes_simulator_tpu import native
+    from kubernetes_simulator_tpu.utils import compile_cache
+
+    cache_dir = compile_cache.enable()
+    require(cache_dir is not None,
+            "compile cache is off (KSIM_COMPILE_CACHE=0?) — every run "
+            "would pay a cold compile")
+    require(native.available(),
+            "native packers did not build (g++ output is in the log "
+            "above) — the Python fallbacks cost minutes at 1M pods")
+    report = {
+        "ok": True,
+        "device": {
+            "platform": dev0.platform,
+            "kind": dev0.device_kind,
+            "count": len(jax.devices()),
+        },
+        "versions": {
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            "libtpu": md.version("libtpu"),
+        },
+        "compile_cache_dir": cache_dir,
+        "native": True,
+        "phases": {},
+    }
+
+    def run(name, fn, *args):
+        say(f"phase {name} ...")
+        t0, c0 = time.perf_counter(), dict(meter.totals)
+        out = fn(*args)
+        out["cold_wall_s"] = round(time.perf_counter() - t0, 3)
+        out.update(meter.since(c0))
+        out.setdefault("reduced", None)
+        say(f"phase {name} ok: {json.dumps(out)}")
+        report["phases"][name] = out
+        return out
+
+    w3 = run("whatif-config3", phase_whatif_config3)
+    run("run-config2", phase_run_config2, w3["placed_scenario0"])
+    run("parity", phase_parity)
+    run("serve", phase_serve)
+    if len(jax.devices()) > 1:
+        run("mesh", phase_mesh)
+    report["total_wall_s"] = round(time.perf_counter() - t_start, 3)
+    report["claim"] = None
+    print(json.dumps({"kind": "chip-smoke-report", **report}))
+    print(result_line(report), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

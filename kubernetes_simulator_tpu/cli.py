@@ -17,6 +17,7 @@ import sys
 
 from .framework.registry import get_strategy
 from .parallel import dcn
+from .utils import compile_cache
 from .utils.config import SimConfig, build_encoded_case
 from .utils.metrics import (
     JsonlWriter,
@@ -1044,9 +1045,13 @@ def main(argv=None) -> int:
             os.environ.setdefault("KSIM_DCN_DURABLE_DIR", str(du.dir))
             if du.resume:
                 os.environ.setdefault("KSIM_DCN_RESUME", "1")
+    # Persistent compile cache for every command that compiles, single
+    # process or fleet — BEFORE jax.distributed.initialize (documented
+    # ordering). None means off (CPU backend / KSIM_COMPILE_CACHE=0).
+    if args.cmd != "validate":
+        log.info("compile cache: %s", compile_cache.enable())
     # Multi-host DCN bring-up (round 11): a no-op without the
-    # KSIM_DCN_* env set by scripts/dcn_launch.py. Enables the compile
-    # cache BEFORE jax.distributed.initialize (documented ordering).
+    # KSIM_DCN_* env set by scripts/dcn_launch.py.
     if dcn.maybe_init_from_env():
         nproc, pid = dcn.process_info()
         log.info("DCN: process %d/%d up", pid, nproc)

@@ -11,12 +11,15 @@ two is pinned by tests/test_native.py.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 from pathlib import Path
 from typing import Optional
 
 import numpy as np
+
+log = logging.getLogger(__name__)
 
 _REPO = Path(__file__).resolve().parent.parent.parent
 _SRC = _REPO / "native"
@@ -44,8 +47,15 @@ def _build_lib() -> Optional[Path]:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, so)
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
         tmp.unlink(missing_ok=True)
+        # Loud: the Python packers are minutes slower at 1M pods, so a
+        # drop to them must show up in the log with the compiler's words.
+        stderr = getattr(e, "stderr", None) or b""
+        log.warning(
+            "native build failed, using the pure-Python fallbacks: %r\n%s",
+            e, stderr.decode(errors="replace"),
+        )
         return None
     return so
 
@@ -60,7 +70,8 @@ def _lib() -> Optional[ctypes.CDLL]:
         if so is not None:
             try:
                 lib = ctypes.CDLL(str(so))
-            except OSError:
+            except OSError as e:
+                log.warning("native library %s did not load: %r", so, e)
                 return None
             lib.ksim_pack_waves.restype = ctypes.c_int64
             lib.ksim_pack_waves.argtypes = [

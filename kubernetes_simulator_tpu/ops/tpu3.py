@@ -68,31 +68,6 @@ from .tpu import (
     select_node,
 )
 
-# Compat shim: some jax versions ship optimization_barrier without a vmap
-# batching rule, and the what-if engine vmaps wave_step (which uses the
-# barrier to pin the feasibility/plane-update schedule). The barrier is
-# identity-per-operand, so under vmap we DROP it entirely (pass the
-# batched operands through unbound) rather than re-binding the
-# primitive: the SPMD partitioner has no sharding rule for it, and a
-# barrier surviving into the mesh-sharded what-if program makes GSPMD
-# replicate its operands — all-gathers on the scenario axis
-# (test_mesh_hlo pins their absence). Values are unaffected either way
-# (the barrier is a scheduling hint, not an op); the non-vmapped
-# single-replay path keeps the real barrier.
-try:  # pragma: no cover - version-dependent
-    from jax._src.lax.control_flow import optimization_barrier_p as _ob_p
-    from jax.interpreters import batching as _batching
-
-    if _ob_p not in _batching.primitive_batchers:
-
-        def _ob_batch(args, dims, **params):
-            del params
-            return list(args), list(dims)
-
-        _batching.primitive_batchers[_ob_p] = _ob_batch
-except Exception:
-    pass
-
 # Round 10 (fused tier-preemption, PR 2's measured 4.1× standalone cost):
 # when on, the preemption wave program (a) packs the three prefix-over-
 # tiers stacks into ONE [Tt+1, R+2, N] tensor so each slot pays a single
@@ -1202,7 +1177,12 @@ def make_wave_step3(
                 nonfit = nonfit & naok_k
 
             # Materialize `feasible` once: it feeds several reduce-rooted
-            # kernels (domfeas, select). used1_r stays UN-materialized since
+            # kernels (domfeas, select). On jax 0.9 optimization_barrier
+            # has a batching rule, so the barrier also stays in the vmapped
+            # what-if program (earlier rounds dropped it there through a
+            # shim and timed that program); whether it should stay under
+            # vmap is a measured question for a later PR.
+            # used1_r stays UN-materialized since
             # round 3 — its two consumers (the feasible fusion and the
             # select reduce's fit score) each re-derive it from carry.used
             # at the same read cost, and skipping the barrier removes the

@@ -59,30 +59,18 @@ def init_distributed(
         return
     from . import dcn
 
-    if not (dcn.recover_enabled() or dcn.wq_enabled()):
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-        return
-    import os
+    heartbeat_timeout_s = 100  # jax.distributed's own default
+    if dcn.recover_enabled() or dcn.wq_enabled():
+        import os
 
-    from jax._src import distributed as _dist
-    from jax._src import xla_bridge as _xb
-
-    if _xb.backends_are_initialized():
-        raise RuntimeError(
-            "init_distributed() must be called before any JAX "
-            "computations are executed."
-        )
-    timeout_s = float(os.environ.get("KSIM_DCN_TIMEOUT_S", "300"))
-    _dist.global_state.initialize(
+        # Twice the gather deadline, never below the runtime's default.
+        timeout_s = float(os.environ.get("KSIM_DCN_TIMEOUT_S", "300"))
+        heartbeat_timeout_s = max(int(2 * timeout_s), heartbeat_timeout_s)
+    jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
         process_id=process_id,
-        service_heartbeat_interval_seconds=10,
-        service_max_missing_heartbeats=max(int(timeout_s / 5), 10),
+        heartbeat_timeout_seconds=heartbeat_timeout_s,
     )
 
 

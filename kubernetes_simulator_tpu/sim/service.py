@@ -112,7 +112,7 @@ class ServiceStats:
     warm_hits: int = 0
     evicted_engines: int = 0
     errors: int = 0
-    compile_counts: Dict[str, Optional[int]] = field(default_factory=dict)
+    compile_counts: Dict[str, int] = field(default_factory=dict)
 
 
 class QueryService:

@@ -708,11 +708,11 @@ class PolicyTuner:
                         "device objective by %.3g (> envelope %.3g)",
                         cpu_env, self.cpu_envelope,
                     )
-        compile_count = None
-        try:
-            compile_count = int(self._train_engine._chunk_fn._cache_size())
-        except Exception:  # jaxlib without _cache_size — report unknown
-            pass
+        compile_count = (
+            int(self._train_engine._chunk_fn._cache_size())
+            if self._train_engine is not None
+            else None  # host evaluator: no device executable
+        )
         emit({
             "kind": "tune-result",
             "best_policy": self.space.describe(best_vec),

@@ -17,6 +17,12 @@ Process 0's output streams through; siblings are captured and replayed on
 failure. Any child failing kills the rest (a DCN replay cannot complete
 with a hole in the scenario axis).
 
+CPU ONLY. Every child runs with ``JAX_PLATFORMS=cpu`` whatever the
+caller's environment says: a TPU chip belongs to one process at a time,
+so N processes on one machine cannot share it (the second one fails or
+hangs). The fleet layer has never run on a chip; on-chip runs are one
+process — ``python chip_smoke.py``, or the CLI / bench.py directly.
+
 ``--watch`` (round 12) tails the workers' liveness heartbeats
 (parallel.dcn.heartbeat mirrors each beacon to ``$KSIM_DCN_HB_DIR``) and
 prints fleet progress to stderr every couple of seconds: last completed
@@ -115,7 +121,7 @@ def child_env(
         # (the coordination connect still happens at launch — the
         # runtime barriers on it; parallel.dcn.wq_run sleeps instead).
         env["KSIM_DCN_JOIN_DELAY_S"] = str(join_delay)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"  # CPU harness: N children cannot share a chip
     flags = [
         f for f in env.get("XLA_FLAGS", "").split()
         if not f.startswith("--xla_force_host_platform_device_count")

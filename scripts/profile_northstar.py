@@ -35,7 +35,10 @@ from kubernetes_simulator_tpu.ops import tpu as T
 from kubernetes_simulator_tpu.sim.borg import BorgSpec, make_borg_encoded
 from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine, uniform_scenarios
 
-V5E_PEAK_GBS = 819.0  # HBM bandwidth, TPU v5e (public spec)
+# Peak HBM bandwidth in GB/s by jax device_kind (Google Cloud documentation,
+# "TPU v5e": 819 GB/s; JAX reports the v5e as "TPU v5 lite"). A device that
+# is not listed gets no "% of peak" figure.
+PEAK_HBM_GBS = {"TPU v5 lite": 819.0}
 
 
 def main():
@@ -108,13 +111,19 @@ def main():
         jax.block_until_ready(out)
         walls.append(time.perf_counter() - t0)
     wall = float(np.median(walls))
+    kind = jax.devices()[0].device_kind
+    peak = PEAK_HBM_GBS.get(kind)
+    of_peak = (
+        f" ({100 * bytes_acc / wall / 1e9 / peak:.0f}% of {kind} peak)"
+        if peak
+        else f" (no peak on record for {kind!r})"
+    )
     attempts = C * wave * S
     n_waves_total = eng.waves.idx.shape[0]
     print(
         f"chunk wall={wall:.3f}s (runs {['%.3f' % w for w in walls]})  "
         f"attempts/s={attempts / wall / 1e6:.2f}M  "
-        f"achieved_bw={bytes_acc / wall / 1e9:.0f} GB/s "
-        f"({100 * bytes_acc / wall / 1e9 / V5E_PEAK_GBS:.0f}% of v5e peak)  "
+        f"achieved_bw={bytes_acc / wall / 1e9:.0f} GB/s{of_peak}  "
         f"flops_rate={flops / wall / 1e12:.2f} TF/s",
         flush=True,
     )

@@ -6,7 +6,9 @@ probe re-runs ITSELF under scripts/dcn_launch.py for each process count,
 so the scaling record holds device-count sweeps (the default sweep below)
 and DCN process-count sweeps side by side. Inside a DCN fleet every
 process prints its local wall; read process 0's line (the others carry a
-[pN] prefix only on failure).
+[pN] prefix only on failure). The launcher is a CPU harness (its children
+run with JAX_PLATFORMS=cpu — N processes cannot share a chip), so the
+``--dcn`` axis is a CPU record whatever device the parent sweep ran on.
 
 ``--exchange [OUT_JSON]`` (round 19) pins the per-slot selection-exchange
 payload bytes and replay wall at node_shards ∈ {1, 2, 4, 8} into a JSON

@@ -211,7 +211,7 @@ def test_atomic_put_writes_whole_entries(tmp_path):
         patch_atomic_writes,
     )
 
-    assert patch_atomic_writes() is True
+    patch_atomic_writes()
     cache = _lru.LRUCache(str(tmp_path), max_size=-1)
     cache.put("entry", b"x" * 1024)
     assert cache.get("entry") == b"x" * 1024

@@ -328,17 +328,3 @@ class TestValidate:
         assert rc == 1
         assert "dcn.recovery.checkpointEvery" in out
         assert "dcn.recovery.maxClaims" in out
-
-    def test_compile_cache_repeat_enable_reports_configured_dir(
-        self, tmp_path
-    ):
-        """ADVICE r4: a second enable() with a different dir must return
-        the dir JAX actually uses, not the ignored new one."""
-        import pytest
-
-        from kubernetes_simulator_tpu.utils import compile_cache as cc
-
-        first = cc.enable()  # whatever conftest/env already configured
-        if first is None:
-            pytest.skip("compile cache disabled in this environment")
-        assert cc.enable(str(tmp_path / "other_cache")) == first

@@ -287,8 +287,7 @@ def test_warm_queries_zero_recompile():
     st = svc.stats()
     assert st["cold_builds"] == 1 and st["warm_hits"] == 1
     assert st["compile_counts"] == {"defrag/summary": 1}
-    if compiled_cache_size(eng._chunk_fn) is not None:
-        assert compiled_cache_size(eng._chunk_fn) == 1
+    assert compiled_cache_size(eng._chunk_fn) == 1
     # Writer saw admission + result rows, wall fields scrubbed-safe keys
     # present for the schema (values stay real without deterministic
     # mode).
@@ -325,8 +324,7 @@ def test_simulator_what_if_engine_reuse():
     eng = sim._whatif_cache[1]
     res2 = sim.what_if(scenarios=_scens(0.125), **ENGINE_KW)
     assert sim._whatif_cache[1] is eng  # resident, not rebuilt
-    if compiled_cache_size(eng._chunk_fn) is not None:
-        assert compiled_cache_size(eng._chunk_fn) == 1
+    assert compiled_cache_size(eng._chunk_fn) == 1
     fresh = Simulator(nodes_and_pods, pods_l, strategy="jax",
                       plugins=[{"name": "NodeResourcesFit"}]).what_if(
         scenarios=_scens(0.125), **ENGINE_KW)
