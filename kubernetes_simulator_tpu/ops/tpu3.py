@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.encode import PAD, EncodedCluster, EncodedPods
+from ..utils.profiling import stage
 from . import tpu as T2
 from .tpu import (
     DevCluster,
@@ -962,817 +963,834 @@ def make_wave_step3(
     def wave_step(carry: DevState3, batch):
         sb, sx = batch
         N = dc.allocatable.shape[0]
-        pre = build_wave_pre3(dc, d, sh, st, sb, sx, spec, dyn)
+        with stage("ksim.reads"):
+            pre = build_wave_pre3(dc, d, sh, st, sb, sx, spec, dyn)
 
-        # Wave-start reads (identical for every pod in the wave).
-        if st.KT:
-            lhs_c = pre.oh_row * pre.coarse_row[:, :, None]  # [W, KT, G]
-            rows0 = (
-                jnp.einsum("wkg,gd->wkd", lhs_c * kmask["mc"][None, :, None],
-                           carry.mc_dom, precision=_HI)
-                + jnp.einsum("wkg,gd->wkd", lhs_c * kmask["anti"][None, :, None],
-                             carry.anti_dom, precision=_HI)
-                + jnp.einsum("wkg,gd->wkd", lhs_c * kmask["pref"][None, :, None],
-                             carry.pref_dom, precision=_HI)
-            )  # [W, KT, Dcap]
-            if st.has_host_rows:
-                # One-hot LHS cast to the plane dtype: bf16×bf16 einsums
-                # with f32 accumulation stay exact (0/1 × small ints).
-                vals_h0 = jnp.zeros((wave_width, st.KT, N), jnp.float32)
-                if len(st.mc_h_ids):
-                    vals_h0 = vals_h0 + jnp.einsum(
-                        "wkh,hn->wkn", pre.oh_mc_h.astype(carry.mc_host.dtype),
-                        carry.mc_host, precision=_HI,
-                        preferred_element_type=jnp.float32,
+            # Wave-start reads (identical for every pod in the wave).
+            if st.KT:
+                lhs_c = pre.oh_row * pre.coarse_row[:, :, None]  # [W, KT, G]
+                rows0 = (
+                    jnp.einsum("wkg,gd->wkd", lhs_c * kmask["mc"][None, :, None],
+                               carry.mc_dom, precision=_HI)
+                    + jnp.einsum("wkg,gd->wkd", lhs_c * kmask["anti"][None, :, None],
+                                 carry.anti_dom, precision=_HI)
+                    + jnp.einsum("wkg,gd->wkd", lhs_c * kmask["pref"][None, :, None],
+                                 carry.pref_dom, precision=_HI)
+                )  # [W, KT, Dcap]
+                if st.has_host_rows:
+                    # One-hot LHS cast to the plane dtype: bf16×bf16 einsums
+                    # with f32 accumulation stay exact (0/1 × small ints).
+                    vals_h0 = jnp.zeros((wave_width, st.KT, N), jnp.float32)
+                    if len(st.mc_h_ids):
+                        vals_h0 = vals_h0 + jnp.einsum(
+                            "wkh,hn->wkn", pre.oh_mc_h.astype(carry.mc_host.dtype),
+                            carry.mc_host, precision=_HI,
+                            preferred_element_type=jnp.float32,
+                        )
+                    if len(st.anti_h_ids):
+                        vals_h0 = vals_h0 + jnp.einsum(
+                            "wkh,hn->wkn", pre.oh_anti_h.astype(carry.anti_host.dtype),
+                            carry.anti_host, precision=_HI,
+                            preferred_element_type=jnp.float32,
+                        )
+                    if len(st.pref_h_ids):
+                        vals_h0 = vals_h0 + jnp.einsum(
+                            "wkh,hn->wkn", pre.oh_pref_h, carry.pref_host, precision=_HI
+                        )
+                totals0 = jnp.einsum("wkg,g->wk", pre.oh_row, carry.match_total, precision=_HI)
+                if need_vals:
+                    # Per-wave node→domain one-hot (scenario-shared) for expansion.
+                    dom_oh = (
+                        pre.dmap[..., None] == jnp.arange(Dcap, dtype=jnp.float32)
+                    ).astype(jnp.float32)  # [W, KT, N, Dcap]
+                if spread_dom_hilo and not st.seg_mode:
+                    # [W, N, Dcap+1]: spread-row domain one-hot + no-domain col
+                    # (built from dmap directly — dom_oh may be skipped).
+                    # seg_mode needs neither: domfeas rides the bit-OR reduce
+                    # and the score expansion is a tile/repeat.
+                    # bf16: 0/1 one-hots and the integer score values they meet
+                    # (≤ MAX_NODE_SCORE) are bf16-exact; accumulation stays f32
+                    # via preferred_element_type. Halves the dominant operand
+                    # traffic of both domain einsums.
+                    domoh2 = jnp.concatenate(
+                        [
+                            (
+                                pre.dmap[:, o2][..., None]
+                                == jnp.arange(Dcap, dtype=jnp.float32)
+                            ).astype(jnp.bfloat16),
+                            (pre.dmap[:, o2] < 0)[..., None].astype(jnp.bfloat16),
+                        ],
+                        axis=-1,
                     )
-                if len(st.anti_h_ids):
-                    vals_h0 = vals_h0 + jnp.einsum(
-                        "wkh,hn->wkn", pre.oh_anti_h.astype(carry.anti_host.dtype),
-                        carry.anti_host, precision=_HI,
-                        preferred_element_type=jnp.float32,
+                # #domains per row (for the domain-space spread min).
+                nd_row = jnp.einsum(
+                    "wkg,g->wk", pre.oh_row, jnp.asarray(st.nd_g, jnp.float32),
+                    precision=_HI,
+                )  # [W, KT]
+            iota_n = jnp.arange(N)
+            if Kdyn:
+                # [K, N] override-node one-hots, built once per wave (f32: the
+                # count deltas they meet are unbounded integers — bf16 would
+                # round past 256).
+                at_ov = (
+                    dyn.ov_nodes[:, None] == iota_n[None, :]
+                ).astype(jnp.float32)
+            R = carry.used.shape[0]
+            if st.preemption:
+                # Prefix-over-tiers stacks: [Tt+1, ...]; row t = aggregate over
+                # tiers < t (wave-start values; in-wave corrections per pod).
+                pfx_u = [jnp.zeros((R, N), jnp.float32)]
+                pfx_n = [jnp.zeros((N,), jnp.float32)]
+                mts = [jnp.full((N,), -1.0, jnp.float32)]
+                for t in range(st.Tt):
+                    pfx_u.append(pfx_u[-1] + carry.used_tier[t])
+                    pfx_n.append(pfx_n[-1] + carry.npods_tier[t])
+                    mts.append(
+                        jnp.maximum(mts[-1], jnp.where(carry.npods_tier[t] > 0, float(t), -1.0))
                     )
-                if len(st.pref_h_ids):
-                    vals_h0 = vals_h0 + jnp.einsum(
-                        "wkh,hn->wkn", pre.oh_pref_h, carry.pref_host, precision=_HI
+                pfx_u = jnp.stack(pfx_u)  # [Tt+1, R, N]
+                pfx_n = jnp.stack(pfx_n)  # [Tt+1, N]
+                mts = jnp.stack(mts)  # [Tt+1, N]
+                if FUSED_PREEMPT:
+                    # One packed [Tt+1, R+2, N] stack: each slot's tier gather
+                    # becomes a single dynamic read (rows [:R] usage, row R
+                    # pod counts, row R+1 max tier) instead of three. Pure
+                    # layout — every element is the same f32 value the
+                    # separate stacks hold.
+                    pfx_pack = jnp.concatenate(
+                        [pfx_u, pfx_n[:, None, :], mts[:, None, :]], axis=1
                     )
-            totals0 = jnp.einsum("wkg,g->wk", pre.oh_row, carry.match_total, precision=_HI)
-            if need_vals:
-                # Per-wave node→domain one-hot (scenario-shared) for expansion.
-                dom_oh = (
-                    pre.dmap[..., None] == jnp.arange(Dcap, dtype=jnp.float32)
-                ).astype(jnp.float32)  # [W, KT, N, Dcap]
-            if spread_dom_hilo and not st.seg_mode:
-                # [W, N, Dcap+1]: spread-row domain one-hot + no-domain col
-                # (built from dmap directly — dom_oh may be skipped).
-                # seg_mode needs neither: domfeas rides the bit-OR reduce
-                # and the score expansion is a tile/repeat.
-                # bf16: 0/1 one-hots and the integer score values they meet
-                # (≤ MAX_NODE_SCORE) are bf16-exact; accumulation stays f32
-                # via preferred_element_type. Halves the dominant operand
-                # traffic of both domain einsums.
-                domoh2 = jnp.concatenate(
-                    [
-                        (
-                            pre.dmap[:, o2][..., None]
-                            == jnp.arange(Dcap, dtype=jnp.float32)
-                        ).astype(jnp.bfloat16),
-                        (pre.dmap[:, o2] < 0)[..., None].astype(jnp.bfloat16),
-                    ],
-                    axis=-1,
-                )
-            # #domains per row (for the domain-space spread min).
-            nd_row = jnp.einsum(
-                "wkg,g->wk", pre.oh_row, jnp.asarray(st.nd_g, jnp.float32),
-                precision=_HI,
-            )  # [W, KT]
-        iota_n = jnp.arange(N)
-        if Kdyn:
-            # [K, N] override-node one-hots, built once per wave (f32: the
-            # count deltas they meet are unbounded integers — bf16 would
-            # round past 256).
-            at_ov = (
-                dyn.ov_nodes[:, None] == iota_n[None, :]
-            ).astype(jnp.float32)
-        R = carry.used.shape[0]
-        if st.preemption:
-            # Prefix-over-tiers stacks: [Tt+1, ...]; row t = aggregate over
-            # tiers < t (wave-start values; in-wave corrections per pod).
-            pfx_u = [jnp.zeros((R, N), jnp.float32)]
-            pfx_n = [jnp.zeros((N,), jnp.float32)]
-            mts = [jnp.full((N,), -1.0, jnp.float32)]
-            for t in range(st.Tt):
-                pfx_u.append(pfx_u[-1] + carry.used_tier[t])
-                pfx_n.append(pfx_n[-1] + carry.npods_tier[t])
-                mts.append(
-                    jnp.maximum(mts[-1], jnp.where(carry.npods_tier[t] > 0, float(t), -1.0))
-                )
-            pfx_u = jnp.stack(pfx_u)  # [Tt+1, R, N]
-            pfx_n = jnp.stack(pfx_n)  # [Tt+1, N]
-            mts = jnp.stack(mts)  # [Tt+1, N]
-            if FUSED_PREEMPT:
-                # One packed [Tt+1, R+2, N] stack: each slot's tier gather
-                # becomes a single dynamic read (rows [:R] usage, row R
-                # pod counts, row R+1 max tier) instead of three. Pure
-                # layout — every element is the same f32 value the
-                # separate stacks hold.
-                pfx_pack = jnp.concatenate(
-                    [pfx_u, pfx_n[:, None, :], mts[:, None, :]], axis=1
-                )
-            preempted = jnp.zeros((), bool)
-            ev_node = jnp.asarray(PAD, jnp.int32)
-            ev_tier = jnp.zeros((), jnp.int32)
-            ev_prior = jnp.zeros((), jnp.float32)
-            ev_total = jnp.zeros((), jnp.float32)
-            eu_acc = [jnp.zeros((), jnp.float32) for _ in range(R)]
-            evicted = []  # per-slot "evicted mid-wave" flags
+                preempted = jnp.zeros((), bool)
+                ev_node = jnp.asarray(PAD, jnp.int32)
+                ev_tier = jnp.zeros((), jnp.int32)
+                ev_prior = jnp.zeros((), jnp.float32)
+                ev_total = jnp.zeros((), jnp.float32)
+                eu_acc = [jnp.zeros((), jnp.float32) for _ in range(R)]
+                evicted = []  # per-slot "evicted mid-wave" flags
         choices, placeds, dom_ats = [], [], []
         for k in range(wave_width):
-            s = jax.tree.map(lambda a: a[k], sb)
+            with stage("ksim.reads"):
+                s = jax.tree.map(lambda a: a[k], sb)
 
             # --- exact in-wave corrections from pods j<k -----------------
             # One-hots are rebuilt from the chosen-node index inside the
             # consuming fusions (never materialized as carried values).
-            rows_corr = jnp.zeros((st.KT, Dcap), jnp.float32) if st.KT else None
-            valh_corr = (
-                jnp.zeros((st.KT, N), jnp.float32)
-                if (st.KT and st.has_host_rows)
-                else None
-            )
-            tot_corr = jnp.zeros((st.KT,), jnp.float32) if st.KT else None
-            used_corr_r = [jnp.zeros((N,), jnp.float32) for _ in range(R)]
-            if st.preemption and k > 0:
-                # An earlier in-wave eviction frees wave-start usage at the
-                # evicted node (evicted slots are excluded below).
-                oh_e = (
-                    preempted.astype(jnp.float32)
-                    * (iota_n == ev_node).astype(jnp.float32)
+            with stage("ksim.corrections"):
+                rows_corr = jnp.zeros((st.KT, Dcap), jnp.float32) if st.KT else None
+                valh_corr = (
+                    jnp.zeros((st.KT, N), jnp.float32)
+                    if (st.KT and st.has_host_rows)
+                    else None
                 )
-                for r in range(R):
-                    used_corr_r[r] = used_corr_r[r] - eu_acc[r] * oh_e
-            for j in range(k):
-                wj = placeds[j].astype(jnp.float32)
-                if st.preemption:
-                    wj_used = wj * (1.0 - evicted[j].astype(jnp.float32))
-                else:
-                    wj_used = wj
-                oh_j = (iota_n == choices[j]).astype(jnp.float32)
-                for r in range(R):
-                    used_corr_r[r] = used_corr_r[r] + wj_used * oh_j * sb.req[j, r]
-                # Count corrections below keep evicted slots (phantom rule).
-                if st.KT:
-                    # domain of j's bound node under row (k, r)'s group
-                    domat_r = jnp.einsum(
-                        "g,rg->r", dom_ats[j], pre.oh_row[k], precision=_HI
-                    )  # [KT]
-                    ovr = pre.ov[j, k] * pre.coarse_row[k]  # [KT]
-                    oh_d = (
-                        domat_r[:, None] == jnp.arange(Dcap, dtype=jnp.float32)
-                    ).astype(jnp.float32)
-                    rows_corr = rows_corr + (wj * ovr)[:, None] * oh_d
-                    if st.has_host_rows:
-                        ovh = (
-                            wj
-                            * pre.ov[j, k]
-                            * (1.0 - pre.coarse_row[k])
-                            * (pre.row_g[k] >= 0)
-                            * (domat_r >= 0)
-                        )
-                        # Domain-equality form: credits every node sharing
-                        # the bound node's domain (== the bound node alone
-                        # for singleton/hostname topologies).
-                        valh_corr = valh_corr + ovh[:, None] * (
-                            pre.dmap[k] == domat_r[:, None]
-                        )
-                    tot_corr = tot_corr + wj * pre.ov[j, k] * kmask["mc"] * (
-                        domat_r >= 0
+                tot_corr = jnp.zeros((st.KT,), jnp.float32) if st.KT else None
+                used_corr_r = [jnp.zeros((N,), jnp.float32) for _ in range(R)]
+                if st.preemption and k > 0:
+                    # An earlier in-wave eviction frees wave-start usage at the
+                    # evicted node (evicted slots are excluded below).
+                    oh_e = (
+                        preempted.astype(jnp.float32)
+                        * (iota_n == ev_node).astype(jnp.float32)
                     )
+                    for r in range(R):
+                        used_corr_r[r] = used_corr_r[r] - eu_acc[r] * oh_e
+                for j in range(k):
+                    wj = placeds[j].astype(jnp.float32)
+                    if st.preemption:
+                        wj_used = wj * (1.0 - evicted[j].astype(jnp.float32))
+                    else:
+                        wj_used = wj
+                    oh_j = (iota_n == choices[j]).astype(jnp.float32)
+                    for r in range(R):
+                        used_corr_r[r] = used_corr_r[r] + wj_used * oh_j * sb.req[j, r]
+                    # Count corrections below keep evicted slots (phantom rule).
+                    if st.KT:
+                        # domain of j's bound node under row (k, r)'s group
+                        domat_r = jnp.einsum(
+                            "g,rg->r", dom_ats[j], pre.oh_row[k], precision=_HI
+                        )  # [KT]
+                        ovr = pre.ov[j, k] * pre.coarse_row[k]  # [KT]
+                        oh_d = (
+                            domat_r[:, None] == jnp.arange(Dcap, dtype=jnp.float32)
+                        ).astype(jnp.float32)
+                        rows_corr = rows_corr + (wj * ovr)[:, None] * oh_d
+                        if st.has_host_rows:
+                            ovh = (
+                                wj
+                                * pre.ov[j, k]
+                                * (1.0 - pre.coarse_row[k])
+                                * (pre.row_g[k] >= 0)
+                                * (domat_r >= 0)
+                            )
+                            # Domain-equality form: credits every node sharing
+                            # the bound node's domain (== the bound node alone
+                            # for singleton/hostname topologies).
+                            valh_corr = valh_corr + ovh[:, None] * (
+                                pre.dmap[k] == domat_r[:, None]
+                            )
+                        tot_corr = tot_corr + wj * pre.ov[j, k] * kmask["mc"] * (
+                            domat_r >= 0
+                        )
 
             # --- fused Filter + Score (bit-identical to v2) --------------
             # used1_r = per-resource used-after-this-pod planes, shared by
             # the fit mask and every fit scoring strategy.
-            used1_r = [
-                carry.used[r] + used_corr_r[r] + s.req[r] for r in range(R)
-            ]
-            alloc_r = [dc.allocatable[:, r] for r in range(R)]
-            # Non-fit filters tracked separately: preemption candidacy
-            # reuses them with the fit check replaced by fit-after-evict.
-            feasible = jnp.ones(N, bool)
-            if spec.fit:
-                for r in range(R):
-                    feasible = feasible & (used1_r[r] <= alloc_r[r] + 1e-6)
-            fit_ok = feasible
-            nonfit = jnp.ones(N, bool)
-            if spec.taints:
-                if st.use_tol_classes:
-                    # Row select by class id — a dynamic slice reads ONE
-                    # [N] row. (The old one-hot einsum contracted the whole
-                    # [C, N] plane per pod: 40% of device time on the
-                    # north-star profile.) Values identical: one-hot × f32
-                    # picked the same row exactly.
-                    tok_k = (
-                        jax.lax.dynamic_index_in_dim(
-                            cmasks["tol_ok"], sx.tol_class[k], 0, keepdims=False
-                        )
-                        > 0.5
-                    )
-                    traw_k = jax.lax.dynamic_index_in_dim(
-                        cmasks["tol_raw"], sx.tol_class[k], 0, keepdims=False
-                    )
-                else:
-                    tok_k, traw_k = pre.taint_ok[k], pre.taint_raw[k]
-                nonfit = nonfit & tok_k
-            if spec.node_affinity:
-                if st.use_na_classes:
-                    naok_k = (
-                        jax.lax.dynamic_index_in_dim(
-                            cmasks["na_ok"], sx.na_class[k], 0, keepdims=False
-                        )
-                        > 0.5
-                    )
-                    naraw_k = jax.lax.dynamic_index_in_dim(
-                        cmasks["na_raw"], sx.na_class[k], 0, keepdims=False
-                    )
-                else:
-                    naok_k, naraw_k = pre.na_ok[k], pre.na_raw[k]
-                nonfit = nonfit & naok_k
-
-            # Materialize `feasible` once: it feeds several reduce-rooted
-            # kernels (domfeas, select). On jax 0.9 optimization_barrier
-            # has a batching rule, so the barrier also stays in the vmapped
-            # what-if program (earlier rounds dropped it there through a
-            # shim and timed that program); whether it should stay under
-            # vmap is a measured question for a later PR.
-            # used1_r stays UN-materialized since
-            # round 3 — its two consumers (the feasible fusion and the
-            # select reduce's fit score) each re-derive it from carry.used
-            # at the same read cost, and skipping the barrier removes the
-            # R×[S, N] write per pod (~14% of device time on the profile).
-            # Preemption still materializes (prefit re-reads used1_r).
-            if st.preemption:
-                used1_r = list(jax.lax.optimization_barrier(tuple(used1_r)))
-            feasible = jax.lax.optimization_barrier(feasible)
-            if st.KT:
-                rows_k = rows0[k] + rows_corr  # [KT, Dcap]
-                totals = totals0[k] + tot_corr
-                if need_vals:
-                    vals = _expand_rows(rows_k, dom_oh[k])
-                    if st.has_host_rows:
-                        vals = vals + vals_h0[k] + valh_corr
-                    gvalid = pre.dmap[k] >= 0  # [KT, N]
-                    if Kdyn:
-                        # labels_dirty: corrections on top of the BASE
-                        # expansion — for each perturbed node, swap in
-                        # rows_k at its new domain and its new validity.
-                        # PAD ids give all-zero one-hots. ONE [2KT, K] ×
-                        # [K, N] matmul carries both the value deltas and
-                        # the validity flips (a per-j Python loop fused
-                        # badly: 1.8× on the config-3 dirty batch).
-                        arange_d = jnp.arange(Dcap, dtype=jnp.float32)
-                        ohn = (
-                            pre.ov_new_row[k][..., None] == arange_d
-                        ).astype(jnp.float32)  # [KT, K, Dcap]
-                        oho = (
-                            pre.ov_old_row[k][..., None] == arange_d
-                        ).astype(jnp.float32)
-                        newv = jnp.einsum("rjd,rd->rj", ohn, rows_k, precision=_HI)
-                        oldv = jnp.einsum("rjd,rd->rj", oho, rows_k, precision=_HI)
-                        delta = newv - oldv  # [KT, K]
-                        if dyn_flip:
-                            flip = (
-                                (pre.ov_new_row[k] >= 0)
-                                != (pre.ov_old_row[k] >= 0)
-                            ).astype(jnp.float32)  # [KT, K]
-                            corr = jnp.einsum(
-                                "rj,jn->rn",
-                                jnp.concatenate([delta, flip], axis=0),
-                                at_ov,
-                                precision=_HI,
-                            )  # [2·KT, N]
-                            vals = vals + corr[: st.KT]
-                            gvalid = gvalid != (corr[st.KT :] > 0.5)
-                        else:
-                            # No key-presence changes in the whole batch:
-                            # validity is untouched, only values shift.
-                            corr = jnp.einsum(
-                                "rj,jn->rn", delta, at_ov, precision=_HI
+            with stage("ksim.filter_score"):
+                with jax.named_scope("NodeResourcesFit"):
+                    used1_r = [
+                        carry.used[r] + used_corr_r[r] + s.req[r] for r in range(R)
+                    ]
+                    alloc_r = [dc.allocatable[:, r] for r in range(R)]
+                    # Non-fit filters tracked separately: preemption candidacy
+                    # reuses them with the fit check replaced by fit-after-evict.
+                    feasible = jnp.ones(N, bool)
+                    if spec.fit:
+                        for r in range(R):
+                            feasible = feasible & (used1_r[r] <= alloc_r[r] + 1e-6)
+                    fit_ok = feasible
+                nonfit = jnp.ones(N, bool)
+                if spec.taints:
+                    with jax.named_scope("TaintToleration"):
+                        if st.use_tol_classes:
+                            # Row select by class id — a dynamic slice reads ONE
+                            # [N] row. (The old one-hot einsum contracted the whole
+                            # [C, N] plane per pod: 40% of device time on the
+                            # north-star profile.) Values identical: one-hot × f32
+                            # picked the same row exactly.
+                            tok_k = (
+                                jax.lax.dynamic_index_in_dim(
+                                    cmasks["tol_ok"], sx.tol_class[k], 0, keepdims=False
+                                )
+                                > 0.5
                             )
-                            vals = vals + corr
-
-            if spec.interpod and st.A:
-                cnt = vals[o0:o1]
-                term_ok = (cnt >= 1) & gvalid[o0:o1]
-                boot = (totals[o0:o1] == 0) & pre.aff_selfm[k]
-                valid = (pre.row_g[k, o0:o1] >= 0)[:, None]
-                nonfit = nonfit & jnp.all(
-                    jnp.where(valid, term_ok | boot[:, None], True), axis=0
-                )
-            if spec.interpod and st.B:
-                viol = (vals[o1:o2] >= 1) & gvalid[o1:o2]
-                valid = (pre.row_g[k, o1:o2] >= 0)[:, None]
-                nonfit = nonfit & jnp.all(jnp.where(valid, ~viol, True), axis=0)
-            if spec.interpod and st.MA:
-                blocked = jnp.sum(vals[o4:o5], axis=0) > 0.5
-                nonfit = nonfit & ~blocked
-            if spec.spread and st.SP and st.has_dns:
-                cnts = vals[o2:o3]
-                gval = gvalid[o2:o3]
-                # Min over domains — every existing domain has ≥1 node, so
-                # min over valid domains == min over gvalid nodes. Coarse
-                # rows reduce over [Dcap] (tiny); host rows (domain≈node)
-                # need the node-space min.
-                dval = (
-                    pre.dex_row[k]
-                    if dyn is not None
-                    else (
-                        jnp.arange(Dcap, dtype=jnp.float32)[None, :]
-                        < nd_row[k, o2:o3][:, None]
-                    )
-                )  # [SP, Dcap]
-                minv_dom = jnp.min(
-                    jnp.where(dval, rows_k[o2:o3], jnp.inf), axis=1
-                )
-                if st.has_host_rows:
-                    minv_node = jnp.min(jnp.where(gval, cnts, jnp.inf), axis=1)
-                    minv = jnp.where(
-                        pre.coarse_row[k, o2:o3] > 0.5, minv_dom, minv_node
-                    )
-                else:
-                    minv = minv_dom
-                has = jnp.isfinite(minv)
-                c_ok = (
-                    gval
-                    & has[:, None]
-                    & (cnts + pre.sp_selfm[k][:, None]
-                       - jnp.where(has, minv, 0.0)[:, None]
-                       <= pre.sp_skew[k][:, None])
-                )
-                nonfit = nonfit & jnp.all(
-                    jnp.where(pre.sp_dns[k][:, None], c_ok, True), axis=0
-                )
-
-            feasible = fit_ok & nonfit
-            any_f = None  # derived from the hi reduce when rows exist
-            total = jnp.zeros(N, jnp.float32)
-            if spec.fit and _on("NodeResourcesFit"):
-                rw = np.asarray(spec.resource_weights, dtype=np.float32)
-                if wvec is not None and spec.fit_strategy in (
-                    "LeastAllocated", "MostAllocated"
-                ):
-                    raw = jnp.where(
-                        wvec[T2.IDX_FIT_LEAST] > 0.5,
-                        _fit_score_r(used1_r, alloc_r, rw, "LeastAllocated",
-                                     spec.shape_x, spec.shape_y),
-                        _fit_score_r(used1_r, alloc_r, rw, "MostAllocated",
-                                     spec.shape_x, spec.shape_y),
-                    )
-                else:
-                    raw = _fit_score_r(
-                        used1_r, alloc_r, rw, spec.fit_strategy,
-                        spec.shape_x, spec.shape_y,
-                    )
-                total = total + _w("NodeResourcesFit") * raw
-            rows_n = []
-            if spec.taints and spec.taint_score and _on("TaintToleration"):
-                rows_n.append((traw_k, _w("TaintToleration"), False, True))
-            if spec.node_affinity and _on("NodeAffinity"):
-                rows_n.append((naraw_k, _w("NodeAffinity"), False, False))
-            if spec.interpod and _on("InterPodAffinity"):
-                raw = jnp.zeros(dc.allocatable.shape[0], jnp.float32)
-                if st.PA:
-                    raw = raw + jnp.einsum(
-                        "p,pn->n", pre.row_w[k, o3:o4], vals[o3:o4], precision=_HI
-                    )
-                if st.MP:
-                    raw = raw + jnp.sum(vals[o5:o6], axis=0)
-                rows_n.append((raw, _w("InterPodAffinity"), True, False))
-            sp_pack = None
-            if (
-                spec.spread
-                and _on("PodTopologySpread")
-                and st.SP
-                and not spread_dom_hilo
-            ):
-                # Upstream scoring raw + ignored mask; extrema ride the
-                # shared stacked reduce as an extra ±inf-pre-masked row.
-                cnts = vals[o2:o3]
-                gval = gvalid[o2:o3]
-                raw_sp = jnp.zeros(N, jnp.float32)
-                sp_ign = jnp.zeros(N, bool)
-                for i in range(st.SP):
-                    contrib = cnts[i] * pre.sp_w[k, i] + (
-                        pre.sp_skew[k, i] - 1.0
-                    )
-                    raw_sp = raw_sp + jnp.where(
-                        pre.sp_scored[k, i], contrib, 0.0
-                    )
-                    sp_ign = sp_ign | (pre.sp_scored[k, i] & ~gval[i])
-                sp_pack = (jnp.floor(raw_sp + 0.5), sp_ign)
-            if rows_n or sp_pack is not None:
-                hi_rows = [jnp.where(feasible, r[0], -jnp.inf) for r in rows_n]
-                lo_rows = [jnp.where(feasible, r[0], jnp.inf) for r in rows_n]
-                if sp_pack is not None:
-                    # Spread extrema run over feasible & ~ignored: its row
-                    # is pre-masked with its own validity, then rides the
-                    # same variadic reduce as the other score rows.
-                    okn = feasible & ~sp_pack[1]
-                    hi_rows.append(jnp.where(okn, sp_pack[0], -jnp.inf))
-                    lo_rows.append(jnp.where(okn, sp_pack[0], jnp.inf))
-                hi, lo = _hi_lo_premasked(
-                    jnp.stack(hi_rows), jnp.stack(lo_rows)
-                )
-                # hi > -inf ⟺ some node is feasible: any() comes free.
-                any_f = (
-                    hi[0] > -jnp.inf if rows_n else jnp.any(feasible)
-                )
-                for i, (raw, wt, minmax, reverse) in enumerate(rows_n):
-                    total = total + wt * _normalize_row(
-                        raw, lo[i], hi[i], any_f, minmax, reverse
-                    )
-                if sp_pack is not None:
-                    total = total + _w(
-                        "PodTopologySpread"
-                    ) * T2.spread_norm_from_extrema(
-                        sp_pack[0], sp_pack[1], hi[-1], lo[-1],
-                        jnp.any(pre.sp_scored[k]),
-                        getattr(spec, "sp_norm_f32", False),
-                    )
-            else:
-                any_f = None
-            if (
-                spec.spread
-                and _on("PodTopologySpread")
-                and st.SP
-                and spread_dom_hilo
-            ):
-                # Upstream scoring ([K8S] scoring.go): cnt·log(size+2) +
-                # (maxSkew−1), rounded, two-pass integer normalize.
-                wt = _w("PodTopologySpread")
-                # Domain-space form (SP == 1, coarse row): raw takes one
-                # value per existing domain; label-less nodes are the
-                # ignored set (the extra bucket), excluded from extrema
-                # and normalized to 0.
-                scored0 = pre.sp_scored[k, 0]
-                raw_d = jnp.floor(
-                    rows_k[o2] * pre.sp_w[k, 0] + (pre.sp_skew[k, 0] - 1.0) + 0.5
-                )  # [Dcap] — floor(x+0.5) = upstream math.Round, x ≥ 0
-                dval = (
-                    jnp.arange(Dcap, dtype=jnp.float32) < nd_row[k, o2]
-                )  # existing domains
-                if st.seg_mode:
-                    # Structured layout: per-domain feasibility via ONE
-                    # full-width bitwise-OR reduce of (1 << dom(n)) — a
-                    # lane-efficient [N]→scalar reduce (the reshape-any
-                    # form reduced over the 8-wide minor axis at ~6% lane
-                    # utilization; the one-hot matmul before it was ~12%
-                    # of device time). Exact: for a PAD spread row the
-                    # downstream out_d is masked to 0 by sp_scored either
-                    # way, and any(domfeas) still equals any(feasible) —
-                    # every node carries a domain under the pattern.
-                    if st.seg_D <= 31:
-                        # Bit-pack: per-domain feasibility in int32 bits.
-                        if st.seg_mode == "stride":
-                            dom_i = iota_n % st.seg_D
+                            traw_k = jax.lax.dynamic_index_in_dim(
+                                cmasks["tol_raw"], sx.tol_class[k], 0, keepdims=False
+                            )
                         else:
-                            dom_i = iota_n // (N // st.seg_D)
-                        word = jax.lax.reduce(
-                            jnp.where(
-                                feasible,
-                                jnp.left_shift(np.int32(1), dom_i),
-                                np.int32(0),
-                            ),
-                            np.int32(0),
-                            jax.lax.bitwise_or,
-                            (0,),
-                        )
-                        core = (
-                            jnp.right_shift(word, jnp.arange(st.seg_D)) & 1
-                        ) > 0  # [D]
-                    elif st.seg_mode == "stride":
-                        # 32..Dcap domains: reshape-any (still cheaper
-                        # than the [N, Dcap+1] one-hot einsum).
-                        core = jnp.any(feasible.reshape(-1, st.seg_D), axis=0)
-                    else:
-                        core = jnp.any(feasible.reshape(st.seg_D, -1), axis=1)
-                    domfeas = jnp.concatenate(
-                        [core, jnp.zeros(Dcap + 1 - st.seg_D, bool)]
-                    )
-                else:
-                    domfeas = (
-                        jnp.einsum(
-                            "n,nd->d", feasible.astype(jnp.bfloat16), domoh2[k],
-                            precision=_HI, preferred_element_type=jnp.float32,
-                        )
-                        > 0.5
-                    )  # [Dcap+1]
-                okd = dval & domfeas[:Dcap]
-                hi_sp = jnp.max(jnp.where(okd, raw_d, -jnp.inf))
-                lo_sp = jnp.min(jnp.where(okd, raw_d, jnp.inf))
-                has = hi_sp > -jnp.inf
-                hi_i = jnp.where(has, hi_sp, 0.0).astype(jnp.int32)
-                lo_i = jnp.where(has, lo_sp, 0.0).astype(jnp.int32)
-                vals_d = (
-                    np.int32(T2.MAX_NODE_SCORE)
-                    * (hi_i + lo_i - raw_d.astype(jnp.int32))
-                ) // jnp.where(hi_i > 0, hi_i, 1)
-                out_d = jnp.where(
-                    hi_i > 0,
-                    vals_d.astype(jnp.float32),
-                    np.float32(T2.MAX_NODE_SCORE),
-                )
-                out_d = jnp.where(dval & has & scored0, out_d, 0.0)
-                if st.seg_mode == "stride":
-                    # dom(n) = n % D: the expansion out_d[dom(n)] is a pure
-                    # tile — no [N, D] one-hot read at all (the expansion
-                    # dot was the single largest op after round-3's other
-                    # cuts). PAD spread rows have out_d ≡ 0 → tile of 0.
-                    out = jnp.tile(out_d[: st.seg_D], N // st.seg_D)
-                elif st.seg_mode == "block":
-                    out = jnp.repeat(out_d[: st.seg_D], N // st.seg_D)
-                else:
-                    # out_d holds integer scores in [0, 100] — bf16-exact.
-                    out = jnp.einsum(
-                        "nd,d->n",
-                        domoh2[k][:, :Dcap],
-                        out_d.astype(jnp.bfloat16),
-                        precision=_HI, preferred_element_type=jnp.float32,
-                    )
-                if any_f is None:
-                    any_f = jnp.any(domfeas)
-                total = total + wt * out
-            if any_f is None:
-                any_f = jnp.any(feasible)
+                            tok_k, traw_k = pre.taint_ok[k], pre.taint_raw[k]
+                        nonfit = nonfit & tok_k
+                if spec.node_affinity:
+                    with jax.named_scope("NodeAffinity"):
+                        if st.use_na_classes:
+                            naok_k = (
+                                jax.lax.dynamic_index_in_dim(
+                                    cmasks["na_ok"], sx.na_class[k], 0, keepdims=False
+                                )
+                                > 0.5
+                            )
+                            naraw_k = jax.lax.dynamic_index_in_dim(
+                                cmasks["na_raw"], sx.na_class[k], 0, keepdims=False
+                            )
+                        else:
+                            naok_k, naraw_k = pre.na_ok[k], pre.na_raw[k]
+                        nonfit = nonfit & naok_k
 
-            if pack_select:
-                node, _ = T2.select_node_packed(total, feasible)
-            else:
-                node, _ = select_node(total, feasible)
-            placed = any_f & s.valid
-            if st.preemption:
-                tier_k = sx.tier[k]  # shared scalar
-                if FUSED_PREEMPT:
-                    pk = jax.lax.dynamic_index_in_dim(
-                        pfx_pack, tier_k, axis=0, keepdims=False
-                    )  # [R+2, N] packed lower-tier aggregates (wave start)
-                    lt_u = pk[:R]  # [R, N] usage of tiers < tier_k
-                    lt_np = pk[R]
-                    mt0 = pk[R + 1]
-                else:
-                    lt_u = jax.lax.dynamic_index_in_dim(
-                        pfx_u, tier_k, axis=0, keepdims=False
-                    )  # [R, N] usage of tiers < tier_k (wave start)
-                    lt_np = jax.lax.dynamic_index_in_dim(
-                        pfx_n, tier_k, 0, False
-                    )
-                    mt0 = jax.lax.dynamic_index_in_dim(mts, tier_k, 0, False)
-                lt_u_eff = [lt_u[r] for r in range(R)]
-                lt_np_eff = lt_np
-                mt_eff = mt0
-                for j in range(k):
-                    lowmask = (
-                        placeds[j].astype(jnp.float32)
-                        * (sx.tier[j] < tier_k).astype(jnp.float32)
-                        * (sb.group[j] == PAD).astype(jnp.float32)
-                    )
-                    oh_j = lowmask * (iota_n == choices[j]).astype(jnp.float32)
-                    for r in range(R):
-                        lt_u_eff[r] = lt_u_eff[r] + oh_j * sb.req[j, r]
-                    lt_np_eff = lt_np_eff + oh_j
-                    mt_eff = jnp.maximum(
-                        mt_eff, jnp.where(oh_j > 0, sx.tier[j].astype(jnp.float32), -1.0)
-                    )
-                prefit = jnp.ones(N, bool)
-                for r in range(R):
-                    prefit = prefit & (
-                        used1_r[r] - lt_u_eff[r] <= alloc_r[r] + 1e-6
-                    )
-                cand = (
-                    prefit
-                    & nonfit
-                    & (lt_np_eff >= 1)
-                    & ~preempted
-                    & ~any_f
-                    & s.valid
-                    & (s.group == PAD)
-                    & (tier_k > 0)
-                )
-                # Rank (fewest victims, lowest max victim tier, lowest
-                # index) — exact small ints in f32; mirrors sim.greedy.
-                score = lt_np_eff * np.float32(1024.0) + mt_eff
-                if FUSED_PREEMPT:
-                    # One variadic reduce for (victim node, any candidate)
-                    # — selection identical to the argmax + any pair.
-                    pnode, p_ok = T2.masked_argmin(score, cand)
-                else:
-                    pnode = jnp.argmax(
-                        jnp.where(cand, -score, -jnp.inf)
-                    ).astype(jnp.int32)
-                    p_ok = jnp.any(cand)
-                evict_k = p_ok & ~any_f & s.valid
-                node = jnp.where(evict_k, pnode, node)
-                placed = placed | evict_k
-                oh_p = evict_k.astype(jnp.float32) * (iota_n == node).astype(jnp.float32)
-                for r in range(R):
-                    eu_acc[r] = jnp.where(
-                        evict_k, jnp.sum(lt_u[r] * oh_p), eu_acc[r]
-                    )
-                ev_prior = jnp.where(evict_k, jnp.sum(lt_np * oh_p), ev_prior)
-                ev_total = jnp.where(evict_k, jnp.sum(lt_np_eff * oh_p), ev_total)
-                ev_node = jnp.where(evict_k, node, ev_node)
-                ev_tier = jnp.where(evict_k, tier_k, ev_tier)
-                preempted = preempted | evict_k
-                # Mark lower-tier non-gang slots already bound there evicted.
-                for j in range(k):
-                    evicted[j] = evicted[j] | (
-                        evict_k
-                        & (choices[j] == node)
-                        & placeds[j]
-                        & (sx.tier[j] < tier_k)
-                        & (sb.group[j] == PAD)
-                    )
-                evicted.append(jnp.zeros((), bool))
-            if maintain_dom:
-                if st.single_topo and dyn is None:
-                    # Every domain-bearing group shares ONE topology: the
-                    # bound node's domain is a single dynamic read of the
-                    # shared [N] map, broadcast over groups — instead of an
-                    # einsum streaming the whole [G, N] table per pod.
-                    dom1 = jax.lax.dynamic_index_in_dim(
-                        sh.topo1_f, jnp.clip(node, 0), 0, keepdims=False
-                    )
-                    dom_at = jnp.where(
-                        placed & (sh.has_dom_g > 0.5), dom1, float(PAD)
-                    )
-                else:
-                    oh_n = ((iota_n == node) & (node >= 0)).astype(jnp.float32)
-                    dom_at = jnp.einsum("gn,n->g", sh.gdom_f, oh_n, precision=_HI)
-                    for j in range(Kdyn):
-                        # Perturbed node bound: its per-group domain is the
-                        # override (== base where that topology unchanged).
-                        dom_at = jnp.where(
-                            node == dyn.ov_nodes[j], dyn.ov_gdom[:, j], dom_at
+                # Materialize `feasible` once: it feeds several reduce-rooted
+                # kernels (domfeas, select). On jax 0.9 optimization_barrier
+                # has a batching rule, so the barrier also stays in the vmapped
+                # what-if program (earlier rounds dropped it there through a
+                # shim and timed that program); whether it should stay under
+                # vmap is a measured question for a later PR.
+                # used1_r stays UN-materialized since
+                # round 3 — its two consumers (the feasible fusion and the
+                # select reduce's fit score) each re-derive it from carry.used
+                # at the same read cost, and skipping the barrier removes the
+                # R×[S, N] write per pod (~14% of device time on the profile).
+                # Preemption still materializes (prefit re-reads used1_r).
+                if st.preemption:
+                    used1_r = list(jax.lax.optimization_barrier(tuple(used1_r)))
+                feasible = jax.lax.optimization_barrier(feasible)
+                if st.KT:
+                    rows_k = rows0[k] + rows_corr  # [KT, Dcap]
+                    totals = totals0[k] + tot_corr
+                    if need_vals:
+                        vals = _expand_rows(rows_k, dom_oh[k])
+                        if st.has_host_rows:
+                            vals = vals + vals_h0[k] + valh_corr
+                        gvalid = pre.dmap[k] >= 0  # [KT, N]
+                        if Kdyn:
+                            # labels_dirty: corrections on top of the BASE
+                            # expansion — for each perturbed node, swap in
+                            # rows_k at its new domain and its new validity.
+                            # PAD ids give all-zero one-hots. ONE [2KT, K] ×
+                            # [K, N] matmul carries both the value deltas and
+                            # the validity flips (a per-j Python loop fused
+                            # badly: 1.8× on the config-3 dirty batch).
+                            arange_d = jnp.arange(Dcap, dtype=jnp.float32)
+                            ohn = (
+                                pre.ov_new_row[k][..., None] == arange_d
+                            ).astype(jnp.float32)  # [KT, K, Dcap]
+                            oho = (
+                                pre.ov_old_row[k][..., None] == arange_d
+                            ).astype(jnp.float32)
+                            newv = jnp.einsum("rjd,rd->rj", ohn, rows_k, precision=_HI)
+                            oldv = jnp.einsum("rjd,rd->rj", oho, rows_k, precision=_HI)
+                            delta = newv - oldv  # [KT, K]
+                            if dyn_flip:
+                                flip = (
+                                    (pre.ov_new_row[k] >= 0)
+                                    != (pre.ov_old_row[k] >= 0)
+                                ).astype(jnp.float32)  # [KT, K]
+                                corr = jnp.einsum(
+                                    "rj,jn->rn",
+                                    jnp.concatenate([delta, flip], axis=0),
+                                    at_ov,
+                                    precision=_HI,
+                                )  # [2·KT, N]
+                                vals = vals + corr[: st.KT]
+                                gvalid = gvalid != (corr[st.KT :] > 0.5)
+                            else:
+                                # No key-presence changes in the whole batch:
+                                # validity is untouched, only values shift.
+                                corr = jnp.einsum(
+                                    "rj,jn->rn", delta, at_ov, precision=_HI
+                                )
+                                vals = vals + corr
+
+                with jax.named_scope("InterPodAffinity"):
+                    if spec.interpod and st.A:
+                        cnt = vals[o0:o1]
+                        term_ok = (cnt >= 1) & gvalid[o0:o1]
+                        boot = (totals[o0:o1] == 0) & pre.aff_selfm[k]
+                        valid = (pre.row_g[k, o0:o1] >= 0)[:, None]
+                        nonfit = nonfit & jnp.all(
+                            jnp.where(valid, term_ok | boot[:, None], True), axis=0
                         )
-                    # A miss (or padded slot) must not look like domain 0.
-                    dom_at = jnp.where(placed, dom_at, float(PAD))
-                dom_ats.append(dom_at)
+                    if spec.interpod and st.B:
+                        viol = (vals[o1:o2] >= 1) & gvalid[o1:o2]
+                        valid = (pre.row_g[k, o1:o2] >= 0)[:, None]
+                        nonfit = nonfit & jnp.all(jnp.where(valid, ~viol, True), axis=0)
+                    if spec.interpod and st.MA:
+                        blocked = jnp.sum(vals[o4:o5], axis=0) > 0.5
+                        nonfit = nonfit & ~blocked
+                if spec.spread and st.SP and st.has_dns:
+                    with jax.named_scope("PodTopologySpread"):
+                        cnts = vals[o2:o3]
+                        gval = gvalid[o2:o3]
+                        # Min over domains — every existing domain has ≥1 node, so
+                        # min over valid domains == min over gvalid nodes. Coarse
+                        # rows reduce over [Dcap] (tiny); host rows (domain≈node)
+                        # need the node-space min.
+                        dval = (
+                            pre.dex_row[k]
+                            if dyn is not None
+                            else (
+                                jnp.arange(Dcap, dtype=jnp.float32)[None, :]
+                                < nd_row[k, o2:o3][:, None]
+                            )
+                        )  # [SP, Dcap]
+                        minv_dom = jnp.min(
+                            jnp.where(dval, rows_k[o2:o3], jnp.inf), axis=1
+                        )
+                        if st.has_host_rows:
+                            minv_node = jnp.min(jnp.where(gval, cnts, jnp.inf), axis=1)
+                            minv = jnp.where(
+                                pre.coarse_row[k, o2:o3] > 0.5, minv_dom, minv_node
+                            )
+                        else:
+                            minv = minv_dom
+                        has = jnp.isfinite(minv)
+                        c_ok = (
+                            gval
+                            & has[:, None]
+                            & (cnts + pre.sp_selfm[k][:, None]
+                               - jnp.where(has, minv, 0.0)[:, None]
+                               <= pre.sp_skew[k][:, None])
+                        )
+                        nonfit = nonfit & jnp.all(
+                            jnp.where(pre.sp_dns[k][:, None], c_ok, True), axis=0
+                        )
+
+                feasible = fit_ok & nonfit
+                any_f = None  # derived from the hi reduce when rows exist
+                total = jnp.zeros(N, jnp.float32)
+                if spec.fit and _on("NodeResourcesFit"):
+                    with jax.named_scope("NodeResourcesFit"):
+                        rw = np.asarray(spec.resource_weights, dtype=np.float32)
+                        if wvec is not None and spec.fit_strategy in (
+                            "LeastAllocated", "MostAllocated"
+                        ):
+                            raw = jnp.where(
+                                wvec[T2.IDX_FIT_LEAST] > 0.5,
+                                _fit_score_r(used1_r, alloc_r, rw, "LeastAllocated",
+                                             spec.shape_x, spec.shape_y),
+                                _fit_score_r(used1_r, alloc_r, rw, "MostAllocated",
+                                             spec.shape_x, spec.shape_y),
+                            )
+                        else:
+                            raw = _fit_score_r(
+                                used1_r, alloc_r, rw, spec.fit_strategy,
+                                spec.shape_x, spec.shape_y,
+                            )
+                        total = total + _w("NodeResourcesFit") * raw
+                rows_n = []
+                if spec.taints and spec.taint_score and _on("TaintToleration"):
+                    rows_n.append((traw_k, _w("TaintToleration"), False, True))
+                if spec.node_affinity and _on("NodeAffinity"):
+                    rows_n.append((naraw_k, _w("NodeAffinity"), False, False))
+                if spec.interpod and _on("InterPodAffinity"):
+                    with jax.named_scope("InterPodAffinity"):
+                        raw = jnp.zeros(dc.allocatable.shape[0], jnp.float32)
+                        if st.PA:
+                            raw = raw + jnp.einsum(
+                                "p,pn->n", pre.row_w[k, o3:o4], vals[o3:o4], precision=_HI
+                            )
+                        if st.MP:
+                            raw = raw + jnp.sum(vals[o5:o6], axis=0)
+                        rows_n.append((raw, _w("InterPodAffinity"), True, False))
+                sp_pack = None
+                if (
+                    spec.spread
+                    and _on("PodTopologySpread")
+                    and st.SP
+                    and not spread_dom_hilo
+                ):
+                    with jax.named_scope("PodTopologySpread"):
+                        # Upstream scoring raw + ignored mask; extrema ride the
+                        # shared stacked reduce as an extra ±inf-pre-masked row.
+                        cnts = vals[o2:o3]
+                        gval = gvalid[o2:o3]
+                        raw_sp = jnp.zeros(N, jnp.float32)
+                        sp_ign = jnp.zeros(N, bool)
+                        for i in range(st.SP):
+                            contrib = cnts[i] * pre.sp_w[k, i] + (
+                                pre.sp_skew[k, i] - 1.0
+                            )
+                            raw_sp = raw_sp + jnp.where(
+                                pre.sp_scored[k, i], contrib, 0.0
+                            )
+                            sp_ign = sp_ign | (pre.sp_scored[k, i] & ~gval[i])
+                        sp_pack = (jnp.floor(raw_sp + 0.5), sp_ign)
+                if rows_n or sp_pack is not None:
+                    hi_rows = [jnp.where(feasible, r[0], -jnp.inf) for r in rows_n]
+                    lo_rows = [jnp.where(feasible, r[0], jnp.inf) for r in rows_n]
+                    if sp_pack is not None:
+                        # Spread extrema run over feasible & ~ignored: its row
+                        # is pre-masked with its own validity, then rides the
+                        # same variadic reduce as the other score rows.
+                        okn = feasible & ~sp_pack[1]
+                        hi_rows.append(jnp.where(okn, sp_pack[0], -jnp.inf))
+                        lo_rows.append(jnp.where(okn, sp_pack[0], jnp.inf))
+                    hi, lo = _hi_lo_premasked(
+                        jnp.stack(hi_rows), jnp.stack(lo_rows)
+                    )
+                    # hi > -inf ⟺ some node is feasible: any() comes free.
+                    any_f = (
+                        hi[0] > -jnp.inf if rows_n else jnp.any(feasible)
+                    )
+                    for i, (raw, wt, minmax, reverse) in enumerate(rows_n):
+                        total = total + wt * _normalize_row(
+                            raw, lo[i], hi[i], any_f, minmax, reverse
+                        )
+                    if sp_pack is not None:
+                        total = total + _w(
+                            "PodTopologySpread"
+                        ) * T2.spread_norm_from_extrema(
+                            sp_pack[0], sp_pack[1], hi[-1], lo[-1],
+                            jnp.any(pre.sp_scored[k]),
+                            getattr(spec, "sp_norm_f32", False),
+                        )
+                else:
+                    any_f = None
+                if (
+                    spec.spread
+                    and _on("PodTopologySpread")
+                    and st.SP
+                    and spread_dom_hilo
+                ):
+                    with jax.named_scope("PodTopologySpread"):
+                        # Upstream scoring ([K8S] scoring.go): cnt·log(size+2) +
+                        # (maxSkew−1), rounded, two-pass integer normalize.
+                        wt = _w("PodTopologySpread")
+                        # Domain-space form (SP == 1, coarse row): raw takes one
+                        # value per existing domain; label-less nodes are the
+                        # ignored set (the extra bucket), excluded from extrema
+                        # and normalized to 0.
+                        scored0 = pre.sp_scored[k, 0]
+                        raw_d = jnp.floor(
+                            rows_k[o2] * pre.sp_w[k, 0] + (pre.sp_skew[k, 0] - 1.0) + 0.5
+                        )  # [Dcap] — floor(x+0.5) = upstream math.Round, x ≥ 0
+                        dval = (
+                            jnp.arange(Dcap, dtype=jnp.float32) < nd_row[k, o2]
+                        )  # existing domains
+                        if st.seg_mode:
+                            # Structured layout: per-domain feasibility via ONE
+                            # full-width bitwise-OR reduce of (1 << dom(n)) — a
+                            # lane-efficient [N]→scalar reduce (the reshape-any
+                            # form reduced over the 8-wide minor axis at ~6% lane
+                            # utilization; the one-hot matmul before it was ~12%
+                            # of device time). Exact: for a PAD spread row the
+                            # downstream out_d is masked to 0 by sp_scored either
+                            # way, and any(domfeas) still equals any(feasible) —
+                            # every node carries a domain under the pattern.
+                            if st.seg_D <= 31:
+                                # Bit-pack: per-domain feasibility in int32 bits.
+                                if st.seg_mode == "stride":
+                                    dom_i = iota_n % st.seg_D
+                                else:
+                                    dom_i = iota_n // (N // st.seg_D)
+                                word = jax.lax.reduce(
+                                    jnp.where(
+                                        feasible,
+                                        jnp.left_shift(np.int32(1), dom_i),
+                                        np.int32(0),
+                                    ),
+                                    np.int32(0),
+                                    jax.lax.bitwise_or,
+                                    (0,),
+                                )
+                                core = (
+                                    jnp.right_shift(word, jnp.arange(st.seg_D)) & 1
+                                ) > 0  # [D]
+                            elif st.seg_mode == "stride":
+                                # 32..Dcap domains: reshape-any (still cheaper
+                                # than the [N, Dcap+1] one-hot einsum).
+                                core = jnp.any(feasible.reshape(-1, st.seg_D), axis=0)
+                            else:
+                                core = jnp.any(feasible.reshape(st.seg_D, -1), axis=1)
+                            domfeas = jnp.concatenate(
+                                [core, jnp.zeros(Dcap + 1 - st.seg_D, bool)]
+                            )
+                        else:
+                            domfeas = (
+                                jnp.einsum(
+                                    "n,nd->d", feasible.astype(jnp.bfloat16), domoh2[k],
+                                    precision=_HI, preferred_element_type=jnp.float32,
+                                )
+                                > 0.5
+                            )  # [Dcap+1]
+                        okd = dval & domfeas[:Dcap]
+                        hi_sp = jnp.max(jnp.where(okd, raw_d, -jnp.inf))
+                        lo_sp = jnp.min(jnp.where(okd, raw_d, jnp.inf))
+                        has = hi_sp > -jnp.inf
+                        hi_i = jnp.where(has, hi_sp, 0.0).astype(jnp.int32)
+                        lo_i = jnp.where(has, lo_sp, 0.0).astype(jnp.int32)
+                        vals_d = (
+                            np.int32(T2.MAX_NODE_SCORE)
+                            * (hi_i + lo_i - raw_d.astype(jnp.int32))
+                        ) // jnp.where(hi_i > 0, hi_i, 1)
+                        out_d = jnp.where(
+                            hi_i > 0,
+                            vals_d.astype(jnp.float32),
+                            np.float32(T2.MAX_NODE_SCORE),
+                        )
+                        out_d = jnp.where(dval & has & scored0, out_d, 0.0)
+                        if st.seg_mode == "stride":
+                            # dom(n) = n % D: the expansion out_d[dom(n)] is a pure
+                            # tile — no [N, D] one-hot read at all (the expansion
+                            # dot was the single largest op after round-3's other
+                            # cuts). PAD spread rows have out_d ≡ 0 → tile of 0.
+                            out = jnp.tile(out_d[: st.seg_D], N // st.seg_D)
+                        elif st.seg_mode == "block":
+                            out = jnp.repeat(out_d[: st.seg_D], N // st.seg_D)
+                        else:
+                            # out_d holds integer scores in [0, 100] — bf16-exact.
+                            out = jnp.einsum(
+                                "nd,d->n",
+                                domoh2[k][:, :Dcap],
+                                out_d.astype(jnp.bfloat16),
+                                precision=_HI, preferred_element_type=jnp.float32,
+                            )
+                        if any_f is None:
+                            any_f = jnp.any(domfeas)
+                        total = total + wt * out
+                if any_f is None:
+                    any_f = jnp.any(feasible)
+
+            with stage("ksim.select"):
+                if pack_select:
+                    node, _ = T2.select_node_packed(total, feasible)
+                else:
+                    node, _ = select_node(total, feasible)
+                placed = any_f & s.valid
+            if st.preemption:
+                with stage("ksim.preempt"):
+                    tier_k = sx.tier[k]  # shared scalar
+                    if FUSED_PREEMPT:
+                        pk = jax.lax.dynamic_index_in_dim(
+                            pfx_pack, tier_k, axis=0, keepdims=False
+                        )  # [R+2, N] packed lower-tier aggregates (wave start)
+                        lt_u = pk[:R]  # [R, N] usage of tiers < tier_k
+                        lt_np = pk[R]
+                        mt0 = pk[R + 1]
+                    else:
+                        lt_u = jax.lax.dynamic_index_in_dim(
+                            pfx_u, tier_k, axis=0, keepdims=False
+                        )  # [R, N] usage of tiers < tier_k (wave start)
+                        lt_np = jax.lax.dynamic_index_in_dim(
+                            pfx_n, tier_k, 0, False
+                        )
+                        mt0 = jax.lax.dynamic_index_in_dim(mts, tier_k, 0, False)
+                    lt_u_eff = [lt_u[r] for r in range(R)]
+                    lt_np_eff = lt_np
+                    mt_eff = mt0
+                    for j in range(k):
+                        lowmask = (
+                            placeds[j].astype(jnp.float32)
+                            * (sx.tier[j] < tier_k).astype(jnp.float32)
+                            * (sb.group[j] == PAD).astype(jnp.float32)
+                        )
+                        oh_j = lowmask * (iota_n == choices[j]).astype(jnp.float32)
+                        for r in range(R):
+                            lt_u_eff[r] = lt_u_eff[r] + oh_j * sb.req[j, r]
+                        lt_np_eff = lt_np_eff + oh_j
+                        mt_eff = jnp.maximum(
+                            mt_eff, jnp.where(oh_j > 0, sx.tier[j].astype(jnp.float32), -1.0)
+                        )
+                    prefit = jnp.ones(N, bool)
+                    for r in range(R):
+                        prefit = prefit & (
+                            used1_r[r] - lt_u_eff[r] <= alloc_r[r] + 1e-6
+                        )
+                    cand = (
+                        prefit
+                        & nonfit
+                        & (lt_np_eff >= 1)
+                        & ~preempted
+                        & ~any_f
+                        & s.valid
+                        & (s.group == PAD)
+                        & (tier_k > 0)
+                    )
+                    # Rank (fewest victims, lowest max victim tier, lowest
+                    # index) — exact small ints in f32; mirrors sim.greedy.
+                    score = lt_np_eff * np.float32(1024.0) + mt_eff
+                    if FUSED_PREEMPT:
+                        # One variadic reduce for (victim node, any candidate)
+                        # — selection identical to the argmax + any pair.
+                        pnode, p_ok = T2.masked_argmin(score, cand)
+                    else:
+                        pnode = jnp.argmax(
+                            jnp.where(cand, -score, -jnp.inf)
+                        ).astype(jnp.int32)
+                        p_ok = jnp.any(cand)
+                    evict_k = p_ok & ~any_f & s.valid
+                    node = jnp.where(evict_k, pnode, node)
+                    placed = placed | evict_k
+                    oh_p = evict_k.astype(jnp.float32) * (iota_n == node).astype(jnp.float32)
+                    for r in range(R):
+                        eu_acc[r] = jnp.where(
+                            evict_k, jnp.sum(lt_u[r] * oh_p), eu_acc[r]
+                        )
+                    ev_prior = jnp.where(evict_k, jnp.sum(lt_np * oh_p), ev_prior)
+                    ev_total = jnp.where(evict_k, jnp.sum(lt_np_eff * oh_p), ev_total)
+                    ev_node = jnp.where(evict_k, node, ev_node)
+                    ev_tier = jnp.where(evict_k, tier_k, ev_tier)
+                    preempted = preempted | evict_k
+                    # Mark lower-tier non-gang slots already bound there evicted.
+                    for j in range(k):
+                        evicted[j] = evicted[j] | (
+                            evict_k
+                            & (choices[j] == node)
+                            & placeds[j]
+                            & (sx.tier[j] < tier_k)
+                            & (sb.group[j] == PAD)
+                        )
+                    evicted.append(jnp.zeros((), bool))
+            with stage("ksim.commit"):
+                if maintain_dom:
+                    if st.single_topo and dyn is None:
+                        # Every domain-bearing group shares ONE topology: the
+                        # bound node's domain is a single dynamic read of the
+                        # shared [N] map, broadcast over groups — instead of an
+                        # einsum streaming the whole [G, N] table per pod.
+                        dom1 = jax.lax.dynamic_index_in_dim(
+                            sh.topo1_f, jnp.clip(node, 0), 0, keepdims=False
+                        )
+                        dom_at = jnp.where(
+                            placed & (sh.has_dom_g > 0.5), dom1, float(PAD)
+                        )
+                    else:
+                        oh_n = ((iota_n == node) & (node >= 0)).astype(jnp.float32)
+                        dom_at = jnp.einsum("gn,n->g", sh.gdom_f, oh_n, precision=_HI)
+                        for j in range(Kdyn):
+                            # Perturbed node bound: its per-group domain is the
+                            # override (== base where that topology unchanged).
+                            dom_at = jnp.where(
+                                node == dyn.ov_nodes[j], dyn.ov_gdom[:, j], dom_at
+                            )
+                        # A miss (or padded slot) must not look like domain 0.
+                        dom_at = jnp.where(placed, dom_at, float(PAD))
+                    dom_ats.append(dom_at)
             choices.append(node)
             placeds.append(placed)
 
-        choice = jnp.stack(choices)  # [W]
-        placed = jnp.stack(placeds)  # [W]
-        if st.has_gangs:
-            groups = sb.group
-            same = (groups[:, None] == groups[None, :]) & (groups[:, None] >= 0)
-            fail = jnp.any(same & ~placed[None, :], axis=1)
-            commit = placed & ~fail
-        else:
-            commit = placed
-        if st.preemption:
-            evicted_w = jnp.stack(evicted)  # [W]
-            # Phantom rule: counts commit for evicted slots too; usage and
-            # the reported placement do not.
-            commit_used = commit & ~evicted_w
-        else:
-            commit_used = commit
-        final = jnp.where(commit_used, choice, PAD).astype(jnp.int32)
+        with stage("ksim.commit"):
+            choice = jnp.stack(choices)  # [W]
+            placed = jnp.stack(placeds)  # [W]
+            if st.has_gangs:
+                groups = sb.group
+                same = (groups[:, None] == groups[None, :]) & (groups[:, None] >= 0)
+                fail = jnp.any(same & ~placed[None, :], axis=1)
+                commit = placed & ~fail
+            else:
+                commit = placed
+            if st.preemption:
+                evicted_w = jnp.stack(evicted)  # [W]
+                # Phantom rule: counts commit for evicted slots too; usage and
+                # the reported placement do not.
+                commit_used = commit & ~evicted_w
+            else:
+                commit_used = commit
+            final = jnp.where(commit_used, choice, PAD).astype(jnp.int32)
 
-        # --- wave-end commit (gang rollback folded into the mask) --------
-        wv = commit.astype(jnp.float32)  # [W]
-        wv_used = commit_used.astype(jnp.float32)  # [W]
-        # One-hots rebuilt from chosen-node indices, bf16 operands: exact
-        # (0/1 values), half the einsum traffic of stacked f32 planes. Only
-        # the host-plane / tier commits still consume them — the `used`
-        # update itself is an unrolled elementwise add since round 3 (the
-        # [W, N]×[W, R] dot emitted layout copies around the carry that
-        # cost more than the dot; same f32 sum of the same multiset).
-        need_oh_all = st.preemption or st.has_host_rows
-        if need_oh_all:
-            oh_all = (
-                (iota_n[None, :] == choice[:, None]) & (choice[:, None] >= 0)
-            ).astype(jnp.bfloat16)  # [W, N]
-        if st.preemption:
-            used = carry.used + jnp.einsum(
-                "w,wn,wr->rn", wv_used, oh_all, sb.req,
-                precision=_HI, preferred_element_type=jnp.float32,
-            )
-        else:
-            coefs = wv_used[:, None] * sb.req  # [W, R] tiny
-            rows_u = []
-            for r in range(R):
-                acc = carry.used[r]
-                for w in range(wave_width):
-                    acc = acc + jnp.where(
-                        iota_n == choice[w], coefs[w, r], 0.0
+            # --- wave-end commit (gang rollback folded into the mask) --------
+            wv = commit.astype(jnp.float32)  # [W]
+            wv_used = commit_used.astype(jnp.float32)  # [W]
+            # One-hots rebuilt from chosen-node indices, bf16 operands: exact
+            # (0/1 values), half the einsum traffic of stacked f32 planes. Only
+            # the host-plane / tier commits still consume them — the `used`
+            # update itself is an unrolled elementwise add since round 3 (the
+            # [W, N]×[W, R] dot emitted layout copies around the carry that
+            # cost more than the dot; same f32 sum of the same multiset).
+            need_oh_all = st.preemption or st.has_host_rows
+            if need_oh_all:
+                oh_all = (
+                    (iota_n[None, :] == choice[:, None]) & (choice[:, None] >= 0)
+                ).astype(jnp.bfloat16)  # [W, N]
+            if st.preemption:
+                used = carry.used + jnp.einsum(
+                    "w,wn,wr->rn", wv_used, oh_all, sb.req,
+                    precision=_HI, preferred_element_type=jnp.float32,
+                )
+            else:
+                coefs = wv_used[:, None] * sb.req  # [W, R] tiny
+                rows_u = []
+                for r in range(R):
+                    acc = carry.used[r]
+                    for w in range(wave_width):
+                        acc = acc + jnp.where(
+                            iota_n == choice[w], coefs[w, r], 0.0
+                        )
+                    rows_u.append(acc)
+                used = jnp.stack(rows_u)
+            used_tier, npods_tier = carry.used_tier, carry.npods_tier
+            if st.preemption:
+                # Eviction: free the wave-start lower-tier usage at the node.
+                oh_e = (
+                    preempted.astype(jnp.float32)
+                    * (iota_n == ev_node).astype(jnp.float32)
+                )  # [N]
+                used = used - jnp.stack([eu_acc[r] * oh_e for r in range(R)])
+                nong = (sb.group == PAD).astype(jnp.float32)  # [W]
+                tiers_w = sx.tier  # [W] shared
+                if st.Tt and FUSED_PREEMPT:
+                    # Batched tier commit: one [Tt, W] slot-weight one-hot and
+                    # two einsums replace the per-tier Python loop (Tt× fewer
+                    # passes over the [W, N] placement one-hot). Each
+                    # (t, ·, n) output still reduces the SAME summands over w
+                    # — bit-parity with the loop form.
+                    wt_all = (
+                        wv_used[None, :]
+                        * nong[None, :]
+                        * (
+                            tiers_w[None, :] == jnp.arange(st.Tt)[:, None]
+                        ).astype(jnp.float32)
+                    )  # [Tt, W]
+                    du_all = jnp.einsum(
+                        "tw,wn,wr->trn", wt_all, oh_all, sb.req,
+                        precision=_HI, preferred_element_type=jnp.float32,
                     )
-                rows_u.append(acc)
-            used = jnp.stack(rows_u)
-        used_tier, npods_tier = carry.used_tier, carry.npods_tier
-        if st.preemption:
-            # Eviction: free the wave-start lower-tier usage at the node.
-            oh_e = (
-                preempted.astype(jnp.float32)
-                * (iota_n == ev_node).astype(jnp.float32)
-            )  # [N]
-            used = used - jnp.stack([eu_acc[r] * oh_e for r in range(R)])
-            nong = (sb.group == PAD).astype(jnp.float32)  # [W]
-            tiers_w = sx.tier  # [W] shared
-            if st.Tt and FUSED_PREEMPT:
-                # Batched tier commit: one [Tt, W] slot-weight one-hot and
-                # two einsums replace the per-tier Python loop (Tt× fewer
-                # passes over the [W, N] placement one-hot). Each
-                # (t, ·, n) output still reduces the SAME summands over w
-                # — bit-parity with the loop form.
-                wt_all = (
-                    wv_used[None, :]
-                    * nong[None, :]
-                    * (
-                        tiers_w[None, :] == jnp.arange(st.Tt)[:, None]
-                    ).astype(jnp.float32)
-                )  # [Tt, W]
-                du_all = jnp.einsum(
-                    "tw,wn,wr->trn", wt_all, oh_all, sb.req,
-                    precision=_HI, preferred_element_type=jnp.float32,
-                )
-                dn_all = jnp.einsum(
-                    "tw,wn->tn", wt_all, oh_all,
-                    precision=_HI, preferred_element_type=jnp.float32,
-                )
-                zmask_all = (
-                    preempted & (jnp.arange(st.Tt) < ev_tier)
-                ).astype(jnp.float32)[:, None] * (
-                    iota_n == ev_node
-                ).astype(jnp.float32)[None, :]  # [Tt, N]
-                used_tier = (
-                    carry.used_tier * (1.0 - zmask_all)[:, None, :] + du_all
-                )
-                npods_tier = carry.npods_tier * (1.0 - zmask_all) + dn_all
-            elif st.Tt:
-                new_ut, new_np = [], []
-                for t in range(st.Tt):
-                    zmask = (
-                        preempted & (jnp.asarray(t) < ev_tier)
-                    ).astype(jnp.float32) * (
+                    dn_all = jnp.einsum(
+                        "tw,wn->tn", wt_all, oh_all,
+                        precision=_HI, preferred_element_type=jnp.float32,
+                    )
+                    zmask_all = (
+                        preempted & (jnp.arange(st.Tt) < ev_tier)
+                    ).astype(jnp.float32)[:, None] * (
                         iota_n == ev_node
+                    ).astype(jnp.float32)[None, :]  # [Tt, N]
+                    used_tier = (
+                        carry.used_tier * (1.0 - zmask_all)[:, None, :] + du_all
+                    )
+                    npods_tier = carry.npods_tier * (1.0 - zmask_all) + dn_all
+                elif st.Tt:
+                    new_ut, new_np = [], []
+                    for t in range(st.Tt):
+                        zmask = (
+                            preempted & (jnp.asarray(t) < ev_tier)
+                        ).astype(jnp.float32) * (
+                            iota_n == ev_node
+                        ).astype(jnp.float32)
+                        w_t = wv_used * nong * (tiers_w == t).astype(jnp.float32)
+                        du = jnp.einsum(
+                            "w,wn,wr->rn", w_t, oh_all, sb.req,
+                            precision=_HI, preferred_element_type=jnp.float32,
+                        )
+                        dn = jnp.einsum(
+                            "w,wn->n", w_t, oh_all,
+                            precision=_HI, preferred_element_type=jnp.float32,
+                        )
+                        new_ut.append(
+                            carry.used_tier[t] * (1.0 - zmask)[None, :] + du
+                        )
+                        new_np.append(carry.npods_tier[t] * (1.0 - zmask) + dn)
+                    used_tier = jnp.stack(new_ut)
+                    npods_tier = jnp.stack(new_np)
+            mc_dom, anti_dom, pref_dom = carry.mc_dom, carry.anti_dom, carry.pref_dom
+            mc_host, anti_host, pref_host = carry.mc_host, carry.anti_host, carry.pref_host
+            match_total = carry.match_total
+            if maintain_dom:
+                dom_all = jnp.stack(dom_ats)  # [W, G]
+                oh_dom = (
+                    dom_all[:, :, None] == jnp.arange(Dcap, dtype=jnp.float32)
+                ).astype(jnp.float32)  # [W, G, Dcap]
+                cf = sh.coarse_f[None, :]
+
+                def dom_commit(plane, vec):
+                    return plane + jnp.einsum(
+                        "w,wg,wgd->gd", wv, vec * cf, oh_dom, precision=_HI
+                    )
+
+                if st.maintain_mc:
+                    mc_dom = dom_commit(carry.mc_dom, pre.pmg_f)
+                if st.maintain_anti:
+                    anti_dom = dom_commit(carry.anti_dom, pre.anti_g)
+                if st.maintain_pref:
+                    pref_dom = dom_commit(carry.pref_dom, pre.pref_g)
+                if st.A:
+                    has_dom = (dom_all >= 0).astype(jnp.float32)  # [W, G]
+                    match_total = carry.match_total + jnp.einsum(
+                        "w,wg->g", wv, pre.pmg_f * has_dom, precision=_HI
+                    )
+
+            def host_commit(plane, vec, ids):
+                vh = vec[:, jnp.asarray(ids)]  # [W, H]
+                if st.single_g[ids].all():
+                    # Singleton domains (hostname): the bound node IS the domain
+                    # — but only when it actually carries the topology label
+                    # (v2's node_has_dom gate; a partially-labeled topology must
+                    # not credit label-less nodes).
+                    has_dom_h = (
+                        jnp.stack(dom_ats)[:, jnp.asarray(ids)] >= 0
+                    ).astype(jnp.float32)  # [W, H]
+                    delta = jnp.einsum(
+                        "w,wh,wn->hn", wv, vh * has_dom_h, oh_all,
+                        precision=_HI, preferred_element_type=jnp.float32,
+                    )
+                    # Cast back to the carry dtype: bf16 planes hold small
+                    # integers, exact through the add.
+                    return (plane.astype(jnp.float32) + delta).astype(plane.dtype)
+                # General path: credit every node in the bound node's domain.
+                gdom_h = sh.gdom_f[jnp.asarray(ids)]  # [H, N] (static row select)
+                dom_at_h = jnp.stack(dom_ats)[:, jnp.asarray(ids)]  # [W, H]
+                for w in range(wave_width):
+                    sel = (
+                        (gdom_h == dom_at_h[w][:, None]) & (dom_at_h[w] >= 0)[:, None]
                     ).astype(jnp.float32)
-                    w_t = wv_used * nong * (tiers_w == t).astype(jnp.float32)
-                    du = jnp.einsum(
-                        "w,wn,wr->rn", w_t, oh_all, sb.req,
-                        precision=_HI, preferred_element_type=jnp.float32,
-                    )
-                    dn = jnp.einsum(
-                        "w,wn->n", w_t, oh_all,
-                        precision=_HI, preferred_element_type=jnp.float32,
-                    )
-                    new_ut.append(
-                        carry.used_tier[t] * (1.0 - zmask)[None, :] + du
-                    )
-                    new_np.append(carry.npods_tier[t] * (1.0 - zmask) + dn)
-                used_tier = jnp.stack(new_ut)
-                npods_tier = jnp.stack(new_np)
-        mc_dom, anti_dom, pref_dom = carry.mc_dom, carry.anti_dom, carry.pref_dom
-        mc_host, anti_host, pref_host = carry.mc_host, carry.anti_host, carry.pref_host
-        match_total = carry.match_total
-        if maintain_dom:
-            dom_all = jnp.stack(dom_ats)  # [W, G]
-            oh_dom = (
-                dom_all[:, :, None] == jnp.arange(Dcap, dtype=jnp.float32)
-            ).astype(jnp.float32)  # [W, G, Dcap]
-            cf = sh.coarse_f[None, :]
+                    plane = plane + (wv[w] * vh[w])[:, None] * sel
+                return plane
 
-            def dom_commit(plane, vec):
-                return plane + jnp.einsum(
-                    "w,wg,wgd->gd", wv, vec * cf, oh_dom, precision=_HI
-                )
-
-            if st.maintain_mc:
-                mc_dom = dom_commit(carry.mc_dom, pre.pmg_f)
-            if st.maintain_anti:
-                anti_dom = dom_commit(carry.anti_dom, pre.anti_g)
-            if st.maintain_pref:
-                pref_dom = dom_commit(carry.pref_dom, pre.pref_g)
-            if st.A:
-                has_dom = (dom_all >= 0).astype(jnp.float32)  # [W, G]
-                match_total = carry.match_total + jnp.einsum(
-                    "w,wg->g", wv, pre.pmg_f * has_dom, precision=_HI
-                )
-
-        def host_commit(plane, vec, ids):
-            vh = vec[:, jnp.asarray(ids)]  # [W, H]
-            if st.single_g[ids].all():
-                # Singleton domains (hostname): the bound node IS the domain
-                # — but only when it actually carries the topology label
-                # (v2's node_has_dom gate; a partially-labeled topology must
-                # not credit label-less nodes).
-                has_dom_h = (
-                    jnp.stack(dom_ats)[:, jnp.asarray(ids)] >= 0
-                ).astype(jnp.float32)  # [W, H]
-                delta = jnp.einsum(
-                    "w,wh,wn->hn", wv, vh * has_dom_h, oh_all,
-                    precision=_HI, preferred_element_type=jnp.float32,
-                )
-                # Cast back to the carry dtype: bf16 planes hold small
-                # integers, exact through the add.
-                return (plane.astype(jnp.float32) + delta).astype(plane.dtype)
-            # General path: credit every node in the bound node's domain.
-            gdom_h = sh.gdom_f[jnp.asarray(ids)]  # [H, N] (static row select)
-            dom_at_h = jnp.stack(dom_ats)[:, jnp.asarray(ids)]  # [W, H]
-            for w in range(wave_width):
-                sel = (
-                    (gdom_h == dom_at_h[w][:, None]) & (dom_at_h[w] >= 0)[:, None]
-                ).astype(jnp.float32)
-                plane = plane + (wv[w] * vh[w])[:, None] * sel
-            return plane
-
-        if len(st.mc_h_ids):
-            mc_host = host_commit(carry.mc_host, pre.pmg_f, st.mc_h_ids)
-        if len(st.anti_h_ids):
-            anti_host = host_commit(carry.anti_host, pre.anti_g, st.anti_h_ids)
-        if len(st.pref_h_ids):
-            pref_host = host_commit(carry.pref_host, pre.pref_g, st.pref_h_ids)
-        new_state = DevState3(
-            used=used, mc_dom=mc_dom, anti_dom=anti_dom, pref_dom=pref_dom,
-            mc_host=mc_host, anti_host=anti_host, pref_host=pref_host,
-            match_total=match_total, used_tier=used_tier, npods_tier=npods_tier,
-        )
+            if len(st.mc_h_ids):
+                mc_host = host_commit(carry.mc_host, pre.pmg_f, st.mc_h_ids)
+            if len(st.anti_h_ids):
+                anti_host = host_commit(carry.anti_host, pre.anti_g, st.anti_h_ids)
+            if len(st.pref_h_ids):
+                pref_host = host_commit(carry.pref_host, pre.pref_g, st.pref_h_ids)
+            new_state = DevState3(
+                used=used, mc_dom=mc_dom, anti_dom=anti_dom, pref_dom=pref_dom,
+                mc_host=mc_host, anti_host=anti_host, pref_host=pref_host,
+                match_total=match_total, used_tier=used_tier, npods_tier=npods_tier,
+            )
         if st.preemption:
             # Eviction event for the host fix-up walk: victims from PRIOR
             # waves (ev_prior) are reconstructed deterministically from the

@@ -44,6 +44,13 @@ from ..utils.metrics import (
     series_gauges,
     utilization_means,
 )
+from ..utils.profiling import (
+    annotate,
+    profiling_active,
+    register_program,
+    shape_structs,
+    stage,
+)
 from .runtime import ReplayResult, events_hash, validate_node_events
 from .telemetry import TelemetryCollector, TelemetryConfig
 from .waves import WaveBatch, pack_waves
@@ -75,8 +82,6 @@ def _make_tick(tel):
         if tel is not None
         else (lambda name: _NULL_CTX)
     )
-    from ..utils.profiling import annotate, profiling_active
-
     if not profiling_active():
         return base
     import contextlib
@@ -92,8 +97,6 @@ def _make_tick(tel):
 def _chunk_ann(ci: int):
     """Chunk-dispatch annotation: ``chunk:<ci>`` marker in device traces
     when profiling is armed, else the shared no-op context."""
-    from ..utils.profiling import annotate, profiling_active
-
     if not profiling_active():
         return _NULL_CTX
     return annotate(f"chunk:{ci}")
@@ -357,31 +360,36 @@ def make_wave_step(
     (pinned by the parity suites)."""
 
     def wave_step(st: T.DevState, slot_batch: T.PodSlot):
-        pre = T.build_wave_pre(dc, d, slot_batch, spec)
-        widths = T.wave_widths(slot_batch, spec)
+        with stage("ksim.reads"):
+            pre = T.build_wave_pre(dc, d, slot_batch, spec)
+            widths = T.wave_widths(slot_batch, spec)
         choices, placeds = [], []
         for wslot in range(wave_width):
             s = jax.tree.map(lambda a: a[wslot], slot_batch)
             p = jax.tree.map(lambda a: a[wslot], pre)
-            feasible, scores, any_f = T.eval_pod_fused(
-                dc, d, st, s, p, spec, widths, wvec=wvec
-            )
-            node, _ = T.select_node(scores, feasible)  # XLA CSEs the any()
-            placed = any_f & s.valid
-            st = T.apply_binding(d, st, s, node, placed)
+            with stage("ksim.filter_score"):
+                feasible, scores, any_f = T.eval_pod_fused(
+                    dc, d, st, s, p, spec, widths, wvec=wvec
+                )
+            with stage("ksim.select"):
+                node, _ = T.select_node(scores, feasible)  # XLA CSEs the any()
+                placed = any_f & s.valid
+            with stage("ksim.commit"):
+                st = T.apply_binding(d, st, s, node, placed)
             choices.append(node)
             placeds.append(placed)
-        choice = jnp.stack(choices)  # [W]
-        placed = jnp.stack(placeds)  # [W]
-        if spec.has_gangs:
-            groups = slot_batch.group  # [W]
-            same = (groups[:, None] == groups[None, :]) & (groups[:, None] >= 0)
-            fail = jnp.any(same & ~placed[None, :], axis=1)  # gang all-or-nothing
-            revert = placed & fail
-            st = T.apply_unbind_wave(d, st, slot_batch, choice, revert)
-            final = jnp.where(placed & ~fail, choice, PAD).astype(jnp.int32)
-        else:
-            final = jnp.where(placed, choice, PAD).astype(jnp.int32)
+        with stage("ksim.commit"):
+            choice = jnp.stack(choices)  # [W]
+            placed = jnp.stack(placeds)  # [W]
+            if spec.has_gangs:
+                groups = slot_batch.group  # [W]
+                same = (groups[:, None] == groups[None, :]) & (groups[:, None] >= 0)
+                fail = jnp.any(same & ~placed[None, :], axis=1)  # gang all-or-nothing
+                revert = placed & fail
+                st = T.apply_unbind_wave(d, st, slot_batch, choice, revert)
+                final = jnp.where(placed & ~fail, choice, PAD).astype(jnp.int32)
+            else:
+                final = jnp.where(placed, choice, PAD).astype(jnp.int32)
         return st, final
 
     return wave_step
@@ -394,7 +402,8 @@ def make_chunk_fn(wave_width: int, spec: StepSpec):
     buffers are donated — the carry updates in place across chunk calls."""
 
     def chunk_fn(dc: T.DevCluster, state: T.DevState, slots: T.PodSlot):
-        d = T.Derived.build(dc)
+        with stage("ksim.derive"):
+            d = T.Derived.build(dc)
         wave_step = make_wave_step(dc, d, wave_width, spec)
         state, choices = jax.lax.scan(wave_step, state, slots)
         return state, choices
@@ -746,14 +755,20 @@ def make_chunk_fn3_src(static3, shared3, rep_slots, wave_width: int, spec: StepS
     from ..ops import tpu3 as V3
 
     def chunk_fn(dc: T.DevCluster, state, src, xsrc, idx):
-        slots = T.gather_slots_device(src, idx)
-        extra = V3.gather_extra_device(xsrc, idx)
-        d = T.Derived.build(dc)
-        cmasks = V3.class_masks(dc, d, static3, spec, rep_slots)
+        with stage("ksim.gather"):
+            slots = T.gather_slots_device(src, idx)
+            extra = V3.gather_extra_device(xsrc, idx)
+        with stage("ksim.derive"):
+            d = T.Derived.build(dc)
+            cmasks = V3.class_masks(dc, d, static3, spec, rep_slots)
         step = V3.make_wave_step3(
             dc, d, shared3, static3, wave_width, spec, cmasks
         )
-        state, choices = jax.lax.scan(step, state, (slots, extra))
+        # The scan's own per-wave slicing of the gathered slots and the
+        # stacking of the choices; the step's stages inside it win (the
+        # innermost scope names an instruction).
+        with stage("ksim.gather"):
+            state, choices = jax.lax.scan(step, state, (slots, extra))
         return state, choices
 
     return jax.jit(chunk_fn, donate_argnums=(1,))
@@ -1471,12 +1486,36 @@ class JaxReplayEngine:
         ``jax.tree.map(jnp.subtract, ...)`` the release/boundary paths
         used allocated a second full state copy per boundary. Cached on
         the engine — jit caches by function identity."""
+        return self._release_jit()(state, delta)
+
+    def _release_jit(self):
         if getattr(self, "_sub_jit", None) is None:
-            self._sub_jit = jax.jit(
-                lambda s, d: jax.tree.map(jnp.subtract, s, d),
-                donate_argnums=(0,),
+            def release_subtract(s, d):
+                with stage("ksim.release"):
+                    return jax.tree.map(jnp.subtract, s, d)
+
+            self._sub_jit = jax.jit(release_subtract, donate_argnums=(0,))
+        return self._sub_jit
+
+    def _register_programs(self, state, idx_chunks, release: bool) -> None:
+        """With profiling armed: hand the resident v3 chunk program this
+        replay is about to call (and its release program) to
+        ``utils.profiling.stage_tables``, by XLA module name, as thunks
+        over the calls' shapes. Nothing is lowered here."""
+        if not idx_chunks or not profiling_active():
+            return
+        chunk_fn = self.chunk_fn
+        args = shape_structs(
+            (self.dc, state, self._slot_src, self._extra_src, idx_chunks[0])
+        )
+        register_program(
+            f"jit_{chunk_fn.__name__}", lambda: chunk_fn.lower(*args)
+        )
+        if release:
+            sub, st = self._release_jit(), args[1]
+            register_program(
+                f"jit_{sub.__name__}", lambda: sub.lower(st, st)
             )
-        return self._sub_jit(state, delta)
 
     def _apply_boundary_delta(self, state, sub_pairs, add_pairs):
         """Net host-layout plane delta of one boundary pass — releases and
@@ -1576,7 +1615,7 @@ class JaxReplayEngine:
         fw = SchedulerFramework(self.ec, self.pods, cfg)
         lazy = self.lazy_boundary
         tel = (
-            TelemetryCollector(self.telemetry_cfg)
+            TelemetryCollector(self.telemetry_cfg, chunk_waves=C)
             if self.telemetry_cfg.enabled
             else None
         )
@@ -1584,123 +1623,125 @@ class JaxReplayEngine:
         # host-side observation only, parity-pinned against recorder-off.
         rec, rec_own = self._open_recorder()
         _tick = _make_tick(tel if tel is not None else rec)
-        probe = (
-            self._make_exchange_probe()
-            if rec is not None and self.node_shards > 1
-            else None
-        )
-        bops = BoundaryOps(
-            self.ec, self.pods, fw,
-            WaveBatch(idx=idx, wave_width=self.wave_width),
-            self.wave_width, C,
-            retry_buffer=retry_req, kube=self.kube, lazy=lazy,
-            telemetry=tel,
-        )
-        self._last_bops = bops  # probe for the quiet-path tests/bench
-        state = self._init_dev_state()
-        pending_events = sorted(node_events or [], key=lambda e: e.time)
-        ev_hash = events_hash(pending_events)
-        ev_applied = 0  # checkpoint event cursor
-        saved_alloc = np.asarray(self.dc.allocatable).copy()
-        saved_alloc_ec = self.ec.allocatable.copy()
-        start_chunk = 0
-        if resume and checkpoint_path:
-            from .checkpoint import ReplayCheckpoint
+        with _tick("stage"):
+            probe = (
+                self._make_exchange_probe()
+                if rec is not None and self.node_shards > 1
+                else None
+            )
+            bops = BoundaryOps(
+                self.ec, self.pods, fw,
+                WaveBatch(idx=idx, wave_width=self.wave_width),
+                self.wave_width, C,
+                retry_buffer=retry_req, kube=self.kube, lazy=lazy,
+                telemetry=tel,
+            )
+            self._last_bops = bops  # probe for the quiet-path tests/bench
+            state = self._init_dev_state()
+            pending_events = sorted(node_events or [], key=lambda e: e.time)
+            ev_hash = events_hash(pending_events)
+            ev_applied = 0  # checkpoint event cursor
+            saved_alloc = np.asarray(self.dc.allocatable).copy()
+            saved_alloc_ec = self.ec.allocatable.copy()
+            start_chunk = 0
+            if resume and checkpoint_path:
+                from .checkpoint import ReplayCheckpoint
 
-            ck = ReplayCheckpoint.load(checkpoint_path)
-            if ck.boundary is None:
-                raise ValueError(
-                    "checkpoint was not written by a boundary-mode "
-                    "(retry/kube) replay — resume it on a plain engine"
+                ck = ReplayCheckpoint.load(checkpoint_path)
+                if ck.boundary is None:
+                    raise ValueError(
+                        "checkpoint was not written by a boundary-mode "
+                        "(retry/kube) replay — resume it on a plain engine"
+                    )
+                ck_hash = ck.boundary.get("ev_hash")
+                if ck_hash is not None and not np.array_equal(
+                    np.asarray(ck_hash, np.uint8), ev_hash
+                ):
+                    raise ValueError(
+                        "checkpoint was written under a different node_events "
+                        "timeline — resuming would re-apply or skip events "
+                        "(evictions are not idempotent); pass the original "
+                        "event list or restart the replay from scratch"
+                    )
+                state = self._state_from_checkpoint(ck)
+                bops.restore(
+                    ck.boundary, ck.used, ck.match_count, ck.anti_active,
+                    ck.pref_wsum,
                 )
-            ck_hash = ck.boundary.get("ev_hash")
-            if ck_hash is not None and not np.array_equal(
-                np.asarray(ck_hash, np.uint8), ev_hash
-            ):
-                raise ValueError(
-                    "checkpoint was written under a different node_events "
-                    "timeline — resuming would re-apply or skip events "
-                    "(evictions are not idempotent); pass the original "
-                    "event list or restart the replay from scratch"
+                start_chunk = ck.chunk_cursor
+                cur = ck.boundary.get("ev_cursor")
+                if cur is not None and int(np.asarray(cur).reshape(-1)[0]):
+                    # Catch-up: past events re-shape allocatable (the device
+                    # cluster starts unperturbed) WITHOUT re-evicting — the
+                    # restored mirror already reflects their evictions.
+                    ev_applied = int(np.asarray(cur).reshape(-1)[0])
+                    done = pending_events[:ev_applied]
+                    self._apply_node_events(done, saved_alloc)
+                    for ev in done:
+                        if ev.kind == "node_down":
+                            self.ec.allocatable[ev.node] = 0.0
+                        elif ev.kind == "node_up":
+                            self.ec.allocatable[ev.node] = saved_alloc_ec[ev.node]
+                        elif ev.kind == "capacity_scale":
+                            self.ec.allocatable[ev.node] = (
+                                saved_alloc_ec[ev.node] * ev.scale
+                            )
+                    pending_events = pending_events[ev_applied:]
+            wave_times = self._wave_start_times(idx)
+            idx_chunks = (
+                [jnp.asarray(idx[c0 : c0 + C]) for c0 in range(0, idx.shape[0], C)]
+                if self.engine == "v3"
+                else None
+            )
+            self._register_programs(state, idx_chunks, True)
+            # Scalar boundary summary: count of failed NON-GANG slots (the only
+            # failures that enter the retry buffer — gang failures never do).
+            if not hasattr(self, "_bfail_fn"):
+                self._bfail_fn = jax.jit(
+                    lambda ch, ix, ng: (
+                        (ix >= 0)
+                        & (ch.reshape(ix.shape) < 0)
+                        & ng[jnp.clip(ix, 0)]
+                    ).sum(dtype=jnp.int32)
                 )
-            state = self._state_from_checkpoint(ck)
-            bops.restore(
-                ck.boundary, ck.used, ck.match_count, ck.anti_active,
-                ck.pref_wsum,
-            )
-            start_chunk = ck.chunk_cursor
-            cur = ck.boundary.get("ev_cursor")
-            if cur is not None and int(np.asarray(cur).reshape(-1)[0]):
-                # Catch-up: past events re-shape allocatable (the device
-                # cluster starts unperturbed) WITHOUT re-evicting — the
-                # restored mirror already reflects their evictions.
-                ev_applied = int(np.asarray(cur).reshape(-1)[0])
-                done = pending_events[:ev_applied]
-                self._apply_node_events(done, saved_alloc)
-                for ev in done:
-                    if ev.kind == "node_down":
-                        self.ec.allocatable[ev.node] = 0.0
-                    elif ev.kind == "node_up":
-                        self.ec.allocatable[ev.node] = saved_alloc_ec[ev.node]
-                    elif ev.kind == "capacity_scale":
-                        self.ec.allocatable[ev.node] = (
-                            saved_alloc_ec[ev.node] * ev.scale
-                        )
-                pending_events = pending_events[ev_applied:]
-        wave_times = self._wave_start_times(idx)
-        idx_chunks = (
-            [jnp.asarray(idx[c0 : c0 + C]) for c0 in range(0, idx.shape[0], C)]
-            if self.engine == "v3"
-            else None
-        )
-        # Scalar boundary summary: count of failed NON-GANG slots (the only
-        # failures that enter the retry buffer — gang failures never do).
-        if not hasattr(self, "_bfail_fn"):
-            self._bfail_fn = jax.jit(
-                lambda ch, ix, ng: (
-                    (ix >= 0)
-                    & (ch.reshape(ix.shape) < 0)
-                    & ng[jnp.clip(ix, 0)]
-                ).sum(dtype=jnp.int32)
-            )
-        ng_dev = jnp.asarray(self.pods.group_id == PAD)
-        # Deferred fold of the previous chunk: (ci, rows, choices_dev,
-        # nfail_dev). Resolved eagerly when the boundary will read the
-        # mirror planes; otherwise folded AFTER the next dispatch so the
-        # D2H copy overlaps device compute.
-        pending = None
+            ng_dev = jnp.asarray(self.pods.group_id == PAD)
+            # Deferred fold of the previous chunk: (ci, rows, choices_dev,
+            # nfail_dev). Resolved eagerly when the boundary will read the
+            # mirror planes; otherwise folded AFTER the next dispatch so the
+            # D2H copy overlaps device compute.
+            pending = None
 
-        def _fold_pending():
-            nonlocal pending
-            if pending is not None:
-                ci_p, rows_p, ch_d, _nf = pending
-                t_f = time.perf_counter()
-                with _tick("device_wait"):
-                    ch_np = np.asarray(ch_d)
-                with _tick("boundary_fold"):
-                    bops.fold_chunk(ci_p, rows_p, ch_np)
-                if rec is not None:
-                    rec.fold(ci_p, time.perf_counter() - t_f)
-                pending = None
+            def _fold_pending():
+                nonlocal pending
+                if pending is not None:
+                    ci_p, rows_p, ch_d, _nf = pending
+                    t_f = time.perf_counter()
+                    with _tick("device_wait"):
+                        ch_np = np.asarray(ch_d)
+                    with _tick("boundary_fold"):
+                        bops.fold_chunk(ci_p, rows_p, ch_np)
+                    if rec is not None:
+                        rec.fold(ci_p, time.perf_counter() - t_f)
+                    pending = None
 
-        dbuf = self.double_buffer and lazy
-        rec_valid = (
-            np.add.accumulate(
-                [
-                    int((idx[c0 : c0 + C] >= 0).sum())
-                    for c0 in range(0, idx.shape[0], C)
-                ]
+            dbuf = self.double_buffer and lazy
+            rec_valid = (
+                np.add.accumulate(
+                    [
+                        int((idx[c0 : c0 + C] >= 0).sum())
+                        for c0 in range(0, idx.shape[0], C)
+                    ]
+                )
+                if rec is not None
+                else None
             )
-            if rec is not None
-            else None
-        )
-        rec_pub = None
-        rec_retry = None
-        if rec is not None:
-            from ..parallel import dcn as _dcn
+            rec_pub = None
+            rec_retry = None
+            if rec is not None:
+                from ..parallel import dcn as _dcn
 
-            rec_pub = _dcn.publish_stats()
-            rec_retry = _dcn.retry_stats()
+                rec_pub = _dcn.publish_stats()
+                rec_retry = _dcn.retry_stats()
         t0 = time.perf_counter()
         try:
             for ci, c0 in enumerate(range(0, idx.shape[0], C)):
@@ -1937,29 +1978,30 @@ class JaxReplayEngine:
                 self.ec.allocatable[:] = saved_alloc_ec
         wall = time.perf_counter() - t0
 
-        to_schedule = int((idx >= 0).sum())
-        assignments = bops.assignments
-        placed = bops.placed_total
-        if self.engine == "v3":
-            used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
-        else:
-            hs = self._unshard_state_v2(state)
-            used = hs.used
-            mc = T.node_space_to_domain(hs.match_count, self._gdom, self._Dhost)
-            aa = T.node_space_to_domain(hs.anti_active, self._gdom, self._Dhost)
-            pw = T.node_space_to_domain(hs.pref_wsum, self._gdom, self._Dhost)
-        util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
-        pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
-        frag = fragmentation_gauges(
-            self.ec.allocatable, used, self.pods.requests[pending_m],
-            self.ec.vocab._r,
-        )
-        host_state = SchedState(
-            used=used, match_count=mc, anti_active=aa, pref_wsum=pw,
-            bound=assignments.copy(),
-        )
-        if rec is not None and rec_own:
-            rec.close({"placed": int(placed)})
+        with _tick("gather"):
+            to_schedule = int((idx >= 0).sum())
+            assignments = bops.assignments
+            placed = bops.placed_total
+            if self.engine == "v3":
+                used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
+            else:
+                hs = self._unshard_state_v2(state)
+                used = hs.used
+                mc = T.node_space_to_domain(hs.match_count, self._gdom, self._Dhost)
+                aa = T.node_space_to_domain(hs.anti_active, self._gdom, self._Dhost)
+                pw = T.node_space_to_domain(hs.pref_wsum, self._gdom, self._Dhost)
+            util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
+            pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
+            frag = fragmentation_gauges(
+                self.ec.allocatable, used, self.pods.requests[pending_m],
+                self.ec.vocab._r,
+            )
+            host_state = SchedState(
+                used=used, match_count=mc, anti_active=aa, pref_wsum=pw,
+                bound=assignments.copy(),
+            )
+            if rec is not None and rec_own:
+                rec.close({"placed": int(placed)})
         return ReplayResult(
             assignments=assignments,
             placed=placed,
@@ -2092,7 +2134,7 @@ class JaxReplayEngine:
         from ..utils.metrics import log
 
         tel = (
-            TelemetryCollector(self.telemetry_cfg)
+            TelemetryCollector(self.telemetry_cfg, chunk_waves=C)
             if self.telemetry_cfg.enabled
             else None
         )
@@ -2102,189 +2144,191 @@ class JaxReplayEngine:
         # changes a device program, a fold order or a checkpoint payload.
         rec, rec_own = self._open_recorder()
         _tick = _make_tick(tel if tel is not None else rec)
-        probe = (
-            self._make_exchange_probe()
-            if rec is not None and self.node_shards > 1
-            else None
-        )
-        # In-scan rejection attribution (series+): thread a [K] i32 reject
-        # counter through the scan carry via the instrumented reference
-        # chunk program — one extra fetch per REPLAY, never per pod. The
-        # default "summary" granularity takes none of these branches and
-        # runs the exact same device program as before.
-        use_rej = tel is not None and tel.cfg.want_series
-        if use_rej and self.preemption:
-            log.info(
-                "telemetry: rejection attribution is not available with "
-                "in-scan tier preemption (the instrumented program has no "
-                "tier planes) — latency/phase telemetry still collected"
+        with _tick("stage"):
+            probe = (
+                self._make_exchange_probe()
+                if rec is not None and self.node_shards > 1
+                else None
             )
-            use_rej = False
-        if use_rej and (checkpoint_path or resume):
-            log.info(
-                "telemetry: rejection attribution is disabled under "
-                "checkpoint/resume (the instrumented carry is not part of "
-                "checkpoints) — latency/phase telemetry still collected"
-            )
-            use_rej = False
-        if use_rej and self.node_shards > 1:
-            log.info(
-                "telemetry: rejection attribution is disabled under node "
-                "sharding (the instrumented reference program carries "
-                "replicated node planes) — latency/phase telemetry still "
-                "collected"
-            )
-            use_rej = False
-        rej_dev = None
-        if use_rej:
-            if self.engine == "v3":
+            # In-scan rejection attribution (series+): thread a [K] i32 reject
+            # counter through the scan carry via the instrumented reference
+            # chunk program — one extra fetch per REPLAY, never per pod. The
+            # default "summary" granularity takes none of these branches and
+            # runs the exact same device program as before.
+            use_rej = tel is not None and tel.cfg.want_series
+            if use_rej and self.preemption:
                 log.info(
-                    "telemetry series: plain v3 replay uses the reference "
-                    "(v2) chunk program for in-scan rejection attribution "
-                    "— placements are bit-identical (parity-pinned), "
-                    "throughput is the v2 envelope"
+                    "telemetry: rejection attribution is not available with "
+                    "in-scan tier preemption (the instrumented program has no "
+                    "tier planes) — latency/phase telemetry still collected"
                 )
-            if not hasattr(self, "_chunk_fn_rej"):
-                self._chunk_fn_rej = make_chunk_fn_rej(
-                    self.wave_width, self.spec
+                use_rej = False
+            if use_rej and (checkpoint_path or resume):
+                log.info(
+                    "telemetry: rejection attribution is disabled under "
+                    "checkpoint/resume (the instrumented carry is not part of "
+                    "checkpoints) — latency/phase telemetry still collected"
                 )
-            rej_dev = jnp.zeros(
-                len(spec_plugin_names(self.spec)), jnp.int32
-            )
+                use_rej = False
+            if use_rej and self.node_shards > 1:
+                log.info(
+                    "telemetry: rejection attribution is disabled under node "
+                    "sharding (the instrumented reference program carries "
+                    "replicated node planes) — latency/phase telemetry still "
+                    "collected"
+                )
+                use_rej = False
+            rej_dev = None
+            if use_rej:
+                if self.engine == "v3":
+                    log.info(
+                        "telemetry series: plain v3 replay uses the reference "
+                        "(v2) chunk program for in-scan rejection attribution "
+                        "— placements are bit-identical (parity-pinned), "
+                        "throughput is the v2 envelope"
+                    )
+                if not hasattr(self, "_chunk_fn_rej"):
+                    self._chunk_fn_rej = make_chunk_fn_rej(
+                        self.wave_width, self.spec
+                    )
+                rej_dev = jnp.zeros(
+                    len(spec_plugin_names(self.spec)), jnp.int32
+                )
 
-        state = self._init_dev_state(force_v2=use_rej)
-        all_choices = []
-        start_chunk = 0
-        if resume and checkpoint_path:
-            ck = ReplayCheckpoint.load(checkpoint_path)
-            if ck.boundary is not None:
-                raise ValueError(
-                    "checkpoint was written by a boundary-mode (retry/"
-                    "kube) replay — its placements live in the host "
-                    "mirror, not the saved outs; resume it with the "
-                    "same retry_buffer/preemption configuration"
-                )
-            state = self._state_from_checkpoint(ck)
-            all_choices = [jnp.asarray(o) for o in ck.outs]
-            start_chunk = ck.chunk_cursor
-        pending_events = sorted(node_events or [], key=lambda e: e.time)
-        rel_time = self.pods.arrival + np.where(
-            np.isfinite(self.pods.duration), self.pods.duration, np.inf
-        )
-        completions_on = bool(
-            self.completions is not False  # None (the default) = on
-            and np.isfinite(rel_time).any()
-        )
-        wave_times = (
-            self._wave_start_times(idx)
-            # use_rej: series telemetry also samples utilization at chunk
-            # boundaries, which needs the chunk start times. The recorder
-            # stamps the chunk's virtual time on every row (host numpy
-            # only — no program effect).
-            if (pending_events or completions_on or use_rej or rec is not None)
-            else None
-        )
-        pending_fold = None  # (rows, choices) of the not-yet-folded chunk
-        nongang = self.pods.group_id == PAD
-        if completions_on and self.preemption:
-            # Completions × preemption (round 4): folds run EAGERLY (the
-            # chunk's eviction events must land in the host bookkeeping
-            # BEFORE the next boundary's release decisions, or a pod the
-            # device evicted would "release" resources it no longer
-            # holds). The one-chunk slack therefore becomes an explicit
-            # bind-chunk check instead of a fold lag; the pipeline eats
-            # one blocking fetch per chunk — correctness over overlap for
-            # this opt-in combination.
-            chunk_of_arr = bind_chunk_of(self.pods, idx, C)
-        if completions_on:
-            host_assign = np.where(
-                self.pods.bound_node >= 0, self.pods.bound_node, PAD
-            ).astype(np.int32)
-            released = np.zeros(self.pods.num_pods, bool)
-            if start_chunk:
-                # Resume: the saved state already carries pre-resume
-                # releases — seed from the persisted mask (or reconstruct
-                # from the saved outs for pre-field checkpoints). The
-                # one-chunk slack is restored by folding only chunks
-                # ≤ start_chunk−2 and re-pending the last saved chunk.
-                have_mask = getattr(ck, "released", None) is not None
-                host_assign, _ = rebuild_fork_state(
-                    self.pods, idx, C, all_choices, wave_times,
-                    max(start_chunk - 1, 0), reconstruct_released=False,
-                )
-                if have_mask:
-                    released = ck.released.astype(bool)
-                else:
-                    # released=None ⟹ a checkpoint from before the field
-                    # existed ⟹ its state was built under the OLD
-                    # (no-slack) release rule — reconstruct with slack=0.
-                    _, released = rebuild_fork_state(
+            state = self._init_dev_state(force_v2=use_rej)
+            all_choices = []
+            start_chunk = 0
+            if resume and checkpoint_path:
+                ck = ReplayCheckpoint.load(checkpoint_path)
+                if ck.boundary is not None:
+                    raise ValueError(
+                        "checkpoint was written by a boundary-mode (retry/"
+                        "kube) replay — its placements live in the host "
+                        "mirror, not the saved outs; resume it with the "
+                        "same retry_buffer/preemption configuration"
+                    )
+                state = self._state_from_checkpoint(ck)
+                all_choices = [jnp.asarray(o) for o in ck.outs]
+                start_chunk = ck.chunk_cursor
+            pending_events = sorted(node_events or [], key=lambda e: e.time)
+            rel_time = self.pods.arrival + np.where(
+                np.isfinite(self.pods.duration), self.pods.duration, np.inf
+            )
+            completions_on = bool(
+                self.completions is not False  # None (the default) = on
+                and np.isfinite(rel_time).any()
+            )
+            wave_times = (
+                self._wave_start_times(idx)
+                # use_rej: series telemetry also samples utilization at chunk
+                # boundaries, which needs the chunk start times. The recorder
+                # stamps the chunk's virtual time on every row (host numpy
+                # only — no program effect).
+                if (pending_events or completions_on or use_rej or rec is not None)
+                else None
+            )
+            pending_fold = None  # (rows, choices) of the not-yet-folded chunk
+            nongang = self.pods.group_id == PAD
+            if completions_on and self.preemption:
+                # Completions × preemption (round 4): folds run EAGERLY (the
+                # chunk's eviction events must land in the host bookkeeping
+                # BEFORE the next boundary's release decisions, or a pod the
+                # device evicted would "release" resources it no longer
+                # holds). The one-chunk slack therefore becomes an explicit
+                # bind-chunk check instead of a fold lag; the pipeline eats
+                # one blocking fetch per chunk — correctness over overlap for
+                # this opt-in combination.
+                chunk_of_arr = bind_chunk_of(self.pods, idx, C)
+            if completions_on:
+                host_assign = np.where(
+                    self.pods.bound_node >= 0, self.pods.bound_node, PAD
+                ).astype(np.int32)
+                released = np.zeros(self.pods.num_pods, bool)
+                if start_chunk:
+                    # Resume: the saved state already carries pre-resume
+                    # releases — seed from the persisted mask (or reconstruct
+                    # from the saved outs for pre-field checkpoints). The
+                    # one-chunk slack is restored by folding only chunks
+                    # ≤ start_chunk−2 and re-pending the last saved chunk.
+                    have_mask = getattr(ck, "released", None) is not None
+                    host_assign, _ = rebuild_fork_state(
                         self.pods, idx, C, all_choices, wave_times,
-                        start_chunk, slack=0,
+                        max(start_chunk - 1, 0), reconstruct_released=False,
                     )
-                if start_chunk >= 1:
-                    pending_fold = (
-                        idx[(start_chunk - 1) * C : start_chunk * C],
-                        np.asarray(all_choices[start_chunk - 1]),
-                    )
-        saved_alloc = np.asarray(self.dc.allocatable).copy()
-        # Pre-stage the per-chunk wave indices on device (a few MB total):
-        # the timed loop then issues ONE call per chunk with no H2D.
-        idx_chunks = (
-            [
-                jnp.asarray(idx[c0 : c0 + C])
-                for c0 in range(0, idx.shape[0], C)
-            ]
-            if self.engine == "v3" and not use_rej and not self.paged
-            else None
-        )
-        # Paged pod waves (round 14): per-chunk pages of the slot planes
-        # stream host->device with one-chunk prefetch instead of whole-trace
-        # residency. v3 pages carry page-LOCAL row indices (the kernels only
-        # consume pod_id as a width, never as an identity).
-        pager = None
-        if self.paged and not use_rej:
-            if self.engine == "v3":
-                def _fetch_page(pci):
-                    rows = idx[pci * C : (pci + 1) * C]
-                    flat = rows.reshape(-1)
-                    local = np.where(
-                        rows >= 0,
-                        np.arange(
-                            rows.size, dtype=np.int32
-                        ).reshape(rows.shape),
-                        PAD,
-                    ).astype(np.int32)
-                    return (
-                        T.SlotSource.page(self.pods, flat),
-                        V3.ExtraSource.page(self.static3, flat),
-                        jnp.asarray(local),
-                    )
-            else:
-                def _fetch_page(pci):
-                    return T.gather_slots(
-                        self.pods, idx[pci * C : (pci + 1) * C]
-                    )
-            pager = _PodPager(_fetch_page, threaded=_pager_thread_enabled())
-        rec_valid = (
-            np.add.accumulate(
+                    if have_mask:
+                        released = ck.released.astype(bool)
+                    else:
+                        # released=None ⟹ a checkpoint from before the field
+                        # existed ⟹ its state was built under the OLD
+                        # (no-slack) release rule — reconstruct with slack=0.
+                        _, released = rebuild_fork_state(
+                            self.pods, idx, C, all_choices, wave_times,
+                            start_chunk, slack=0,
+                        )
+                    if start_chunk >= 1:
+                        pending_fold = (
+                            idx[(start_chunk - 1) * C : start_chunk * C],
+                            np.asarray(all_choices[start_chunk - 1]),
+                        )
+            saved_alloc = np.asarray(self.dc.allocatable).copy()
+            # Pre-stage the per-chunk wave indices on device (a few MB total):
+            # the timed loop then issues ONE call per chunk with no H2D.
+            idx_chunks = (
                 [
-                    int((idx[c0 : c0 + C] >= 0).sum())
+                    jnp.asarray(idx[c0 : c0 + C])
                     for c0 in range(0, idx.shape[0], C)
                 ]
+                if self.engine == "v3" and not use_rej and not self.paged
+                else None
             )
-            if rec is not None
-            else None
-        )
-        rec_stalls_seen = 0
-        rec_inval_seen = 0
-        rec_pub = None
-        rec_retry = None
-        if rec is not None:
-            from ..parallel import dcn as _dcn
+            self._register_programs(state, idx_chunks, completions_on)
+            # Paged pod waves (round 14): per-chunk pages of the slot planes
+            # stream host->device with one-chunk prefetch instead of whole-trace
+            # residency. v3 pages carry page-LOCAL row indices (the kernels only
+            # consume pod_id as a width, never as an identity).
+            pager = None
+            if self.paged and not use_rej:
+                if self.engine == "v3":
+                    def _fetch_page(pci):
+                        rows = idx[pci * C : (pci + 1) * C]
+                        flat = rows.reshape(-1)
+                        local = np.where(
+                            rows >= 0,
+                            np.arange(
+                                rows.size, dtype=np.int32
+                            ).reshape(rows.shape),
+                            PAD,
+                        ).astype(np.int32)
+                        return (
+                            T.SlotSource.page(self.pods, flat),
+                            V3.ExtraSource.page(self.static3, flat),
+                            jnp.asarray(local),
+                        )
+                else:
+                    def _fetch_page(pci):
+                        return T.gather_slots(
+                            self.pods, idx[pci * C : (pci + 1) * C]
+                        )
+                pager = _PodPager(_fetch_page, threaded=_pager_thread_enabled())
+            rec_valid = (
+                np.add.accumulate(
+                    [
+                        int((idx[c0 : c0 + C] >= 0).sum())
+                        for c0 in range(0, idx.shape[0], C)
+                    ]
+                )
+                if rec is not None
+                else None
+            )
+            rec_stalls_seen = 0
+            rec_inval_seen = 0
+            rec_pub = None
+            rec_retry = None
+            if rec is not None:
+                from ..parallel import dcn as _dcn
 
-            rec_pub = _dcn.publish_stats()
-            rec_retry = _dcn.retry_stats()
+                rec_pub = _dcn.publish_stats()
+                rec_retry = _dcn.retry_stats()
         t0 = time.perf_counter()
         for ci, c0 in enumerate(range(0, idx.shape[0], C)):
             if ci < start_chunk:
@@ -2304,35 +2348,39 @@ class JaxReplayEngine:
             if completions_on:
                 if self.preemption and pending_fold is not None:
                     # Eager eviction-aware fold of the previous chunk.
-                    rows_p, out_p = pending_fold
-                    preemption_walk(
-                        host_assign, rows_p,
-                        np.asarray(out_p[0]).reshape(rows_p.shape),
-                        np.asarray(out_p[1]), np.asarray(out_p[2]),
-                        self.static3.pod_tier, nongang,
-                        released=released,
-                    )
+                    with _tick("boundary_fold"):
+                        rows_p, out_p = pending_fold
+                        preemption_walk(
+                            host_assign, rows_p,
+                            np.asarray(out_p[0]).reshape(rows_p.shape),
+                            np.asarray(out_p[1]), np.asarray(out_p[2]),
+                            self.static3.pod_tier, nongang,
+                            released=released,
+                        )
                     pending_fold = None
                 t_chunk = wave_times[c0]
                 if np.isfinite(t_chunk):
-                    due_m = (
-                        (host_assign != PAD)
-                        & ~released
-                        & np.isfinite(rel_time)
-                        & (rel_time <= t_chunk)
-                    )
-                    if self.preemption:
-                        # Folds are eager here, so the one-chunk slack
-                        # is the explicit bind-chunk rule.
-                        due_m &= chunk_of_arr < ci - 1
-                    due_p = np.nonzero(due_m)[0]
-                    if due_p.size:
-                        with _tick("host_mirror"):
+                    # The whole of what a boundary costs the host: the
+                    # scan over all pods for due releases, the delta
+                    # build and the release program's dispatch.
+                    with _tick("host_mirror"):
+                        due_m = (
+                            (host_assign != PAD)
+                            & ~released
+                            & np.isfinite(rel_time)
+                            & (rel_time <= t_chunk)
+                        )
+                        if self.preemption:
+                            # Folds are eager here, so the one-chunk slack
+                            # is the explicit bind-chunk rule.
+                            due_m &= chunk_of_arr < ci - 1
+                        due_p = np.nonzero(due_m)[0]
+                        if due_p.size:
                             state = self._apply_release(
                                 state, due_p, host_assign[due_p],
                                 as_v2=use_rej,
                             )
-                        released[due_p] = True
+                            released[due_p] = True
             if use_rej and wave_times is not None and np.isfinite(
                 wave_times[c0]
             ):
@@ -2387,10 +2435,11 @@ class JaxReplayEngine:
                 # boundary b only ever sees chunks ≤ b−2 (the one-chunk
                 # slack; the greedy anchor implements the same rule).
                 if pending_fold is not None:
-                    rows_p, ch_p = pending_fold
-                    ch = np.asarray(ch_p).reshape(rows_p.shape)
-                    v = rows_p >= 0
-                    host_assign[rows_p[v]] = ch[v]
+                    with _tick("boundary_fold"):
+                        rows_p, ch_p = pending_fold
+                        ch = np.asarray(ch_p).reshape(rows_p.shape)
+                        v = rows_p >= 0
+                        host_assign[rows_p[v]] = ch[v]
                 pending_fold = (idx[c0 : c0 + C], choices)
             if checkpoint_path and checkpoint_every and (ci + 1) % checkpoint_every == 0:
                 t_ck = time.perf_counter()
@@ -2471,94 +2520,95 @@ class JaxReplayEngine:
         with _tick("device_wait"):
             jax.block_until_ready(all_choices[-1] if all_choices else state)
         wall = time.perf_counter() - t0
-        if node_events:
-            self.dc = self.dc._replace(allocatable=self._put_alloc(saved_alloc))
+        with _tick("gather"):
+            if node_events:
+                self.dc = self.dc._replace(allocatable=self._put_alloc(saved_alloc))
 
-        preemptions = 0
-        to_schedule = int((idx >= 0).sum())
-        if self.preemption and completions_on:
-            # The incremental eviction-aware folds ARE the walk; finish
-            # the last pending chunk and read the result off the host
-            # bookkeeping (a fresh full walk would replay evictions
-            # against completed pods with the wrong interleaving).
-            if pending_fold is not None:
-                rows_p, out_p = pending_fold
-                preemption_walk(
-                    host_assign, rows_p,
-                    np.asarray(out_p[0]).reshape(rows_p.shape),
-                    np.asarray(out_p[1]), np.asarray(out_p[2]),
-                    self.static3.pod_tier, nongang, released=released,
+            preemptions = 0
+            to_schedule = int((idx >= 0).sum())
+            if self.preemption and completions_on:
+                # The incremental eviction-aware folds ARE the walk; finish
+                # the last pending chunk and read the result off the host
+                # bookkeeping (a fresh full walk would replay evictions
+                # against completed pods with the wrong interleaving).
+                if pending_fold is not None:
+                    rows_p, out_p = pending_fold
+                    preemption_walk(
+                        host_assign, rows_p,
+                        np.asarray(out_p[0]).reshape(rows_p.shape),
+                        np.asarray(out_p[1]), np.asarray(out_p[2]),
+                        self.static3.pod_tier, nongang, released=released,
+                    )
+                assignments = host_assign
+                scheduled = self.pods.bound_node == PAD
+                placed = int((assignments[scheduled] >= 0).sum())
+                preemptions = int(
+                    np.concatenate(
+                        [np.asarray(c[4]) for c in all_choices]
+                    ).sum()
                 )
-            assignments = host_assign
-            scheduled = self.pods.bound_node == PAD
-            placed = int((assignments[scheduled] >= 0).sum())
-            preemptions = int(
-                np.concatenate(
-                    [np.asarray(c[4]) for c in all_choices]
-                ).sum()
-            )
-        elif self.preemption:
-            finals = np.concatenate([np.asarray(c[0]) for c in all_choices])
-            ev_node = np.concatenate([np.asarray(c[1]) for c in all_choices])
-            ev_tier = np.concatenate([np.asarray(c[2]) for c in all_choices])
-            ev_total = np.concatenate([np.asarray(c[4]) for c in all_choices])
-            assignments, placed = self._preemption_walk(
-                idx, finals, ev_node, ev_tier
-            )
-            preemptions = int(ev_total.sum())
-        else:
-            choices_np = np.asarray(jnp.concatenate(all_choices, axis=0))
-            assignments = np.where(
-                self.pods.bound_node >= 0, self.pods.bound_node, PAD
-            ).astype(np.int32)
-            flat_idx = idx.reshape(-1)
-            flat_choice = choices_np.reshape(-1)
-            valid = flat_idx >= 0
-            assignments[flat_idx[valid]] = flat_choice[valid]
-            placed = int((flat_choice[valid] >= 0).sum())
-
-        if tel is not None:
-            # Plain replay: every placement is a wave placement — bound in
-            # the same chunk it arrived in, zero virtual-time latency by
-            # the chunk-granular convention (SURVEY.md §5).
-            tel.bind_zero(placed)
-            if use_rej:
-                tel.rejection_bulk(
-                    spec_plugin_names(self.spec), np.asarray(rej_dev)
+            elif self.preemption:
+                finals = np.concatenate([np.asarray(c[0]) for c in all_choices])
+                ev_node = np.concatenate([np.asarray(c[1]) for c in all_choices])
+                ev_tier = np.concatenate([np.asarray(c[2]) for c in all_choices])
+                ev_total = np.concatenate([np.asarray(c[4]) for c in all_choices])
+                assignments, placed = self._preemption_walk(
+                    idx, finals, ev_node, ev_tier
                 )
+                preemptions = int(ev_total.sum())
+            else:
+                choices_np = np.asarray(jnp.concatenate(all_choices, axis=0))
+                assignments = np.where(
+                    self.pods.bound_node >= 0, self.pods.bound_node, PAD
+                ).astype(np.int32)
+                flat_idx = idx.reshape(-1)
+                flat_choice = choices_np.reshape(-1)
+                valid = flat_idx >= 0
+                assignments[flat_idx[valid]] = flat_choice[valid]
+                placed = int((flat_choice[valid] >= 0).sum())
 
-        if self.engine == "v3" and not use_rej:
-            used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
-        else:
-            hs = self._unshard_state_v2(state)
-            used = hs.used
-            mc = T.node_space_to_domain(hs.match_count, self._gdom, self._Dhost)
-            aa = T.node_space_to_domain(hs.anti_active, self._gdom, self._Dhost)
-            pw = T.node_space_to_domain(hs.pref_wsum, self._gdom, self._Dhost)
-        util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
-        pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
-        frag = fragmentation_gauges(
-            self.ec.allocatable, used, self.pods.requests[pending_m],
-            self.ec.vocab._r,
-        )
-        host_state = SchedState(
-            used=used, match_count=mc, anti_active=aa, pref_wsum=pw,
-            bound=assignments.copy(),
-        )
-        if rec is not None:
-            # Pager walls join the phase accumulators (keys only present
-            # when paging is on AND the recorder observed them, so the
-            # canonical PHASE_NAMES-only runs are unchanged).
-            # ``pager_stall`` is the EXPOSED wall; ``pager_prefetch`` the
-            # fetch wall itself — hidden under the round-19 thread,
-            # loop-exposed without it.
-            if pager is not None and tel is not None:
-                tel.phases.add("pager_stall", pager.stall_s)
-                tel.phases.add("pager_prefetch", pager.prefetch_wall_s)
-            if rec_own:
-                rec.close({"placed": int(placed)})
-        if pager is not None:
-            pager.close()
+            if tel is not None:
+                # Plain replay: every placement is a wave placement — bound in
+                # the same chunk it arrived in, zero virtual-time latency by
+                # the chunk-granular convention (SURVEY.md §5).
+                tel.bind_zero(placed)
+                if use_rej:
+                    tel.rejection_bulk(
+                        spec_plugin_names(self.spec), np.asarray(rej_dev)
+                    )
+
+            if self.engine == "v3" and not use_rej:
+                used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
+            else:
+                hs = self._unshard_state_v2(state)
+                used = hs.used
+                mc = T.node_space_to_domain(hs.match_count, self._gdom, self._Dhost)
+                aa = T.node_space_to_domain(hs.anti_active, self._gdom, self._Dhost)
+                pw = T.node_space_to_domain(hs.pref_wsum, self._gdom, self._Dhost)
+            util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
+            pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
+            frag = fragmentation_gauges(
+                self.ec.allocatable, used, self.pods.requests[pending_m],
+                self.ec.vocab._r,
+            )
+            host_state = SchedState(
+                used=used, match_count=mc, anti_active=aa, pref_wsum=pw,
+                bound=assignments.copy(),
+            )
+            if rec is not None:
+                # Pager walls join the phase accumulators (keys only present
+                # when paging is on AND the recorder observed them, so the
+                # canonical PHASE_NAMES-only runs are unchanged).
+                # ``pager_stall`` is the EXPOSED wall; ``pager_prefetch`` the
+                # fetch wall itself — hidden under the round-19 thread,
+                # loop-exposed without it.
+                if pager is not None and tel is not None:
+                    tel.phases.add("pager_stall", pager.stall_s)
+                    tel.phases.add("pager_prefetch", pager.prefetch_wall_s)
+                if rec_own:
+                    rec.close({"placed": int(placed)})
+            if pager is not None:
+                pager.close()
         return ReplayResult(
             assignments=assignments,
             placed=placed,

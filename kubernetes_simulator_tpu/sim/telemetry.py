@@ -138,7 +138,10 @@ def latency_summary(
 # (scripts/northstar.py, bench consumers) key on these strings when
 # attributing wall-clock, so they are API: renaming one is a breaking
 # change pinned by tests/test_telemetry.py.
-PHASE_NAMES = ("dispatch", "device_wait", "boundary_fold", "host_mirror")
+PHASE_NAMES = (
+    "stage", "dispatch", "device_wait", "boundary_fold", "host_mirror",
+    "gather",
+)
 
 
 class PhaseTimers:
@@ -203,9 +206,14 @@ class ReplayTelemetry:
     zero_latency_binds: int = 0
     # Timeline events: (kind, t, pod, node) with pod/node = -1 when n/a.
     events: List[Tuple[str, float, int, int]] = field(default_factory=list)
+    # Chunk width the device replay ran: the granularity guard may shrink
+    # the configured one (sim.granularity). None where no chunk loop ran.
+    chunk_waves: Optional[int] = None
 
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
+        if self.chunk_waves is not None:
+            out["chunk_waves"] = self.chunk_waves
         if self.latency is not None:
             out["latency"] = self.latency
         if self.reasons is not None:
@@ -345,8 +353,12 @@ class TelemetryCollector:
     further failed attempts only grow ``rejection_attempts`` until a bind
     or an eviction (``clear_episode``) re-arms it."""
 
-    def __init__(self, config: Optional[TelemetryConfig] = None):
+    def __init__(
+        self, config: Optional[TelemetryConfig] = None,
+        chunk_waves: Optional[int] = None,
+    ):
         self.cfg = TelemetryConfig.resolve(config)
+        self.chunk_waves = chunk_waves
         self.phases = PhaseTimers()
         self._lat: Dict[int, float] = {}
         self._zero = 0
@@ -422,6 +434,7 @@ class TelemetryCollector:
             phases=self.phases.summary(),
             bind_latency=dict(self._lat),
             zero_latency_binds=self._zero,
+            chunk_waves=self.chunk_waves,
         )
         if self.cfg.want_series:
             # Zero entries are dropped so engine comparisons see the same

@@ -41,6 +41,7 @@ from ..parallel.mesh import (
     shard_scenario_tree,
     spans_processes,
 )
+from ..utils.profiling import stage
 from .jax_runtime import StepSpec, make_wave_step
 from .waves import pack_waves
 
@@ -1332,8 +1333,9 @@ class WhatIfEngine:
             )
 
             def per_scenario(dc, state, slots, extra, dyn=None, wvec=None):
-                d = T.Derived.build(dc)
-                cmasks = V3.class_masks(dc, d, st3, spec, reps)
+                with stage("ksim.derive"):
+                    d = T.Derived.build(dc)
+                    cmasks = V3.class_masks(dc, d, st3, spec, reps)
                 wave_step = V3.make_wave_step3(
                     dc, d, sh3, st3, wave_width, spec, cmasks, dyn=dyn,
                     dyn_flip=dyn_flip, wvec=wvec,
@@ -1371,10 +1373,11 @@ class WhatIfEngine:
             # dispatch per chunk, only indices as per-chunk input
             # (scenario-shared → gathered once, not per scenario).
             def per_scenario_src(dc, state, src, xsrc, idx, dyn=None, wvec=None):
-                slots = T.gather_slots_device(src, idx)
                 from ..ops import tpu3 as V3m
 
-                extra = V3m.gather_extra_device(xsrc, idx)
+                with stage("ksim.gather"):
+                    slots = T.gather_slots_device(src, idx)
+                    extra = V3m.gather_extra_device(xsrc, idx)
                 return per_scenario(dc, state, slots, extra, dyn, wvec)
 
             if self._completions_dev:
@@ -1394,11 +1397,12 @@ class WhatIfEngine:
                         dc, state, src, xsrc, idx, dyn, wvec
                     )
                     choices, counts = out
-                    vassign = jax.lax.dynamic_update_slice(
-                        vassign,
-                        choices.reshape(-1),
-                        (b * idx.size,),
-                    )
+                    with stage("ksim.release"):
+                        vassign = jax.lax.dynamic_update_slice(
+                            vassign,
+                            choices.reshape(-1),
+                            (b * idx.size,),
+                        )
                     return state, vassign, counts
 
                 if self.retry_buffer:
@@ -1434,11 +1438,12 @@ class WhatIfEngine:
                         due_p = (pend_id >= 0) & (pend_relb <= b)
                         safe_p = jnp.clip(pend_id, 0)
                         nd_p = jnp.where(due_p, pend_node, -1)
-                        state = rel_core(
-                            state, nd_p, src.requests[safe_p],
-                            mgt[safe_p], antit[safe_p],
-                            preft[safe_p], prefwt[safe_p],
-                        )
+                        with stage("ksim.release"):
+                            state = rel_core(
+                                state, nd_p, src.requests[safe_p],
+                                mgt[safe_p], antit[safe_p],
+                                preft[safe_p], prefwt[safe_p],
+                            )
                         # 2. bounded retry pass: the NORMAL wave step
                         # over the buffer (empty slots are invalid
                         # no-ops), FIFO order preserved by the wave
@@ -1794,49 +1799,50 @@ class WhatIfEngine:
         def rel_one(state, vassign, rel_pos, rel_req, rel_mg,
                     rel_anti, rel_pref, rel_prefw,
                     ov_nodes=None, ov_gdom=None, ov_old=None):
-            node_k = vassign[rel_pos]  # sentinel pos → the PAD tail slot
-            nd = jnp.where(node_k >= 0, node_k, -1)  # -1 matches no node
-            if not dyn_mode:
-                return core(
+            with stage("ksim.release"):
+                node_k = vassign[rel_pos]  # sentinel pos → the PAD tail slot
+                nd = jnp.where(node_k >= 0, node_k, -1)  # -1 matches no node
+                if not dyn_mode:
+                    return core(
+                        state, nd, rel_req, rel_mg, rel_anti, rel_pref,
+                        rel_prefw,
+                    )
+                # DynTables correction layered on the base update: a node the
+                # scenario relabeled releases into its OVERRIDDEN domain (and
+                # base validity doesn't apply — a node that gained the key
+                # releases into the appended domain the bind counted). Uses
+                # the UNMASKED accumulator; old/new one-hots encode validity.
+                state, rc_raw = core(
                     state, nd, rel_req, rel_mg, rel_anti, rel_pref,
-                    rel_prefw,
+                    rel_prefw, want_raw=True,
                 )
-            # DynTables correction layered on the base update: a node the
-            # scenario relabeled releases into its OVERRIDDEN domain (and
-            # base validity doesn't apply — a node that gained the key
-            # releases into the appended domain the bind counted). Uses
-            # the UNMASKED accumulator; old/new one-hots encode validity.
-            state, rc_raw = core(
-                state, nd, rel_req, rel_mg, rel_anti, rel_pref,
-                rel_prefw, want_raw=True,
-            )
-            raw_chunks = jnp.split(rc_raw, nparts, axis=0)
-            safe_ov = jnp.where(ov_nodes >= 0, ov_nodes, 0)
-            ok_ov = (ov_nodes >= 0).astype(jnp.float32)  # [K32]
-            ar_D = jnp.arange(Dcap, dtype=jnp.float32)
-            mk_oh = lambda a: (
-                (a[..., None] == ar_D) & (a[..., None] >= 0)
-            ).astype(jnp.float32)  # [G, K, Dcap]
-            doh = mk_oh(ov_gdom) - mk_oh(ov_old)
+                raw_chunks = jnp.split(rc_raw, nparts, axis=0)
+                safe_ov = jnp.where(ov_nodes >= 0, ov_nodes, 0)
+                ok_ov = (ov_nodes >= 0).astype(jnp.float32)  # [K32]
+                ar_D = jnp.arange(Dcap, dtype=jnp.float32)
+                mk_oh = lambda a: (
+                    (a[..., None] == ar_D) & (a[..., None] >= 0)
+                ).astype(jnp.float32)  # [G, K, Dcap]
+                doh = mk_oh(ov_gdom) - mk_oh(ov_old)
 
-            def corr_of(raw):
-                rv = raw[:, safe_ov] * ok_ov[None, :]  # [G, K32]
-                return jnp.einsum(
-                    "gk,gkd->gd", rv, doh, precision=T._HI
-                )
+                def corr_of(raw):
+                    rv = raw[:, safe_ov] * ok_ov[None, :]  # [G, K32]
+                    return jnp.einsum(
+                        "gk,gkd->gd", rv, doh, precision=T._HI
+                    )
 
-            corr_mc = corr_of(raw_chunks[0])
-            new = {
-                "mc_dom": state.mc_dom - corr_mc,
-                "match_total": state.match_total - corr_mc.sum(-1),
-            }
-            if want_an:
-                new["anti_dom"] = state.anti_dom - corr_of(raw_chunks[1])
-            if want_pf:
-                new["pref_dom"] = state.pref_dom - corr_of(
-                    raw_chunks[1 + want_an]
-                )
-            return state._replace(**new)
+                corr_mc = corr_of(raw_chunks[0])
+                new = {
+                    "mc_dom": state.mc_dom - corr_mc,
+                    "match_total": state.match_total - corr_mc.sum(-1),
+                }
+                if want_an:
+                    new["anti_dom"] = state.anti_dom - corr_of(raw_chunks[1])
+                if want_pf:
+                    new["pref_dom"] = state.pref_dom - corr_of(
+                        raw_chunks[1 + want_an]
+                    )
+                return state._replace(**new)
 
         axes = (
             (0, 0, None, None, None, None, None, None, 0, 0, 0)
@@ -2031,10 +2037,11 @@ class WhatIfEngine:
         state copy per release/boundary chunk. Cached on the engine — jit
         caches by function identity."""
         if self._sub_jit is None:
-            self._sub_jit = jax.jit(
-                lambda s, d: jax.tree.map(jnp.subtract, s, d),
-                donate_argnums=(0,),
-            )
+            def release_subtract(s, d):
+                with stage("ksim.release"):
+                    return jax.tree.map(jnp.subtract, s, d)
+
+            self._sub_jit = jax.jit(release_subtract, donate_argnums=(0,))
         return self._sub_jit(states, delta)
 
     def _apply_stacked_boundary_delta(self, states, subs, adds):

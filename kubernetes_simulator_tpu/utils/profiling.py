@@ -10,16 +10,47 @@
   in XLA traces. Off by default; annotations never change results (pinned
   in tests/test_telemetry.py).
 - ``live_buffer_stats()``: live-buffer / memory watermark gauge.
-- ``timed(fn)``: block-until-ready wall-clock timing harness.
-- ``cost_analysis(jitted, *args)``: XLA cost analysis of a compiled step.
+- ``STAGES`` / ``stage(name)``: the one vocabulary of the device programs'
+  stages, written into the HLO by ``jax.named_scope`` (trace-time metadata,
+  no op). Beneath ``ksim.filter_score`` a plugin's own work sits under its
+  registry name (``ksim.filter_score/PodTopologySpread``).
+- ``register_program(module_name, lower)`` / ``stage_tables()``: device op
+  events in a trace carry the HLO instruction's name (``fusion.628``), the
+  scope sits in the executable's HLO text; the engines hand the programs of
+  an armed replay over by XLA module name, and ``stage_tables()`` joins the
+  two after the run.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
-import time
-from typing import Any, Callable, Optional
+import re
+from typing import Callable, Dict, Optional
+
+#: Stage scopes of the device programs (chunk, release), in program order.
+STAGES = (
+    "ksim.gather",        # slot / extra gathers inside the chunk jit
+    "ksim.derive",        # Derived.build, class_masks: per chunk
+    "ksim.reads",         # wave-start reads of the wave step
+    "ksim.corrections",   # exact in-wave corrections from pods j<k
+    "ksim.filter_score",  # fused Filter + Score, one sub-scope per plugin
+    "ksim.select",        # the pick: extrema, tie-break, pack-select
+    "ksim.preempt",       # victim ranking and eviction marking
+    "ksim.commit",        # wave-end commit, gang rollback mask
+    "ksim.release",       # boundary release programs
+)
+
+# A stage path inside an HLO ``op_name``: the ``ksim.`` component and the
+# CamelCase components after it (plugin names). JAX's own components
+# (primitives, ``jit(..)``, ``while/body``) are lower case.
+_STAGE_PATH = re.compile(r"ksim\.\w+(?:/[A-Z]\w*)*")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+# XLA module name -> thunk lowering the jitted program on the shapes of the
+# call an armed replay made.
+_PROGRAMS: Dict[str, Callable] = {}
 
 
 def profile_dir() -> Optional[str]:
@@ -91,21 +122,80 @@ def device_trace(log_dir: Optional[str]):
         yield
 
 
-def timed(fn: Callable, *args, **kw):
-    """(result, seconds) with device completion awaited."""
+def stage(name: str):
+    """``jax.named_scope`` for a member of :data:`STAGES`."""
     import jax
 
-    t0 = time.perf_counter()
-    out = fn(*args, **kw)
-    jax.block_until_ready(out)
-    return out, time.perf_counter() - t0
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r} (have {STAGES})")
+    return jax.named_scope(name)
 
 
-def cost_analysis(jitted: Callable, *args) -> dict:
-    """FLOP/byte estimates for one compiled step (flattened keys only)."""
-    lowered = jitted.lower(*args)
-    compiled = lowered.compile()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
-    return {k: v for k, v in (ca or {}).items() if isinstance(v, (int, float))}
+def register_program(module_name: str, lower: Callable) -> None:
+    """Remember a device program for :func:`stage_tables`: its XLA module
+    name as a trace prints it (``jit_chunk_fn``) and a thunk that lowers
+    the same jitted function on ``ShapeDtypeStruct``s of the same
+    arguments. Stores the closure; lowers nothing."""
+    _PROGRAMS[module_name] = lower
+
+
+def shape_structs(tree):
+    """``ShapeDtypeStruct`` tree of a call's arguments, for
+    :func:`register_program` thunks: holds no device buffer. A sharding
+    is kept only where it spans devices; on one device it would add
+    attributes to the module that the call itself does not have."""
+    import jax
+
+    def struct(a):
+        sharding = getattr(a, "sharding", None)
+        if sharding is not None and len(sharding.device_set) == 1:
+            sharding = None
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    return jax.tree.map(struct, tree)
+
+
+def parse_stage_table(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: stage path} of one executable's HLO text: the
+    innermost ``ksim.`` scope of its ``op_name`` (a scan issued under
+    ``ksim.gather`` keeps that stage for its own slicing only); an
+    instruction under no ``ksim.`` scope maps to ``""``."""
+    table: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        op = _HLO_OP_NAME.search(line)
+        paths = _STAGE_PATH.findall(op.group(1)) if op else ()
+        table[m.group(1)] = paths[-1] if paths else ""
+    return table
+
+
+def stage_tables() -> Dict[str, Dict[str, str]]:
+    """{module name: {instruction name: stage path}} for every registered
+    program: each is lowered and compiled again and its optimized HLO
+    parsed. Two caches stand between a program and its scopes. The
+    persistent cache's key leaves metadata out, so a hit hands back an
+    executable compiled from whichever tree filled the entry, with other
+    scopes or none: here the key takes the metadata in. And
+    ``Lowered.compile()`` hands back the executable the process already
+    holds for the module unless it is given compiler options: it gets one
+    at its default value. That costs one compile per program and cache
+    directory. Instruction names are the same in all of them: metadata
+    steers no pass."""
+    import jax
+
+    key = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, key)
+    jax.config.update(key, True)
+    try:
+        return {
+            name: parse_stage_table(
+                lower().compile(
+                    compiler_options={"xla_dump_disable_metadata": False}
+                ).as_text()
+            )
+            for name, lower in _PROGRAMS.items()
+        }
+    finally:
+        jax.config.update(key, before)

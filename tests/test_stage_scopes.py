@@ -1,0 +1,156 @@
+"""Stage scopes of the device programs and the replay's host spans
+(utils.profiling STAGES / register_program / stage_tables; telemetry
+PHASE_NAMES): the join a traced run makes between device op events and the
+program's stages, and the phases that cover a replay() call."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+import time
+
+import jax.numpy as jnp
+import pytest
+
+from kubernetes_simulator_tpu.framework.framework import FrameworkConfig
+from kubernetes_simulator_tpu.sim.borg import BorgSpec, make_borg_encoded
+from kubernetes_simulator_tpu.sim.jax_runtime import JaxReplayEngine
+from kubernetes_simulator_tpu.sim.telemetry import PHASE_NAMES
+from kubernetes_simulator_tpu.utils import profiling
+
+
+@pytest.fixture(scope="module")
+def borg():
+    """The benchmark cell's rehearsal size: Borg-shaped tasks with gangs,
+    taints and a zone spread, completions on."""
+    ec, ep, _ = make_borg_encoded(BorgSpec(nodes=64, tasks=4096, seed=0))
+    return ec, ep
+
+
+def _engine(borg, completions=True):
+    return JaxReplayEngine(
+        *borg, FrameworkConfig(), wave_width=8, chunk_waves=16,
+        completions=completions,
+    )
+
+
+def test_stage_tables_after_an_armed_replay(borg, tmp_path, monkeypatch):
+    profiling._PROGRAMS.clear()
+    monkeypatch.setenv("KSIM_PROFILE_DIR", str(tmp_path))
+    eng = _engine(borg)
+    eng.replay()
+    monkeypatch.delenv("KSIM_PROFILE_DIR")
+    # What was registered lowers to the module the replay's own call gives
+    # (the trace's instruction names are that module's).
+    call = (eng.dc, eng._init_dev_state(), eng._slot_src, eng._extra_src,
+            jnp.asarray(eng.waves.idx[:16]))
+    assert (profiling._PROGRAMS["jit_chunk_fn"]().as_text()
+            == eng.chunk_fn.lower(*call).as_text())
+    tables = profiling.stage_tables()
+    assert set(tables) == {"jit_chunk_fn", "jit_release_subtract"}
+    assert set(tables["jit_release_subtract"].values()) == {"", "ksim.release"}
+    chunk = tables["jit_chunk_fn"]
+    ran = {path.split("/")[0] for path in chunk.values()} - {""}
+    # every stage this configuration runs: no preemption, releases apart
+    assert ran == set(profiling.STAGES) - {"ksim.preempt", "ksim.release"}
+    assert {"ksim.filter_score/NodeResourcesFit",
+            "ksim.filter_score/TaintToleration",
+            "ksim.filter_score/PodTopologySpread"} <= set(chunk.values())
+    # The scan sits under ksim.gather, which its own slicing keeps; inside
+    # its body the wave step's primitives (op_name: .../while/body/
+    # closed_call/<scopes>/<primitive>) have to carry a stage of the step's
+    # own and may not fall back to the scan's.
+    text = profiling._PROGRAMS["jit_chunk_fn"]().compile().as_text()
+    step = [m.group(1).split("/while/body/closed_call/", 1)[1]
+            for m in re.finditer(r'op_name="([^"]*)"', text)
+            if "/while/body/closed_call/" in m.group(1)]
+    staged = [op for op in step if "ksim." in op]
+    assert len(step) > 1000 and len(staged) >= 0.9 * len(step)
+
+
+def test_parse_stage_table_takes_the_innermost_scope():
+    text = """
+  %p.1 = f32[8]{0} parameter(0)
+  ROOT %fusion.3 = s32[]{:T(128)} fusion(%a), kind=kLoop, metadata={op_name="jit(chunk_fn)/ksim.gather/while/body/closed_call/ksim.filter_score/NodeResourcesFit/jit(_where)/select_n" source_file="x.py"}
+  %dynamic-slice.7 = f32[1,8]{1,0} dynamic-slice(%b, %i), metadata={op_name="jit(chunk_fn)/ksim.gather/while/body/dynamic_slice"}
+  copy.9 = f32[8]{0} copy(%p.1), metadata={op_name="jit(chunk_fn)/vmap()/while/body/closed_call/ksim.select/reduce"}
+"""
+    assert profiling.parse_stage_table(text) == {
+        "p.1": "", "fusion.3": "ksim.filter_score/NodeResourcesFit",
+        "dynamic-slice.7": "ksim.gather", "copy.9": "ksim.select",
+    }
+    with pytest.raises(ValueError, match="unknown stage"):
+        profiling.stage("ksim.pick")
+
+
+@pytest.mark.parametrize("completions", [False, True],
+                         ids=["plain", "completions"])
+def test_phases_cover_the_replay_call(borg, completions):
+    """The phases are sequential on one thread; what replay() spends
+    outside them (argument checks, the guard, building the result) is
+    small. The best of three calls, so that a stall of the machine in the
+    untimed part does not fail it."""
+    eng = _engine(borg, completions)
+    eng.replay()  # compiles
+    shares = []
+    for _ in range(3):
+        t = time.perf_counter()
+        res = eng.replay()
+        wall = time.perf_counter() - t
+        phases = res.telemetry.phases
+        assert set(phases) <= set(PHASE_NAMES)
+        assert {"stage", "dispatch", "device_wait", "gather"} <= set(phases)
+        assert ("host_mirror" in phases) == completions
+        assert ("boundary_fold" in phases) == completions
+        shares.append(sum(phases.values()) / wall)
+    assert max(shares) >= 0.95, shares
+    assert res.telemetry.summary()["chunk_waves"] == 16
+
+
+def test_summary_records_the_chunk_width_the_guard_left(borg):
+    """Asked for 256 waves a chunk on a trace whose tasks live for about 21
+    waves, the guard shrinks the chunk and says so only in a warning; the
+    width that ran is in the result."""
+    eng = JaxReplayEngine(
+        *borg, FrameworkConfig(), wave_width=8, chunk_waves=256,
+    )
+    ran = eng.replay().telemetry.summary()["chunk_waves"]
+    assert eng.chunk_waves == 256 and 0 < ran < 256
+
+
+_STALE = """
+import contextlib, json, sys
+import jax, jax.numpy as jnp
+from kubernetes_simulator_tpu.utils import profiling
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+scope = profiling.stage if sys.argv[2] == "scoped" else (
+    lambda name: contextlib.nullcontext())
+def f(x):
+    with scope("ksim.reads"):
+        return jnp.sin(x) * 2 + x
+f, x = jax.jit(f), jnp.ones(1024)
+f(x).block_until_ready()  # the scoped tree loads what the other compiled
+profiling.register_program("jit_f", lambda: f.lower(x))
+print(json.dumps(profiling.stage_tables()))
+"""
+
+
+def test_stage_tables_see_through_a_cache_filled_by_another_tree(tmp_path):
+    """The persistent cache's key leaves metadata out: a tree with scopes
+    loads the executable a tree without them compiled (the chip's machine
+    comes with such a cache), and ``Lowered.compile()`` hands that same
+    executable back. ``stage_tables()`` has to get the scopes anyway."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    stages = []
+    for tree in ("plain", "scoped"):
+        out = subprocess.run(
+            [sys.executable, "-c", _STALE, str(tmp_path), tree], env=env,
+            capture_output=True, text=True, timeout=120, check=True,
+            cwd=os.path.dirname(os.path.dirname(__file__)),
+        ).stdout
+        stages.append(set(json.loads(out.splitlines()[-1])["jit_f"].values()))
+        assert list(tmp_path.glob("jit_f-*"))
+    assert stages == [{""}, {"", "ksim.reads"}]

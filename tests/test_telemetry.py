@@ -108,7 +108,8 @@ def test_phase_timer_names_stable():
     emit exactly that set (a rename or a new un-registered phase fails
     here first)."""
     assert PHASE_NAMES == (
-        "dispatch", "device_wait", "boundary_fold", "host_mirror"
+        "stage", "dispatch", "device_wait", "boundary_fold", "host_mirror",
+        "gather",
     )
     ec, ep = _light_trace(duration=10.0)  # releases fire inside the run
     res = JaxReplayEngine(
@@ -560,6 +561,29 @@ def test_profiler_annotations_bit_parity(tmp_path, monkeypatch):
     np.testing.assert_array_equal(off.assignments, on.assignments)
     assert off.telemetry.latency == on.telemetry.latency
     np.testing.assert_array_equal(woff.placed, won.placed)
+    # The plain completions path (the benchmark's): armed, it also hands
+    # its programs to profiling.stage_tables(), which nobody reads here —
+    # registering lowers nothing and changes no answer or checkpoint.
+    profiling._PROGRAMS.clear()
+    ec, ep = _light_trace(num_pods=16, num_nodes=4, duration=4.0)
+    plain = {}
+    for armed in (False, True):
+        if armed:
+            monkeypatch.setenv("KSIM_PROFILE_DIR", str(tmp_path))
+        else:
+            monkeypatch.delenv("KSIM_PROFILE_DIR")
+        ck = tmp_path / f"plain{int(armed)}.npz"
+        plain[armed] = JaxReplayEngine(
+            ec, ep, cfg, wave_width=2, chunk_waves=2,
+        ).replay(checkpoint_path=str(ck), checkpoint_every=3), ck.read_bytes()
+        assert set(profiling._PROGRAMS) == (
+            {"jit_chunk_fn", "jit_release_subtract"} if armed else set()
+        )
+    (poff, ck_off), (pon, ck_on) = plain[False], plain[True]
+    assert poff.placed == 16 and poff.state.used[:, 0].sum() < 16  # released
+    np.testing.assert_array_equal(poff.assignments, pon.assignments)
+    assert poff.telemetry.latency == pon.telemetry.latency
+    assert ck_off == ck_on
     np.testing.assert_array_equal(
         np.asarray(woff.latency_p50, np.float64),
         np.asarray(won.latency_p50, np.float64),
