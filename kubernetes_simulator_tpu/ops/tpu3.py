@@ -12,11 +12,21 @@ scenario count. v3 restructures the STATE, not the semantics:
   topologies (domain ≈ node), kept per *referenced plane section* so a
   trace with no such terms (Borg shape) carries none.
 - **Wave-deferred commits**: within a wave the carried tensors are never
-  rewritten; each pod's evaluation adds exact in-wave correction terms
-  (rank-1 in the bound node / bound domain) for the pods before it, and
-  the wave commits once — with the gang all-or-nothing mask folded in, so
-  rollback is free. ``used`` is read once per pod (the unavoidable fit
-  stream) but written once per wave.
+  rewritten; each pod's evaluation adds exact in-wave corrections for the
+  pods before it, and the wave commits once — with the gang all-or-nothing
+  mask folded in, so rollback is free. ``used`` is read once per pod (the
+  unavoidable fit stream) but written once per wave. The COUNT corrections
+  are rank-1 terms in the bound domain (domain space, a few KB). The USAGE
+  correction is one running ``[R, N]`` plane a wave: zero at wave start,
+  and each slot, once it has chosen, adds its request at its one chosen
+  node (read, add and write back the node's 128-lane block in place).
+  Slot k reads ``used + plane + req``: the f32 association of summing the
+  k earlier slots' one-hot terms from zero, at a cost that does not grow
+  with k. (The k-term form re-evaluates those k terms over all N inside
+  both [N]-wide reduces of slot k: on a v5e at N=10,000 the spread reduce
+  took 1.5 µs in slot 0 and 9.2 in slot 7, PERF.md §5.) The tier-
+  preemption program and the vmapped what-if program keep the k-term
+  form — see :func:`inwave_corrections`.
 - **Node-value expansion** of domain-space rows rides a fused masked-sum
   over the ≤Dcap domains (``val[n] = rows[dom(n)]`` without gathers, which
   serialize on TPU — measured 100× slower than the arithmetic forms).
@@ -920,7 +930,7 @@ def class_masks(dc: DevCluster, d: Derived, st: V3Static, spec, rep_slots):
 def make_wave_step3(
     dc: DevCluster, d: Derived, sh: Shared3, st: V3Static,
     wave_width: int, spec, cmasks=None, dyn: Optional[DynTables] = None,
-    dyn_flip: bool = True, wvec=None,
+    dyn_flip: bool = True, wvec=None, scenario_axis: bool = False,
 ):
     """Scan body over (PodSlot, SlotExtra) wave batches. Bit-identical to
     the v2 step; see module docstring for the traffic model. ``cmasks``:
@@ -929,7 +939,10 @@ def make_wave_step3(
     shared; corrections apply as K-term fused elementwise updates.
     ``wvec``: optional traced policy vector (T2.POLICY_COLS) replacing the
     static score weights — the round 9 tuner's population axis; disables
-    the packed select (its integer-weight bound needs static weights)."""
+    the packed select (its integer-weight bound needs static weights).
+    ``scenario_axis``: the caller maps the step over a scenario axis
+    (``vmap``) — a static fact of how the program is built, which picks the
+    form of the in-wave usage corrections (:func:`inwave_corrections`)."""
     cmasks = cmasks or {}
     G = st.G
     Dcap = st.Dcap
@@ -959,6 +972,7 @@ def make_wave_step3(
         or (st.SP and (st.has_dns or not spread_dom_hilo))
     )
     pack_select = wvec is None and pack_select_ok(spec, w_cfg, dc.allocatable.shape[0])
+    corr_plane = inwave_corrections(st, scenario_axis) == "plane"
 
     def wave_step(carry: DevState3, batch):
         sb, sx = batch
@@ -1067,14 +1081,25 @@ def make_wave_step3(
                 ev_total = jnp.zeros((), jnp.float32)
                 eu_acc = [jnp.zeros((), jnp.float32) for _ in range(R)]
                 evicted = []  # per-slot "evicted mid-wave" flags
+            if corr_plane:
+                # The wave's usage corrections, one running [R, N] plane
+                # (N padded to whole 128-lane blocks): zero here, and each
+                # slot adds its request at its ONE chosen node once it has
+                # chosen (below). Slot k reads it as it stands, whatever k:
+                # the [N]-wide reduces of a slot cost the same in slot 7 as
+                # in slot 0.
+                used_corr = jnp.zeros((R, -(-N // 128) * 128), jnp.float32)
+                lane = jnp.arange(128, dtype=jnp.int32)
         choices, placeds, dom_ats = [], [], []
         for k in range(wave_width):
             with stage("ksim.reads"):
                 s = jax.tree.map(lambda a: a[k], sb)
 
             # --- exact in-wave corrections from pods j<k -----------------
-            # One-hots are rebuilt from the chosen-node index inside the
-            # consuming fusions (never materialized as carried values).
+            # Usage: the running plane, or (preemption, scenario batch) k
+            # one-hot terms rebuilt from the chosen-node indices inside the
+            # consuming fusions — see inwave_corrections(). Counts: domain-
+            # space or host-row terms, never materialized as carried values.
             with stage("ksim.corrections"):
                 rows_corr = jnp.zeros((st.KT, Dcap), jnp.float32) if st.KT else None
                 valh_corr = (
@@ -1083,7 +1108,10 @@ def make_wave_step3(
                     else None
                 )
                 tot_corr = jnp.zeros((st.KT,), jnp.float32) if st.KT else None
-                used_corr_r = [jnp.zeros((N,), jnp.float32) for _ in range(R)]
+                if corr_plane:
+                    used_corr_r = [used_corr[r, :N] for r in range(R)]
+                else:
+                    used_corr_r = [jnp.zeros((N,), jnp.float32) for _ in range(R)]
                 if st.preemption and k > 0:
                     # An earlier in-wave eviction frees wave-start usage at the
                     # evicted node (evicted slots are excluded below).
@@ -1099,9 +1127,12 @@ def make_wave_step3(
                         wj_used = wj * (1.0 - evicted[j].astype(jnp.float32))
                     else:
                         wj_used = wj
-                    oh_j = (iota_n == choices[j]).astype(jnp.float32)
-                    for r in range(R):
-                        used_corr_r[r] = used_corr_r[r] + wj_used * oh_j * sb.req[j, r]
+                    if not corr_plane:
+                        oh_j = (iota_n == choices[j]).astype(jnp.float32)
+                        for r in range(R):
+                            used_corr_r[r] = (
+                                used_corr_r[r] + wj_used * oh_j * sb.req[j, r]
+                            )
                     # Count corrections below keep evicted slots (phantom rule).
                     if st.KT:
                         # domain of j's bound node under row (k, r)'s group
@@ -1196,6 +1227,13 @@ def make_wave_step3(
                 # at the same read cost, and skipping the barrier removes the
                 # R×[S, N] write per pod (~14% of device time on the profile).
                 # Preemption still materializes (prefit re-reads used1_r).
+                # PR 26: in the single replay XLA materializes it by itself
+                # once the usage plane is a carried array (one fusion a
+                # slot feeds both reduces, and they run 3-14× faster for
+                # it); and the barrier below is dead — its result is
+                # overwritten at `feasible = fit_ok & nonfit`, so jax drops
+                # it at lowering. What to materialize per slot is ROADMAP
+                # S1.5's question.
                 if st.preemption:
                     used1_r = list(jax.lax.optimization_barrier(tuple(used1_r)))
                 feasible = jax.lax.optimization_barrier(feasible)
@@ -1502,6 +1540,28 @@ def make_wave_step3(
                 else:
                     node, _ = select_node(total, feasible)
                 placed = any_f & s.valid
+            if corr_plane and k + 1 < wave_width:
+                with stage("ksim.corrections"):
+                    # Point update: R values at the chosen node, as the
+                    # node's 128-lane block read, added to and written back
+                    # in place. (The slice starts on a lane-block boundary:
+                    # sliced at the node itself, an [R, 1] column, XLA lays
+                    # the whole plane out node-major and copies around
+                    # every consumer.) `where(here, req, 0)` is the k-term
+                    # form's `placed * one_hot * req` to the bit; the
+                    # block's other nodes add 0.0, as every node did there.
+                    # An unplaced slot's node is PAD (a dynamic slice would
+                    # wrap -1 to the last node): it lands on node 0 with
+                    # nothing to add. Two slots on one node add in slot
+                    # order.
+                    at = jnp.clip(node, 0)
+                    lo = jax.lax.bitwise_and(at, ~127)
+                    blk = jax.lax.dynamic_slice(used_corr, (0, lo), (R, 128))
+                    here = placed & (lane == at - lo)
+                    add = jnp.where(here[None, :], s.req[:, None], 0.0)
+                    used_corr = jax.lax.dynamic_update_slice(
+                        used_corr, blk + add, (0, lo)
+                    )
             if st.preemption:
                 with stage("ksim.preempt"):
                     tier_k = sx.tier[k]  # shared scalar
@@ -1802,6 +1862,29 @@ def make_wave_step3(
         return new_state, final
 
     return wave_step
+
+
+def inwave_corrections(st: V3Static, scenario_axis: bool = False) -> str:
+    """Which form of the in-wave USAGE corrections a step built from ``st``
+    carries — static per compiled program; a replay reports it as
+    ``telemetry.summary()["inwave_corrections"]``.
+
+    ``"plane"``: one running ``[R, N]`` plane a wave, updated at each slot's
+    chosen node (module docstring). ``"terms"``: slot k rebuilds k one-hot
+    terms from the chosen-node indices, fused into every ``[N]``-wide
+    consumer. Two programs keep the terms, both by a fact of how they are
+    built and neither by a switch:
+
+    - tier preemption: its correction starts from the eviction term and
+      drops evicted slots retroactively, an order of f32 additions a
+      running sum cannot reproduce bit for bit;
+    - a step mapped over a scenario axis (the what-if batch): there the
+      point update is a gather and a scatter of one block per scenario,
+      which a v5e runs 17x slower than the whole fused-terms program (128
+      scenarios x 10,000 nodes: 148 s a batch against 8.7, PERF.md §6
+      PR 26), and a plane written out whole is R x [S, N] of HBM traffic
+      per slot."""
+    return "terms" if (st.preemption or scenario_axis) else "plane"
 
 
 def pack_select_ok(spec, w_cfg, n_nodes: int) -> bool:

@@ -1497,6 +1497,15 @@ class JaxReplayEngine:
             self._sub_jit = jax.jit(release_subtract, donate_argnums=(0,))
         return self._sub_jit
 
+    def _inwave_corrections(self) -> Optional[str]:
+        """The form of in-wave usage corrections the resident chunk program
+        was built with; the v2 step binds per pod and has none."""
+        if self.engine != "v3":
+            return None
+        from ..ops import tpu3 as V3
+
+        return V3.inwave_corrections(self.static3)
+
     def _register_programs(self, state, idx_chunks, release: bool) -> None:
         """With profiling armed: hand the resident v3 chunk program this
         replay is about to call (and its release program) to
@@ -1615,7 +1624,10 @@ class JaxReplayEngine:
         fw = SchedulerFramework(self.ec, self.pods, cfg)
         lazy = self.lazy_boundary
         tel = (
-            TelemetryCollector(self.telemetry_cfg, chunk_waves=C)
+            TelemetryCollector(
+                self.telemetry_cfg, chunk_waves=C,
+                inwave_corrections=self._inwave_corrections(),
+            )
             if self.telemetry_cfg.enabled
             else None
         )
@@ -2134,7 +2146,10 @@ class JaxReplayEngine:
         from ..utils.metrics import log
 
         tel = (
-            TelemetryCollector(self.telemetry_cfg, chunk_waves=C)
+            TelemetryCollector(
+                self.telemetry_cfg, chunk_waves=C,
+                inwave_corrections=self._inwave_corrections(),
+            )
             if self.telemetry_cfg.enabled
             else None
         )

@@ -209,11 +209,16 @@ class ReplayTelemetry:
     # Chunk width the device replay ran: the granularity guard may shrink
     # the configured one (sim.granularity). None where no chunk loop ran.
     chunk_waves: Optional[int] = None
+    # Form of the in-wave usage corrections the v3 chunk program was built
+    # with (ops.tpu3.inwave_corrections): "plane" or "terms". None for v2.
+    inwave_corrections: Optional[str] = None
 
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
         if self.chunk_waves is not None:
             out["chunk_waves"] = self.chunk_waves
+        if self.inwave_corrections is not None:
+            out["inwave_corrections"] = self.inwave_corrections
         if self.latency is not None:
             out["latency"] = self.latency
         if self.reasons is not None:
@@ -356,9 +361,11 @@ class TelemetryCollector:
     def __init__(
         self, config: Optional[TelemetryConfig] = None,
         chunk_waves: Optional[int] = None,
+        inwave_corrections: Optional[str] = None,
     ):
         self.cfg = TelemetryConfig.resolve(config)
         self.chunk_waves = chunk_waves
+        self.inwave_corrections = inwave_corrections
         self.phases = PhaseTimers()
         self._lat: Dict[int, float] = {}
         self._zero = 0
@@ -435,6 +442,7 @@ class TelemetryCollector:
             bind_latency=dict(self._lat),
             zero_latency_binds=self._zero,
             chunk_waves=self.chunk_waves,
+            inwave_corrections=self.inwave_corrections,
         )
         if self.cfg.want_series:
             # Zero entries are dropped so engine comparisons see the same

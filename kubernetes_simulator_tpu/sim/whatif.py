@@ -1338,7 +1338,7 @@ class WhatIfEngine:
                     cmasks = V3.class_masks(dc, d, st3, spec, reps)
                 wave_step = V3.make_wave_step3(
                     dc, d, sh3, st3, wave_width, spec, cmasks, dyn=dyn,
-                    dyn_flip=dyn_flip, wvec=wvec,
+                    dyn_flip=dyn_flip, wvec=wvec, scenario_axis=True,
                 )
 
                 def step(st, batch):
@@ -1430,7 +1430,8 @@ class WhatIfEngine:
                         d = T.Derived.build(dc)
                         cmasks = V3.class_masks(dc, d, st3, spec, reps)
                         wave_step = V3.make_wave_step3(
-                            dc, d, sh3, st3, wave_width, spec, cmasks
+                            dc, d, sh3, st3, wave_width, spec, cmasks,
+                            scenario_axis=True,
                         )
                         # 1. releases of retried-placed pods whose
                         # boundary arrived (relb encodes the f32 time

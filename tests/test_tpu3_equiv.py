@@ -147,3 +147,149 @@ def test_bf16_host_planes_disabled_under_capacity_events():
     res = eng.replay(node_events=ev)
     assert not (eng.static3.mc_h_bf16 or eng.static3.anti_h_bf16)
     assert res.placed > 0
+
+
+# --- in-wave usage corrections: the running plane (ops.tpu3) -------------
+# One wave of 8 slots on two nodes, built so that a slot's choice depends
+# on what the earlier slots of ITS wave used. Requests are cpu only; with
+# LeastAllocated the big node wins until it is full.
+
+def _mini(node_cpus, pod_cpus, groups=None, gangs=None):
+    from kubernetes_simulator_tpu.models.core import Cluster, Node, Pod, PodGroup
+
+    nodes = [
+        Node(f"n{i}", capacity={"cpu": c, "memory": 8 * 2**30, "pods": 110})
+        for i, c in enumerate(node_cpus)
+    ]
+    pods = [
+        Pod(f"p{i}", requests={"cpu": c}, arrival_time=float(i),
+            pod_group=(groups or {}).get(i))
+        for i, c in enumerate(pod_cpus)
+    ]
+    pod_groups = {g: PodGroup(g, m) for g, m in (gangs or {}).items()}
+    return encode(Cluster(nodes=nodes, pod_groups=pod_groups), pods)
+
+
+_BIG = 10 ** 6  # cpus: fits nowhere, so the slot's choice is PAD
+# name -> (node cpus, pod cpus, pod -> gang, gang -> min members, placements)
+_WAVE_TRAPS = {
+    # two / three slots bind to n1, the next no longer fits there
+    "same_node_x2": ([3.0, 7.0], [3.0] * 4, None, None, [1, 1, 0, -1]),
+    "same_node_x3": ([3.0, 10.0], [3.0] * 5, None, None, [1, 1, 1, 0, -1]),
+    # an unplaced slot ahead of a placed one whose only node is the
+    # FIRST (a clamped point update lands there) / the LAST (a wrapped
+    # one) and has room for exactly that pod
+    "pad_then_node0": ([1.0, 0.5], [_BIG, 1.0, 1.0], None, None, [-1, 0, -1]),
+    "pad_then_last": ([0.5, 0.5, 1.0], [_BIG, 1.0, 1.0], None, None, [-1, 2, -1]),
+    # a gang whose first two members fill n0 for the slots behind them
+    # and whose third fits nowhere: rolled back at wave end, so the
+    # next wave finds n0 empty
+    "gang_rollback": (
+        [7.0, 3.0], [3.0, 3.0, _BIG] + [3.0] * 8,
+        {0: "g", 1: "g", 2: "g"}, {"g": 3},
+        [-1, -1, -1, 1, -1, -1, -1, -1, 0, 0, -1],
+    ),
+}
+
+
+@pytest.mark.parametrize("trap", sorted(_WAVE_TRAPS))
+def test_v3_inwave_usage_traps(trap):
+    node_cpus, pod_cpus, groups, gangs, want = _WAVE_TRAPS[trap]
+    ec, ep = _mini(node_cpus, pod_cpus, groups, gangs)
+    v3 = _assert_same(ec, ep)
+    np.testing.assert_array_equal(v3.assignments, want)
+    assert v3.telemetry.summary()["inwave_corrections"] == "plane"
+
+
+def _nondyadic(seed, n_nodes=5, n_pods=96):
+    """Contended, requests that no f32 holds exactly (0.1, 0.3, 0.7 ...):
+    a sum's last bit depends on the order it was added in."""
+    rng = np.random.default_rng(seed)
+    cpus = rng.choice([0.1, 0.3, 0.7, 1.1, 1.3], size=n_pods)
+    return _mini(list(rng.choice([3.3, 4.7, 6.1], size=n_nodes)), list(cpus))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_v3_plane_equals_terms_bit_for_bit(seed, monkeypatch):
+    """The plane accumulates from zero in slot order and is added to
+    ``used`` before the slot's own request: the k-term form's association.
+    Built both ways on one trace, the placements and the final ``used``
+    plane have to be equal to the bit."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    ec, ep = _nondyadic(seed)
+    cfg = FrameworkConfig()
+    plane = JaxReplayEngine(ec, ep, cfg, engine="v3").replay()
+    monkeypatch.setattr(V3, "inwave_corrections", lambda *a, **k: "terms")
+    terms = JaxReplayEngine(ec, ep, cfg, engine="v3").replay()
+    assert plane.telemetry.summary()["inwave_corrections"] == "plane"
+    assert terms.telemetry.summary()["inwave_corrections"] == "terms"
+    assert plane.unschedulable > 0  # contended: fit edges are met
+    np.testing.assert_array_equal(plane.assignments, terms.assignments)
+    np.testing.assert_array_equal(plane.state.used, terms.state.used)
+
+
+def _node_wide_compares(ec, ep, wave_width):
+    """How many [N]-wide integer equality tests (node iota against a chosen
+    node) one traced chunk program of width ``wave_width`` holds: the jaxpr
+    walked through every nested jaxpr (pjit, scan, closed_call)."""
+    import jax
+    import jax.numpy as jnp
+
+    eng = JaxReplayEngine(
+        ec, ep, FrameworkConfig(), engine="v3", wave_width=wave_width
+    )
+    N = ec.num_nodes
+    args = (eng.dc, eng._init_dev_state(), eng._slot_src, eng._extra_src,
+            jnp.asarray(eng.waves.idx[:2]))
+
+    def count(jaxpr):
+        n = 0
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "eq"
+                    and eqn.outvars[0].aval.shape == (N,)
+                    and jnp.issubdtype(eqn.invars[0].aval.dtype, jnp.integer)):
+                n += 1
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        n += count(sub)
+        return n
+
+    return count(jax.make_jaxpr(eng.chunk_fn)(*args).jaxpr)
+
+
+def test_v3_node_wide_compares_grow_linearly_with_wave_width(monkeypatch):
+    """The plane form tests the node iota against a chosen node only in the
+    wave-end ``used`` update (W x R times); the k-term form adds one test
+    for every earlier slot of every slot, W(W-1)/2. Keeps a refactor from
+    fusing the terms back in."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    ec, ep = _mini([4.0] * 37, [1.0] * 64)
+    plane = {w: _node_wide_compares(ec, ep, w) for w in (2, 4, 8)}
+    assert plane[2] > 0
+    assert plane[8] - plane[4] == 2 * (plane[4] - plane[2]), plane
+    monkeypatch.setattr(V3, "inwave_corrections", lambda *a, **k: "terms")
+    terms = {w: _node_wide_compares(ec, ep, w) for w in (2, 4, 8)}
+    assert {w: terms[w] - plane[w] for w in terms} == {2: 1, 4: 6, 8: 28}
+
+
+@pytest.mark.parametrize(
+    "preemption, scenario_axis, form",
+    [(False, False, "plane"), (True, False, "terms"), (False, True, "terms")],
+    ids=["replay", "tier-preemption", "scenario-axis"],
+)
+def test_inwave_corrections_form_follows_how_the_step_is_built(
+    preemption, scenario_axis, form
+):
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+    from kubernetes_simulator_tpu.sim.jax_runtime import StepSpec
+
+    ec, ep = _mini([4.0, 4.0], [1.0] * 4)
+    st = V3.V3Static.build(
+        ec, ep, StepSpec.from_config(ec, FrameworkConfig(), ep),
+        preemption=preemption,
+    )
+    assert V3.inwave_corrections(st, scenario_axis) == form
