@@ -141,6 +141,8 @@ def latency_summary(
 PHASE_NAMES = (
     "stage", "dispatch", "device_wait", "boundary_fold", "host_mirror",
     "gather",
+    # what-if only: the device-release path's placements to the host
+    "handback",
 )
 
 
@@ -212,13 +214,19 @@ class ReplayTelemetry:
     # Form of the in-wave usage corrections the v3 chunk program was built
     # with (ops.tpu3.inwave_corrections): "plane" or "terms". None for v2.
     inwave_corrections: Optional[str] = None
+    # What-if batches only: scenarios evaluated; on the device-release
+    # path the pow2 widths its release program ran with, and the bytes of
+    # the placement hand-back (0 when placements were not asked for).
+    scenarios: Optional[int] = None
+    release_buckets: Optional[List[int]] = None
+    handback_bytes: Optional[int] = None
 
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
-        if self.chunk_waves is not None:
-            out["chunk_waves"] = self.chunk_waves
-        if self.inwave_corrections is not None:
-            out["inwave_corrections"] = self.inwave_corrections
+        for key in ("chunk_waves", "inwave_corrections", "scenarios",
+                    "release_buckets", "handback_bytes"):
+            if getattr(self, key) is not None:
+                out[key] = getattr(self, key)
         if self.latency is not None:
             out["latency"] = self.latency
         if self.reasons is not None:
@@ -345,6 +353,20 @@ class ReplayTelemetry:
         tel.reasons = _sum_counters("reasons")
         tel.rejection_attempts = _sum_counters("rejection_attempts")
         tel.series = series
+        # Engine-level counters: parts are disjoint scenario blocks of one
+        # batch (or none carries them).
+        widths = {p.chunk_waves for _, p in keep}
+        if len(widths) == 1:
+            tel.chunk_waves = widths.pop()
+        for key in ("scenarios", "handback_bytes"):
+            have = [getattr(p, key) for _, p in keep
+                    if getattr(p, key) is not None]
+            if have:
+                setattr(tel, key, sum(have))
+        buckets = [p.release_buckets for _, p in keep
+                   if p.release_buckets is not None]
+        if buckets:
+            tel.release_buckets = sorted(set().union(*buckets))
         return tel
 
 

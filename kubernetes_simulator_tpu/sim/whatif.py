@@ -41,6 +41,7 @@ from ..parallel.mesh import (
     shard_scenario_tree,
     spans_processes,
 )
+from ..utils.profiling import register_call as _register_call
 from ..utils.profiling import stage
 from .jax_runtime import StepSpec, make_wave_step
 from .waves import pack_waves
@@ -525,7 +526,16 @@ class WhatIfEngine:
         node_shards: int = 0,
         _dcn_recovery: Optional[dict] = None,
     ):
-        """``fork_checkpoint``: path to a JaxReplayEngine checkpoint — the
+        """``collect_assignments``: hand back every task's node in
+        ``WhatIfResult.assignments`` ([S, P], -1 = none). It picks no
+        path: on the device-release path the placements are copied from
+        the on-device wave-order buffer once, when the last chunk is done
+        (the ``handback`` phase); on the other paths they come from the
+        per-chunk choices those paths fetch anyway. The device
+        ``retry_buffer`` refuses it (retried placements are not kept per
+        task on the device).
+
+        ``fork_checkpoint``: path to a JaxReplayEngine checkpoint — the
         what-if FORK POINT (SURVEY.md §5 checkpoint/resume): every scenario
         starts from that replay's mid-trace state and continues with its own
         perturbed cluster over the remaining waves.
@@ -956,8 +966,7 @@ class WhatIfEngine:
             # corrections would need the override tables threaded through
             # every bucketed release call's shard specs.
             dev_ok = bool(
-                not collect_assignments
-                and not preemption
+                not preemption
                 and not self.kube  # BoundaryOps owns releases in kube mode
                 and fork_checkpoint is None
                 and (self.mesh is None or self._dyn is None)
@@ -981,12 +990,10 @@ class WhatIfEngine:
             blockers.append("device tier preemption under a mesh")
         if self._dyn is not None and not dev_ok:
             # _dyn is only set with fork_checkpoint None and engine v3,
-            # so the failing dev_ok condition is one of these three.
+            # so the failing dev_ok condition is one of these two.
             why = []
             if self.mesh is not None:
                 why.append("mesh")
-            if collect_assignments:
-                why.append("collect_assignments")
             if not why:
                 why.append("non-singleton host-scale count planes")
             blockers.append(
@@ -1033,8 +1040,6 @@ class WhatIfEngine:
             why = []
             if self.mesh is not None and self._dyn is not None:
                 why.append("mesh with label-perturbation DynTables")
-            if collect_assignments:
-                why.append("collect_assignments")
             if preemption:
                 why.append("preemption (eager eviction-aware folds)")
             if self.kube:
@@ -1088,15 +1093,30 @@ class WhatIfEngine:
                 raise ValueError(
                     "retry_buffer requires the device-release completions "
                     "path (v3 engine, finite durations, no "
-                    "collect_assignments/preemption/fork, singleton "
-                    "host-scale topologies) without label-perturbation "
-                    "DynTables (meshes are supported since round 10)"
+                    "preemption/fork, singleton host-scale topologies) "
+                    "without label-perturbation DynTables (meshes are "
+                    "supported since round 10)"
+                )
+            if collect_assignments and not self.kube:
+                # A placement made by the boundary retry pass never enters
+                # the on-device placement buffer (its release rides the
+                # pending list), so that buffer handed back would call a
+                # retried task unplaced.
+                raise ValueError(
+                    "collect_assignments is not supported with the device "
+                    "retry_buffer: retried placements are not kept per "
+                    "task on the device. Use preemption='kube' (host "
+                    "mirrors) or greedy_replay(retry_buffer=...) for "
+                    "per-task placements under retry"
                 )
         # Host-side completions need per-scenario choices even when the
-        # caller only wants counts; the device path never fetches them.
-        # kube mode folds every chunk into the host mirrors.
-        self._need_choices = collect_assignments or self.kube or (
-            self.completions_on and not self._completions_dev
+        # caller only wants counts; the device-release path never fetches
+        # them per chunk: asked for placements, it hands its on-device
+        # placement buffer back once, at the end of run(). kube mode folds
+        # every chunk into the host mirrors.
+        self._need_choices = self.kube or (
+            not self._completions_dev
+            and (collect_assignments or self.completions_on)
         )
         # Per-scenario policy vectors (round 9 tuner). Validated AFTER the
         # retry/granularity resolution above: the gates below read the
@@ -1132,6 +1152,10 @@ class WhatIfEngine:
                 )
             self._policies = pol
         self._rel_fn_cache: Dict[tuple, Callable] = {}
+        # run()'s small programs (state and vassign broadcasts, the count
+        # reductions, utilization): built at first use, kept here, so a
+        # second run() traces and compiles nothing.
+        self._run_jits: Dict[str, Callable] = {}
         self._rel_core: Optional[Callable] = None
         self._dev_rel_stage: Optional[dict] = None
         self._chunk_fn = self._build_chunk_fn()
@@ -1151,6 +1175,16 @@ class WhatIfEngine:
             if self.mesh is not None:
                 srcs = replicate_tree(self.mesh, srcs)
             self._slot_srcs = srcs
+
+    @property
+    def release_path(self) -> Optional[str]:
+        """Where this batch's completions release: ``"device"`` (the
+        bucketed release program over the on-device placement buffer),
+        ``"host"`` (per-chunk choice fetches and host deltas, kube
+        boundary passes among them), or None for an arrivals-only batch."""
+        if not self.completions_on:
+            return None
+        return "device" if self._completions_dev else "host"
 
     def set_policies(self, policies) -> None:
         """Swap the per-scenario policy VECTORS without rebuilding the
@@ -1865,7 +1899,13 @@ class WhatIfEngine:
                 out_specs=sh,
                 check_vma=False,
             )
-        fn = jax.jit(fn_v, donate_argnums=(0,))
+        # One XLA module name per bucket, whatever vmap or shard_map would
+        # call their wrapper: a trace finds the release program by it.
+        def whatif_release(*args):
+            return fn_v(*args)
+
+        whatif_release.__name__ = f"whatif_release_k{K}"
+        fn = jax.jit(whatif_release, donate_argnums=(0,))
         self._rel_fn_cache[key] = fn
         return fn
 
@@ -1913,6 +1953,15 @@ class WhatIfEngine:
         host = init_state(self.ec, self.pods)  # pre-bound pods
         return host.used, host.match_count
 
+    def _jit_once(self, name: str, build: Callable) -> Callable:
+        """``build()``'s jitted function, made once per engine: jit caches
+        by function identity, so a ``jax.jit(lambda ...)`` written inline
+        in run() is a new program, and a compile, in every call."""
+        fn = self._run_jits.get(name)
+        if fn is None:
+            fn = self._run_jits[name] = build()
+        return fn
+
     def _init_states(self) -> T.DevState:
         self._load_fork_or_init()  # sets fork bookkeeping
         if self.fork_checkpoint:
@@ -1934,11 +1983,11 @@ class WhatIfEngine:
             # ONE jitted broadcast dispatch instead of a jnp.repeat
             # round-trip per leaf.
             S = self.S
-            return jax.jit(
+            return self._jit_once("states", lambda: jax.jit(
                 lambda s: jax.tree.map(
                     lambda a: jnp.broadcast_to(a[None], (S,) + a.shape), s
                 )
-            )(one)
+            ))(one)
         G, D = host.match_count.shape[0], self.D
         # Domain dim may have grown (label perturbations) → pad.
         mc = np.zeros((G, D), np.float32)
@@ -2230,6 +2279,25 @@ class WhatIfEngine:
             x = self._replicate_fn(x)
         return np.asarray(x)
 
+    def _handback(self, vassign_d) -> Tuple[np.ndarray, int]:
+        """(assignments [S, P], bytes copied): the device-release path's
+        placements, task by task. ``vassign`` holds them in wave order
+        (and the pre-bound tasks in its tail); the static ``pos`` map of
+        ``_stage_dev_rel`` puts them into task order on the device (one
+        gather), and the result comes to the host in one copy. On a v5e
+        at 128 x 131,072: 0.03 s, against 0.29 s for the copy first and
+        ``np.take`` on the host (PERF.md §6, PR 27)."""
+        def build():
+            pos = jnp.asarray(self._dev_rel_stage["pos"])
+
+            def whatif_handback(vassign):
+                return jnp.take(vassign, pos, axis=1)
+
+            return jax.jit(whatif_handback)
+
+        out = self._fetch(self._jit_once("handback", build)(vassign_d))
+        return out, int(out.nbytes)
+
     def _stage_dev_rel(self, idx: np.ndarray, C: int) -> dict:
         """Host bucketing + device staging for the device-release path —
         all static per engine (wave packing, durations, chunk layout), so
@@ -2339,6 +2407,9 @@ class WhatIfEngine:
             "rel_calls": rel_calls,
             "b_c": [jnp.asarray(np.int32(bb)) for bb in range(nchunks)],
             "va": jnp.asarray(va),
+            # Each task's slot in vassign, for the hand-back (a task in no
+            # wave and not pre-bound reads the PAD sentinel).
+            "pos": np.where(pos_of >= 0, pos_of, SENT).astype(np.int32),
         }
         if self.retry_buffer:
             stg["mgt"] = jnp.asarray(matched.astype(np.int32))
@@ -2698,6 +2769,28 @@ class WhatIfEngine:
             return self._run_workqueue()
         if self._dcn_spare:
             return self._run_spare()
+        # Engine-level wall-clock phase breakdown (round 12): the what-if
+        # chunk loop gets the same PHASE_NAMES timers the single-replay
+        # paths carry, feeding heartbeats, the fleet telemetry merge, and
+        # the bench `phases` detail. ``stage`` runs from here to the first
+        # chunk's boundary work; it is opened and closed by hand so that
+        # the code between keeps its indentation.
+        import contextlib as _ctxlib
+
+        from .telemetry import PhaseTimers, ReplayTelemetry
+        from ..utils.profiling import annotate as _prof_ann
+        from ..utils.profiling import profiling_active as _prof_on
+
+        run_phases = PhaseTimers()
+        _null = _ctxlib.nullcontext()
+        _prof = _prof_on()
+        _cann = (
+            (lambda i: _prof_ann(f"chunk:{i}")) if _prof else (lambda i: _null)
+        )
+        _pann = _prof_ann if _prof else (lambda name: _null)
+        _t_stage = time.perf_counter()
+        _stage_ann = _pann("stage")
+        _stage_ann.__enter__()
         states = self._init_states()  # sets fork bookkeeping first
         idx = self.waves.idx
         if self._fork_waves_done:
@@ -2728,12 +2821,13 @@ class WhatIfEngine:
             # vassign is donated through the chunk calls — fresh per run.
             # Under a mesh it materializes SHARDED (each device holds its
             # scenarios' buffer; the broadcast never builds a global copy).
-            _bc = lambda a: jnp.broadcast_to(a[None], (self.S,) + a.shape)
-            vassign_d = (
+            S = self.S
+            _bc = lambda a: jnp.broadcast_to(a[None], (S,) + a.shape)
+            vassign_d = self._jit_once("vassign", lambda: (
                 jax.jit(_bc, out_shardings=scenario_sharding(self.mesh))
                 if self.mesh is not None
                 else jax.jit(_bc)
-            )(stg["va"])
+            ))(stg["va"])
             if self.retry_buffer:
                 RB = self.retry_buffer
                 mgt_d, durt_d = stg["mgt"], stg["durt"]
@@ -3065,15 +3159,6 @@ class WhatIfEngine:
                     lambda evn: (evn >= 0).any(axis=1)
                 )
         outs = []
-        # Engine-level wall-clock phase breakdown (round 12): the what-if
-        # chunk loop gets the same PHASE_NAMES timers the single-replay
-        # paths carry, feeding heartbeats, the fleet telemetry merge, and
-        # the bench `phases` detail.
-        from .telemetry import PhaseTimers, ReplayTelemetry
-        from ..utils.profiling import annotate as _prof_ann
-        from ..utils.profiling import profiling_active as _prof_on
-
-        run_phases = PhaseTimers()
         # PUBLISH_STATS / RETRY_STATS / CRC_STATS are cumulative module
         # state — snapshot them so the fleet phases below surface only
         # THIS run's publications, KV retries and CRC fallbacks (a prior
@@ -3082,14 +3167,6 @@ class WhatIfEngine:
         _bg_start = dcn.bg_publish_stats()
         _rs_start = dcn.retry_stats()
         _cs_start = dcn.crc_stats()
-        import contextlib as _ctxlib
-
-        _null = _ctxlib.nullcontext()
-        _prof = _prof_on()
-        _cann = (
-            (lambda i: _prof_ann(f"chunk:{i}")) if _prof else (lambda i: _null)
-        )
-        _pann = _prof_ann if _prof else (lambda name: _null)
         n_chunks = len(range(0, idx.shape[0], C))
         # Liveness heartbeats (round 12): one overwritten KV beacon per
         # process on a chunk cadence — plain puts, never a gather. A
@@ -3260,7 +3337,21 @@ class WhatIfEngine:
         # carried prefix) — the queue driver charges these to
         # spec_wasted_chunks when a speculative duplicate is discarded.
         self._wq_exec_chunks = max(n_chunks - start_ci, 0)
+        # With profiling armed, the v3 chunk program and each release
+        # bucket's program go to utils.profiling.stage_tables by module
+        # name, on the shapes of their first call (taken before the call:
+        # it donates its buffers).
+        registered: set = set()
+
+        def _reg(fn, args):
+            if _prof and fn not in registered:
+                registered.add(fn)
+                _register_call(fn, args)
+
+        rel_buckets: set = set()  # the pow2 release widths this run used
         t0 = time.perf_counter()
+        run_phases.add("stage", t0 - _t_stage)
+        _stage_ann.__exit__(None, None, None)
         for ci, c0 in enumerate(range(0, idx.shape[0], C)):
             if ci < start_ci:
                 continue  # chunks already carried by the resumed state
@@ -3417,8 +3508,12 @@ class WhatIfEngine:
                             self._dyn_dev.ov_gdom,
                             self._dyn_dev.ov_old,
                         )
+                    K_rel = int(rc[0].shape[0])
+                    rel_fn = self._release_fn(K_rel)
+                    rel_buckets.add(K_rel)
+                    _reg(rel_fn, args)
                     with run_phases.tick("boundary_fold"):
-                        states = self._release_fn(rc[0].shape[0])(*args)
+                        states = rel_fn(*args)
             # Dispatch phase (the chunk-fn if/elif chain below runs exactly
             # one branch): timed via add() rather than a context manager so
             # the chain's indentation is untouched; the profiler chunk
@@ -3427,16 +3522,18 @@ class WhatIfEngine:
             _ann.__enter__()
             _t_disp = time.perf_counter()
             if dev_rel and self.retry_buffer:
-                (
-                    states, vassign_d, rbuf_d, rcount_d,
-                    pend_id_d, pend_node_d, pend_relb_d, rdrop_d, out,
-                ) = self._chunk_fn(
+                args = (
                     dc, states, srcs[0], srcs[1], mgt_d, antit_d,
                     preft_d, prefwt_d, durt_d, tbt_d,
                     idx_chunks[ci], tb_c[ci], b_c[ci],
                     vassign_d, rbuf_d, rcount_d,
                     pend_id_d, pend_node_d, pend_relb_d, rdrop_d,
                 )
+                _reg(self._chunk_fn, args)
+                (
+                    states, vassign_d, rbuf_d, rcount_d,
+                    pend_id_d, pend_node_d, pend_relb_d, rdrop_d, out,
+                ) = self._chunk_fn(*args)
             elif dev_rel:
                 args = (
                     dc, states, srcs[0], srcs[1], idx_chunks[ci],
@@ -3448,6 +3545,7 @@ class WhatIfEngine:
                     args = args + (None,)  # dyn slot
                 if pol_d is not None:
                     args = args + (pol_d,)
+                _reg(self._chunk_fn, args)
                 states, vassign_d, out = self._chunk_fn(*args)
             elif self.engine == "v3":
                 # Fused device-side gather + wave scan: one dispatch per
@@ -3461,6 +3559,7 @@ class WhatIfEngine:
                     args = args + (None,)  # dyn slot
                 if pol_d is not None:
                     args = args + (pol_d,)
+                _reg(self._chunk_fn, args)
                 states, out = self._chunk_fn(*args)
             else:
                 slots = T.gather_slots(self.pods, idx[c0 : c0 + C])
@@ -3563,6 +3662,11 @@ class WhatIfEngine:
                 dcn.drain_publisher()
         wall = time.perf_counter() - t0
 
+        # ``gather``: counts, utilization and what the host-side paths
+        # assemble, up to the placements' hand-back.
+        _t_gather = time.perf_counter()
+        _gather_ann = _pann("gather")
+        _gather_ann.__enter__()
         to_schedule = int((idx >= 0).sum())
         kube_preempt = kube_dropped = None
         kube_evict = kube_resched = kube_stranded = kube_lat = None
@@ -3645,7 +3749,7 @@ class WhatIfEngine:
                 )
             scheduled = ~prebound
             placed = (assignments[:, scheduled] >= 0).sum(axis=1).astype(np.int32)
-        elif self.collect_assignments:
+        elif self.collect_assignments and not dev_rel:
             choices = np.concatenate(
                 [self._fetch(o) for o in outs], axis=1
             )  # [S, Cw, W]
@@ -3680,7 +3784,7 @@ class WhatIfEngine:
                 # (counts [S, C], retry_placed [S]) per chunk: placements
                 # from arrival waves plus boundary retry passes.
                 placed = self._fetch(
-                    jax.jit(
+                    self._jit_once("placed_retry", lambda: jax.jit(
                         lambda o: (
                             jnp.concatenate(
                                 [c for c, _ in o], axis=1
@@ -3689,17 +3793,17 @@ class WhatIfEngine:
                                 axis=1, dtype=jnp.int32
                             )
                         )
-                    )(outs)
+                    ))(outs)
                 ).astype(np.int32)
             else:
                 # Device-side reduce, ONE small D2H instead of one
                 # np.asarray round-trip per array.
                 placed = self._fetch(
-                    jax.jit(
+                    self._jit_once("placed", lambda: jax.jit(
                         lambda o: jnp.concatenate(o, axis=1).sum(
                             axis=1, dtype=jnp.int32
                         )
-                    )(outs)
+                    ))(outs)
                 ).astype(np.int32)
 
         util = None
@@ -3715,13 +3819,23 @@ class WhatIfEngine:
 
             # [S] floats instead of the full [S, R, N] used plane D2H.
             util = self._fetch(
-                jax.jit(_util)(states.used, self.sset.dc.allocatable)
+                self._jit_once("util", lambda: jax.jit(_util))(
+                    states.used, self.sset.dc.allocatable
+                )
             )
         dropped = kube_dropped
         if dropped is None and dev_rel and self.retry_buffer:
             # The device retry path counts overflow drops in-scan now
             # (round 6): every drop-capable engine reports them.
             dropped = np.asarray(self._fetch(rdrop_d)).astype(np.int32)
+        run_phases.add("gather", time.perf_counter() - _t_gather)
+        _gather_ann.__exit__(None, None, None)
+        handback_bytes = 0
+        if self.collect_assignments and dev_rel:
+            # The device-release path's placements: the wave-order buffer
+            # comes to the host once, after the last chunk.
+            with run_phases.tick("handback"), _pann("handback"):
+                assignments, handback_bytes = self._handback(vassign_d)
         # This process's partial fleet telemetry (round 12): per-scenario
         # collectors merged same-process (phases key-wise summed would be
         # wrong here — the fleet view wants the ENGINE's wall clocks, so
@@ -3736,6 +3850,11 @@ class WhatIfEngine:
                     granularity=self.telemetry_cfg.granularity
                 )
             fleet_local.phases = run_phases.summary()
+            fleet_local.chunk_waves = int(C)
+            fleet_local.scenarios = int(self.S)
+            if dev_rel:
+                fleet_local.release_buckets = sorted(rel_buckets)
+                fleet_local.handback_bytes = handback_bytes
             # DCN checkpoint-publication attribution (round 16): the
             # cumulative encode+push wall, publication count and encoded
             # MiB ride the fleet phase map (merged under this pid's

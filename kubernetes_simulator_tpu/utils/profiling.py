@@ -14,7 +14,8 @@
   stages, written into the HLO by ``jax.named_scope`` (trace-time metadata,
   no op). Beneath ``ksim.filter_score`` a plugin's own work sits under its
   registry name (``ksim.filter_score/PodTopologySpread``).
-- ``register_program(module_name, lower)`` / ``stage_tables()``: device op
+- ``register_program(module_name, lower)`` / ``register_call(fn, args)`` /
+  ``stage_tables()``: device op
   events in a trace carry the HLO instruction's name (``fusion.628``), the
   scope sits in the executable's HLO text; the engines hand the programs of
   an armed replay over by XLA module name, and ``stage_tables()`` joins the
@@ -153,6 +154,14 @@ def shape_structs(tree):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
 
     return jax.tree.map(struct, tree)
+
+
+def register_call(fn, args) -> None:
+    """:func:`register_program` for a jitted ``fn`` about to be called
+    with ``args``, under the module name jit gives it. The shapes are
+    taken now: the call may donate its buffers."""
+    structs = shape_structs(args)
+    register_program(f"jit_{fn.__name__}", lambda: fn.lower(*structs))
 
 
 def parse_stage_table(hlo_text: str) -> Dict[str, str]:

@@ -27,3 +27,30 @@ from kubernetes_simulator_tpu.utils.compile_cache import enable as _cc
 
 if _cc() is not None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
+
+
+import pytest
+
+
+@pytest.fixture
+def fork_at_start(tmp_path):
+    """``make(ec, ep) -> path``: a fork checkpoint taken before the first
+    chunk. Nothing is skipped, and a fork is a structural cause that keeps
+    a what-if batch on the host pending-fold path (asking for placements
+    no longer does)."""
+    import numpy as np
+
+    from kubernetes_simulator_tpu.models.state import init_state
+    from kubernetes_simulator_tpu.sim.checkpoint import ReplayCheckpoint
+
+    def make(ec, ep) -> str:
+        h = init_state(ec, ep)
+        path = str(tmp_path / "fork_at_start.npz")
+        ReplayCheckpoint(
+            chunk_cursor=0, used=h.used, match_count=h.match_count,
+            anti_active=h.anti_active, pref_wsum=h.pref_wsum, outs=[],
+            released=np.zeros(ep.num_pods, bool),
+        ).save(path)
+        return path
+
+    return make

@@ -193,10 +193,11 @@ def test_whatif_completions_scenario0_matches_single_replay():
     assert (off.assignments[0] != res.assignments[0]).any()
 
 
-def test_whatif_device_release_path_matches_host_path():
+def test_whatif_device_release_path_matches_host_path(fork_at_start):
     """The device-side release path (no per-chunk D2H; round 3) must agree
     with the host pending-fold path: same per-scenario placed counts and
-    utilization. Gate sanity: collect_assignments forces the host path."""
+    utilization. A fork checkpoint taken before the first chunk keeps the
+    second batch on the host path (collect_assignments picks no path)."""
     from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine, uniform_scenarios
 
     cluster = make_cluster(12, seed=3, taint_fraction=0.2)
@@ -210,7 +211,8 @@ def test_whatif_device_release_path_matches_host_path():
     dev = WhatIfEngine(ec, ep, scen, cfg, chunk_waves=4)
     assert dev._completions_dev and not dev._need_choices
     r1 = dev.run()
-    host = WhatIfEngine(ec, ep, scen, cfg, chunk_waves=4, collect_assignments=True)
+    host = WhatIfEngine(ec, ep, scen, cfg, chunk_waves=4, collect_assignments=True,
+                        fork_checkpoint=fork_at_start(ec, ep))
     assert not host._completions_dev and host.completions_on
     r2 = host.run()
     np.testing.assert_array_equal(r1.placed, r2.placed)
@@ -225,7 +227,7 @@ def test_whatif_device_release_path_matches_host_path():
 
 
 @pytest.mark.slow
-def test_whatif_device_release_full_plugin_envelope():
+def test_whatif_device_release_full_plugin_envelope(fork_at_start):
     """Round 4: the device-release path covers anti/pref count planes,
     multi-topology traces and singleton host-scale rows (the bench /
     config-3 workload shape). Device vs host pending-fold vs greedy
@@ -253,7 +255,8 @@ def test_whatif_device_release_full_plugin_envelope():
     assert dev._completions_dev
     r1 = dev.run()
     host = WhatIfEngine(
-        ec, ep, scen, cfg, chunk_waves=4, collect_assignments=True
+        ec, ep, scen, cfg, chunk_waves=4, collect_assignments=True,
+        fork_checkpoint=fork_at_start(ec, ep),
     )
     assert not host._completions_dev
     r2 = host.run()

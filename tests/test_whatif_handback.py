@@ -1,0 +1,258 @@
+"""The what-if device-release path hands back every task's node
+(``collect_assignments=True`` picks no path any more): held, task for task
+and scenario by scenario, to the CPU plugin path's replay of that
+scenario's own cluster and to the host pending-fold path; pre-bound tasks;
+a resident engine's later ``run()`` calls compile nothing; the phases cover
+the call; the programs a profiled run registers.
+
+"The CPU engine" here is ``greedy_replay``: the CPU framework
+(``framework/``, the plugins ``sim/runtime.py`` drives) run over the device
+engines' waves and chunk-granular releases. The event engine of
+``sim/runtime.py`` releases at event time and requeues, so it answers
+another question (tests/test_divergence_pin.py)."""
+
+import dataclasses
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from kubernetes_simulator_tpu.framework.framework import FrameworkConfig
+from kubernetes_simulator_tpu.models.encode import PAD, encode
+from kubernetes_simulator_tpu.sim.greedy import greedy_replay
+from kubernetes_simulator_tpu.sim.synthetic import make_cluster, make_workload
+from kubernetes_simulator_tpu.sim.telemetry import PHASE_NAMES
+from kubernetes_simulator_tpu.sim.whatif import (
+    Perturbation,
+    Scenario,
+    ScenarioSet,
+    WhatIfEngine,
+)
+from kubernetes_simulator_tpu.utils import profiling
+
+W, C = 4, 4
+
+
+def scenarios(n_nodes: int):
+    """Six scenarios, every perturbation kind alone and all three at once;
+    scenario 0 is the base."""
+    down = Perturbation("node_down", nodes=np.array([1, 5]))
+    half = Perturbation("scale_capacity", nodes=np.arange(0, n_nodes, 2),
+                        resource="cpu", factor=0.5)
+    more = Perturbation("scale_capacity", nodes=np.arange(3), resource="cpu",
+                        factor=1.5)
+    taint = Perturbation("add_taint", nodes=np.arange(2, 6),
+                         key="whatif/injected", value="true",
+                         effect="NoSchedule")
+    return [Scenario(), Scenario([down]), Scenario([half]), Scenario([taint]),
+            Scenario([down, half, taint]), Scenario([more, taint])]
+
+
+@pytest.fixture(scope="module")
+def case():
+    """A contended trace: gangs, a taint some tasks tolerate, a zone
+    spread, and durations that keep the cluster full, so releases decide what
+    fits (4 to 227 of the 400 tasks go unplaced, by scenario)."""
+    cluster = make_cluster(8, seed=3, taint_fraction=0.2)
+    pods, _ = make_workload(
+        400, seed=3, arrival_rate=12.0, duration_mean=20.0, with_spread=True,
+        with_tolerations=True, gang_fraction=0.1, gang_size=3,
+    )
+    ec, ep = encode(cluster, pods)
+    return ec, ep, FrameworkConfig(), scenarios(ec.num_nodes)
+
+
+def device_engine(ec, ep, cfg, scen, **kw):
+    eng = WhatIfEngine(ec, ep, scen, cfg, wave_width=W, chunk_waves=C,
+                       completions=True, collect_assignments=True, **kw)
+    assert eng._completions_dev and not eng._need_choices
+    return eng
+
+
+@pytest.fixture(scope="module")
+def answered(case):
+    ec, ep, cfg, scen = case
+    eng = device_engine(ec, ep, cfg, scen)
+    return eng, eng.run()
+
+
+@pytest.mark.parametrize("s", range(6))
+def test_each_scenario_is_the_cpu_replay_of_its_own_cluster(case, answered, s):
+    ec, ep, cfg, scen = case
+    eng, res = answered
+    own = ScenarioSet(ec, scen, keep_host_stacks=True).host_clusters(ec)[s]
+    ref = greedy_replay(own, ep, cfg, wave_width=W,
+                        completions_chunk_waves=eng.chunk_waves)
+    np.testing.assert_array_equal(res.assignments[s], ref.assignments)
+    assert int(res.placed[s]) == ref.placed
+    assert int(res.unschedulable[s]) == ref.unschedulable
+    # the device-side count and the placements handed back agree
+    assert int((res.assignments[s] >= 0).sum()) == int(res.placed[s])
+
+
+def test_perturbations_and_releases_decide_the_answers(case, answered):
+    """Not vacuous: some tasks go unplaced, every perturbed scenario parts
+    from the base, and without completions the base answers otherwise."""
+    ec, ep, cfg, scen = case
+    _, res = answered
+    assert (res.unschedulable > 0).any()
+    for s in range(1, len(scen)):
+        assert (res.assignments[s] != res.assignments[0]).any(), s
+    off = WhatIfEngine(ec, ep, scen, cfg, wave_width=W, chunk_waves=C,
+                       completions=False, collect_assignments=True).run()
+    assert (off.assignments[0] != res.assignments[0]).any()
+
+
+def test_device_release_path_equals_host_pending_fold_path(case, answered,
+                                                           fork_at_start):
+    ec, ep, cfg, scen = case
+    _, res = answered
+    host = WhatIfEngine(
+        ec, ep, scen, cfg, wave_width=W, chunk_waves=C, completions=True,
+        collect_assignments=True,
+        fork_checkpoint=fork_at_start(ec, ep))
+    assert host.completions_on and not host._completions_dev
+    r2 = host.run()
+    np.testing.assert_array_equal(res.assignments, r2.assignments)
+    np.testing.assert_array_equal(res.placed, r2.placed)
+    np.testing.assert_array_equal(res.utilization_cpu, r2.utilization_cpu)
+
+
+def test_counts_only_batch_fetches_no_placements(case, answered):
+    """Without the flag the path answers as before: the same counts, no
+    placements, and no hand-back phase or bytes."""
+    ec, ep, cfg, scen = case
+    _, res = answered
+    eng = WhatIfEngine(ec, ep, scen, cfg, wave_width=W, chunk_waves=C,
+                       completions=True)
+    assert eng._completions_dev
+    r = eng.run()
+    assert r.assignments is None
+    np.testing.assert_array_equal(r.placed, res.placed)
+    np.testing.assert_array_equal(r.utilization_cpu, res.utilization_cpu)
+    tel = r.fleet_telemetry
+    assert "p0/handback" not in tel.phases
+    assert tel.summary()["handback_bytes"] == 0
+    got = res.fleet_telemetry.summary()
+    assert got["handback_bytes"] == 4 * len(scen) * ep.num_pods
+    assert got["scenarios"] == len(scen) and got["chunk_waves"] == C
+    assert got["release_buckets"] == [256]
+
+
+def test_prebound_tasks_come_back_from_the_tail_region(case):
+    ec, ep, cfg, scen = case
+    bound = np.full(ep.num_pods, PAD, np.int32)
+    bound[[0, 7, 30]] = [2, 2, 7]
+    ep2 = dataclasses.replace(ep, bound_node=bound)
+    eng = device_engine(ec, ep2, cfg, scen[:3])
+    res = eng.run()
+    np.testing.assert_array_equal(res.assignments[:, [0, 7, 30]],
+                                  np.tile([2, 2, 7], (3, 1)))
+    ref = greedy_replay(ec, ep2, cfg, wave_width=W,
+                        completions_chunk_waves=eng.chunk_waves)
+    np.testing.assert_array_equal(res.assignments[0], ref.assignments)
+    # pre-bound tasks are in the placements, never in the count
+    assert int(res.placed[0]) == ref.placed
+    assert int((res.assignments[0] >= 0).sum()) == ref.placed + 3
+
+
+def test_later_runs_compile_nothing_and_answer_the_same(case, answered):
+    ec, ep, cfg, scen = case
+    eng, first = answered
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, _, **kw: compiles.append(name)
+        if name == "/jax/core/compile/backend_compile_duration" else None)
+    for _ in range(2):
+        again = eng.run()
+        np.testing.assert_array_equal(again.assignments, first.assignments)
+        np.testing.assert_array_equal(again.placed, first.placed)
+        np.testing.assert_array_equal(again.utilization_cpu,
+                                      first.utilization_cpu)
+    assert compiles == []
+
+
+def test_later_runs_compile_nothing_under_a_mesh(case):
+    from kubernetes_simulator_tpu.parallel.mesh import make_mesh
+
+    ec, ep, cfg, scen = case
+    eng = device_engine(ec, ep, cfg, scen[:4], mesh=make_mesh(2))
+    first = eng.run()
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, _, **kw: compiles.append(name)
+        if name == "/jax/core/compile/backend_compile_duration" else None)
+    again = eng.run()
+    assert compiles == []
+    np.testing.assert_array_equal(again.assignments, first.assignments)
+    plain = device_engine(ec, ep, cfg, scen[:4]).run()
+    np.testing.assert_array_equal(first.assignments, plain.assignments)
+
+
+def test_device_retry_buffer_refuses_placements_loudly(case):
+    ec, ep, cfg, scen = case
+    with pytest.raises(ValueError, match="retried placements"):
+        WhatIfEngine(ec, ep, scen, cfg, wave_width=W, chunk_waves=C,
+                     completions=True, collect_assignments=True,
+                     retry_buffer=16)
+
+
+def test_phases_cover_a_whatif_run(answered):
+    """As tests/test_stage_scopes.py holds the replay: the phases are
+    sequential on one thread and what run() spends outside them is small.
+    Best of three."""
+    eng, _ = answered
+    shares = []
+    for _ in range(3):
+        t = time.perf_counter()
+        res = eng.run()
+        wall = time.perf_counter() - t
+        phases = {k.split("/", 1)[1]: v
+                  for k, v in res.fleet_telemetry.phases.items()}
+        assert set(phases) <= set(PHASE_NAMES)
+        assert {"stage", "dispatch", "boundary_fold", "device_wait", "gather",
+                "handback"} <= set(phases)
+        shares.append(sum(phases.values()) / wall)
+    assert max(shares) >= 0.95, shares
+
+
+def test_a_profiled_run_registers_its_programs(case, tmp_path, monkeypatch):
+    """The chunk program under the name the benchmark's reader looks for,
+    the release program under one pinned name per bucket; both lower to
+    modules whose stages ``stage_tables`` can name."""
+    ec, ep, cfg, scen = case
+    profiling._PROGRAMS.clear()
+    monkeypatch.setenv("KSIM_PROFILE_DIR", str(tmp_path))
+    device_engine(ec, ep, cfg, scen[:2]).run()
+    monkeypatch.delenv("KSIM_PROFILE_DIR")
+    assert set(profiling._PROGRAMS) == {"jit_per_scenario_rel",
+                                        "jit_whatif_release_k256"}
+    tables = profiling.stage_tables()
+    profiling._PROGRAMS.clear()
+    assert "ksim.release" in set(tables["jit_whatif_release_k256"].values())
+    ran = {p.split("/")[0] for p in tables["jit_per_scenario_rel"].values()}
+    assert {"ksim.filter_score", "ksim.select", "ksim.commit",
+            "ksim.release"} <= ran
+
+
+def test_asking_for_placements_leaves_the_chunk_program_alone(case):
+    """Handing the placements back is the end of run()'s business: with and
+    without the flag the engine builds the same chunk program, to the
+    letter of its lowered module."""
+    import jax.numpy as jnp
+
+    ec, ep, cfg, scen = case
+    texts = []
+    for collect in (False, True):
+        eng = WhatIfEngine(ec, ep, scen, cfg, wave_width=W, chunk_waves=C,
+                           completions=True, collect_assignments=collect)
+        idx = eng.waves.idx
+        idx = np.concatenate(
+            [idx, np.full((-idx.shape[0] % C, W), PAD, np.int32)])
+        stg = eng._stage_dev_rel(idx, C)
+        va = jnp.broadcast_to(stg["va"][None], (eng.S,) + stg["va"].shape)
+        texts.append(eng._chunk_fn.lower(
+            eng.sset.dc, eng._init_states(), *eng._slot_srcs,
+            jnp.asarray(idx[:C]), stg["b_c"][0], va).as_text())
+    assert texts[0] == texts[1]
