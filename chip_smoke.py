@@ -20,8 +20,10 @@ no result line (nothing here catches a phase's exception):
                   (256 scenarios x 5,000 nodes x 50,000 pods, one chip)
   run-config2     run examples/config2_full_plugins_5k.yaml --strategy jax
   parity          chip vs host reference: config-2 head, a Borg-shaped
-                  case with completions, and the same as a what-if batch
-                  through the device release program
+                  case with completions, the same as a what-if batch
+                  through the device release program, and a release-heavy
+                  what-if batch whose every release block adds many
+                  releases to one node (prints ``release_rounds``)
   serve           serve examples/config20_service.yaml < 4 defrag queries
   mesh            only with >1 device: what-if
                   examples/config5_multitenant_mesh.yaml over all devices
@@ -59,6 +61,10 @@ PARITY_HEAD_PODS = 2000
 # Borg case: few nodes and long tasks, so the cluster runs full (some tasks
 # stay unschedulable) and fit decisions sit on what the releases freed.
 BORG_NODES, BORG_TASKS, BORG_MEAN_DURATION, BORG_CHUNK_WAVES = 12, 2048, 15000.0, 16
+# Release-heavy what-if case: short tasks and wide chunks on the same 12 nodes,
+# so a boundary releases hundreds of tasks and every 128-row block of the
+# release program adds many releases to one node, in rank order.
+HEAVY_SEED, HEAVY_MEAN_DURATION, HEAVY_CHUNK_WAVES, HEAVY_MIN_ROUNDS = 0, 2000.0, 64, 8
 
 
 def say(msg: str) -> None:
@@ -285,6 +291,45 @@ def phase_parity() -> dict:
         "scenarios": len(scen),
         "placed": [int(x) for x in r_dev.placed],
     }
+
+    # (d) the release-heavy case. The CPU backend's dot is exact and
+    # sequential, so only here can an MXU that is not show: the device
+    # release program sums a node's releases through single-term products
+    # (ops.release_planes), and each scenario has to equal the host
+    # reference (np.add.at in list order) placement for placement.
+    ec, ep, _ = make_borg_encoded(
+        BorgSpec(nodes=BORG_NODES, tasks=BORG_TASKS, seed=HEAVY_SEED,
+                 mean_duration=HEAVY_MEAN_DURATION)
+    )
+    alloc = ec.allocatable.copy()
+    alloc[half, cpu] = alloc[half, cpu] * 0.75
+    refs = [
+        greedy_replay(e, ep, fw, wave_width=8,
+                      completions_chunk_waves=HEAVY_CHUNK_WAVES)
+        for e in (ec, dataclasses.replace(ec, allocatable=alloc))
+    ]
+    require(0 < refs[0].unschedulable < BORG_TASKS // 2,
+            "release-heavy case: the cluster never runs full")
+    heavy = WhatIfEngine(
+        ec, ep, scen[:2], fw, completions=True, collect_assignments=True,
+        wave_width=8, chunk_waves=HEAVY_CHUNK_WAVES, granularity_guard=False)
+    require(heavy._completions_dev,
+            "release-heavy what-if did not take the device release path")
+    r_heavy = heavy.run()
+    rounds = r_heavy.fleet_telemetry.summary()["release_rounds"]
+    require(rounds >= HEAVY_MIN_ROUNDS,
+            f"release-heavy case: release_rounds {rounds}, no deep collision")
+    for s, ref in enumerate(refs):
+        same(r_heavy.assignments[s], ref.assignments,
+             f"release-heavy what-if scenario {s} (device release path)")
+        require(int(r_heavy.placed[s]) == ref.placed,
+                f"release-heavy what-if scenario {s}: placed differs")
+    out["release_heavy_whatif"] = {
+        "placed": [int(x) for x in r_heavy.placed],
+        "unschedulable": refs[0].unschedulable,
+        "release_rounds": rounds,
+    }
+    say(f"release-heavy what-if: release_rounds {rounds}")
     return out
 
 

@@ -215,16 +215,19 @@ class ReplayTelemetry:
     # with (ops.tpu3.inwave_corrections): "plane" or "terms". None for v2.
     inwave_corrections: Optional[str] = None
     # What-if batches only: scenarios evaluated; on the device-release
-    # path the pow2 widths its release program ran with, and the bytes of
+    # path the pow2 widths its release program ran with, the largest number
+    # of rank rounds one block of a release list needed (1: no two releases
+    # of a block ever hit one node; ops.release_planes), and the bytes of
     # the placement hand-back (0 when placements were not asked for).
     scenarios: Optional[int] = None
     release_buckets: Optional[List[int]] = None
+    release_rounds: Optional[int] = None
     handback_bytes: Optional[int] = None
 
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
         for key in ("chunk_waves", "inwave_corrections", "scenarios",
-                    "release_buckets", "handback_bytes"):
+                    "release_buckets", "release_rounds", "handback_bytes"):
             if getattr(self, key) is not None:
                 out[key] = getattr(self, key)
         if self.latency is not None:
@@ -367,6 +370,10 @@ class ReplayTelemetry:
                    if p.release_buckets is not None]
         if buckets:
             tel.release_buckets = sorted(set().union(*buckets))
+        rounds = [p.release_rounds for _, p in keep
+                  if p.release_rounds is not None]
+        if rounds:
+            tel.release_rounds = max(rounds)
         return tel
 
 

@@ -31,6 +31,7 @@ from ..models.core import Effect
 from ..models.encode import PAD, EncodedCluster, EncodedPods
 from ..models.state import init_state
 from ..ops import tpu as T
+from ..ops.release_planes import bf16_parts, release_planes
 from ..parallel import dcn
 from ..parallel.mesh import (
     SCENARIO_AXIS,
@@ -46,6 +47,9 @@ from ..utils.profiling import stage
 from .jax_runtime import StepSpec, make_wave_step
 from .waves import pack_waves
 
+# The release program's vmap axis: named so that the rank rounds of a block
+# run to ONE trip count, the largest among the scenarios (ops.release_planes).
+_RELEASE_VMAP = "release_scenarios"
 
 @dataclass
 class Perturbation:
@@ -1474,7 +1478,7 @@ class WhatIfEngine:
                         safe_p = jnp.clip(pend_id, 0)
                         nd_p = jnp.where(due_p, pend_node, -1)
                         with stage("ksim.release"):
-                            state = rel_core(
+                            state, _, _ = rel_core(
                                 state, nd_p, src.requests[safe_p],
                                 mgt[safe_p], antit[safe_p],
                                 preft[safe_p], prefwt[safe_p],
@@ -1657,14 +1661,25 @@ class WhatIfEngine:
 
     def _release_core(self):
         """Shared device release-update core (cached): subtract a K-list
-        of released placements from every carried plane via one-hot
-        commit blocks — used by the bucketed static-release fns AND the
-        retry path's pending releases. Covers the full plane set: used,
-        coarse domain planes (per-topology static matmuls), singleton
-        host-scale rows, anti/pref when the trace carries the terms,
-        match_total. Returns ``core(state, nd, req, mg, an, pf, pw,
-        want_raw=False)``; with ``want_raw`` also returns the UNMASKED
-        node-space accumulator stack (the DynTables correction input)."""
+        of released placements from every carried plane — used by the
+        bucketed static-release fns AND the retry path's pending
+        releases. Two node-space accumulators come from
+        ``ops.release_planes`` (node-factored one-hot contractions on the
+        MXU, no scatter): ``rel [R, N]``, the released requests summed per
+        node IN LIST ORDER — the host reference's own arithmetic
+        (``models.state.release_delta``: ``np.add.at``, then ONE
+        subtraction), because resources are not associative-exact (a Borg
+        0.1-core request is no dyadic rational) — and ``rc``, the
+        released matched-group / anti / pref-weight counts, integers and
+        exact in any order. From them: used, coarse domain planes
+        (per-topology static matmuls), singleton host-scale rows,
+        anti/pref when the trace carries the terms, match_total. Returns
+        ``core(state, nd, req, mg, an, pf, pw, axis_name=None) ->
+        (state, rc_raw, rounds)``; ``nd == -1`` ("not placed") matches no
+        node; ``rc_raw`` is the UNMASKED node-space count stack (the
+        DynTables correction input); ``rounds`` is the largest number of
+        rank rounds a block of the list needed; ``axis_name`` names the
+        enclosing ``vmap`` axis so the rounds share one trip count."""
         if self._rel_core is not None:
             return self._rel_core
         from ..ops import tpu3 as V3
@@ -1683,9 +1698,16 @@ class WhatIfEngine:
         )  # [G, N]
         gt = ec.group_topo[:G]
         coarse = (~st3.is_host) & (gt >= 0)
-        topo_tables = []
+        # Per coarse topology: its groups and the static node→domain
+        # one-hot. ``row_of[g]`` is group g's row in the stacked products
+        # (the last, zero row for a group with no coarse topology), so the
+        # [G, Dcap] delta is one static take and the program has no scatter.
+        topo_tables, n_rows = [], 0
+        row_of = np.full(G, int(coarse.sum()), np.int32)
         for t in sorted(set(gt[coarse].tolist())):
             ids = np.nonzero(coarse & (gt == t))[0]
+            row_of[ids] = n_rows + np.arange(len(ids))
+            n_rows += len(ids)
             oh_t = (
                 ec.node_domain[t][:, None]
                 == np.arange(Dcap, dtype=np.int64)[None, :]
@@ -1693,6 +1715,7 @@ class WhatIfEngine:
             topo_tables.append(
                 (jnp.asarray(ids), jnp.asarray(oh_t.astype(np.float32)))
             )
+        row_of = jnp.asarray(row_of)
         h_sel = [
             jnp.asarray(np.asarray(ids, np.int32))
             for ids in (st3.mc_h_ids, st3.anti_h_ids, st3.pref_h_ids)
@@ -1703,68 +1726,39 @@ class WhatIfEngine:
         nparts = 1 + want_an + want_pf
 
         def coarse_delta(rc):
-            delta = jnp.zeros((G, Dcap), jnp.float32)
-            for ids, oh_t in topo_tables:
-                delta = delta.at[ids].set(
-                    jnp.matmul(rc[ids], oh_t, precision=T._HI)
-                )
-            return delta
+            rows = [
+                jnp.matmul(rc[ids], oh_t, precision=T._HI)
+                for ids, oh_t in topo_tables
+            ]
+            rows.append(jnp.zeros((1, Dcap), jnp.float32))
+            return jnp.concatenate(rows)[row_of]
 
         def core(state, nd, req_rows, mg_rows, an_rows, pf_rows, pw_rows,
-                 want_raw=False):
-            K = nd.shape[0]
-            Wr = 256 if K % 256 == 0 else K
-            nb = K // Wr
-            iota = jnp.arange(N, dtype=jnp.int32)
-            R = req_rows.shape[1]
-
-            def body(carry, xs):
-                rel, rc = carry
-                nd_b, req_b, mg_b, an_b, pf_b, pw_b = xs
-                # Resources are NOT associative-exact (a Borg 0.1-core
-                # request is no dyadic rational), so the released sum
-                # takes the host reference's own arithmetic
-                # (models.state.release_delta: np.add.at, then ONE
-                # subtraction): per node a sequential f32 sum in list
-                # order — what a scatter-add does, on the TPU and on the
-                # CPU backend alike — never an MXU contraction, whose
-                # summation order is the hardware's. nd == -1 ("not
-                # placed") must be dropped, not wrapped to the last node.
-                # chip_smoke.py's parity phase holds the chip to this.
-                rel = rel.at[jnp.where(nd_b >= 0, nd_b, N)].add(
-                    req_b, mode="drop"
-                )
-                oh = (nd_b[:, None] == iota[None, :]).astype(jnp.float32)
-                parts = [(mg_b[:, :, None] == ar_G).sum(1)]
-                if want_an:
-                    parts.append((an_b[:, :, None] == ar_G).sum(1))
-                if want_pf:
-                    parts.append(
-                        ((pf_b[:, :, None] == ar_G) * pw_b[:, :, None])
-                        .sum(1)
-                    )
-                mm = jnp.concatenate(parts, axis=1).astype(jnp.float32)
-                rc = rc + jnp.einsum(
-                    "wn,wk->kn", oh, mm, precision=T._HI
-                )
-                return (rel, rc), None
-
-            (rel, rc), _ = jax.lax.scan(
-                body,
-                (
-                    jnp.zeros((N, R), jnp.float32),
-                    jnp.zeros((nparts * G, N), jnp.float32),
-                ),
-                (
-                    nd.reshape(nb, Wr),
-                    req_rows.reshape(nb, Wr, R),
-                    mg_rows.reshape(nb, Wr, mg_rows.shape[1]),
-                    an_rows.reshape(nb, Wr, an_rows.shape[1]),
-                    pf_rows.reshape(nb, Wr, pf_rows.shape[1]),
-                    pw_rows.reshape(nb, Wr, pw_rows.shape[1]),
-                ),
+                 axis_name=None):
+            # Count channels have to be exact in bfloat16: a 0/1 match sum
+            # over at most 256 term slots is, a summed preference weight
+            # is cut into three parts that are.
+            if max(mg_rows.shape[1], an_rows.shape[1]) > 256:
+                raise ValueError("release core: over 256 term slots a pod")
+            parts = [(mg_rows[:, :, None] == ar_G).sum(1)]
+            if want_an:
+                parts.append((an_rows[:, :, None] == ar_G).sum(1))
+            if want_pf:
+                parts.extend(bf16_parts(
+                    ((pf_rows[:, :, None] == ar_G) * pw_rows[:, :, None])
+                    .sum(1)
+                ))
+            mm = jnp.concatenate(parts, axis=1).astype(jnp.float32)
+            rel, rc, rounds = release_planes(
+                nd, req_rows, mm, N, axis_name=axis_name
             )
-            used = state.used - rel.T
+            if want_pf:  # integer parts: exact in any order
+                lo = (nparts - 1) * G
+                rc = jnp.concatenate([
+                    rc[:lo],
+                    rc[lo : lo + G] + rc[lo + G : lo + 2 * G] + rc[lo + 2 * G :],
+                ])
+            used = state.used - rel
             rc_raw = rc
             rc = rc * jnp.tile(vdom, (nparts, 1))
             chunks = jnp.split(rc, nparts, axis=0)
@@ -1789,7 +1783,7 @@ class WhatIfEngine:
                     plane = getattr(state, pkey)
                     new[pkey] = plane - rcx[ids].astype(plane.dtype)
             out = state._replace(**new)
-            return (out, rc_raw) if want_raw else out
+            return out, rc_raw, rounds
 
         core.nparts = nparts
         core.want_an = want_an
@@ -1804,23 +1798,27 @@ class WhatIfEngine:
         of the global maximum — the Borg duration distribution makes the
         max ~2.4× the mean.
 
-        The count planes update through a scan over 256-wide one-hot
-        COMMIT blocks (the wave-commit trick, measured 4×+ faster than a
-        [K]-index scatter on an earlier platform — scatter serializes
-        colliding indices): each block builds the [Wr, N] placement
-        one-hot once and contracts it with the matched-group matrix (→ a
-        node-space [G, N] released-count accumulator). The count planes
-        then drop to domain space through ONE static node→domain one-hot
-        matmul; match_total is its row sum. Exactness: one-hot operands
-        are 0/1 and the counts are small integers, exact in any
-        summation order — at ``precision=_HI`` only: the TPU's default
-        precision rounds the f32 operands to bf16. ``used`` is the one
-        plane that is not integer-valued and takes a scatter-add in the
-        same scan (why: the comment in ``_release_core``). Seen on the
-        v5e with ``used`` in the contraction: 0.10009766 subtracted for a
-        0.1-core request at default precision, the MXU's summation order
-        at ``_HI``, placements off the host reference either way — and
-        no CPU test could see it (that backend is exact and sequential)."""
+        Both node-space accumulators are node-factored one-hot
+        contractions (``ops.release_planes``; the module's docstring has
+        the arithmetic): a node is ``128 * hi + lo``, so placing a value
+        at its node is a ``[C * NH, K] x [K, 128]`` product on the MXU
+        with bfloat16 operands and float32 accumulation, and no
+        ``[K, N]`` one-hot and no scatter is left. The counts take ONE
+        product over the whole list; the count planes then drop to domain
+        space through ONE static node→domain one-hot matmul, match_total
+        is its row sum. ``used`` is summed in list order: per block of
+        128 rows each row's collision rank is counted, and round r
+        contracts the rows of rank r only, so no product ever adds two
+        values. A single-term product returns the float32 itself because
+        its three bfloat16 parts lie on disjoint bits of it. History, on
+        the v5e: at default precision a 0.1-core request came back as
+        0.10009766; at ``HIGHEST`` with colliding rows in one product the
+        sum took the MXU's order, placements left the host reference
+        (PR 21), and no CPU test could see either (that backend is exact
+        and sequential); the 256-blocked scatter-add that repaired it
+        executed one update after another, 62% of the program (PR 27).
+        ``chip_smoke.py``'s parity phase holds the chip to the host
+        reference on a case where every block collides."""
         dyn_mode = self._dyn is not None
         key = (K, dyn_mode)
         fn = self._rel_fn_cache.get(key)
@@ -1831,26 +1829,24 @@ class WhatIfEngine:
         nparts = core.nparts
         want_an, want_pf = core.want_an, core.want_pf
 
-        def rel_one(state, vassign, rel_pos, rel_req, rel_mg,
+        def rel_one(state, vassign, rounds, rel_pos, rel_req, rel_mg,
                     rel_anti, rel_pref, rel_prefw,
                     ov_nodes=None, ov_gdom=None, ov_old=None):
             with stage("ksim.release"):
                 node_k = vassign[rel_pos]  # sentinel pos → the PAD tail slot
                 nd = jnp.where(node_k >= 0, node_k, -1)  # -1 matches no node
+                state, rc_raw, top = core(
+                    state, nd, rel_req, rel_mg, rel_anti, rel_pref,
+                    rel_prefw, axis_name=_RELEASE_VMAP,
+                )
+                rounds = jnp.maximum(rounds, top)
                 if not dyn_mode:
-                    return core(
-                        state, nd, rel_req, rel_mg, rel_anti, rel_pref,
-                        rel_prefw,
-                    )
+                    return state, rounds
                 # DynTables correction layered on the base update: a node the
                 # scenario relabeled releases into its OVERRIDDEN domain (and
                 # base validity doesn't apply — a node that gained the key
                 # releases into the appended domain the bind counted). Uses
                 # the UNMASKED accumulator; old/new one-hots encode validity.
-                state, rc_raw = core(
-                    state, nd, rel_req, rel_mg, rel_anti, rel_pref,
-                    rel_prefw, want_raw=True,
-                )
                 raw_chunks = jnp.split(rc_raw, nparts, axis=0)
                 safe_ov = jnp.where(ov_nodes >= 0, ov_nodes, 0)
                 ok_ov = (ov_nodes >= 0).astype(jnp.float32)  # [K32]
@@ -1877,18 +1873,17 @@ class WhatIfEngine:
                     new["pref_dom"] = state.pref_dom - corr_of(
                         raw_chunks[1 + want_an]
                     )
-                return state._replace(**new)
+                return state._replace(**new), rounds
 
-        axes = (
-            (0, 0, None, None, None, None, None, None, 0, 0, 0)
-            if dyn_mode
-            else (0, 0, None, None, None, None, None, None)
+        axes = (0, 0, 0, None, None, None, None, None, None) + (
+            (0, 0, 0) if dyn_mode else ()
         )
-        fn_v = jax.vmap(rel_one, in_axes=axes)
+        fn_v = jax.vmap(rel_one, in_axes=axes, axis_name=_RELEASE_VMAP)
         if self.mesh is not None:
             # Same shard_map discipline as the chunk program (round 10):
             # sharded state/vassign, replicated release tables — each
-            # device rewinds its local scenarios, no collectives.
+            # device rewinds its local scenarios, no collectives (the rank
+            # loop's trip count is the largest among a device's own).
             from jax.sharding import PartitionSpec as P
 
             sh, rp = P(SCENARIO_AXIS), P()
@@ -2828,6 +2823,13 @@ class WhatIfEngine:
                 if self.mesh is not None
                 else jax.jit(_bc)
             ))(stg["va"])
+            # Largest number of rank rounds a release block needed so far: a
+            # running max per scenario beside the state, fetched at gather.
+            rounds_d = jnp.zeros(S, jnp.int32)
+            if self.mesh is not None:
+                rounds_d = jax.device_put(
+                    rounds_d, scenario_sharding(self.mesh)
+                )
             if self.retry_buffer:
                 RB = self.retry_buffer
                 mgt_d, durt_d = stg["mgt"], stg["durt"]
@@ -3499,7 +3501,7 @@ class WhatIfEngine:
                 # data dependency on states/vassign), then the chunk.
                 rc = rel_calls[ci]
                 if rc is not None:
-                    args = (states, vassign_d) + rc
+                    args = (states, vassign_d, rounds_d) + rc
                     if self._dyn is not None:
                         # Per-scenario domain overrides: releases of
                         # relabeled nodes land in the overridden domain.
@@ -3513,7 +3515,7 @@ class WhatIfEngine:
                     rel_buckets.add(K_rel)
                     _reg(rel_fn, args)
                     with run_phases.tick("boundary_fold"):
-                        states = rel_fn(*args)
+                        states, rounds_d = rel_fn(*args)
             # Dispatch phase (the chunk-fn if/elif chain below runs exactly
             # one branch): timed via add() rather than a context manager so
             # the chain's indentation is untouched; the profiler chunk
@@ -3828,6 +3830,9 @@ class WhatIfEngine:
             # The device retry path counts overflow drops in-scan now
             # (round 6): every drop-capable engine reports them.
             dropped = np.asarray(self._fetch(rdrop_d)).astype(np.int32)
+        release_rounds = (
+            int(np.max(self._fetch(rounds_d))) if dev_rel else None
+        )
         run_phases.add("gather", time.perf_counter() - _t_gather)
         _gather_ann.__exit__(None, None, None)
         handback_bytes = 0
@@ -3854,6 +3859,7 @@ class WhatIfEngine:
             fleet_local.scenarios = int(self.S)
             if dev_rel:
                 fleet_local.release_buckets = sorted(rel_buckets)
+                fleet_local.release_rounds = release_rounds
                 fleet_local.handback_bytes = handback_bytes
             # DCN checkpoint-publication attribution (round 16): the
             # cumulative encode+push wall, publication count and encoded

@@ -119,7 +119,10 @@ def _chunk_args(eng: WhatIfEngine, with_durations: bool):
     rel = None
     for rc in stg["rel_calls"]:
         if rc is not None:
-            rel = (states, vassign) + rc
+            rounds = jax.device_put(
+                jnp.zeros(eng.S, jnp.int32), scenario_sharding(eng.mesh)
+            )
+            rel = (states, vassign, rounds) + rc
             break
     return args, rel
 
