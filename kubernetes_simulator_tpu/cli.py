@@ -99,7 +99,6 @@ def cmd_run(args) -> int:
         kw.update({"wave_width": cfg.wave_width, "chunk_waves": cfg.chunk_waves,
                    "preemption": cfg.device_preemption,
                    "retry_buffer": cfg.whatif.retry_buffer,
-                   "node_shards": cfg.node_shards,
                    "paged": cfg.paged_waves})
         if cfg.flight_recorder is not None:
             from .sim.flight import FlightRecorderConfig
@@ -596,12 +595,6 @@ def _service_errors(cfg) -> list:
             "boundary retry pass a drained node's pods are never "
             "rescheduled, so every defrag answer degenerates"
         )
-    if cfg.node_shards > 1:
-        errors.append(
-            "service: nodeShards > 1 is not supported — the query batch "
-            "spends the device on the scenario axis, and set_scenarios "
-            "refuses sliced engines"
-        )
     if cfg.whatif.mesh:
         errors.append(
             "service: whatIf.mesh is not supported (resident engines "
@@ -733,21 +726,6 @@ def validate_config(cfg) -> list:
             "whatIf.completions: false (the retry pass runs at completion "
             "boundaries)"
         )
-    if cfg.node_shards < 0:
-        errors.append("nodeShards: must be >= 0 (0/1 = replicated planes)")
-    if cfg.node_shards > 1:
-        if cfg.strategy != "jax":
-            errors.append(
-                "nodeShards: intra-scenario node-plane sharding is a "
-                "strategy: jax feature (the what-if batch spends the mesh "
-                "on the scenario axis)"
-            )
-        if tier_on:
-            errors.append(
-                "nodeShards is not supported with tier devicePreemption "
-                "(the sharded chunk program is the node-space engine; use "
-                "devicePreemption: kube)"
-            )
     if cfg.paged_waves:
         if cfg.strategy != "jax":
             errors.append("pagedWaves: requires strategy: jax")
@@ -876,13 +854,6 @@ def validate_config(cfg) -> list:
             )
         if fr.every <= 0:
             errors.append("flightRecorder.every: must be > 0")
-        if cfg.borg is not None and cfg.node_shards <= 1:
-            errors.append(
-                "flightRecorder on a borg headline workload without "
-                "nodeShards: the replicated planes bust one device at "
-                "Borg scale — set nodeShards > 1 (and usually "
-                "pagedWaves: true)"
-            )
     errors.extend(_recovery_errors(cfg))
     errors.extend(_workqueue_errors(cfg))
     errors.extend(_faultline_errors(cfg))
@@ -1023,7 +994,6 @@ def main(argv=None) -> int:
             for val, env in (
                 (ov.pager_thread, "KSIM_PAGER_THREAD"),
                 (ov.background_publisher, "KSIM_DCN_CKPT_ASYNC"),
-                (ov.two_phase_exchange, "KSIM_TWO_PHASE_EXCHANGE"),
             ):
                 if val is not None:
                     os.environ.setdefault(env, "1" if val else "0")

@@ -26,7 +26,6 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
-import os
 from typing import NamedTuple
 
 import jax
@@ -763,27 +762,6 @@ def select_node(scores: jax.Array, feasible: jax.Array):
     return jnp.where(placed, choice.astype(jnp.int32), PAD), placed
 
 
-class ShardCtx(NamedTuple):
-    """Static description of a node-plane shard (round 14 big-scenario
-    mode): inside ``shard_map`` over ``parallel.mesh.NODE_AXIS`` each
-    device holds a contiguous ``n_local``-wide block of the (padded)
-    node axis. ``n_real`` is the unpadded node count — pad rows are
-    masked infeasible so they can never win selection."""
-
-    axis: str  # mesh axis name (parallel.mesh.NODE_AXIS)
-    n_local: int  # nodes per shard (padded total / nshards)
-    n_real: int  # real (unpadded) node count
-    nshards: int
-
-
-def shard_gids(ctx: ShardCtx) -> jax.Array:
-    """[n_local] i32 — GLOBAL node ids of this shard's rows (contiguous
-    blocks, so global id order equals the replicated program's node
-    order — the property that makes the two-stage tie-break exact)."""
-    off = jax.lax.axis_index(ctx.axis).astype(jnp.int32) * np.int32(ctx.n_local)
-    return off + jnp.arange(ctx.n_local, dtype=jnp.int32)
-
-
 def masked_argmin(scores: jax.Array, mask: jax.Array):
     """(choice i32, any bool) — lowest-index argmin over the masked
     entries, in ONE variadic reduce (the ``select_node`` comparator with
@@ -1064,7 +1042,6 @@ def eval_pod_fused(
     spec,
     widths: tuple,
     wvec=None,
-    shard_ctx: "ShardCtx | None" = None,
 ):
     """Fused Filter+Score for one slot using wave-precomputed tensors.
     Bit-identical to the reference chain (sim.jax_runtime.eval_pod) — the
@@ -1072,31 +1049,13 @@ def eval_pod_fused(
 
     ``wvec`` (optional [len(POLICY_COLS)] traced f32) swaps the static
     config weights for per-scenario policy-vector columns (round 9 tuner);
-    filtering is weight-independent and unchanged.
-
-    ``shard_ctx`` (round 14): evaluate one NODE SHARD inside shard_map —
-    every per-node op is local; the only cross-shard values are the
-    score-normalization extrema (one packed ``pmax`` carrying the stacked
-    hi/lo rows + the global any-feasible bit) and the spread filter's
-    per-constraint domain minimum (one ``pmin``), both exact in f32
-    (max-of-per-shard-maxes IS the global max). Traces whose score rows
-    are all absolute (fit-only — the Borg shape) compile with NO
-    collective here at all. With ``shard_ctx=None`` the program is
-    token-identical to before. NOTE: in sharded mode the returned
-    ``any_f`` is only global when a normalization row forced the packed
-    pmax; callers must take placement from select_node_sharded (whose
-    reduce spans shards), never from ``any_f``."""
+    filtering is weight-independent and unchanged."""
     N = dc.allocatable.shape[0]
     A, B, SP = widths
     K = p.lhs.shape[0]
 
     used1 = st.used + s.req[None, :]  # shared by fit mask + fit score
     feasible = jnp.ones(N, dtype=bool)
-    if shard_ctx is not None and shard_ctx.nshards * shard_ctx.n_local > shard_ctx.n_real:
-        # Pad rows (node axis rounded up to a multiple of nshards) are
-        # never feasible — their capacity/label/taint fill is neutral but
-        # this mask is the guarantee.
-        feasible = shard_gids(shard_ctx) < np.int32(shard_ctx.n_real)
     if spec.fit:
         feasible = jnp.all(used1 <= dc.allocatable + 1e-6, axis=1)
     if spec.taints:
@@ -1130,10 +1089,6 @@ def eval_pod_fused(
         cnts = reads[A + B : A + B + SP]  # [SP, N]
         gval = p.gvalid[A + B : A + B + SP]
         minv = jnp.min(jnp.where(gval, cnts, jnp.inf), axis=1)
-        if shard_ctx is not None:
-            # Per-constraint min over the GLOBAL domain set (pad nodes
-            # carry gdom = -1 → gval False, auto-excluded).
-            minv = jax.lax.pmin(minv, shard_ctx.axis)
         has = jnp.isfinite(minv)
         c_ok = (
             gval
@@ -1207,19 +1162,6 @@ def eval_pod_fused(
         lo_stack = jnp.where(feasible[None, :], jnp.stack(lo_rows), jnp.inf)
         hi = jnp.max(hi_stack, axis=1)
         lo = jnp.min(lo_stack, axis=1)
-        if shard_ctx is not None:
-            # ONE packed pmax carries every row's hi, −lo, and the global
-            # any-feasible bit. Exact: f32 max of per-shard maxes is the
-            # global max (same value set), and −(+inf) = −inf is a clean
-            # identity for the empty-shard rows.
-            nrm = hi.shape[0]
-            packed = jnp.concatenate(
-                [hi, -lo, jnp.where(any_f, 1.0, 0.0)[None].astype(jnp.float32)]
-            )
-            packed = jax.lax.pmax(packed, shard_ctx.axis)
-            hi = packed[:nrm]
-            lo = -packed[nrm : 2 * nrm]
-            any_f = packed[-1] > 0.5
         for i, (raw, wt, minmax, reverse) in enumerate(rows):
             out = _normalize_row(raw, lo[i], hi[i], any_f, minmax, reverse)
             total = total + wt * out
@@ -1256,211 +1198,6 @@ def apply_unbind_wave(
         "w,wg->g", w, pmg_f * has_dom.astype(jnp.float32), precision=_HI
     )
     anti_wg, pref_wg = _pod_group_vectors(sb, G)  # [W, G] each
-    anti = st.anti_active - jnp.einsum("w,wg,wgn->gn", w, anti_wg, dom_sel, precision=_HI)
-    pref = st.pref_wsum - jnp.einsum("w,wg,wgn->gn", w, pref_wg, dom_sel, precision=_HI)
-    return DevState(
-        used=used, match_count=match_count, anti_active=anti, pref_wsum=pref,
-        match_total=match_total,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Node-sharded selection + state update (round 14 big-scenario mode)
-#
-# Each device carries one contiguous node block; the wave step stays the
-# same math with three changes, all exact:
-# 1. eval_pod_fused(shard_ctx=...) localizes every per-node op and routes
-#    the normalization extrema through one packed pmax (f32 max-of-maxes
-#    is the global max — scores stay bit-identical).
-# 2. selection is two-stage: the per-shard variadic reduce, then ONE tiny
-#    all_gather of (score, global node id, bind-domain row) with a static
-#    fold — lowest-global-id tie-break at equal score equals the
-#    replicated argmax because shards are contiguous blocks.
-# 3. the winning bind broadcasts back as a masked per-shard plane update:
-#    only the owner shard's one-hot is nonzero, while the [G] domain row
-#    (gdom_at/has_dom, exchanged with the winner) applies the count-plane
-#    update to every shard's slice of the winner's domain.
-# ---------------------------------------------------------------------------
-
-
-def two_phase_exchange() -> bool:
-    """Round-19 A/B gate for the slim two-phase selection exchange.
-    Read at TRACE time (engine build), not import time, so tests and the
-    ``overlap:`` config section can flip it per engine: set
-    ``KSIM_TWO_PHASE_EXCHANGE=0`` before building an engine to compile
-    the legacy single-gather program."""
-    return os.environ.get("KSIM_TWO_PHASE_EXCHANGE", "1") not in ("", "0")
-
-
-def exchange_payload_bytes(nshards: int, groups: int, two_phase: bool) -> int:
-    """Bytes RECEIVED per shard per selection slot by the exchange —
-    the latency-proportional payload scaling_probe/bench pin.
-
-    Legacy single-phase: one all_gather of a ``[2 + 2G]`` f32 row from
-    every shard. Two-phase: an all_gather of the ``[2]`` f32
-    (score, gid) pair plus an all-reduce of the owner-masked ``[2G]``
-    f32 domain row, charged at the standard ring all-reduce cost of
-    2·(n−1)/n of the row per shard — so the two-phase payload equals
-    legacy at n = 2 (the reduce degenerates to a peer swap) and is
-    strictly smaller at every n ≥ 3."""
-    g2 = 2 * int(groups)
-    n = max(int(nshards), 1)
-    if n <= 1:
-        return 0  # no collective compiles on a single shard
-    if not two_phase:
-        return 4 * (n - 1) * (2 + g2)
-    return 4 * ((n - 1) * 2 + (2 * (n - 1) * g2) // n)
-
-
-def select_node_sharded(
-    scores: jax.Array, feasible: jax.Array, gdom_f: jax.Array, ctx: ShardCtx
-):
-    """Two-stage select over node shards → (choice GLOBAL i32, placed,
-    gdom_at [G] f32, has_dom [G] f32). Bit-identical to
-    :func:`select_node` on the unsharded planes: global node ids < 2²⁴
-    are exact in f32 and the (max score, min id) fold reproduces numpy's
-    first-occurrence argmax.
-
-    Two exchange programs compile behind :func:`two_phase_exchange`:
-
-    * legacy (round 14): ONE all_gather of a ``[2 + 2G]`` f32 row
-      (score, gid, domain row) per shard, folded statically.
-    * two-phase (round 19): phase 1 all_gathers only the ``[2]`` f32
-      (score, gid) pair and folds the winner — replicated on every
-      shard; phase 2 moves the winner's ``[2G]`` domain row with a
-      single owner-selected exchange, a psum of the row masked to the
-      owner shard (``winner_gid // n_local`` — shards are contiguous
-      blocks, and the owner's LOCAL argmax IS the global winner, so its
-      candidate row is exactly the winner's row). The mask makes every
-      non-owner contribution ±0.0, so the f32 sum returns the owner's
-      row exactly; when nothing is feasible anywhere the psum of
-      all-masked rows is the same zero row the legacy fold returns, and
-      downstream ``has_dom > 0.5`` gates keep it inert. Payload per
-      shard drops from ``nshards·(2+2G)`` to ``nshards·2 + ~2·2G`` f32
-      per slot — the latency term the ROADMAP flags at 40+ shards.
-    """
-    masked = jnp.where(feasible, scores, NEG_INF)
-    iota = jax.lax.broadcasted_iota(jnp.int32, masked.shape, masked.ndim - 1)
-
-    def comb(a, b):
-        av, ai = a
-        bv, bi = b
-        better = (bv > av) | ((bv == av) & (bi < ai))
-        return jnp.where(better, bv, av), jnp.where(better, bi, ai)
-
-    mx, loc = jax.lax.reduce(
-        (masked, iota),
-        (np.float32(-np.inf), np.int32(np.iinfo(np.int32).max)),
-        comb,
-        dimensions=(masked.ndim - 1,),
-    )
-    ok = mx > NEG_INF
-    off = jax.lax.axis_index(ctx.axis).astype(jnp.int32) * np.int32(ctx.n_local)
-    gid = off + jnp.where(ok, loc, 0)  # guard the int32-max empty sentinel
-    # Empty shards advertise a giant-but-finite id so the fold's
-    # min-id tie-break stays well-ordered (their −inf score loses anyway).
-    gid_f = jnp.where(ok, gid.astype(jnp.float32), np.float32(2.0**31))
-    oh = ((jnp.arange(ctx.n_local) == loc) & ok).astype(jnp.float32)
-    gdom_cand = jnp.einsum("gn,n->g", gdom_f, oh, precision=_HI)
-    hasdom_cand = jnp.einsum(
-        "gn,n->g", (gdom_f >= 0).astype(jnp.float32), oh, precision=_HI
-    )
-    G = gdom_f.shape[0]
-
-    def fold(rows):
-        best = rows[0]
-        for k in range(1, ctx.nshards):
-            cand = rows[k]
-            better = (cand[0] > best[0]) | (
-                (cand[0] == best[0]) & (cand[1] < best[1])
-            )
-            best = jnp.where(better, cand, best)
-        return best
-
-    if not two_phase_exchange():
-        row = jnp.concatenate([mx[None], gid_f[None], gdom_cand, hasdom_cand])
-        best = fold(jax.lax.all_gather(row, ctx.axis))  # [nshards, 2 + 2G]
-        placed = best[0] > NEG_INF
-        choice = jnp.where(placed, best[1], 0.0).astype(jnp.int32)
-        choice = jnp.where(placed, choice, PAD)
-        return choice, placed, best[2 : 2 + G], best[2 + G : 2 + 2 * G]
-
-    # Phase 1: winner election on the [2] f32 (score, gid) pair only.
-    best = fold(jax.lax.all_gather(jnp.stack([mx, gid_f]), ctx.axis))
-    placed = best[0] > NEG_INF
-    choice = jnp.where(placed, best[1], 0.0).astype(jnp.int32)
-    # Phase 2: owner-selected domain-row exchange. The owner's local
-    # candidate row is the winner's row; everyone else contributes ±0.0.
-    owner = choice // np.int32(ctx.n_local)
-    mine = (
-        (jax.lax.axis_index(ctx.axis).astype(jnp.int32) == owner) & placed
-    ).astype(jnp.float32)
-    dom = jax.lax.psum(
-        jnp.concatenate([gdom_cand, hasdom_cand]) * mine, ctx.axis
-    )
-    choice = jnp.where(placed, choice, PAD)
-    return choice, placed, dom[:G], dom[G:]
-
-
-def apply_binding_sharded(
-    d: Derived, st: DevState, s: PodSlot, node: jax.Array, on: jax.Array,
-    gdom_at: jax.Array, has_dom: jax.Array, ctx: ShardCtx,
-) -> DevState:
-    """apply_binding on one node shard. ``node`` is the GLOBAL winner id
-    (replicated from select_node_sharded) — only the owner shard's
-    one-hot fires for the [N, R] resource row, while ``gdom_at``/
-    ``has_dom`` (the winner's [G] domain row) drive each shard's slice of
-    the domain-equality count-plane update. ``match_total`` is replicated
-    state: every shard applies the identical scalar-per-group add."""
-    G = st.match_count.shape[0]
-    w = jnp.where(on & s.valid, 1.0, 0.0).astype(jnp.float32)
-    oh_n = ((shard_gids(ctx) == node) & (node >= 0)).astype(jnp.float32)
-    dom_sel = (
-        (d.gdom_f == gdom_at[:, None]) & (has_dom[:, None] > 0.5) & (d.gdom_f >= 0)
-    ).astype(jnp.float32)
-    used = st.used + (w * oh_n)[:, None] * s.req[None, :]
-    pmg_f = s.pmg.astype(jnp.float32)
-    match_count = st.match_count + (w * pmg_f)[:, None] * dom_sel
-    match_total = st.match_total + w * pmg_f * has_dom
-    anti_g, pref_g = _pod_group_vectors(s, G)
-    anti = st.anti_active + (w * anti_g)[:, None] * dom_sel
-    pref = st.pref_wsum + (w * pref_g)[:, None] * dom_sel
-    return DevState(
-        used=used, match_count=match_count, anti_active=anti, pref_wsum=pref,
-        match_total=match_total,
-    )
-
-
-def apply_unbind_wave_sharded(
-    d: Derived, st: DevState, sb: PodSlot, choice: jax.Array,
-    revert: jax.Array, gdom_at_w: jax.Array, has_dom_w: jax.Array,
-    ctx: ShardCtx,
-) -> DevState:
-    """apply_unbind_wave on one node shard: ``choice`` carries GLOBAL ids
-    and ``gdom_at_w``/``has_dom_w`` ([W, G], stacked from the wave's
-    selections) replace the local one-hot domain recovery — the bound
-    node's domain row lives on its owner shard, so it must ride in from
-    selection rather than be recomputed locally."""
-    G = st.match_count.shape[0]
-    w = jnp.where(revert & sb.valid, 1.0, 0.0).astype(jnp.float32)  # [W]
-    gids = shard_gids(ctx)
-    oh = ((gids[None, :] == choice[:, None]) & (choice[:, None] >= 0)).astype(
-        jnp.float32
-    )  # [W, n_local]
-    used = st.used - jnp.einsum("w,wn,wr->nr", w, oh, sb.req, precision=_HI)
-    dom_sel = (
-        (d.gdom_f[None] == gdom_at_w[:, :, None])
-        & (has_dom_w[:, :, None] > 0.5)
-        & (d.gdom_f >= 0)[None]
-    ).astype(jnp.float32)  # [W, G, n_local]
-    pmg_f = sb.pmg.astype(jnp.float32)  # [W, G]
-    match_count = st.match_count - jnp.einsum(
-        "w,wg,wgn->gn", w, pmg_f, dom_sel, precision=_HI
-    )
-    match_total = st.match_total - jnp.einsum(
-        "w,wg->g", w, pmg_f * has_dom_w, precision=_HI
-    )
-    anti_wg, pref_wg = _pod_group_vectors(sb, G)
     anti = st.anti_active - jnp.einsum("w,wg,wgn->gn", w, anti_wg, dom_sel, precision=_HI)
     pref = st.pref_wsum - jnp.einsum("w,wg,wgn->gn", w, pref_wg, dom_sel, precision=_HI)
     return DevState(

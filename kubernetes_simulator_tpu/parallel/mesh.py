@@ -26,16 +26,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 SCENARIO_AXIS = "scenarios"
 
-#: Intra-scenario node-plane axis (round 14). Where SCENARIO_AXIS shards
-#: *which* cluster each device sees, NODE_AXIS shards *the nodes of one
-#: cluster*: each device holds a contiguous block of the node planes
-#: ([N, R] resources, [G, N] count planes) and evaluates its block's
-#: Filter+Score; selection is a two-stage argmax (local per-shard reduce,
-#: then one tiny cross-device (score, global-node-id) exchange — see
-#: ops.tpu.select_node_sharded). Composes with the scenario/DCN axes by
-#: nesting: processes × scenarios × node shards.
-NODE_AXIS = "nodes"
-
 
 def init_distributed(
     coordinator_address: Optional[str] = None,
@@ -83,25 +73,6 @@ def make_mesh(num_devices: Optional[int] = None, axis: str = SCENARIO_AXIS) -> M
     return Mesh(np.array(devs), (axis,))
 
 
-def make_node_mesh(num_shards: int) -> Mesh:
-    """1-D device mesh over the NODE axis — ``num_shards`` devices each
-    carrying 1/num_shards of a single scenario's node planes. Raises when
-    the host does not expose that many devices (node sharding never spans
-    processes; compose with parallel.dcn for that). LOCAL devices only:
-    inside a DCN fleet ``jax.devices()`` leads with process 0's devices,
-    which are unaddressable from every other process — a node-sharded
-    source replay feeding a fleet (the round-18 work-queue fork leg)
-    must shard over the devices this process owns."""
-    devs = jax.local_devices()
-    if num_shards > len(devs):
-        raise ValueError(
-            f"node_shards={num_shards} exceeds the {len(devs)} visible "
-            f"devices; use node_shards <= {len(devs)} (or shard scenarios "
-            "across processes with parallel.dcn instead)"
-        )
-    return Mesh(np.array(devs[:num_shards]), (NODE_AXIS,))
-
-
 def spans_processes(mesh: Optional[Mesh]) -> bool:
     """True when ``mesh`` contains devices this process cannot address —
     i.e. it is a cross-process (DCN) mesh. The engine localizes such
@@ -122,36 +93,6 @@ def scenario_sharding(mesh: Mesh, axis: str = SCENARIO_AXIS) -> NamedSharding:
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
-
-
-def node_sharding(mesh: Mesh, spec: P) -> NamedSharding:
-    """NamedSharding for one node-plane tensor from its PartitionSpec
-    (``P(NODE_AXIS)`` for [N, ...] planes, ``P(None, NODE_AXIS)`` for
-    [G, N] / [T, N] planes, ``P()`` for replicated scalars/tables)."""
-    return NamedSharding(mesh, spec)
-
-
-def pad_node_axis(a: np.ndarray, axis: int, n_pad: int, fill) -> np.ndarray:
-    """Host copy of ``a`` with its node ``axis`` padded to ``n_pad`` rows
-    of ``fill``. Padding is host-side only — encoded inputs and results
-    always keep the real node count; sharded device planes carry the pad
-    so every shard is the same width (see shard_node_planes)."""
-    n = a.shape[axis]
-    if n == n_pad:
-        return np.asarray(a)
-    pad = [(0, 0)] * a.ndim
-    pad[axis] = (0, n_pad - n)
-    return np.pad(np.asarray(a), pad, constant_values=fill)
-
-
-def shard_node_planes(mesh: Mesh, tree, specs):
-    """device_put every leaf of ``tree`` under the matching PartitionSpec
-    in ``specs`` (same structure). Leaves must already be padded so the
-    node axis divides ``mesh`` evenly (pad_node_axis); a leaf with spec
-    P() is replicated across the node shards."""
-    return jax.tree.map(
-        lambda a, sp: jax.device_put(a, NamedSharding(mesh, sp)), tree, specs
-    )
 
 
 def _global_put(a, sh: NamedSharding):

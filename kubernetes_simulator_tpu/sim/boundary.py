@@ -40,17 +40,6 @@ chunk_waves=1`` the boundary follows every pod and placements match
 traces (tests/test_kube_preempt.py); at production chunk sizes the
 divergence is a measured, pinned number — the same contract as
 completions (tests/test_divergence_pin.py).
-
-Node sharding (round 14): the boundary pass is sharding-agnostic by
-construction. The mirror lives in HOST layout over the real node count
-(never the shard padding), the device choices it folds are GLOBAL node
-ids (ops.tpu.select_node_sharded reduces shard-local winners to the
-global argmax before anything leaves the chunk program), and the
-(release, bind, evict) lists it returns land on the sharded carry
-through the same pad-and-shard transform as every other host delta
-(JaxReplayEngine._to_dev_state_v2). Nothing here branches on the shard
-count — which is what keeps checkpoint blobs and JSONL byte-identical
-across node_shards ∈ {1, 2, 4} (tests/test_node_sharding.py).
 """
 
 from __future__ import annotations

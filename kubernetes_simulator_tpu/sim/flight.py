@@ -6,10 +6,9 @@ watchable while it executes and attributable afterwards.
 Every row carries: virtual time at the chunk boundary, placements /
 slots dispatched so far, a rolling placements-per-second gauge,
 PHASE_NAMES phase-timer deltas since the previous event, pager state
-(prefetch depth, cumulative stall count, stall wall-time), the
-selection-exchange probe wall under nodeShards, checkpoint blob bytes,
-and memory residency (the ``replicated_resident_bytes`` estimate plus
-the host RSS high-water from ``getrusage``).
+(prefetch depth, cumulative stall count, stall wall-time), checkpoint
+blob bytes, and memory residency (the ``replicated_resident_bytes``
+estimate plus the host RSS high-water from ``getrusage``).
 
 The recorder is OFF by default and bit-parity pinned
 (tests/test_flight.py): placements, deterministic JSONL and checkpoint
@@ -21,9 +20,7 @@ under DCN); ``KSIM_DETERMINISTIC_JSONL=1`` zeroes every wall-clock-
 derived field (``FLIGHT_WALL_FIELDS``) so a fixed-seed recorder stream
 is byte-stable — the flight twin of the replay-row scrub.
 
-Consumers: ``scripts/bottleneck_report.py`` (dominant-regime naming),
-``scripts/dcn_launch.py --watch`` (live recorder lines), and bench.py's
-``borg_headline`` mode.
+Consumer: ``scripts/dcn_launch.py --watch`` (live recorder lines).
 """
 
 from __future__ import annotations
@@ -59,8 +56,6 @@ FLIGHT_WALL_FIELDS = (
     "pager_prefetch_s",
     "pager_wait_s",
     "pager_waits",
-    "exchange_probe_s",
-    "exchange_est_s",
     "ckpt_wall_s",
     "rss_peak_mib",
     # Round 22: serving-plane query rows carry the batch's wall latency
@@ -176,8 +171,6 @@ class FlightRecorder:
         placed: Optional[int] = None,
         phase_acc: Optional[Dict[str, float]] = None,
         pager=None,
-        exchange_probe_s: Optional[float] = None,
-        exchange_slots: Optional[int] = None,
         ckpt_publish: Optional[dict] = None,
         kv_retry: Optional[dict] = None,
     ) -> None:
@@ -185,9 +178,6 @@ class FlightRecorder:
         accumulator (the collector's or this recorder's own) — the row
         carries deltas since the previous chunk row. ``pager`` is a
         ``_PodPager`` (or anything with stalls/stall_s/prefetches/depth).
-        ``exchange_probe_s`` is one timed round of the selection-exchange
-        probe; ``exchange_est_s`` scales it to the chunk's slot count
-        (the per-slot all_gather runs once per slot inside the scan).
         ``kv_retry`` (round 17) is the chunk's KV retry delta — retries
         burned, give-ups, backoff wall — attributing coordination-plane
         flakiness (real or faultline-injected) to the chunk it hit."""
@@ -251,13 +241,6 @@ class FlightRecorder:
             row["pager_invalidations"] = int(
                 getattr(pager, "invalidations", 0)
             )
-        if exchange_probe_s is not None:
-            row["exchange_probe_s"] = round(float(exchange_probe_s), 6)
-            if exchange_slots:
-                row["exchange_slots"] = int(exchange_slots)
-                row["exchange_est_s"] = round(
-                    float(exchange_probe_s) * int(exchange_slots), 6
-                )
         if ckpt_publish:
             row["dcn_publish"] = dict(ckpt_publish)
         if kv_retry:
@@ -437,7 +420,7 @@ class FlightRecorder:
 
 def read_stream(path: str):
     """Parsed flight rows from ``path`` (list of dicts, malformed lines
-    skipped). Shared by bottleneck_report and the tests."""
+    skipped)."""
     import json
 
     rows = []

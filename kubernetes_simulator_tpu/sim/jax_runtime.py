@@ -464,127 +464,6 @@ def make_chunk_fn_rej(wave_width: int, spec: StepSpec):
     return jax.jit(chunk_fn, donate_argnums=(1, 2))
 
 
-def _node_plane_specs():
-    """(DevCluster, DevState) PartitionSpec trees for the node-sharded
-    chunk program (round 14): [N, ...] leading-axis tensors shard the
-    node axis, [*, N] trailing-axis planes shard the last axis, and the
-    group/expr tables plus ``match_total`` (replicated semantic state —
-    every shard applies the identical scalar updates) carry P()."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh import NODE_AXIS
-
-    dc_specs = T.DevCluster(
-        allocatable=P(NODE_AXIS),
-        node_label_key=P(NODE_AXIS),
-        node_label_kv=P(NODE_AXIS),
-        node_label_num=P(NODE_AXIS),
-        taint_key=P(NODE_AXIS),
-        taint_kv=P(NODE_AXIS),
-        taint_effect=P(NODE_AXIS),
-        node_domain=P(None, NODE_AXIS),
-        num_domains=P(),
-        expr_key=P(),
-        expr_op=P(),
-        expr_vals=P(),
-        expr_num=P(),
-        group_topo=P(),
-    )
-    st_specs = T.DevState(
-        used=P(NODE_AXIS),
-        match_count=P(None, NODE_AXIS),
-        anti_active=P(None, NODE_AXIS),
-        pref_wsum=P(None, NODE_AXIS),
-        match_total=P(),
-    )
-    return dc_specs, st_specs
-
-
-def make_wave_step_sharded(
-    dc: T.DevCluster, d: T.Derived, wave_width: int, spec: StepSpec,
-    ctx: "T.ShardCtx",
-):
-    """:func:`make_wave_step` over one NODE SHARD (round 14 big-scenario
-    mode; runs inside shard_map — ``dc``/``d``/``st`` carry the local
-    node block). Three deltas from the replicated body, each exact (see
-    ops.tpu's sharded section): the fused eval takes ``shard_ctx``;
-    selection is the two-stage :func:`ops.tpu.select_node_sharded` whose
-    global (score, node-id, bind-domain-row) exchange also yields
-    ``placed`` (the local ``any_f`` never decides placement); and the
-    winner's [G] domain row is stacked across the wave so gang rollback
-    can undo count-plane updates without re-reading the owner shard."""
-
-    def wave_step(st: T.DevState, slot_batch: T.PodSlot):
-        pre = T.build_wave_pre(dc, d, slot_batch, spec)
-        widths = T.wave_widths(slot_batch, spec)
-        choices, placeds, gdoms, hasdoms = [], [], [], []
-        for wslot in range(wave_width):
-            s = jax.tree.map(lambda a: a[wslot], slot_batch)
-            p = jax.tree.map(lambda a: a[wslot], pre)
-            feasible, scores, _any_f = T.eval_pod_fused(
-                dc, d, st, s, p, spec, widths, shard_ctx=ctx
-            )
-            node, placed_any, gdom_at, has_dom = T.select_node_sharded(
-                scores, feasible, d.gdom_f, ctx
-            )
-            placed = placed_any & s.valid
-            st = T.apply_binding_sharded(
-                d, st, s, node, placed, gdom_at, has_dom, ctx
-            )
-            choices.append(node)
-            placeds.append(placed)
-            gdoms.append(gdom_at)
-            hasdoms.append(has_dom)
-        choice = jnp.stack(choices)  # [W] GLOBAL node ids
-        placed = jnp.stack(placeds)  # [W]
-        if spec.has_gangs:
-            groups = slot_batch.group  # [W]
-            same = (groups[:, None] == groups[None, :]) & (groups[:, None] >= 0)
-            fail = jnp.any(same & ~placed[None, :], axis=1)
-            revert = placed & fail
-            st = T.apply_unbind_wave_sharded(
-                d, st, slot_batch, choice, revert,
-                jnp.stack(gdoms), jnp.stack(hasdoms), ctx,
-            )
-            final = jnp.where(placed & ~fail, choice, PAD).astype(jnp.int32)
-        else:
-            final = jnp.where(placed, choice, PAD).astype(jnp.int32)
-        return st, final
-
-    return wave_step
-
-
-def make_chunk_fn_sharded(
-    wave_width: int, spec: StepSpec, mesh, ctx: "T.ShardCtx"
-):
-    """:func:`make_chunk_fn` under shard_map over the NODE axis: each
-    device scans the same waves against its node-plane block; the slots
-    replicate; the choices come out replicated (every shard computes the
-    same global winner — out_spec P()). shard_map, not jit-with-
-    shardings, for the same reason as the what-if mesh path: the sharding
-    becomes a compile-time guarantee and the ONLY collectives are the
-    tiny per-slot exchanges the sharded primitives spell out (pinned by
-    tests/test_mesh_hlo.py)."""
-    from jax.sharding import PartitionSpec as P
-
-    dc_specs, st_specs = _node_plane_specs()
-
-    def body(dc: T.DevCluster, state: T.DevState, slots: T.PodSlot):
-        d = T.Derived.build(dc)
-        wave_step = make_wave_step_sharded(dc, d, wave_width, spec, ctx)
-        state, choices = jax.lax.scan(wave_step, state, slots)
-        return state, choices
-
-    fn = jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(dc_specs, st_specs, P()),
-        out_specs=(st_specs, P()),
-        check_vma=False,
-    )
-    return jax.jit(fn, donate_argnums=(1,))
-
-
 def replicated_resident_bytes(
     ec: EncodedCluster, pods: EncodedPods, pods_resident: bool = True
 ) -> int:
@@ -593,8 +472,8 @@ def replicated_resident_bytes(
     ``pods_resident`` — the v3 unpaged layout) the whole-trace
     SlotSource/ExtraSource rows. The ``KSIM_MAX_REPLICATED_BYTES`` gate
     in JaxReplayEngine refuses replicated runs past this estimate with a
-    pointer at node_shards/paged — the Borg-scale shapes (10k nodes ×
-    1M pods) are exactly the ones that OOM one chip silently otherwise."""
+    pointer at paged — the Borg-scale shapes (10k nodes × 1M pods) are
+    exactly the ones that OOM one chip silently otherwise."""
     dc_fields = (
         ec.allocatable, ec.node_label_key, ec.node_label_kv,
         ec.node_label_num, ec.taint_key, ec.taint_kv, ec.taint_effect,
@@ -984,7 +863,6 @@ class JaxReplayEngine:
         lazy_boundary: bool = True,
         double_buffer: bool = True,
         telemetry=None,
-        node_shards: int = 0,
         paged: bool = False,
         flight_recorder=None,
     ):
@@ -1056,34 +934,15 @@ class JaxReplayEngine:
                 "preemption='kube' requires retry_buffer > 0 (failed pods "
                 "reach the PostFilter through the boundary retry pass)"
             )
-        # Round 14 big-scenario mode: shard ONE scenario's node planes over
-        # the local devices (node_shards > 1) and/or stream pod pages
-        # host->device (paged) instead of keeping the whole trace resident.
-        self.node_shards = int(node_shards or 0)
+        # Round 14 big-scenario mode: stream pod pages host->device (paged)
+        # instead of keeping the whole trace resident.
         self.paged = bool(paged)
-        if self.node_shards > 1 and mode == "tier":
-            raise ValueError(
-                "node_shards is not supported with tier preemption: the "
-                "node-sharded chunk program is the node-space (v2) engine "
-                "and tier preemption is v3-only — use preemption='kube'"
-            )
         if self.paged and (mode == "kube" or retry_buffer):
             raise ValueError(
                 "paged=True is not supported with retry_buffer / "
                 "preemption='kube' yet — the boundary mirror pre-stages the "
                 "whole wave index tensor; run paged replays on the plain path"
             )
-        if self.node_shards > 1 and engine == "v3":
-            from ..utils.metrics import log
-
-            log.info(
-                "node_shards=%d: forcing engine='v2' — the node-sharded "
-                "chunk program runs on the node-space planes (the v3 "
-                "domain-space layout replicates exactly the per-domain "
-                "state node sharding is meant to split)",
-                self.node_shards,
-            )
-            engine = "v2"
         self.ec = ec
         self.pods = pods
         self.spec = StepSpec.from_config(ec, config, pods)
@@ -1118,7 +977,7 @@ class JaxReplayEngine:
         import os
 
         budget = os.environ.get("KSIM_MAX_REPLICATED_BYTES")
-        if budget and self.node_shards <= 1:
+        if budget:
             est = replicated_resident_bytes(
                 ec, pods, pods_resident=(engine == "v3" and not self.paged)
             )
@@ -1126,25 +985,10 @@ class JaxReplayEngine:
                 raise ValueError(
                     f"replicated single-scenario residency ~{est / 2**20:.0f} "
                     f"MiB/device exceeds KSIM_MAX_REPLICATED_BYTES "
-                    f"({int(budget) / 2**20:.0f} MiB): shard the node axis "
-                    "across devices (node_shards=...) and/or stream pod "
-                    "pages (paged=True) instead of the replicated path"
+                    f"({int(budget) / 2**20:.0f} MiB): stream pod pages "
+                    "(paged=True) instead of keeping the trace resident"
                 )
-        if self.node_shards > 1:
-            from ..parallel import mesh as M
-
-            self._node_mesh = M.make_node_mesh(self.node_shards)
-            n_real = ec.num_nodes
-            n_local = -(-n_real // self.node_shards)
-            self._n_real = n_real
-            self._n_pad = n_local * self.node_shards
-            self._shard_ctx = T.ShardCtx(
-                axis=M.NODE_AXIS, n_local=n_local, n_real=n_real,
-                nshards=self.node_shards,
-            )
-            self.dc = self._shard_cluster(ec)
-        else:
-            self.dc = T.DevCluster.from_encoded(ec)
+        self.dc = T.DevCluster.from_encoded(ec)
         # "auto": measured optimum is W=8 across shapes (W=16 loses to the
         # W² in-wave coupling even on coarse-only traces) — kept as a
         # resolution point for when the kernel cost model changes.
@@ -1159,10 +1003,6 @@ class JaxReplayEngine:
             self.chunk_fn = make_chunk_fn3_src(
                 self.static3, self.shared3, rep_slots_for(self.static3, pods),
                 wave_width, self.spec,
-            )
-        elif self.node_shards > 1:
-            self.chunk_fn = make_chunk_fn_sharded(
-                wave_width, self.spec, self._node_mesh, self._shard_ctx
             )
         else:
             self.chunk_fn = make_chunk_fn(wave_width, self.spec)
@@ -1186,68 +1026,8 @@ class JaxReplayEngine:
             else None
         )
 
-    def _shard_cluster(self, ec: EncodedCluster) -> T.DevCluster:
-        """Padded + node-sharded device cluster. Node-axis tensors are
-        padded to the shard width with NEUTRAL fill (zero capacity, PAD
-        labels/taints/domains, no-op taint effect) so pad rows filter out
-        identically on every plugin, then placed under the node-plane
-        shardings. ``ec`` itself is untouched — results and the host mirror
-        always see the real node count."""
-        from ..parallel import mesh as M
-
-        n_pad = self._n_pad
-        pad = M.pad_node_axis
-        host = T.DevCluster(
-            allocatable=pad(ec.allocatable, 0, n_pad, 0.0),
-            node_label_key=pad(ec.node_label_key, 0, n_pad, PAD),
-            node_label_kv=pad(ec.node_label_kv, 0, n_pad, PAD),
-            node_label_num=pad(ec.node_label_num, 0, n_pad, 0.0),
-            taint_key=pad(ec.taint_key, 0, n_pad, PAD),
-            taint_kv=pad(ec.taint_kv, 0, n_pad, PAD),
-            taint_effect=pad(ec.taint_effect, 0, n_pad, 0),
-            node_domain=pad(ec.node_domain, 1, n_pad, PAD),
-            num_domains=np.asarray(ec.num_domains),
-            expr_key=np.asarray(ec.expr_key),
-            expr_op=np.asarray(ec.expr_op),
-            expr_vals=np.asarray(ec.expr_vals),
-            expr_num=np.asarray(ec.expr_num),
-            group_topo=np.asarray(ec.group_topo),
-        )
-        dc_specs, _ = _node_plane_specs()
-        return M.shard_node_planes(self._node_mesh, host, dc_specs)
-
-    def _put_alloc(self, alloc: np.ndarray):
-        """Device copy of an allocatable plane, re-placed under the node
-        sharding when the node mesh is active (a bare jnp.asarray would
-        leave the replaced DevCluster with mixed shardings and trip the
-        shard_map in_specs)."""
-        if self.node_shards > 1:
-            from jax.sharding import PartitionSpec as P
-
-            from ..parallel import mesh as M
-
-            return jax.device_put(
-                alloc, M.node_sharding(self._node_mesh, P(M.NODE_AXIS))
-            )
-        return jnp.asarray(alloc)
-
     def _to_dev_state_v2(self, used, mc, aa, pw, mt) -> T.DevState:
-        """Device v2 (node-space) state/delta from host planes — padded to
-        the shard width and placed under the node-plane shardings when node
-        sharding is active, plain device arrays otherwise."""
-        if self.node_shards > 1:
-            from ..parallel import mesh as M
-
-            n_pad = self._n_pad
-            host = T.DevState(
-                used=M.pad_node_axis(np.asarray(used, np.float32), 0, n_pad, 0.0),
-                match_count=M.pad_node_axis(np.asarray(mc, np.float32), 1, n_pad, 0.0),
-                anti_active=M.pad_node_axis(np.asarray(aa, np.float32), 1, n_pad, 0.0),
-                pref_wsum=M.pad_node_axis(np.asarray(pw, np.float32), 1, n_pad, 0.0),
-                match_total=np.asarray(mt, np.float32),
-            )
-            _, st_specs = _node_plane_specs()
-            return M.shard_node_planes(self._node_mesh, host, st_specs)
+        """Device v2 (node-space) state/delta from host planes."""
         return T.DevState(
             used=jnp.asarray(used),
             match_count=jnp.asarray(mc),
@@ -1256,21 +1036,12 @@ class JaxReplayEngine:
             match_total=jnp.asarray(mt),
         )
 
-    def _unshard_state_v2(self, state) -> T.DevState:
-        """Host node-space copy of a (possibly node-sharded) v2 carry,
-        sliced back to the real node count — checkpoint blobs and result
-        planes never see the shard padding, so they are byte-identical
-        across shard counts."""
-        u = np.asarray(state.used)
-        mc = np.asarray(state.match_count)
-        aa = np.asarray(state.anti_active)
-        pw = np.asarray(state.pref_wsum)
-        if self.node_shards > 1:
-            n = self._n_real
-            u, mc, aa, pw = u[:n], mc[:, :n], aa[:, :n], pw[:, :n]
-        return T.DevState(
-            used=u, match_count=mc, anti_active=aa, pref_wsum=pw,
-            match_total=np.asarray(state.match_total),
+    def _v2_to_host(self, state: T.DevState):
+        """(used, match_count, anti_active, pref_wsum) of a v2 carry in the
+        host's domain-space layout: ``DevState3.to_host``'s twin."""
+        return (np.asarray(state.used),) + tuple(
+            T.node_space_to_domain(np.asarray(p), self._gdom, self._Dhost)
+            for p in (state.match_count, state.anti_active, state.pref_wsum)
         )
 
     def _init_dev_state(self, force_v2: bool = False):
@@ -1302,8 +1073,7 @@ class JaxReplayEngine:
         from .flight import FlightRecorder, FlightRecorderConfig
 
         # Re-resolve here (not just in __init__): callers may assign a
-        # raw path onto .flight_recorder between replays (bench.py turns
-        # the recorder on for the timed run only).
+        # raw path onto .flight_recorder between replays.
         spec = FlightRecorderConfig.resolve(self.flight_recorder)
         if spec is None:
             return None, False
@@ -1312,7 +1082,6 @@ class JaxReplayEngine:
         meta = {
             "nodes": int(self.ec.num_nodes),
             "pods": int(self.pods.num_pods),
-            "node_shards": int(self.node_shards),
             "paged": bool(self.paged),
             "engine": self.engine,
             "chunk_waves": int(self.chunk_waves),
@@ -1325,67 +1094,6 @@ class JaxReplayEngine:
         }
         self._last_flight = FlightRecorder(spec, meta=meta)
         return self._last_flight, True
-
-    def _make_exchange_probe(self):
-        """Timed probe of the per-slot selection exchange (round 16):
-        a jitted shard_map running the EXACT collective shape the sharded
-        wave step compiles (ops.tpu.select_node_sharded) — legacy: one
-        ``all_gather`` of a ``[2 + 2G]`` f32 row plus the static
-        (max score, min id) fold; two-phase (round 19, the default): the
-        ``[2]`` f32 all_gather + fold, then the owner-masked ``[2G]``
-        psum. The production chunk program is untouched (the exchange
-        runs inside its scan, where a host clock cannot reach without
-        changing the compiled program — and the compiled program is
-        exactly what bit-parity pins); the probe prices one exchange
-        round at chunk cadence, and the recorder scales it by the
-        chunk's slot count for the per-chunk estimate. Returns a
-        zero-arg callable → seconds for one probed round."""
-        from jax.sharding import PartitionSpec as P
-
-        G = max(self.ec.num_groups, 1)
-        n = self.node_shards
-        axis = self._shard_ctx.axis
-        two_phase = T.two_phase_exchange()
-
-        def fold(rows):
-            best = rows[0]
-            for k in range(1, n):
-                cand = rows[k]
-                better = (cand[0] > best[0]) | (
-                    (cand[0] == best[0]) & (cand[1] < best[1])
-                )
-                best = jnp.where(better, cand, best)
-            return best
-
-        def body(row):
-            if not two_phase:
-                return fold(jax.lax.all_gather(row, axis))
-            best = fold(jax.lax.all_gather(row[:2], axis))
-            placed = best[0] > T.NEG_INF
-            owner = jnp.where(placed, best[1], 0.0).astype(jnp.int32) // (
-                np.int32(max(self._shard_ctx.n_local, 1))
-            )
-            mine = (
-                (jax.lax.axis_index(axis).astype(jnp.int32) == owner)
-                & placed
-            ).astype(jnp.float32)
-            return best, jax.lax.psum(row[2:] * mine, axis)
-
-        fn = jax.jit(
-            jax.shard_map(
-                body, mesh=self._node_mesh, in_specs=P(), out_specs=P(),
-                check_vma=False,
-            )
-        )
-        row = jnp.zeros(2 + 2 * G, jnp.float32)
-        jax.block_until_ready(fn(row))  # compile outside the timed loop
-
-        def probe() -> float:
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(row))
-            return time.perf_counter() - t0
-
-        return probe
 
     def _save_checkpoint(self, state, cursor: int, all_choices, path: str,
                          released=None, boundary=None) -> None:
@@ -1400,8 +1108,7 @@ class JaxReplayEngine:
             ).save(path)
         else:
             ck = state_to_checkpoint(
-                self._unshard_state_v2(state), self._gdom, self._Dhost,
-                cursor, all_choices,
+                state, self._gdom, self._Dhost, cursor, all_choices,
             )
             ck.released = released
             ck.boundary = boundary
@@ -1565,15 +1272,6 @@ class JaxReplayEngine:
                 ck.used, ck.match_count, ck.anti_active, ck.pref_wsum,
                 self.ec, self.static3,
             )
-        if self.node_shards > 1:
-            g = self._gdom
-            return self._to_dev_state_v2(
-                ck.used,
-                T.domain_to_node_space(ck.match_count, g),
-                T.domain_to_node_space(ck.anti_active, g),
-                T.domain_to_node_space(ck.pref_wsum, g),
-                ck.match_count.sum(axis=1).astype(np.float32),
-            )
         return checkpoint_to_state(ck, self._gdom)
 
     def _replay_boundary(
@@ -1636,11 +1334,6 @@ class JaxReplayEngine:
         rec, rec_own = self._open_recorder()
         _tick = _make_tick(tel if tel is not None else rec)
         with _tick("stage"):
-            probe = (
-                self._make_exchange_probe()
-                if rec is not None and self.node_shards > 1
-                else None
-            )
             bops = BoundaryOps(
                 self.ec, self.pods, fw,
                 WaveBatch(idx=idx, wave_width=self.wave_width),
@@ -1922,9 +1615,6 @@ class JaxReplayEngine:
                             time.perf_counter() - t_ck,
                         )
                 if rec is not None:
-                    ex_s = probe() if probe is not None else None
-                    if ex_s is not None and tel is not None:
-                        tel.phases.add("selection_exchange", ex_s)
                     pub_now = _dcn.publish_stats()
                     ck_pub = None
                     if pub_now != rec_pub:
@@ -1962,10 +1652,6 @@ class JaxReplayEngine:
                             if tel is not None
                             else rec.phases.acc
                         ),
-                        exchange_probe_s=ex_s,
-                        exchange_slots=(
-                            C * idx.shape[1] if ex_s is not None else None
-                        ),
                         ckpt_publish=ck_pub,
                         kv_retry=kv_retry,
                     )
@@ -1986,7 +1672,7 @@ class JaxReplayEngine:
                     jax.block_until_ready(state)
         finally:
             if node_events:
-                self.dc = self.dc._replace(allocatable=self._put_alloc(saved_alloc))
+                self.dc = self.dc._replace(allocatable=jnp.asarray(saved_alloc))
                 self.ec.allocatable[:] = saved_alloc_ec
         wall = time.perf_counter() - t0
 
@@ -1997,11 +1683,7 @@ class JaxReplayEngine:
             if self.engine == "v3":
                 used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
             else:
-                hs = self._unshard_state_v2(state)
-                used = hs.used
-                mc = T.node_space_to_domain(hs.match_count, self._gdom, self._Dhost)
-                aa = T.node_space_to_domain(hs.anti_active, self._gdom, self._Dhost)
-                pw = T.node_space_to_domain(hs.pref_wsum, self._gdom, self._Dhost)
+                used, mc, aa, pw = self._v2_to_host(state)
             util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
             pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
             frag = fragmentation_gauges(
@@ -2054,7 +1736,7 @@ class JaxReplayEngine:
                 alloc[ev.node] = saved_alloc[ev.node]
             elif ev.kind == "capacity_scale":
                 alloc[ev.node] = saved_alloc[ev.node] * ev.scale
-        self.dc = self.dc._replace(allocatable=self._put_alloc(alloc))
+        self.dc = self.dc._replace(allocatable=jnp.asarray(alloc))
 
     def replay(
         self,
@@ -2160,11 +1842,6 @@ class JaxReplayEngine:
         rec, rec_own = self._open_recorder()
         _tick = _make_tick(tel if tel is not None else rec)
         with _tick("stage"):
-            probe = (
-                self._make_exchange_probe()
-                if rec is not None and self.node_shards > 1
-                else None
-            )
             # In-scan rejection attribution (series+): thread a [K] i32 reject
             # counter through the scan carry via the instrumented reference
             # chunk program — one extra fetch per REPLAY, never per pod. The
@@ -2183,14 +1860,6 @@ class JaxReplayEngine:
                     "telemetry: rejection attribution is disabled under "
                     "checkpoint/resume (the instrumented carry is not part of "
                     "checkpoints) — latency/phase telemetry still collected"
-                )
-                use_rej = False
-            if use_rej and self.node_shards > 1:
-                log.info(
-                    "telemetry: rejection attribution is disabled under node "
-                    "sharding (the instrumented reference program carries "
-                    "replicated node planes) — latency/phase telemetry still "
-                    "collected"
                 )
                 use_rej = False
             rej_dev = None
@@ -2482,9 +2151,6 @@ class JaxReplayEngine:
                     )
                     rec_stalls_seen = pager.stalls
                     rec_inval_seen = pager.invalidations
-                ex_s = probe() if probe is not None else None
-                if ex_s is not None and tel is not None:
-                    tel.phases.add("selection_exchange", ex_s)
                 pub_now = _dcn.publish_stats()
                 ck_pub = None
                 if pub_now != rec_pub:
@@ -2525,10 +2191,6 @@ class JaxReplayEngine:
                         tel.phases.acc if tel is not None else rec.phases.acc
                     ),
                     pager=pager,
-                    exchange_probe_s=ex_s,
-                    exchange_slots=(
-                        C * idx.shape[1] if ex_s is not None else None
-                    ),
                     ckpt_publish=ck_pub,
                     kv_retry=kv_retry,
                 )
@@ -2537,7 +2199,7 @@ class JaxReplayEngine:
         wall = time.perf_counter() - t0
         with _tick("gather"):
             if node_events:
-                self.dc = self.dc._replace(allocatable=self._put_alloc(saved_alloc))
+                self.dc = self.dc._replace(allocatable=jnp.asarray(saved_alloc))
 
             preemptions = 0
             to_schedule = int((idx >= 0).sum())
@@ -2595,11 +2257,7 @@ class JaxReplayEngine:
             if self.engine == "v3" and not use_rej:
                 used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
             else:
-                hs = self._unshard_state_v2(state)
-                used = hs.used
-                mc = T.node_space_to_domain(hs.match_count, self._gdom, self._Dhost)
-                aa = T.node_space_to_domain(hs.anti_active, self._gdom, self._Dhost)
-                pw = T.node_space_to_domain(hs.pref_wsum, self._gdom, self._Dhost)
+                used, mc, aa, pw = self._v2_to_host(state)
             util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
             pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
             frag = fragmentation_gauges(

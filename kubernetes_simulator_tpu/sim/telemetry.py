@@ -134,8 +134,8 @@ def latency_summary(
     }
 
 
-# Canonical phase-timer names instrumented by the replay engines. Scripts
-# (scripts/northstar.py, bench consumers) key on these strings when
+# Canonical phase-timer names instrumented by the replay engines. The
+# flight stream's and the benchmark's consumers key on these strings when
 # attributing wall-clock, so they are API: renaming one is a breaking
 # change pinned by tests/test_telemetry.py.
 PHASE_NAMES = (

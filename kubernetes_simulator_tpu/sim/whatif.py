@@ -527,7 +527,6 @@ class WhatIfEngine:
         granularity_guard: bool = True,
         telemetry=None,
         policies=None,
-        node_shards: int = 0,
         _dcn_recovery: Optional[dict] = None,
     ):
         """``collect_assignments``: hand back every task's node in
@@ -595,13 +594,6 @@ class WhatIfEngine:
         from .telemetry import TelemetryConfig
 
         self.telemetry_cfg = TelemetryConfig.resolve(telemetry)
-        if node_shards and int(node_shards) > 1:
-            raise NotImplementedError(
-                "node_shards (intra-scenario node-plane sharding, round 14) "
-                "is a single-replay feature: the what-if batch already "
-                "spends the mesh on the scenario axis. Run the big scenario "
-                "through the 'jax' strategy / JaxReplayEngine(node_shards=...)"
-            )
         pmode = normalize_preemption(preemption)
         # "kube" (round 5): the EXACT minimal-victims PostFilter runs in
         # per-scenario HOST boundary passes (sim.boundary) against the

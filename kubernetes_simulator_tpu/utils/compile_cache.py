@@ -4,8 +4,8 @@ The chunk programs cost minutes of XLA compile per shape; a process
 restart with the SAME shapes should pay seconds. ``enable()`` turns on
 JAX's persistent compilation cache so compiled executables survive across
 processes — every config change still compiles once, but only once per
-cache directory. ``cli.main``, ``bench.py``, ``scripts/northstar.py`` and
-``chip_smoke.py`` all call the one ``enable()``.
+cache directory. ``cli.main``, ``chip_smoke.py`` and ``benchmark/run.py``
+call the one ``enable()``.
 
 Where the cache lives is decided from outside: when
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this module

@@ -250,7 +250,7 @@ class FaultlineSpec:
 @dataclass
 class OverlapSpec:
     """Overlap plane (``overlap:`` YAML section, round 19). Config-level
-    spelling of the three stall-hiding gates — each defaults ON in the
+    spelling of the two stall-hiding gates — each defaults ON in the
     engines; a field left None inherits the engine/env default, an
     explicit false exports the opt-out BEFORE ``jax.distributed``
     bring-up (setdefault — an operator's explicit env wins):
@@ -262,15 +262,12 @@ class OverlapSpec:
       single-flight newest-wins checkpoint publication off the loop
       thread. Requires a checkpoint cadence (``dcn.recovery:
       checkpointEvery >= 1`` or a work queue) when explicitly enabled.
-    * ``twoPhaseExchange`` → ``KSIM_TWO_PHASE_EXCHANGE`` (ops.tpu): slim
-      two-phase selection exchange under ``nodeShards``.
 
-    All three are bit-parity pinned (tests/test_overlap.py): placements,
+    Both are bit-parity pinned (tests/test_overlap.py): placements,
     deterministic JSONL and checkpoint blobs are identical on vs off."""
 
     pager_thread: Optional[bool] = None
     background_publisher: Optional[bool] = None
-    two_phase_exchange: Optional[bool] = None
 
 
 @dataclass
@@ -287,8 +284,8 @@ class ServiceSpec:
     boundary retry pass defrag drains evict through; ``input`` is an
     NDJSON query source (a file or named pipe; null = stdin). Results
     stream to the top-level ``output`` (null = stdout). Requires
-    ``strategy: jax``, ``devicePreemption: kube`` and no
-    ``nodeShards`` — validate_config refuses anything else."""
+    ``strategy: jax`` and ``devicePreemption: kube`` — validate_config
+    refuses anything else."""
 
     max_batch: int = 3
     batch_deadline_s: float = 0.05
@@ -325,6 +322,14 @@ def _coerce_completions(v: object) -> Optional[bool]:
     )
 
 
+#: The refusal for a YAML file that still asks for the removed axis.
+_NODE_SHARDING_REMOVED = (
+    "intra-scenario node sharding was removed: a 10,000-node cluster holds "
+    "36.7 MB on the chip (0.2% of its memory), so splitting one scenario's "
+    "nodes over devices bought nothing; delete the key"
+)
+
+
 @dataclass
 class SimConfig:
     strategy: str = "cpu"
@@ -348,17 +353,15 @@ class SimConfig:
     # PostFilter at chunk boundaries; single-replay engine only — see
     # sim.greedy / sim.boundary docstrings).
     device_preemption: object = False
-    # Big-scenario mode (round 14, jax strategy only): shard ONE scenario's
-    # node planes over `nodeShards` local devices, and/or stream pod pages
+    # Big-scenario mode (round 14, jax strategy only): stream pod pages
     # host->device instead of whole-trace residency (`pagedWaves`).
-    node_shards: int = 0
     paged_waves: bool = False
     # Flight recorder (round 16, jax strategy only): streaming JSONL
     # observability for long replays (sim.flight). None = off (the
     # default — the recorder is bit-parity pinned but still costs a
     # stream).
     flight_recorder: Optional[FlightRecorderSpec] = None
-    # Overlap plane (round 19): the three stall-hiding gates. None = all
+    # Overlap plane (round 19): the two stall-hiding gates. None = all
     # engine defaults (on).
     overlap: Optional[OverlapSpec] = None
     # Resident query service (round 22, `serve` subcommand only). None =
@@ -538,7 +541,8 @@ class SimConfig:
         # bool (legacy: true = tier) or the string "tier"/"kube".
         dp = d.get("devicePreemption", False)
         cfg.device_preemption = dp if isinstance(dp, str) else bool(dp)
-        cfg.node_shards = int(d.get("nodeShards", 0))
+        if int(d.get("nodeShards") or 0) > 1:
+            raise ValueError("nodeShards: " + _NODE_SHARDING_REMOVED)
         cfg.paged_waves = bool(d.get("pagedWaves", False))
         fr = d.get("flightRecorder")
         if fr is not None:
@@ -550,6 +554,10 @@ class SimConfig:
             )
         ov = d.get("overlap")
         if ov is not None:
+            if "twoPhaseExchange" in ov:
+                raise ValueError(
+                    "overlap.twoPhaseExchange: " + _NODE_SHARDING_REMOVED
+                )
 
             def _tristate(key: str) -> Optional[bool]:
                 v = ov.get(key)
@@ -564,7 +572,6 @@ class SimConfig:
             cfg.overlap = OverlapSpec(
                 pager_thread=_tristate("pagerThread"),
                 background_publisher=_tristate("backgroundPublisher"),
-                two_phase_exchange=_tristate("twoPhaseExchange"),
             )
         sv = d.get("service")
         if sv is not None:

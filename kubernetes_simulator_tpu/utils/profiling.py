@@ -3,8 +3,7 @@
 - ``device_trace(dir)``: jax.profiler trace (TensorBoard/Perfetto) around a
   replay.
 - ``profiling_active()`` / ``annotate(name)``: the round-12 device-profiler
-  hook contract — ``KSIM_PROFILE_DIR`` (set directly or via the
-  ``--profile`` flags on bench.py / scripts/northstar.py) arms
+  hook contract — ``KSIM_PROFILE_DIR`` arms
   ``jax.profiler.TraceAnnotation`` markers on the telemetry PHASE_NAMES
   phases and chunk dispatch, so fused-program device time is attributable
   in XLA traces. Off by default; annotations never change results (pinned

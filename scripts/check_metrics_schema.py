@@ -66,8 +66,8 @@ _OPTIONAL = {
     "telemetry": dict,
     "config": str,
     "mesh": bool,
-    # Round 11 (multi-host DCN): provenance fields stamped by bench.py
-    # and DCN-aware writers. Round 12: JsonlWriter stamps process_id +
+    # Round 11 (multi-host DCN): provenance fields stamped by
+    # DCN-aware writers. Round 12: JsonlWriter stamps process_id +
     # process_count on every row of a multi-process fleet (so rows are
     # attributable to the worker that wrote them); single-process files
     # are byte-unchanged, and the DCN parity bar strips exactly these two

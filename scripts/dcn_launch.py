@@ -5,11 +5,9 @@ processes ON ONE MACHINE and run the same command in each.
     python scripts/dcn_launch.py --nproc 2 -- \
         python -m kubernetes_simulator_tpu what-if examples/whatif.yaml
 
-    python scripts/dcn_launch.py --nproc 2 -- python bench.py --dcn
-
 Each child gets ``KSIM_DCN_COORD`` / ``KSIM_DCN_NPROC`` / ``KSIM_DCN_PID``
-(consumed by ``parallel.dcn.maybe_init_from_env`` — the CLI, bench.py and
-scripts/northstar.py all call it on startup), plus
+(consumed by ``parallel.dcn.maybe_init_from_env`` — the CLI calls it on
+startup), plus
 ``--xla_force_host_platform_device_count`` so every process exposes
 ``--devices-per-proc`` virtual CPU devices — the same mechanism real
 multi-host TPU uses, minus the hardware, so the DCN code path runs in CI.
@@ -21,7 +19,7 @@ CPU ONLY. Every child runs with ``JAX_PLATFORMS=cpu`` whatever the
 caller's environment says: a TPU chip belongs to one process at a time,
 so N processes on one machine cannot share it (the second one fails or
 hangs). The fleet layer has never run on a chip; on-chip runs are one
-process — ``python chip_smoke.py``, or the CLI / bench.py directly.
+process — ``python chip_smoke.py``, ``benchmark/run.py`` or the CLI directly.
 
 ``--watch`` (round 12) tails the workers' liveness heartbeats
 (parallel.dcn.heartbeat mirrors each beacon to ``$KSIM_DCN_HB_DIR``) and
@@ -161,7 +159,7 @@ class FleetWatch:
         stream at ``flight_path`` (process 0) and its ``.p<pid>``
         siblings with a byte cursor per file, and renders the newest
         chunk row of each as a one-line gauge: rolling placements/sec,
-        pager stalls, exchange ms. Tolerant of a missing/partial stream
+        pager stalls. Tolerant of a missing/partial stream
         — the recorder is off by default, and a mid-write tail just
         waits for the next interval."""
         if not self.flight_path:
@@ -200,10 +198,6 @@ class FleetWatch:
             )
             if stalls is not None:
                 seg += f" stalls={stalls}"
-            if last.get("exchange_est_s") is not None:
-                seg += (
-                    f" exch={1e3 * float(last['exchange_est_s']):.1f}ms"
-                )
             if last.get("rss_peak_mib"):
                 seg += f" rss={float(last['rss_peak_mib']):.0f}MiB"
             out.append(f"dcn_launch[watch]: {seg}")
@@ -634,8 +628,8 @@ def main(argv=None) -> int:
         metavar="PATH",
         help="round 16: with --watch, also tail this flight-recorder "
              "stream (process 0's path; .p<pid> siblings are tailed "
-             "automatically) and print rolling pps / pager stalls / "
-             "exchange ms per process — point it at the same path the "
+             "automatically) and print rolling pps / pager stalls "
+             "per process — point it at the same path the "
              "children's flightRecorder: config writes. Missing streams "
              "are tolerated (the recorder is off by default)",
     )

@@ -684,7 +684,7 @@ def export_perfetto(
 
 def resolve_links(timeline: List[dict]) -> int:
     """Count parent/link references that resolve to an emitted span —
-    the health gauge of the causal graph (bench_compare surfaces it)."""
+    the health gauge of the causal graph."""
     spans = {
         e.get("span") for e in timeline if isinstance(e.get("span"), str)
     }

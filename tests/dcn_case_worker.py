@@ -485,9 +485,8 @@ def case_wqmerge():
 
 
 def case_wqfork():
-    """Round-18 work-queue over the node-sharded (round-14) leg: every
-    scenario forks from a checkpoint written by a
-    ``JaxReplayEngine(node_shards=2)`` replay, then the S=6 what-if batch
+    """Round-18 work-queue over the fork leg: every scenario forks from
+    a checkpoint written by a ``JaxReplayEngine`` replay, then the S=6 what-if batch
     runs (under the queue when enabled) — placements and the collected
     assignment matrix must bit-match the single-process oracle."""
     from kubernetes_simulator_tpu.framework.framework import FrameworkConfig
@@ -512,7 +511,7 @@ def case_wqfork():
     os.unlink(ck)
     try:
         JaxReplayEngine(
-            ec, ep, cfg, chunk_waves=5, node_shards=2,
+            ec, ep, cfg, chunk_waves=5,
         ).replay(checkpoint_path=ck, checkpoint_every=2)
         scenarios = [Scenario()] + list(
             uniform_scenarios(ec, 5, seed=18, p_capacity=0.5, p_taint=0.2)

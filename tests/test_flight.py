@@ -2,11 +2,10 @@
 
 The contract under test: the recorder is a pure OBSERVER. Turning it on
 changes no placement, no deterministic JSONL byte, and no checkpoint
-blob byte across every engine mode it instruments — plain, nodeShards,
-pagedWaves, kube-boundary — including a cross-mode resume. Its own
-stream is schema-v6 valid, byte-stable for a fixed seed under
-KSIM_DETERMINISTIC_JSONL, and carries the attribution the bottleneck
-report names regimes from. Pager stall counters are pinned on a crafted
+blob byte across every engine mode it instruments — plain, pagedWaves,
+kube-boundary — including a cross-mode resume. Its own stream is
+schema-v6 valid and byte-stable for a fixed seed under
+KSIM_DETERMINISTIC_JSONL. Pager stall counters are pinned on a crafted
 slow-page trace (a sleeping fetch) without any engine in the loop.
 """
 
@@ -60,7 +59,6 @@ def case():
 # Engine-mode matrix: kwargs beyond (ec, ep, cfg, chunk_waves=4).
 MODES = {
     "plain": {},
-    "nodeShards": {"node_shards": 2},
     "pagedWaves": {"paged": True},
     "kube-boundary": {"preemption": "kube", "retry_buffer": 64},
 }
@@ -99,7 +97,7 @@ def test_recorder_checkpoint_blobs_identical_and_cross_mode_resume(
     case, tmp_path
 ):
     """Checkpoint blobs byte-identical recorder on/off, and a blob
-    written recorder-ON under nodeShards resumes recorder-OFF under
+    written recorder-ON resident resumes recorder-OFF under
     pagedWaves (cross-mode resume) to the same end state."""
     ec, ep = case
     ref = JaxReplayEngine(
@@ -109,7 +107,7 @@ def test_recorder_checkpoint_blobs_identical_and_cross_mode_resume(
     for tag, rec in (("off", None), ("on", str(tmp_path / "fl.jsonl"))):
         p = tmp_path / f"ckpt_{tag}.npz"
         res = JaxReplayEngine(
-            ec, ep, FrameworkConfig(), chunk_waves=4, node_shards=2,
+            ec, ep, FrameworkConfig(), chunk_waves=4,
             telemetry="off", flight_recorder=rec,
         ).replay(checkpoint_path=str(p), checkpoint_every=2)
         np.testing.assert_array_equal(res.assignments, ref.assignments)
@@ -125,7 +123,7 @@ def test_recorder_checkpoint_blobs_identical_and_cross_mode_resume(
         r["ckpt_bytes"] == os.path.getsize(tmp_path / "ckpt_on.npz")
         for r in cks[-1:]
     )
-    # Cross-mode resume: sharded+recorded blob under a paged engine.
+    # Cross-mode resume: resident+recorded blob under a paged engine.
     res = JaxReplayEngine(
         ec, ep, FrameworkConfig(), chunk_waves=4, paged=True,
         telemetry="off",
@@ -176,15 +174,21 @@ def test_flight_stream_validates_against_schema_v6(case, tmp_path):
     ec, ep = case
     path = str(tmp_path / "fl.jsonl")
     JaxReplayEngine(
-        ec, ep, FrameworkConfig(), chunk_waves=4, node_shards=2,
+        ec, ep, FrameworkConfig(), chunk_waves=4,
         paged=False, telemetry="summary", flight_recorder=path,
     ).replay()
     assert validate_file(path) == []
     rows = read_stream(path)
     assert all(r["schema"] == 7 for r in rows)
-    # The sharded run's chunk rows carry the exchange attribution.
+    # The selection-exchange fields went with node sharding (PR 29): no
+    # row carries one, and the recorder takes no such argument.
     cks = [r for r in rows if r["event"] == "chunk"]
-    assert cks and all("exchange_est_s" in r for r in cks)
+    assert cks and not any(k.startswith("exchange_") for r in rows for k in r)
+    assert not any(k.startswith("exchange_") for k in FLIGHT_WALL_FIELDS)
+    with pytest.raises(TypeError, match="exchange_probe_s"):
+        FlightRecorder(FlightRecorderConfig(path=path)).chunk(
+            0, exchange_probe_s=0.1
+        )
 
 
 def test_pager_stall_counters_on_crafted_slow_page_trace():
@@ -261,72 +265,6 @@ def test_recorder_every_cadence(tmp_path):
     assert rows[0]["event"] == "start" and rows[-1]["event"] == "end"
 
 
-@pytest.mark.slow
-def test_bottleneck_report_names_regime(case, tmp_path, capsys):
-    """End to end: record a composed (sharded × paged is refused, so
-    sharded) replay, run the report, get a named dominant regime with
-    evidence."""
-    from bottleneck_report import REGIMES, main as report_main  # noqa: E402
-
-    ec, ep = case
-    path = str(tmp_path / "fl.jsonl")
-    JaxReplayEngine(
-        ec, ep, FrameworkConfig(), chunk_waves=4, node_shards=2,
-        telemetry="summary", flight_recorder=path,
-    ).replay()
-    assert report_main([path]) == 0
-    out = capsys.readouterr().out
-    assert "DOMINANT REGIME:" in out
-    assert any(r in out for r in REGIMES)
-    assert "selection exchange" in out
-    # Missing stream: exit 1 with a pointer, no traceback.
-    assert report_main([str(tmp_path / "missing.jsonl")]) == 1
-
-
-def test_bottleneck_report_synthetic_regimes(tmp_path):
-    """Regime naming pinned on crafted streams: a stream dominated by
-    pager stalls is pager-bound, one dominated by exchange time is
-    exchange-bound, one dominated by folds is host-fold-bound."""
-    from bottleneck_report import aggregate, attribute  # noqa: E402
-
-    def _mk(name, rows):
-        p = tmp_path / f"{name}.jsonl"
-        p.write_text(
-            "\n".join(
-                json.dumps({"kind": "flight", "schema": 5, "ts": 0, **r})
-                for r in rows
-            )
-            + "\n"
-        )
-        return str(p)
-
-    pager_rows = [
-        {"event": "chunk", "chunk": 0, "wall_s": 1.0,
-         "phases": {"dispatch": 0.1}, "pager_stalls": 4,
-         "pager_stall_s": 0.9},
-    ]
-    exch_rows = [
-        {"event": "chunk", "chunk": 0, "wall_s": 1.0,
-         "phases": {"dispatch": 0.1}, "exchange_probe_s": 0.001,
-         "exchange_slots": 900, "exchange_est_s": 0.9},
-    ]
-    fold_rows = [
-        {"event": "boundary_fold", "chunk": 0, "stall_s": 0.9,
-         "wall_s": 0.9},
-        {"event": "chunk", "chunk": 0, "wall_s": 1.0,
-         "phases": {"dispatch": 0.1}},
-    ]
-    for name, rows, want in (
-        ("pager", pager_rows, "pager-bound"),
-        ("exch", exch_rows, "exchange-bound"),
-        ("fold", fold_rows, "host-fold-bound"),
-    ):
-        ranked = attribute(aggregate(
-            [json.loads(line) for line in open(_mk(name, rows))]
-        ))
-        assert ranked[0][0] == want, f"{name}: got {ranked[0]}"
-
-
 def test_fleetwatch_flight_lines_tolerant(tmp_path):
     """dcn_launch --watch --flight: renders recorder gauges per process
     and tolerates a missing stream / torn tail entirely."""
@@ -338,7 +276,7 @@ def test_fleetwatch_flight_lines_tolerant(tmp_path):
     fl.write_text(
         json.dumps({"kind": "flight", "event": "chunk", "chunk": 3,
                     "rolling_pps": 1234.5, "pager_stalls": 2,
-                    "exchange_est_s": 0.012, "rss_peak_mib": 300.0})
+                    "rss_peak_mib": 300.0})
         + "\n"
     )
     (tmp_path / "fl.jsonl.p1").write_text('{"torn json\n')
@@ -346,7 +284,7 @@ def test_fleetwatch_flight_lines_tolerant(tmp_path):
     assert len(lines) == 1
     assert "p0 flight chunk 3" in lines[0]
     assert "1234pps" in lines[0] or "1235pps" in lines[0]
-    assert "stalls=2" in lines[0] and "exch=12.0ms" in lines[0]
+    assert "stalls=2" in lines[0] and "rss=300MiB" in lines[0]
     # Byte cursor: nothing new → nothing repeated.
     assert w.flight_lines() == []
     # Recorder off entirely: FleetWatch without a flight path is silent.

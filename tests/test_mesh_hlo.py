@@ -8,13 +8,12 @@ time"; this lowers the actual program on the virtual 8-device CPU mesh
 string-matches the optimized, SPMD-partitioned HLO. No TPU needed: the
 partitioner that would insert collectives runs at compile time.
 
-Round 10 made the mesh the DEFAULT headline configuration (bench.py runs
-8 devices × 1024 scenarios) and moved the mesh chunk program to the
+Round 10 made the mesh the DEFAULT headline configuration (8 devices ×
+1024 scenarios) and moved the mesh chunk program to the
 device-gather src signature with device-side releases — so this suite
 now lowers those exact programs, at the headline scenario count as well
 as the small smoke shape, plus the bucketed release program."""
 
-import re
 
 import jax
 import jax.numpy as jnp
@@ -143,7 +142,7 @@ def _assert_no_collectives(txt: str) -> None:
     )
 
 
-# 8 = smoke shape; 1024 = the bench.py headline (8 devices × 128
+# 8 = smoke shape; 1024 = the round-10 headline (8 devices × 128
 # scenarios/device). The partitioner runs at compile time, so this pins
 # the SHIPPED configuration collective-free, not just a toy.
 @pytest.mark.parametrize(
@@ -166,110 +165,3 @@ def test_mesh_chunk_program_no_collectives_with_completions(S):
     assert rel is not None, "expected at least one static release bucket"
     rel_fn = eng._release_fn(rel[2].shape[0])
     _assert_no_collectives(rel_fn.lower(*rel).compile().as_text())
-
-
-# ── Node-sharded chunk program (round 14) ────────────────────────────
-# The OTHER mesh axis: one scenario, node planes split across devices.
-# Here collectives are not forbidden — they are RATIONED. The design
-# claim ("one tiny (score, node-id) exchange per slot is the only
-# collective in the chunk loop") is pinned by whitelisting the compiled
-# op set: the winner exchange lowers to all-gather (+ all-reduce for
-# the packed plugin folds; partition-id for global-id arithmetic), and
-# anything else — all-to-all, permutes, point-to-point, reduce-scatter
-# — means node planes are being reshuffled mid-scan.
-
-NODE_SHARD_ALLOWED = frozenset({"all-gather", "all-reduce", "partition-id"})
-
-
-def _collective_hits(txt):
-    assert "ENTRY" in txt
-    return sorted({
-        op
-        for ln in txt.splitlines()
-        for op in COLLECTIVE_OPS
-        if f" {op}" in ln or ln.lstrip().startswith(op)
-    })
-
-
-def _node_sharded_hlo(fit_only: bool) -> str:
-    from kubernetes_simulator_tpu.ops import tpu as T
-    from kubernetes_simulator_tpu.sim.jax_runtime import JaxReplayEngine
-    from kubernetes_simulator_tpu.sim.synthetic import config1
-
-    if fit_only:
-        cluster, pods, _ = config1(24, 64, seed=3)
-    else:
-        cluster = make_cluster(24, seed=3, taint_fraction=0.2)
-        pods, _ = make_workload(
-            64, seed=3, with_affinity=True, with_spread=True,
-            with_tolerations=True, gang_fraction=0.1, gang_size=4,
-        )
-    ec, ep = encode(cluster, pods)
-    eng = JaxReplayEngine(
-        ec, ep, FrameworkConfig(), node_shards=8, chunk_waves=4
-    )
-    state = eng._init_dev_state()
-    C = min(eng.chunk_waves, eng.waves.idx.shape[0])
-    src = T.gather_slots(eng.pods, eng.waves.idx[:C])
-    return eng.chunk_fn.lower(eng.dc, state, src).compile().as_text()
-
-
-def test_node_sharded_chunk_collectives_whitelisted():
-    ops = _collective_hits(_node_sharded_hlo(fit_only=False))
-    assert "all-gather" in ops, (
-        "node-sharded chunk program lowered without the winner exchange "
-        "— selection is no longer crossing shards (is the mesh real?)"
-    )
-    extra = set(ops) - NODE_SHARD_ALLOWED
-    assert not extra, (
-        "node-sharded chunk program contains collectives beyond the "
-        f"per-slot selection/fold exchanges: {sorted(extra)} — node "
-        "planes are being reshuffled inside the chunk scan"
-    )
-
-
-def _gather_row_widths(txt):
-    """Per-shard row widths of every all-gather in the compiled program
-    (the gathered operand is f32[nshards, width])."""
-    return sorted({
-        int(m.group(1))
-        for m in re.finditer(r"= f32\[8,(\d+)\][^ ]* all-gather\(", txt)
-    })
-
-
-def test_node_sharded_fit_only_two_phase_exchange():
-    """Round 19 slims the selection exchange to two phases: phase 1
-    all-gathers ONLY the slim (score, global-node-id) pair — a 2-wide
-    f32 row per shard — and phase 2 moves the winner's domain rows with
-    a single owner-masked all-reduce. Fit-only drops the packed plugin
-    folds, so the compiled op set is exactly those two exchanges plus
-    the partition-id for global-id/owner arithmetic, and every gathered
-    row is provably the slim pair, never the old (2+2G)-wide one."""
-    txt = _node_sharded_hlo(fit_only=True)
-    ops = _collective_hits(txt)
-    assert set(ops) == {"all-gather", "all-reduce", "partition-id"}, (
-        f"fit-only two-phase program op set drifted: {ops}"
-    )
-    assert _gather_row_widths(txt) == [2], (
-        "two-phase phase-1 gather must move only the (score, id) pair — "
-        f"saw per-shard row widths {_gather_row_widths(txt)}"
-    )
-
-
-def test_node_sharded_fit_only_legacy_single_exchange(monkeypatch):
-    """The legacy single-exchange program (KSIM_TWO_PHASE_EXCHANGE=0)
-    is still the round-14 shape: one wide all-gather carrying
-    (score, id, gdom, hasdom) = 2+2G floats per shard, no all-reduce.
-    Pinned so the A/B switch stays a real program-level fork."""
-    monkeypatch.setenv("KSIM_TWO_PHASE_EXCHANGE", "0")
-    txt = _node_sharded_hlo(fit_only=True)
-    ops = _collective_hits(txt)
-    assert "all-gather" in ops
-    assert set(ops) <= {"all-gather", "partition-id"}, (
-        f"legacy fit-only program grew extra collectives: {ops}"
-    )
-    widths = _gather_row_widths(txt)
-    assert len(widths) == 1 and widths[0] > 2, (
-        "legacy exchange should gather the combined (2+2G)-wide row — "
-        f"saw {widths}"
-    )

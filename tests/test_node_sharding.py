@@ -1,20 +1,14 @@
-"""Round 14: intra-scenario node-plane sharding + paged pod waves.
+"""Round 14: paged pod waves (the node-sharding half of the round was
+removed in PR 29 — the file keeps its name).
 
-The contract under test: ``node_shards`` and ``paged`` are pure
-memory/latency knobs — placements, JSONL rows, and checkpoint blobs are
-BIT-IDENTICAL across node_shards ∈ {1, 2, 4} and paged on/off. (The CPU
-greedy-oracle link is transitive: sharded ≡ replicated here, replicated
-≡ oracle in tests/test_oracle_parity.py.) Runs on the virtual 8-device
-CPU mesh (conftest forces XLA_FLAGS=--xla_force_host_platform_device_count=8).
+The contract under test: ``paged`` is a pure memory/latency knob —
+placements and result summaries are BIT-IDENTICAL paged on/off.
 
 Also here: the paged-mode gang guard in pack_waves, the
 KSIM_MAX_REPLICATED_BYTES refusal gate, the knob-combination validation
 raises, and byte-parity for the round-14 DCN gather payload compression
 (delta+zlib with raw-zlib overflow fallback).
 """
-
-import hashlib
-import json
 
 import numpy as np
 import pytest
@@ -40,22 +34,6 @@ def _case(n_nodes=24, n_pods=220, seed=7):
     return encode(cluster, pods)
 
 
-@pytest.fixture(scope="module")
-def shard_results():
-    """{node_shards: (engine, ReplayResult)} for the same trace."""
-    ec, ep = _case()
-    out = {}
-    for s in (1, 2, 4):
-        # telemetry="off": phase timers are wall clocks — the one field
-        # family that legitimately differs across shard counts.
-        eng = JaxReplayEngine(
-            ec, ep, FrameworkConfig(), chunk_waves=4, node_shards=s,
-            telemetry="off",
-        )
-        out[s] = (eng, eng.replay())
-    return out
-
-
 def _stable_summary(res):
     """summary() minus the wall-clock-derived fields (the exact set the
     KSIM_DETERMINISTIC_JSONL scrub zeroes)."""
@@ -65,74 +43,22 @@ def _stable_summary(res):
     return row
 
 
-def test_shard_count_invariance(shard_results):
-    _, ref = shard_results[1]
-    for s in (2, 4):
-        _, res = shard_results[s]
-        np.testing.assert_array_equal(
-            res.assignments, ref.assignments,
-            err_msg=f"node_shards={s}: per-pod assignments diverged",
-        )
-        assert _stable_summary(res) == _stable_summary(ref), (
-            f"node_shards={s}: result summary diverged"
-        )
-
-
-def test_jsonl_byte_identical(shard_results, tmp_path, monkeypatch):
-    """The JSONL a run would emit is byte-identical across shard counts
-    once wall-clock fields are scrubbed (KSIM_DETERMINISTIC_JSONL — the
-    repo's standing rule: determinism lives in results, never timing)."""
-    from kubernetes_simulator_tpu.utils.metrics import JsonlWriter, replay_row
-
-    monkeypatch.setenv("KSIM_DETERMINISTIC_JSONL", "1")
-    blobs = {}
-    for s, (_, res) in shard_results.items():
-        p = tmp_path / f"shards{s}.jsonl"
-        with JsonlWriter(str(p)) as w:
-            w.write(replay_row("replay-jax", res))
-        blobs[s] = p.read_bytes()
-        json.loads(blobs[s].splitlines()[-1])  # still valid JSONL
-    assert blobs[1] == blobs[2] == blobs[4]
-
-
-def test_checkpoint_blobs_identical_and_cross_resume(shard_results, tmp_path):
-    """Checkpoints are written in HOST layout (sharded state is
-    unsharded and sliced back to the real node count first), so the
-    blob on disk is byte-identical across shard counts — and a
-    replicated checkpoint resumes under a sharded engine."""
-    eng1, ref = shard_results[1]
-    eng4, _ = shard_results[4]
-    digests = {}
-    for s, eng in ((1, eng1), (4, eng4)):
-        p = tmp_path / f"ckpt{s}.npz"
-        res = eng.replay(checkpoint_path=str(p), checkpoint_every=2)
-        np.testing.assert_array_equal(res.assignments, ref.assignments)
-        digests[s] = hashlib.sha256(p.read_bytes()).hexdigest()
-    assert digests[1] == digests[4], (
-        "checkpoint blob differs between replicated and node-sharded "
-        "engines — the sharded path is leaking device layout to disk"
-    )
-    # Replicated-written blob, sharded resume: identical end state.
-    res = eng4.replay(checkpoint_path=str(tmp_path / "ckpt1.npz"), resume=True)
-    np.testing.assert_array_equal(res.assignments, ref.assignments)
-
-
-def test_paged_parity(shard_results):
-    """Paged pod waves change residency, not results: paged ≡ unpaged on
-    the replicated engine, and paged+sharded ≡ replicated."""
+def test_paged_parity():
+    """Paged pod waves change residency, not results: paged ≡ unpaged."""
     ec, ep = _case()
-    _, ref = shard_results[1]
-    for shards in (1, 4):
-        eng = JaxReplayEngine(
-            ec, ep, FrameworkConfig(), chunk_waves=4,
-            node_shards=shards, paged=True, telemetry="off",
-        )
-        res = eng.replay()
-        np.testing.assert_array_equal(
-            res.assignments, ref.assignments,
-            err_msg=f"paged (node_shards={shards}): assignments diverged",
-        )
-        assert _stable_summary(res) == _stable_summary(ref)
+    # telemetry="off": phase timers are wall clocks.
+    ref, res = (
+        JaxReplayEngine(
+            ec, ep, FrameworkConfig(), chunk_waves=4, paged=paged,
+            telemetry="off",
+        ).replay()
+        for paged in (False, True)
+    )
+    np.testing.assert_array_equal(
+        res.assignments, ref.assignments,
+        err_msg="paged: assignments diverged",
+    )
+    assert _stable_summary(res) == _stable_summary(ref)
 
 
 def test_pack_waves_rejects_page_smaller_than_gang():
@@ -153,37 +79,78 @@ def test_pack_waves_rejects_page_smaller_than_gang():
 
 def test_replicated_refusal_gate(monkeypatch):
     """KSIM_MAX_REPLICATED_BYTES refuses the replicated path past the
-    budget (pointing at node_shards/paged); the sharded engine
-    constructs under the same budget."""
+    budget (pointing at paged)."""
     ec, ep = _case(n_pods=64)
     assert replicated_resident_bytes(ec, ep) > 1000
     monkeypatch.setenv("KSIM_MAX_REPLICATED_BYTES", "1000")
     with pytest.raises(ValueError, match="KSIM_MAX_REPLICATED_BYTES"):
         JaxReplayEngine(ec, ep, FrameworkConfig())
-    eng = JaxReplayEngine(ec, ep, FrameworkConfig(), node_shards=2)
-    assert eng.node_shards == 2
 
 
 def test_knob_combination_raises():
     ec, ep = _case(n_pods=64)
-    with pytest.raises(ValueError, match="tier preemption"):
-        JaxReplayEngine(
-            ec, ep, FrameworkConfig(), node_shards=2, preemption="tier"
-        )
     with pytest.raises(ValueError, match="paged=True is not supported"):
         JaxReplayEngine(ec, ep, FrameworkConfig(), paged=True, retry_buffer=8)
 
 
-def test_whatif_rejects_node_shards():
+# ── the removed node axis (PR 29) ────────────────────────────────────
+
+
+@pytest.mark.parametrize(
+    "doc, key",
+    [
+        ({"nodeShards": 2}, "nodeShards"),
+        ({"overlap": {"twoPhaseExchange": True}}, "overlap.twoPhaseExchange"),
+    ],
+    ids=["nodeShards", "twoPhaseExchange"],
+)
+def test_removed_node_axis_keys_refused(doc, key, tmp_path, capsys):
+    """A YAML file that still asks for the node axis is refused with the
+    sentence that says it went and why, by ``from_dict`` and so by the
+    ``validate`` subcommand."""
+    import yaml
+
+    from kubernetes_simulator_tpu.cli import main
+    from kubernetes_simulator_tpu.utils.config import SimConfig
+
+    doc = {"strategy": "jax", **doc}
+    with pytest.raises(ValueError, match="node sharding was removed") as ei:
+        SimConfig.from_dict(doc)
+    assert str(ei.value).startswith(key + ":") and "36.7 MB" in str(ei.value)
+    path = tmp_path / "old.yaml"
+    path.write_text(yaml.safe_dump(doc))
+    assert main(["validate", str(path)]) == 1
+    assert "node sharding was removed" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("shards", [0, 1])
+def test_replicated_node_shards_accepted_and_ignored(shards):
+    """``nodeShards: 0/1`` always meant the replicated path, so configs
+    that carry it keep running; nothing of it reaches the config."""
+    from kubernetes_simulator_tpu.cli import validate_config
+    from kubernetes_simulator_tpu.utils.config import SimConfig
+
+    cfg = SimConfig.from_dict({"strategy": "jax", "nodeShards": shards})
+    assert not hasattr(cfg, "node_shards")
+    assert validate_config(cfg) == []
+
+
+@pytest.mark.parametrize("engine", ["replay", "whatif"])
+def test_engines_take_no_node_shards(engine):
     from kubernetes_simulator_tpu.sim.whatif import (
         WhatIfEngine,
         uniform_scenarios,
     )
 
     ec, ep = _case(n_pods=64)
-    scen = uniform_scenarios(ec, 2, seed=0)
-    with pytest.raises(NotImplementedError, match="node_shards"):
-        WhatIfEngine(ec, ep, scen, FrameworkConfig(), node_shards=2)
+    with pytest.raises(TypeError, match="node_shards"):
+        if engine == "replay":
+            JaxReplayEngine(ec, ep, FrameworkConfig(), node_shards=2)
+        else:
+            WhatIfEngine(
+                ec, ep, uniform_scenarios(ec, 2, seed=0), FrameworkConfig(),
+                node_shards=2,
+            )
 
 
 # ── DCN gather payload compression (round-14 satellite) ──────────────

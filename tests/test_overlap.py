@@ -1,38 +1,29 @@
-"""Round 19 — overlap plane parity: the three stall-hiding features
-(threaded pager, background checkpoint publication, slim two-phase
-selection exchange) are pure LATENCY knobs. Placements, deterministic
-JSONL and checkpoint blobs are BIT-IDENTICAL with each feature on vs
-off, across nodeShards ∈ {1, 2, 4} × paged on/off × the kube-boundary
-leg, including cross-mode resume (a checkpoint written with a feature
-ON resumes with it OFF and vice versa). Runs on the virtual 8-device
-CPU mesh (conftest forces XLA_FLAGS=--xla_force_host_platform_device_count=8).
+"""Round 19 — overlap plane parity: the two stall-hiding features
+(threaded pager, background checkpoint publication) are pure LATENCY
+knobs. Placements and deterministic JSONL are BIT-IDENTICAL with the
+pager thread on vs off.
 
-Also here: the exchange payload-accounting pins (the two-phase exchange
-provably moves fewer bytes per slot at every shard count and group
-count), the round-19 pager resume-jump invalidation fix (a stale staged
+Also here: the round-19 pager resume-jump invalidation fix (a stale staged
 page is discarded and counted, never silently under-reported as a plain
 miss), the background publisher's single-flight/newest-wins/drain/error
 unit semantics, and the ``overlap:`` config section's parsing and
 validation refusals.
 """
 
-import hashlib
-import json
+import os
 
 import numpy as np
 import pytest
 
 from kubernetes_simulator_tpu.framework.framework import FrameworkConfig
 from kubernetes_simulator_tpu.models.encode import encode
-from kubernetes_simulator_tpu.ops import tpu as T
 from kubernetes_simulator_tpu.sim.jax_runtime import (
     JaxReplayEngine,
     _PodPager,
 )
 from kubernetes_simulator_tpu.sim.synthetic import make_cluster, make_workload
 
-# The three env gates, all default-ON.
-GATE_EXCHANGE = "KSIM_TWO_PHASE_EXCHANGE"
+# The two env gates, both default-ON.
 GATE_PAGER = "KSIM_PAGER_THREAD"
 GATE_CKPT = "KSIM_DCN_CKPT_ASYNC"
 
@@ -68,161 +59,12 @@ def _deterministic_jsonl(res, path, monkeypatch):
     return path.read_bytes()
 
 
-# ── exchange payload accounting ──────────────────────────────────────
-
-
-def test_exchange_payload_bytes_formula():
-    """The analytic per-slot payload model the scaling probe and the
-    whitelist tests rest on: a single shard exchanges nothing; the
-    two-phase exchange receives (n−1)·2 floats of slim rows plus a
-    ring all-reduce (2·(n−1)/n of the 2G dom row) — never MORE bytes
-    than the legacy (n−1)·(2+2G) wide gather (equal at n = 2, where the
-    reduce degenerates to a peer swap) and strictly fewer at n ≥ 3."""
-    for n in (0, 1):
-        assert T.exchange_payload_bytes(n, 8, True) == 0
-        assert T.exchange_payload_bytes(n, 8, False) == 0
-    for n in (2, 4, 8):
-        for g in (1, 4, 32):
-            legacy = T.exchange_payload_bytes(n, g, False)
-            slim = T.exchange_payload_bytes(n, g, True)
-            assert legacy == 4 * (n - 1) * (2 + 2 * g)
-            assert slim == 4 * ((n - 1) * 2 + (2 * (n - 1) * 2 * g) // n)
-            assert slim <= legacy, (n, g, slim, legacy)
-            if n > 2:
-                assert slim < legacy, (n, g, slim, legacy)
-    # The win grows with shard count (the wide gather scales with n·G,
-    # the psum's dom traffic does not).
-    assert (
-        T.exchange_payload_bytes(8, 32, False)
-        / T.exchange_payload_bytes(8, 32, True)
-        > T.exchange_payload_bytes(2, 32, False)
-        / T.exchange_payload_bytes(2, 32, True)
-    )
-
-
-# ── two-phase exchange bit-parity ────────────────────────────────────
-
-
-@pytest.fixture(scope="module")
-def exchange_results(case):
-    """{(shards, two_phase): (engine, ReplayResult)} over the same
-    trace. Env is read at trace time, so each engine is constructed AND
-    replayed (compiled) under its own gate value."""
-    import os
-
-    ec, ep = case
-    out = {}
-    for two_phase in (True, False):
-        os.environ[GATE_EXCHANGE] = "1" if two_phase else "0"
-        try:
-            for s in (1, 2, 4):
-                eng = JaxReplayEngine(
-                    ec, ep, FrameworkConfig(), chunk_waves=4, node_shards=s,
-                    telemetry="off",
-                )
-                out[(s, two_phase)] = (eng, eng.replay())
-        finally:
-            os.environ.pop(GATE_EXCHANGE, None)
-    return out
-
-
-def test_two_phase_exchange_bit_parity(exchange_results):
-    _, ref = exchange_results[(1, False)]
-    for s in (1, 2, 4):
-        for two_phase in (True, False):
-            _, res = exchange_results[(s, two_phase)]
-            np.testing.assert_array_equal(
-                res.assignments, ref.assignments,
-                err_msg=(
-                    f"node_shards={s} two_phase={two_phase}: per-pod "
-                    "assignments diverged"
-                ),
-            )
-            assert _stable_summary(res) == _stable_summary(ref)
-
-
-def test_two_phase_jsonl_byte_identical(
-    exchange_results, tmp_path, monkeypatch
-):
-    blobs = {}
-    for key, (_, res) in exchange_results.items():
-        blobs[key] = _deterministic_jsonl(
-            res, tmp_path / f"{key[0]}_{key[1]}.jsonl", monkeypatch
-        )
-    assert len(set(blobs.values())) == 1, (
-        "deterministic JSONL differs across shards × exchange modes"
-    )
-
-
-def test_two_phase_checkpoint_blob_and_cross_mode_resume(
-    exchange_results, tmp_path
-):
-    """Checkpoint blobs are byte-identical exchange on/off, and a blob
-    written under one exchange mode resumes under the other."""
-    eng_on, ref = exchange_results[(2, True)]
-    eng_off, _ = exchange_results[(2, False)]
-    digests = {}
-    for name, eng in (("on", eng_on), ("off", eng_off)):
-        p = tmp_path / f"ckpt_{name}.npz"
-        res = eng.replay(checkpoint_path=str(p), checkpoint_every=2)
-        np.testing.assert_array_equal(res.assignments, ref.assignments)
-        digests[name] = hashlib.sha256(p.read_bytes()).hexdigest()
-    assert digests["on"] == digests["off"], (
-        "checkpoint blob depends on the exchange mode"
-    )
-    # Cross-mode resume: two-phase-written blob, legacy-compiled engine
-    # (and the reverse).
-    res = eng_off.replay(
-        checkpoint_path=str(tmp_path / "ckpt_on.npz"), resume=True
-    )
-    np.testing.assert_array_equal(res.assignments, ref.assignments)
-    res = eng_on.replay(
-        checkpoint_path=str(tmp_path / "ckpt_off.npz"), resume=True
-    )
-    np.testing.assert_array_equal(res.assignments, ref.assignments)
-
-
-# ── kube-boundary leg ────────────────────────────────────────────────
-
-
-def test_kube_boundary_two_phase_parity_and_resume(case, tmp_path):
-    """The kube PostFilter boundary path (retry buffer + minimal-victims
-    preemption) under nodeShards: identical placements and checkpoint
-    blobs exchange on/off, including a cross-mode resume."""
-    import os
-
-    ec, ep = case
-    results = {}
-    for two_phase in (True, False):
-        os.environ[GATE_EXCHANGE] = "1" if two_phase else "0"
-        try:
-            eng = JaxReplayEngine(
-                ec, ep, FrameworkConfig(), chunk_waves=4, node_shards=2,
-                preemption="kube", retry_buffer=16, telemetry="off",
-            )
-            p = tmp_path / f"kube_{two_phase}.npz"
-            res = eng.replay(checkpoint_path=str(p), checkpoint_every=2)
-            results[two_phase] = (eng, res, p)
-        finally:
-            os.environ.pop(GATE_EXCHANGE, None)
-    _, ref, p_on = results[True]
-    eng_off, res_off, p_off = results[False]
-    np.testing.assert_array_equal(res_off.assignments, ref.assignments)
-    assert _stable_summary(res_off) == _stable_summary(ref)
-    assert (
-        hashlib.sha256(p_on.read_bytes()).hexdigest()
-        == hashlib.sha256(p_off.read_bytes()).hexdigest()
-    )
-    res = eng_off.replay(checkpoint_path=str(p_on), resume=True)
-    np.testing.assert_array_equal(res.assignments, ref.assignments)
-
-
 # ── threaded pager parity ────────────────────────────────────────────
 
 
 @pytest.fixture(scope="module")
 def pager_results(case):
-    """{(shards, threaded): (engine, ReplayResult, flight_bytes)} for
+    """{threaded: (engine, ReplayResult, flight_bytes)} for
     paged replays with the flight recorder on under the deterministic
     scrub — the stream itself must be byte-identical threaded on/off."""
     import os
@@ -234,17 +76,16 @@ def pager_results(case):
     try:
         for threaded in (True, False):
             os.environ[GATE_PAGER] = "1" if threaded else "0"
-            for s in (1, 2):
-                fl = os.path.join(
-                    tempfile.mkdtemp(prefix="ksim_ov_"), "fl.jsonl"
-                )
-                eng = JaxReplayEngine(
-                    ec, ep, FrameworkConfig(), chunk_waves=4, node_shards=s,
-                    paged=True, telemetry="off", flight_recorder=fl,
-                )
-                res = eng.replay()
-                with open(fl, "rb") as f:
-                    out[(s, threaded)] = (eng, res, f.read())
+            fl = os.path.join(
+                tempfile.mkdtemp(prefix="ksim_ov_"), "fl.jsonl"
+            )
+            eng = JaxReplayEngine(
+                ec, ep, FrameworkConfig(), chunk_waves=4,
+                paged=True, telemetry="off", flight_recorder=fl,
+            )
+            res = eng.replay()
+            with open(fl, "rb") as f:
+                out[threaded] = (eng, res, f.read())
     finally:
         os.environ.pop(GATE_PAGER, None)
         os.environ.pop("KSIM_DETERMINISTIC_JSONL", None)
@@ -252,37 +93,33 @@ def pager_results(case):
 
 
 def test_threaded_pager_bit_parity(pager_results):
-    _, ref, _ = pager_results[(1, False)]
-    for (s, threaded), (_, res, _) in pager_results.items():
-        np.testing.assert_array_equal(
-            res.assignments, ref.assignments,
-            err_msg=(
-                f"node_shards={s} pager_thread={threaded}: assignments "
-                "diverged"
-            ),
-        )
-        assert _stable_summary(res) == _stable_summary(ref)
+    _, ref, _ = pager_results[False]
+    _, res, _ = pager_results[True]
+    np.testing.assert_array_equal(
+        res.assignments, ref.assignments,
+        err_msg="pager_thread on vs off: assignments diverged",
+    )
+    assert _stable_summary(res) == _stable_summary(ref)
 
 
 def test_threaded_pager_flight_stream_byte_identical(pager_results):
     """Under KSIM_DETERMINISTIC_JSONL the recorded stream is
-    byte-identical threaded on/off at each shard count: miss counts are
-    structural, wait/wall fields are scrubbed, and the row schema never
-    leaks which thread fetched the page."""
-    for s in (1, 2):
-        assert pager_results[(s, True)][2] == pager_results[(s, False)][2], (
-            f"node_shards={s}: flight stream differs threaded on/off"
-        )
+    byte-identical threaded on/off: miss counts are structural,
+    wait/wall fields are scrubbed, and the row schema never leaks which
+    thread fetched the page."""
+    assert pager_results[True][2] == pager_results[False][2], (
+        "flight stream differs threaded on/off"
+    )
 
 
 def test_threaded_pager_jsonl_byte_identical(
     pager_results, tmp_path, monkeypatch
 ):
     blobs = {
-        key: _deterministic_jsonl(
-            res, tmp_path / f"p{key[0]}_{key[1]}.jsonl", monkeypatch
+        threaded: _deterministic_jsonl(
+            res, tmp_path / f"p{threaded}.jsonl", monkeypatch
         )
-        for key, (_, res, _) in pager_results.items()
+        for threaded, (_, res, _) in pager_results.items()
     }
     assert len(set(blobs.values())) == 1
 
@@ -435,11 +272,10 @@ def test_overlap_spec_parsing():
 
     cfg = SimConfig.from_dict({
         "strategy": "jax",
-        "overlap": {"pagerThread": True, "twoPhaseExchange": False},
+        "overlap": {"pagerThread": True},
     })
     assert cfg.overlap.pager_thread is True
     assert cfg.overlap.background_publisher is None
-    assert cfg.overlap.two_phase_exchange is False
     assert SimConfig.from_dict({}).overlap is None
     with pytest.raises(ValueError, match="overlap.pagerThread"):
         SimConfig.from_dict({"overlap": {"pagerThread": "yes"}})
@@ -480,16 +316,13 @@ def test_overlap_validation_refusals():
     # assume it.
     cfg = SimConfig.from_dict({
         "strategy": "jax",
-        "overlap": {
-            "pagerThread": False, "backgroundPublisher": False,
-            "twoPhaseExchange": False,
-        },
+        "overlap": {"pagerThread": False, "backgroundPublisher": False},
     })
     assert _overlap_errors(cfg) == []
 
 
 def test_validate_accepts_example_config18():
-    """The shipped round-19 example parses, carries all three gates
+    """The shipped round-19 example parses, carries both gates
     (backgroundPublisher deliberately false — it is the fleet-only
     leg), and passes full validation with zero errors."""
     import os
@@ -501,10 +334,32 @@ def test_validate_accepts_example_config18():
         os.path.dirname(__file__), "..", "examples", "config18_overlap.yaml"
     )
     cfg = SimConfig.load(path)
-    assert cfg.node_shards == 2 and cfg.paged_waves
+    assert cfg.paged_waves
     assert cfg.overlap is not None
     assert cfg.overlap.pager_thread is True
-    assert cfg.overlap.two_phase_exchange is True
     assert cfg.overlap.background_publisher is False
     assert cfg.flight_recorder is not None
+    assert validate_config(cfg) == []
+
+
+_EXAMPLES = os.path.join(os.path.dirname(__file__), "..", "examples")
+
+
+@pytest.mark.parametrize(
+    "name", sorted(f for f in os.listdir(_EXAMPLES) if f.endswith(".yaml"))
+)
+def test_every_example_validates(name, tmp_path, monkeypatch):
+    """Every shipped example parses and passes full validation. The
+    fleet examples (``dcn:`` recovery / workQueue / durable) are
+    validated as ``scripts/dcn_launch.py`` would start them."""
+    from kubernetes_simulator_tpu.cli import validate_config
+    from kubernetes_simulator_tpu.utils.config import SimConfig
+
+    cfg = SimConfig.load(os.path.join(_EXAMPLES, name))
+    if cfg.dcn_recovery or cfg.dcn_workqueue or cfg.dcn_durable:
+        monkeypatch.setenv("KSIM_DCN_NPROC", "2")
+    if cfg.dcn_durable is not None and cfg.dcn_durable.dir:
+        cfg.dcn_durable.dir = str(tmp_path / "journal")
+    if cfg.flight_recorder is not None:
+        cfg.flight_recorder.path = str(tmp_path / "flight.jsonl")
     assert validate_config(cfg) == []

@@ -183,12 +183,10 @@ def test_validate_config_refusals():
     bad = SimConfig.from_dict({
         "strategy": "jax", "devicePreemption": "kube",
         "whatIf": {"retryBuffer": 64},
-        "nodeShards": 2,
         "service": {"batchDeadlineS": 0, "maxEngines": 0,
                     "granularity": "verbose"},
     })
     errs = "\n".join(_service_errors(bad))
-    assert "nodeShards" in errs
     assert "batchDeadlineS: must be > 0" in errs
     assert "maxEngines" in errs
     assert "granularity" in errs

@@ -102,8 +102,8 @@ def test_default_summary_attached_both_engines():
 
 
 def test_phase_timer_names_stable():
-    """The instrumented phase names are API — scripts/northstar.py and
-    bench consumers attribute wall-clock by these exact strings. The
+    """The instrumented phase names are API — the flight stream's and the
+    benchmark's consumers attribute wall-clock by these exact strings. The
     canonical tuple is PHASE_NAMES; a boundary-mode device replay must
     emit exactly that set (a rename or a new un-registered phase fails
     here first)."""
