@@ -23,7 +23,10 @@ no result line (nothing here catches a phase's exception):
                   case with completions, the same as a what-if batch
                   through the device release program, and a release-heavy
                   what-if batch whose every release block adds many
-                  releases to one node (prints ``release_rounds``)
+                  releases to one node (prints ``release_rounds``), and
+                  the Borg case on 16 stride-zoned nodes, single replay
+                  against what-if scenario 0; each case prints its
+                  ``select_form``, ``zone_packed`` required of the zoned one
   serve           serve examples/config20_service.yaml < 4 defrag queries
   mesh            only with >1 device: what-if
                   examples/config5_multitenant_mesh.yaml over all devices
@@ -65,6 +68,10 @@ BORG_NODES, BORG_TASKS, BORG_MEAN_DURATION, BORG_CHUNK_WAVES = 12, 2048, 15000.0
 # so a boundary releases hundreds of tasks and every 128-row block of the
 # release program adds many releases to one node, in rank order.
 HEAVY_SEED, HEAVY_MEAN_DURATION, HEAVY_CHUNK_WAVES, HEAVY_MIN_ROUNDS = 0, 2000.0, 64, 8
+# Zoned Borg case: two nodes in each of the generator's 8 zones, the stride
+# layout of the 10,000-node cluster, which 12 nodes are not: its chunk
+# programs take the zone-packed select (ops.tpu3.select_form).
+ZONED_NODES = 16
 
 
 def say(msg: str) -> None:
@@ -203,11 +210,21 @@ def phase_parity() -> dict:
     )
     from kubernetes_simulator_tpu.utils.config import SimConfig, build_case
 
-    def same(dev, ref, what):
+    def same(dev, ref, what, ref_name="the host reference"):
         bad = np.nonzero(np.asarray(dev) != np.asarray(ref))[0]
         require(bad.size == 0,
-                f"{what}: {bad.size} placements differ from the host "
-                f"reference, first at pod {bad[:5].tolist()}")
+                f"{what}: {bad.size} placements differ from {ref_name}, "
+                f"first at pod {bad[:5].tolist()}")
+
+    def select_form(result, what, want=None):
+        """The chunk program's select form (ops.tpu3.select_form), printed;
+        the zoned Borg case has to run the one-reduce form."""
+        tel = getattr(result, "fleet_telemetry", None) or result.telemetry
+        form = tel.summary().get("select_form")
+        say(f"{what}: select_form {form}")
+        require(want is None or form == want,
+                f"{what}: select_form {form!r}, not {want!r}")
+        return form
 
     out = {}
     # (a) the head of the config-2 case, full default plugin set.
@@ -218,7 +235,8 @@ def phase_parity() -> dict:
     dev = JaxReplayEngine(ec, ep, cfg.framework, wave_width=8).replay()
     same(dev.assignments, host.assignments, "config2 head")
     require(dev.placed == host.placed > 0, "config2 head: placed differs")
-    out["config2_head"] = {"pods": PARITY_HEAD_PODS, "placed": dev.placed}
+    out["config2_head"] = {"pods": PARITY_HEAD_PODS, "placed": dev.placed,
+                           "select_form": select_form(dev, "config2 head")}
 
     # (b) Borg-shaped, gangs on, finite durations: 0.1-core requests (not
     # bf16-exact) are bound AND released mid-replay.
@@ -244,6 +262,7 @@ def phase_parity() -> dict:
     out["borg_single"] = {
         "nodes": BORG_NODES, "tasks": BORG_TASKS, "placed": dev.placed,
         "unschedulable": dev.unschedulable,
+        "select_form": select_form(dev, "borg single replay"),
     }
 
     # (c) the same case as a 4-scenario what-if, completions on, every
@@ -290,6 +309,7 @@ def phase_parity() -> dict:
     out["borg_whatif"] = {
         "scenarios": len(scen),
         "placed": [int(x) for x in r_dev.placed],
+        "select_form": select_form(r_dev, "borg what-if"),
     }
 
     # (d) the release-heavy case. The CPU backend's dot is exact and
@@ -328,8 +348,42 @@ def phase_parity() -> dict:
         "placed": [int(x) for x in r_heavy.placed],
         "unschedulable": refs[0].unschedulable,
         "release_rounds": rounds,
+        "select_form": select_form(r_heavy, "release-heavy what-if"),
     }
     say(f"release-heavy what-if: release_rounds {rounds}")
+
+    # (e) the Borg case on a stride-zoned cluster: both chunk programs make
+    # ONE node-wide reduce a slot (the best packed node per zone), the
+    # single replay through a lane fold and the scenario-mapped batch
+    # through a sublane view (ops.tpu.zone_packed_max), two compiled forms
+    # that have to agree task for task: scenario 0 is the single replay.
+    # Held to each other and not to the host reference, which at this size
+    # parts from the device at a float32 floor edge on most seeds, on the
+    # chip and off it, with the two-pass form too (PERF.md §7).
+    ec, ep, _ = make_borg_encoded(
+        BorgSpec(nodes=ZONED_NODES, tasks=BORG_TASKS, seed=0,
+                 mean_duration=BORG_MEAN_DURATION)
+    )
+    dev = JaxReplayEngine(ec, ep, fw, **kw).replay()
+    r_zoned = WhatIfEngine(ec, ep, scen, fw, completions=True,
+                           collect_assignments=True, **kw).run()
+    same(r_zoned.assignments[0], dev.assignments,
+         "zoned what-if scenario 0", "the single replay's")
+    require(0 < dev.unschedulable < BORG_TASKS // 2,
+            "zoned borg case: the cluster never runs full")
+    for s in range(len(scen)):
+        require(int((r_zoned.assignments[s] >= 0).sum()) == int(r_zoned.placed[s]),
+                f"zoned what-if scenario {s}: placed differs from the "
+                "placements handed back")
+        require(s == 0 or (r_zoned.assignments[s] != dev.assignments).any(),
+                f"zoned what-if scenario {s}: the perturbation changes nothing")
+    out["borg_zoned"] = {
+        "nodes": ZONED_NODES, "placed": [int(x) for x in r_zoned.placed],
+        "select_form": [
+            select_form(dev, "zoned single replay", "zone_packed"),
+            select_form(r_zoned, "zoned what-if", "zone_packed"),
+        ],
+    }
     return out
 
 

@@ -814,6 +814,27 @@ PACK_MAX_TOTAL = 1023  # (1023·2^14 + 16383) < 2^24
 PACK_MAX_NODES = 16384
 
 
+def _pack(scores: jax.Array, feasible: jax.Array) -> jax.Array:
+    """``total·2^14 + (2^14−1−n)`` at feasible nodes, −inf elsewhere."""
+    N = scores.shape[-1]
+    iota_f = jnp.arange(N, dtype=jnp.float32)
+    return jnp.where(
+        feasible,
+        scores * np.float32(PACK_SHIFT)
+        + (np.float32(PACK_SHIFT - 1.0) - iota_f),
+        NEG_INF,
+    )
+
+
+def _unpack(mx: jax.Array):
+    """(choice i32, placed bool) from the max of packed values."""
+    placed = mx > NEG_INF
+    safe = jnp.where(placed, mx, 0.0)
+    t = jnp.floor(safe / np.float32(PACK_SHIFT))  # power-of-2 divide: exact
+    idx = np.float32(PACK_SHIFT - 1.0) - (safe - t * np.float32(PACK_SHIFT))
+    return jnp.where(placed, idx.astype(jnp.int32), PAD), placed
+
+
 def select_node_packed(scores: jax.Array, feasible: jax.Array):
     """select_node via a single native max reduce: pack (total, node) into
     one f32 so argmax-with-min-index-ties becomes max over
@@ -826,20 +847,68 @@ def select_node_packed(scores: jax.Array, feasible: jax.Array):
     exactly representable in f32, and max/decode are bit-exact. A native
     single-output max reduce is ~2× the throughput of the variadic
     (value, index) comparator reduce on TPU."""
+    return _unpack(jnp.max(_pack(scores, feasible), axis=-1))
+
+
+def zone_packed_max(
+    scores: jax.Array, feasible: jax.Array, seg_mode: str, seg_D: int,
+    scenario_axis: bool = False,
+) -> jax.Array:
+    """[seg_D] — the best packed node of each zone (−inf: the zone has no
+    feasible node), in ONE pass over the node axis. ``scores`` holds the
+    part of the total that varies inside a zone; what is constant in a
+    zone is added to these seg_D values afterwards
+    (:func:`select_node_zone_packed`), so one reduce tells which zones are
+    feasible AND, once the zone scores are known, which node wins.
+
+    Zones follow V3Static's structured layouts: ``"block"`` (zone =
+    n // (N / seg_D)) reduces the minor axis of the ``[seg_D, N / seg_D]``
+    view; ``"stride"`` (zone = n % seg_D, the benchmark's clusters) the
+    major axis of a view chosen for where the TPU compiler puts the nodes
+    (``scenario_axis``: the caller maps this over a scenario axis):
+
+    - one scenario's ``[N]`` operands lie along the lanes, 1,024 nodes a
+      register. N is padded to whole 128-lane rows; the max over the rows
+      is elementwise into 128 lanes, and the lanes fold 128 → seg_D on 128
+      values (128 % seg_D == 0: a lane's zone is lane % seg_D);
+    - mapped over scenarios, ``[S, N]`` operands lie with the scenarios on
+      the lanes and the nodes on the sublanes, so a stride zone IS a
+      sublane: the ``[N / seg_D, seg_D]`` view is a bitcast of the fused
+      producer (seg_D a multiple of 8) and the reduce an elementwise max
+      of whole registers, with no padding pass.
+
+    Measured on a v5e at N = 10,000, seg_D 8 (PERF.md §6, PR 30): the
+    lane fold under the scenario map costs the 128-scenario batch 2.8%
+    (the padded copy is written out), the sublane view without it costs
+    the single replay 12%, and one variadic reduce of seg_D rows masked
+    to their zones 9.5%."""
     N = scores.shape[-1]
-    iota_f = jnp.arange(N, dtype=jnp.float32)
-    packed = jnp.where(
-        feasible,
-        scores * np.float32(PACK_SHIFT)
-        + (np.float32(PACK_SHIFT - 1.0) - iota_f),
-        NEG_INF,
+    packed = _pack(scores, feasible)
+    if seg_mode == "block":
+        return jnp.max(packed.reshape(seg_D, N // seg_D), axis=1)
+    if scenario_axis:
+        return jnp.max(packed.reshape(N // seg_D, seg_D), axis=0)
+    rows = -(-N // 128)
+    lanes = jnp.max(
+        jnp.pad(packed, (0, rows * 128 - N), constant_values=NEG_INF).reshape(
+            rows, 128
+        ),
+        axis=0,
     )
-    mx = jnp.max(packed, axis=-1)
-    placed = mx > NEG_INF
-    safe = jnp.where(placed, mx, 0.0)
-    t = jnp.floor(safe / np.float32(PACK_SHIFT))  # power-of-2 divide: exact
-    idx = np.float32(PACK_SHIFT - 1.0) - (safe - t * np.float32(PACK_SHIFT))
-    return jnp.where(placed, idx.astype(jnp.int32), PAD), placed
+    return jnp.max(lanes.reshape(128 // seg_D, seg_D), axis=0)
+
+
+def select_node_zone_packed(zone_best: jax.Array, zone_scores: jax.Array):
+    """(choice i32, placed bool) from :func:`zone_packed_max`'s per-zone
+    bests and each zone's constant score: the max of
+    ``zone_best + zone_scores·2^14``. Under :func:`select_node_packed`'s
+    gate (integer totals ≤ PACK_MAX_TOTAL with the zone score included)
+    every value is an integer < 2^24, adding a zone's constant to all
+    packed values of the zone keeps their order and float32 adds of such
+    integers are exact: the max is the number ``select_node_packed`` finds
+    over ``scores + zone_scores[zone(n)]``, bit for bit, the lowest index
+    among equal totals included."""
+    return _unpack(jnp.max(zone_best + zone_scores * np.float32(PACK_SHIFT)))
 
 
 def _bind_deltas(d: Derived, node: jax.Array):

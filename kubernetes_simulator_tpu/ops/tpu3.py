@@ -27,6 +27,11 @@ scenario count. v3 restructures the STATE, not the semantics:
   took 1.5 µs in slot 0 and 9.2 in slot 7, PERF.md §5.) The tier-
   preemption program and the vmapped what-if program keep the k-term
   form — see :func:`inwave_corrections`.
+- **One node-wide reduce a slot** where every score row but the fit score
+  is constant inside a zone (the Borg shape): the best packed node of each
+  zone gives the spread's zone feasibility and, once the zone scores are
+  added to its seg_D results, the chosen node — see :func:`select_form`.
+  Other profiles make two (zone feasibility, then the select).
 - **Node-value expansion** of domain-space rows rides a fused masked-sum
   over the ≤Dcap domains (``val[n] = rows[dom(n)]`` without gathers, which
   serialize on TPU — measured 100× slower than the arithmetic forms).
@@ -973,6 +978,13 @@ def make_wave_step3(
     )
     pack_select = wvec is None and pack_select_ok(spec, w_cfg, dc.allocatable.shape[0])
     corr_plane = inwave_corrections(st, scenario_axis) == "plane"
+    zone_select = (
+        select_form(
+            st, spec, dc.allocatable.shape[0],
+            traced_weights=wvec is not None, dyn_labels=dyn is not None,
+        )
+        == "zone_packed"
+    )
 
     def wave_step(carry: DevState3, batch):
         sb, sx = batch
@@ -1215,28 +1227,21 @@ def make_wave_step3(
                             naok_k, naraw_k = pre.na_ok[k], pre.na_raw[k]
                         nonfit = nonfit & naok_k
 
-                # Materialize `feasible` once: it feeds several reduce-rooted
-                # kernels (domfeas, select). On jax 0.9 optimization_barrier
-                # has a batching rule, so the barrier also stays in the vmapped
-                # what-if program (earlier rounds dropped it there through a
-                # shim and timed that program); whether it should stay under
-                # vmap is a measured question for a later PR.
-                # used1_r stays UN-materialized since
-                # round 3 — its two consumers (the feasible fusion and the
-                # select reduce's fit score) each re-derive it from carry.used
-                # at the same read cost, and skipping the barrier removes the
-                # R×[S, N] write per pod (~14% of device time on the profile).
+                # Nothing of a slot is materialized by hand. used1_r and
+                # `feasible` are re-derived from carry.used inside every
+                # [N]-wide reduce that reads them, the k usage terms of
+                # the "terms" form included: under select_form() ==
+                # "zone_packed" that is ONE reduce a slot (the best packed
+                # node per zone, below), otherwise two (the spread's zone
+                # feasibility and the select). A barrier on used1_r cost
+                # an R×[S, N] write per pod (round 3); one on `feasible`
+                # stood here until PR 30 and was dead: its result was
+                # overwritten at `feasible = fit_ok & nonfit`, so jax
+                # dropped it at lowering. In the single replay XLA writes
+                # `used + plane + req` out once a slot by itself (PR 26).
                 # Preemption still materializes (prefit re-reads used1_r).
-                # PR 26: in the single replay XLA materializes it by itself
-                # once the usage plane is a carried array (one fusion a
-                # slot feeds both reduces, and they run 3-14× faster for
-                # it); and the barrier below is dead — its result is
-                # overwritten at `feasible = fit_ok & nonfit`, so jax drops
-                # it at lowering. What to materialize per slot is ROADMAP
-                # S1.5's question.
                 if st.preemption:
                     used1_r = list(jax.lax.optimization_barrier(tuple(used1_r)))
-                feasible = jax.lax.optimization_barrier(feasible)
                 if st.KT:
                     rows_k = rows0[k] + rows_corr  # [KT, Dcap]
                     totals = totals0[k] + tot_corr
@@ -1450,7 +1455,23 @@ def make_wave_step3(
                         dval = (
                             jnp.arange(Dcap, dtype=jnp.float32) < nd_row[k, o2]
                         )  # existing domains
-                        if st.seg_mode:
+                        if zone_select:
+                            # Every score row but the fit score is constant
+                            # inside a zone here (no node-space row: the
+                            # gate of select_form), so ONE node-wide reduce,
+                            # the best packed node of each zone over the
+                            # fit-only total, gives this normalize its zone
+                            # feasibility and the select below its node.
+                            # Under the select's scope: the fit arithmetic
+                            # and the usage terms fuse into this reduce.
+                            assert not rows_n
+                            with stage("ksim.select"):
+                                zone_best = T2.zone_packed_max(
+                                    total, feasible, st.seg_mode, st.seg_D,
+                                    scenario_axis,
+                                )
+                            core = zone_best > -jnp.inf  # [D]
+                        elif st.seg_mode:
                             # Structured layout: per-domain feasibility via ONE
                             # full-width bitwise-OR reduce of (1 << dom(n)) — a
                             # lane-efficient [N]→scalar reduce (the reshape-any
@@ -1485,9 +1506,6 @@ def make_wave_step3(
                                 core = jnp.any(feasible.reshape(-1, st.seg_D), axis=0)
                             else:
                                 core = jnp.any(feasible.reshape(st.seg_D, -1), axis=1)
-                            domfeas = jnp.concatenate(
-                                [core, jnp.zeros(Dcap + 1 - st.seg_D, bool)]
-                            )
                         else:
                             domfeas = (
                                 jnp.einsum(
@@ -1496,6 +1514,10 @@ def make_wave_step3(
                                 )
                                 > 0.5
                             )  # [Dcap+1]
+                        if st.seg_mode:
+                            domfeas = jnp.concatenate(
+                                [core, jnp.zeros(Dcap + 1 - st.seg_D, bool)]
+                            )
                         okd = dval & domfeas[:Dcap]
                         hi_sp = jnp.max(jnp.where(okd, raw_d, -jnp.inf))
                         lo_sp = jnp.min(jnp.where(okd, raw_d, jnp.inf))
@@ -1512,7 +1534,11 @@ def make_wave_step3(
                             np.float32(T2.MAX_NODE_SCORE),
                         )
                         out_d = jnp.where(dval & has & scored0, out_d, 0.0)
-                        if st.seg_mode == "stride":
+                        if zone_select:
+                            # No node-space expansion: the zone's score joins
+                            # its best packed node in the select.
+                            zone_scores = wt * out_d[: st.seg_D]
+                        elif st.seg_mode == "stride":
                             # dom(n) = n % D: the expansion out_d[dom(n)] is a pure
                             # tile — no [N, D] one-hot read at all (the expansion
                             # dot was the single largest op after round-3's other
@@ -1530,12 +1556,15 @@ def make_wave_step3(
                             )
                         if any_f is None:
                             any_f = jnp.any(domfeas)
-                        total = total + wt * out
+                        if not zone_select:
+                            total = total + wt * out
                 if any_f is None:
                     any_f = jnp.any(feasible)
 
             with stage("ksim.select"):
-                if pack_select:
+                if zone_select:
+                    node, _ = T2.select_node_zone_packed(zone_best, zone_scores)
+                elif pack_select:
                     node, _ = T2.select_node_packed(total, feasible)
                 else:
                     node, _ = select_node(total, feasible)
@@ -1908,6 +1937,47 @@ def pack_select_ok(spec, w_cfg, n_nodes: int) -> bool:
         and all(float(w).is_integer() and w >= 0 for w in w_active)
         and 100.0 * sum(w_active) <= T2.PACK_MAX_TOTAL
     )
+
+
+def select_form(
+    st: V3Static, spec, n_nodes: int,
+    traced_weights: bool = False, dyn_labels: bool = False,
+) -> str:
+    """How many node-wide reduces a slot of a step built from these static
+    facts makes for the spread's zone feasibility and the select — static
+    per compiled program; a replay reports it as
+    ``telemetry.summary()["select_form"]``, a what-if batch in its
+    ``fleet_telemetry``.
+
+    ``"zone_packed"``: ONE, the best packed node of each zone
+    (``ops.tpu.zone_packed_max`` over the fit-only total); zone
+    feasibility is ``best > -inf`` and the node is the best zone's once the
+    zone scores are added (``select_node_zone_packed``). Exact where every
+    score row but the fit score is constant inside a zone and the packed
+    select holds: one coarse spread row scored over a structured layout
+    (``seg_mode``), no TaintToleration / NodeAffinity / InterPodAffinity
+    score row, static integer weights (``pack_select_ok``), no label
+    perturbation. ``"two_pass"``: the bit-OR word (or the domain one-hot
+    contraction) and then the select, each over all nodes. Kept, by a fact
+    of how the program is built and not by a switch, by every profile
+    outside that gate, by a stride layout whose zones do not tile the 128
+    lanes or more than 31 zones, and by tier preemption, whose candidate
+    ranking reads ``feasible`` again after the select."""
+    _, scored = T2.policy_weight_fns(spec, None)
+    ok = bool(
+        not traced_weights and not dyn_labels and not st.preemption
+        and pack_select_ok(spec, dict(spec.weights), n_nodes)
+        # one coarse spread row, scored, over a structured layout ...
+        and spec.spread and scored("PodTopologySpread")
+        and st.SP == 1 and not st.has_host_rows
+        and st.seg_mode and st.seg_D <= 31
+        and (st.seg_mode == "block" or 128 % st.seg_D == 0)
+        # ... and no score row that varies inside a zone but the fit score
+        and not (spec.taints and spec.taint_score and scored("TaintToleration"))
+        and not (spec.node_affinity and scored("NodeAffinity"))
+        and not (spec.interpod and scored("InterPodAffinity"))
+    )
+    return "zone_packed" if ok else "two_pass"
 
 
 def kind_masks(st: V3Static):

@@ -1204,14 +1204,20 @@ class JaxReplayEngine:
             self._sub_jit = jax.jit(release_subtract, donate_argnums=(0,))
         return self._sub_jit
 
-    def _inwave_corrections(self) -> Optional[str]:
-        """The form of in-wave usage corrections the resident chunk program
-        was built with; the v2 step binds per pod and has none."""
+    def _program_forms(self) -> dict:
+        """The static forms the resident chunk program was built with, as
+        the collector's keywords: the in-wave usage corrections and the
+        select (ops.tpu3). The v2 step binds per pod and has neither."""
         if self.engine != "v3":
-            return None
+            return {}
         from ..ops import tpu3 as V3
 
-        return V3.inwave_corrections(self.static3)
+        return {
+            "inwave_corrections": V3.inwave_corrections(self.static3),
+            "select_form": V3.select_form(
+                self.static3, self.spec, self.ec.num_nodes
+            ),
+        }
 
     def _register_programs(self, state, idx_chunks, release: bool) -> None:
         """With profiling armed: hand the resident v3 chunk program this
@@ -1324,7 +1330,7 @@ class JaxReplayEngine:
         tel = (
             TelemetryCollector(
                 self.telemetry_cfg, chunk_waves=C,
-                inwave_corrections=self._inwave_corrections(),
+                **self._program_forms(),
             )
             if self.telemetry_cfg.enabled
             else None
@@ -1830,7 +1836,7 @@ class JaxReplayEngine:
         tel = (
             TelemetryCollector(
                 self.telemetry_cfg, chunk_waves=C,
-                inwave_corrections=self._inwave_corrections(),
+                **self._program_forms(),
             )
             if self.telemetry_cfg.enabled
             else None

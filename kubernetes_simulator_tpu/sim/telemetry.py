@@ -214,6 +214,10 @@ class ReplayTelemetry:
     # Form of the in-wave usage corrections the v3 chunk program was built
     # with (ops.tpu3.inwave_corrections): "plane" or "terms". None for v2.
     inwave_corrections: Optional[str] = None
+    # Whether a slot of the v3 chunk program finds the spread's zone
+    # feasibility and its node in one node-wide reduce or in two
+    # (ops.tpu3.select_form): "zone_packed" or "two_pass". None for v2.
+    select_form: Optional[str] = None
     # What-if batches only: scenarios evaluated; on the device-release
     # path the pow2 widths its release program ran with, the largest number
     # of rank rounds one block of a release list needed (1: no two releases
@@ -226,8 +230,9 @@ class ReplayTelemetry:
 
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
-        for key in ("chunk_waves", "inwave_corrections", "scenarios",
-                    "release_buckets", "release_rounds", "handback_bytes"):
+        for key in ("chunk_waves", "inwave_corrections", "select_form",
+                    "scenarios", "release_buckets", "release_rounds",
+                    "handback_bytes"):
             if getattr(self, key) is not None:
                 out[key] = getattr(self, key)
         if self.latency is not None:
@@ -358,9 +363,10 @@ class ReplayTelemetry:
         tel.series = series
         # Engine-level counters: parts are disjoint scenario blocks of one
         # batch (or none carries them).
-        widths = {p.chunk_waves for _, p in keep}
-        if len(widths) == 1:
-            tel.chunk_waves = widths.pop()
+        for key in ("chunk_waves", "select_form"):
+            values = {getattr(p, key) for _, p in keep}
+            if len(values) == 1:
+                setattr(tel, key, values.pop())
         for key in ("scenarios", "handback_bytes"):
             have = [getattr(p, key) for _, p in keep
                     if getattr(p, key) is not None]
@@ -391,10 +397,12 @@ class TelemetryCollector:
         self, config: Optional[TelemetryConfig] = None,
         chunk_waves: Optional[int] = None,
         inwave_corrections: Optional[str] = None,
+        select_form: Optional[str] = None,
     ):
         self.cfg = TelemetryConfig.resolve(config)
         self.chunk_waves = chunk_waves
         self.inwave_corrections = inwave_corrections
+        self.select_form = select_form
         self.phases = PhaseTimers()
         self._lat: Dict[int, float] = {}
         self._zero = 0
@@ -472,6 +480,7 @@ class TelemetryCollector:
             zero_latency_binds=self._zero,
             chunk_waves=self.chunk_waves,
             inwave_corrections=self.inwave_corrections,
+            select_form=self.select_form,
         )
         if self.cfg.want_series:
             # Zero entries are dropped so engine comparisons see the same

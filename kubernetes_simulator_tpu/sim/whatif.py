@@ -3849,6 +3849,14 @@ class WhatIfEngine:
             fleet_local.phases = run_phases.summary()
             fleet_local.chunk_waves = int(C)
             fleet_local.scenarios = int(self.S)
+            if self.engine == "v3":
+                from ..ops import tpu3 as V3
+
+                fleet_local.select_form = V3.select_form(
+                    self.static3, self.spec, self.ec.num_nodes,
+                    traced_weights=self._policies is not None,
+                    dyn_labels=self._dyn_dev is not None,
+                )
             if dev_rel:
                 fleet_local.release_buckets = sorted(rel_buckets)
                 fleet_local.release_rounds = release_rounds

@@ -11,6 +11,7 @@
 """
 
 import dataclasses
+import zlib
 
 import numpy as np
 import pytest
@@ -88,6 +89,163 @@ def test_pack_gate_edges():
     assert not ok(spec5, w5, 100)  # 5*3*100 = 1500 > 1023
     spec2 = StepSpec(taints=False, node_affinity=False, interpod=False)
     assert ok(spec2, w5, 100)  # only fit+spread active: 600
+
+
+# ---------------------------------------------------------------------------
+# zone-packed select (one reduce: best packed node per zone) vs
+# select_node_packed over the same (total, feasible)
+# ---------------------------------------------------------------------------
+
+
+def _zone_of(N, D, mode):
+    n = np.arange(N)
+    return n % D if mode == "stride" else n // (N // D)
+
+
+def _zone_inputs(case, N, D, mode):
+    """(fit [N], zone scores [D], feasible [N]): integer scores whose sum
+    stays within the packed select's bound."""
+    rng = np.random.default_rng(zlib.crc32(repr((case, N, D, mode)).encode()))
+    zone = _zone_of(N, D, mode)
+    fit = rng.integers(0, 101, size=N).astype(np.float32)
+    zs = (2.0 * rng.integers(0, 101, size=D)).astype(np.float32)
+    feasible = rng.random(N) < 0.4
+    if case == "empty_zone":
+        feasible &= zone != 3 % D
+        zs[3 % D] = 200.0  # the best zone score has nobody to give it to
+    elif case == "none_feasible":
+        feasible[:] = False
+    elif case == "ties_across_zones":
+        # every feasible node has the same total: the lowest index wins,
+        # and it is not in zone 0
+        fit[:] = 40.0
+        zs[:] = 60.0
+        feasible[: N // 2] = False
+    elif case == "ties_inside_a_zone":
+        fit[:] = 7.0
+        zs[:] = 0.0
+        zs[D - 1] = 100.0
+    elif case == "max_total":
+        zs = np.full(D, T.PACK_MAX_TOTAL - 100.0, np.float32)
+        fit[rng.integers(0, N, size=N // 4)] = 100.0
+    elif case == "last_node_only":
+        feasible[:] = False
+        feasible[N - 1] = True
+    else:
+        assert case == "random"
+    return fit, zs, feasible, zone
+
+
+@pytest.mark.parametrize("scenario_axis", [False, True], ids=["one", "vmap"])
+@pytest.mark.parametrize(
+    "N, D, mode",
+    [(272, 8, "stride"), (272, 8, "block"), (240, 16, "stride"),
+     (96, 4, "stride"), (90, 6, "block")],
+    ids=["stride8-tail16", "block8", "stride16", "stride4", "block6"],
+)
+@pytest.mark.parametrize(
+    "case",
+    ["random", "empty_zone", "none_feasible", "ties_across_zones",
+     "ties_inside_a_zone", "max_total", "last_node_only"],
+)
+def test_zone_packed_select_equals_packed_select(case, N, D, mode, scenario_axis):
+    """The node, ``placed`` and each zone's feasibility from the ONE
+    per-zone reduce equal what select_node_packed and a per-zone any() give
+    on the summed total — N off a 128 multiple, an empty zone, nothing
+    feasible (PAD), equal totals across and inside zones (lowest index),
+    totals at PACK_MAX_TOTAL; one scenario and a scenario-mapped batch."""
+    fit, zs, feasible, zone = _zone_inputs(case, N, D, mode)
+
+    def zone_form(fit, zs, feasible):
+        best = T.zone_packed_max(fit, feasible, mode, D, scenario_axis)
+        node, placed = T.select_node_zone_packed(best, zs)
+        return node, placed, best > -jnp.inf
+
+    def plain(fit, zs, feasible):
+        node, placed = T.select_node_packed(fit + zs[jnp.asarray(zone)], feasible)
+        return node, placed
+
+    args = (jnp.asarray(fit), jnp.asarray(zs), jnp.asarray(feasible))
+    if scenario_axis:
+        # three scenarios: the case, its mirror image, nothing feasible
+        args = (
+            jnp.stack([args[0], args[0][::-1], args[0]]),
+            jnp.stack([args[1], args[1], args[1]]),
+            jnp.stack([args[2], args[2][::-1], jnp.zeros(N, bool)]),
+        )
+        zone_form, plain = jax.vmap(zone_form), jax.vmap(plain)
+    node, placed, zfeas = jax.jit(zone_form)(*args)
+    want_node, want_placed = jax.jit(plain)(*args)
+    np.testing.assert_array_equal(node, want_node)
+    np.testing.assert_array_equal(placed, want_placed)
+    feas = np.asarray(args[2]).reshape(-1, N)
+    want_zfeas = np.stack(
+        [[f[zone == d].any() for d in range(D)] for f in feas]
+    )
+    np.testing.assert_array_equal(np.asarray(zfeas).reshape(-1, D), want_zfeas)
+    if case == "none_feasible":
+        assert (np.asarray(node) == PAD).all() and not np.asarray(placed).any()
+    if case == "ties_across_zones" and not scenario_axis:
+        assert int(node) == int(np.flatnonzero(feasible)[0])
+
+
+def _borg_like_static(nodes=80, D=None, mode="stride", **spec_kw):
+    ec, ep = _spread_case(nodes=nodes, pods=40, seed=9)
+    spec = StepSpec.from_config(ec, None, ep)
+    if spec_kw:
+        spec = dataclasses.replace(spec, **spec_kw)
+    t0 = V3.V3Static.build(ec, ep, spec).topo0
+    if D is not None:
+        n = np.arange(ec.num_nodes)
+        ec.node_domain[t0] = (
+            n % D if mode == "stride" else n // (ec.num_nodes // D)
+        ).astype(np.int32)
+        ec.num_domains[t0] = D
+        ec.max_domains = max(ec.max_domains, D)
+    return V3.V3Static.build(ec, ep, spec), spec, ec.num_nodes
+
+
+@pytest.mark.parametrize(
+    "kw, form",
+    [
+        (dict(), "zone_packed"),
+        (dict(D=16), "zone_packed"),
+        (dict(D=8, mode="block"), "zone_packed"),
+        (dict(D=10, mode="block"), "zone_packed"),
+        (dict(D=40), "two_pass"),          # more zones than the word holds
+        (dict(D=10), "two_pass"),          # stride zones do not tile 128 lanes
+        (dict(D=5), "two_pass"),
+        (dict(taint_score=True, taints=True), "two_pass"),   # node-space row
+        (dict(node_affinity=True), "two_pass"),
+        (dict(weights=(("PodTopologySpread", 1.5),)), "two_pass"),  # no packing
+        (dict(weights=(("PodTopologySpread", 0.0),)), "two_pass"),  # no zone row
+    ],
+    ids=["borg", "stride16", "block8", "block10", "stride40", "stride10",
+         "stride5", "taint-score-row", "node-affinity-row", "fractional-weight",
+         "spread-unscored"],
+)
+def test_select_form_gate(kw, form):
+    kw = dict(kw)
+    D, mode = kw.pop("D", None), kw.pop("mode", "stride")
+    st, spec, N = _borg_like_static(D=D, mode=mode, **kw)
+    assert st.seg_mode == mode and (D is None or st.seg_D == D)
+    assert V3.select_form(st, spec, N) == form
+
+
+@pytest.mark.parametrize(
+    "kw",
+    [dict(traced_weights=True), dict(dyn_labels=True), dict(preemption=True)],
+    ids=["traced-weights", "label-perturbation", "tier-preemption"],
+)
+def test_select_form_follows_how_the_step_is_built(kw):
+    """Facts of the program, not of the profile, that keep two reduces: a
+    traced policy vector (no packed select), per-scenario label tables (no
+    domain-space spread), tier preemption (reads `feasible` again)."""
+    st, spec, N = _borg_like_static()
+    assert V3.select_form(st, spec, N) == "zone_packed"
+    if kw.pop("preemption", False):
+        st = dataclasses.replace(st, preemption=True)
+    assert V3.select_form(st, spec, N, **kw) == "two_pass"
 
 
 # ---------------------------------------------------------------------------

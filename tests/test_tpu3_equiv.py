@@ -293,3 +293,191 @@ def test_inwave_corrections_form_follows_how_the_step_is_built(
         preemption=preemption,
     )
     assert V3.inwave_corrections(st, scenario_axis) == form
+
+
+# --- one node-wide reduce a slot: the zone-packed select (ops.tpu3) -------
+# The traps above on a cluster with stride zones and a scored zone spread
+# (the Borg shape: the fit score and ONE zone row), so that the step's
+# gate is on. Held to the CPU oracle, to v2 and to the step built in the
+# two-pass form on the same trace, task for task.
+
+_ZONE = "topology.kubernetes.io/zone"
+
+
+def _zoned(node_cpus, pod_cpus, groups=None, gangs=None, zones=2, duration=None):
+    from kubernetes_simulator_tpu.models.core import (
+        Cluster, LabelSelector, Node, Pod, PodGroup, TopologySpreadConstraint,
+    )
+
+    nodes = [
+        Node(f"n{i}", capacity={"cpu": c, "memory": 8 * 2**30, "pods": 110},
+             labels={_ZONE: f"z{i % zones}"})
+        for i, c in enumerate(node_cpus)
+    ]
+    spread = TopologySpreadConstraint(
+        1, _ZONE, "ScheduleAnyway", LabelSelector.make({"app": "a"})
+    )
+    pods = [
+        Pod(f"p{i}", labels={"app": "a"}, requests={"cpu": c},
+            arrival_time=float(i), duration=duration,
+            pod_group=(groups or {}).get(i), topology_spread=[spread])
+        for i, c in enumerate(pod_cpus)
+    ]
+    pod_groups = {g: PodGroup(g, m) for g, m in (gangs or {}).items()}
+    return encode(Cluster(nodes=nodes, pod_groups=pod_groups), pods)
+
+
+# name -> (node cpus, pod cpus, pod -> gang, gang -> min members)
+_ZONE_TRAPS = {name: trap[:4] for name, trap in _WAVE_TRAPS.items()}
+# four nodes, so that two zones tile them
+_ZONE_TRAPS["pad_then_last"] = ([0.5, 0.5, 0.5, 1.0], [_BIG, 1.0, 1.0], None, None)
+# one wave whose slots collide on the best node of BOTH zones: n0 (zone 0)
+# and n1 (zone 1) take two pods each, in turn as the spread score moves,
+# then the small nodes one each, and two pods fit nowhere
+_ZONE_TRAPS["collide_on_the_best_node_of_two_zones"] = (
+    [4.0, 4.0, 2.0, 2.0], [2.0] * 8, None, None,
+)
+
+
+def _contended_zones(seed, n_nodes=16, n_pods=160, zones=8, duration=90.0):
+    """Requests no f32 holds exactly on 8 stride zones, arrivals faster
+    than the durations free the nodes: fit edges and releases decide."""
+    rng = np.random.default_rng(seed)
+    return _zoned(
+        list(rng.choice([3.3, 4.7, 6.1], size=n_nodes)),
+        list(rng.choice([0.3, 0.7, 1.1, 1.3], size=n_pods)),
+        zones=zones, duration=duration,
+    )
+
+
+def _two_pass(monkeypatch):
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    monkeypatch.setattr(V3, "select_form", lambda *a, **k: "two_pass")
+
+
+@pytest.mark.parametrize("trap", sorted(_ZONE_TRAPS))
+def test_v3_zone_packed_select_on_the_wave_traps(trap, monkeypatch):
+    ec, ep = _zoned(*_ZONE_TRAPS[trap])
+    v3 = _assert_same(ec, ep)
+    tel = v3.telemetry.summary()
+    assert tel["select_form"] == "zone_packed"
+    assert tel["inwave_corrections"] == "plane"
+    _two_pass(monkeypatch)
+    parent = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v3").replay()
+    assert parent.telemetry.summary()["select_form"] == "two_pass"
+    np.testing.assert_array_equal(v3.assignments, parent.assignments)
+    np.testing.assert_array_equal(v3.state.used, parent.state.used)
+    if trap == "collide_on_the_best_node_of_two_zones":
+        np.testing.assert_array_equal(
+            np.sort(v3.assignments), [-1, -1, 0, 0, 1, 1, 2, 3]
+        )
+
+
+def _whatif(ec, ep, **kw):
+    from kubernetes_simulator_tpu.sim.whatif import (
+        Perturbation, Scenario, WhatIfEngine,
+    )
+
+    N = ec.num_nodes
+    scen = [
+        Scenario(),
+        Scenario([Perturbation("node_down", nodes=np.array([0]))]),
+        Scenario([Perturbation("scale_capacity", nodes=np.arange(0, N, 2),
+                               resource="cpu", factor=0.5)]),
+        Scenario([Perturbation("add_taint", nodes=np.array([N - 1]),
+                               key="whatif/injected", value="true",
+                               effect="NoSchedule")]),
+    ]
+    eng = WhatIfEngine(ec, ep, scen, FrameworkConfig(), wave_width=8,
+                       chunk_waves=2, completions=True,
+                       collect_assignments=True, **kw)
+    assert eng.engine == "v3" and eng._completions_dev
+    return eng.run()
+
+
+@pytest.mark.parametrize(
+    "case", sorted(_ZONE_TRAPS) + ["contended-0", "contended-1"]
+)
+def test_whatif_zone_packed_select_equals_two_pass(case, monkeypatch):
+    """A 4-scenario what-if (the k-term corrections, completions on the
+    device-release path): every task's node in every scenario equals the
+    two-pass form's, and scenario 0 the single replay's."""
+    if case in _ZONE_TRAPS:
+        ec, ep = _zoned(*_ZONE_TRAPS[case], duration=20.0)
+    else:
+        ec, ep = _contended_zones(int(case[-1]))
+    res = _whatif(ec, ep)
+    assert res.fleet_telemetry.summary()["select_form"] == "zone_packed"
+    single = JaxReplayEngine(
+        ec, ep, FrameworkConfig(), wave_width=8, chunk_waves=2
+    ).replay()
+    np.testing.assert_array_equal(res.assignments[0], single.assignments)
+    _two_pass(monkeypatch)
+    parent = _whatif(ec, ep)
+    assert parent.fleet_telemetry.summary()["select_form"] == "two_pass"
+    np.testing.assert_array_equal(res.assignments, parent.assignments)
+    np.testing.assert_array_equal(res.placed, parent.placed)
+    if case not in _ZONE_TRAPS:
+        assert (res.unschedulable > 0).all()  # contended in every scenario
+        assert (res.assignments[1:] != res.assignments[0]).any()
+
+
+def _lowered_sha(ec, ep, cfg):
+    import hashlib
+
+    import jax.numpy as jnp
+
+    eng = JaxReplayEngine(ec, ep, cfg, engine="v3", wave_width=4, chunk_waves=4)
+    args = (eng.dc, eng._init_dev_state(), eng._slot_src, eng._extra_src,
+            jnp.asarray(eng.waves.idx[:4]))
+    text = eng.chunk_fn.lower(*args).as_text()
+    return eng, hashlib.sha256(text.encode()).hexdigest()
+
+
+# sha256 of `Lowered.as_text()` of the chunk program, taken on the PARENT
+# of PR 30 (083b5a2, jax 0.9.0): profiles outside the zone-packed gate
+# keep their program to the byte. A PR that changes the step for these
+# profiles on purpose re-pins them; one that meant to leave them alone
+# has found a leak.
+_PARENT_PROGRAMS = {
+    "taint-score-row": "94f14475324557d4f4307df822b7b4bab30c95c5c95e22a9f999fbe3878f70fb",
+    "node-affinity-row": "8442c1fb3a4dd8691261b4b3d968d0658902e0b99d1bd70605b8cd0466a07dd0",
+}
+
+
+def _profile_with_a_node_space_row(profile):
+    """The spread case of the gate tests plus ONE node-space score row: a
+    PreferNoSchedule taint on every fifth node, or a preferred node
+    affinity on every third pod."""
+    from kubernetes_simulator_tpu.models.core import (
+        MatchExpression, NodeAffinitySpec, NodeSelectorTerm,
+        PreferredSchedulingTerm, Taint,
+    )
+
+    cluster = make_cluster(16, seed=5)
+    pods, _ = make_workload(48, seed=5, with_spread=True)
+    if profile == "taint-score-row":
+        for node in cluster.nodes[::5]:
+            node.taints.append(Taint("soft", "x", "PreferNoSchedule"))
+    else:
+        for i, node in enumerate(cluster.nodes):
+            node.labels["tier"] = "hot" if i % 4 == 0 else "cold"
+        prefer_hot = NodeAffinitySpec(preferred=(PreferredSchedulingTerm(
+            10, NodeSelectorTerm((MatchExpression.make("tier", "In", ["hot"]),))
+        ),))
+        for pod in pods[::3]:
+            pod.node_affinity = prefer_hot
+    return encode(cluster, pods)
+
+
+@pytest.mark.parametrize("profile", sorted(_PARENT_PROGRAMS))
+def test_profiles_outside_the_gate_keep_the_parents_program(profile):
+    """A PreferNoSchedule taint or a preferred node affinity puts a
+    node-space score row into the total: two reduces, and the lowered text
+    of the chunk program is the parent's."""
+    ec, ep = _profile_with_a_node_space_row(profile)
+    eng, sha = _lowered_sha(ec, ep, FrameworkConfig())
+    assert eng.static3.seg_mode == "stride"
+    assert eng.replay().telemetry.summary()["select_form"] == "two_pass"
+    assert sha == _PARENT_PROGRAMS[profile]
