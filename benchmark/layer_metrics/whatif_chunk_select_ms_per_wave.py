@@ -1,0 +1,7 @@
+"""whatif_chunk_select_ms_per_wave: device ms a wave of the vmapped chunk
+program in the pick (``ksim.select``; the ``two_pass`` form: extrema over the
+summed score rows, then the lowest index among the best): what
+``chunk_select_ms_per_wave`` reads in the replay cell, under a name of its
+own because the accepted metric lists that cell alone (_stages.py)."""
+
+from layer_metrics.chunk_select_ms_per_wave import read  # noqa: F401

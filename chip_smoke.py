@@ -26,7 +26,11 @@ no result line (nothing here catches a phase's exception):
                   releases to one node (prints ``release_rounds``), and
                   the Borg case on 16 stride-zoned nodes, single replay
                   against what-if scenario 0; each case prints its
-                  ``select_form``, ``zone_packed`` required of the zoned one
+                  ``select_form``, ``zone_packed`` required of the zoned one;
+                  and the default plugin set on 160 nodes as an
+                  arrivals-only what-if batch (``two_pass``, host-scale
+                  count rows), scenario 0 against the single replay; and
+                  the normalize rows' divisions against the integer division
   serve           serve examples/config20_service.yaml < 4 defrag queries
   mesh            only with >1 device: what-if
                   examples/config5_multitenant_mesh.yaml over all devices
@@ -72,6 +76,9 @@ HEAVY_SEED, HEAVY_MEAN_DURATION, HEAVY_CHUNK_WAVES, HEAVY_MIN_ROUNDS = 0, 2000.0
 # layout of the 10,000-node cluster, which 12 nodes are not: its chunk
 # programs take the zone-packed select (ops.tpu3.select_form).
 ZONED_NODES = 16
+# Default-plugin-set what-if case: over 128 nodes, so that hostname is a
+# host-scale topology (ops.tpu3.DMAX_COARSE) as in the 5,000-node cluster.
+PLUGINS_NODES, PLUGINS_PODS = 160, 1024
 
 
 def say(msg: str) -> None:
@@ -203,6 +210,7 @@ def phase_parity() -> dict:
     from kubernetes_simulator_tpu.sim.borg import BorgSpec, make_borg_encoded
     from kubernetes_simulator_tpu.sim.greedy import greedy_replay
     from kubernetes_simulator_tpu.sim.jax_runtime import JaxReplayEngine
+    from kubernetes_simulator_tpu.sim.synthetic import make_cluster
     from kubernetes_simulator_tpu.sim.whatif import (
         Perturbation,
         Scenario,
@@ -384,6 +392,65 @@ def phase_parity() -> dict:
             select_form(r_zoned, "zoned what-if", "zone_packed"),
         ],
     }
+    # (f) the default plugin set as a what-if batch, arrivals only, on 160
+    # nodes: hostname is then a host-scale topology, as at 5,000, so the
+    # anti-affinity rows are [H, N] count planes and the chunk program is
+    # the two-pass form. Scenario 0 has to be the single replay pod for pod,
+    # the placements come back through the hand-back of the chunks' choices.
+    ec, ep = encode(make_cluster(PLUGINS_NODES, seed=0, taint_fraction=0.1),
+                    pods[:PLUGINS_PODS])  # config 2's pods, as in (a)
+    dev = JaxReplayEngine(ec, ep, cfg.framework, wave_width=8,
+                          chunk_waves=BORG_CHUNK_WAVES).replay()
+    r_plug = WhatIfEngine(ec, ep, scen, cfg.framework, wave_width=8,
+                          chunk_waves=BORG_CHUNK_WAVES,
+                          collect_assignments=True).run()
+    same(r_plug.assignments[0], dev.assignments,
+         "default-plugins what-if scenario 0", "the single replay's")
+    planes = r_plug.fleet_telemetry.summary()["count_planes"]
+    require(planes["host_rows"] > 0,
+            f"default-plugins what-if: no host-scale count rows ({planes})")
+    for s in range(len(scen)):
+        require(int((r_plug.assignments[s] >= 0).sum()) == int(r_plug.placed[s]),
+                f"default-plugins what-if scenario {s}: placed differs from "
+                "the placements handed back")
+    require((r_plug.assignments[1:] != dev.assignments).any(),
+            "default-plugins what-if: the perturbations change nothing")
+    out["plugins_whatif"] = {
+        "nodes": PLUGINS_NODES, "pods": PLUGINS_PODS,
+        "placed": [int(x) for x in r_plug.placed], "count_planes": planes,
+        "select_form": select_form(r_plug, "default-plugins what-if",
+                                   "two_pass"),
+    }
+    # (g) the normalize rows divide exactly. The chip's float32 division is
+    # not correctly rounded (floor(6100 / 61) reads 99), which the CPU
+    # backend cannot show: every node-affinity weight against itself, and
+    # spread triples (max, min, score) against the integer division.
+    import jax
+    import jax.numpy as jnp
+
+    from kubernetes_simulator_tpu.ops import tpu as T
+
+    w = np.arange(1, 100, dtype=np.float32)
+    got = np.asarray(jax.vmap(
+        lambda x: T.normalize_max(jnp.stack([x, 0 * x]), jnp.ones(2, bool))
+    )(jnp.asarray(w)))
+    require((got == [100.0, 0.0]).all(),
+            f"normalize_max: weights {w[got[:, 0] != 100].tolist()} of "
+            "themselves are not 100")
+    hi, lo, sc = np.meshgrid(np.arange(1, 400), np.arange(0, 400, 7),
+                             np.arange(0, 400, 3), indexing="ij")
+    ok = (lo <= sc) & (sc <= hi)
+    hi, lo, sc = hi[ok], lo[ok], sc[ok]
+    got = np.asarray(jax.vmap(
+        lambda h, l, x: T.spread_norm_from_extrema(
+            x, jnp.asarray(False), h, l, jnp.asarray(True), True)
+    )(*(jnp.asarray(a, jnp.float32) for a in (hi, lo, sc))))
+    bad = np.nonzero(got != (100 * (hi + lo - sc)) // hi)[0]
+    require(bad.size == 0,
+            f"spread normalize: {bad.size} of {ok.sum()} triples part from "
+            f"the integer division, first (max, min, score) "
+            f"{(hi[bad[:1]], lo[bad[:1]], sc[bad[:1]])}")
+    out["normalize_exact"] = {"weights": len(w), "spread_triples": int(ok.sum())}
     return out
 
 

@@ -252,6 +252,49 @@ class Encoder:
         ns = term.namespaces or (pod_ns,)
         return self._intern_group(term.label_selector, tuple(ns), term.topology_key)
 
+    def _intern_sorted(self, pods: Sequence[Pod]) -> None:
+        """Number what the pods' specs name (toleration keys and values,
+        node-affinity expressions, count groups) by what it is, not by
+        which pod names it first: the tables built from these numbers
+        become constants of the device programs, and the same pods in
+        another arrival order have to find the same program (one
+        executable, one compile-cache entry)."""
+        keys, kvs, exprs, groups = set(), set(), set(), set()
+        for p in pods:
+            for t in p.tolerations:
+                if t.key is not None:
+                    keys.add(t.key)
+                if t.operator != "Exists":
+                    kvs.add((t.key or "", str(t.value)))
+            na = p.node_affinity
+            for term in list(na.required) + [pt.term for pt in na.preferred]:
+                exprs.update(term.match_expressions)
+            terms = list(p.pod_affinity.required) + list(p.pod_anti_affinity.required)
+            terms += [wt.term for wt in p.pod_affinity.preferred]
+            terms += [wt.term for wt in p.pod_anti_affinity.preferred]
+            for t in terms:
+                groups.add((t.label_selector, tuple(sorted(t.namespaces or (p.namespace,))),
+                            t.topology_key))
+            for c in p.topology_spread:
+                groups.add((c.label_selector, (p.namespace,), c.topology_key))
+
+        def by_expr(e):
+            return (e.key, int(e.operator), e.values)
+
+        def by_group(group):
+            sel, ns, topo = group
+            return (topo, ns, sel.match_labels,
+                    tuple(by_expr(e) for e in sel.match_expressions))
+
+        for k in sorted(keys):
+            self.vocab.key(k)
+        for k, v in sorted(kvs):
+            self.vocab.kv(k, v)
+        for e in sorted(exprs, key=by_expr):
+            self._intern_expr(e)
+        for sel, ns, topo in sorted(groups, key=by_group):
+            self._intern_group(sel, ns, topo)
+
     # -- main entry --------------------------------------------------------
 
     def encode(self, cluster: Cluster, workload: Sequence[Pod]) -> Tuple[EncodedCluster, EncodedPods]:
@@ -290,6 +333,7 @@ class Encoder:
         aff_rows, anti_rows, pref_rows, pref_w_rows = [], [], [], []
         spr_rows, spr_skew_rows, spr_dns_rows = [], [], []
 
+        self._intern_sorted(pods)
         for p in pods:
             tk, tv, te = [], [], []
             for t in p.tolerations:

@@ -560,25 +560,45 @@ def spread_score_upstream(d: Derived, st: DevState, s: PodSlot, w_g) -> tuple:
     return jnp.floor(raw + 0.5), ignored, jnp.any(scored)
 
 
+def floor_div_f32(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``floor(a / b)`` for integer-valued f32 ``a ≥ 0`` and ``b > 0`` with
+    ``a + b < 2²⁴``, EXACT on a backend whose f32 division is not correctly
+    rounded. A TPU's is not: ``floor(6100 / 61)`` reads 99 there, and
+    ``floor(100·57 / 95)`` 59 (chip run, PR 31: 5 of the 99 node-affinity
+    weights and 6,411 of 7.4M spread triples part from the integer
+    division), which moved a ScheduleAnyway pod's zone scores by a point
+    and 0.6% of config 2's picks. The quotient is corrected by its
+    remainder, exact under the bound (``q·b ≤ a + b``); where the division
+    is correctly rounded (the CPU backend, numpy) no correction ever
+    fires, so the host and device paths stay bit-identical."""
+    return _fix_quotient(jnp.floor(a / b), a, b)
+
+
+def _fix_quotient(q: jax.Array, a: jax.Array, b: jax.Array) -> jax.Array:
+    """``q`` moved by one where the remainder ``a − q·b`` says it is off by
+    one (the reach of a division wrong in its last bits)."""
+    r = a - q * b
+    return q + (r >= b).astype(q.dtype) - (r < 0).astype(q.dtype)
+
+
 def spread_norm_from_extrema(raw, ignored, hi, lo, any_scored, f32ok=False) -> jax.Array:
     """The normalize half of :func:`spread_upstream_normalize`, with the
     extrema (over feasible & ~ignored nodes, ±inf-masked reductions)
     supplied by the caller — so they can ride a shared stacked reduce.
 
     ``f32ok`` (static): when the trace bound guarantees raw ≤ 83886,
-    ``floor((100·(hi+lo−s)) / hi)`` computed in f32 equals the integer
-    division exactly (numerator ≤ 200·83886 < 2²⁴ is exactly
-    representable, and a misround needs hi·quotient > 2²⁴ — impossible
-    under the bound), so the slow int32 floordiv (no hardware int div on
-    TPU) is skipped."""
+    ``floor((100·(hi+lo−s)) / hi)`` runs in f32 (numerator ≤ 200·83886 <
+    2²⁴ is exactly representable; :func:`floor_div_f32` makes the quotient
+    the integer division's on any backend), so the slow int32 floordiv (no
+    hardware int div on TPU) is skipped."""
     has = hi > -jnp.inf
     if f32ok:
         hi_f = jnp.where(has, hi, 0.0)
         lo_f = jnp.where(has, lo, 0.0)
         pos = hi_f > 0
-        vals = jnp.floor(
-            (np.float32(MAX_NODE_SCORE) * (hi_f + lo_f - raw))
-            / jnp.where(pos, hi_f, 1.0)
+        vals = floor_div_f32(
+            np.float32(MAX_NODE_SCORE) * (hi_f + lo_f - raw),
+            jnp.where(pos, hi_f, 1.0),
         )
         out = jnp.where(pos, vals, np.float32(MAX_NODE_SCORE))
         return jnp.where(ignored | ~has | ~any_scored, 0.0, out)
@@ -710,8 +730,12 @@ def _normalize_row(raw, lo, hi, any_f, minmax: bool, reverse: bool) -> jax.Array
         if reverse:
             out = jnp.where(ok, np.float32(MAX_NODE_SCORE) - out, 0.0)
     else:
+        # Raws are small non-negative integers (counts, summed int weights):
+        # the exact floordiv, not the backend's division alone.
         pos = hi > 0
-        out = jnp.floor((raw * np.float32(MAX_NODE_SCORE)) / jnp.where(pos, hi, 1.0))
+        out = floor_div_f32(
+            raw * np.float32(MAX_NODE_SCORE), jnp.where(pos, hi, 1.0)
+        )
         out = jnp.where(pos, out, 0.0)
         if reverse:
             out = jnp.where(

@@ -387,16 +387,16 @@ def ec_width(arr: np.ndarray) -> int:
 
 def _row_classes(rows: np.ndarray):
     """(class_of [P] i32, rep [C] i32): group identical rows; rep[c] is the
-    first pod index exhibiting class c."""
+    first pod index exhibiting class c. Classes are numbered in the order
+    of their rows, not of their first pods: the representatives' rows are
+    constants of the chunk program, and the same pods in another arrival
+    order have to find the same program."""
     if rows.shape[0] == 0:
         return np.zeros(0, np.int32), np.zeros(0, np.int32)
-    uniq, first, inv = np.unique(
+    _, first, inv = np.unique(
         np.ascontiguousarray(rows), axis=0, return_index=True, return_inverse=True
     )
-    order = np.argsort(first)
-    rank = np.empty(len(uniq), np.int32)
-    rank[order] = np.arange(len(uniq), dtype=np.int32)
-    return rank[inv].astype(np.int32), first[order].astype(np.int32)
+    return inv.reshape(-1).astype(np.int32), first.astype(np.int32)
 
 
 def _matched_idx(pmg: np.ndarray, ref: np.ndarray) -> np.ndarray:
@@ -1978,6 +1978,25 @@ def select_form(
         and not (spec.interpod and scored("InterPodAffinity"))
     )
     return "zone_packed" if ok else "two_pass"
+
+
+def count_planes(st: V3Static) -> dict:
+    """The count planes a step built from these static facts carries,
+    static per compiled program; a what-if batch reports it in its
+    ``fleet_telemetry`` as ``summary()["count_planes"]``. ``domain_rows``:
+    count groups kept at domain scale, rows of the ``[G, Dcap]`` planes a
+    term can read; ``host_rows``: rows of the ``[H, N]`` host-scale planes
+    (match counts, anti-affinity holders, preference weights: a topology
+    of more than ``DMAX_COARSE`` domains, hostname in practice); ``dcap``:
+    the domain planes' width; ``spread_rows`` and ``term_rows``: ``SP`` and
+    ``KT`` of the unified term axis."""
+    return {
+        "domain_rows": int((~st.is_host).sum()),
+        "host_rows": len(st.mc_h_ids) + len(st.anti_h_ids) + len(st.pref_h_ids),
+        "dcap": int(st.Dcap),
+        "spread_rows": int(st.SP),
+        "term_rows": int(st.KT),
+    }
 
 
 def kind_masks(st: V3Static):

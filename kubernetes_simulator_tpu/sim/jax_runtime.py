@@ -218,7 +218,10 @@ class StepSpec:
 def _spread_norm_f32_ok(sp_w, pods: Optional[EncodedPods]) -> bool:
     """True when NO trace state can push a spread raw score past 83886 —
     the bound under which the f32 normalize division is exactly the
-    integer division (ops.tpu.spread_norm_from_extrema). Conservative:
+    integer division (ops.tpu.spread_norm_from_extrema). The 80,000 below
+    is also what keeps ``ops.tpu.floor_div_f32`` inside its own bound there:
+    numerator 100·(hi+lo−raw) ≤ 16,000,000 plus denominator hi ≤ 80,000 is
+    under 2²⁴ = 16,777,216; raise it only with that sum in hand. Conservative:
     per-group counts are bounded by the total pods matching the group
     (plus a wave-correction margin), summed over the pod's constraint
     width at the largest weight/skew."""

@@ -218,11 +218,15 @@ class ReplayTelemetry:
     # feasibility and its node in one node-wide reduce or in two
     # (ops.tpu3.select_form): "zone_packed" or "two_pass". None for v2.
     select_form: Optional[str] = None
-    # What-if batches only: scenarios evaluated; on the device-release
-    # path the pow2 widths its release program ran with, the largest number
-    # of rank rounds one block of a release list needed (1: no two releases
-    # of a block ever hit one node; ops.release_planes), and the bytes of
-    # the placement hand-back (0 when placements were not asked for).
+    # What-if batches only: the count planes of the compiled problem
+    # (ops.tpu3.count_planes: rows at domain scale and at host scale, the
+    # domain width, spread rows, term rows); scenarios evaluated; on the
+    # device-release path the pow2 widths its release program ran with and
+    # the largest number of rank rounds one block of a release list needed
+    # (1: no two releases of a block ever hit one node; ops.release_planes);
+    # where placements come back from the device in one copy, its bytes (0
+    # when they were not asked for).
+    count_planes: Optional[Dict[str, int]] = None
     scenarios: Optional[int] = None
     release_buckets: Optional[List[int]] = None
     release_rounds: Optional[int] = None
@@ -231,8 +235,8 @@ class ReplayTelemetry:
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
         for key in ("chunk_waves", "inwave_corrections", "select_form",
-                    "scenarios", "release_buckets", "release_rounds",
-                    "handback_bytes"):
+                    "count_planes", "scenarios", "release_buckets",
+                    "release_rounds", "handback_bytes"):
             if getattr(self, key) is not None:
                 out[key] = getattr(self, key)
         if self.latency is not None:
@@ -363,10 +367,10 @@ class ReplayTelemetry:
         tel.series = series
         # Engine-level counters: parts are disjoint scenario blocks of one
         # batch (or none carries them).
-        for key in ("chunk_waves", "select_form"):
-            values = {getattr(p, key) for _, p in keep}
-            if len(values) == 1:
-                setattr(tel, key, values.pop())
+        for key in ("chunk_waves", "select_form", "count_planes"):
+            values = [getattr(p, key) for _, p in keep]
+            if all(v == values[0] for v in values):
+                setattr(tel, key, values[0])
         for key in ("scenarios", "handback_bytes"):
             have = [getattr(p, key) for _, p in keep
                     if getattr(p, key) is not None]

@@ -2266,24 +2266,43 @@ class WhatIfEngine:
             x = self._replicate_fn(x)
         return np.asarray(x)
 
-    def _handback(self, vassign_d) -> Tuple[np.ndarray, int]:
-        """(assignments [S, P], bytes copied): the device-release path's
-        placements, task by task. ``vassign`` holds them in wave order
-        (and the pre-bound tasks in its tail); the static ``pos`` map of
-        ``_stage_dev_rel`` puts them into task order on the device (one
-        gather), and the result comes to the host in one copy. On a v5e
-        at 128 x 131,072: 0.03 s, against 0.29 s for the copy first and
-        ``np.take`` on the host (PERF.md §6, PR 27)."""
+    def _handback(self, wave_order, pos) -> Tuple[np.ndarray, int]:
+        """(assignments [S, P], bytes copied): every task's node, task by
+        task. ``wave_order`` holds the placements in wave order on the
+        device: the device-release path's ``vassign`` buffer (the pre-bound
+        tasks in its tail), or, where nothing is released on the device (an
+        arrivals-only batch, the host fold path), the list of every chunk's
+        choices ``[S, C, W]``, strung together here with a PAD column at
+        the end for a task in no wave. ``pos()`` gives the static map from
+        task to place in that order; one gather on the device puts the
+        placements into task order, and the result comes to the host in one
+        copy. On a v5e at 128 x 131,072: 0.03 s, against 0.29 s for the
+        copy first and ``np.take`` on the host (PERF.md §6, PR 27); at
+        256 x 50,000 from ten chunks 0.027 s against 0.11 s for a fetch a
+        chunk and a host scatter (PR 31)."""
         def build():
-            pos = jnp.asarray(self._dev_rel_stage["pos"])
+            pos_d = jnp.asarray(pos())
 
-            def whatif_handback(vassign):
-                return jnp.take(vassign, pos, axis=1)
+            def whatif_handback(buf):
+                if isinstance(buf, (list, tuple)):
+                    flat = [c.reshape(c.shape[0], -1) for c in buf]
+                    none = jnp.full((flat[0].shape[0], 1), PAD, flat[0].dtype)
+                    buf = jnp.concatenate(flat + [none], axis=1)
+                return jnp.take(buf, pos_d, axis=1).astype(jnp.int32)
 
             return jax.jit(whatif_handback)
 
-        out = self._fetch(self._jit_once("handback", build)(vassign_d))
+        out = self._fetch(self._jit_once("handback", build)(wave_order))
         return out, int(out.nbytes)
+
+    def _chunks_pos(self, idx: np.ndarray) -> np.ndarray:
+        """[P] each task's place in the chunks' wave order; a task in no
+        wave reads the PAD column after the last slot."""
+        flat_idx = idx.reshape(-1)
+        valid = np.nonzero(flat_idx >= 0)[0]
+        pos = np.full(self.pods.num_pods, flat_idx.size, np.int32)
+        pos[flat_idx[valid]] = valid
+        return pos
 
     def _stage_dev_rel(self, idx: np.ndarray, C: int) -> dict:
         """Host bucketing + device staging for the device-release path —
@@ -3662,6 +3681,7 @@ class WhatIfEngine:
         _gather_ann = _pann("gather")
         _gather_ann.__enter__()
         to_schedule = int((idx >= 0).sum())
+        chunk_handback = False  # placements from the chunks' choices
         kube_preempt = kube_dropped = None
         kube_evict = kube_resched = kube_stranded = kube_lat = None
         sc_lat_p50 = sc_lat_p90 = sc_lat_p99 = sc_telemetry = None
@@ -3744,24 +3764,11 @@ class WhatIfEngine:
             scheduled = ~prebound
             placed = (assignments[:, scheduled] >= 0).sum(axis=1).astype(np.int32)
         elif self.collect_assignments and not dev_rel:
-            choices = np.concatenate(
-                [self._fetch(o) for o in outs], axis=1
-            )  # [S, Cw, W]
-            flat_idx = idx.reshape(-1)
-            valid = flat_idx >= 0
-            assignments = np.full((self.S, self.pods.num_pods), PAD, np.int32)
-            assignments[:, self.pods.bound_node >= 0] = self.pods.bound_node[
-                self.pods.bound_node >= 0
-            ]
-            flat_choice = choices.reshape(self.S, -1)
-            assignments[:, flat_idx[valid]] = flat_choice[:, valid]
-            if self._fork_choices is not None:
-                # Pre-fork placements are common to every scenario.
-                pidx = self.waves.idx[: self._fork_waves_done].reshape(-1)
-                pch = self._fork_choices.reshape(-1)
-                pv = pidx >= 0
-                assignments[:, pidx[pv]] = pch[pv][None, :]
-            placed = (flat_choice[:, valid] >= 0).sum(axis=1).astype(np.int32)
+            # Every chunk's choices are still on the device: the placements
+            # come to the host in the ``handback`` phase below, and
+            # ``placed`` is counted from them there.
+            assignments = placed = None
+            chunk_handback = True
         else:
             assignments = None
             if self._need_choices:
@@ -3832,7 +3839,25 @@ class WhatIfEngine:
             # The device-release path's placements: the wave-order buffer
             # comes to the host once, after the last chunk.
             with run_phases.tick("handback"), _pann("handback"):
-                assignments, handback_bytes = self._handback(vassign_d)
+                assignments, handback_bytes = self._handback(
+                    vassign_d, lambda: self._dev_rel_stage["pos"])
+        elif chunk_handback:
+            # The chunks' choices, put into task order on the device and
+            # copied once.
+            with run_phases.tick("handback"), _pann("handback"):
+                assignments, handback_bytes = self._handback(
+                    outs, lambda: self._chunks_pos(idx))
+                placed = (assignments >= 0).sum(axis=1).astype(np.int32)
+                prebound = self.pods.bound_node >= 0
+                if prebound.any() or self._fork_choices is not None:
+                    assignments = np.array(assignments)  # the copy is read-only
+                    assignments[:, prebound] = self.pods.bound_node[prebound]
+                if self._fork_choices is not None:
+                    # Pre-fork placements are common to every scenario.
+                    pidx = self.waves.idx[: self._fork_waves_done].reshape(-1)
+                    pch = self._fork_choices.reshape(-1)
+                    pv = pidx >= 0
+                    assignments[:, pidx[pv]] = pch[pv][None, :]
         # This process's partial fleet telemetry (round 12): per-scenario
         # collectors merged same-process (phases key-wise summed would be
         # wrong here — the fleet view wants the ENGINE's wall clocks, so
@@ -3857,9 +3882,11 @@ class WhatIfEngine:
                     traced_weights=self._policies is not None,
                     dyn_labels=self._dyn_dev is not None,
                 )
+                fleet_local.count_planes = V3.count_planes(self.static3)
             if dev_rel:
                 fleet_local.release_buckets = sorted(rel_buckets)
                 fleet_local.release_rounds = release_rounds
+            if dev_rel or self.collect_assignments:
                 fleet_local.handback_bytes = handback_bytes
             # DCN checkpoint-publication attribution (round 16): the
             # cumulative encode+push wall, publication count and encoded

@@ -435,14 +435,21 @@ def _lowered_sha(ec, ep, cfg):
     return eng, hashlib.sha256(text.encode()).hexdigest()
 
 
-# sha256 of `Lowered.as_text()` of the chunk program, taken on the PARENT
-# of PR 30 (083b5a2, jax 0.9.0): profiles outside the zone-packed gate
-# keep their program to the byte. A PR that changes the step for these
-# profiles on purpose re-pins them; one that meant to leave them alone
-# has found a leak.
+# sha256 of `Lowered.as_text()` of the chunk program (jax 0.9.0): profiles
+# outside the zone-packed gate keep their program to the byte. Taken on the
+# parent of PR 30 (083b5a2) and pinned again in PR 31, on purpose: a
+# max-normalised score row (TaintToleration, NodeAffinity) now divides
+# through `ops.tpu.floor_div_f32`, because the chip's float32 division
+# reads floor(6100 / 61) as 99 (94f14475... and 8442c1fb... before), and
+# the toleration and node-affinity classes are numbered by their rows, not
+# by their first pods, so that another arrival order finds the same program:
+# the node-affinity profile's two class rows changed places in two constants
+# and nothing else did (2d3895a1... with the first pod's class first). A PR
+# that changes the step for these profiles on purpose re-pins them; one
+# that meant to leave them alone has found a leak.
 _PARENT_PROGRAMS = {
-    "taint-score-row": "94f14475324557d4f4307df822b7b4bab30c95c5c95e22a9f999fbe3878f70fb",
-    "node-affinity-row": "8442c1fb3a4dd8691261b4b3d968d0658902e0b99d1bd70605b8cd0466a07dd0",
+    "taint-score-row": "941161e7208d8dc2cba70398fcdc7fbd88351032cc1ddd8aed2185237877eb2a",
+    "node-affinity-row": "ae170876ca19279d9c6ce01ff614d7fe43d0f69b566719d783f7e10b06b6a99e",
 }
 
 
