@@ -409,6 +409,9 @@ def phase_parity() -> dict:
     planes = r_plug.fleet_telemetry.summary()["count_planes"]
     require(planes["host_rows"] > 0,
             f"default-plugins what-if: no host-scale count rows ({planes})")
+    require(planes["host_read_positions"] > 0,
+            "default-plugins what-if: no position of the term axis reads a "
+            f"host row by its index ({planes})")
     for s in range(len(scen)):
         require(int((r_plug.assignments[s] >= 0).sum()) == int(r_plug.placed[s]),
                 f"default-plugins what-if scenario {s}: placed differs from "

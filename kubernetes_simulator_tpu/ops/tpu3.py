@@ -152,6 +152,12 @@ class V3Static:
     # filter that never fires). Profile round 3: _expand_rows was ~10% of
     # device time on the north-star shape purely from this.
     has_dns: bool
+    # [KT] bool: some pod's term at this position of the row axis names a
+    # host-scale group. Only such a position reads a host-plane row where
+    # the step reads rows by index (host_rows_at); the others emit no read.
+    # Like ``has_dns`` a fact of the pod multiset, not of the arrival order:
+    # every deal of the same pods finds the same program.
+    host_pos: np.ndarray
     # All domain-bearing groups share one topology key (the Borg shape:
     # zone-only): bound-node domain lookups collapse to one shared [N] map.
     # ``topo0`` is that topology's id (PAD when no group carries domains);
@@ -337,6 +343,12 @@ class V3Static:
             and single_g[anti_h_ids].all()
             and max_pods * max(B, 1) <= 256
         )
+        term_g = np.concatenate(
+            [ep.aff_req[:, :A], ep.anti_req[:, :B], ep.spread_g[:, :SP],
+             ep.pref_aff[:, :PA], anti_midx, pref_midx],
+            axis=1,
+        )  # [P, KT] the group each pod names at each row position (PAD none)
+        host_pos = ((term_g >= 0) & is_host[np.clip(term_g, 0, G - 1)]).any(axis=0)
         topo_groups = (gt >= 0) & (nd_g > 0)
         single_topo = bool(len(set(gt[topo_groups].tolist())) <= 1)
         topo0 = int(gt[topo_groups][0]) if topo_groups.any() else PAD
@@ -369,6 +381,7 @@ class V3Static:
             has_dns=bool(
                 SP and (ep.spread_dns[:, :SP] & (ep.spread_g[:, :SP] >= 0)).any()
             ),
+            host_pos=host_pos,
             single_topo=single_topo,
         )
         if preemption and out.has_host_rows:
@@ -677,9 +690,12 @@ class WavePre3(NamedTuple):
     coarse_row: jax.Array  # [W, KT] f32 row's group is coarse
     dmap: jax.Array  # [W, KT, N] f32 node→domain per row (PAD=-1)
     ov: jax.Array  # [W(j), W(k), KT] f32 bind-of-j → read-of-(k,row) coupling
-    oh_mc_h: jax.Array  # [W, KT, Hmc] f32 host-plane one-hots
+    # Host-plane reads in the form host_row_reads() names; the other form's
+    # fields are empty.
+    oh_mc_h: jax.Array  # [W, KT, Hmc] f32 host-plane one-hots ("contraction")
     oh_anti_h: jax.Array  # [W, KT, Ha] f32
     oh_pref_h: jax.Array  # [W, KT, Hp] f32
+    row_h: jax.Array  # [W, KT] i32 row of its kind's host plane, PAD none ("rows")
     row_w: jax.Array  # [W, KT] f32 per-row weight (pref rows; 1/0 elsewhere)
     aff_selfm: jax.Array  # [W, A] bool
     sp_selfm: jax.Array  # [W, SP] f32
@@ -703,6 +719,7 @@ class WavePre3(NamedTuple):
 def build_wave_pre3(
     dc: DevCluster, d: Derived, sh: Shared3, st: V3Static,
     sb: PodSlot, sx: SlotExtra, spec, dyn: Optional[DynTables] = None,
+    host_rows_read: bool = False,
 ) -> WavePre3:
     W = sb.pod_id.shape[0]
     G = st.G
@@ -752,7 +769,7 @@ def build_wave_pre3(
     )
 
     def hostoh(g2local, H):
-        if H == 0:
+        if H == 0 or host_rows_read:
             return jnp.zeros((W, st.KT, 0), jnp.float32)
         loc = jnp.asarray(g2local)  # [G] static table
         # one-hot over local host ids; zero for coarse/PAD rows
@@ -767,6 +784,22 @@ def build_wave_pre3(
     oh_mc_h = hostoh(st.g2mc_h, len(st.mc_h_ids)) * kmask["mc"][None, :, None]
     oh_anti_h = hostoh(st.g2anti_h, len(st.anti_h_ids)) * kmask["anti"][None, :, None]
     oh_pref_h = hostoh(st.g2pref_h, len(st.pref_h_ids)) * kmask["pref"][None, :, None]
+
+    # The "rows" form: the row of its plane kind's [H, N] host plane that
+    # each position names, from the static [G] tables g2*_h through the term
+    # one-hot (small whole numbers, exact); PAD for a PAD, coarse or unread
+    # group.
+    if st.has_host_rows and host_rows_read:
+        row_h = jnp.where(
+            row_g >= 0,
+            jnp.einsum(
+                "wkg,kg->wk", oh_row, jnp.asarray(host_row_table(st)),
+                precision=_HI,
+            ),
+            float(PAD),
+        ).astype(jnp.int32)
+    else:
+        row_h = jnp.full((W, st.KT), PAD, jnp.int32)
 
     o0, o1, o2, o3, o4, o5, o6 = st.sections
     row_w = jnp.ones((W, st.KT), jnp.float32)
@@ -848,7 +881,7 @@ def build_wave_pre3(
     return WavePre3(
         row_g=row_g, oh_row=oh_row, coarse_row=coarse_row, dmap=dmap, ov=ov,
         oh_mc_h=oh_mc_h, oh_anti_h=oh_anti_h, oh_pref_h=oh_pref_h,
-        row_w=row_w, aff_selfm=aff_selfm,
+        row_h=row_h, row_w=row_w, aff_selfm=aff_selfm,
         sp_selfm=sp_selfm, sp_skew=sp_skew, sp_dns=sp_dns,
         sp_scored=sp_scored, sp_w=sp_w,
         pmg_f=pmg_f, anti_g=anti_g, pref_g=pref_g,
@@ -947,7 +980,8 @@ def make_wave_step3(
     the packed select (its integer-weight bound needs static weights).
     ``scenario_axis``: the caller maps the step over a scenario axis
     (``vmap``) — a static fact of how the program is built, which picks the
-    form of the in-wave usage corrections (:func:`inwave_corrections`)."""
+    form of the in-wave usage corrections (:func:`inwave_corrections`) and
+    of the host-scale count row reads (:func:`host_row_reads`)."""
     cmasks = cmasks or {}
     G = st.G
     Dcap = st.Dcap
@@ -978,6 +1012,7 @@ def make_wave_step3(
     )
     pack_select = wvec is None and pack_select_ok(spec, w_cfg, dc.allocatable.shape[0])
     corr_plane = inwave_corrections(st, scenario_axis) == "plane"
+    host_rows_read = host_row_reads(scenario_axis) == "rows"
     zone_select = (
         select_form(
             st, spec, dc.allocatable.shape[0],
@@ -990,7 +1025,7 @@ def make_wave_step3(
         sb, sx = batch
         N = dc.allocatable.shape[0]
         with stage("ksim.reads"):
-            pre = build_wave_pre3(dc, d, sh, st, sb, sx, spec, dyn)
+            pre = build_wave_pre3(dc, d, sh, st, sb, sx, spec, dyn, host_rows_read)
 
             # Wave-start reads (identical for every pod in the wave).
             if st.KT:
@@ -1003,7 +1038,7 @@ def make_wave_step3(
                     + jnp.einsum("wkg,gd->wkd", lhs_c * kmask["pref"][None, :, None],
                                  carry.pref_dom, precision=_HI)
                 )  # [W, KT, Dcap]
-                if st.has_host_rows:
+                if st.has_host_rows and not host_rows_read:
                     # One-hot LHS cast to the plane dtype: bf16×bf16 einsums
                     # with f32 accumulation stay exact (0/1 × small ints).
                     vals_h0 = jnp.zeros((wave_width, st.KT, N), jnp.float32)
@@ -1106,6 +1141,8 @@ def make_wave_step3(
         for k in range(wave_width):
             with stage("ksim.reads"):
                 s = jax.tree.map(lambda a: a[k], sb)
+                if st.has_host_rows and host_rows_read:
+                    vals_h = host_rows_at(st, carry, pre.row_h[k])
 
             # --- exact in-wave corrections from pods j<k -----------------
             # Usage: the running plane, or (preemption, scenario batch) k
@@ -1248,7 +1285,9 @@ def make_wave_step3(
                     if need_vals:
                         vals = _expand_rows(rows_k, dom_oh[k])
                         if st.has_host_rows:
-                            vals = vals + vals_h0[k] + valh_corr
+                            if not host_rows_read:
+                                vals_h = vals_h0[k]
+                            vals = vals + vals_h + valh_corr
                         gvalid = pre.dmap[k] >= 0  # [KT, N]
                         if Kdyn:
                             # labels_dirty: corrections on top of the BASE
@@ -1916,6 +1955,32 @@ def inwave_corrections(st: V3Static, scenario_axis: bool = False) -> str:
     return "terms" if (st.preemption or scenario_axis) else "plane"
 
 
+def host_row_reads(scenario_axis: bool = False) -> str:
+    """How a slot of the step obtains the wave-start values of its
+    host-scale count rows — static per compiled program, and like
+    :func:`inwave_corrections` a fact of how the program is built, not a
+    switch. Both forms give the same values to the bit.
+
+    ``"rows"``: each slot reads the ONE row a term names, by its index, at
+    the positions of the term axis that can name a host-scale group and at
+    no other (:func:`host_rows_at`). The step mapped over a scenario axis
+    (the what-if batch): the index comes from the pods' terms, which every
+    scenario shares, so the read is a dynamic slice of the ``[S, H, N]``
+    plane, where the contraction below wrote and read back an
+    ``[S, W, KT, N]`` float32 tensor every wave: 42% of a
+    ``k8s5k-whatif256`` wave, 687,457 -> 1,047,151 placements/s on a v5e
+    (PERF.md §6 PR 32).
+
+    ``"contraction"``: at wave start the ``[W, KT, H]`` one-hots of all
+    positions against the whole ``[H, N]`` planes, two small dots and an add
+    (``[8, 4, 5000]`` float32, 640 KB), each slot a static slice of the
+    result. The single replay, which runs at op latency: 16 row reads a
+    wave in place of those three operations lose 8.6% there (50,000 pods on
+    5,000 nodes: 1.1272 s a replay against 1.2239; read once at wave start
+    for all slots, 11%; same PR)."""
+    return "rows" if scenario_axis else "contraction"
+
+
 def pack_select_ok(spec, w_cfg, n_nodes: int) -> bool:
     """Static gate for ops.tpu.select_node_packed (see its exactness
     bounds): integer non-negative weights on every ACTIVE score row keep
@@ -1989,14 +2054,54 @@ def count_planes(st: V3Static) -> dict:
     (match counts, anti-affinity holders, preference weights: a topology
     of more than ``DMAX_COARSE`` domains, hostname in practice); ``dcap``:
     the domain planes' width; ``spread_rows`` and ``term_rows``: ``SP`` and
-    ``KT`` of the unified term axis."""
+    ``KT`` of the unified term axis; ``host_read_positions``: how many of
+    the ``KT`` positions read a host row in every slot
+    (:func:`host_rows_at`; the others can never name a host-scale group)."""
     return {
         "domain_rows": int((~st.is_host).sum()),
         "host_rows": len(st.mc_h_ids) + len(st.anti_h_ids) + len(st.pref_h_ids),
         "dcap": int(st.Dcap),
         "spread_rows": int(st.SP),
         "term_rows": int(st.KT),
+        "host_read_positions": int(st.host_pos.sum()),
     }
+
+
+def host_row_table(st: V3Static) -> np.ndarray:
+    """[KT, G] f32: the row of its plane kind's host plane that group g
+    holds, for each position of the term axis (PAD where the group is
+    coarse or no term reads it): ``g2mc_h`` under the match-count sections,
+    ``g2anti_h`` under sym-anti, ``g2pref_h`` under sym-pref."""
+    o0, o1, o2, o3, o4, o5, o6 = st.sections
+    return np.stack(
+        [st.g2mc_h] * (o4 - o0) + [st.g2anti_h] * (o5 - o4)
+        + [st.g2pref_h] * (o6 - o5)
+    ).astype(np.float32)
+
+
+def host_rows_at(st: V3Static, carry: DevState3, row_h_k: jax.Array) -> jax.Array:
+    """[KT, N] f32: the wave-start values of the host-scale count rows one
+    slot's terms name (``row_h_k``: the slot's ``WavePre3.row_h``), zero at
+    a position that names none. Each is read from the carried plane of its
+    kind by row index: what a one-hot over the plane's rows selects, to the
+    bit (one non-zero term; a bf16 plane holds small whole numbers). A
+    position no pod's term can point at a host-scale group
+    (``st.host_pos``) reads nothing."""
+    o0, o1, o2, o3, o4, o5, o6 = st.sections
+    rows = [jnp.zeros(carry.used.shape[1:], jnp.float32)] * st.KT
+    for lo, hi, plane in (
+        (o0, o4, carry.mc_host), (o4, o5, carry.anti_host),
+        (o5, o6, carry.pref_host),
+    ):
+        for r in range(lo, hi):
+            if st.host_pos[r]:
+                row = jax.lax.dynamic_index_in_dim(
+                    plane, jnp.clip(row_h_k[r], 0), 0, keepdims=False
+                )
+                rows[r] = jnp.where(
+                    row_h_k[r] >= 0, row.astype(jnp.float32), 0.0
+                )
+    return jnp.stack(rows)
 
 
 def kind_masks(st: V3Static):

@@ -67,28 +67,7 @@ def test_v3_host_singleton_partial_labels():
     label-less nodes must not credit the host planes (regression: the
     singleton commit fast path skipped v2's node_has_dom gate, making the
     symmetric-anti check wrongly block label-less nodes)."""
-    from kubernetes_simulator_tpu.models.core import (
-        Cluster, LabelSelector, Node, Pod, PodAffinitySpec, PodAffinityTerm,
-    )
-
-    key = "custom/slot"
-    nodes = [
-        Node(
-            f"n{i}",
-            capacity={"cpu": 4.0, "memory": 8 * 2**30, "pods": 20},
-            labels=({key: f"s{i}"} if i % 3 != 0 else {}),  # every 3rd bare
-        )
-        for i in range(12)
-    ]
-    anti = PodAffinitySpec(
-        required=(PodAffinityTerm(LabelSelector.make({"app": "a"}), key),)
-    )
-    pods = [
-        Pod(f"p{i}", labels={"app": "a"}, requests={"cpu": 1.0},
-            arrival_time=float(i), pod_anti_affinity=anti)
-        for i in range(20)
-    ]
-    ec, ep = encode(Cluster(nodes=nodes), pods)
+    ec, ep = _partial_label_slots()
     # dmax_coarse=0 forces every topology onto the host-plane path; the
     # custom key's domains are singletons.
     _assert_same(ec, ep, dmax_coarse=0)
@@ -229,10 +208,21 @@ def test_v3_plane_equals_terms_bit_for_bit(seed, monkeypatch):
     np.testing.assert_array_equal(plane.state.used, terms.state.used)
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
 def _node_wide_compares(ec, ep, wave_width):
     """How many [N]-wide integer equality tests (node iota against a chosen
-    node) one traced chunk program of width ``wave_width`` holds: the jaxpr
-    walked through every nested jaxpr (pjit, scan, closed_call)."""
+    node) one traced chunk program of width ``wave_width`` holds, nested
+    jaxprs (pjit, scan, closed_call) included."""
     import jax
     import jax.numpy as jnp
 
@@ -243,21 +233,12 @@ def _node_wide_compares(ec, ep, wave_width):
     args = (eng.dc, eng._init_dev_state(), eng._slot_src, eng._extra_src,
             jnp.asarray(eng.waves.idx[:2]))
 
-    def count(jaxpr):
-        n = 0
-        for eqn in jaxpr.eqns:
-            if (eqn.primitive.name == "eq"
-                    and eqn.outvars[0].aval.shape == (N,)
-                    and jnp.issubdtype(eqn.invars[0].aval.dtype, jnp.integer)):
-                n += 1
-            for v in eqn.params.values():
-                for sub in (v if isinstance(v, (list, tuple)) else (v,)):
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        n += count(sub)
-        return n
-
-    return count(jax.make_jaxpr(eng.chunk_fn)(*args).jaxpr)
+    return sum(
+        eqn.primitive.name == "eq"
+        and eqn.outvars[0].aval.shape == (N,)
+        and jnp.issubdtype(eqn.invars[0].aval.dtype, jnp.integer)
+        for eqn in _eqns(jax.make_jaxpr(eng.chunk_fn)(*args).jaxpr)
+    )
 
 
 def test_v3_node_wide_compares_grow_linearly_with_wave_width(monkeypatch):
@@ -375,20 +356,9 @@ def test_v3_zone_packed_select_on_the_wave_traps(trap, monkeypatch):
 
 
 def _whatif(ec, ep, **kw):
-    from kubernetes_simulator_tpu.sim.whatif import (
-        Perturbation, Scenario, WhatIfEngine,
-    )
+    from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
 
-    N = ec.num_nodes
-    scen = [
-        Scenario(),
-        Scenario([Perturbation("node_down", nodes=np.array([0]))]),
-        Scenario([Perturbation("scale_capacity", nodes=np.arange(0, N, 2),
-                               resource="cpu", factor=0.5)]),
-        Scenario([Perturbation("add_taint", nodes=np.array([N - 1]),
-                               key="whatif/injected", value="true",
-                               effect="NoSchedule")]),
-    ]
+    scen = _perturbed(ec.num_nodes, 4)
     eng = WhatIfEngine(ec, ep, scen, FrameworkConfig(), wave_width=8,
                        chunk_waves=2, completions=True,
                        collect_assignments=True, **kw)
@@ -488,3 +458,257 @@ def test_profiles_outside_the_gate_keep_the_parents_program(profile):
     assert eng.static3.seg_mode == "stride"
     assert eng.replay().telemetry.summary()["select_form"] == "two_pass"
     assert sha == _PARENT_PROGRAMS[profile]
+
+
+# --- a slot's host-scale count rows, read by row index (ops.tpu3) ---------
+# Where the step is mapped over a scenario axis a slot reads the ONE row of
+# a host plane a term names (`host_row_reads` "rows", `host_rows_at`: a
+# dynamic slice by a scenario-shared index, and only at the positions of the
+# term axis that can name a host-scale group); the single replay keeps the
+# wave-start contraction of all W x KT one-hots with the whole planes. The
+# row read is held to that contraction in both mappings, and to the plain
+# form below: each position's one-hot over its kind's H rows against the
+# whole [H, N] plane, slot by slot and at EVERY position.
+
+
+def _host_rows_by_contraction(st, carry, row_h_k):
+    import jax
+    import jax.numpy as jnp
+
+    o = st.sections
+    rows = [jnp.zeros(carry.used.shape[1:], jnp.float32)] * st.KT
+    for lo, hi, plane in ((o[0], o[4], carry.mc_host),
+                          (o[4], o[5], carry.anti_host),
+                          (o[5], o[6], carry.pref_host)):
+        if plane.shape[0]:
+            for r in range(lo, hi):
+                oh = (row_h_k[r] == jnp.arange(plane.shape[0])).astype(plane.dtype)
+                rows[r] = jnp.einsum(
+                    "h,hn->n", oh, plane, precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32,
+                )
+    return jnp.stack(rows)
+
+
+def _partial_label_slots():
+    """A singleton host topology (`custom/slot`) that every third node
+    lacks, 20 pods with a required anti-affinity to their own app on it."""
+    from kubernetes_simulator_tpu.models.core import (
+        Cluster, LabelSelector, Node, Pod, PodAffinitySpec, PodAffinityTerm,
+    )
+
+    key = "custom/slot"
+    nodes = [
+        Node(f"n{i}", capacity={"cpu": 4.0, "memory": 8 * 2**30, "pods": 20},
+             labels=({key: f"s{i}"} if i % 3 != 0 else {}))
+        for i in range(12)
+    ]
+    anti = PodAffinitySpec(
+        required=(PodAffinityTerm(LabelSelector.make({"app": "a"}), key),)
+    )
+    pods = [
+        Pod(f"p{i}", labels={"app": "a"}, requests={"cpu": 1.0},
+            arrival_time=float(i), pod_anti_affinity=anti)
+        for i in range(20)
+    ]
+    return encode(Cluster(nodes=nodes), pods)
+
+
+def _default_plugins_136():
+    """The 136-node default-plugins trace of
+    tests/test_default_plugins_reference.py: hostname is a host-scale
+    topology at the program's own threshold, bf16 planes."""
+    from test_default_plugins_reference import case
+
+    return case(136, 512, 5)[2:]
+
+
+# name -> (trace, the threshold that forces groups onto host planes or None)
+_HOST_ROW_TRACES = {
+    "zone-rack-forced": (lambda: _case(3), 4),
+    "singleton-partial-labels": (_partial_label_slots, 0),
+    "default-plugins-136": (_default_plugins_136, None),
+}
+
+
+def _perturbed(N, count):
+    """The base cluster and up to four perturbed copies of it."""
+    from kubernetes_simulator_tpu.sim.whatif import Perturbation, Scenario
+
+    return [
+        Scenario(),
+        Scenario([Perturbation("node_down", nodes=np.array([0]))]),
+        Scenario([Perturbation("scale_capacity", nodes=np.arange(0, N, 2),
+                               resource="cpu", factor=0.5)]),
+        Scenario([Perturbation("add_taint", nodes=np.array([N - 1]),
+                               key="whatif/injected", value="true",
+                               effect="NoSchedule")]),
+        Scenario([Perturbation("node_down", nodes=np.array([2, 3]))]),
+    ][:count]
+
+
+def _run_with_host_planes(ec, ep, mapping):
+    """(every pod's node, the final mc_host and anti_host planes, the static
+    facts) of a single replay or of a 4-scenario arrivals-only what-if."""
+    cfg = FrameworkConfig()
+    if mapping == "replay":
+        eng = JaxReplayEngine(ec, ep, cfg, engine="v3", wave_width=8,
+                              chunk_waves=4)
+        res = eng.replay()
+        return (res.assignments, res.state.match_count, res.state.anti_active,
+                eng.static3)
+    from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
+
+    scen = _perturbed(ec.num_nodes, 4)
+    eng = WhatIfEngine(ec, ep, scen, cfg, wave_width=8, chunk_waves=4,
+                       collect_assignments=True)
+    assert eng.engine == "v3"
+    last, chunk_fn = {}, eng._chunk_fn
+
+    def spy(*args):
+        out = chunk_fn(*args)
+        last["state"] = out[0]
+        return out
+
+    eng._chunk_fn = spy
+    res = eng.run()
+    st = last["state"]
+    return (res.assignments, np.asarray(st.mc_host, np.float32),
+            np.asarray(st.anti_host, np.float32), eng.static3)
+
+
+@pytest.mark.parametrize("mapping", ["replay", "whatif"])
+@pytest.mark.parametrize("trace", sorted(_HOST_ROW_TRACES))
+def test_v3_host_row_read_equals_the_one_hot_contraction(
+    trace, mapping, monkeypatch
+):
+    """Every pod's node and the final ``mc_host`` / ``anti_host`` planes of
+    the step that reads rows by index equal, bit for bit, those of the step
+    that contracts at wave start (the program's other form) and those of
+    the plain form."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    make, dmax = _HOST_ROW_TRACES[trace]
+    ec, ep = make()
+    if dmax is not None:
+        build = V3.V3Static.build
+        monkeypatch.setattr(
+            V3.V3Static, "build",
+            lambda ec, ep, spec, dmax_coarse=None, **kw: build(
+                ec, ep, spec, dmax, **kw),
+        )
+    monkeypatch.setattr(V3, "host_row_reads", lambda *a, **k: "rows")
+    nodes, mc, anti, st = _run_with_host_planes(ec, ep, mapping)
+    assert st.has_host_rows and 0 < st.host_pos.sum() <= st.KT
+    assert (nodes >= 0).any() and (mc.any() or anti.any())
+    monkeypatch.setattr(V3, "host_row_reads", lambda *a, **k: "contraction")
+    others = [_run_with_host_planes(ec, ep, mapping)]
+    if mapping == "whatif":
+        monkeypatch.setattr(V3, "host_row_reads", lambda *a, **k: "rows")
+        monkeypatch.setattr(V3, "host_rows_at", _host_rows_by_contraction)
+        others.append(_run_with_host_planes(ec, ep, mapping))
+        others.append(_run_with_host_planes(ec, ep, "replay"))
+        np.testing.assert_array_equal(nodes[0], others.pop()[0])
+    for other in others:
+        np.testing.assert_array_equal(nodes, other[0])
+        np.testing.assert_array_equal(mc, other[1])
+        np.testing.assert_array_equal(anti, other[2])
+
+
+@pytest.mark.parametrize(
+    "scenario_axis, form", [(False, "contraction"), (True, "rows")],
+    ids=["replay", "scenario-axis"],
+)
+def test_host_row_reads_form_follows_how_the_step_is_built(scenario_axis, form):
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    assert V3.host_row_reads(scenario_axis) == form
+
+
+def test_whatif_host_row_reads_build_no_wave_wide_tensor_and_gather_nothing(
+    monkeypatch,
+):
+    """The arrivals-only what-if chunk program (``jit_per_scenario_src``) on
+    the 136-node default-plugins trace, 5 scenarios: no value shaped
+    [S, W, KT, N] or [W, KT, S, N] (the wave-start expansion of the host
+    rows, and its dots' outputs), and no gather whose indices carry the
+    scenario axis in the wave scan: every scenario reads the same row, so
+    ``vmap`` leaves a gather of ONE slice, [S, 1, N] at one start index,
+    which XLA lowers to a dynamic slice. (The plain form contracts a
+    slot's one-hot with the whole plane and slices nothing.)"""
+    import jax
+
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+    from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
+
+    ec, ep = _default_plugins_136()
+    S, W, N = 5, 8, ec.num_nodes
+
+    def traced(plain):
+        if plain:
+            monkeypatch.setattr(V3, "host_rows_at", _host_rows_by_contraction)
+        eng = WhatIfEngine(ec, ep, _perturbed(N, S), FrameworkConfig(),
+                           wave_width=W, chunk_waves=4,
+                           collect_assignments=True)
+        KT, seen, chunk_fn = eng.static3.KT, {}, eng._chunk_fn
+
+        class Traced(Exception):
+            pass
+
+        def spy(*args):
+            seen["jaxpr"] = jax.make_jaxpr(chunk_fn)(*args).jaxpr
+            raise Traced
+
+        eng._chunk_fn = spy
+        with pytest.raises(Traced):
+            eng.run()
+        wide, batched_gathers, slices = set(), 0, 0
+        planes = {(S, len(ids), N) for ids in (
+            eng.static3.mc_h_ids, eng.static3.anti_h_ids) if len(ids)}
+        (scan,) = [e for e in _eqns(seen["jaxpr"]) if e.primitive.name == "scan"]
+        for eqn in _eqns(scan.params["jaxpr"].jaxpr):
+            for v in eqn.outvars:
+                if tuple(v.aval.shape) in ((S, W, KT, N), (W, KT, S, N)):
+                    wide.add(tuple(v.aval.shape))
+            if eqn.primitive.name == "gather":
+                batched_gathers += S in eqn.invars[1].aval.shape
+                # a host-plane row read: one row of [S, H, N], one index
+                slices += (tuple(eqn.invars[0].aval.shape) in planes
+                           and tuple(eqn.outvars[0].aval.shape) == (S, 1, N))
+        return eng.static3, wide, batched_gathers, slices
+
+    st, wide, batched_gathers, slices = traced(plain=False)
+    assert st.has_host_rows
+    assert not wide and batched_gathers == 0
+    # one read a slot at each position that can name a host-scale group
+    assert slices == W * int(st.host_pos.sum()) == 16
+    _, wide, _, slices = traced(plain=True)
+    assert slices == 0 and not wide  # a slot's contraction: [S, H, N] whole
+
+
+@pytest.mark.parametrize(
+    "cell, nodes, host_pos",
+    [("k8s5k-whatif256", 136, (False, True, False, True)),
+     ("borg10k-whatif128", 64, (False,)), ("borg10k-replay1", 64, (False,))],
+)
+def test_host_read_positions_of_the_cells_traces(cell, nodes, host_pos):
+    """``count_planes()["host_read_positions"]``: B (hostname anti-affinity,
+    match counts) and MA (its symmetric check, holders) of the default-plugins
+    trace's four positions A, B, SP, MA; none on the Borg trace, whose one
+    position is a zone spread. The same for twelve deals of one pod multiset:
+    a fact of the pods, so every seed finds one program."""
+    from test_default_plugins_reference import bench
+
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+    from kubernetes_simulator_tpu.sim.jax_runtime import StepSpec
+
+    _, _, config, _ = bench.load_cell(cell)
+    gen = bench.load_part("generators", config["generator"])
+    got = set()
+    for seed in range(12):
+        ec, ep = gen.to_program(gen.generate(config, nodes, 256, seed), config)
+        st = V3.V3Static.build(
+            ec, ep, StepSpec.from_config(ec, FrameworkConfig(), ep))
+        got.add((V3.count_planes(st)["host_read_positions"],
+                 tuple(st.host_pos), st.has_host_rows))
+    assert got == {(sum(host_pos), host_pos, any(host_pos))}
