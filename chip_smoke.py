@@ -34,7 +34,9 @@ no result line (nothing here catches a phase's exception):
   serve           serve examples/config20_service.yaml < 4 defrag queries
   mesh            only with >1 device: what-if
                   examples/config5_multitenant_mesh.yaml over all devices
-                  vs the same file on one device
+                  vs the same file on one device; the meshed run's
+                  ``summary()["mesh"]`` counters are required, with no
+                  collective in its chunk program
 
 Stdout is two JSON lines, written only after every phase passed. The
 first is the report (versions, compile-cache directory, per phase: cold
@@ -546,8 +548,22 @@ def phase_mesh() -> dict:
     before = device_peaks()
     prev = os.environ.get("KSIM_DETERMINISTIC_JSONL")
     os.environ["KSIM_DETERMINISTIC_JSONL"] = "1"
+    # The CLI's rows carry no telemetry: the meshed run's own summary is
+    # taken as the engine hands it back, at no further run.
+    from unittest import mock
+
+    from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
+
+    summaries, engine_run = [], WhatIfEngine.run
+
+    def run_and_keep(self):
+        res = engine_run(self)
+        summaries.append(res.fleet_telemetry.summary())
+        return res
+
     try:
-        rows_mesh = cli(["what-if", CONFIG5])
+        with mock.patch.object(WhatIfEngine, "run", run_and_keep):
+            rows_mesh = cli(["what-if", CONFIG5])
         after = device_peaks()
         with tempfile.TemporaryDirectory(prefix="ksim_smoke_") as tmp:
             with open(CONFIG5) as f:
@@ -572,6 +588,19 @@ def phase_mesh() -> dict:
             f"devices that held a shard: {held} (peak bytes {after})")
     require(strip(rows_mesh) == strip(rows_one),
             "mesh rows differ from the one-device run of the same file")
+    # What the meshed run says of itself (``summary()["mesh"]``): every
+    # device holds its share, the tables went to the devices, and neither
+    # the chunk program nor any hand-back holds a collective.
+    counters = summaries[0].get("mesh") or {}
+    say(f"mesh: {counters}")
+    require(counters.get("devices") == len(held)
+            and counters.get("scenarios_per_device") == S // len(held),
+            f"mesh counters: {counters} for {S} scenarios on {len(held)} devices")
+    require(counters.get("put_bytes", 0) > 0 and "fetch_bytes" in counters,
+            f"mesh counters: no bytes put on the devices: {counters}")
+    held_by = counters.get("collectives", {})
+    require(held_by.get("chunk") == 0 and not held_by.get("handback"),
+            f"mesh programs hold collectives: {held_by}")
     return {
         "size": f"{S} scenarios x {N} nodes x {P} pods",
         "engine": agg["engine"],
@@ -579,6 +608,7 @@ def phase_mesh() -> dict:
         "peak_bytes_per_device": after,
         "placements": agg["total_placed"],
         "rows_equal_one_device": True,
+        "mesh": counters,
     }
 
 

@@ -26,6 +26,14 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 SCENARIO_AXIS = "scenarios"
 
+# Optimized-HLO op names of every XLA cross-device primitive (the start and
+# done variants share these prefixes); point-to-point would serialize the
+# scenario mesh just as a reduction would.
+COLLECTIVE_OPS = (
+    "all-reduce", "all-gather", "all-to-all", "collective-permute",
+    "collective-broadcast", "reduce-scatter", "partition-id", "send", "recv",
+)
+
 
 def init_distributed(
     coordinator_address: Optional[str] = None,
@@ -122,6 +130,27 @@ def replicate_tree(mesh: Mesh, tree):
     if spans_processes(mesh):
         return jax.tree.map(lambda a: _global_put(a, sh), tree)
     return jax.tree.map(lambda a: jax.device_put(a, sh), tree)
+
+
+def tree_bytes(tree) -> int:
+    """Bytes of every leaf of ``tree``, from shapes: touches no buffer."""
+    return sum(
+        int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+        for a in jax.tree_util.tree_leaves(tree)
+    )
+
+
+def collective_lines(hlo_text: str) -> list:
+    """The instructions of a compiled program's HLO text that cross
+    devices (:data:`COLLECTIVE_OPS`). The scenario axis is embarrassingly
+    parallel: the chunk, release and hand-back programs are expected to
+    hold none (tests/test_mesh_hlo.py; ``summary()["mesh"]``)."""
+    return [
+        ln.strip()
+        for ln in hlo_text.splitlines()
+        if any(f" {op}" in ln or ln.lstrip().startswith(op)
+               for op in COLLECTIVE_OPS)
+    ]
 
 
 def fit_population(population: int, per_candidate: int, mesh: Optional[Mesh]) -> int:

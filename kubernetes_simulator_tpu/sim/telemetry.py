@@ -225,18 +225,25 @@ class ReplayTelemetry:
     # the largest number of rank rounds one block of a release list needed
     # (1: no two releases of a block ever hit one node; ops.release_planes);
     # where placements come back from the device in one copy, its bytes (0
-    # when they were not asked for).
+    # when they were not asked for). Under a device mesh, ``mesh``: devices,
+    # scenarios a device, bytes put on the devices and fetched from them in
+    # the batch and the host seconds of those calls (nested in the ``stage``
+    # and ``handback`` phases, so not phases themselves: the phases tile
+    # the call), and the cross-device instructions counted once in the
+    # compiled programs (``chunk`` and ``handback``: 0 expected; ``gather``:
+    # the one all-gather that brings the placements to one device).
     count_planes: Optional[Dict[str, int]] = None
     scenarios: Optional[int] = None
     release_buckets: Optional[List[int]] = None
     release_rounds: Optional[int] = None
     handback_bytes: Optional[int] = None
+    mesh: Optional[Dict[str, object]] = None
 
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
         for key in ("chunk_waves", "inwave_corrections", "select_form",
                     "count_planes", "scenarios", "release_buckets",
-                    "release_rounds", "handback_bytes"):
+                    "release_rounds", "handback_bytes", "mesh"):
             if getattr(self, key) is not None:
                 out[key] = getattr(self, key)
         if self.latency is not None:
@@ -384,6 +391,19 @@ class ReplayTelemetry:
                   if p.release_rounds is not None]
         if rounds:
             tel.release_rounds = max(rounds)
+        meshes = [p.mesh for _, p in keep if p.mesh is not None]
+        if meshes:
+            # Each process's local mesh: devices, bytes, seconds and
+            # collectives add up; the scenarios a device are the largest.
+            tel.mesh = {
+                k: (max(m[k] for m in meshes) if k == "scenarios_per_device"
+                    else sum(m[k] for m in meshes))
+                for k in meshes[0] if k != "collectives"
+            }
+            tel.mesh["collectives"] = {
+                name: sum(m["collectives"].get(name, 0) for m in meshes)
+                for m0 in meshes for name in m0["collectives"]
+            }
         return tel
 
 

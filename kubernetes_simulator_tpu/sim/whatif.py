@@ -35,14 +35,18 @@ from ..ops.release_planes import bf16_parts, release_planes
 from ..parallel import dcn
 from ..parallel.mesh import (
     SCENARIO_AXIS,
+    collective_lines,
     make_mesh,
     replicate_tree,
     replicated,
     scenario_sharding,
     shard_scenario_tree,
     spans_processes,
+    tree_bytes,
 )
+from ..utils.profiling import annotate as _annotate
 from ..utils.profiling import register_call as _register_call
+from ..utils.profiling import shape_structs as _shape_structs
 from ..utils.profiling import stage
 from .jax_runtime import StepSpec, make_wave_step
 from .waves import pack_waves
@@ -1154,6 +1158,18 @@ class WhatIfEngine:
         self._run_jits: Dict[str, Callable] = {}
         self._rel_core: Optional[Callable] = None
         self._dev_rel_stage: Optional[dict] = None
+        # Under a mesh: the scenario tables as put on the devices (static
+        # per scenario batch: sharded once, kept); without a fork checkpoint
+        # the one initial state and the chunks' wave indices too (static
+        # per engine: replicated once, kept); the transfer counters of the
+        # batch in flight; and the collectives counted in the compiled
+        # chunk, hand-back and gather programs (read once per engine).
+        self._dc_mesh = None
+        self._state_one_mesh = None
+        self._idx_chunks_mesh: Optional[list] = None
+        self._mesh_batch: Optional[Dict[str, float]] = None
+        self._mesh_collectives: Optional[Dict[str, int]] = None
+        self._mesh_programs: Optional[Dict[str, tuple]] = None
         self._chunk_fn = self._build_chunk_fn()
         # Device-resident slot sources (one upload per engine): the chunk
         # loop then gathers rows on device — see ops.tpu.SlotSource.
@@ -1310,6 +1326,7 @@ class WhatIfEngine:
                 "the engine for this batch"
             )
         self.sset = sset
+        self._dc_mesh = None
         self._timelines = timelines
 
     def _build_chunk_fn(self):
@@ -1949,7 +1966,32 @@ class WhatIfEngine:
             fn = self._run_jits[name] = build()
         return fn
 
+    def _mesh_put(self, tree, replicate: bool = False):
+        """``tree`` put on the mesh's devices, its leading axis sharded over
+        the scenario axis or (``replicate``) whole on each: the ``mesh_put``
+        span when profiling is armed, and its bytes (as they land on the
+        devices) and host seconds in the counters of the batch in flight
+        (``summary()["mesh"]``). The seconds are those of the put calls,
+        which return before a copy is done."""
+        with _annotate("mesh_put"):
+            t = time.perf_counter()
+            out = (replicate_tree if replicate else shard_scenario_tree)(
+                self.mesh, tree
+            )
+            if self._mesh_batch is not None:
+                n = tree_bytes(tree)
+                if replicate:
+                    n *= int(self.mesh.devices.size)
+                self._mesh_batch["put_bytes"] += n
+                self._mesh_batch["put_s"] += time.perf_counter() - t
+        return out
+
     def _init_states(self) -> T.DevState:
+        if self._state_one_mesh is not None:
+            # Meshed, no fork: the one initial state is static and already
+            # lies whole on every device; a batch only broadcasts it.
+            self._fork_waves_done, self._fork_choices = 0, None
+            return self._run_jits["states"](self._state_one_mesh)
         self._load_fork_or_init()  # sets fork bookkeeping
         if self.fork_checkpoint:
             ck = self._fork_ck
@@ -1968,13 +2010,24 @@ class WhatIfEngine:
                 self.ec, self.static3, ep=self.pods,
             )
             # ONE jitted broadcast dispatch instead of a jnp.repeat
-            # round-trip per leaf.
+            # round-trip per leaf. Under a mesh the one state is replicated
+            # and every device broadcasts its own scenarios' share: the
+            # [S, ...] stack is born sharded and never lies whole on one
+            # device to be dealt out again.
             S = self.S
-            return self._jit_once("states", lambda: jax.jit(
-                lambda s: jax.tree.map(
-                    lambda a: jnp.broadcast_to(a[None], (S,) + a.shape), s
-                )
-            ))(one)
+            _bc = lambda s: jax.tree.map(
+                lambda a: jnp.broadcast_to(a[None], (S,) + a.shape), s
+            )
+            if self.mesh is not None:
+                one = self._mesh_put(one, replicate=True)
+            states_fn = self._jit_once("states", lambda: (
+                jax.jit(_bc, out_shardings=scenario_sharding(self.mesh))
+                if self.mesh is not None
+                else jax.jit(_bc)
+            ))
+            if self.mesh is not None and not self.fork_checkpoint:
+                self._state_one_mesh = one
+            return states_fn(one)
         G, D = host.match_count.shape[0], self.D
         # Domain dim may have grown (label perturbations) → pad.
         mc = np.zeros((G, D), np.float32)
@@ -2266,9 +2319,14 @@ class WhatIfEngine:
             x = self._replicate_fn(x)
         return np.asarray(x)
 
-    def _handback(self, wave_order, pos) -> Tuple[np.ndarray, int]:
-        """(assignments [S, P], bytes copied): every task's node, task by
-        task. ``wave_order`` holds the placements in wave order on the
+    def _handback(
+        self, wave_order, pos, count: bool = False
+    ) -> Tuple[np.ndarray, int, Optional[np.ndarray]]:
+        """(assignments [S, P], bytes copied, placed [S] or None): every
+        task's node, task by task, and with ``count`` how many of each
+        scenario's tasks have one, counted on the device (on the host the
+        compare writes a [S, P] temporary: 21 ms at 1,024 x 10,000, PERF.md
+        §6, PR 33). ``wave_order`` holds the placements in wave order on the
         device: the device-release path's ``vassign`` buffer (the pre-bound
         tasks in its tail), or, where nothing is released on the device (an
         arrivals-only batch, the host fold path), the list of every chunk's
@@ -2279,7 +2337,17 @@ class WhatIfEngine:
         copy. On a v5e at 128 x 131,072: 0.03 s, against 0.29 s for the
         copy first and ``np.take`` on the host (PERF.md §6, PR 27); at
         256 x 50,000 from ten chunks 0.027 s against 0.11 s for a fetch a
-        chunk and a host scatter (PR 31)."""
+        chunk and a host scatter (PR 31).
+
+        Under a mesh the result is sharded on the scenario axis. Its shards
+        come to the host in 2.4 ms, but stringing them together there costs
+        72 ms at 1,024 x 10,000 on a v5e host (the fresh 41 MB array is
+        page-faulted in; four threads: 29 ms), against 4.5 ms for the same
+        bytes fetched whole from one device, which the host takes as they
+        land (my chip run, PR 33). So one more program, ``jit_whatif_gather``,
+        replicates the placements over the mesh (one all-gather over ICI,
+        the one collective of a batch, after the chunk and hand-back
+        programs, which hold none) and the host fetches one device's copy."""
         def build():
             pos_d = jnp.asarray(pos())
 
@@ -2292,8 +2360,73 @@ class WhatIfEngine:
 
             return jax.jit(whatif_handback)
 
-        out = self._fetch(self._jit_once("handback", build)(wave_order))
-        return out, int(out.nbytes)
+        fn = self._jit_once("handback", build)
+        if self._mesh_programs is not None:
+            self._mesh_programs["handback"] = (
+                fn, (_shape_structs(wave_order),)
+            )
+        placed = fn(wave_order)
+        counts = None
+        if count:
+            counts = self._jit_once("handback_placed", lambda: jax.jit(
+                lambda a: (a >= 0).sum(axis=1, dtype=jnp.int32)
+            ))(placed)
+        if self.mesh is None:
+            out = self._fetch(placed)
+        else:
+            def build_gather():
+                def whatif_gather(a):
+                    return a
+
+                return jax.jit(
+                    whatif_gather, out_shardings=replicated(self.mesh)
+                )
+
+            with _annotate("mesh_fetch"):
+                t = time.perf_counter()
+                gather = self._jit_once("gather", build_gather)
+                if self._mesh_programs is not None:
+                    self._mesh_programs["gather"] = (
+                        gather, (_shape_structs(placed),)
+                    )
+                out = self._fetch(gather(placed))
+                self._mesh_batch["fetch_bytes"] += int(out.nbytes)
+                self._mesh_batch["fetch_s"] += time.perf_counter() - t
+        if counts is not None:
+            counts = self._fetch(counts).astype(np.int32)
+        return out, int(out.nbytes), counts
+
+    def _mesh_summary(self) -> dict:
+        """``summary()["mesh"]`` of the batch that just ran: devices,
+        scenarios a device, bytes put on the devices and fetched from them
+        (and the host seconds of those calls, nested in ``stage`` and
+        ``handback``), and the cross-device instructions in the compiled
+        programs: 0 in ``chunk`` and ``handback``, the scenario axis is
+        embarrassingly parallel; ``gather``, which brings the placements
+        to one device for the fetch (``_handback``), is the batch's one
+        all-gather. The programs are read once per engine, at
+        the end of its first meshed run: each is lowered again on the
+        shapes it was called with (the process already holds its
+        executable) and its optimized HLO is searched."""
+        if self._mesh_collectives is None:
+            self._mesh_collectives = {
+                name: len(collective_lines(
+                    fn.lower(*structs).compile().as_text()
+                ))
+                for name, (fn, structs) in (self._mesh_programs or {}).items()
+            }
+            self._mesh_programs = None
+        ndev = int(self.mesh.devices.size)
+        b = self._mesh_batch
+        return {
+            "devices": ndev,
+            "scenarios_per_device": int(self.S) // ndev,
+            "put_bytes": int(b["put_bytes"]),
+            "fetch_bytes": int(b["fetch_bytes"]),
+            "put_s": round(b["put_s"], 6),
+            "fetch_s": round(b["fetch_s"], 6),
+            "collectives": dict(self._mesh_collectives),
+        }
 
     def _chunks_pos(self, idx: np.ndarray) -> np.ndarray:
         """[P] each task's place in the chunks' wave order; a task in no
@@ -2797,6 +2930,13 @@ class WhatIfEngine:
         _t_stage = time.perf_counter()
         _stage_ann = _pann("stage")
         _stage_ann.__enter__()
+        if self.mesh is not None:
+            self._mesh_batch = {
+                "put_bytes": 0, "put_s": 0.0, "fetch_bytes": 0, "fetch_s": 0.0,
+            }
+            # First meshed run of the engine: remember the chunk and
+            # hand-back programs as called, to count their collectives.
+            self._mesh_programs = {} if self._mesh_collectives is None else None
         states = self._init_states()  # sets fork bookkeeping first
         idx = self.waves.idx
         if self._fork_waves_done:
@@ -2809,8 +2949,15 @@ class WhatIfEngine:
             idx = np.concatenate([idx, np.full((pad_to - idx.shape[0], idx.shape[1]), PAD, np.int32)])
         dc = self.sset.dc
         if self.mesh is not None:
-            dc = shard_scenario_tree(self.mesh, dc)
-            states = shard_scenario_tree(self.mesh, states)
+            # The scenario tables are static per scenario batch: sharded
+            # over the devices at the engine's first run and kept (each
+            # run() used to deal them out again from device 0). The v3
+            # state stack is born sharded (_init_states).
+            if self._dc_mesh is None:
+                self._dc_mesh = self._mesh_put(dc)
+            dc = self._dc_mesh
+            if self.engine != "v3":
+                states = self._mesh_put(states)
         comp_on = (
             self.completions_on
             and not self._completions_dev
@@ -2838,9 +2985,7 @@ class WhatIfEngine:
             # running max per scenario beside the state, fetched at gather.
             rounds_d = jnp.zeros(S, jnp.int32)
             if self.mesh is not None:
-                rounds_d = jax.device_put(
-                    rounds_d, scenario_sharding(self.mesh)
-                )
+                rounds_d = self._mesh_put(rounds_d)
             if self.retry_buffer:
                 RB = self.retry_buffer
                 mgt_d, durt_d = stg["mgt"], stg["durt"]
@@ -2960,11 +3105,22 @@ class WhatIfEngine:
             if self.mesh is not None:
                 pol_d = shard_scenario_tree(self.mesh, pol_d)
         srcs = self._slot_srcs
-        idx_chunks = (
-            [jnp.asarray(idx[c0 : c0 + C]) for c0 in range(0, idx.shape[0], C)]
-            if srcs is not None
-            else None
-        )
+        idx_chunks = None
+        if srcs is not None and self._idx_chunks_mesh is not None:
+            idx_chunks = self._idx_chunks_mesh
+        elif srcs is not None:
+            idx_chunks = [
+                jnp.asarray(idx[c0 : c0 + C])
+                for c0 in range(0, idx.shape[0], C)
+            ]
+            if self.mesh is not None:
+                # Scenario-shared like the sources: whole on every device
+                # before the loop, not dealt out from device 0 by each
+                # dispatch; and, with no fork to cut the wave list, the
+                # same in every batch: kept.
+                idx_chunks = self._mesh_put(idx_chunks, replicate=True)
+                if not self.fork_checkpoint:
+                    self._idx_chunks_mesh = idx_chunks
         pre_comp = comp_on and self.preemption
         kbops = None
         if self.kube:
@@ -3357,6 +3513,10 @@ class WhatIfEngine:
         registered: set = set()
 
         def _reg(fn, args):
+            if self._mesh_programs is not None:
+                self._mesh_programs.setdefault(
+                    "chunk", (fn, _shape_structs(args))
+                )
             if _prof and fn not in registered:
                 registered.add(fn)
                 _register_call(fn, args)
@@ -3821,7 +3981,7 @@ class WhatIfEngine:
             # [S] floats instead of the full [S, R, N] used plane D2H.
             util = self._fetch(
                 self._jit_once("util", lambda: jax.jit(_util))(
-                    states.used, self.sset.dc.allocatable
+                    states.used, dc.allocatable
                 )
             )
         dropped = kube_dropped
@@ -3839,15 +3999,14 @@ class WhatIfEngine:
             # The device-release path's placements: the wave-order buffer
             # comes to the host once, after the last chunk.
             with run_phases.tick("handback"), _pann("handback"):
-                assignments, handback_bytes = self._handback(
+                assignments, handback_bytes, _ = self._handback(
                     vassign_d, lambda: self._dev_rel_stage["pos"])
         elif chunk_handback:
             # The chunks' choices, put into task order on the device and
             # copied once.
             with run_phases.tick("handback"), _pann("handback"):
-                assignments, handback_bytes = self._handback(
-                    outs, lambda: self._chunks_pos(idx))
-                placed = (assignments >= 0).sum(axis=1).astype(np.int32)
+                assignments, handback_bytes, placed = self._handback(
+                    outs, lambda: self._chunks_pos(idx), count=True)
                 prebound = self.pods.bound_node >= 0
                 if prebound.any() or self._fork_choices is not None:
                     assignments = np.array(assignments)  # the copy is read-only
@@ -3888,6 +4047,8 @@ class WhatIfEngine:
                 fleet_local.release_rounds = release_rounds
             if dev_rel or self.collect_assignments:
                 fleet_local.handback_bytes = handback_bytes
+            if self.mesh is not None:
+                fleet_local.mesh = self._mesh_summary()
             # DCN checkpoint-publication attribution (round 16): the
             # cumulative encode+push wall, publication count and encoded
             # MiB ride the fleet phase map (merged under this pid's

@@ -61,6 +61,9 @@ class SyntheticWorkloadSpec:
     arrival_rate: float = 100.0
     duration_mean: Optional[float] = None
     num_apps: int = 20
+    # [resource, count, fraction]: that fraction of the pods asks for 1, 2
+    # or ``count`` of the extended resource (``make_workload``).
+    extended_resource: Optional[list] = None
 
 
 @dataclass
@@ -410,6 +413,7 @@ class SimConfig:
                 arrival_rate=float(syn.get("arrivalRate", 100.0)),
                 duration_mean=syn.get("durationMean"),
                 num_apps=int(syn.get("numApps", 20)),
+                extended_resource=syn.get("extendedResource"),
             )
         prof = d.get("profile", {})
         plugins = prof.get("plugins")
@@ -624,6 +628,11 @@ def build_case(cfg: SimConfig):
         num_apps=wl.num_apps,
         gang_fraction=wl.gang_fraction,
         gang_size=wl.gang_size,
+        extended_resource=(
+            (str(wl.extended_resource[0]), int(wl.extended_resource[1]),
+             float(wl.extended_resource[2]))
+            if wl.extended_resource else None
+        ),
     )
     from ..plugins.builtin import inject_default_spread
 

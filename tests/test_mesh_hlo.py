@@ -64,6 +64,12 @@ def test_detector_catches_real_collective():
     )
     txt = f.lower(jax.ShapeDtypeStruct((8, 16), jnp.float32)).compile().as_text()
     assert "all-reduce" in txt
+    # the program's own counter (``summary()["mesh"]["collectives"]``) sees
+    # it too, and every primitive this file looks for
+    from kubernetes_simulator_tpu.parallel import mesh as M
+
+    assert any("all-reduce" in ln for ln in M.collective_lines(txt))
+    assert set(COLLECTIVE_OPS) == set(M.COLLECTIVE_OPS)
 
 
 def _mesh_engine(S: int, with_durations: bool) -> WhatIfEngine:
@@ -165,3 +171,30 @@ def test_mesh_chunk_program_no_collectives_with_completions(S):
     assert rel is not None, "expected at least one static release bucket"
     rel_fn = eng._release_fn(rel[2].shape[0])
     _assert_no_collectives(rel_fn.lower(*rel).compile().as_text())
+
+
+def test_mesh_handback_program_has_no_collectives():
+    """The arrivals-only hand-back under a mesh (``collect_assignments`` on a
+    trace with no durations): the chunks' choices, sharded on the scenario
+    axis, strung together and put into task order by one static map. It is
+    jitted plainly, so the partitioner decides: it has to keep every
+    scenario's row on its device. The engine's own count, read once from the
+    compiled programs, agrees."""
+    cluster = make_cluster(12, seed=21, taint_fraction=0.2)
+    pods, _ = make_workload(48, seed=21, with_tolerations=True,
+                            gang_fraction=0.2, gang_size=4,
+                            extended_resource=("google.com/tpu", 8, 0.2))
+    ec, ep = encode(cluster, pods)
+    scen = uniform_scenarios(ec, 8, seed=21, p_capacity=0.5, p_taint=0.3)
+    eng = WhatIfEngine(ec, ep, scen, FrameworkConfig(), mesh=make_mesh(),
+                       chunk_waves=4, collect_assignments=True,
+                       telemetry="summary")
+    res = eng.run()
+    assert res.fleet_telemetry.summary()["mesh"]["collectives"] == {
+        "chunk": 0, "handback": 0, "gather": 1}
+    args, _ = _chunk_args(eng, with_durations=False)
+    _, out = eng._chunk_fn(*args)
+    n_chunks = eng.waves.idx.shape[0] // 4 + bool(eng.waves.idx.shape[0] % 4)
+    txt = eng._run_jits["handback"].lower([out] * n_chunks).compile().as_text()
+    _assert_no_collectives(txt)
+    assert "s32[1,48]" in txt  # one scenario's row a device, in task order
