@@ -414,6 +414,10 @@ def phase_parity() -> dict:
     require(planes["host_read_positions"] > 0,
             "default-plugins what-if: no position of the term axis reads a "
             f"host row by its index ({planes})")
+    require(planes["host_commit"] == {"rows": planes["host_rows"],
+                                      "elementwise": 0, "dot": 0},
+            "default-plugins what-if: its host rows are not all committed "
+            f"row by row, with no dot ({planes})")
     for s in range(len(scen)):
         require(int((r_plug.assignments[s] >= 0).sum()) == int(r_plug.placed[s]),
                 f"default-plugins what-if scenario {s}: placed differs from "

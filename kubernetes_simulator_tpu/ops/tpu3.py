@@ -186,6 +186,13 @@ class V3Static:
     # the dominant host-read/commit traffic. pref stays f32 (fractional).
     mc_h_bf16: bool = False
     anti_h_bf16: bool = False
+    # No pod names more than ONE row of the plane (matches more than one
+    # host-scale group a term reads; holds anti-affinity terms of more than
+    # one): its bind then touches one row, which the step mapped over a
+    # scenario axis commits alone (host_commit_form "rows"). Like
+    # ``host_pos`` a fact of the pod multiset.
+    mc_h_one_row: bool = False
+    anti_h_one_row: bool = False
 
     @property
     def KT(self) -> int:
@@ -343,6 +350,17 @@ class V3Static:
             and single_g[anti_h_ids].all()
             and max_pods * max(B, 1) <= 256
         )
+        # How many rows of its plane a pod's bind adds to, at most: the
+        # host-scale groups it matches (mc_host), its own anti-affinity terms
+        # on host-scale groups (anti_host, `_pod_group_vectors`; two terms on
+        # one group count twice, which only forgoes the row form).
+        mc_named = np.count_nonzero(pmg[:, mc_h_ids[mc_h_ids < Pg]], axis=1)
+        anti_h = np.zeros(G, bool)
+        anti_h[anti_h_ids] = True
+        anti_terms = ep.anti_req[:, :B]
+        anti_named = (
+            (anti_terms >= 0) & anti_h[np.clip(anti_terms, 0, G - 1)]
+        ).sum(axis=1)
         term_g = np.concatenate(
             [ep.aff_req[:, :A], ep.anti_req[:, :B], ep.spread_g[:, :SP],
              ep.pref_aff[:, :PA], anti_midx, pref_midx],
@@ -368,6 +386,8 @@ class V3Static:
             na_class=na_class, na_rep=na_rep,
             preemption=preemption, Tt=Tt, pod_tier=pod_tier,
             mc_h_bf16=mc_h_bf16, anti_h_bf16=anti_h_bf16,
+            mc_h_one_row=bool(mc_named.max(initial=0) <= 1),
+            anti_h_one_row=bool(anti_named.max(initial=0) <= 1),
             A=A, B=B, SP=SP, PA=PA,
             MA=anti_midx.shape[1], MP=pref_midx.shape[1],
             maintain_mc=bool(mc_ref.any()),
@@ -1766,11 +1786,14 @@ def make_wave_step3(
             wv_used = commit_used.astype(jnp.float32)  # [W]
             # One-hots rebuilt from chosen-node indices, bf16 operands: exact
             # (0/1 values), half the einsum traffic of stacked f32 planes. Only
-            # the host-plane / tier commits still consume them — the `used`
-            # update itself is an unrolled elementwise add since round 3 (the
-            # [W, N]×[W, R] dot emitted layout copies around the carry that
-            # cost more than the dot; same f32 sum of the same multiset).
-            need_oh_all = st.preemption or st.has_host_rows
+            # the tier commits and a `pref_host` plane still consume them — the
+            # `used` update itself is an unrolled elementwise add since round 3
+            # (the [W, N]×[W, R] dot emitted layout copies around the carry
+            # that cost more than the dot; same f32 sum of the same multiset),
+            # and so are the whole-number host planes (host_commit_form).
+            need_oh_all = (
+                st.preemption or host_commit_form(st, scenario_axis)["dot"] > 0
+            )
             if need_oh_all:
                 oh_all = (
                     (iota_n[None, :] == choice[:, None]) & (choice[:, None] >= 0)
@@ -1881,7 +1904,7 @@ def make_wave_step3(
                         "w,wg->g", wv, pre.pmg_f * has_dom, precision=_HI
                     )
 
-            def host_commit(plane, vec, ids):
+            def host_commit(plane, vec, ids, kind):
                 vh = vec[:, jnp.asarray(ids)]  # [W, H]
                 if st.single_g[ids].all():
                     # Singleton domains (hostname): the bound node IS the domain
@@ -1891,13 +1914,29 @@ def make_wave_step3(
                     has_dom_h = (
                         jnp.stack(dom_ats)[:, jnp.asarray(ids)] >= 0
                     ).astype(jnp.float32)  # [W, H]
-                    delta = jnp.einsum(
-                        "w,wh,wn->hn", wv, vh * has_dom_h, oh_all,
-                        precision=_HI, preferred_element_type=jnp.float32,
-                    )
-                    # Cast back to the carry dtype: bf16 planes hold small
-                    # integers, exact through the add.
-                    return (plane.astype(jnp.float32) + delta).astype(plane.dtype)
+                    form = host_commit_plane_form(st, kind, scenario_axis)
+                    if form == "dot":
+                        # `pref_host` (fractional weights): the sum depends on
+                        # the order of its additions, so it keeps the dot.
+                        delta = jnp.einsum(
+                            "w,wh,wn->hn", wv, vh * has_dom_h, oh_all,
+                            precision=_HI, preferred_element_type=jnp.float32,
+                        )
+                        return (plane.astype(jnp.float32) + delta).astype(plane.dtype)
+                    # Whole-number planes (match counts, anti-affinity
+                    # holders): the wave's binds added at the chosen nodes in
+                    # slot order, the `used` update's form above and for its
+                    # reason: the [W, H]x[W, N] dot wrote the plane in another
+                    # layout than the row reads want and XLA turned each plane
+                    # before it and back after it, every wave (42% of a
+                    # k8s5k-whatif256 wave, PERF.md §6 PR 34). The dot's values
+                    # to the bit: every summand is a whole number and an f32
+                    # sum of whole numbers under 2^24 is exact in any order. A
+                    # slot with choice < 0 matches no node (iota_n >= 0).
+                    coef = wv[:, None] * vh * has_dom_h  # [W, H] tiny
+                    if form == "rows":
+                        return host_named_row_add(plane, coef, choice, vh != 0)
+                    return host_rows_add(plane, coef, choice)
                 # General path: credit every node in the bound node's domain.
                 gdom_h = sh.gdom_f[jnp.asarray(ids)]  # [H, N] (static row select)
                 dom_at_h = jnp.stack(dom_ats)[:, jnp.asarray(ids)]  # [W, H]
@@ -1909,11 +1948,15 @@ def make_wave_step3(
                 return plane
 
             if len(st.mc_h_ids):
-                mc_host = host_commit(carry.mc_host, pre.pmg_f, st.mc_h_ids)
+                mc_host = host_commit(carry.mc_host, pre.pmg_f, st.mc_h_ids, "mc")
             if len(st.anti_h_ids):
-                anti_host = host_commit(carry.anti_host, pre.anti_g, st.anti_h_ids)
+                anti_host = host_commit(
+                    carry.anti_host, pre.anti_g, st.anti_h_ids, "anti"
+                )
             if len(st.pref_h_ids):
-                pref_host = host_commit(carry.pref_host, pre.pref_g, st.pref_h_ids)
+                pref_host = host_commit(
+                    carry.pref_host, pre.pref_g, st.pref_h_ids, "pref"
+                )
             new_state = DevState3(
                 used=used, mc_dom=mc_dom, anti_dom=anti_dom, pref_dom=pref_dom,
                 mc_host=mc_host, anti_host=anti_host, pref_host=pref_host,
@@ -2045,7 +2088,64 @@ def select_form(
     return "zone_packed" if ok else "two_pass"
 
 
-def count_planes(st: V3Static) -> dict:
+def host_commit_plane_form(
+    st: V3Static, kind: str, scenario_axis: bool = False
+) -> str:
+    """The form (:func:`host_commit_form`) in which the rows of ONE host
+    plane, ``"mc"``, ``"anti"`` or ``"pref"``, are committed."""
+    ids, whole, one_row = {
+        "mc": (st.mc_h_ids, True, st.mc_h_one_row),
+        "anti": (st.anti_h_ids, True, st.anti_h_one_row),
+        "pref": (st.pref_h_ids, False, False),
+    }[kind]
+    if not st.single_g[ids].all():
+        return "elementwise"
+    if not whole:
+        return "dot"
+    return "rows" if scenario_axis and one_row else "elementwise"
+
+
+def host_commit_form(st: V3Static, scenario_axis: bool = False) -> dict:
+    """How the wave-end commit of a step built from ``st`` adds a wave's
+    binds to its host-scale count rows: the rows by form, static per
+    compiled program and like :func:`host_row_reads` a fact of how it is
+    built, not a switch. Every form gives the same values to the bit.
+
+    ``"rows"``: each slot adds its bind to the ONE row its pod names, read,
+    added to at the chosen node and written back in place by the row's index
+    (:func:`host_named_row_add`). The step mapped over a scenario axis, on a
+    singleton-domain (hostname) plane of whole numbers (``mc_host``,
+    ``anti_host``) of which no pod names more than one row
+    (``V3Static.mc_h_one_row`` / ``anti_h_one_row``): the index comes from
+    the pods' labels and terms, which every scenario shares, so 8 slots
+    rewrite 16 rows of ``[S, N]`` where the form below rewrites both planes
+    whole: ``k8s5k-whatif256`` 9.81 -> 8.21 s a batch on a v5e (PERF.md §6
+    PR 34). The single replay, at op latency, loses 24% to it (1.393 s a
+    replay against 1.134) and keeps the form below.
+
+    ``"elementwise"``: an unrolled add over the whole plane in slot order,
+    no matrix product: at the chosen node for the other singleton-domain
+    rows of whole numbers (the float32 sum of whole numbers is exact in any
+    order, so the values are the dot's), over the bound node's whole domain
+    for a row of a wider topology.
+
+    ``"dot"``: the ``[W, H] x [W, N]`` product with the wave's node
+    one-hots, kept by the singleton-domain rows of ``pref_host`` alone: its
+    fractional preference weights sum to another float32 in another order.
+    Until PR 34 the whole-number planes went through it too: it wrote the
+    plane in a layout of its own and XLA turned the carried plane before it
+    and back after it every wave, 0.82 ms of a 1.95 ms ``k8s5k-whatif256``
+    wave (12.22 -> 9.81 s a batch without it)."""
+    form = {"rows": 0, "elementwise": 0, "dot": 0}
+    for kind, ids in (
+        ("mc", st.mc_h_ids), ("anti", st.anti_h_ids), ("pref", st.pref_h_ids)
+    ):
+        if len(ids):
+            form[host_commit_plane_form(st, kind, scenario_axis)] += len(ids)
+    return form
+
+
+def count_planes(st: V3Static, scenario_axis: bool = False) -> dict:
     """The count planes a step built from these static facts carries,
     static per compiled program; a what-if batch reports it in its
     ``fleet_telemetry`` as ``summary()["count_planes"]``. ``domain_rows``:
@@ -2056,7 +2156,9 @@ def count_planes(st: V3Static) -> dict:
     the domain planes' width; ``spread_rows`` and ``term_rows``: ``SP`` and
     ``KT`` of the unified term axis; ``host_read_positions``: how many of
     the ``KT`` positions read a host row in every slot
-    (:func:`host_rows_at`; the others can never name a host-scale group)."""
+    (:func:`host_rows_at`; the others can never name a host-scale group);
+    ``host_commit``: host rows by the form of their wave-end commit
+    (:func:`host_commit_form`; ``scenario_axis`` as the step was built)."""
     return {
         "domain_rows": int((~st.is_host).sum()),
         "host_rows": len(st.mc_h_ids) + len(st.anti_h_ids) + len(st.pref_h_ids),
@@ -2064,6 +2166,7 @@ def count_planes(st: V3Static) -> dict:
         "spread_rows": int(st.SP),
         "term_rows": int(st.KT),
         "host_read_positions": int(st.host_pos.sum()),
+        "host_commit": host_commit_form(st, scenario_axis),
     }
 
 
@@ -2077,6 +2180,38 @@ def host_row_table(st: V3Static) -> np.ndarray:
         [st.g2mc_h] * (o4 - o0) + [st.g2anti_h] * (o5 - o4)
         + [st.g2pref_h] * (o6 - o5)
     ).astype(np.float32)
+
+
+def host_rows_add(plane: jax.Array, coef: jax.Array, choice: jax.Array) -> jax.Array:
+    """The ``[H, N]`` host plane with a wave's binds added: slot ``w`` adds
+    ``coef[w]`` (``[W, H]`` f32, whole numbers) to every row at its node
+    ``choice[w]`` (``[W]``; negative: no node). Elementwise in slot order on
+    the plane in float32, cast back to the plane's dtype: a bf16 plane holds
+    small whole numbers, exact through the add."""
+    iota_n = jnp.arange(plane.shape[1])
+    acc = plane.astype(jnp.float32)
+    for w in range(choice.shape[0]):
+        acc = acc + jnp.where(iota_n[None, :] == choice[w], coef[w][:, None], 0.0)
+    return acc.astype(plane.dtype)
+
+
+def host_named_row_add(
+    plane: jax.Array, coef: jax.Array, choice: jax.Array, named: jax.Array
+) -> jax.Array:
+    """:func:`host_rows_add` where slot ``w`` names at most ONE row of the
+    plane (``named`` ``[W, H]`` bool, from the pod's labels and terms alone):
+    that row takes ``coef[w]``'s entry at the node ``choice[w]``, by a
+    scatter-add at the row's index, and no other row is touched. A slot that
+    names none adds zero to row 0. Where the step is mapped over a scenario
+    axis the index is shared, and XLA reads the ``[S, 1, N]`` row, adds and
+    writes it back in place in ONE fusion a slot."""
+    iota_n = jnp.arange(plane.shape[1])
+    row = jnp.argmax(named, axis=1)  # [W]
+    for w in range(choice.shape[0]):
+        c = jax.lax.dynamic_index_in_dim(coef[w], row[w], 0, keepdims=False)
+        delta = jnp.where(iota_n == choice[w], c, 0.0).astype(plane.dtype)
+        plane = plane.at[row[w]].add(delta)
+    return plane
 
 
 def host_rows_at(st: V3Static, carry: DevState3, row_h_k: jax.Array) -> jax.Array:

@@ -220,7 +220,8 @@ class ReplayTelemetry:
     select_form: Optional[str] = None
     # What-if batches only: the count planes of the compiled problem
     # (ops.tpu3.count_planes: rows at domain scale and at host scale, the
-    # domain width, spread rows, term rows); scenarios evaluated; on the
+    # domain width, spread rows, term rows, and under ``host_commit`` the
+    # host rows by the form of their wave-end commit); scenarios evaluated; on the
     # device-release path the pow2 widths its release program ran with and
     # the largest number of rank rounds one block of a release list needed
     # (1: no two releases of a block ever hit one node; ops.release_planes);
@@ -232,7 +233,7 @@ class ReplayTelemetry:
     # the call), and the cross-device instructions counted once in the
     # compiled programs (``chunk`` and ``handback``: 0 expected; ``gather``:
     # the one all-gather that brings the placements to one device).
-    count_planes: Optional[Dict[str, int]] = None
+    count_planes: Optional[Dict[str, object]] = None
     scenarios: Optional[int] = None
     release_buckets: Optional[List[int]] = None
     release_rounds: Optional[int] = None

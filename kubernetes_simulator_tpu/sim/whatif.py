@@ -4041,7 +4041,9 @@ class WhatIfEngine:
                     traced_weights=self._policies is not None,
                     dyn_labels=self._dyn_dev is not None,
                 )
-                fleet_local.count_planes = V3.count_planes(self.static3)
+                fleet_local.count_planes = V3.count_planes(
+                    self.static3, scenario_axis=True
+                )
             if dev_rel:
                 fleet_local.release_buckets = sorted(rel_buckets)
                 fleet_local.release_rounds = release_rounds

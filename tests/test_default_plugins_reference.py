@@ -146,11 +146,14 @@ def test_the_summary_reports_the_count_planes_the_form_and_the_handback(batch):
     got = res.fleet_telemetry.summary()
     assert got["select_form"] == "two_pass"
     st = eng.static3
-    assert got["count_planes"] == V3.count_planes(st) == {
+    assert got["count_planes"] == V3.count_planes(st, scenario_axis=True) == {
         "domain_rows": int((~st.is_host).sum()),
         "host_rows": len(st.mc_h_ids) + len(st.anti_h_ids),
         "dcap": 8, "spread_rows": 1, "term_rows": 4,
-        "host_read_positions": 2}
+        "host_read_positions": 2,
+        "host_commit": {
+            "rows": len(st.mc_h_ids) + len(st.anti_h_ids), "elementwise": 0,
+            "dot": 0}}
     assert got["count_planes"]["host_rows"] > 0 and st.has_host_rows
     assert got["handback_bytes"] == 4 * 4 * ep.num_pods == res.assignments.nbytes
     assert got["scenarios"] == 4 and got["chunk_waves"] == 16

@@ -490,22 +490,37 @@ def _host_rows_by_contraction(st, carry, row_h_k):
     return jnp.stack(rows)
 
 
+_SLOT = "custom/slot"
+
+
+def _slot_nodes(cpu=4.0):
+    """12 nodes; every third lacks the singleton topology `custom/slot`."""
+    from kubernetes_simulator_tpu.models.core import Node
+
+    return [
+        Node(f"n{i}", capacity={"cpu": cpu, "memory": 8 * 2**30, "pods": 20},
+             labels=({_SLOT: f"s{i}"} if i % 3 != 0 else {}))
+        for i in range(12)
+    ]
+
+
+def _anti_on_slot(**selector):
+    from kubernetes_simulator_tpu.models.core import (
+        LabelSelector, PodAffinityTerm,
+    )
+
+    return PodAffinityTerm(LabelSelector.make(selector), _SLOT)
+
+
 def _partial_label_slots():
     """A singleton host topology (`custom/slot`) that every third node
     lacks, 20 pods with a required anti-affinity to their own app on it."""
     from kubernetes_simulator_tpu.models.core import (
-        Cluster, LabelSelector, Node, Pod, PodAffinitySpec, PodAffinityTerm,
+        Cluster, Pod, PodAffinitySpec,
     )
 
-    key = "custom/slot"
-    nodes = [
-        Node(f"n{i}", capacity={"cpu": 4.0, "memory": 8 * 2**30, "pods": 20},
-             labels=({key: f"s{i}"} if i % 3 != 0 else {}))
-        for i in range(12)
-    ]
-    anti = PodAffinitySpec(
-        required=(PodAffinityTerm(LabelSelector.make({"app": "a"}), key),)
-    )
+    nodes = _slot_nodes()
+    anti = PodAffinitySpec(required=(_anti_on_slot(app="a"),))
     pods = [
         Pod(f"p{i}", labels={"app": "a"}, requests={"cpu": 1.0},
             arrival_time=float(i), pod_anti_affinity=anti)
@@ -591,12 +606,7 @@ def test_v3_host_row_read_equals_the_one_hot_contraction(
     make, dmax = _HOST_ROW_TRACES[trace]
     ec, ep = make()
     if dmax is not None:
-        build = V3.V3Static.build
-        monkeypatch.setattr(
-            V3.V3Static, "build",
-            lambda ec, ep, spec, dmax_coarse=None, **kw: build(
-                ec, ep, spec, dmax, **kw),
-        )
+        _force_host_planes(monkeypatch, dmax)
     monkeypatch.setattr(V3, "host_row_reads", lambda *a, **k: "rows")
     nodes, mc, anti, st = _run_with_host_planes(ec, ep, mapping)
     assert st.has_host_rows and 0 < st.host_pos.sum() <= st.KT
@@ -712,3 +722,376 @@ def test_host_read_positions_of_the_cells_traces(cell, nodes, host_pos):
         got.add((V3.count_planes(st)["host_read_positions"],
                  tuple(st.host_pos), st.has_host_rows))
     assert got == {(sum(host_pos), host_pos, any(host_pos))}
+
+
+# --- the wave-end commit of the host-scale count rows (ops.tpu3) ----------
+# The whole-number planes (`mc_host`, `anti_host`) of a singleton-domain
+# topology take a wave's binds at the chosen nodes with no matrix product
+# (`host_commit_form`): the step mapped over a scenario axis row by row where
+# no pod names two rows of a plane (`host_named_row_add`, "rows"), every other
+# step over the whole plane (`host_rows_add`, "elementwise"). The parent's
+# form, the [W, H] x [W, N] dot with the wave's node one-hots, is kept HERE as
+# the plain form both are held to.
+
+
+def _host_rows_add_by_dot(plane, coef, choice):
+    import jax
+    import jax.numpy as jnp
+
+    oh_all = (
+        (jnp.arange(plane.shape[1])[None, :] == choice[:, None])
+        & (choice[:, None] >= 0)
+    ).astype(jnp.bfloat16)  # [W, N]
+    delta = jnp.einsum(
+        "wh,wn->hn", coef, oh_all, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    return (plane.astype(jnp.float32) + delta).astype(plane.dtype)
+
+
+def _commit_by_dot(monkeypatch, calls=None):
+    """Both no-dot forms of the program replaced by the plain form."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    def by_dot(plane, coef, choice, named=None):
+        if calls is not None:
+            calls.append(named is not None)
+        return _host_rows_add_by_dot(plane, coef, choice)
+
+    monkeypatch.setattr(V3, "host_rows_add", by_dot)
+    monkeypatch.setattr(V3, "host_named_row_add", by_dot)
+
+
+def _assert_the_dots_answers(monkeypatch, ec, ep, mapping, got, calls=None):
+    """(nodes, mc, anti) of the step as built equal those of the step that
+    commits through the plain form."""
+    _commit_by_dot(monkeypatch, calls)
+    other = _run_with_host_planes(ec, ep, mapping)
+    for ours, theirs in zip(got, other[:3]):
+        np.testing.assert_array_equal(ours, theirs)
+
+
+def _force_host_planes(monkeypatch, dmax):
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    build = V3.V3Static.build
+    monkeypatch.setattr(
+        V3.V3Static, "build",
+        lambda ec, ep, spec, dmax_coarse=None, **kw: build(ec, ep, spec, dmax, **kw),
+    )
+
+
+@pytest.mark.parametrize("mapping", ["replay", "whatif"])
+@pytest.mark.parametrize("trace", sorted(_HOST_ROW_TRACES))
+def test_v3_host_commit_without_a_dot_equals_the_dot(trace, mapping, monkeypatch):
+    """Every pod's node and the final ``mc_host`` / ``anti_host`` planes of
+    the step that adds a wave's binds with no matrix product (row by row
+    under the scenario axis, over the whole plane in the single replay)
+    equal, bit for bit, those of the step that commits them through the
+    parent's dot."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    make, dmax = _HOST_ROW_TRACES[trace]
+    ec, ep = make()
+    if dmax is not None:
+        _force_host_planes(monkeypatch, dmax)
+    nodes, mc, anti, st = _run_with_host_planes(ec, ep, mapping)
+    assert st.has_host_rows and (nodes >= 0).any() and (mc.any() or anti.any())
+    form = V3.host_commit_form(st, scenario_axis=mapping == "whatif")
+    assert form["dot"] == 0
+    assert sum(form.values()) == V3.count_planes(st)["host_rows"]
+    calls = []
+    _assert_the_dots_answers(monkeypatch, ec, ep, mapping, (nodes, mc, anti), calls)
+    # a plane of singleton domains goes through an add, row by row under
+    # the scenario axis alone; one whose groups credit a whole zone or rack
+    # keeps the general branch in both runs
+    assert bool(calls) == any(
+        len(ids) and st.single_g[ids].all()
+        for ids in (st.mc_h_ids, st.anti_h_ids))
+    assert set(calls) <= {mapping == "whatif"}
+    assert (form["rows"] > 0) == (mapping == "whatif" and bool(calls))
+
+
+def _gangs_on_partial_label_slots():
+    """The partial-label cluster of ``_partial_label_slots`` at 2 cpu a
+    node, and gangs of pods of 1 cpu with a required anti-affinity to their
+    own app on the singleton topology: a labelled node takes one pod, a
+    label-less one two, 16 places. Three gangs of 4 take 12; the gang of 6
+    finds 4 places and not 6 and is rolled back in its wave; a gang of 2
+    after it takes two of the places it gave back."""
+    from kubernetes_simulator_tpu.models.core import (
+        Cluster, Pod, PodAffinitySpec, PodGroup,
+    )
+
+    anti = PodAffinitySpec(required=(_anti_on_slot(app="a"),))
+    sizes = [4, 4, 4, 6, 2, 2]
+    pods, groups = [], {}
+    for g, size in enumerate(sizes):
+        groups[f"g{g}"] = PodGroup(f"g{g}", size)
+        for _ in range(size):
+            i = len(pods)
+            pods.append(Pod(
+                f"p{i}", labels={"app": "a"}, requests={"cpu": 1.0},
+                arrival_time=float(i), pod_anti_affinity=anti,
+                pod_group=f"g{g}"))
+    return encode(Cluster(nodes=_slot_nodes(2.0), pod_groups=groups), pods), sizes
+
+
+@pytest.mark.parametrize("mapping", ["replay", "whatif"])
+def test_v3_host_commit_leaves_out_a_rolled_back_gang_and_label_less_nodes(
+    mapping, monkeypatch
+):
+    """A gang with a hostname-scale anti-affinity term that is rolled back in
+    its wave (the ``wv`` mask) on a topology some nodes lack (the
+    ``has_dom_h`` gate): the planes hold the binds that stand, on labelled
+    nodes only: an integer count made in numpy from the answers, and the
+    dot's planes."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    (ec, ep), sizes = _gangs_on_partial_label_slots()
+    _force_host_planes(monkeypatch, 0)
+    nodes, mc, anti, st = _run_with_host_planes(ec, ep, mapping)
+    assert st.has_gangs and st.single_g[st.mc_h_ids].all()
+    base = nodes[0] if mapping == "whatif" else nodes
+    starts = np.cumsum([0] + sizes)
+    gangs = [base[a:b] for a, b in zip(starts[:-1], starts[1:])]
+    assert all((g >= 0).all() or (g < 0).all() for g in gangs)  # never split
+    rolled = [i for i, g in enumerate(gangs) if (g < 0).all()]
+    later = [i for i, g in enumerate(gangs) if (g >= 0).all()]
+    # a gang was rolled back though places were left: a later gang took them
+    assert rolled and max(later) > min(rolled)
+    labelled = np.array([i % 3 != 0 for i in range(ec.num_nodes)])
+    # ONE row in each plane (every pod matches and holds the one term); the
+    # single replay hands its state back by domain (the labelled nodes in
+    # node order), the what-if step's planes are [S, H, N].
+    for s, placed in enumerate(nodes if mapping == "whatif" else nodes[None]):
+        count = np.bincount(placed[placed >= 0], minlength=ec.num_nodes)
+        assert count[~labelled].sum() > 0  # binds the planes must not hold
+        for plane in (mc, anti):
+            if mapping == "whatif":
+                np.testing.assert_array_equal(plane[s], [count * labelled])
+            else:
+                np.testing.assert_array_equal(plane, [count[labelled]])
+    _assert_the_dots_answers(monkeypatch, ec, ep, mapping, (nodes, mc, anti))
+
+
+def _one_node_filled_to_the_bf16_bound():
+    """Three nodes with hostname labels; only n0 has room, for exactly 256
+    pods (``pods`` 256, the bound of a bfloat16 count). 260 pods of app
+    ``a`` and 4 of app ``c`` with a required hostname anti-affinity to ``a``
+    (so the ``(a, hostname)`` match counts are carried): 256 land on n0."""
+    from kubernetes_simulator_tpu.models.core import (
+        Cluster, LabelSelector, Node, Pod, PodAffinitySpec, PodAffinityTerm,
+    )
+
+    key = "kubernetes.io/hostname"
+    nodes = [
+        Node(f"n{i}", labels={key: f"n{i}"},
+             capacity={"cpu": 1000.0 if i == 0 else 0.05,
+                       "memory": 64 * 2**30, "pods": 256})
+        for i in range(3)
+    ]
+    anti = PodAffinitySpec(
+        required=(PodAffinityTerm(LabelSelector.make({"app": "a"}), key),)
+    )
+    pods = [
+        Pod(f"p{i}", labels={"app": "a"}, requests={"cpu": 0.1},
+            arrival_time=float(i))
+        for i in range(260)
+    ] + [
+        Pod(f"c{i}", labels={"app": "c"}, requests={"cpu": 0.1},
+            arrival_time=260.0 + i, pod_anti_affinity=anti)
+        for i in range(4)
+    ]
+    return encode(Cluster(nodes=nodes), pods)
+
+
+@pytest.mark.parametrize("mapping", ["replay", "whatif"])
+def test_v3_host_commit_fills_a_bf16_plane_to_its_bound(mapping, monkeypatch):
+    """A bfloat16 ``mc_host`` row committed up to 256, the largest count the
+    plane's dtype holds exactly (``_host_plane``'s bound): equal to an
+    integer count made in numpy from the answers, and to the dot's plane."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    ec, ep = _one_node_filled_to_the_bf16_bound()
+    _force_host_planes(monkeypatch, 0)
+    nodes, mc, anti, st = _run_with_host_planes(ec, ep, mapping)
+    assert st.mc_h_bf16 and len(st.mc_h_ids) == 1
+    for s, placed in enumerate(nodes if mapping == "whatif" else nodes[None]):
+        count = np.bincount(placed[:260][placed[:260] >= 0], minlength=3)
+        assert (placed[260:] < 0).all()
+        # scenario 1 of `_perturbed` has n0 down and places nothing
+        assert count[0] == (0 if s == 1 else 256) and count[1:].sum() == 0
+        np.testing.assert_array_equal(
+            mc[s] if mapping == "whatif" else mc, [count])
+    _assert_the_dots_answers(monkeypatch, ec, ep, mapping, (nodes, mc, anti))
+
+
+def test_whatif_host_commit_builds_no_dot_and_no_wave_one_hot(monkeypatch):
+    """The arrivals-only what-if chunk program (``jit_per_scenario_src``) on
+    the 136-node default-plugins trace, 5 scenarios: inside the wave scan no
+    ``dot_general`` whose result has a host plane's shape ([S, H, N] in any
+    order) and, with neither tier preemption nor a ``pref_host`` plane, no
+    bfloat16 one-hot of the wave's nodes ([S, W, N]); each slot adds ONE
+    [S, N] row to each plane, at an index without the scenario axis. The
+    plain form (the parent's dot) builds the dots and the one-hot."""
+    import jax
+    import jax.numpy as jnp
+
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+    from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
+
+    ec, ep = _default_plugins_136()
+    S, W, N = 5, 8, ec.num_nodes
+
+    def traced(plain):
+        if plain:
+            _commit_by_dot(monkeypatch)
+        eng = WhatIfEngine(ec, ep, _perturbed(N, S), FrameworkConfig(),
+                           wave_width=W, chunk_waves=4,
+                           collect_assignments=True)
+        st, seen, chunk_fn = eng.static3, {}, eng._chunk_fn
+
+        class Traced(Exception):
+            pass
+
+        def spy(*args):
+            seen["jaxpr"] = jax.make_jaxpr(chunk_fn)(*args).jaxpr
+            raise Traced
+
+        eng._chunk_fn = spy
+        with pytest.raises(Traced):
+            eng.run()
+        planes = {tuple(sorted((S, len(ids), N)))
+                  for ids in (st.mc_h_ids, st.anti_h_ids) if len(ids)}
+        dots, one_hots, row_adds = 0, 0, 0
+        (scan,) = [e for e in _eqns(seen["jaxpr"]) if e.primitive.name == "scan"]
+        for eqn in _eqns(scan.params["jaxpr"].jaxpr):
+            for v in eqn.outvars:
+                shape = tuple(sorted(v.aval.shape))
+                dots += eqn.primitive.name == "dot_general" and shape in planes
+                one_hots += (v.aval.dtype == jnp.bfloat16
+                             and shape == tuple(sorted((S, W, N))))
+            if eqn.primitive.name == "scatter-add":
+                plane, index, row = (tuple(x.aval.shape) for x in eqn.invars)
+                # ONE row of [S, H, N], at an index every scenario shares
+                row_adds += (tuple(sorted(plane)) in planes
+                             and S not in index and row == (S, N))
+        return st, dots, one_hots, row_adds
+
+    st, dots, one_hots, row_adds = traced(plain=False)
+    assert st.has_host_rows and not st.preemption and not len(st.pref_h_ids)
+    assert st.mc_h_one_row and st.anti_h_one_row
+    assert V3.host_commit_form(st, scenario_axis=True) == {
+        "rows": len(st.mc_h_ids) + len(st.anti_h_ids), "elementwise": 0,
+        "dot": 0}
+    # a row a slot in each of the two planes, and nothing else
+    assert (dots, one_hots, row_adds) == (0, 0, 2 * W)
+    _, dots, one_hots, row_adds = traced(plain=True)
+    # one dot a plane, over one one-hot
+    assert dots == 2 and one_hots >= 1 and row_adds == 0
+
+
+@pytest.mark.parametrize("scenario_axis", [False, True],
+                         ids=["replay", "scenario-axis"])
+@pytest.mark.parametrize(
+    "cell, nodes, host_rows",
+    [("k8s5k-whatif256", 136, True), ("borg10k-whatif128", 64, False),
+     ("borg10k-replay1", 64, False), ("multitenant-mesh4", 64, False)],
+)
+def test_host_commit_form_of_the_cells_traces(cell, nodes, host_rows,
+                                              scenario_axis):
+    """``count_planes()["host_commit"]``: every host row of the
+    default-plugins trace (hostname anti-affinity: match counts and holders,
+    whole numbers, no pod naming two rows of a plane) is committed row by
+    row where the step is mapped over a scenario axis and over the whole
+    plane in the single replay, none through a dot; the Borg and the
+    multi-tenant traces carry no host row: 0, 0 and 0."""
+    from test_default_plugins_reference import bench
+
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+    from kubernetes_simulator_tpu.sim.jax_runtime import StepSpec
+
+    _, _, config, _ = bench.load_cell(cell)
+    gen = bench.load_part("generators", config["generator"])
+    ec, ep = gen.to_program(gen.generate(config, nodes, 256, 3), config)
+    st = V3.V3Static.build(
+        ec, ep, StepSpec.from_config(ec, FrameworkConfig(), ep))
+    planes = V3.count_planes(st, scenario_axis)
+    rows = len(st.mc_h_ids) + len(st.anti_h_ids)
+    assert planes["host_commit"] == V3.host_commit_form(st, scenario_axis) == {
+        "rows": rows if scenario_axis else 0,
+        "elementwise": 0 if scenario_axis else rows, "dot": 0}
+    assert planes["host_rows"] == rows and (rows > 0) == host_rows
+    assert st.mc_h_one_row and st.anti_h_one_row
+
+
+def _two_rows_a_pod():
+    """The partial-label cluster with pods of TWO apps at once, each with a
+    required anti-affinity to both on the singleton topology: a bind adds
+    to two rows of ``mc_host`` and to two of ``anti_host``."""
+    from kubernetes_simulator_tpu.models.core import (
+        Cluster, Pod, PodAffinitySpec,
+    )
+
+    anti = PodAffinitySpec(
+        required=(_anti_on_slot(app="a"), _anti_on_slot(team="t")))
+    pods = [
+        Pod(f"p{i}", labels={"app": "a", "team": "t"}, requests={"cpu": 1.0},
+            arrival_time=float(i), pod_anti_affinity=anti)
+        for i in range(20)
+    ]
+    return encode(Cluster(nodes=_slot_nodes()), pods)
+
+
+def test_a_pod_that_names_two_rows_keeps_the_whole_plane_add(monkeypatch):
+    """``mc_h_one_row`` / ``anti_h_one_row``: where a pod's bind adds to two
+    rows of a plane the step commits the plane whole under the scenario axis
+    too, and its planes are the dot's."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    ec, ep = _two_rows_a_pod()
+    _force_host_planes(monkeypatch, 0)
+    nodes, mc, anti, st = _run_with_host_planes(ec, ep, "whatif")
+    assert len(st.mc_h_ids) == len(st.anti_h_ids) == 2
+    assert not st.mc_h_one_row and not st.anti_h_one_row
+    assert V3.host_commit_form(st, scenario_axis=True) == {
+        "rows": 0, "elementwise": 4, "dot": 0}
+    count = np.bincount(nodes[0][nodes[0] >= 0], minlength=ec.num_nodes)
+    labelled = np.array([i % 3 != 0 for i in range(ec.num_nodes)])
+    np.testing.assert_array_equal(mc[0], [count * labelled] * 2)
+    np.testing.assert_array_equal(anti[0], [count * labelled] * 2)
+    calls = []
+    _assert_the_dots_answers(monkeypatch, ec, ep, "whatif", (nodes, mc, anti), calls)
+    assert calls and not any(calls)  # the whole-plane add, never the row's
+
+
+def test_a_preferred_hostname_term_keeps_the_dot_and_the_one_hot():
+    """A preferred pod-affinity term on a host-scale singleton topology puts
+    fractional weights into ``pref_host``: its rows keep the dot (the sum
+    depends on the order of its additions) and ``host_commit_form`` says so;
+    the whole-number rows beside them are elementwise."""
+    from kubernetes_simulator_tpu.models.core import (
+        LabelSelector, PodAffinitySpec, PodAffinityTerm, WeightedPodAffinityTerm,
+    )
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    cluster = make_cluster(150, seed=7)
+    pods, _ = make_workload(200, seed=7, with_affinity=True)
+    near = PodAffinitySpec(preferred=(WeightedPodAffinityTerm(
+        7, PodAffinityTerm(LabelSelector.make({"app": pods[0].labels["app"]}),
+                           "kubernetes.io/hostname")),))
+    for pod in pods[::5]:
+        pod.pod_affinity = near
+    ec, ep = encode(cluster, pods)
+    eng = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v3", wave_width=8,
+                          chunk_waves=4)
+    st = eng.static3
+    assert len(st.pref_h_ids) and st.single_g[st.pref_h_ids].all()
+    form = V3.host_commit_form(st)
+    assert form["dot"] == len(st.pref_h_ids) and form["rows"] == 0
+    assert form["elementwise"] == len(st.mc_h_ids) + len(st.anti_h_ids) > 0
+    assert V3.host_commit_form(st, scenario_axis=True)["dot"] == form["dot"]
+    v2 = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v2").replay()
+    v3 = eng.replay()
+    np.testing.assert_array_equal(v2.assignments, v3.assignments)
