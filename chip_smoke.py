@@ -36,7 +36,11 @@ no result line (nothing here catches a phase's exception):
                   examples/config5_multitenant_mesh.yaml over all devices
                   vs the same file on one device; the meshed run's
                   ``summary()["mesh"]`` counters are required, with no
-                  collective in its chunk program
+                  collective in its chunk program, and of one more batch
+                  of that engine, armed and traced
+                  (``utils.profiling.device_trace``): one root span,
+                  ``mesh_fetch`` with ``bytes`` = ``fetch_bytes``, every
+                  span closed inside the root
 
 Stdout is two JSON lines, written only after every phase passed. The
 first is the report (versions, compile-cache directory, per phase: cold
@@ -142,6 +146,22 @@ def cli(argv) -> list:
 def require(cond, msg: str) -> None:
     if not cond:
         raise AssertionError(msg)
+
+
+def program_spans(trace_dir) -> list:
+    """[(name, start_ns, end_ns, thread, stats)] of the host events in the
+    profiler trace under ``trace_dir`` that carry a name the program
+    exports (``sim.telemetry``), roots among them, in start order: read by
+    the benchmark's reader of the same spans."""
+    sys.path[:0] = [str(Path(__file__).resolve().parent / "benchmark")]
+    import trace_reduce
+    from layer_metrics import _program_spans
+
+    kept, _ = _program_spans.span_names()
+    events = _program_spans.events_from_xplane(
+        trace_reduce.find_xplane(trace_dir), kept)
+    return [(name, start, start + duration, thread, stats)
+            for name, start, duration, thread, stats in events]
 
 
 def split_whatif(rows):
@@ -558,15 +578,30 @@ def phase_mesh() -> dict:
 
     from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
 
-    summaries, engine_run = [], WhatIfEngine.run
+    from kubernetes_simulator_tpu.utils import profiling
+
+    summaries, engine_run, engine_init = [], WhatIfEngine.run, WhatIfEngine.__init__
+    armed, trace_dir = [], tempfile.mkdtemp(prefix="ksim_smoke_trace_")
 
     def run_and_keep(self):
         res = engine_run(self)
         summaries.append(res.fleet_telemetry.summary())
+        if self.mesh is not None and not armed:
+            # One more batch of the meshed engine, nothing left to compile:
+            # armed and traced as ``--profile-dir`` does it.
+            with profiling.device_trace(trace_dir):
+                again = engine_run(self)
+            armed.append(again.fleet_telemetry.summary())
         return res
 
+    def init_handing_back(self, *args, **kw):
+        # The CLI asks for counts only; the meshed run here also hands the
+        # placements back, so that the batch has a fetch to trace.
+        engine_init(self, *args, **{**kw, "collect_assignments": True})
+
     try:
-        with mock.patch.object(WhatIfEngine, "run", run_and_keep):
+        with mock.patch.object(WhatIfEngine, "run", run_and_keep), \
+                mock.patch.object(WhatIfEngine, "__init__", init_handing_back):
             rows_mesh = cli(["what-if", CONFIG5])
         after = device_peaks()
         with tempfile.TemporaryDirectory(prefix="ksim_smoke_") as tmp:
@@ -605,6 +640,29 @@ def phase_mesh() -> dict:
     held_by = counters.get("collectives", {})
     require(held_by.get("chunk") == 0 and not held_by.get("handback"),
             f"mesh programs hold collectives: {held_by}")
+    # The armed batch: one root, the fetch's span carrying the bytes the
+    # counter holds, and every span of the program closed inside the root
+    # (one left open is never written; a root left open takes all with it).
+    require(len(armed) == 1, f"armed meshed batches: {len(armed)}")
+    spans = program_spans(trace_dir)
+    roots = [e for e in spans if e[0].startswith("whatif_run:")]
+    require(len(roots) == 1, f"root spans of one armed batch: {roots}")
+    root = roots[0]
+    loose = [e[0] for e in spans if e is not root and not (
+        e[3] == root[3] and root[1] <= e[1] and e[2] <= root[2])]
+    require(not loose, f"spans outside the root {root[0]}: {loose}")
+    names = [e[0] for e in spans]
+    for phase in ("stage", "dispatch", "device_wait", "gather", "handback"):
+        require(phase in names, f"no {phase!r} span in the armed batch: {names}")
+    fetch = [e for e in spans if e[0] == "mesh_fetch"]
+    handback = [e for e in spans if e[0] == "handback"]
+    want = armed[0]["mesh"]["fetch_bytes"]
+    require(len(fetch) == 1 and fetch[0][4] == {"bytes": want} and want > 0
+            and handback[0][1] <= fetch[0][1] and fetch[0][2] <= handback[0][2],
+            f"mesh_fetch spans {fetch} for fetch_bytes {want}")
+    say(f"mesh: armed batch {root[0]} {(root[2] - root[1]) / 1e6:.3f} ms, "
+        f"{len(spans)} spans, mesh_fetch {want} bytes in "
+        f"{(fetch[0][2] - fetch[0][1]) / 1e6:.3f} ms")
     return {
         "size": f"{S} scenarios x {N} nodes x {P} pods",
         "engine": agg["engine"],

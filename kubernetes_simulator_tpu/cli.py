@@ -894,7 +894,9 @@ def main(argv=None) -> int:
         p = sub.add_parser(name)
         p.add_argument("config")
         p.add_argument("--strategy", choices=["cpu", "jax"])
-        p.add_argument("--profile-dir", default=None, help="jax.profiler trace output dir")
+        p.add_argument("--profile-dir", default=None,
+                       help="jax.profiler trace output dir; arms the "
+                       "program's host spans (KSIM_PROFILE_DIR) for the run")
         if name == "run":
             p.add_argument(
                 "--timeline-out", default=None,

@@ -45,7 +45,7 @@ from ..utils.metrics import (
     utilization_means,
 )
 from ..utils.profiling import (
-    annotate,
+    make_span,
     profiling_active,
     register_program,
     shape_structs,
@@ -54,52 +54,6 @@ from ..utils.profiling import (
 from .runtime import ReplayResult, events_hash, validate_node_events
 from .telemetry import TelemetryCollector, TelemetryConfig
 from .waves import WaveBatch, pack_waves
-
-
-class _NullCtx:
-    """No-op context for phase ticks when telemetry is off."""
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_CTX = _NullCtx()
-
-
-def _make_tick(tel):
-    """Phase-tick factory shared by both replay paths: the telemetry
-    phase timer when collecting, stacked under a
-    ``jax.profiler.TraceAnnotation`` when ``KSIM_PROFILE_DIR`` is armed
-    (round 12 device-profiler hooks) — the annotation names the
-    PHASE_NAMES phase in XLA traces. ``profiling_active`` is consulted
-    ONCE per replay, here; with profiling off the returned callable is
-    exactly the pre-round-12 lambda."""
-    base = (
-        (lambda name: tel.phases.tick(name))
-        if tel is not None
-        else (lambda name: _NULL_CTX)
-    )
-    if not profiling_active():
-        return base
-    import contextlib
-
-    @contextlib.contextmanager
-    def _tick(name):
-        with annotate(name), base(name):
-            yield
-
-    return _tick
-
-
-def _chunk_ann(ci: int):
-    """Chunk-dispatch annotation: ``chunk:<ci>`` marker in device traces
-    when profiling is armed, else the shared no-op context."""
-    if not profiling_active():
-        return _NULL_CTX
-    return annotate(f"chunk:{ci}")
 
 
 def _file_bytes(path: str) -> int:
@@ -959,6 +913,7 @@ class JaxReplayEngine:
         self.kube = mode == "kube"
         self.retry_buffer = int(retry_buffer)
         self.lazy_boundary = bool(lazy_boundary)
+        self._replay_calls = 0  # ordinal of the next replay()'s root span
         self.double_buffer = bool(double_buffer)
         self.completions = completions
         self.granularity_guard = granularity_guard
@@ -1284,7 +1239,7 @@ class JaxReplayEngine:
         return checkpoint_to_state(ck, self._gdom)
 
     def _replay_boundary(
-        self, node_events=None, chunk_req: Optional[int] = None,
+        self, _tick, node_events=None, chunk_req: Optional[int] = None,
         retry_req: Optional[int] = None,
         checkpoint_path: Optional[str] = None, checkpoint_every: int = 0,
         resume: bool = False,
@@ -1341,7 +1296,7 @@ class JaxReplayEngine:
         # Flight recorder (round 16): same contract as the plain path —
         # host-side observation only, parity-pinned against recorder-off.
         rec, rec_own = self._open_recorder()
-        _tick = _make_tick(tel if tel is not None else rec)
+        _tick.timers = getattr(tel if tel is not None else rec, "phases", None)
         with _tick("stage"):
             bops = BoundaryOps(
                 self.ec, self.pods, fw,
@@ -1562,7 +1517,7 @@ class JaxReplayEngine:
                             ),
                             binds,
                         )
-                with _tick("dispatch"), _chunk_ann(ci):
+                with _tick("dispatch"), _tick.mark(f"chunk:{ci}"):
                     if self.engine == "v3":
                         state, choices = self.chunk_fn(
                             self.dc, state, self._slot_src, self._extra_src,
@@ -1763,548 +1718,560 @@ class JaxReplayEngine:
         smaller chunks for finer timing). With ``retry_buffer``/``kube``
         active, ``node_down`` additionally evicts bound pods (NoExecute)
         through the boundary mirror; without a retry buffer only future
-        placements are affected."""
-        from .checkpoint import ReplayCheckpoint, checkpoint_to_state, state_to_checkpoint
+        placements are affected.
 
-        validate_node_events(node_events, self.ec.num_nodes)
-        if self.preemption and (checkpoint_path or resume):
-            raise ValueError(
-                "checkpoint/resume is not supported with device preemption "
-                "(tier planes are not checkpointed)"
-            )
-        if self.retry_buffer or self.kube:
-            if self.completions is False:
+        With profiling armed the whole call lies under one root span
+        ``replay:<n>``, ``n`` this engine's call ordinal: the phases are
+        its children on the calling thread, and what lies under the root
+        alone is host work no span names."""
+        # The call's ONE look at KSIM_PROFILE_DIR; the phase timers are bound
+        # once telemetry is known. The body stays in this function: one more
+        # Python frame between the caller and the jitted calls makes every
+        # lowering a quarter slower (PERF.md §6, PR 35).
+        _tick = make_span()
+        n, self._replay_calls = self._replay_calls, self._replay_calls + 1
+        with _tick.mark(f"replay:{n}"):
+            from .checkpoint import ReplayCheckpoint, checkpoint_to_state, state_to_checkpoint
+
+            validate_node_events(node_events, self.ec.num_nodes)
+            if self.preemption and (checkpoint_path or resume):
                 raise ValueError(
-                    "completions=False is not supported with retry_buffer/"
-                    "kube preemption (the boundary pass owns releases)"
+                    "checkpoint/resume is not supported with device preemption "
+                    "(tier planes are not checkpointed)"
                 )
-        # Granularity-envelope guard (round 5, VERDICT r4 #2; see
-        # sim.granularity) — ONE call site for every replay path; no-op
-        # for duration-free traces, shapes inside the measured-safe
-        # regime, and explicit completions=False (which the boundary
-        # modes reject above).
-        chunk_req, retry_req = self.chunk_waves, self.retry_buffer
-        if self.completions is not False:
-            from .granularity import guard as _gran_guard
-
-            chunk_req, retry_req = _gran_guard(
-                self.pods, self.waves.idx, chunk_req, retry_req,
-                enabled=self.granularity_guard,
-                engine_name="jax replay engine",
-            )
-        if self.retry_buffer or self.kube:
-            return self._replay_boundary(
-                node_events=node_events, chunk_req=chunk_req,
-                retry_req=retry_req, checkpoint_path=checkpoint_path,
-                checkpoint_every=checkpoint_every, resume=resume,
-            )
-        if (
-            node_events
-            and self.engine == "v3"
-            and (self.static3.mc_h_bf16 or self.static3.anti_h_bf16)
-            and any(e.kind == "capacity_scale" for e in node_events)
-        ):
-            # Capacity scaling can push per-node pod counts past the bf16
-            # exactness bound baked into the kernel — rebuild without it.
-            from ..ops import tpu3 as V3
-
-            self.static3 = V3.V3Static.build(
-                self.ec, self.pods, self.spec, self.dmax_coarse,
-                preemption=self.preemption, allow_bf16_host=False,
-            )
-            self.shared3 = V3.Shared3.build(self.ec, self.static3)
-            self.chunk_fn = make_chunk_fn3_src(
-                self.static3, self.shared3,
-                rep_slots_for(self.static3, self.pods),
-                self.wave_width, self.spec,
-            )
-            # Keep the device-resident per-pod rows in lockstep with the
-            # rebuilt static tables (value-identical today, but a silent
-            # desync trap if V3Static ever derives them from a rebuild
-            # parameter).
-            self._extra_src = V3.ExtraSource.build(
-                self.static3, self.pods.num_pods
-            )
-
-        idx = self.waves.idx
-        C = min(chunk_req, max(idx.shape[0], 1))
-        pad_to = ((idx.shape[0] + C - 1) // C) * C
-        if pad_to != idx.shape[0]:
-            idx = np.concatenate(
-                [idx, np.full((pad_to - idx.shape[0], idx.shape[1]), PAD, np.int32)]
-            )
-        from ..ops import tpu3 as V3
-        from ..utils.metrics import log
-
-        tel = (
-            TelemetryCollector(
-                self.telemetry_cfg, chunk_waves=C,
-                **self._program_forms(),
-            )
-            if self.telemetry_cfg.enabled
-            else None
-        )
-        # Flight recorder (round 16): pure host-side observation — with
-        # telemetry off it owns the phase timers, so recorder rows still
-        # carry PHASE_NAMES deltas without a collector. Nothing below
-        # changes a device program, a fold order or a checkpoint payload.
-        rec, rec_own = self._open_recorder()
-        _tick = _make_tick(tel if tel is not None else rec)
-        with _tick("stage"):
-            # In-scan rejection attribution (series+): thread a [K] i32 reject
-            # counter through the scan carry via the instrumented reference
-            # chunk program — one extra fetch per REPLAY, never per pod. The
-            # default "summary" granularity takes none of these branches and
-            # runs the exact same device program as before.
-            use_rej = tel is not None and tel.cfg.want_series
-            if use_rej and self.preemption:
-                log.info(
-                    "telemetry: rejection attribution is not available with "
-                    "in-scan tier preemption (the instrumented program has no "
-                    "tier planes) — latency/phase telemetry still collected"
-                )
-                use_rej = False
-            if use_rej and (checkpoint_path or resume):
-                log.info(
-                    "telemetry: rejection attribution is disabled under "
-                    "checkpoint/resume (the instrumented carry is not part of "
-                    "checkpoints) — latency/phase telemetry still collected"
-                )
-                use_rej = False
-            rej_dev = None
-            if use_rej:
-                if self.engine == "v3":
-                    log.info(
-                        "telemetry series: plain v3 replay uses the reference "
-                        "(v2) chunk program for in-scan rejection attribution "
-                        "— placements are bit-identical (parity-pinned), "
-                        "throughput is the v2 envelope"
-                    )
-                if not hasattr(self, "_chunk_fn_rej"):
-                    self._chunk_fn_rej = make_chunk_fn_rej(
-                        self.wave_width, self.spec
-                    )
-                rej_dev = jnp.zeros(
-                    len(spec_plugin_names(self.spec)), jnp.int32
-                )
-
-            state = self._init_dev_state(force_v2=use_rej)
-            all_choices = []
-            start_chunk = 0
-            if resume and checkpoint_path:
-                ck = ReplayCheckpoint.load(checkpoint_path)
-                if ck.boundary is not None:
+            if self.retry_buffer or self.kube:
+                if self.completions is False:
                     raise ValueError(
-                        "checkpoint was written by a boundary-mode (retry/"
-                        "kube) replay — its placements live in the host "
-                        "mirror, not the saved outs; resume it with the "
-                        "same retry_buffer/preemption configuration"
+                        "completions=False is not supported with retry_buffer/"
+                        "kube preemption (the boundary pass owns releases)"
                     )
-                state = self._state_from_checkpoint(ck)
-                all_choices = [jnp.asarray(o) for o in ck.outs]
-                start_chunk = ck.chunk_cursor
-            pending_events = sorted(node_events or [], key=lambda e: e.time)
-            rel_time = self.pods.arrival + np.where(
-                np.isfinite(self.pods.duration), self.pods.duration, np.inf
-            )
-            completions_on = bool(
-                self.completions is not False  # None (the default) = on
-                and np.isfinite(rel_time).any()
-            )
-            wave_times = (
-                self._wave_start_times(idx)
-                # use_rej: series telemetry also samples utilization at chunk
-                # boundaries, which needs the chunk start times. The recorder
-                # stamps the chunk's virtual time on every row (host numpy
-                # only — no program effect).
-                if (pending_events or completions_on or use_rej or rec is not None)
+            # Granularity-envelope guard (round 5, VERDICT r4 #2; see
+            # sim.granularity) — ONE call site for every replay path; no-op
+            # for duration-free traces, shapes inside the measured-safe
+            # regime, and explicit completions=False (which the boundary
+            # modes reject above).
+            chunk_req, retry_req = self.chunk_waves, self.retry_buffer
+            if self.completions is not False:
+                from .granularity import guard as _gran_guard
+
+                chunk_req, retry_req = _gran_guard(
+                    self.pods, self.waves.idx, chunk_req, retry_req,
+                    enabled=self.granularity_guard,
+                    engine_name="jax replay engine",
+                )
+            if self.retry_buffer or self.kube:
+                return self._replay_boundary(
+                    _tick, node_events=node_events, chunk_req=chunk_req,
+                    retry_req=retry_req, checkpoint_path=checkpoint_path,
+                    checkpoint_every=checkpoint_every, resume=resume,
+                )
+            if (
+                node_events
+                and self.engine == "v3"
+                and (self.static3.mc_h_bf16 or self.static3.anti_h_bf16)
+                and any(e.kind == "capacity_scale" for e in node_events)
+            ):
+                # Capacity scaling can push per-node pod counts past the bf16
+                # exactness bound baked into the kernel — rebuild without it.
+                from ..ops import tpu3 as V3
+
+                self.static3 = V3.V3Static.build(
+                    self.ec, self.pods, self.spec, self.dmax_coarse,
+                    preemption=self.preemption, allow_bf16_host=False,
+                )
+                self.shared3 = V3.Shared3.build(self.ec, self.static3)
+                self.chunk_fn = make_chunk_fn3_src(
+                    self.static3, self.shared3,
+                    rep_slots_for(self.static3, self.pods),
+                    self.wave_width, self.spec,
+                )
+                # Keep the device-resident per-pod rows in lockstep with the
+                # rebuilt static tables (value-identical today, but a silent
+                # desync trap if V3Static ever derives them from a rebuild
+                # parameter).
+                self._extra_src = V3.ExtraSource.build(
+                    self.static3, self.pods.num_pods
+                )
+
+            idx = self.waves.idx
+            C = min(chunk_req, max(idx.shape[0], 1))
+            pad_to = ((idx.shape[0] + C - 1) // C) * C
+            if pad_to != idx.shape[0]:
+                idx = np.concatenate(
+                    [idx, np.full((pad_to - idx.shape[0], idx.shape[1]), PAD, np.int32)]
+                )
+            from ..ops import tpu3 as V3
+            from ..utils.metrics import log
+
+            tel = (
+                TelemetryCollector(
+                    self.telemetry_cfg, chunk_waves=C,
+                    **self._program_forms(),
+                )
+                if self.telemetry_cfg.enabled
                 else None
             )
-            pending_fold = None  # (rows, choices) of the not-yet-folded chunk
-            nongang = self.pods.group_id == PAD
-            if completions_on and self.preemption:
-                # Completions × preemption (round 4): folds run EAGERLY (the
-                # chunk's eviction events must land in the host bookkeeping
-                # BEFORE the next boundary's release decisions, or a pod the
-                # device evicted would "release" resources it no longer
-                # holds). The one-chunk slack therefore becomes an explicit
-                # bind-chunk check instead of a fold lag; the pipeline eats
-                # one blocking fetch per chunk — correctness over overlap for
-                # this opt-in combination.
-                chunk_of_arr = bind_chunk_of(self.pods, idx, C)
-            if completions_on:
-                host_assign = np.where(
-                    self.pods.bound_node >= 0, self.pods.bound_node, PAD
-                ).astype(np.int32)
-                released = np.zeros(self.pods.num_pods, bool)
-                if start_chunk:
-                    # Resume: the saved state already carries pre-resume
-                    # releases — seed from the persisted mask (or reconstruct
-                    # from the saved outs for pre-field checkpoints). The
-                    # one-chunk slack is restored by folding only chunks
-                    # ≤ start_chunk−2 and re-pending the last saved chunk.
-                    have_mask = getattr(ck, "released", None) is not None
-                    host_assign, _ = rebuild_fork_state(
-                        self.pods, idx, C, all_choices, wave_times,
-                        max(start_chunk - 1, 0), reconstruct_released=False,
+            # Flight recorder (round 16): pure host-side observation — with
+            # telemetry off it owns the phase timers, so recorder rows still
+            # carry PHASE_NAMES deltas without a collector. Nothing below
+            # changes a device program, a fold order or a checkpoint payload.
+            rec, rec_own = self._open_recorder()
+            _tick.timers = getattr(tel if tel is not None else rec, "phases", None)
+            with _tick("stage"):
+                # In-scan rejection attribution (series+): thread a [K] i32 reject
+                # counter through the scan carry via the instrumented reference
+                # chunk program — one extra fetch per REPLAY, never per pod. The
+                # default "summary" granularity takes none of these branches and
+                # runs the exact same device program as before.
+                use_rej = tel is not None and tel.cfg.want_series
+                if use_rej and self.preemption:
+                    log.info(
+                        "telemetry: rejection attribution is not available with "
+                        "in-scan tier preemption (the instrumented program has no "
+                        "tier planes) — latency/phase telemetry still collected"
                     )
-                    if have_mask:
-                        released = ck.released.astype(bool)
-                    else:
-                        # released=None ⟹ a checkpoint from before the field
-                        # existed ⟹ its state was built under the OLD
-                        # (no-slack) release rule — reconstruct with slack=0.
-                        _, released = rebuild_fork_state(
+                    use_rej = False
+                if use_rej and (checkpoint_path or resume):
+                    log.info(
+                        "telemetry: rejection attribution is disabled under "
+                        "checkpoint/resume (the instrumented carry is not part of "
+                        "checkpoints) — latency/phase telemetry still collected"
+                    )
+                    use_rej = False
+                rej_dev = None
+                if use_rej:
+                    if self.engine == "v3":
+                        log.info(
+                            "telemetry series: plain v3 replay uses the reference "
+                            "(v2) chunk program for in-scan rejection attribution "
+                            "— placements are bit-identical (parity-pinned), "
+                            "throughput is the v2 envelope"
+                        )
+                    if not hasattr(self, "_chunk_fn_rej"):
+                        self._chunk_fn_rej = make_chunk_fn_rej(
+                            self.wave_width, self.spec
+                        )
+                    rej_dev = jnp.zeros(
+                        len(spec_plugin_names(self.spec)), jnp.int32
+                    )
+
+                state = self._init_dev_state(force_v2=use_rej)
+                all_choices = []
+                start_chunk = 0
+                if resume and checkpoint_path:
+                    ck = ReplayCheckpoint.load(checkpoint_path)
+                    if ck.boundary is not None:
+                        raise ValueError(
+                            "checkpoint was written by a boundary-mode (retry/"
+                            "kube) replay — its placements live in the host "
+                            "mirror, not the saved outs; resume it with the "
+                            "same retry_buffer/preemption configuration"
+                        )
+                    state = self._state_from_checkpoint(ck)
+                    all_choices = [jnp.asarray(o) for o in ck.outs]
+                    start_chunk = ck.chunk_cursor
+                pending_events = sorted(node_events or [], key=lambda e: e.time)
+                rel_time = self.pods.arrival + np.where(
+                    np.isfinite(self.pods.duration), self.pods.duration, np.inf
+                )
+                completions_on = bool(
+                    self.completions is not False  # None (the default) = on
+                    and np.isfinite(rel_time).any()
+                )
+                wave_times = (
+                    self._wave_start_times(idx)
+                    # use_rej: series telemetry also samples utilization at chunk
+                    # boundaries, which needs the chunk start times. The recorder
+                    # stamps the chunk's virtual time on every row (host numpy
+                    # only — no program effect).
+                    if (pending_events or completions_on or use_rej or rec is not None)
+                    else None
+                )
+                pending_fold = None  # (rows, choices) of the not-yet-folded chunk
+                nongang = self.pods.group_id == PAD
+                if completions_on and self.preemption:
+                    # Completions × preemption (round 4): folds run EAGERLY (the
+                    # chunk's eviction events must land in the host bookkeeping
+                    # BEFORE the next boundary's release decisions, or a pod the
+                    # device evicted would "release" resources it no longer
+                    # holds). The one-chunk slack therefore becomes an explicit
+                    # bind-chunk check instead of a fold lag; the pipeline eats
+                    # one blocking fetch per chunk — correctness over overlap for
+                    # this opt-in combination.
+                    chunk_of_arr = bind_chunk_of(self.pods, idx, C)
+                if completions_on:
+                    host_assign = np.where(
+                        self.pods.bound_node >= 0, self.pods.bound_node, PAD
+                    ).astype(np.int32)
+                    released = np.zeros(self.pods.num_pods, bool)
+                    if start_chunk:
+                        # Resume: the saved state already carries pre-resume
+                        # releases — seed from the persisted mask (or reconstruct
+                        # from the saved outs for pre-field checkpoints). The
+                        # one-chunk slack is restored by folding only chunks
+                        # ≤ start_chunk−2 and re-pending the last saved chunk.
+                        have_mask = getattr(ck, "released", None) is not None
+                        host_assign, _ = rebuild_fork_state(
                             self.pods, idx, C, all_choices, wave_times,
-                            start_chunk, slack=0,
+                            max(start_chunk - 1, 0), reconstruct_released=False,
                         )
-                    if start_chunk >= 1:
-                        pending_fold = (
-                            idx[(start_chunk - 1) * C : start_chunk * C],
-                            np.asarray(all_choices[start_chunk - 1]),
-                        )
-            saved_alloc = np.asarray(self.dc.allocatable).copy()
-            # Pre-stage the per-chunk wave indices on device (a few MB total):
-            # the timed loop then issues ONE call per chunk with no H2D.
-            idx_chunks = (
-                [
-                    jnp.asarray(idx[c0 : c0 + C])
-                    for c0 in range(0, idx.shape[0], C)
-                ]
-                if self.engine == "v3" and not use_rej and not self.paged
-                else None
-            )
-            self._register_programs(state, idx_chunks, completions_on)
-            # Paged pod waves (round 14): per-chunk pages of the slot planes
-            # stream host->device with one-chunk prefetch instead of whole-trace
-            # residency. v3 pages carry page-LOCAL row indices (the kernels only
-            # consume pod_id as a width, never as an identity).
-            pager = None
-            if self.paged and not use_rej:
-                if self.engine == "v3":
-                    def _fetch_page(pci):
-                        rows = idx[pci * C : (pci + 1) * C]
-                        flat = rows.reshape(-1)
-                        local = np.where(
-                            rows >= 0,
-                            np.arange(
-                                rows.size, dtype=np.int32
-                            ).reshape(rows.shape),
-                            PAD,
-                        ).astype(np.int32)
-                        return (
-                            T.SlotSource.page(self.pods, flat),
-                            V3.ExtraSource.page(self.static3, flat),
-                            jnp.asarray(local),
-                        )
-                else:
-                    def _fetch_page(pci):
-                        return T.gather_slots(
-                            self.pods, idx[pci * C : (pci + 1) * C]
-                        )
-                pager = _PodPager(_fetch_page, threaded=_pager_thread_enabled())
-            rec_valid = (
-                np.add.accumulate(
+                        if have_mask:
+                            released = ck.released.astype(bool)
+                        else:
+                            # released=None ⟹ a checkpoint from before the field
+                            # existed ⟹ its state was built under the OLD
+                            # (no-slack) release rule — reconstruct with slack=0.
+                            _, released = rebuild_fork_state(
+                                self.pods, idx, C, all_choices, wave_times,
+                                start_chunk, slack=0,
+                            )
+                        if start_chunk >= 1:
+                            pending_fold = (
+                                idx[(start_chunk - 1) * C : start_chunk * C],
+                                np.asarray(all_choices[start_chunk - 1]),
+                            )
+                saved_alloc = np.asarray(self.dc.allocatable).copy()
+                # Pre-stage the per-chunk wave indices on device (a few MB total):
+                # the timed loop then issues ONE call per chunk with no H2D.
+                idx_chunks = (
                     [
-                        int((idx[c0 : c0 + C] >= 0).sum())
+                        jnp.asarray(idx[c0 : c0 + C])
                         for c0 in range(0, idx.shape[0], C)
                     ]
+                    if self.engine == "v3" and not use_rej and not self.paged
+                    else None
                 )
-                if rec is not None
-                else None
-            )
-            rec_stalls_seen = 0
-            rec_inval_seen = 0
-            rec_pub = None
-            rec_retry = None
-            if rec is not None:
-                from ..parallel import dcn as _dcn
+                self._register_programs(state, idx_chunks, completions_on)
+                # Paged pod waves (round 14): per-chunk pages of the slot planes
+                # stream host->device with one-chunk prefetch instead of whole-trace
+                # residency. v3 pages carry page-LOCAL row indices (the kernels only
+                # consume pod_id as a width, never as an identity).
+                pager = None
+                if self.paged and not use_rej:
+                    if self.engine == "v3":
+                        def _fetch_page(pci):
+                            rows = idx[pci * C : (pci + 1) * C]
+                            flat = rows.reshape(-1)
+                            local = np.where(
+                                rows >= 0,
+                                np.arange(
+                                    rows.size, dtype=np.int32
+                                ).reshape(rows.shape),
+                                PAD,
+                            ).astype(np.int32)
+                            return (
+                                T.SlotSource.page(self.pods, flat),
+                                V3.ExtraSource.page(self.static3, flat),
+                                jnp.asarray(local),
+                            )
+                    else:
+                        def _fetch_page(pci):
+                            return T.gather_slots(
+                                self.pods, idx[pci * C : (pci + 1) * C]
+                            )
+                    pager = _PodPager(_fetch_page, threaded=_pager_thread_enabled())
+                rec_valid = (
+                    np.add.accumulate(
+                        [
+                            int((idx[c0 : c0 + C] >= 0).sum())
+                            for c0 in range(0, idx.shape[0], C)
+                        ]
+                    )
+                    if rec is not None
+                    else None
+                )
+                rec_stalls_seen = 0
+                rec_inval_seen = 0
+                rec_pub = None
+                rec_retry = None
+                if rec is not None:
+                    from ..parallel import dcn as _dcn
 
-                rec_pub = _dcn.publish_stats()
-                rec_retry = _dcn.retry_stats()
-        t0 = time.perf_counter()
-        for ci, c0 in enumerate(range(0, idx.shape[0], C)):
-            if ci < start_chunk:
-                continue
-            if pending_events:
-                chunk_t = wave_times[c0]
-                due = [e for e in pending_events if e.time <= chunk_t]
-                if due:
-                    self._apply_node_events(due, saved_alloc)
-                    if tel is not None and tel.cfg.want_timeline:
-                        for ev in due:
-                            if ev.kind in ("node_down", "node_up"):
-                                tel.event(
-                                    ev.kind, float(ev.time), -1, int(ev.node)
+                    rec_pub = _dcn.publish_stats()
+                    rec_retry = _dcn.retry_stats()
+            t0 = time.perf_counter()
+            for ci, c0 in enumerate(range(0, idx.shape[0], C)):
+                if ci < start_chunk:
+                    continue
+                if pending_events:
+                    chunk_t = wave_times[c0]
+                    due = [e for e in pending_events if e.time <= chunk_t]
+                    if due:
+                        self._apply_node_events(due, saved_alloc)
+                        if tel is not None and tel.cfg.want_timeline:
+                            for ev in due:
+                                if ev.kind in ("node_down", "node_up"):
+                                    tel.event(
+                                        ev.kind, float(ev.time), -1, int(ev.node)
+                                    )
+                        pending_events = pending_events[len(due):]
+                if completions_on:
+                    if self.preemption and pending_fold is not None:
+                        # Eager eviction-aware fold of the previous chunk.
+                        with _tick("boundary_fold"):
+                            rows_p, out_p = pending_fold
+                            preemption_walk(
+                                host_assign, rows_p,
+                                np.asarray(out_p[0]).reshape(rows_p.shape),
+                                np.asarray(out_p[1]), np.asarray(out_p[2]),
+                                self.static3.pod_tier, nongang,
+                                released=released,
+                            )
+                        pending_fold = None
+                    t_chunk = wave_times[c0]
+                    if np.isfinite(t_chunk):
+                        # The whole of what a boundary costs the host: the
+                        # scan over all pods for due releases, the delta
+                        # build and the release program's dispatch.
+                        with _tick("host_mirror"):
+                            due_m = (
+                                (host_assign != PAD)
+                                & ~released
+                                & np.isfinite(rel_time)
+                                & (rel_time <= t_chunk)
+                            )
+                            if self.preemption:
+                                # Folds are eager here, so the one-chunk slack
+                                # is the explicit bind-chunk rule.
+                                due_m &= chunk_of_arr < ci - 1
+                            due_p = np.nonzero(due_m)[0]
+                            if due_p.size:
+                                state = self._apply_release(
+                                    state, due_p, host_assign[due_p],
+                                    as_v2=use_rej,
                                 )
-                    pending_events = pending_events[len(due):]
-            if completions_on:
-                if self.preemption and pending_fold is not None:
-                    # Eager eviction-aware fold of the previous chunk.
-                    with _tick("boundary_fold"):
+                                released[due_p] = True
+                if use_rej and wave_times is not None and np.isfinite(
+                    wave_times[c0]
+                ):
+                    # Utilization economics (round 13): chunk-boundary sample
+                    # of the committed device state (binds through chunk ci-1
+                    # plus the releases applied above). The fetch blocks on
+                    # the in-flight chunk — a series-mode-only sync; summary
+                    # runs the untouched program. The instrumented-rej carry
+                    # guarantees node-space [N, R] state.used here.
+                    with _tick("host_mirror"):
+                        tel.sample(
+                            float(wave_times[c0]),
+                            **series_gauges(
+                                np.asarray(state.used),
+                                np.asarray(self.dc.allocatable),
+                                self.ec.vocab._r,
+                            ),
+                        )
+                with _tick("dispatch"), _tick.mark(f"chunk:{ci}"):
+                    if use_rej:
+                        state, rej_dev, choices = self._chunk_fn_rej(
+                            self.dc, state, rej_dev,
+                            T.gather_slots(self.pods, idx[c0 : c0 + C]),
+                        )
+                    elif self.engine == "v3":
+                        if pager is not None:
+                            src, xsrc, lidx = pager.get(ci)
+                            state, choices = self.chunk_fn(
+                                self.dc, state, src, xsrc, lidx
+                            )
+                        else:
+                            state, choices = self.chunk_fn(
+                                self.dc, state, self._slot_src, self._extra_src,
+                                idx_chunks[ci],
+                            )
+                    else:
+                        state, choices = self.chunk_fn(
+                            self.dc, state,
+                            pager.get(ci)
+                            if pager is not None
+                            else T.gather_slots(self.pods, idx[c0 : c0 + C]),
+                        )
+                if pager is not None and c0 + C < idx.shape[0]:
+                    # Stage the next page while this chunk is still on device.
+                    pager.prefetch(ci + 1)
+                all_choices.append(choices)
+                if completions_on and self.preemption:
+                    pending_fold = (idx[c0 : c0 + C], choices)
+                elif completions_on:
+                    # Fold the PREVIOUS chunk's choices AFTER dispatching this
+                    # one: the blocking fetch overlaps the in-flight chunk, and
+                    # boundary b only ever sees chunks ≤ b−2 (the one-chunk
+                    # slack; the greedy anchor implements the same rule).
+                    if pending_fold is not None:
+                        with _tick("boundary_fold"):
+                            rows_p, ch_p = pending_fold
+                            ch = np.asarray(ch_p).reshape(rows_p.shape)
+                            v = rows_p >= 0
+                            host_assign[rows_p[v]] = ch[v]
+                    pending_fold = (idx[c0 : c0 + C], choices)
+                if checkpoint_path and checkpoint_every and (ci + 1) % checkpoint_every == 0:
+                    t_ck = time.perf_counter()
+                    self._save_checkpoint(
+                        state, ci + 1, all_choices, checkpoint_path,
+                        released=(
+                            released
+                            if completions_on
+                            else np.zeros(self.pods.num_pods, bool)
+                        ),
+                    )
+                    if rec is not None:
+                        rec.checkpoint(
+                            ci + 1, _file_bytes(checkpoint_path),
+                            time.perf_counter() - t_ck,
+                        )
+                if rec is not None:
+                    if pager is not None and (
+                        pager.stalls > rec_stalls_seen
+                        or pager.invalidations > rec_inval_seen
+                    ):
+                        rec.page(
+                            ci, pager.last_stall_s, pager.stalls,
+                            invalidations=pager.invalidations,
+                        )
+                        rec_stalls_seen = pager.stalls
+                        rec_inval_seen = pager.invalidations
+                    pub_now = _dcn.publish_stats()
+                    ck_pub = None
+                    if pub_now != rec_pub:
+                        ck_pub = {
+                            "count": pub_now["count"] - rec_pub["count"],
+                            "wall_s": round(
+                                pub_now["wall_s"] - rec_pub["wall_s"], 6
+                            ),
+                            "bytes": pub_now["bytes"] - rec_pub["bytes"],
+                        }
+                        rec_pub = pub_now
+                    retry_now = _dcn.retry_stats()
+                    kv_retry = None
+                    if retry_now != rec_retry:
+                        kv_retry = {
+                            "retries": retry_now["retries"]
+                            - rec_retry["retries"],
+                            "giveups": retry_now["giveups"]
+                            - rec_retry["giveups"],
+                            "backoff_s": round(
+                                retry_now["backoff_s"]
+                                - rec_retry["backoff_s"], 6
+                            ),
+                        }
+                        rec_retry = retry_now
+                    rec.chunk(
+                        ci,
+                        t_virtual=(
+                            wave_times[c0] if wave_times is not None else None
+                        ),
+                        dispatched=int(rec_valid[ci]),
+                        placed=(
+                            int((host_assign >= 0).sum())
+                            if completions_on
+                            else None
+                        ),
+                        phase_acc=(
+                            tel.phases.acc if tel is not None else rec.phases.acc
+                        ),
+                        pager=pager,
+                        ckpt_publish=ck_pub,
+                        kv_retry=kv_retry,
+                    )
+            with _tick("device_wait"):
+                jax.block_until_ready(all_choices[-1] if all_choices else state)
+            wall = time.perf_counter() - t0
+            with _tick("gather"):
+                if node_events:
+                    self.dc = self.dc._replace(allocatable=jnp.asarray(saved_alloc))
+
+                preemptions = 0
+                to_schedule = int((idx >= 0).sum())
+                if self.preemption and completions_on:
+                    # The incremental eviction-aware folds ARE the walk; finish
+                    # the last pending chunk and read the result off the host
+                    # bookkeeping (a fresh full walk would replay evictions
+                    # against completed pods with the wrong interleaving).
+                    if pending_fold is not None:
                         rows_p, out_p = pending_fold
                         preemption_walk(
                             host_assign, rows_p,
                             np.asarray(out_p[0]).reshape(rows_p.shape),
                             np.asarray(out_p[1]), np.asarray(out_p[2]),
-                            self.static3.pod_tier, nongang,
-                            released=released,
+                            self.static3.pod_tier, nongang, released=released,
                         )
-                    pending_fold = None
-                t_chunk = wave_times[c0]
-                if np.isfinite(t_chunk):
-                    # The whole of what a boundary costs the host: the
-                    # scan over all pods for due releases, the delta
-                    # build and the release program's dispatch.
-                    with _tick("host_mirror"):
-                        due_m = (
-                            (host_assign != PAD)
-                            & ~released
-                            & np.isfinite(rel_time)
-                            & (rel_time <= t_chunk)
-                        )
-                        if self.preemption:
-                            # Folds are eager here, so the one-chunk slack
-                            # is the explicit bind-chunk rule.
-                            due_m &= chunk_of_arr < ci - 1
-                        due_p = np.nonzero(due_m)[0]
-                        if due_p.size:
-                            state = self._apply_release(
-                                state, due_p, host_assign[due_p],
-                                as_v2=use_rej,
-                            )
-                            released[due_p] = True
-            if use_rej and wave_times is not None and np.isfinite(
-                wave_times[c0]
-            ):
-                # Utilization economics (round 13): chunk-boundary sample
-                # of the committed device state (binds through chunk ci-1
-                # plus the releases applied above). The fetch blocks on
-                # the in-flight chunk — a series-mode-only sync; summary
-                # runs the untouched program. The instrumented-rej carry
-                # guarantees node-space [N, R] state.used here.
-                with _tick("host_mirror"):
-                    tel.sample(
-                        float(wave_times[c0]),
-                        **series_gauges(
-                            np.asarray(state.used),
-                            np.asarray(self.dc.allocatable),
-                            self.ec.vocab._r,
-                        ),
+                    assignments = host_assign
+                    scheduled = self.pods.bound_node == PAD
+                    placed = int((assignments[scheduled] >= 0).sum())
+                    preemptions = int(
+                        np.concatenate(
+                            [np.asarray(c[4]) for c in all_choices]
+                        ).sum()
                     )
-            with _tick("dispatch"), _chunk_ann(ci):
-                if use_rej:
-                    state, rej_dev, choices = self._chunk_fn_rej(
-                        self.dc, state, rej_dev,
-                        T.gather_slots(self.pods, idx[c0 : c0 + C]),
+                elif self.preemption:
+                    finals = np.concatenate([np.asarray(c[0]) for c in all_choices])
+                    ev_node = np.concatenate([np.asarray(c[1]) for c in all_choices])
+                    ev_tier = np.concatenate([np.asarray(c[2]) for c in all_choices])
+                    ev_total = np.concatenate([np.asarray(c[4]) for c in all_choices])
+                    assignments, placed = self._preemption_walk(
+                        idx, finals, ev_node, ev_tier
                     )
-                elif self.engine == "v3":
-                    if pager is not None:
-                        src, xsrc, lidx = pager.get(ci)
-                        state, choices = self.chunk_fn(
-                            self.dc, state, src, xsrc, lidx
-                        )
-                    else:
-                        state, choices = self.chunk_fn(
-                            self.dc, state, self._slot_src, self._extra_src,
-                            idx_chunks[ci],
-                        )
+                    preemptions = int(ev_total.sum())
                 else:
-                    state, choices = self.chunk_fn(
-                        self.dc, state,
-                        pager.get(ci)
-                        if pager is not None
-                        else T.gather_slots(self.pods, idx[c0 : c0 + C]),
-                    )
-            if pager is not None and c0 + C < idx.shape[0]:
-                # Stage the next page while this chunk is still on device.
-                pager.prefetch(ci + 1)
-            all_choices.append(choices)
-            if completions_on and self.preemption:
-                pending_fold = (idx[c0 : c0 + C], choices)
-            elif completions_on:
-                # Fold the PREVIOUS chunk's choices AFTER dispatching this
-                # one: the blocking fetch overlaps the in-flight chunk, and
-                # boundary b only ever sees chunks ≤ b−2 (the one-chunk
-                # slack; the greedy anchor implements the same rule).
-                if pending_fold is not None:
-                    with _tick("boundary_fold"):
-                        rows_p, ch_p = pending_fold
-                        ch = np.asarray(ch_p).reshape(rows_p.shape)
-                        v = rows_p >= 0
-                        host_assign[rows_p[v]] = ch[v]
-                pending_fold = (idx[c0 : c0 + C], choices)
-            if checkpoint_path and checkpoint_every and (ci + 1) % checkpoint_every == 0:
-                t_ck = time.perf_counter()
-                self._save_checkpoint(
-                    state, ci + 1, all_choices, checkpoint_path,
-                    released=(
-                        released
-                        if completions_on
-                        else np.zeros(self.pods.num_pods, bool)
-                    ),
+                    choices_np = np.asarray(jnp.concatenate(all_choices, axis=0))
+                    assignments = np.where(
+                        self.pods.bound_node >= 0, self.pods.bound_node, PAD
+                    ).astype(np.int32)
+                    flat_idx = idx.reshape(-1)
+                    flat_choice = choices_np.reshape(-1)
+                    valid = flat_idx >= 0
+                    assignments[flat_idx[valid]] = flat_choice[valid]
+                    placed = int((flat_choice[valid] >= 0).sum())
+
+                if tel is not None:
+                    # Plain replay: every placement is a wave placement — bound in
+                    # the same chunk it arrived in, zero virtual-time latency by
+                    # the chunk-granular convention (SURVEY.md §5).
+                    tel.bind_zero(placed)
+                    if use_rej:
+                        tel.rejection_bulk(
+                            spec_plugin_names(self.spec), np.asarray(rej_dev)
+                        )
+
+                if self.engine == "v3" and not use_rej:
+                    used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
+                else:
+                    used, mc, aa, pw = self._v2_to_host(state)
+                util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
+                pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
+                frag = fragmentation_gauges(
+                    self.ec.allocatable, used, self.pods.requests[pending_m],
+                    self.ec.vocab._r,
+                )
+                host_state = SchedState(
+                    used=used, match_count=mc, anti_active=aa, pref_wsum=pw,
+                    bound=assignments.copy(),
                 )
                 if rec is not None:
-                    rec.checkpoint(
-                        ci + 1, _file_bytes(checkpoint_path),
-                        time.perf_counter() - t_ck,
-                    )
-            if rec is not None:
-                if pager is not None and (
-                    pager.stalls > rec_stalls_seen
-                    or pager.invalidations > rec_inval_seen
-                ):
-                    rec.page(
-                        ci, pager.last_stall_s, pager.stalls,
-                        invalidations=pager.invalidations,
-                    )
-                    rec_stalls_seen = pager.stalls
-                    rec_inval_seen = pager.invalidations
-                pub_now = _dcn.publish_stats()
-                ck_pub = None
-                if pub_now != rec_pub:
-                    ck_pub = {
-                        "count": pub_now["count"] - rec_pub["count"],
-                        "wall_s": round(
-                            pub_now["wall_s"] - rec_pub["wall_s"], 6
-                        ),
-                        "bytes": pub_now["bytes"] - rec_pub["bytes"],
-                    }
-                    rec_pub = pub_now
-                retry_now = _dcn.retry_stats()
-                kv_retry = None
-                if retry_now != rec_retry:
-                    kv_retry = {
-                        "retries": retry_now["retries"]
-                        - rec_retry["retries"],
-                        "giveups": retry_now["giveups"]
-                        - rec_retry["giveups"],
-                        "backoff_s": round(
-                            retry_now["backoff_s"]
-                            - rec_retry["backoff_s"], 6
-                        ),
-                    }
-                    rec_retry = retry_now
-                rec.chunk(
-                    ci,
-                    t_virtual=(
-                        wave_times[c0] if wave_times is not None else None
-                    ),
-                    dispatched=int(rec_valid[ci]),
-                    placed=(
-                        int((host_assign >= 0).sum())
-                        if completions_on
-                        else None
-                    ),
-                    phase_acc=(
-                        tel.phases.acc if tel is not None else rec.phases.acc
-                    ),
-                    pager=pager,
-                    ckpt_publish=ck_pub,
-                    kv_retry=kv_retry,
-                )
-        with _tick("device_wait"):
-            jax.block_until_ready(all_choices[-1] if all_choices else state)
-        wall = time.perf_counter() - t0
-        with _tick("gather"):
-            if node_events:
-                self.dc = self.dc._replace(allocatable=jnp.asarray(saved_alloc))
-
-            preemptions = 0
-            to_schedule = int((idx >= 0).sum())
-            if self.preemption and completions_on:
-                # The incremental eviction-aware folds ARE the walk; finish
-                # the last pending chunk and read the result off the host
-                # bookkeeping (a fresh full walk would replay evictions
-                # against completed pods with the wrong interleaving).
-                if pending_fold is not None:
-                    rows_p, out_p = pending_fold
-                    preemption_walk(
-                        host_assign, rows_p,
-                        np.asarray(out_p[0]).reshape(rows_p.shape),
-                        np.asarray(out_p[1]), np.asarray(out_p[2]),
-                        self.static3.pod_tier, nongang, released=released,
-                    )
-                assignments = host_assign
-                scheduled = self.pods.bound_node == PAD
-                placed = int((assignments[scheduled] >= 0).sum())
-                preemptions = int(
-                    np.concatenate(
-                        [np.asarray(c[4]) for c in all_choices]
-                    ).sum()
-                )
-            elif self.preemption:
-                finals = np.concatenate([np.asarray(c[0]) for c in all_choices])
-                ev_node = np.concatenate([np.asarray(c[1]) for c in all_choices])
-                ev_tier = np.concatenate([np.asarray(c[2]) for c in all_choices])
-                ev_total = np.concatenate([np.asarray(c[4]) for c in all_choices])
-                assignments, placed = self._preemption_walk(
-                    idx, finals, ev_node, ev_tier
-                )
-                preemptions = int(ev_total.sum())
-            else:
-                choices_np = np.asarray(jnp.concatenate(all_choices, axis=0))
-                assignments = np.where(
-                    self.pods.bound_node >= 0, self.pods.bound_node, PAD
-                ).astype(np.int32)
-                flat_idx = idx.reshape(-1)
-                flat_choice = choices_np.reshape(-1)
-                valid = flat_idx >= 0
-                assignments[flat_idx[valid]] = flat_choice[valid]
-                placed = int((flat_choice[valid] >= 0).sum())
-
-            if tel is not None:
-                # Plain replay: every placement is a wave placement — bound in
-                # the same chunk it arrived in, zero virtual-time latency by
-                # the chunk-granular convention (SURVEY.md §5).
-                tel.bind_zero(placed)
-                if use_rej:
-                    tel.rejection_bulk(
-                        spec_plugin_names(self.spec), np.asarray(rej_dev)
-                    )
-
-            if self.engine == "v3" and not use_rej:
-                used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
-            else:
-                used, mc, aa, pw = self._v2_to_host(state)
-            util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
-            pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
-            frag = fragmentation_gauges(
-                self.ec.allocatable, used, self.pods.requests[pending_m],
-                self.ec.vocab._r,
+                    # Pager walls join the phase accumulators (keys only present
+                    # when paging is on AND the recorder observed them, so the
+                    # canonical PHASE_NAMES-only runs are unchanged).
+                    # ``pager_stall`` is the EXPOSED wall; ``pager_prefetch`` the
+                    # fetch wall itself — hidden under the round-19 thread,
+                    # loop-exposed without it.
+                    if pager is not None and tel is not None:
+                        tel.phases.add("pager_stall", pager.stall_s)
+                        tel.phases.add("pager_prefetch", pager.prefetch_wall_s)
+                    if rec_own:
+                        rec.close({"placed": int(placed)})
+                if pager is not None:
+                    pager.close()
+            return ReplayResult(
+                assignments=assignments,
+                placed=placed,
+                unschedulable=to_schedule - placed,
+                preemptions=preemptions,
+                attempts=to_schedule,
+                wall_clock_s=wall,
+                placements_per_sec=placed / wall if wall > 0 else 0.0,
+                virtual_makespan=float(self.pods.arrival.max()) if self.pods.num_pods else 0.0,
+                utilization=util,
+                state=host_state,
+                fragmentation=frag,
+                telemetry=tel.result() if tel is not None else None,
             )
-            host_state = SchedState(
-                used=used, match_count=mc, anti_active=aa, pref_wsum=pw,
-                bound=assignments.copy(),
-            )
-            if rec is not None:
-                # Pager walls join the phase accumulators (keys only present
-                # when paging is on AND the recorder observed them, so the
-                # canonical PHASE_NAMES-only runs are unchanged).
-                # ``pager_stall`` is the EXPOSED wall; ``pager_prefetch`` the
-                # fetch wall itself — hidden under the round-19 thread,
-                # loop-exposed without it.
-                if pager is not None and tel is not None:
-                    tel.phases.add("pager_stall", pager.stall_s)
-                    tel.phases.add("pager_prefetch", pager.prefetch_wall_s)
-                if rec_own:
-                    rec.close({"placed": int(placed)})
-            if pager is not None:
-                pager.close()
-        return ReplayResult(
-            assignments=assignments,
-            placed=placed,
-            unschedulable=to_schedule - placed,
-            preemptions=preemptions,
-            attempts=to_schedule,
-            wall_clock_s=wall,
-            placements_per_sec=placed / wall if wall > 0 else 0.0,
-            virtual_makespan=float(self.pods.arrival.max()) if self.pods.num_pods else 0.0,
-            utilization=util,
-            state=host_state,
-            fragmentation=frag,
-            telemetry=tel.result() if tel is not None else None,
-        )
 
 
 @register_strategy("jax")

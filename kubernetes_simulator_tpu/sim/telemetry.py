@@ -145,6 +145,20 @@ PHASE_NAMES = (
     "handback",
 )
 
+#: Every host span the program writes by a fixed name (``profiling.Span``,
+#: armed by ``KSIM_PROFILE_DIR``): the phases, each at every site that
+#: ticks its timer, a what-if block's checkpoint publication, and under a
+#: mesh the puts on the devices (inside ``stage``) and the placements'
+#: gather and fetch (inside ``handback``), which carry ``bytes=`` as the
+#: event's stats. Trace readers import these and hold no list of their own.
+HOST_SPAN_NAMES = PHASE_NAMES + ("checkpoint", "mesh_put", "mesh_fetch")
+#: ``chunk:<i>``: the dispatch of chunk ``i``, inside ``dispatch``.
+CHUNK_SPAN = "chunk"
+#: ``replay:<n>`` / ``whatif_run:<n>``: one root around everything the
+#: engine's ``n``-th ``replay()`` / ``run()`` does (0-based). Every other
+#: span of the call lies inside it on the calling thread.
+ROOT_SPANS = ("replay", "whatif_run")
+
 
 class PhaseTimers:
     """Accumulating wall-clock phase breakdown. ``tick(phase)`` returns a

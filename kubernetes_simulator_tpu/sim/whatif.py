@@ -44,7 +44,7 @@ from ..parallel.mesh import (
     spans_processes,
     tree_bytes,
 )
-from ..utils.profiling import annotate as _annotate
+from ..utils.profiling import make_span as _make_span
 from ..utils.profiling import register_call as _register_call
 from ..utils.profiling import shape_structs as _shape_structs
 from ..utils.profiling import stage
@@ -1156,6 +1156,7 @@ class WhatIfEngine:
         # reductions, utilization): built at first use, kept here, so a
         # second run() traces and compiles nothing.
         self._run_jits: Dict[str, Callable] = {}
+        self._run_calls = 0  # ordinal of the next run()'s root span
         self._rel_core: Optional[Callable] = None
         self._dev_rel_stage: Optional[dict] = None
         # Under a mesh: the scenario tables as put on the devices (static
@@ -1966,27 +1967,32 @@ class WhatIfEngine:
             fn = self._run_jits[name] = build()
         return fn
 
-    def _mesh_put(self, tree, replicate: bool = False):
+    def _mesh_put(self, span, tree, replicate: bool = False):
         """``tree`` put on the mesh's devices, its leading axis sharded over
         the scenario axis or (``replicate``) whole on each: the ``mesh_put``
-        span when profiling is armed, and its bytes (as they land on the
-        devices) and host seconds in the counters of the batch in flight
+        span when ``span`` (the run's) is armed, and its bytes (as they land
+        on the devices) and host seconds in the counters of the batch in flight
         (``summary()["mesh"]``). The seconds are those of the put calls,
         which return before a copy is done."""
-        with _annotate("mesh_put"):
+        n = tree_bytes(tree)
+        if replicate:
+            n *= int(self.mesh.devices.size)
+        with span.mark("mesh_put", bytes=n):
             t = time.perf_counter()
             out = (replicate_tree if replicate else shard_scenario_tree)(
                 self.mesh, tree
             )
             if self._mesh_batch is not None:
-                n = tree_bytes(tree)
-                if replicate:
-                    n *= int(self.mesh.devices.size)
                 self._mesh_batch["put_bytes"] += n
                 self._mesh_batch["put_s"] += time.perf_counter() - t
         return out
 
-    def _init_states(self) -> T.DevState:
+    def _init_states(self, span=None) -> T.DevState:
+        """The batch's initial [S, ...] state stack. ``span`` is the run's
+        span primitive (for ``mesh_put``); a caller outside ``run()`` gets
+        one of its own."""
+        if span is None:
+            span = _make_span()
         if self._state_one_mesh is not None:
             # Meshed, no fork: the one initial state is static and already
             # lies whole on every device; a batch only broadcasts it.
@@ -2019,7 +2025,7 @@ class WhatIfEngine:
                 lambda a: jnp.broadcast_to(a[None], (S,) + a.shape), s
             )
             if self.mesh is not None:
-                one = self._mesh_put(one, replicate=True)
+                one = self._mesh_put(span, one, replicate=True)
             states_fn = self._jit_once("states", lambda: (
                 jax.jit(_bc, out_shardings=scenario_sharding(self.mesh))
                 if self.mesh is not None
@@ -2320,9 +2326,10 @@ class WhatIfEngine:
         return np.asarray(x)
 
     def _handback(
-        self, wave_order, pos, count: bool = False
+        self, span, wave_order, pos, count: bool = False
     ) -> Tuple[np.ndarray, int, Optional[np.ndarray]]:
-        """(assignments [S, P], bytes copied, placed [S] or None): every
+        """(assignments [S, P], bytes copied, placed [S] or None), under the
+        run's ``span`` (a mesh's gather and fetch is ``mesh_fetch``): every
         task's node, task by task, and with ``count`` how many of each
         scenario's tasks have one, counted on the device (on the host the
         compare writes a [S, P] temporary: 21 ms at 1,024 x 10,000, PERF.md
@@ -2382,7 +2389,7 @@ class WhatIfEngine:
                     whatif_gather, out_shardings=replicated(self.mesh)
                 )
 
-            with _annotate("mesh_fetch"):
+            with span.mark("mesh_fetch", bytes=int(placed.nbytes)):
                 t = time.perf_counter()
                 gather = self._jit_once("gather", build_gather)
                 if self._mesh_programs is not None:
@@ -2911,1397 +2918,1376 @@ class WhatIfEngine:
         # Engine-level wall-clock phase breakdown (round 12): the what-if
         # chunk loop gets the same PHASE_NAMES timers the single-replay
         # paths carry, feeding heartbeats, the fleet telemetry merge, and
-        # the bench `phases` detail. ``stage`` runs from here to the first
-        # chunk's boundary work; it is opened and closed by hand so that
-        # the code between keeps its indentation.
-        import contextlib as _ctxlib
-
+        # the bench `phases` detail, and the same span primitive: with
+        # profiling armed every phase is a TraceAnnotation under one root
+        # ``whatif_run:<n>``, ``n`` this engine's call ordinal.
         from .telemetry import PhaseTimers, ReplayTelemetry
-        from ..utils.profiling import annotate as _prof_ann
-        from ..utils.profiling import profiling_active as _prof_on
 
-        run_phases = PhaseTimers()
-        _null = _ctxlib.nullcontext()
-        _prof = _prof_on()
-        _cann = (
-            (lambda i: _prof_ann(f"chunk:{i}")) if _prof else (lambda i: _null)
-        )
-        _pann = _prof_ann if _prof else (lambda name: _null)
-        _t_stage = time.perf_counter()
-        _stage_ann = _pann("stage")
-        _stage_ann.__enter__()
-        if self.mesh is not None:
-            self._mesh_batch = {
-                "put_bytes": 0, "put_s": 0.0, "fetch_bytes": 0, "fetch_s": 0.0,
-            }
-            # First meshed run of the engine: remember the chunk and
-            # hand-back programs as called, to count their collectives.
-            self._mesh_programs = {} if self._mesh_collectives is None else None
-        states = self._init_states()  # sets fork bookkeeping first
-        idx = self.waves.idx
-        if self._fork_waves_done:
-            idx = idx[self._fork_waves_done :]
-            if idx.shape[0] == 0:
-                idx = np.full((1, self.waves.wave_width), PAD, np.int32)
-        C = min(self.chunk_waves, max(idx.shape[0], 1))
-        pad_to = ((idx.shape[0] + C - 1) // C) * C
-        if pad_to != idx.shape[0]:
-            idx = np.concatenate([idx, np.full((pad_to - idx.shape[0], idx.shape[1]), PAD, np.int32)])
-        dc = self.sset.dc
-        if self.mesh is not None:
-            # The scenario tables are static per scenario batch: sharded
-            # over the devices at the engine's first run and kept (each
-            # run() used to deal them out again from device 0). The v3
-            # state stack is born sharded (_init_states).
-            if self._dc_mesh is None:
-                self._dc_mesh = self._mesh_put(dc)
-            dc = self._dc_mesh
-            if self.engine != "v3":
-                states = self._mesh_put(states)
-        comp_on = (
-            self.completions_on
-            and not self._completions_dev
-            and not self.kube  # BoundaryOps owns releases in kube mode
-        )
-        dev_rel = self._completions_dev
-        if dev_rel:
-            # Everything here is static per engine — staged ONCE and
-            # cached (a second run() pays zero host bucketing/upload).
-            if self._dev_rel_stage is None:
-                self._dev_rel_stage = self._stage_dev_rel(idx, C)
-            stg = self._dev_rel_stage
-            rel_calls, b_c = stg["rel_calls"], stg["b_c"]
-            # vassign is donated through the chunk calls — fresh per run.
-            # Under a mesh it materializes SHARDED (each device holds its
-            # scenarios' buffer; the broadcast never builds a global copy).
-            S = self.S
-            _bc = lambda a: jnp.broadcast_to(a[None], (S,) + a.shape)
-            vassign_d = self._jit_once("vassign", lambda: (
-                jax.jit(_bc, out_shardings=scenario_sharding(self.mesh))
-                if self.mesh is not None
-                else jax.jit(_bc)
-            ))(stg["va"])
-            # Largest number of rank rounds a release block needed so far: a
-            # running max per scenario beside the state, fetched at gather.
-            rounds_d = jnp.zeros(S, jnp.int32)
-            if self.mesh is not None:
-                rounds_d = self._mesh_put(rounds_d)
-            if self.retry_buffer:
-                RB = self.retry_buffer
-                mgt_d, durt_d = stg["mgt"], stg["durt"]
-                antit_d, preft_d, prefwt_d = (
-                    stg["antit"], stg["preft"], stg["prefwt"]
+        # The call's ONE look at the environment; _init_states, _mesh_put
+        # and _handback are handed it.
+        span = _make_span(PhaseTimers())
+        n, self._run_calls = self._run_calls, self._run_calls + 1
+        # Every span below is a ``with`` block, so no exception leaves one
+        # open. The body stays in this function: one more Python frame
+        # between the caller and the jitted calls makes every lowering a
+        # quarter slower (PERF.md §6, PR 35).
+        with span.mark(f"whatif_run:{n}"):
+            run_phases = span.timers
+            with span("stage"):
+                if self.mesh is not None:
+                    self._mesh_batch = {
+                        "put_bytes": 0, "put_s": 0.0, "fetch_bytes": 0, "fetch_s": 0.0,
+                    }
+                    # First meshed run of the engine: remember the chunk and
+                    # hand-back programs as called, to count their collectives.
+                    self._mesh_programs = {} if self._mesh_collectives is None else None
+                states = self._init_states(span)  # sets fork bookkeeping first
+                idx = self.waves.idx
+                if self._fork_waves_done:
+                    idx = idx[self._fork_waves_done :]
+                    if idx.shape[0] == 0:
+                        idx = np.full((1, self.waves.wave_width), PAD, np.int32)
+                C = min(self.chunk_waves, max(idx.shape[0], 1))
+                pad_to = ((idx.shape[0] + C - 1) // C) * C
+                if pad_to != idx.shape[0]:
+                    idx = np.concatenate([idx, np.full((pad_to - idx.shape[0], idx.shape[1]), PAD, np.int32)])
+                dc = self.sset.dc
+                if self.mesh is not None:
+                    # The scenario tables are static per scenario batch: sharded
+                    # over the devices at the engine's first run and kept (each
+                    # run() used to deal them out again from device 0). The v3
+                    # state stack is born sharded (_init_states).
+                    if self._dc_mesh is None:
+                        self._dc_mesh = self._mesh_put(span, dc)
+                    dc = self._dc_mesh
+                    if self.engine != "v3":
+                        states = self._mesh_put(span, states)
+                comp_on = (
+                    self.completions_on
+                    and not self._completions_dev
+                    and not self.kube  # BoundaryOps owns releases in kube mode
                 )
-                tbt_d, tb_c = stg["tbt"], stg["tb_c"]
-                sh_s = (
-                    (lambda a: jax.device_put(
-                        a, scenario_sharding(self.mesh)
-                    ))
-                    if self.mesh is not None
-                    else (lambda a: a)
-                )
-                zs = lambda fill, dt: sh_s(jnp.full(
-                    (self.S, RB), fill, dtype=dt
-                ))
-                rbuf_d = zs(PAD, jnp.int32)
-                rcount_d = sh_s(jnp.zeros(self.S, jnp.int32))
-                pend_id_d = zs(PAD, jnp.int32)
-                pend_node_d = zs(PAD, jnp.int32)
-                pend_relb_d = zs(0, jnp.int32)
-                rdrop_d = sh_s(jnp.zeros(self.S, jnp.int32))
-        pending_fold = None  # (rows, choices) of the not-yet-folded chunk
-        if comp_on:
-            from .jax_runtime import wave_start_times
-
-            wave_t = wave_start_times(self.pods, idx)
-            host_assign = np.tile(
-                np.where(
-                    self.pods.bound_node >= 0, self.pods.bound_node, PAD
-                ).astype(np.int32),
-                (self.S, 1),
-            )
-            if self._fork_choices is not None:
-                # Fold pre-fork placements except the SOURCE's last chunk,
-                # which stays pending — restoring the one-chunk slack the
-                # uninterrupted source run would be carrying here.
-                C_src = (
-                    self._fork_ck.outs[0].shape[0]
-                    if self._fork_ck.outs
-                    else 0
-                )
-                cut = (
-                    min((self._fork_ck.chunk_cursor - 1) * C_src,
-                        self._fork_waves_done)
-                    if C_src
-                    else self._fork_waves_done
-                )
-                cut = max(cut, 0)
-                pidx = self.waves.idx[:cut].reshape(-1)
-                pch = self._fork_choices[:cut].reshape(-1)
-                pv = pidx >= 0
-                host_assign[:, pidx[pv]] = pch[pv][None, :]
-                if cut < self._fork_waves_done:
-                    pending_fold = (
-                        self.waves.idx[cut : self._fork_waves_done],
-                        self._fork_choices[cut : self._fork_waves_done],
-                    )
-            released = np.zeros((self.S, self.pods.num_pods), bool)
-            if self.fork_checkpoint and self._fork_waves_done:
-                # The forked state already carries the source replay's
-                # pre-fork releases (completions default ON there): seed
-                # from the persisted mask, or reconstruct what the source
-                # applied at its own chunk boundaries — else the first
-                # post-fork boundary re-subtracts every pre-fork release,
-                # driving count planes negative (advisor round-2 medium).
-                ck = self._fork_ck
-                if ck.released is not None:
-                    rel0 = ck.released.astype(bool)
-                else:
-                    from .jax_runtime import rebuild_fork_state
-
-                    C_src = ck.outs[0].shape[0] if ck.outs else 0
-                    full_first = self.waves.idx[:, 0]
-                    full_t = np.where(
-                        full_first >= 0,
-                        self.pods.arrival[np.clip(full_first, 0, None)],
-                        np.inf,
-                    )
-                    if C_src:
-                        # The source padded ITS wave list to a multiple of
-                        # C_src — mirror that so chunk rows line up.
-                        # (slack=0: a maskless checkpoint predates the
-                        # slack rule — see JaxReplayEngine.replay.)
-                        idx_src = self.waves.idx
-                        need = ck.chunk_cursor * C_src
-                        if idx_src.shape[0] < need:
-                            idx_src = np.concatenate([
-                                idx_src,
-                                np.full(
-                                    (need - idx_src.shape[0], idx_src.shape[1]),
-                                    PAD, np.int32,
-                                ),
-                            ])
-                            full_t = np.concatenate([
-                                full_t,
-                                np.full(need - full_t.shape[0], np.inf),
-                            ])
-                        _, rel0 = rebuild_fork_state(
-                            self.pods, idx_src, C_src, ck.outs,
-                            full_t, ck.chunk_cursor, slack=0,
-                        )
-                    else:
-                        rel0 = np.zeros(self.pods.num_pods, bool)
-                released |= rel0[None, :]
-        dyn_sharded = self._dyn_dev
-        if dyn_sharded is not None and self.mesh is not None:
-            # Chunk-invariant: shard once, not per chunk.
-            dyn_sharded = shard_scenario_tree(self.mesh, dyn_sharded)
-        pol_d = None
-        if self._policies is not None:
-            # Per-scenario policy vectors (round 9): value-only input to
-            # the compiled chunk program — set_policies + run() reuses the
-            # executable. Sharded once (chunk-invariant) under a mesh.
-            pol_d = jnp.asarray(self._policies)
-            if self.mesh is not None:
-                pol_d = shard_scenario_tree(self.mesh, pol_d)
-        srcs = self._slot_srcs
-        idx_chunks = None
-        if srcs is not None and self._idx_chunks_mesh is not None:
-            idx_chunks = self._idx_chunks_mesh
-        elif srcs is not None:
-            idx_chunks = [
-                jnp.asarray(idx[c0 : c0 + C])
-                for c0 in range(0, idx.shape[0], C)
-            ]
-            if self.mesh is not None:
-                # Scenario-shared like the sources: whole on every device
-                # before the loop, not dealt out from device 0 by each
-                # dispatch; and, with no fork to cut the wave list, the
-                # same in every batch: kept.
-                idx_chunks = self._mesh_put(idx_chunks, replicate=True)
-                if not self.fork_checkpoint:
-                    self._idx_chunks_mesh = idx_chunks
-        pre_comp = comp_on and self.preemption
-        kbops = None
-        if self.kube:
-            # Per-scenario host mirrors over the PERTURBED clusters: the
-            # PostFilter pass then runs the CPU engine's arithmetic per
-            # scenario, and deltas land stacked (sim.boundary docstring).
-            from dataclasses import replace as cfg_replace
-
-            from ..framework.framework import (
-                FrameworkConfig as _FC,
-                SchedulerFramework,
-            )
-            from .boundary import BoundaryOps
-            from .waves import WaveBatch
-
-            cfgk = cfg_replace(
-                self._config if self._config is not None else _FC(),
-                enable_preemption=True,
-            )
-            from .telemetry import TelemetryCollector
-
-            wb = WaveBatch(idx=idx, wave_width=self.wave_width)
-            # One collector per scenario: the host mirrors are the only
-            # carrier of per-scenario bind times / rejection reasons.
-            ktel = [
-                TelemetryCollector(self.telemetry_cfg)
-                if self.telemetry_cfg.enabled
-                else None
-                for _ in range(self.S)
-            ]
-            kbops = [
-                BoundaryOps(
-                    ec_s, self.pods, SchedulerFramework(ec_s, self.pods, cfgk),
-                    wb, self.wave_width, C,
-                    retry_buffer=self.retry_buffer, kube=True, lazy=True,
-                    telemetry=ktel[si],
-                )
-                for si, ec_s in enumerate(self.sset.host_clusters(self.ec))
-            ]
-            from .jax_runtime import wave_start_times
-
-            kube_wave_t = wave_start_times(self.pods, idx)
-            # Lazy boundary sync (round 6): per chunk, fetch only a [S]
-            # non-gang failure count; the full choices fetch + mirror
-            # folds run AFTER the next dispatch (overlapped) unless some
-            # scenario's retry pass will actually read its mirror.
-            # Series telemetry disables the deferral entirely: every
-            # boundary SAMPLES the mirror's occupancy planes
-            # (BoundaryOps.boundary's tel.sample), so the fold must land
-            # pre-boundary at every chunk — otherwise WHICH boundaries
-            # see chunk ci-1's binds depends on the batch-mates' failure
-            # clustering, and the per-scenario gauge series would differ
-            # across DCN slicings of the same scenario list (round 15:
-            # survivor-rebuilt blocks must bit-match the dead process).
-            kwant_series = self.telemetry_cfg.want_series
-            kube_ng = jnp.asarray(self.pods.group_id == PAD)
-            if getattr(self, "_kfail_jit", None) is None:
-                self._kfail_jit = jax.jit(
-                    lambda ch, ix, ng: (
-                        (ix >= 0)[None]
-                        & (ch.reshape((ch.shape[0],) + ix.shape) < 0)
-                        & ng[jnp.clip(ix, 0)][None]
-                    ).sum(axis=(1, 2), dtype=jnp.int32)
-                )
-            kpending = None  # (ci, rows, choices_dev, nfail_dev[S])
-
-            def _kfold_pending():
-                nonlocal kpending
-                if kpending is not None:
-                    ci_p, rows_p, out_p, _nf = kpending
-                    # run_phases is bound later in run() — always before
-                    # the first call site (the chunk loop).
-                    with run_phases.tick("host_mirror"):
-                        ch = jax.device_get(out_p)
-                        for s in range(self.S):
-                            kbops[s].fold_chunk(ci_p, rows_p, ch[s])
-                    kpending = None
-
-            # Per-scenario timed timelines (chaos campaigns, round 7).
-            # The mirrors' EncodedCluster twins hold VIEWS of
-            # host_stacks["alloc"][s], so mutating the stack rows keeps
-            # host and (re-uploaded) device allocatable in lockstep.
-            hs = self.sset.host_stacks
-            ktimelines = self._timelines
-            kev_cursor = [0] * self.S
-            khas_events = any(ktimelines)
-            if khas_events:
-                ksaved_alloc = hs["alloc"].copy()  # [S, N, R] at t=0
-        if pre_comp:
-            # Eager eviction-aware folds (the single-replay round-4 rule,
-            # S-stacked): eviction events must land in the host
-            # bookkeeping BEFORE the next boundary's release decisions,
-            # so the one-chunk slack becomes an explicit bind-chunk gate
-            # instead of a fold lag.
-            from .jax_runtime import bind_chunk_of
-
-            chunk_of = bind_chunk_of(self.pods, idx, C)
-            nongang = self.pods.group_id == PAD
-        rel_bkt = None
-        if comp_on:
-            # Static release buckets (round 6): each pod's earliest
-            # eligible boundary — rel_time <= tb[b] and the one-chunk
-            # slack elapsed — is known up front, so boundary b scans only
-            # its own candidates ([S, K_b]) instead of an [S, P] mask.
-            # The dynamic residue (actually assigned, not yet released /
-            # evicted) is re-checked in _apply_releases; a pod still PAD
-            # at its bucket boundary stays PAD forever on these paths, so
-            # the single check is exact.
-            from .jax_runtime import bind_chunk_of as _bco
-
-            chunk_of_rel = _bco(self.pods, idx, C)
-            if self._fork_choices is not None and not pre_comp:
-                # Lagged-fold fork semantics: pre-fork folded pods can
-                # release from boundary 0 (floor -2+2), the source's
-                # pending last chunk from boundary 1 (floor -1+2 = 1).
-                # (Under pre_comp the eager gate keys off THIS run's idx
-                # only — pre-fork pods keep the 'absent' sentinel there,
-                # matching the eager mask exactly.)
-                C_src = (
-                    self._fork_ck.outs[0].shape[0]
-                    if self._fork_ck.outs
-                    else 0
-                )
-                cut = (
-                    min((self._fork_ck.chunk_cursor - 1) * C_src,
-                        self._fork_waves_done)
-                    if C_src
-                    else self._fork_waves_done
-                )
-                cut = max(cut, 0)
-                fidx = self.waves.idx[:cut].reshape(-1)
-                chunk_of_rel[fidx[fidx >= 0]] = -2
-                hidx = self.waves.idx[cut : self._fork_waves_done].reshape(-1)
-                chunk_of_rel[hidx[hidx >= 0]] = -1
-            tb_rel = wave_t[0::C]
-            nfin_rel = int(np.isfinite(tb_rel).sum())
-            b_rel = np.maximum(
-                np.searchsorted(
-                    tb_rel[:nfin_rel], self._rel_time, side="left"
-                ),
-                chunk_of_rel + 2,
-            )
-            rcand = np.nonzero(b_rel < nfin_rel)[0].astype(np.int64)
-            rcand = rcand[np.argsort(b_rel[rcand], kind="stable")]
-            roff = np.concatenate(
-                ([0], np.cumsum(
-                    np.bincount(b_rel[rcand], minlength=max(nfin_rel, 1))
-                ))
-            ).astype(np.int64)
-            rel_bkt = (rcand, roff, nfin_rel)
-        ppending = None  # pre_comp deferred chunk: dict, see closures
-        if pre_comp:
-            from .jax_runtime import preemption_walk
-
-            def _pre_walk():
-                """Fetch the [S] eviction summary of the deferred chunk
-                and walk ONLY the evicting scenarios (rare). Idempotent —
-                caches the fetches on the entry."""
-                e = ppending
-                if e is None or e["ev"] is not None:
-                    return
-                ev = np.asarray(jax.device_get(e["ev_d"])).astype(bool)
-                e["ev"] = ev
-                if ev.any():
-                    ch, evn, evt = jax.device_get(
-                        (e["out"][0], e["out"][1], e["out"][2])
-                    )
-                    e["ch"] = ch
-                    rows = e["rows"]
-                    for s in np.nonzero(ev)[0]:
-                        preemption_walk(
-                            host_assign[s], rows,
-                            ch[s].reshape(rows.shape), evn[s], evt[s],
-                            self.static3.pod_tier, nongang,
-                            released=released[s],
-                        )
-
-            def _pre_finish():
-                """Complete the deferred chunk: eviction walks (if not
-                already done), then ONE vectorized fold for every
-                no-eviction scenario — with zero events the walk is
-                exactly `assignments[rows] = finals`, so the bulk
-                assignment is bit-identical to S per-scenario walks."""
-                nonlocal ppending
-                e = ppending
-                if e is None:
-                    return
-                _pre_walk()
-                quiet = np.nonzero(~e["ev"])[0]
-                if quiet.size:
-                    ch = e["ch"]
-                    if ch is None:
-                        ch = np.asarray(jax.device_get(e["out"][0]))
-                    rows = e["rows"]
-                    flat = rows.reshape(-1)
-                    v = np.nonzero(flat >= 0)[0]
-                    if v.size:
-                        host_assign[np.ix_(quiet, flat[v])] = (
-                            ch.reshape(self.S, -1)[np.ix_(quiet, v)]
-                        )
-                ppending = None
-
-            if getattr(self, "_evany_jit", None) is None:
-                self._evany_jit = jax.jit(
-                    lambda evn: (evn >= 0).any(axis=1)
-                )
-        outs = []
-        # PUBLISH_STATS / RETRY_STATS / CRC_STATS are cumulative module
-        # state — snapshot them so the fleet phases below surface only
-        # THIS run's publications, KV retries and CRC fallbacks (a prior
-        # run in the same process must not leak into the phase map).
-        _ps_start = dcn.publish_stats()
-        _bg_start = dcn.bg_publish_stats()
-        _rs_start = dcn.retry_stats()
-        _cs_start = dcn.crc_stats()
-        n_chunks = len(range(0, idx.shape[0], C))
-        # Liveness heartbeats (round 12): one overwritten KV beacon per
-        # process on a chunk cadence — plain puts, never a gather. A
-        # recovery engine (round 15) beats too, under the CLAIMANT's own
-        # pid with state="recover" and the claimed block named, so a
-        # SECOND failure during recovery is attributed to the claimant.
-        recovering = self._dcn_recovery is not None
-        wq_info = self._dcn_wq_info  # block engine under the round-18 queue
-        hb_on = (
-            self._dcn_sliced or recovering
-        ) and dcn.heartbeat_every() > 0
-        hb_block = (self._proc_lo, self._proc_lo + self.S)
-        if wq_info is not None:
-            # Work-queue block engine: beats under our OWN pid with the
-            # lease named (dcn.heartbeat also renews the lease on every
-            # beat). wq_rate — chunks per wall second, the straggler
-            # watermark's input — is refreshed per beat in the loop.
-            hb_kw = dict(
-                state="spec" if wq_info.get("speculative") else "run",
-                extra={
-                    "wq_block": int(wq_info.get("block", -1)),
-                    "leased_blocks": 1,
-                    "queue_depth": int(wq_info.get("queue_depth", 0)),
-                    "wq_rate": 0.0,
-                },
-            )
-        elif recovering:
-            hb_kw = dict(
-                state="recover",
-                extra={
-                    "recovering_for": int(
-                        self._dcn_recovery.get("for_pid", -1)
-                    ),
-                    "recover_gen": int(self._dcn_recovery.get("gen", 0)),
-                },
-            )
-        else:
-            hb_kw = {}
-        # Recoverable work-queue (round 15, parallel.dcn): on a chunk
-        # cadence, publish a compressed host snapshot of the loop
-        # carriers so a survivor can resume THIS block mid-replay after
-        # a host loss. Supported on the device-carrier paths (plain
-        # v3/v2 and device-release ± retry, where the whole block state
-        # lives in `states`/`vassign`/retry tensors plus `outs`); the
-        # host-fold modes (completions host path, kube mirrors) carry
-        # state in per-scenario host structures instead — a claimed
-        # block there re-executes from chunk 0, still byte-identical.
-        ck_ok = kbops is None and not comp_on
-        # Queue block engines checkpoint too (under the block's own
-        # negative epoch) — that is what a speculator or thief resumes.
-        ck_every = (
-            dcn.ckpt_every()
-            if ck_ok
-            and (
-                (self._dcn_sliced and not self._dcn_spare)
-                or wq_info is not None
-            )
-            else 0
-        )
-
-        def _carriers():
-            c = {"states": states}
-            if dev_rel:
-                c["vassign"] = vassign_d
-                if self.retry_buffer:
-                    c["retry"] = (
-                        rbuf_d, rcount_d, pend_id_d, pend_node_d,
-                        pend_relb_d, rdrop_d,
-                    )
-            return c
-
-        _ck_sig = [
-            self.engine, bool(dev_rel), int(self.retry_buffer),
-            int(self.S), int(C), int(n_chunks),
-        ]
-        start_ci = 0
-        # for_pid < 0 is a generation-0 queue lease: nobody ran this block
-        # before us, so there is no checkpoint to resume — execute from
-        # chunk 0 (steals/speculation name the holder via for_pid >= 0).
-        resume_pid, resume_epoch = -1, None
-        if recovering and ck_ok:
-            resume_pid = int(self._dcn_recovery.get("for_pid", -1))
-            resume_epoch = self._dcn_recovery.get("epoch")
-        elif (
-            ck_ok
-            and ck_every > 0
-            and wq_info is None
-            and self._dcn_sliced
-            and not self._dcn_spare
-            and dcn.resume_enabled()
-            and dcn.durable_dir()
-        ):
-            # Durable ground (round 20): a restarted fleet (dcn_launch
-            # --resume after whole-fleet death) seeds each process's OWN
-            # static block from its newest complete durable checkpoint.
-            # Epoch defaults to checkpoint_epoch(), which matches the
-            # dead fleet's — the gather sequence replays
-            # deterministically — and load_checkpoint merges the journal
-            # mirror into its candidate walk, so the torn-newest-cursor
-            # fallback applies to journal files too.
-            resume_pid = dcn.process_info()[1]
-        if resume_pid >= 0:
-            from ..utils.metrics import log as _log
-            from .jax_runtime import restore_carriers
-
-            dead = resume_pid
-            # Round 17: walk the dead process's checkpoints newest-first.
-            # dcn.load_checkpoint already skips CRC-invalid blobs; this
-            # loop additionally falls back past blobs that validate on
-            # the wire but turn out unusable here (signature or carrier-
-            # shape mismatch), via `before_cursor`, instead of giving up
-            # on the whole resume.
-            before = None
-            while True:
-                ckd = dcn.load_checkpoint(
-                    dead,
-                    epoch=resume_epoch,
-                    before_cursor=before,
-                )
-                if ckd is None:
-                    if before is not None:
-                        _log.warning(
-                            "dcn: no usable checkpoint left for process "
-                            "%d — re-executing its block from chunk 0",
-                            dead,
-                        )
-                    break
-                before = int(ckd["cursor"])
-                pay = ckd["payload"]
-                if not (
-                    isinstance(pay, dict)
-                    and tuple(ckd["block"])
-                    == (int(hb_block[0]), int(hb_block[1]))
-                    and pay.get("sig") == _ck_sig
-                ):
-                    _log.warning(
-                        "dcn: ignoring mismatched checkpoint (cursor %d) "
-                        "for process %d — trying an older one",
-                        before, dead,
-                    )
-                    continue
-                try:
-                    carr = restore_carriers(_carriers(), pay["leaves"])
-                except ValueError as e:
-                    _log.warning(
-                        "dcn: process %d's checkpoint at cursor %d is "
-                        "unusable (%s) — trying an older one",
-                        dead, before, e,
-                    )
-                    continue
-                states = carr["states"]
+                dev_rel = self._completions_dev
                 if dev_rel:
-                    vassign_d = carr["vassign"]
+                    # Everything here is static per engine — staged ONCE and
+                    # cached (a second run() pays zero host bucketing/upload).
+                    if self._dev_rel_stage is None:
+                        self._dev_rel_stage = self._stage_dev_rel(idx, C)
+                    stg = self._dev_rel_stage
+                    rel_calls, b_c = stg["rel_calls"], stg["b_c"]
+                    # vassign is donated through the chunk calls — fresh per run.
+                    # Under a mesh it materializes SHARDED (each device holds its
+                    # scenarios' buffer; the broadcast never builds a global copy).
+                    S = self.S
+                    _bc = lambda a: jnp.broadcast_to(a[None], (S,) + a.shape)
+                    vassign_d = self._jit_once("vassign", lambda: (
+                        jax.jit(_bc, out_shardings=scenario_sharding(self.mesh))
+                        if self.mesh is not None
+                        else jax.jit(_bc)
+                    ))(stg["va"])
+                    # Largest number of rank rounds a release block needed so far: a
+                    # running max per scenario beside the state, fetched at gather.
+                    rounds_d = jnp.zeros(S, jnp.int32)
+                    if self.mesh is not None:
+                        rounds_d = self._mesh_put(span, rounds_d)
                     if self.retry_buffer:
-                        (
-                            rbuf_d, rcount_d, pend_id_d, pend_node_d,
-                            pend_relb_d, rdrop_d,
-                        ) = carr["retry"]
-                outs = list(pay["outs"])
-                start_ci = int(pay["cursor"])
-                _log.warning(
-                    "dcn: resumed process %d's block [%d, %d) from "
-                    "its newest checkpoint at chunk %d/%d",
-                    dead, hb_block[0], hb_block[1], start_ci, n_chunks,
-                )
-                break
-        # Chunks this engine will actually execute (resumes skip the
-        # carried prefix) — the queue driver charges these to
-        # spec_wasted_chunks when a speculative duplicate is discarded.
-        self._wq_exec_chunks = max(n_chunks - start_ci, 0)
-        # With profiling armed, the v3 chunk program and each release
-        # bucket's program go to utils.profiling.stage_tables by module
-        # name, on the shapes of their first call (taken before the call:
-        # it donates its buffers).
-        registered: set = set()
+                        RB = self.retry_buffer
+                        mgt_d, durt_d = stg["mgt"], stg["durt"]
+                        antit_d, preft_d, prefwt_d = (
+                            stg["antit"], stg["preft"], stg["prefwt"]
+                        )
+                        tbt_d, tb_c = stg["tbt"], stg["tb_c"]
+                        sh_s = (
+                            (lambda a: jax.device_put(
+                                a, scenario_sharding(self.mesh)
+                            ))
+                            if self.mesh is not None
+                            else (lambda a: a)
+                        )
+                        zs = lambda fill, dt: sh_s(jnp.full(
+                            (self.S, RB), fill, dtype=dt
+                        ))
+                        rbuf_d = zs(PAD, jnp.int32)
+                        rcount_d = sh_s(jnp.zeros(self.S, jnp.int32))
+                        pend_id_d = zs(PAD, jnp.int32)
+                        pend_node_d = zs(PAD, jnp.int32)
+                        pend_relb_d = zs(0, jnp.int32)
+                        rdrop_d = sh_s(jnp.zeros(self.S, jnp.int32))
+                pending_fold = None  # (rows, choices) of the not-yet-folded chunk
+                if comp_on:
+                    from .jax_runtime import wave_start_times
 
-        def _reg(fn, args):
-            if self._mesh_programs is not None:
-                self._mesh_programs.setdefault(
-                    "chunk", (fn, _shape_structs(args))
-                )
-            if _prof and fn not in registered:
-                registered.add(fn)
-                _register_call(fn, args)
-
-        rel_buckets: set = set()  # the pow2 release widths this run used
-        t0 = time.perf_counter()
-        run_phases.add("stage", t0 - _t_stage)
-        _stage_ann.__exit__(None, None, None)
-        for ci, c0 in enumerate(range(0, idx.shape[0], C)):
-            if ci < start_ci:
-                continue  # chunks already carried by the resumed state
-            if ck_every and ci and ci % ck_every == 0:
-                from .jax_runtime import checkpoint_payload
-
-                # Round-19 split: only the device→host snapshot stays on
-                # the loop thread (it must see the state exactly as of
-                # chunk ci); encode + CRC framing + the retried KV sets
-                # — and the round-20 durable-journal mirror — ride the
-                # single-flight publisher thread, newest-wins. Drained
-                # before the final gather below — the one place this
-                # leg needs a durable cursor.
-                with run_phases.tick("checkpoint"):
-                    dcn.publish_checkpoint_async(
-                        ci,
-                        checkpoint_payload(ci, _ck_sig, _carriers(), outs),
-                        hb_block,
-                        epoch=(self._dcn_recovery or {}).get("epoch"),
+                    wave_t = wave_start_times(self.pods, idx)
+                    host_assign = np.tile(
+                        np.where(
+                            self.pods.bound_node >= 0, self.pods.bound_node, PAD
+                        ).astype(np.int32),
+                        (self.S, 1),
                     )
-            if hb_on:
-                if wq_info is not None and ci > start_ci:
-                    wall_now = time.perf_counter() - t0
-                    if wall_now > 0:
-                        hb_kw["extra"]["wq_rate"] = round(
-                            (ci - start_ci) / wall_now, 4
+                    if self._fork_choices is not None:
+                        # Fold pre-fork placements except the SOURCE's last chunk,
+                        # which stays pending — restoring the one-chunk slack the
+                        # uninterrupted source run would be carrying here.
+                        C_src = (
+                            self._fork_ck.outs[0].shape[0]
+                            if self._fork_ck.outs
+                            else 0
                         )
-                dcn.maybe_heartbeat(
-                    ci - 1,
-                    total=n_chunks,
-                    block=hb_block,
-                    wall_s=time.perf_counter() - t0,
-                    phases=run_phases.acc,
-                    **hb_kw,
+                        cut = (
+                            min((self._fork_ck.chunk_cursor - 1) * C_src,
+                                self._fork_waves_done)
+                            if C_src
+                            else self._fork_waves_done
+                        )
+                        cut = max(cut, 0)
+                        pidx = self.waves.idx[:cut].reshape(-1)
+                        pch = self._fork_choices[:cut].reshape(-1)
+                        pv = pidx >= 0
+                        host_assign[:, pidx[pv]] = pch[pv][None, :]
+                        if cut < self._fork_waves_done:
+                            pending_fold = (
+                                self.waves.idx[cut : self._fork_waves_done],
+                                self._fork_choices[cut : self._fork_waves_done],
+                            )
+                    released = np.zeros((self.S, self.pods.num_pods), bool)
+                    if self.fork_checkpoint and self._fork_waves_done:
+                        # The forked state already carries the source replay's
+                        # pre-fork releases (completions default ON there): seed
+                        # from the persisted mask, or reconstruct what the source
+                        # applied at its own chunk boundaries — else the first
+                        # post-fork boundary re-subtracts every pre-fork release,
+                        # driving count planes negative (advisor round-2 medium).
+                        ck = self._fork_ck
+                        if ck.released is not None:
+                            rel0 = ck.released.astype(bool)
+                        else:
+                            from .jax_runtime import rebuild_fork_state
+
+                            C_src = ck.outs[0].shape[0] if ck.outs else 0
+                            full_first = self.waves.idx[:, 0]
+                            full_t = np.where(
+                                full_first >= 0,
+                                self.pods.arrival[np.clip(full_first, 0, None)],
+                                np.inf,
+                            )
+                            if C_src:
+                                # The source padded ITS wave list to a multiple of
+                                # C_src — mirror that so chunk rows line up.
+                                # (slack=0: a maskless checkpoint predates the
+                                # slack rule — see JaxReplayEngine.replay.)
+                                idx_src = self.waves.idx
+                                need = ck.chunk_cursor * C_src
+                                if idx_src.shape[0] < need:
+                                    idx_src = np.concatenate([
+                                        idx_src,
+                                        np.full(
+                                            (need - idx_src.shape[0], idx_src.shape[1]),
+                                            PAD, np.int32,
+                                        ),
+                                    ])
+                                    full_t = np.concatenate([
+                                        full_t,
+                                        np.full(need - full_t.shape[0], np.inf),
+                                    ])
+                                _, rel0 = rebuild_fork_state(
+                                    self.pods, idx_src, C_src, ck.outs,
+                                    full_t, ck.chunk_cursor, slack=0,
+                                )
+                            else:
+                                rel0 = np.zeros(self.pods.num_pods, bool)
+                        released |= rel0[None, :]
+                dyn_sharded = self._dyn_dev
+                if dyn_sharded is not None and self.mesh is not None:
+                    # Chunk-invariant: shard once, not per chunk.
+                    dyn_sharded = shard_scenario_tree(self.mesh, dyn_sharded)
+                pol_d = None
+                if self._policies is not None:
+                    # Per-scenario policy vectors (round 9): value-only input to
+                    # the compiled chunk program — set_policies + run() reuses the
+                    # executable. Sharded once (chunk-invariant) under a mesh.
+                    pol_d = jnp.asarray(self._policies)
+                    if self.mesh is not None:
+                        pol_d = shard_scenario_tree(self.mesh, pol_d)
+                srcs = self._slot_srcs
+                idx_chunks = None
+                if srcs is not None and self._idx_chunks_mesh is not None:
+                    idx_chunks = self._idx_chunks_mesh
+                elif srcs is not None:
+                    idx_chunks = [
+                        jnp.asarray(idx[c0 : c0 + C])
+                        for c0 in range(0, idx.shape[0], C)
+                    ]
+                    if self.mesh is not None:
+                        # Scenario-shared like the sources: whole on every device
+                        # before the loop, not dealt out from device 0 by each
+                        # dispatch; and, with no fork to cut the wave list, the
+                        # same in every batch: kept.
+                        idx_chunks = self._mesh_put(span, idx_chunks, replicate=True)
+                        if not self.fork_checkpoint:
+                            self._idx_chunks_mesh = idx_chunks
+                pre_comp = comp_on and self.preemption
+                kbops = None
+                if self.kube:
+                    # Per-scenario host mirrors over the PERTURBED clusters: the
+                    # PostFilter pass then runs the CPU engine's arithmetic per
+                    # scenario, and deltas land stacked (sim.boundary docstring).
+                    from dataclasses import replace as cfg_replace
+
+                    from ..framework.framework import (
+                        FrameworkConfig as _FC,
+                        SchedulerFramework,
+                    )
+                    from .boundary import BoundaryOps
+                    from .waves import WaveBatch
+
+                    cfgk = cfg_replace(
+                        self._config if self._config is not None else _FC(),
+                        enable_preemption=True,
+                    )
+                    from .telemetry import TelemetryCollector
+
+                    wb = WaveBatch(idx=idx, wave_width=self.wave_width)
+                    # One collector per scenario: the host mirrors are the only
+                    # carrier of per-scenario bind times / rejection reasons.
+                    ktel = [
+                        TelemetryCollector(self.telemetry_cfg)
+                        if self.telemetry_cfg.enabled
+                        else None
+                        for _ in range(self.S)
+                    ]
+                    kbops = [
+                        BoundaryOps(
+                            ec_s, self.pods, SchedulerFramework(ec_s, self.pods, cfgk),
+                            wb, self.wave_width, C,
+                            retry_buffer=self.retry_buffer, kube=True, lazy=True,
+                            telemetry=ktel[si],
+                        )
+                        for si, ec_s in enumerate(self.sset.host_clusters(self.ec))
+                    ]
+                    from .jax_runtime import wave_start_times
+
+                    kube_wave_t = wave_start_times(self.pods, idx)
+                    # Lazy boundary sync (round 6): per chunk, fetch only a [S]
+                    # non-gang failure count; the full choices fetch + mirror
+                    # folds run AFTER the next dispatch (overlapped) unless some
+                    # scenario's retry pass will actually read its mirror.
+                    # Series telemetry disables the deferral entirely: every
+                    # boundary SAMPLES the mirror's occupancy planes
+                    # (BoundaryOps.boundary's tel.sample), so the fold must land
+                    # pre-boundary at every chunk — otherwise WHICH boundaries
+                    # see chunk ci-1's binds depends on the batch-mates' failure
+                    # clustering, and the per-scenario gauge series would differ
+                    # across DCN slicings of the same scenario list (round 15:
+                    # survivor-rebuilt blocks must bit-match the dead process).
+                    kwant_series = self.telemetry_cfg.want_series
+                    kube_ng = jnp.asarray(self.pods.group_id == PAD)
+                    if getattr(self, "_kfail_jit", None) is None:
+                        self._kfail_jit = jax.jit(
+                            lambda ch, ix, ng: (
+                                (ix >= 0)[None]
+                                & (ch.reshape((ch.shape[0],) + ix.shape) < 0)
+                                & ng[jnp.clip(ix, 0)][None]
+                            ).sum(axis=(1, 2), dtype=jnp.int32)
+                        )
+                    kpending = None  # (ci, rows, choices_dev, nfail_dev[S])
+
+                    def _kfold_pending():
+                        nonlocal kpending
+                        if kpending is not None:
+                            ci_p, rows_p, out_p, _nf = kpending
+                            with span("host_mirror"):
+                                ch = jax.device_get(out_p)
+                                for s in range(self.S):
+                                    kbops[s].fold_chunk(ci_p, rows_p, ch[s])
+                            kpending = None
+
+                    # Per-scenario timed timelines (chaos campaigns, round 7).
+                    # The mirrors' EncodedCluster twins hold VIEWS of
+                    # host_stacks["alloc"][s], so mutating the stack rows keeps
+                    # host and (re-uploaded) device allocatable in lockstep.
+                    hs = self.sset.host_stacks
+                    ktimelines = self._timelines
+                    kev_cursor = [0] * self.S
+                    khas_events = any(ktimelines)
+                    if khas_events:
+                        ksaved_alloc = hs["alloc"].copy()  # [S, N, R] at t=0
+                if pre_comp:
+                    # Eager eviction-aware folds (the single-replay round-4 rule,
+                    # S-stacked): eviction events must land in the host
+                    # bookkeeping BEFORE the next boundary's release decisions,
+                    # so the one-chunk slack becomes an explicit bind-chunk gate
+                    # instead of a fold lag.
+                    from .jax_runtime import bind_chunk_of
+
+                    chunk_of = bind_chunk_of(self.pods, idx, C)
+                    nongang = self.pods.group_id == PAD
+                rel_bkt = None
+                if comp_on:
+                    # Static release buckets (round 6): each pod's earliest
+                    # eligible boundary — rel_time <= tb[b] and the one-chunk
+                    # slack elapsed — is known up front, so boundary b scans only
+                    # its own candidates ([S, K_b]) instead of an [S, P] mask.
+                    # The dynamic residue (actually assigned, not yet released /
+                    # evicted) is re-checked in _apply_releases; a pod still PAD
+                    # at its bucket boundary stays PAD forever on these paths, so
+                    # the single check is exact.
+                    from .jax_runtime import bind_chunk_of as _bco
+
+                    chunk_of_rel = _bco(self.pods, idx, C)
+                    if self._fork_choices is not None and not pre_comp:
+                        # Lagged-fold fork semantics: pre-fork folded pods can
+                        # release from boundary 0 (floor -2+2), the source's
+                        # pending last chunk from boundary 1 (floor -1+2 = 1).
+                        # (Under pre_comp the eager gate keys off THIS run's idx
+                        # only — pre-fork pods keep the 'absent' sentinel there,
+                        # matching the eager mask exactly.)
+                        C_src = (
+                            self._fork_ck.outs[0].shape[0]
+                            if self._fork_ck.outs
+                            else 0
+                        )
+                        cut = (
+                            min((self._fork_ck.chunk_cursor - 1) * C_src,
+                                self._fork_waves_done)
+                            if C_src
+                            else self._fork_waves_done
+                        )
+                        cut = max(cut, 0)
+                        fidx = self.waves.idx[:cut].reshape(-1)
+                        chunk_of_rel[fidx[fidx >= 0]] = -2
+                        hidx = self.waves.idx[cut : self._fork_waves_done].reshape(-1)
+                        chunk_of_rel[hidx[hidx >= 0]] = -1
+                    tb_rel = wave_t[0::C]
+                    nfin_rel = int(np.isfinite(tb_rel).sum())
+                    b_rel = np.maximum(
+                        np.searchsorted(
+                            tb_rel[:nfin_rel], self._rel_time, side="left"
+                        ),
+                        chunk_of_rel + 2,
+                    )
+                    rcand = np.nonzero(b_rel < nfin_rel)[0].astype(np.int64)
+                    rcand = rcand[np.argsort(b_rel[rcand], kind="stable")]
+                    roff = np.concatenate(
+                        ([0], np.cumsum(
+                            np.bincount(b_rel[rcand], minlength=max(nfin_rel, 1))
+                        ))
+                    ).astype(np.int64)
+                    rel_bkt = (rcand, roff, nfin_rel)
+                ppending = None  # pre_comp deferred chunk: dict, see closures
+                if pre_comp:
+                    from .jax_runtime import preemption_walk
+
+                    def _pre_walk():
+                        """Fetch the [S] eviction summary of the deferred chunk
+                        and walk ONLY the evicting scenarios (rare). Idempotent —
+                        caches the fetches on the entry."""
+                        e = ppending
+                        if e is None or e["ev"] is not None:
+                            return
+                        ev = np.asarray(jax.device_get(e["ev_d"])).astype(bool)
+                        e["ev"] = ev
+                        if ev.any():
+                            ch, evn, evt = jax.device_get(
+                                (e["out"][0], e["out"][1], e["out"][2])
+                            )
+                            e["ch"] = ch
+                            rows = e["rows"]
+                            for s in np.nonzero(ev)[0]:
+                                preemption_walk(
+                                    host_assign[s], rows,
+                                    ch[s].reshape(rows.shape), evn[s], evt[s],
+                                    self.static3.pod_tier, nongang,
+                                    released=released[s],
+                                )
+
+                    def _pre_finish():
+                        """Complete the deferred chunk: eviction walks (if not
+                        already done), then ONE vectorized fold for every
+                        no-eviction scenario — with zero events the walk is
+                        exactly `assignments[rows] = finals`, so the bulk
+                        assignment is bit-identical to S per-scenario walks."""
+                        nonlocal ppending
+                        e = ppending
+                        if e is None:
+                            return
+                        _pre_walk()
+                        quiet = np.nonzero(~e["ev"])[0]
+                        if quiet.size:
+                            ch = e["ch"]
+                            if ch is None:
+                                ch = np.asarray(jax.device_get(e["out"][0]))
+                            rows = e["rows"]
+                            flat = rows.reshape(-1)
+                            v = np.nonzero(flat >= 0)[0]
+                            if v.size:
+                                host_assign[np.ix_(quiet, flat[v])] = (
+                                    ch.reshape(self.S, -1)[np.ix_(quiet, v)]
+                                )
+                        ppending = None
+
+                    if getattr(self, "_evany_jit", None) is None:
+                        self._evany_jit = jax.jit(
+                            lambda evn: (evn >= 0).any(axis=1)
+                        )
+                outs = []
+                # PUBLISH_STATS / RETRY_STATS / CRC_STATS are cumulative module
+                # state — snapshot them so the fleet phases below surface only
+                # THIS run's publications, KV retries and CRC fallbacks (a prior
+                # run in the same process must not leak into the phase map).
+                _ps_start = dcn.publish_stats()
+                _bg_start = dcn.bg_publish_stats()
+                _rs_start = dcn.retry_stats()
+                _cs_start = dcn.crc_stats()
+                n_chunks = len(range(0, idx.shape[0], C))
+                # Liveness heartbeats (round 12): one overwritten KV beacon per
+                # process on a chunk cadence — plain puts, never a gather. A
+                # recovery engine (round 15) beats too, under the CLAIMANT's own
+                # pid with state="recover" and the claimed block named, so a
+                # SECOND failure during recovery is attributed to the claimant.
+                recovering = self._dcn_recovery is not None
+                wq_info = self._dcn_wq_info  # block engine under the round-18 queue
+                hb_on = (
+                    self._dcn_sliced or recovering
+                ) and dcn.heartbeat_every() > 0
+                hb_block = (self._proc_lo, self._proc_lo + self.S)
+                if wq_info is not None:
+                    # Work-queue block engine: beats under our OWN pid with the
+                    # lease named (dcn.heartbeat also renews the lease on every
+                    # beat). wq_rate — chunks per wall second, the straggler
+                    # watermark's input — is refreshed per beat in the loop.
+                    hb_kw = dict(
+                        state="spec" if wq_info.get("speculative") else "run",
+                        extra={
+                            "wq_block": int(wq_info.get("block", -1)),
+                            "leased_blocks": 1,
+                            "queue_depth": int(wq_info.get("queue_depth", 0)),
+                            "wq_rate": 0.0,
+                        },
+                    )
+                elif recovering:
+                    hb_kw = dict(
+                        state="recover",
+                        extra={
+                            "recovering_for": int(
+                                self._dcn_recovery.get("for_pid", -1)
+                            ),
+                            "recover_gen": int(self._dcn_recovery.get("gen", 0)),
+                        },
+                    )
+                else:
+                    hb_kw = {}
+                # Recoverable work-queue (round 15, parallel.dcn): on a chunk
+                # cadence, publish a compressed host snapshot of the loop
+                # carriers so a survivor can resume THIS block mid-replay after
+                # a host loss. Supported on the device-carrier paths (plain
+                # v3/v2 and device-release ± retry, where the whole block state
+                # lives in `states`/`vassign`/retry tensors plus `outs`); the
+                # host-fold modes (completions host path, kube mirrors) carry
+                # state in per-scenario host structures instead — a claimed
+                # block there re-executes from chunk 0, still byte-identical.
+                ck_ok = kbops is None and not comp_on
+                # Queue block engines checkpoint too (under the block's own
+                # negative epoch) — that is what a speculator or thief resumes.
+                ck_every = (
+                    dcn.ckpt_every()
+                    if ck_ok
+                    and (
+                        (self._dcn_sliced and not self._dcn_spare)
+                        or wq_info is not None
+                    )
+                    else 0
                 )
-            if kbops is not None:
-                t_now = kube_wave_t[c0]
-                due_any = khas_events and any(
-                    kev_cursor[s] < len(ktimelines[s])
-                    and ktimelines[s][kev_cursor[s]].time <= t_now
-                    for s in range(self.S)
-                )
-                if kpending is not None and (
-                    kwant_series
-                    or np.asarray(kpending[3]).any()
-                    or any(b.retry_q for b in kbops)
-                    or due_any
+
+                def _carriers():
+                    c = {"states": states}
+                    if dev_rel:
+                        c["vassign"] = vassign_d
+                        if self.retry_buffer:
+                            c["retry"] = (
+                                rbuf_d, rcount_d, pend_id_d, pend_node_d,
+                                pend_relb_d, rdrop_d,
+                            )
+                    return c
+
+                _ck_sig = [
+                    self.engine, bool(dev_rel), int(self.retry_buffer),
+                    int(self.S), int(C), int(n_chunks),
+                ]
+                start_ci = 0
+                # for_pid < 0 is a generation-0 queue lease: nobody ran this block
+                # before us, so there is no checkpoint to resume — execute from
+                # chunk 0 (steals/speculation name the holder via for_pid >= 0).
+                resume_pid, resume_epoch = -1, None
+                if recovering and ck_ok:
+                    resume_pid = int(self._dcn_recovery.get("for_pid", -1))
+                    resume_epoch = self._dcn_recovery.get("epoch")
+                elif (
+                    ck_ok
+                    and ck_every > 0
+                    and wq_info is None
+                    and self._dcn_sliced
+                    and not self._dcn_spare
+                    and dcn.resume_enabled()
+                    and dcn.durable_dir()
                 ):
-                    # Some scenario's retry pass will read its mirror —
-                    # or a due node_down must evict against bookkeeping
-                    # current through chunk ci-1: resolve the deferred
-                    # fold (all scenarios — failures cluster, and the
-                    # boundary pass needs every mirror current anyway).
+                    # Durable ground (round 20): a restarted fleet (dcn_launch
+                    # --resume after whole-fleet death) seeds each process's OWN
+                    # static block from its newest complete durable checkpoint.
+                    # Epoch defaults to checkpoint_epoch(), which matches the
+                    # dead fleet's — the gather sequence replays
+                    # deterministically — and load_checkpoint merges the journal
+                    # mirror into its candidate walk, so the torn-newest-cursor
+                    # fallback applies to journal files too.
+                    resume_pid = dcn.process_info()[1]
+                if resume_pid >= 0:
+                    from ..utils.metrics import log as _log
+                    from .jax_runtime import restore_carriers
+
+                    dead = resume_pid
+                    # Round 17: walk the dead process's checkpoints newest-first.
+                    # dcn.load_checkpoint already skips CRC-invalid blobs; this
+                    # loop additionally falls back past blobs that validate on
+                    # the wire but turn out unusable here (signature or carrier-
+                    # shape mismatch), via `before_cursor`, instead of giving up
+                    # on the whole resume.
+                    before = None
+                    while True:
+                        ckd = dcn.load_checkpoint(
+                            dead,
+                            epoch=resume_epoch,
+                            before_cursor=before,
+                        )
+                        if ckd is None:
+                            if before is not None:
+                                _log.warning(
+                                    "dcn: no usable checkpoint left for process "
+                                    "%d — re-executing its block from chunk 0",
+                                    dead,
+                                )
+                            break
+                        before = int(ckd["cursor"])
+                        pay = ckd["payload"]
+                        if not (
+                            isinstance(pay, dict)
+                            and tuple(ckd["block"])
+                            == (int(hb_block[0]), int(hb_block[1]))
+                            and pay.get("sig") == _ck_sig
+                        ):
+                            _log.warning(
+                                "dcn: ignoring mismatched checkpoint (cursor %d) "
+                                "for process %d — trying an older one",
+                                before, dead,
+                            )
+                            continue
+                        try:
+                            carr = restore_carriers(_carriers(), pay["leaves"])
+                        except ValueError as e:
+                            _log.warning(
+                                "dcn: process %d's checkpoint at cursor %d is "
+                                "unusable (%s) — trying an older one",
+                                dead, before, e,
+                            )
+                            continue
+                        states = carr["states"]
+                        if dev_rel:
+                            vassign_d = carr["vassign"]
+                            if self.retry_buffer:
+                                (
+                                    rbuf_d, rcount_d, pend_id_d, pend_node_d,
+                                    pend_relb_d, rdrop_d,
+                                ) = carr["retry"]
+                        outs = list(pay["outs"])
+                        start_ci = int(pay["cursor"])
+                        _log.warning(
+                            "dcn: resumed process %d's block [%d, %d) from "
+                            "its newest checkpoint at chunk %d/%d",
+                            dead, hb_block[0], hb_block[1], start_ci, n_chunks,
+                        )
+                        break
+                # Chunks this engine will actually execute (resumes skip the
+                # carried prefix) — the queue driver charges these to
+                # spec_wasted_chunks when a speculative duplicate is discarded.
+                self._wq_exec_chunks = max(n_chunks - start_ci, 0)
+                # With profiling armed, the v3 chunk program and each release
+                # bucket's program go to utils.profiling.stage_tables by module
+                # name, on the shapes of their first call (taken before the call:
+                # it donates its buffers).
+                registered: set = set()
+
+                def _reg(fn, args):
+                    if self._mesh_programs is not None:
+                        self._mesh_programs.setdefault(
+                            "chunk", (fn, _shape_structs(args))
+                        )
+                    if span.armed and fn not in registered:
+                        registered.add(fn)
+                        _register_call(fn, args)
+
+                rel_buckets: set = set()  # the pow2 release widths this run used
+            t0 = time.perf_counter()
+            for ci, c0 in enumerate(range(0, idx.shape[0], C)):
+                if ci < start_ci:
+                    continue  # chunks already carried by the resumed state
+                if ck_every and ci and ci % ck_every == 0:
+                    from .jax_runtime import checkpoint_payload
+
+                    # Round-19 split: only the device→host snapshot stays on
+                    # the loop thread (it must see the state exactly as of
+                    # chunk ci); encode + CRC framing + the retried KV sets
+                    # — and the round-20 durable-journal mirror — ride the
+                    # single-flight publisher thread, newest-wins. Drained
+                    # before the final gather below — the one place this
+                    # leg needs a durable cursor.
+                    with span("checkpoint"):
+                        dcn.publish_checkpoint_async(
+                            ci,
+                            checkpoint_payload(ci, _ck_sig, _carriers(), outs),
+                            hb_block,
+                            epoch=(self._dcn_recovery or {}).get("epoch"),
+                        )
+                if hb_on:
+                    if wq_info is not None and ci > start_ci:
+                        wall_now = time.perf_counter() - t0
+                        if wall_now > 0:
+                            hb_kw["extra"]["wq_rate"] = round(
+                                (ci - start_ci) / wall_now, 4
+                            )
+                    dcn.maybe_heartbeat(
+                        ci - 1,
+                        total=n_chunks,
+                        block=hb_block,
+                        wall_s=time.perf_counter() - t0,
+                        phases=run_phases.acc,
+                        **hb_kw,
+                    )
+                if kbops is not None:
+                    t_now = kube_wave_t[c0]
+                    due_any = khas_events and any(
+                        kev_cursor[s] < len(ktimelines[s])
+                        and ktimelines[s][kev_cursor[s]].time <= t_now
+                        for s in range(self.S)
+                    )
+                    if kpending is not None and (
+                        kwant_series
+                        or np.asarray(kpending[3]).any()
+                        or any(b.retry_q for b in kbops)
+                        or due_any
+                    ):
+                        # Some scenario's retry pass will read its mirror —
+                        # or a due node_down must evict against bookkeeping
+                        # current through chunk ci-1: resolve the deferred
+                        # fold (all scenarios — failures cluster, and the
+                        # boundary pass needs every mirror current anyway).
+                        _kfold_pending()
+                    chaos = None
+                    if due_any:
+                        chaos = []  # per-scenario eviction PairArrays (or None)
+                        dirty_alloc = False
+                        for s in range(self.S):
+                            tl, cur = ktimelines[s], kev_cursor[s]
+                            cps, cns = [], []
+                            while cur < len(tl) and tl[cur].time <= t_now:
+                                ev = tl[cur]
+                                cur += 1
+                                dirty_alloc = True
+                                if (
+                                    ktel[s] is not None
+                                    and ktel[s].cfg.want_timeline
+                                    and ev.kind in ("node_down", "node_up")
+                                ):
+                                    ktel[s].event(
+                                        ev.kind, float(ev.time), -1, int(ev.node)
+                                    )
+                                if ev.kind == "node_down":
+                                    hs["alloc"][s, ev.node] = 0.0
+                                    cp, cn = kbops[s].evict_node(
+                                        ev.node, ci, float(t_now)
+                                    )
+                                    if cp.size:
+                                        cps.append(cp)
+                                        cns.append(cn)
+                                elif ev.kind == "node_up":
+                                    hs["alloc"][s, ev.node] = ksaved_alloc[
+                                        s, ev.node
+                                    ]
+                                elif ev.kind == "capacity_scale":
+                                    hs["alloc"][s, ev.node] = (
+                                        ksaved_alloc[s, ev.node] * ev.scale
+                                    )
+                            kev_cursor[s] = cur
+                            chaos.append(
+                                (np.concatenate(cps), np.concatenate(cns))
+                                if cps
+                                else None
+                            )
+                        if dirty_alloc:
+                            # One [S, N, R] upload per event-bearing boundary
+                            # — events are sparse in virtual time, so this
+                            # stays off the steady-state chunk path.
+                            dc = dc._replace(
+                                allocatable=jnp.asarray(hs["alloc"])
+                            )
+                    subs = []
+                    adds = []
+                    any_bdelta = False
+                    for s, b in enumerate(kbops):
+                        rel, binds, evicts = b.boundary(ci, kube_wave_t[c0])
+                        cev = chaos[s] if chaos is not None else None
+                        sub = (
+                            np.concatenate(
+                                [rel[0], evicts[0]]
+                                + ([cev[0]] if cev is not None else [])
+                            ),
+                            np.concatenate(
+                                [rel[1], evicts[1]]
+                                + ([cev[1]] if cev is not None else [])
+                            ),
+                        )
+                        if sub[0].size or binds[0].size:
+                            any_bdelta = True
+                        subs.append(sub)
+                        adds.append(binds)
+                    if any_bdelta:
+                        with span("boundary_fold"):
+                            states = self._apply_stacked_boundary_delta(
+                                states, subs, adds
+                            )
+                if comp_on and ci < rel_bkt[2]:
+                    cand_b = rel_bkt[0][rel_bkt[1][ci] : rel_bkt[1][ci + 1]]
+                    if cand_b.size:
+                        if pre_comp and ppending is not None:
+                            # Evicting scenarios must walk chunk ci-1 BEFORE
+                            # the release decision (evicted pods never
+                            # release); quiet scenarios' folds stay deferred —
+                            # their ci-1 binds are not candidates here.
+                            _pre_walk()
+                        with span("boundary_fold"):
+                            states = self._apply_releases(
+                                states, host_assign, released, cand_b
+                            )
+                if dev_rel:
+                    # Static releases first (the bucketed fn; ordering is by
+                    # data dependency on states/vassign), then the chunk.
+                    rc = rel_calls[ci]
+                    if rc is not None:
+                        args = (states, vassign_d, rounds_d) + rc
+                        if self._dyn is not None:
+                            # Per-scenario domain overrides: releases of
+                            # relabeled nodes land in the overridden domain.
+                            args = args + (
+                                self._dyn_dev.ov_nodes,
+                                self._dyn_dev.ov_gdom,
+                                self._dyn_dev.ov_old,
+                            )
+                        K_rel = int(rc[0].shape[0])
+                        rel_fn = self._release_fn(K_rel)
+                        rel_buckets.add(K_rel)
+                        _reg(rel_fn, args)
+                        with span("boundary_fold"):
+                            states, rounds_d = rel_fn(*args)
+                # Dispatch phase (the chunk-fn if/elif chain below runs exactly
+                # one branch), with the chunk's marker inside it.
+                with span("dispatch"), span.mark(f"chunk:{ci}"):
+                    if dev_rel and self.retry_buffer:
+                        args = (
+                            dc, states, srcs[0], srcs[1], mgt_d, antit_d,
+                            preft_d, prefwt_d, durt_d, tbt_d,
+                            idx_chunks[ci], tb_c[ci], b_c[ci],
+                            vassign_d, rbuf_d, rcount_d,
+                            pend_id_d, pend_node_d, pend_relb_d, rdrop_d,
+                        )
+                        _reg(self._chunk_fn, args)
+                        (
+                            states, vassign_d, rbuf_d, rcount_d,
+                            pend_id_d, pend_node_d, pend_relb_d, rdrop_d, out,
+                        ) = self._chunk_fn(*args)
+                    elif dev_rel:
+                        args = (
+                            dc, states, srcs[0], srcs[1], idx_chunks[ci],
+                            b_c[ci], vassign_d,
+                        )
+                        if dyn_sharded is not None:
+                            args = args + (dyn_sharded,)
+                        elif pol_d is not None:
+                            args = args + (None,)  # dyn slot
+                        if pol_d is not None:
+                            args = args + (pol_d,)
+                        _reg(self._chunk_fn, args)
+                        states, vassign_d, out = self._chunk_fn(*args)
+                    elif self.engine == "v3":
+                        # Fused device-side gather + wave scan: one dispatch per
+                        # chunk, indices pre-staged (ops.tpu.SlotSource). Under a
+                        # mesh the sources are replicated once per engine and
+                        # every device gathers its chunk rows locally.
+                        args = (dc, states, srcs[0], srcs[1], idx_chunks[ci])
+                        if dyn_sharded is not None:
+                            args = args + (dyn_sharded,)
+                        elif pol_d is not None:
+                            args = args + (None,)  # dyn slot
+                        if pol_d is not None:
+                            args = args + (pol_d,)
+                        _reg(self._chunk_fn, args)
+                        states, out = self._chunk_fn(*args)
+                    else:
+                        slots = T.gather_slots(self.pods, idx[c0 : c0 + C])
+                        if self.mesh is not None:
+                            slots = replicate_tree(self.mesh, slots)
+                        args = (dc, states, slots)
+                        if pol_d is not None:
+                            args = args + (pol_d,)
+                        states, out = self._chunk_fn(*args)
+                if pre_comp:
+                    # Deferred eviction-aware fold (round 6): fetch only the
+                    # [S] eviction summary now; the previous chunk resolves
+                    # here — its D2H copies were launched an iteration ago
+                    # and this chunk is already in flight, so the host work
+                    # overlaps device compute. Evicting scenarios take the
+                    # per-scenario walk; the (common) no-eviction scenarios
+                    # get one vectorized fold.
+                    ev_d = self._evany_jit(out[1])
+                    for a in (out[0], out[1], out[2]):
+                        if hasattr(a, "copy_to_host_async"):
+                            a.copy_to_host_async()
+                    _pre_finish()
+                    ppending = {
+                        "rows": idx[c0 : c0 + C], "out": out, "ev_d": ev_d,
+                        "ev": None, "ch": None,
+                    }
+                    continue  # host_assign is the result carrier — outs unused
+                if kbops is not None:
+                    # Deferred fold into the scenario host mirrors (round 6):
+                    # only the [S] failure count is fetched per chunk; the
+                    # full choices land after the next dispatch (or eagerly
+                    # at the next boundary if any retry pass needs them).
+                    ix_dev = (
+                        idx_chunks[ci]
+                        if idx_chunks is not None
+                        else jnp.asarray(idx[c0 : c0 + C])
+                    )
+                    nf_d = self._kfail_jit(out, ix_dev, kube_ng)
+                    if hasattr(out, "copy_to_host_async"):
+                        out.copy_to_host_async()
                     _kfold_pending()
-                chaos = None
-                if due_any:
-                    chaos = []  # per-scenario eviction PairArrays (or None)
-                    dirty_alloc = False
-                    for s in range(self.S):
-                        tl, cur = ktimelines[s], kev_cursor[s]
-                        cps, cns = [], []
-                        while cur < len(tl) and tl[cur].time <= t_now:
-                            ev = tl[cur]
-                            cur += 1
-                            dirty_alloc = True
-                            if (
-                                ktel[s] is not None
-                                and ktel[s].cfg.want_timeline
-                                and ev.kind in ("node_down", "node_up")
-                            ):
-                                ktel[s].event(
-                                    ev.kind, float(ev.time), -1, int(ev.node)
-                                )
-                            if ev.kind == "node_down":
-                                hs["alloc"][s, ev.node] = 0.0
-                                cp, cn = kbops[s].evict_node(
-                                    ev.node, ci, float(t_now)
-                                )
-                                if cp.size:
-                                    cps.append(cp)
-                                    cns.append(cn)
-                            elif ev.kind == "node_up":
-                                hs["alloc"][s, ev.node] = ksaved_alloc[
-                                    s, ev.node
-                                ]
-                            elif ev.kind == "capacity_scale":
-                                hs["alloc"][s, ev.node] = (
-                                    ksaved_alloc[s, ev.node] * ev.scale
-                                )
-                        kev_cursor[s] = cur
-                        chaos.append(
-                            (np.concatenate(cps), np.concatenate(cns))
-                            if cps
-                            else None
-                        )
-                    if dirty_alloc:
-                        # One [S, N, R] upload per event-bearing boundary
-                        # — events are sparse in virtual time, so this
-                        # stays off the steady-state chunk path.
-                        dc = dc._replace(
-                            allocatable=jnp.asarray(hs["alloc"])
-                        )
+                    kpending = (ci, idx[c0 : c0 + C], out, nf_d)
+                    continue  # the mirrors carry the result — outs unused
+                outs.append(out)
+                if comp_on:
+                    # Fold the PREVIOUS chunk's choices AFTER dispatching this
+                    # one: the blocking fetch overlaps the in-flight chunk and
+                    # boundary b only ever sees chunks ≤ b−2 (one-chunk slack,
+                    # shared with JaxReplayEngine and the greedy anchor).
+                    if pending_fold is not None:
+                        with span("host_mirror"):
+                            self._fold(host_assign, *pending_fold)
+                    if hasattr(out, "copy_to_host_async"):
+                        out.copy_to_host_async()  # overlap D2H with the chunk
+                    pending_fold = (idx[c0 : c0 + C], out)
+            if pre_comp:
+                _pre_finish()  # the last chunk's deferred walk/fold
+            if kbops is not None:
+                # Trailing boundary (the single-replay/greedy twin): last-
+                # chunk failures still get their PostFilter attempt. The
+                # final chunk's fold must land first (bookkeeping parity).
+                _kfold_pending()
                 subs = []
                 adds = []
                 any_bdelta = False
-                for s, b in enumerate(kbops):
-                    rel, binds, evicts = b.boundary(ci, kube_wave_t[c0])
-                    cev = chaos[s] if chaos is not None else None
+                for b in kbops:
+                    rel, binds, evicts = b.boundary(idx.shape[0] // C, np.inf)
                     sub = (
-                        np.concatenate(
-                            [rel[0], evicts[0]]
-                            + ([cev[0]] if cev is not None else [])
-                        ),
-                        np.concatenate(
-                            [rel[1], evicts[1]]
-                            + ([cev[1]] if cev is not None else [])
-                        ),
+                        np.concatenate([rel[0], evicts[0]]),
+                        np.concatenate([rel[1], evicts[1]]),
                     )
                     if sub[0].size or binds[0].size:
                         any_bdelta = True
                     subs.append(sub)
                     adds.append(binds)
                 if any_bdelta:
-                    with run_phases.tick("boundary_fold"), _pann(
-                        "boundary_fold"
-                    ):
+                    with span("boundary_fold"):
                         states = self._apply_stacked_boundary_delta(
                             states, subs, adds
                         )
-            if comp_on and ci < rel_bkt[2]:
-                cand_b = rel_bkt[0][rel_bkt[1][ci] : rel_bkt[1][ci + 1]]
-                if cand_b.size:
-                    if pre_comp and ppending is not None:
-                        # Evicting scenarios must walk chunk ci-1 BEFORE
-                        # the release decision (evicted pods never
-                        # release); quiet scenarios' folds stay deferred —
-                        # their ci-1 binds are not candidates here.
-                        _pre_walk()
-                    with run_phases.tick("boundary_fold"):
-                        states = self._apply_releases(
-                            states, host_assign, released, cand_b
-                        )
-            if dev_rel:
-                # Static releases first (the bucketed fn; ordering is by
-                # data dependency on states/vassign), then the chunk.
-                rc = rel_calls[ci]
-                if rc is not None:
-                    args = (states, vassign_d, rounds_d) + rc
-                    if self._dyn is not None:
-                        # Per-scenario domain overrides: releases of
-                        # relabeled nodes land in the overridden domain.
-                        args = args + (
-                            self._dyn_dev.ov_nodes,
-                            self._dyn_dev.ov_gdom,
-                            self._dyn_dev.ov_old,
-                        )
-                    K_rel = int(rc[0].shape[0])
-                    rel_fn = self._release_fn(K_rel)
-                    rel_buckets.add(K_rel)
-                    _reg(rel_fn, args)
-                    with run_phases.tick("boundary_fold"):
-                        states, rounds_d = rel_fn(*args)
-            # Dispatch phase (the chunk-fn if/elif chain below runs exactly
-            # one branch): timed via add() rather than a context manager so
-            # the chain's indentation is untouched; the profiler chunk
-            # marker brackets it the same way.
-            _ann = _cann(ci)
-            _ann.__enter__()
-            _t_disp = time.perf_counter()
-            if dev_rel and self.retry_buffer:
-                args = (
-                    dc, states, srcs[0], srcs[1], mgt_d, antit_d,
-                    preft_d, prefwt_d, durt_d, tbt_d,
-                    idx_chunks[ci], tb_c[ci], b_c[ci],
-                    vassign_d, rbuf_d, rcount_d,
-                    pend_id_d, pend_node_d, pend_relb_d, rdrop_d,
-                )
-                _reg(self._chunk_fn, args)
-                (
-                    states, vassign_d, rbuf_d, rcount_d,
-                    pend_id_d, pend_node_d, pend_relb_d, rdrop_d, out,
-                ) = self._chunk_fn(*args)
-            elif dev_rel:
-                args = (
-                    dc, states, srcs[0], srcs[1], idx_chunks[ci],
-                    b_c[ci], vassign_d,
-                )
-                if dyn_sharded is not None:
-                    args = args + (dyn_sharded,)
-                elif pol_d is not None:
-                    args = args + (None,)  # dyn slot
-                if pol_d is not None:
-                    args = args + (pol_d,)
-                _reg(self._chunk_fn, args)
-                states, vassign_d, out = self._chunk_fn(*args)
-            elif self.engine == "v3":
-                # Fused device-side gather + wave scan: one dispatch per
-                # chunk, indices pre-staged (ops.tpu.SlotSource). Under a
-                # mesh the sources are replicated once per engine and
-                # every device gathers its chunk rows locally.
-                args = (dc, states, srcs[0], srcs[1], idx_chunks[ci])
-                if dyn_sharded is not None:
-                    args = args + (dyn_sharded,)
-                elif pol_d is not None:
-                    args = args + (None,)  # dyn slot
-                if pol_d is not None:
-                    args = args + (pol_d,)
-                _reg(self._chunk_fn, args)
-                states, out = self._chunk_fn(*args)
-            else:
-                slots = T.gather_slots(self.pods, idx[c0 : c0 + C])
-                if self.mesh is not None:
-                    slots = replicate_tree(self.mesh, slots)
-                args = (dc, states, slots)
-                if pol_d is not None:
-                    args = args + (pol_d,)
-                states, out = self._chunk_fn(*args)
-            run_phases.add("dispatch", time.perf_counter() - _t_disp)
-            _ann.__exit__(None, None, None)
-            if pre_comp:
-                # Deferred eviction-aware fold (round 6): fetch only the
-                # [S] eviction summary now; the previous chunk resolves
-                # here — its D2H copies were launched an iteration ago
-                # and this chunk is already in flight, so the host work
-                # overlaps device compute. Evicting scenarios take the
-                # per-scenario walk; the (common) no-eviction scenarios
-                # get one vectorized fold.
-                ev_d = self._evany_jit(out[1])
-                for a in (out[0], out[1], out[2]):
-                    if hasattr(a, "copy_to_host_async"):
-                        a.copy_to_host_async()
-                _pre_finish()
-                ppending = {
-                    "rows": idx[c0 : c0 + C], "out": out, "ev_d": ev_d,
-                    "ev": None, "ch": None,
-                }
-                continue  # host_assign is the result carrier — outs unused
-            if kbops is not None:
-                # Deferred fold into the scenario host mirrors (round 6):
-                # only the [S] failure count is fetched per chunk; the
-                # full choices land after the next dispatch (or eagerly
-                # at the next boundary if any retry pass needs them).
-                ix_dev = (
-                    idx_chunks[ci]
-                    if idx_chunks is not None
-                    else jnp.asarray(idx[c0 : c0 + C])
-                )
-                nf_d = self._kfail_jit(out, ix_dev, kube_ng)
-                if hasattr(out, "copy_to_host_async"):
-                    out.copy_to_host_async()
-                _kfold_pending()
-                kpending = (ci, idx[c0 : c0 + C], out, nf_d)
-                continue  # the mirrors carry the result — outs unused
-            outs.append(out)
-            if comp_on:
-                # Fold the PREVIOUS chunk's choices AFTER dispatching this
-                # one: the blocking fetch overlaps the in-flight chunk and
-                # boundary b only ever sees chunks ≤ b−2 (one-chunk slack,
-                # shared with JaxReplayEngine and the greedy anchor).
-                if pending_fold is not None:
-                    with run_phases.tick("host_mirror"):
-                        self._fold(host_assign, *pending_fold)
-                if hasattr(out, "copy_to_host_async"):
-                    out.copy_to_host_async()  # overlap D2H with the chunk
-                pending_fold = (idx[c0 : c0 + C], out)
-        if pre_comp:
-            _pre_finish()  # the last chunk's deferred walk/fold
-        if kbops is not None:
-            # Trailing boundary (the single-replay/greedy twin): last-
-            # chunk failures still get their PostFilter attempt. The
-            # final chunk's fold must land first (bookkeeping parity).
-            _kfold_pending()
-            subs = []
-            adds = []
-            any_bdelta = False
-            for b in kbops:
-                rel, binds, evicts = b.boundary(idx.shape[0] // C, np.inf)
-                sub = (
-                    np.concatenate([rel[0], evicts[0]]),
-                    np.concatenate([rel[1], evicts[1]]),
-                )
-                if sub[0].size or binds[0].size:
-                    any_bdelta = True
-                subs.append(sub)
-                adds.append(binds)
-            if any_bdelta:
-                with run_phases.tick("boundary_fold"), _pann(
-                    "boundary_fold"
-                ):
-                    states = self._apply_stacked_boundary_delta(
-                        states, subs, adds
+                if khas_events:
+                    # The stack rows were mutated in lockstep with the
+                    # mirrors — restore the t=0 view so the engine (and its
+                    # ScenarioSet) stays reusable.
+                    hs["alloc"][...] = ksaved_alloc
+            with span("device_wait"):
+                jax.block_until_ready(states)
+            if ck_every:
+                # Round-19 durable-cursor boundary: every queued background
+                # publication must be on the KV plane before this process
+                # beacons "gather" / completes its work-queue block — a
+                # sibling recovering after that point may only be offered
+                # cursors that are actually complete. Drain wall is exposed
+                # loop wall, attributed to the checkpoint phase.
+                with span("checkpoint"):
+                    dcn.drain_publisher()
+            wall = time.perf_counter() - t0
+
+            # ``gather``: counts, utilization and what the host-side paths
+            # assemble, up to the placements' hand-back.
+            with span("gather"):
+                to_schedule = int((idx >= 0).sum())
+                chunk_handback = False  # placements from the chunks' choices
+                kube_preempt = kube_dropped = None
+                kube_evict = kube_resched = kube_stranded = kube_lat = None
+                sc_lat_p50 = sc_lat_p90 = sc_lat_p99 = sc_telemetry = None
+                frag_stranded = frag_index = frag_pack = None
+                stel = None
+                if kbops is not None:
+                    host_k = np.stack([b.assignments for b in kbops])
+                    assignments = host_k if self.collect_assignments else None
+                    scheduled = self.pods.bound_node == PAD
+                    placed = (
+                        (host_k[:, scheduled] >= 0).sum(axis=1).astype(np.int32)
                     )
-            if khas_events:
-                # The stack rows were mutated in lockstep with the
-                # mirrors — restore the t=0 view so the engine (and its
-                # ScenarioSet) stays reusable.
-                hs["alloc"][...] = ksaved_alloc
-        with run_phases.tick("device_wait"), _pann("device_wait"):
-            jax.block_until_ready(states)
-        if ck_every:
-            # Round-19 durable-cursor boundary: every queued background
-            # publication must be on the KV plane before this process
-            # beacons "gather" / completes its work-queue block — a
-            # sibling recovering after that point may only be offered
-            # cursors that are actually complete. Drain wall is exposed
-            # loop wall, attributed to the checkpoint phase.
-            with run_phases.tick("checkpoint"):
-                dcn.drain_publisher()
-        wall = time.perf_counter() - t0
+                    # One counter tuple per mirror (BoundaryOps.counters owns the
+                    # field list — result assembly and the DCN gather can't drift).
+                    cnt = np.asarray([b.counters() for b in kbops], np.float64)
+                    kube_preempt = cnt[:, 0].astype(np.int32)
+                    kube_dropped = cnt[:, 1].astype(np.int32)
+                    kube_evict = cnt[:, 2].astype(np.int32)
+                    kube_resched = cnt[:, 3].astype(np.int32)
+                    kube_stranded = cnt[:, 4].astype(np.int32)
+                    kube_lat = cnt[:, 5]
+                    # Fragmentation economics (round 13): each mirror holds the
+                    # scenario's committed state, its restored allocatable view
+                    # (hs["alloc"][s] — put back above when events ran), and the
+                    # still-pending set — exactly the inputs the single-replay
+                    # engines hand to the same helper, so the [S] gauges
+                    # bit-match the per-scenario kube replays.
+                    from ..utils.metrics import fragmentation_gauges
 
-        # ``gather``: counts, utilization and what the host-side paths
-        # assemble, up to the placements' hand-back.
-        _t_gather = time.perf_counter()
-        _gather_ann = _pann("gather")
-        _gather_ann.__enter__()
-        to_schedule = int((idx >= 0).sum())
-        chunk_handback = False  # placements from the chunks' choices
-        kube_preempt = kube_dropped = None
-        kube_evict = kube_resched = kube_stranded = kube_lat = None
-        sc_lat_p50 = sc_lat_p90 = sc_lat_p99 = sc_telemetry = None
-        frag_stranded = frag_index = frag_pack = None
-        stel = None
-        if kbops is not None:
-            host_k = np.stack([b.assignments for b in kbops])
-            assignments = host_k if self.collect_assignments else None
-            scheduled = self.pods.bound_node == PAD
-            placed = (
-                (host_k[:, scheduled] >= 0).sum(axis=1).astype(np.int32)
-            )
-            # One counter tuple per mirror (BoundaryOps.counters owns the
-            # field list — result assembly and the DCN gather can't drift).
-            cnt = np.asarray([b.counters() for b in kbops], np.float64)
-            kube_preempt = cnt[:, 0].astype(np.int32)
-            kube_dropped = cnt[:, 1].astype(np.int32)
-            kube_evict = cnt[:, 2].astype(np.int32)
-            kube_resched = cnt[:, 3].astype(np.int32)
-            kube_stranded = cnt[:, 4].astype(np.int32)
-            kube_lat = cnt[:, 5]
-            # Fragmentation economics (round 13): each mirror holds the
-            # scenario's committed state, its restored allocatable view
-            # (hs["alloc"][s] — put back above when events ran), and the
-            # still-pending set — exactly the inputs the single-replay
-            # engines hand to the same helper, so the [S] gauges
-            # bit-match the per-scenario kube replays.
-            from ..utils.metrics import fragmentation_gauges
-
-            frag_stranded = np.zeros(self.S, np.float64)
-            frag_index = np.zeros(self.S, np.float64)
-            frag_pack = np.zeros(self.S, np.float64)
-            for s, b in enumerate(kbops):
-                b.flush_planes()
-                pend = scheduled & (host_k[s] == PAD)
-                fr = fragmentation_gauges(
-                    b.ec.allocatable, b.st.used,
-                    self.pods.requests[pend], b.ec.vocab._r,
-                )
-                frag_stranded[s] = fr["stranded"].get("cpu", 0.0)
-                frag_index[s] = fr["frag_index"].get("cpu", 0.0)
-                frag_pack[s] = fr["packing_efficiency"]
-            if self.telemetry_cfg.enabled:
-                stel = [t.result() for t in ktel]
-                lat_q = np.full((3, self.S), np.nan, np.float64)
-                for s, t in enumerate(stel):
-                    if t is not None and t.latency is not None:
-                        lat_q[:, s] = (
-                            t.latency["p50"],
-                            t.latency["p90"],
-                            t.latency["p99"],
+                    frag_stranded = np.zeros(self.S, np.float64)
+                    frag_index = np.zeros(self.S, np.float64)
+                    frag_pack = np.zeros(self.S, np.float64)
+                    for s, b in enumerate(kbops):
+                        b.flush_planes()
+                        pend = scheduled & (host_k[s] == PAD)
+                        fr = fragmentation_gauges(
+                            b.ec.allocatable, b.st.used,
+                            self.pods.requests[pend], b.ec.vocab._r,
                         )
-                sc_lat_p50, sc_lat_p90, sc_lat_p99 = lat_q
-                sc_telemetry = (
-                    stel if self.telemetry_cfg.want_series else None
-                )
-        elif comp_on and self.preemption:
-            # The eager eviction-aware folds ARE the walk (see the chunk
-            # loop); host_assign is the result carrier. Counting device
-            # finals would overcount later-evicted pods.
-            assignments = host_assign if self.collect_assignments else None
-            scheduled = self.pods.bound_node == PAD
-            placed = (
-                (host_assign[:, scheduled] >= 0).sum(axis=1).astype(np.int32)
-            )
-        elif self.collect_assignments and self.preemption:
-            choices = np.concatenate([self._fetch(o[0]) for o in outs], axis=1)
-            ev_node = np.concatenate([self._fetch(o[1]) for o in outs], axis=1)
-            ev_tier = np.concatenate([self._fetch(o[2]) for o in outs], axis=1)
-            from .jax_runtime import preemption_walk
-
-            assignments = np.full((self.S, self.pods.num_pods), PAD, np.int32)
-            prebound = self.pods.bound_node >= 0
-            assignments[:, prebound] = self.pods.bound_node[prebound]
-            for s in range(self.S):
-                preemption_walk(
-                    assignments[s], idx, choices[s], ev_node[s], ev_tier[s],
-                    self.static3.pod_tier, self.pods.group_id == PAD,
-                )
-            scheduled = ~prebound
-            placed = (assignments[:, scheduled] >= 0).sum(axis=1).astype(np.int32)
-        elif self.collect_assignments and not dev_rel:
-            # Every chunk's choices are still on the device: the placements
-            # come to the host in the ``handback`` phase below, and
-            # ``placed`` is counted from them there.
-            assignments = placed = None
-            chunk_handback = True
-        else:
-            assignments = None
-            if self._need_choices:
-                # Completions forced per-pod choices; count from them.
-                choices = np.concatenate([self._fetch(o) for o in outs], axis=1)
-                flat_idx = idx.reshape(-1)
-                valid = flat_idx >= 0
-                placed = (
-                    (choices.reshape(self.S, -1)[:, valid] >= 0)
-                    .sum(axis=1)
-                    .astype(np.int32)
-                )
-            elif self.retry_buffer:
-                # (counts [S, C], retry_placed [S]) per chunk: placements
-                # from arrival waves plus boundary retry passes.
-                placed = self._fetch(
-                    self._jit_once("placed_retry", lambda: jax.jit(
-                        lambda o: (
-                            jnp.concatenate(
-                                [c for c, _ in o], axis=1
-                            ).sum(axis=1, dtype=jnp.int32)
-                            + jnp.stack([r for _, r in o], axis=1).sum(
-                                axis=1, dtype=jnp.int32
-                            )
+                        frag_stranded[s] = fr["stranded"].get("cpu", 0.0)
+                        frag_index[s] = fr["frag_index"].get("cpu", 0.0)
+                        frag_pack[s] = fr["packing_efficiency"]
+                    if self.telemetry_cfg.enabled:
+                        stel = [t.result() for t in ktel]
+                        lat_q = np.full((3, self.S), np.nan, np.float64)
+                        for s, t in enumerate(stel):
+                            if t is not None and t.latency is not None:
+                                lat_q[:, s] = (
+                                    t.latency["p50"],
+                                    t.latency["p90"],
+                                    t.latency["p99"],
+                                )
+                        sc_lat_p50, sc_lat_p90, sc_lat_p99 = lat_q
+                        sc_telemetry = (
+                            stel if self.telemetry_cfg.want_series else None
                         )
-                    ))(outs)
-                ).astype(np.int32)
-            else:
-                # Device-side reduce, ONE small D2H instead of one
-                # np.asarray round-trip per array.
-                placed = self._fetch(
-                    self._jit_once("placed", lambda: jax.jit(
-                        lambda o: jnp.concatenate(o, axis=1).sum(
-                            axis=1, dtype=jnp.int32
-                        )
-                    ))(outs)
-                ).astype(np.int32)
+                elif comp_on and self.preemption:
+                    # The eager eviction-aware folds ARE the walk (see the chunk
+                    # loop); host_assign is the result carrier. Counting device
+                    # finals would overcount later-evicted pods.
+                    assignments = host_assign if self.collect_assignments else None
+                    scheduled = self.pods.bound_node == PAD
+                    placed = (
+                        (host_assign[:, scheduled] >= 0).sum(axis=1).astype(np.int32)
+                    )
+                elif self.collect_assignments and self.preemption:
+                    choices = np.concatenate([self._fetch(o[0]) for o in outs], axis=1)
+                    ev_node = np.concatenate([self._fetch(o[1]) for o in outs], axis=1)
+                    ev_tier = np.concatenate([self._fetch(o[2]) for o in outs], axis=1)
+                    from .jax_runtime import preemption_walk
 
-        util = None
-        ri = self.ec.vocab._r.get("cpu")
-        if ri is not None:
-            v3_layout = self.engine == "v3"
-
-            def _util(used, alloc):
-                a = alloc[:, :, ri]  # [S, N]
-                u_row = used[:, ri, :] if v3_layout else used[:, :, ri]
-                u = jnp.where(a > 0, u_row / jnp.where(a > 0, a, 1.0), 0.0)
-                return u.mean(axis=1)
-
-            # [S] floats instead of the full [S, R, N] used plane D2H.
-            util = self._fetch(
-                self._jit_once("util", lambda: jax.jit(_util))(
-                    states.used, dc.allocatable
-                )
-            )
-        dropped = kube_dropped
-        if dropped is None and dev_rel and self.retry_buffer:
-            # The device retry path counts overflow drops in-scan now
-            # (round 6): every drop-capable engine reports them.
-            dropped = np.asarray(self._fetch(rdrop_d)).astype(np.int32)
-        release_rounds = (
-            int(np.max(self._fetch(rounds_d))) if dev_rel else None
-        )
-        run_phases.add("gather", time.perf_counter() - _t_gather)
-        _gather_ann.__exit__(None, None, None)
-        handback_bytes = 0
-        if self.collect_assignments and dev_rel:
-            # The device-release path's placements: the wave-order buffer
-            # comes to the host once, after the last chunk.
-            with run_phases.tick("handback"), _pann("handback"):
-                assignments, handback_bytes, _ = self._handback(
-                    vassign_d, lambda: self._dev_rel_stage["pos"])
-        elif chunk_handback:
-            # The chunks' choices, put into task order on the device and
-            # copied once.
-            with run_phases.tick("handback"), _pann("handback"):
-                assignments, handback_bytes, placed = self._handback(
-                    outs, lambda: self._chunks_pos(idx), count=True)
-                prebound = self.pods.bound_node >= 0
-                if prebound.any() or self._fork_choices is not None:
-                    assignments = np.array(assignments)  # the copy is read-only
+                    assignments = np.full((self.S, self.pods.num_pods), PAD, np.int32)
+                    prebound = self.pods.bound_node >= 0
                     assignments[:, prebound] = self.pods.bound_node[prebound]
-                if self._fork_choices is not None:
-                    # Pre-fork placements are common to every scenario.
-                    pidx = self.waves.idx[: self._fork_waves_done].reshape(-1)
-                    pch = self._fork_choices.reshape(-1)
-                    pv = pidx >= 0
-                    assignments[:, pidx[pv]] = pch[pv][None, :]
-        # This process's partial fleet telemetry (round 12): per-scenario
-        # collectors merged same-process (phases key-wise summed would be
-        # wrong here — the fleet view wants the ENGINE's wall clocks, so
-        # they are overwritten below), shipped through the one gather.
-        fleet_local = None
-        if self.telemetry_cfg.enabled:
-            fleet_local = (
-                ReplayTelemetry.merge(stel) if stel is not None else None
-            )
-            if fleet_local is None:
-                fleet_local = ReplayTelemetry(
-                    granularity=self.telemetry_cfg.granularity
-                )
-            fleet_local.phases = run_phases.summary()
-            fleet_local.chunk_waves = int(C)
-            fleet_local.scenarios = int(self.S)
-            if self.engine == "v3":
-                from ..ops import tpu3 as V3
+                    for s in range(self.S):
+                        preemption_walk(
+                            assignments[s], idx, choices[s], ev_node[s], ev_tier[s],
+                            self.static3.pod_tier, self.pods.group_id == PAD,
+                        )
+                    scheduled = ~prebound
+                    placed = (assignments[:, scheduled] >= 0).sum(axis=1).astype(np.int32)
+                elif self.collect_assignments and not dev_rel:
+                    # Every chunk's choices are still on the device: the placements
+                    # come to the host in the ``handback`` phase below, and
+                    # ``placed`` is counted from them there.
+                    assignments = placed = None
+                    chunk_handback = True
+                else:
+                    assignments = None
+                    if self._need_choices:
+                        # Completions forced per-pod choices; count from them.
+                        choices = np.concatenate([self._fetch(o) for o in outs], axis=1)
+                        flat_idx = idx.reshape(-1)
+                        valid = flat_idx >= 0
+                        placed = (
+                            (choices.reshape(self.S, -1)[:, valid] >= 0)
+                            .sum(axis=1)
+                            .astype(np.int32)
+                        )
+                    elif self.retry_buffer:
+                        # (counts [S, C], retry_placed [S]) per chunk: placements
+                        # from arrival waves plus boundary retry passes.
+                        placed = self._fetch(
+                            self._jit_once("placed_retry", lambda: jax.jit(
+                                lambda o: (
+                                    jnp.concatenate(
+                                        [c for c, _ in o], axis=1
+                                    ).sum(axis=1, dtype=jnp.int32)
+                                    + jnp.stack([r for _, r in o], axis=1).sum(
+                                        axis=1, dtype=jnp.int32
+                                    )
+                                )
+                            ))(outs)
+                        ).astype(np.int32)
+                    else:
+                        # Device-side reduce, ONE small D2H instead of one
+                        # np.asarray round-trip per array.
+                        placed = self._fetch(
+                            self._jit_once("placed", lambda: jax.jit(
+                                lambda o: jnp.concatenate(o, axis=1).sum(
+                                    axis=1, dtype=jnp.int32
+                                )
+                            ))(outs)
+                        ).astype(np.int32)
 
-                fleet_local.select_form = V3.select_form(
-                    self.static3, self.spec, self.ec.num_nodes,
-                    traced_weights=self._policies is not None,
-                    dyn_labels=self._dyn_dev is not None,
+                util = None
+                ri = self.ec.vocab._r.get("cpu")
+                if ri is not None:
+                    v3_layout = self.engine == "v3"
+
+                    def _util(used, alloc):
+                        a = alloc[:, :, ri]  # [S, N]
+                        u_row = used[:, ri, :] if v3_layout else used[:, :, ri]
+                        u = jnp.where(a > 0, u_row / jnp.where(a > 0, a, 1.0), 0.0)
+                        return u.mean(axis=1)
+
+                    # [S] floats instead of the full [S, R, N] used plane D2H.
+                    util = self._fetch(
+                        self._jit_once("util", lambda: jax.jit(_util))(
+                            states.used, dc.allocatable
+                        )
+                    )
+                dropped = kube_dropped
+                if dropped is None and dev_rel and self.retry_buffer:
+                    # The device retry path counts overflow drops in-scan now
+                    # (round 6): every drop-capable engine reports them.
+                    dropped = np.asarray(self._fetch(rdrop_d)).astype(np.int32)
+                release_rounds = (
+                    int(np.max(self._fetch(rounds_d))) if dev_rel else None
                 )
-                fleet_local.count_planes = V3.count_planes(
-                    self.static3, scenario_axis=True
+            handback_bytes = 0
+            if self.collect_assignments and dev_rel:
+                # The device-release path's placements: the wave-order buffer
+                # comes to the host once, after the last chunk.
+                with span("handback"):
+                    assignments, handback_bytes, _ = self._handback(
+                        span, vassign_d, lambda: self._dev_rel_stage["pos"])
+            elif chunk_handback:
+                # The chunks' choices, put into task order on the device and
+                # copied once.
+                with span("handback"):
+                    assignments, handback_bytes, placed = self._handback(
+                        span, outs, lambda: self._chunks_pos(idx), count=True)
+                    prebound = self.pods.bound_node >= 0
+                    if prebound.any() or self._fork_choices is not None:
+                        assignments = np.array(assignments)  # the copy is read-only
+                        assignments[:, prebound] = self.pods.bound_node[prebound]
+                    if self._fork_choices is not None:
+                        # Pre-fork placements are common to every scenario.
+                        pidx = self.waves.idx[: self._fork_waves_done].reshape(-1)
+                        pch = self._fork_choices.reshape(-1)
+                        pv = pidx >= 0
+                        assignments[:, pidx[pv]] = pch[pv][None, :]
+            # This process's partial fleet telemetry (round 12): per-scenario
+            # collectors merged same-process (phases key-wise summed would be
+            # wrong here — the fleet view wants the ENGINE's wall clocks, so
+            # they are overwritten below), shipped through the one gather.
+            fleet_local = None
+            if self.telemetry_cfg.enabled:
+                fleet_local = (
+                    ReplayTelemetry.merge(stel) if stel is not None else None
                 )
-            if dev_rel:
-                fleet_local.release_buckets = sorted(rel_buckets)
-                fleet_local.release_rounds = release_rounds
-            if dev_rel or self.collect_assignments:
-                fleet_local.handback_bytes = handback_bytes
-            if self.mesh is not None:
-                fleet_local.mesh = self._mesh_summary()
-            # DCN checkpoint-publication attribution (round 16): the
-            # cumulative encode+push wall, publication count and encoded
-            # MiB ride the fleet phase map (merged under this pid's
-            # namespace). Only present when this process actually
-            # published — single-process runs keep the pinned phase set.
-            _ps = dcn.publish_stats()
-            if _ps["count"] > _ps_start["count"]:
-                fleet_local.phases["ckpt_publish"] = round(
-                    _ps["wall_s"] - _ps_start["wall_s"], 6
-                )
-                fleet_local.phases["ckpt_publish_count"] = float(
-                    _ps["count"] - _ps_start["count"]
-                )
-                fleet_local.phases["ckpt_publish_mib"] = round(
-                    (_ps["bytes"] - _ps_start["bytes"]) / 2**20, 3
-                )
-            # Background-publisher attribution (round 19): submissions,
-            # newest-wins coalesces and drain wall — with the publisher
-            # on, ``ckpt_publish`` above is HIDDEN (worker-thread) wall
-            # and the drain wait is the only exposed remainder. Only
-            # present when the publisher actually ran, so overlap-off
-            # and single-process runs keep the pinned phase set.
-            _bg = dcn.bg_publish_stats()
-            if _bg["submitted"] > _bg_start["submitted"]:
-                fleet_local.phases["ckpt_publish_bg_submitted"] = float(
-                    _bg["submitted"] - _bg_start["submitted"]
-                )
-                fleet_local.phases["ckpt_publish_bg_coalesced"] = float(
-                    _bg["coalesced"] - _bg_start["coalesced"]
-                )
-                fleet_local.phases["ckpt_publish_drain_s"] = round(
-                    _bg["drain_wait_s"] - _bg_start["drain_wait_s"], 6
-                )
-            # Faultline attribution (round 17): KV retries burned and CRC
-            # fallbacks taken during THIS run ride the same phase map,
-            # again only when nonzero — clean runs keep the pinned phase
-            # set byte-identical to pre-round-17.
-            _rs = dcn.retry_stats()
-            if (
-                _rs["retries"] > _rs_start["retries"]
-                or _rs["giveups"] > _rs_start["giveups"]
-            ):
-                fleet_local.phases["kv_retry"] = round(
-                    _rs["backoff_s"] - _rs_start["backoff_s"], 6
-                )
-                fleet_local.phases["kv_retry_count"] = float(
-                    _rs["retries"] - _rs_start["retries"]
-                )
-                fleet_local.phases["kv_retry_giveups"] = float(
-                    _rs["giveups"] - _rs_start["giveups"]
-                )
-            _cs = dcn.crc_stats()
-            if _cs["fallbacks"] > _cs_start["fallbacks"]:
-                fleet_local.phases["ckpt_crc_fallback_count"] = float(
-                    _cs["fallbacks"] - _cs_start["fallbacks"]
-                )
-            if self._dcn_wq_info is not None:
-                # Work-queue provenance (round 18): which block this
-                # engine executed, at which lease generation, and whether
-                # it was a speculative re-execution — the telemetry trail
-                # the straggler tests pin.
-                fleet_local.phases["wq_block"] = float(
-                    self._dcn_wq_info.get("block", -1)
-                )
-                fleet_local.phases["wq_gen"] = float(
-                    self._dcn_recovery.get("gen", 0)
-                )
-                if self._dcn_wq_info.get("speculative"):
-                    fleet_local.phases["wq_spec"] = 1.0
-            elif self._dcn_recovery is not None:
-                # Claim-generation fencing (round 17): which claim
-                # attempt produced this block, and for whom. gen > 0
-                # marks a hand-off after a claimant death mid-recovery.
-                fleet_local.phases["recovery_gen"] = float(
-                    self._dcn_recovery.get("gen", 0)
-                )
-                fleet_local.phases["recovery_for"] = float(
-                    self._dcn_recovery.get("for_pid", -1)
-                )
-        fleet_tel = None
-        # ---- THE end-of-replay gather (round 11, parallel.dcn) ----
-        # The one point per replay where processes exchange data: every
-        # per-scenario result array is concatenated across the contiguous
-        # per-process blocks, in process order — bit-identical to what the
-        # single-process mesh run assembles. Everything above this line
-        # (the whole chunk loop, the boundary passes, the result fetches)
-        # was process-local.
-        process_count = 1
-        if self._dcn_sliced:
-            if hb_on:
-                # Final beacon before blocking in the gather: siblings'
-                # attributed-timeout diagnostics see "state=gather" rather
-                # than a stale mid-replay chunk.
-                dcn.heartbeat(
-                    n_chunks - 1,
-                    total=n_chunks,
-                    block=hb_block,
-                    wall_s=wall,
-                    phases=run_phases.acc,
-                    state="gather",
-                    # Fleet utilization gauge (round 13): this process's
-                    # mean CPU utilization over its local scenario block —
-                    # already computed above, so the beacon stays free of
-                    # extra D2H. dcn_launch --watch renders it next to
-                    # the live-buffer gauge.
-                    extra=(
-                        {"util_cpu": round(float(np.mean(util)), 4)}
-                        if util is not None and len(util)
+                if fleet_local is None:
+                    fleet_local = ReplayTelemetry(
+                        granularity=self.telemetry_cfg.granularity
+                    )
+                fleet_local.phases = run_phases.summary()
+                fleet_local.chunk_waves = int(C)
+                fleet_local.scenarios = int(self.S)
+                if self.engine == "v3":
+                    from ..ops import tpu3 as V3
+
+                    fleet_local.select_form = V3.select_form(
+                        self.static3, self.spec, self.ec.num_nodes,
+                        traced_weights=self._policies is not None,
+                        dyn_labels=self._dyn_dev is not None,
+                    )
+                    fleet_local.count_planes = V3.count_planes(
+                        self.static3, scenario_axis=True
+                    )
+                if dev_rel:
+                    fleet_local.release_buckets = sorted(rel_buckets)
+                    fleet_local.release_rounds = release_rounds
+                if dev_rel or self.collect_assignments:
+                    fleet_local.handback_bytes = handback_bytes
+                if self.mesh is not None:
+                    fleet_local.mesh = self._mesh_summary()
+                # DCN checkpoint-publication attribution (round 16): the
+                # cumulative encode+push wall, publication count and encoded
+                # MiB ride the fleet phase map (merged under this pid's
+                # namespace). Only present when this process actually
+                # published — single-process runs keep the pinned phase set.
+                _ps = dcn.publish_stats()
+                if _ps["count"] > _ps_start["count"]:
+                    fleet_local.phases["ckpt_publish"] = round(
+                        _ps["wall_s"] - _ps_start["wall_s"], 6
+                    )
+                    fleet_local.phases["ckpt_publish_count"] = float(
+                        _ps["count"] - _ps_start["count"]
+                    )
+                    fleet_local.phases["ckpt_publish_mib"] = round(
+                        (_ps["bytes"] - _ps_start["bytes"]) / 2**20, 3
+                    )
+                # Background-publisher attribution (round 19): submissions,
+                # newest-wins coalesces and drain wall — with the publisher
+                # on, ``ckpt_publish`` above is HIDDEN (worker-thread) wall
+                # and the drain wait is the only exposed remainder. Only
+                # present when the publisher actually ran, so overlap-off
+                # and single-process runs keep the pinned phase set.
+                _bg = dcn.bg_publish_stats()
+                if _bg["submitted"] > _bg_start["submitted"]:
+                    fleet_local.phases["ckpt_publish_bg_submitted"] = float(
+                        _bg["submitted"] - _bg_start["submitted"]
+                    )
+                    fleet_local.phases["ckpt_publish_bg_coalesced"] = float(
+                        _bg["coalesced"] - _bg_start["coalesced"]
+                    )
+                    fleet_local.phases["ckpt_publish_drain_s"] = round(
+                        _bg["drain_wait_s"] - _bg_start["drain_wait_s"], 6
+                    )
+                # Faultline attribution (round 17): KV retries burned and CRC
+                # fallbacks taken during THIS run ride the same phase map,
+                # again only when nonzero — clean runs keep the pinned phase
+                # set byte-identical to pre-round-17.
+                _rs = dcn.retry_stats()
+                if (
+                    _rs["retries"] > _rs_start["retries"]
+                    or _rs["giveups"] > _rs_start["giveups"]
+                ):
+                    fleet_local.phases["kv_retry"] = round(
+                        _rs["backoff_s"] - _rs_start["backoff_s"], 6
+                    )
+                    fleet_local.phases["kv_retry_count"] = float(
+                        _rs["retries"] - _rs_start["retries"]
+                    )
+                    fleet_local.phases["kv_retry_giveups"] = float(
+                        _rs["giveups"] - _rs_start["giveups"]
+                    )
+                _cs = dcn.crc_stats()
+                if _cs["fallbacks"] > _cs_start["fallbacks"]:
+                    fleet_local.phases["ckpt_crc_fallback_count"] = float(
+                        _cs["fallbacks"] - _cs_start["fallbacks"]
+                    )
+                if self._dcn_wq_info is not None:
+                    # Work-queue provenance (round 18): which block this
+                    # engine executed, at which lease generation, and whether
+                    # it was a speculative re-execution — the telemetry trail
+                    # the straggler tests pin.
+                    fleet_local.phases["wq_block"] = float(
+                        self._dcn_wq_info.get("block", -1)
+                    )
+                    fleet_local.phases["wq_gen"] = float(
+                        self._dcn_recovery.get("gen", 0)
+                    )
+                    if self._dcn_wq_info.get("speculative"):
+                        fleet_local.phases["wq_spec"] = 1.0
+                elif self._dcn_recovery is not None:
+                    # Claim-generation fencing (round 17): which claim
+                    # attempt produced this block, and for whom. gen > 0
+                    # marks a hand-off after a claimant death mid-recovery.
+                    fleet_local.phases["recovery_gen"] = float(
+                        self._dcn_recovery.get("gen", 0)
+                    )
+                    fleet_local.phases["recovery_for"] = float(
+                        self._dcn_recovery.get("for_pid", -1)
+                    )
+            fleet_tel = None
+            # ---- THE end-of-replay gather (round 11, parallel.dcn) ----
+            # The one point per replay where processes exchange data: every
+            # per-scenario result array is concatenated across the contiguous
+            # per-process blocks, in process order — bit-identical to what the
+            # single-process mesh run assembles. Everything above this line
+            # (the whole chunk loop, the boundary passes, the result fetches)
+            # was process-local.
+            process_count = 1
+            if self._dcn_sliced:
+                if hb_on:
+                    # Final beacon before blocking in the gather: siblings'
+                    # attributed-timeout diagnostics see "state=gather" rather
+                    # than a stale mid-replay chunk.
+                    dcn.heartbeat(
+                        n_chunks - 1,
+                        total=n_chunks,
+                        block=hb_block,
+                        wall_s=wall,
+                        phases=run_phases.acc,
+                        state="gather",
+                        # Fleet utilization gauge (round 13): this process's
+                        # mean CPU utilization over its local scenario block —
+                        # already computed above, so the beacon stays free of
+                        # extra D2H. dcn_launch --watch renders it next to
+                        # the live-buffer gauge.
+                        extra=(
+                            {"util_cpu": round(float(np.mean(util)), 4)}
+                            if util is not None and len(util)
+                            else None
+                        ),
+                    )
+                parts = dcn.gather(
+                    "whatif",
+                    dict(
+                        placed=placed,
+                        assignments=assignments,
+                        util=util,
+                        preemptions=kube_preempt,
+                        dropped=dropped,
+                        evictions=kube_evict,
+                        resched=kube_resched,
+                        stranded=kube_stranded,
+                        evict_lat=kube_lat,
+                        lat50=sc_lat_p50,
+                        lat90=sc_lat_p90,
+                        lat99=sc_lat_p99,
+                        frag_stranded=frag_stranded,
+                        frag_index=frag_index,
+                        frag_pack=frag_pack,
+                        telemetry=sc_telemetry,
+                        fleet=fleet_local,
+                    ),
+                    # Survivor rebalance (round 15): with KSIM_DCN_RECOVER on,
+                    # a stale sibling's block is claimed and re-executed
+                    # through this callback instead of failing the fleet.
+                    recover=(
+                        self._dcn_recover_block
+                        if self._dcn_rebuild is not None
                         else None
                     ),
                 )
-            parts = dcn.gather(
-                "whatif",
-                dict(
-                    placed=placed,
-                    assignments=assignments,
-                    util=util,
-                    preemptions=kube_preempt,
-                    dropped=dropped,
-                    evictions=kube_evict,
-                    resched=kube_resched,
-                    stranded=kube_stranded,
-                    evict_lat=kube_lat,
-                    lat50=sc_lat_p50,
-                    lat90=sc_lat_p90,
-                    lat99=sc_lat_p99,
-                    frag_stranded=frag_stranded,
-                    frag_index=frag_index,
-                    frag_pack=frag_pack,
-                    telemetry=sc_telemetry,
-                    fleet=fleet_local,
-                ),
-                # Survivor rebalance (round 15): with KSIM_DCN_RECOVER on,
-                # a stale sibling's block is claimed and re-executed
-                # through this callback instead of failing the fleet.
-                recover=(
-                    self._dcn_recover_block
-                    if self._dcn_rebuild is not None
+                # Spare processes contribute liveness, not scenarios — their
+                # sentinel parts are dropped before concatenation (worker
+                # parts are the contiguous pids 0..workers-1, still in global
+                # scenario order).
+                parts = [
+                    p for p in parts
+                    if not (isinstance(p, dict) and p.get("spare"))
+                ]
+
+                def _cat(k):
+                    if parts[0][k] is None:
+                        return None
+                    return np.concatenate([p[k] for p in parts], axis=0)
+
+                placed = _cat("placed")
+                assignments = _cat("assignments")
+                util = _cat("util")
+                kube_preempt = _cat("preemptions")
+                dropped = _cat("dropped")
+                kube_evict = _cat("evictions")
+                kube_resched = _cat("resched")
+                kube_stranded = _cat("stranded")
+                kube_lat = _cat("evict_lat")
+                sc_lat_p50 = _cat("lat50")
+                sc_lat_p90 = _cat("lat90")
+                sc_lat_p99 = _cat("lat99")
+                frag_stranded = _cat("frag_stranded")
+                frag_index = _cat("frag_index")
+                frag_pack = _cat("frag_pack")
+                sc_telemetry = (
+                    None
+                    if parts[0]["telemetry"] is None
+                    else [t for p in parts for t in p["telemetry"]]
+                )
+                if parts[0].get("fleet") is not None:
+                    # Fleet merge: phases land under "p<pid>/<phase>", the
+                    # aggregates are exact merges over the global scenario
+                    # order — bit-matching the single-process oracle. A part
+                    # recovered by a claimant arrives with its phases ALREADY
+                    # scoped "p<claimant>/..." (see _dcn_recover_block) —
+                    # merge passes "/"-scoped keys through unprefixed, so
+                    # recovered wall clock lands under the pid that spent it.
+                    fleet_tel = ReplayTelemetry.merge(
+                        [p["fleet"] for p in parts],
+                        process_ids=list(range(len(parts))),
+                    )
+                process_count = jax.process_count()
+                # Device-footprint provenance counts block-owning workers
+                # only: spares ran no scenario over their devices.
+                dev_scale = len(parts)
+            elif fleet_local is not None:
+                # Single-process runs get the SAME shape ("p0/..." phase keys)
+                # so consumers never branch on process_count. A recovery
+                # engine (round 15) scopes its phases under the CLAIMANT's
+                # pid, keeping per-process attribution honest after a merge.
+                fleet_tel = ReplayTelemetry.merge(
+                    [fleet_local],
+                    process_ids=[
+                        jax.process_index()
+                        if self._dcn_recovery is not None
+                        else 0
+                    ],
+                )
+                dev_scale = process_count
+            else:
+                dev_scale = process_count
+            total = int(placed.sum())
+            ndev_local = int(self.mesh.devices.size) if self.mesh is not None else 1
+            return WhatIfResult(
+                placed=placed,
+                unschedulable=(to_schedule - placed).astype(np.int32),
+                total_placed=total,
+                wall_clock_s=wall,
+                placements_per_sec=total / wall if wall > 0 else 0.0,
+                assignments=assignments,
+                utilization_cpu=util,
+                completions_on=self.completions_on,
+                engine=self.engine,
+                preemptions=kube_preempt,
+                retry_dropped=dropped,
+                evictions=kube_evict,
+                evict_rescheduled=kube_resched,
+                evict_stranded=kube_stranded,
+                evict_latency_mean=kube_lat,
+                latency_p50=sc_lat_p50,
+                latency_p90=sc_lat_p90,
+                latency_p99=sc_lat_p99,
+                stranded_cpu=frag_stranded,
+                frag_index_cpu=frag_index,
+                packing_efficiency=frag_pack,
+                scenario_telemetry=sc_telemetry,
+                fleet_telemetry=fleet_tel,
+                # Global footprint: worker count × local devices when the
+                # scenario axis was DCN-sliced (the local mesh is one worker's
+                # share of the fleet that produced the gathered result; spare
+                # processes contribute no compute).
+                n_devices=ndev_local * dev_scale,
+                mesh_shape=(
+                    dict(zip(
+                        self.mesh.axis_names,
+                        (
+                            int(d) * dev_scale
+                            for d in self.mesh.devices.shape
+                        ),
+                    ))
+                    if self.mesh is not None
                     else None
                 ),
+                process_count=process_count,
             )
-            # Spare processes contribute liveness, not scenarios — their
-            # sentinel parts are dropped before concatenation (worker
-            # parts are the contiguous pids 0..workers-1, still in global
-            # scenario order).
-            parts = [
-                p for p in parts
-                if not (isinstance(p, dict) and p.get("spare"))
-            ]
-
-            def _cat(k):
-                if parts[0][k] is None:
-                    return None
-                return np.concatenate([p[k] for p in parts], axis=0)
-
-            placed = _cat("placed")
-            assignments = _cat("assignments")
-            util = _cat("util")
-            kube_preempt = _cat("preemptions")
-            dropped = _cat("dropped")
-            kube_evict = _cat("evictions")
-            kube_resched = _cat("resched")
-            kube_stranded = _cat("stranded")
-            kube_lat = _cat("evict_lat")
-            sc_lat_p50 = _cat("lat50")
-            sc_lat_p90 = _cat("lat90")
-            sc_lat_p99 = _cat("lat99")
-            frag_stranded = _cat("frag_stranded")
-            frag_index = _cat("frag_index")
-            frag_pack = _cat("frag_pack")
-            sc_telemetry = (
-                None
-                if parts[0]["telemetry"] is None
-                else [t for p in parts for t in p["telemetry"]]
-            )
-            if parts[0].get("fleet") is not None:
-                # Fleet merge: phases land under "p<pid>/<phase>", the
-                # aggregates are exact merges over the global scenario
-                # order — bit-matching the single-process oracle. A part
-                # recovered by a claimant arrives with its phases ALREADY
-                # scoped "p<claimant>/..." (see _dcn_recover_block) —
-                # merge passes "/"-scoped keys through unprefixed, so
-                # recovered wall clock lands under the pid that spent it.
-                fleet_tel = ReplayTelemetry.merge(
-                    [p["fleet"] for p in parts],
-                    process_ids=list(range(len(parts))),
-                )
-            process_count = jax.process_count()
-            # Device-footprint provenance counts block-owning workers
-            # only: spares ran no scenario over their devices.
-            dev_scale = len(parts)
-        elif fleet_local is not None:
-            # Single-process runs get the SAME shape ("p0/..." phase keys)
-            # so consumers never branch on process_count. A recovery
-            # engine (round 15) scopes its phases under the CLAIMANT's
-            # pid, keeping per-process attribution honest after a merge.
-            fleet_tel = ReplayTelemetry.merge(
-                [fleet_local],
-                process_ids=[
-                    jax.process_index()
-                    if self._dcn_recovery is not None
-                    else 0
-                ],
-            )
-            dev_scale = process_count
-        else:
-            dev_scale = process_count
-        total = int(placed.sum())
-        ndev_local = int(self.mesh.devices.size) if self.mesh is not None else 1
-        return WhatIfResult(
-            placed=placed,
-            unschedulable=(to_schedule - placed).astype(np.int32),
-            total_placed=total,
-            wall_clock_s=wall,
-            placements_per_sec=total / wall if wall > 0 else 0.0,
-            assignments=assignments,
-            utilization_cpu=util,
-            completions_on=self.completions_on,
-            engine=self.engine,
-            preemptions=kube_preempt,
-            retry_dropped=dropped,
-            evictions=kube_evict,
-            evict_rescheduled=kube_resched,
-            evict_stranded=kube_stranded,
-            evict_latency_mean=kube_lat,
-            latency_p50=sc_lat_p50,
-            latency_p90=sc_lat_p90,
-            latency_p99=sc_lat_p99,
-            stranded_cpu=frag_stranded,
-            frag_index_cpu=frag_index,
-            packing_efficiency=frag_pack,
-            scenario_telemetry=sc_telemetry,
-            fleet_telemetry=fleet_tel,
-            # Global footprint: worker count × local devices when the
-            # scenario axis was DCN-sliced (the local mesh is one worker's
-            # share of the fleet that produced the gathered result; spare
-            # processes contribute no compute).
-            n_devices=ndev_local * dev_scale,
-            mesh_shape=(
-                dict(zip(
-                    self.mesh.axis_names,
-                    (
-                        int(d) * dev_scale
-                        for d in self.mesh.devices.shape
-                    ),
-                ))
-                if self.mesh is not None
-                else None
-            ),
-            process_count=process_count,
-        )
 
 
 def uniform_scenarios(

@@ -1,13 +1,16 @@
 """Profiling hooks (SURVEY.md §5 tracing/profiling).
 
 - ``device_trace(dir)``: jax.profiler trace (TensorBoard/Perfetto) around a
-  replay.
-- ``profiling_active()`` / ``annotate(name)``: the round-12 device-profiler
-  hook contract — ``KSIM_PROFILE_DIR`` arms
-  ``jax.profiler.TraceAnnotation`` markers on the telemetry PHASE_NAMES
-  phases and chunk dispatch, so fused-program device time is attributable
-  in XLA traces. Off by default; annotations never change results (pinned
-  in tests/test_telemetry.py).
+  replay, with the program's host spans armed for its extent.
+- ``profiling_active()`` / ``make_span(timers)``: the device-profiler hook
+  contract. ``KSIM_PROFILE_DIR`` arms the program's host spans, and
+  ``make_span`` is their one primitive: a factory both engines consult
+  once per ``replay()`` / ``run()`` for ``span(name, **counts)``, the
+  phase timer under a ``jax.profiler.TraceAnnotation`` when armed, so that
+  what the host does lies on the device trace's clock. Off by default;
+  spans never change results (pinned in tests/test_telemetry.py).
+  The names a span can carry are ``sim.telemetry``'s, beside
+  ``PHASE_NAMES``: ``HOST_SPAN_NAMES`` / ``CHUNK_SPAN`` / ``ROOT_SPANS``.
 - ``live_buffer_stats()``: live-buffer / memory watermark gauge.
 - ``STAGES`` / ``stage(name)``: the one vocabulary of the device programs'
   stages, written into the HLO by ``jax.named_scope`` (trace-time metadata,
@@ -61,21 +64,66 @@ def profile_dir() -> Optional[str]:
 
 def profiling_active() -> bool:
     """True when profiler hooks should annotate. One env-dict lookup — the
-    replay engines consult this per replay (not per chunk) to build their
-    tick functions."""
+    engines consult this per ``replay()`` / ``run()`` (not per chunk),
+    through :func:`make_span`."""
     return bool(profile_dir())
 
 
-def annotate(name: str):
-    """``jax.profiler.TraceAnnotation(name)`` when profiling is active,
-    else a no-op context. Annotations outside a live ``jax.profiler.trace``
-    are harmless, so callers gate on :func:`profiling_active` only to skip
-    the object construction on hot paths."""
-    if not profiling_active():
-        return contextlib.nullcontext()
-    import jax
+#: The no-op context every unarmed, untimed span is (one shared object).
+NULL_SPAN = contextlib.nullcontext()
 
-    return jax.profiler.TraceAnnotation(name)
+
+@contextlib.contextmanager
+def _stacked(outer, inner):
+    with outer, inner:
+        yield
+
+
+class Span:
+    """``span(name, **counts)``: a context for one phase of a call. The
+    phase timer (``timers.tick(name)``) when ``timers`` collect, under a
+    ``jax.profiler.TraceAnnotation(name, **counts)`` when armed (``counts``
+    become the trace event's stats); armed or not, with no timers, the
+    timer falls away, and with neither it is the shared no-op context.
+    ``span.mark(name, **counts)`` is the same span for a name that is no
+    phase (``chunk:<i>``, ``mesh_put``, a root): never timed. Built by
+    :func:`make_span`; ``timers`` may be bound after the root is open."""
+
+    __slots__ = ("_annotation", "timers")
+
+    def __init__(self, annotation, timers=None):
+        self._annotation = annotation
+        self.timers = timers
+
+    @property
+    def armed(self) -> bool:
+        return self._annotation is not None
+
+    def __call__(self, name: str, **counts):
+        if self.timers is None:
+            return self.mark(name, **counts)
+        tick = self.timers.tick(name)
+        if self._annotation is None:
+            return tick
+        return _stacked(self._annotation(name, **counts), tick)
+
+    def mark(self, name: str, **counts):
+        if self._annotation is None:
+            return NULL_SPAN
+        return self._annotation(name, **counts)
+
+
+def make_span(timers=None) -> Span:
+    """The span primitive of one ``replay()`` / ``run()`` call.
+    :func:`profiling_active` is read ONCE, here, and nowhere per chunk:
+    unarmed, a span is exactly the phase timer (two ``perf_counter`` reads)
+    or, with no ``timers`` (``PhaseTimers``), the shared no-op."""
+    annotation = None
+    if profiling_active():
+        import jax
+
+        annotation = jax.profiler.TraceAnnotation
+    return Span(annotation, timers)
 
 
 def live_buffer_stats(collect: bool = True) -> dict:
@@ -113,13 +161,24 @@ def live_buffer_stats(collect: bool = True) -> dict:
 
 @contextlib.contextmanager
 def device_trace(log_dir: Optional[str]):
+    """``jax.profiler.trace(log_dir)`` with ``KSIM_PROFILE_DIR`` set to it
+    for its extent (and restored after): the trace holds the program's
+    host spans beside the device's ops. Nothing for an empty ``log_dir``."""
     import jax
 
     if not log_dir:
         yield
         return
-    with jax.profiler.trace(log_dir):
-        yield
+    before = os.environ.get("KSIM_PROFILE_DIR")
+    os.environ["KSIM_PROFILE_DIR"] = str(log_dir)
+    try:
+        with jax.profiler.trace(log_dir):
+            yield
+    finally:
+        if before is None:
+            os.environ.pop("KSIM_PROFILE_DIR", None)
+        else:
+            os.environ["KSIM_PROFILE_DIR"] = before
 
 
 def stage(name: str):

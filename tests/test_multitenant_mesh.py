@@ -170,32 +170,38 @@ def test_the_mesh_spans_nest_in_stage_and_handback(batch, monkeypatch, tmp_path)
     opened, stack = [], []
 
     class Span:
-        def __init__(self, name):
-            self.name = name
+        def __init__(self, name, **counts):
+            self.name, self.counts = name, counts
 
         def __enter__(self):
-            opened.append((self.name, tuple(stack)))
+            opened.append((self.name, tuple(stack), self.counts))
             stack.append(self.name)
 
         def __exit__(self, *exc):
             stack.pop()
 
+    # every span of the program is the factory's (profiling.make_span),
+    # which takes jax.profiler.TraceAnnotation when armed
     monkeypatch.setenv("KSIM_PROFILE_DIR", str(tmp_path))
-    monkeypatch.setattr(W, "_annotate", Span)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Span)
     from kubernetes_simulator_tpu.utils import profiling
 
-    monkeypatch.setattr(profiling, "annotate", Span)
     monkeypatch.setattr(profiling, "register_call", lambda fn, args: None)
     monkeypatch.setattr(W, "_register_call", lambda fn, args: None)
-    eng.run()
-    inside = {name: {outer for n, outer in opened if n == name}
+    first = eng.run().fleet_telemetry.summary()["mesh"]
+    inside = {name: {outer for n, outer, _ in opened if n == name}
               for name in ("mesh_put", "mesh_fetch")}
-    assert inside["mesh_put"] == {("stage",)}
-    assert inside["mesh_fetch"] == {("handback",)}
+    assert inside["mesh_put"] == {("whatif_run:0", "stage")}
+    assert inside["mesh_fetch"] == {("whatif_run:0", "handback")}
     assert not stack
+    # each carries what it counts as the event's stats
+    put = sum(c["bytes"] for n, _, c in opened if n == "mesh_put")
+    fetch = [c for n, _, c in opened if n == "mesh_fetch"]
+    assert put == first["put_bytes"] and fetch == [{"bytes": first["fetch_bytes"]}]
     del opened[:]
     eng.run()
-    assert [n for n, _ in opened if n.startswith("mesh_")] == ["mesh_fetch"]
+    assert [n for n, *_ in opened if n.startswith("mesh_")] == ["mesh_fetch"]
+    assert opened[0][:2] == ("whatif_run:1", ())
 
 
 def test_a_new_scenario_batch_is_put_on_the_devices_again(batch):

@@ -567,7 +567,7 @@ def test_profiler_annotations_bit_parity(tmp_path, monkeypatch):
     # registering lowers nothing and changes no answer or checkpoint.
     profiling._PROGRAMS.clear()
     ec, ep = _light_trace(num_pods=16, num_nodes=4, duration=4.0)
-    plain = {}
+    plain, whatif = {}, {}
     for armed in (False, True):
         if armed:
             monkeypatch.setenv("KSIM_PROFILE_DIR", str(tmp_path))
@@ -580,6 +580,19 @@ def test_profiler_annotations_bit_parity(tmp_path, monkeypatch):
         assert set(profiling._PROGRAMS) == (
             {"jit_chunk_fn", "jit_release_subtract"} if armed else set()
         )
+        # ... and the what-if on its device-release path, every task's
+        # node handed back: stage, dispatch, boundary_fold, device_wait,
+        # gather and handback are spans when armed, and change no answer.
+        whatif[armed] = WhatIfEngine(
+            ec, ep, [Scenario(), Scenario()], cfg, wave_width=2,
+            chunk_waves=2, completions=True, collect_assignments=True,
+        ).run()
+    assert whatif[True].fleet_telemetry.phases.keys() == (
+        whatif[False].fleet_telemetry.phases.keys()
+    )
+    np.testing.assert_array_equal(
+        whatif[False].assignments, whatif[True].assignments
+    )
     (poff, ck_off), (pon, ck_on) = plain[False], plain[True]
     assert poff.placed == 16 and poff.state.used[:, 0].sum() < 16  # released
     np.testing.assert_array_equal(poff.assignments, pon.assignments)
