@@ -26,7 +26,9 @@ no result line (nothing here catches a phase's exception):
                   releases to one node (prints ``release_rounds``), and
                   the Borg case on 16 stride-zoned nodes, single replay
                   against what-if scenario 0; each case prints its
-                  ``select_form``, ``zone_packed`` required of the zoned one;
+                  ``select_form``, ``zone_packed`` required of the zoned one,
+                  and every what-if batch ``inwave_corrections``
+                  ``resolved_terms``;
                   and the default plugin set on 160 nodes as an
                   arrivals-only what-if batch (``two_pass``, host-scale
                   count rows), scenario 0 against the single replay; and
@@ -256,6 +258,16 @@ def phase_parity() -> dict:
                 f"{what}: select_form {form!r}, not {want!r}")
         return form
 
+    def resolved_terms(result, what):
+        """A what-if batch's in-wave usage corrections
+        (ops.tpu3.inwave_corrections): collisions resolved among the
+        scenarios' scalars, a compare and R selects a node a term."""
+        form = result.fleet_telemetry.summary().get("inwave_corrections")
+        say(f"{what}: inwave_corrections {form}")
+        require(form == "resolved_terms",
+                f"{what}: inwave_corrections {form!r}, not 'resolved_terms'")
+        return form
+
     out = {}
     # (a) the head of the config-2 case, full default plugin set.
     cfg = SimConfig.load(CONFIG2)
@@ -340,6 +352,7 @@ def phase_parity() -> dict:
         "scenarios": len(scen),
         "placed": [int(x) for x in r_dev.placed],
         "select_form": select_form(r_dev, "borg what-if"),
+        "inwave_corrections": resolved_terms(r_dev, "borg what-if"),
     }
 
     # (d) the release-heavy case. The CPU backend's dot is exact and
@@ -379,6 +392,7 @@ def phase_parity() -> dict:
         "unschedulable": refs[0].unschedulable,
         "release_rounds": rounds,
         "select_form": select_form(r_heavy, "release-heavy what-if"),
+        "inwave_corrections": resolved_terms(r_heavy, "release-heavy what-if"),
     }
     say(f"release-heavy what-if: release_rounds {rounds}")
 
@@ -413,6 +427,7 @@ def phase_parity() -> dict:
             select_form(dev, "zoned single replay", "zone_packed"),
             select_form(r_zoned, "zoned what-if", "zone_packed"),
         ],
+        "inwave_corrections": resolved_terms(r_zoned, "zoned what-if"),
     }
     # (f) the default plugin set as a what-if batch, arrivals only, on 160
     # nodes: hostname is then a host-scale topology, as at 5,000, so the
@@ -449,6 +464,7 @@ def phase_parity() -> dict:
         "placed": [int(x) for x in r_plug.placed], "count_planes": planes,
         "select_form": select_form(r_plug, "default-plugins what-if",
                                    "two_pass"),
+        "inwave_corrections": resolved_terms(r_plug, "default-plugins what-if"),
     }
     # (g) the normalize rows divide exactly. The chip's float32 division is
     # not correctly rounded (floor(6100 / 61) reads 99), which the CPU

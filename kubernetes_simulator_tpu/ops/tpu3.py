@@ -25,8 +25,9 @@ scenario count. v3 restructures the STATE, not the semantics:
   with k. (The k-term form re-evaluates those k terms over all N inside
   both [N]-wide reduces of slot k: on a v5e at N=10,000 the spread reduce
   took 1.5 µs in slot 0 and 9.2 in slot 7, PERF.md §5.) The tier-
-  preemption program and the vmapped what-if program keep the k-term
-  form — see :func:`inwave_corrections`.
+  preemption program keeps the k-term form; the vmapped what-if program
+  keeps the k compares but picks sums resolved among the scenarios'
+  scalars — see :func:`inwave_corrections`.
 - **One node-wide reduce a slot** where every score row but the fit score
   is constant inside a zone (the Borg shape): the best packed node of each
   zone gives the spread's zone feasibility and, once the zone scores are
@@ -1031,7 +1032,9 @@ def make_wave_step3(
         or (st.SP and (st.has_dns or not spread_dom_hilo))
     )
     pack_select = wvec is None and pack_select_ok(spec, w_cfg, dc.allocatable.shape[0])
-    corr_plane = inwave_corrections(st, scenario_axis) == "plane"
+    corr_form = inwave_corrections(st, scenario_axis)
+    corr_plane = corr_form == "plane"
+    corr_resolved = corr_form == "resolved_terms"
     host_rows_read = host_row_reads(scenario_axis) == "rows"
     zone_select = (
         select_form(
@@ -1157,6 +1160,9 @@ def make_wave_step3(
                 # in slot 0.
                 used_corr = jnp.zeros((R, -(-N // 128) * 128), jnp.float32)
                 lane = jnp.arange(128, dtype=jnp.int32)
+            # "resolved_terms": per earlier slot, the R sums its node had
+            # taken in this wave once that slot was bound (resolve_usage).
+            node_sums = []
         choices, placeds, dom_ats = [], [], []
         for k in range(wave_width):
             with stage("ksim.reads"):
@@ -1165,10 +1171,12 @@ def make_wave_step3(
                     vals_h = host_rows_at(st, carry, pre.row_h[k])
 
             # --- exact in-wave corrections from pods j<k -----------------
-            # Usage: the running plane, or (preemption, scenario batch) k
-            # one-hot terms rebuilt from the chosen-node indices inside the
-            # consuming fusions — see inwave_corrections(). Counts: domain-
-            # space or host-row terms, never materialized as carried values.
+            # Usage: the running plane, or k terms rebuilt from the chosen-
+            # node indices inside the consuming fusions: summed one-hot terms
+            # (preemption) or a chain of selects among sums resolved ahead
+            # of the pass (scenario batch) — see inwave_corrections().
+            # Counts: domain-space or host-row terms, never materialized as
+            # carried values.
             with stage("ksim.corrections"):
                 rows_corr = jnp.zeros((st.KT, Dcap), jnp.float32) if st.KT else None
                 valh_corr = (
@@ -1181,6 +1189,16 @@ def make_wave_step3(
                     used_corr_r = [used_corr[r, :N] for r in range(R)]
                 else:
                     used_corr_r = [jnp.zeros((N,), jnp.float32) for _ in range(R)]
+                if corr_resolved:
+                    # One compare and R selects a node a term, no add: the
+                    # outermost select is the latest slot's, whose sums
+                    # hold everything bound to its node so far.
+                    for j in range(k):
+                        at_j = iota_n == choices[j]
+                        used_corr_r = [
+                            jnp.where(at_j, node_sums[j][r], used_corr_r[r])
+                            for r in range(R)
+                        ]
                 if st.preemption and k > 0:
                     # An earlier in-wave eviction frees wave-start usage at the
                     # evicted node (evicted slots are excluded below).
@@ -1196,7 +1214,7 @@ def make_wave_step3(
                         wj_used = wj * (1.0 - evicted[j].astype(jnp.float32))
                     else:
                         wj_used = wj
-                    if not corr_plane:
+                    if corr_form == "terms":
                         oh_j = (iota_n == choices[j]).astype(jnp.float32)
                         for r in range(R):
                             used_corr_r[r] = (
@@ -1287,7 +1305,9 @@ def make_wave_step3(
                 # Nothing of a slot is materialized by hand. used1_r and
                 # `feasible` are re-derived from carry.used inside every
                 # [N]-wide reduce that reads them, the k usage terms of
-                # the "terms" form included: under select_form() ==
+                # the "terms" and "resolved_terms" forms included (of the
+                # latter only the selects: the sums they pick are scalars
+                # kept out of the fusion, resolve_usage): under select_form() ==
                 # "zone_packed" that is ONE reduce a slot (the best packed
                 # node per zone, below), otherwise two (the spread's zone
                 # feasibility and the select). A barrier on used1_r cost
@@ -1759,6 +1779,11 @@ def make_wave_step3(
                         # A miss (or padded slot) must not look like domain 0.
                         dom_at = jnp.where(placed, dom_at, float(PAD))
                     dom_ats.append(dom_at)
+            if corr_resolved and k + 1 < wave_width:
+                with stage("ksim.corrections"):
+                    node_sums.append(
+                        resolve_usage(node_sums, choices, node, placed, s.req)
+                    )
             choices.append(node)
             placeds.append(placed)
 
@@ -1975,6 +2000,40 @@ def make_wave_step3(
     return wave_step
 
 
+def resolve_usage(node_sums, choices, node, placed, req):
+    """The ``"resolved_terms"`` form's upkeep once a slot has chosen:
+    same-node collisions of a wave are resolved among scalars (``[S]``
+    vectors under a scenario axis), never on the node axis.
+
+    ``node_sums[j]`` holds, for every earlier slot j, the R float32 sums of
+    what the slots up to and including j bound to j's node asked for, added
+    from zero in slot order: the value the k-term form's sum of one-hot
+    terms takes at that node once slot j's term is in, to the bit (a term
+    adds +0.0 everywhere else, which changes no float32). The new slot
+    starts from the sums of the LATEST earlier slot on its node, else from
+    zero, and adds its own request if it was placed. Nothing is written
+    back to the earlier slots: the node-wide select takes a node's value
+    from the latest slot bound to it. Returns the new slot's R scalars
+    (scalars, not one vector: a vector is sliced apart again, in a fusion
+    of its own, before every node-wide pass that reads it).
+
+    The barrier hands the ``[N]``-wide fusions that select among the sums
+    R finished values a slot. Without it XLA spreads the chain over more
+    small fusions (237 against 232 a wave in the Borg what-if program) and
+    a v5e runs the batch 6% slower (PERF.md §6 PR 36); fused into a
+    node-wide pass the chain would be computed again for every register of
+    nodes."""
+    w = placed.astype(jnp.float32)
+    same = [choice_j == node for choice_j in choices]
+    sums = []
+    for r in range(req.shape[0]):
+        prior = jnp.zeros((), jnp.float32)
+        for same_j, sums_j in zip(same, node_sums):
+            prior = jnp.where(same_j, sums_j[r], prior)
+        sums.append(prior + w * req[r])
+    return jax.lax.optimization_barrier(sums)
+
+
 def inwave_corrections(st: V3Static, scenario_axis: bool = False) -> str:
     """Which form of the in-wave USAGE corrections a step built from ``st``
     carries — static per compiled program; a replay reports it as
@@ -1982,20 +2041,27 @@ def inwave_corrections(st: V3Static, scenario_axis: bool = False) -> str:
 
     ``"plane"``: one running ``[R, N]`` plane a wave, updated at each slot's
     chosen node (module docstring). ``"terms"``: slot k rebuilds k one-hot
-    terms from the chosen-node indices, fused into every ``[N]``-wide
-    consumer. Two programs keep the terms, both by a fact of how they are
-    built and neither by a switch:
+    terms from the chosen-node indices and sums them, fused into every
+    ``[N]``-wide consumer: a compare, a convert and per resource two
+    multiplies and an add a node a term. ``"resolved_terms"``: the same k
+    compares, but each picks R scalars that already hold the node's sum
+    (:func:`resolve_usage`), so a term is a compare and R selects. Which
+    one is a fact of how the step is built, never a switch:
 
-    - tier preemption: its correction starts from the eviction term and
-      drops evicted slots retroactively, an order of f32 additions a
-      running sum cannot reproduce bit for bit;
-    - a step mapped over a scenario axis (the what-if batch): there the
-      point update is a gather and a scatter of one block per scenario,
-      which a v5e runs 17x slower than the whole fused-terms program (128
-      scenarios x 10,000 nodes: 148 s a batch against 8.7, PERF.md §6
-      PR 26), and a plane written out whole is R x [S, N] of HBM traffic
-      per slot."""
-    return "terms" if (st.preemption or scenario_axis) else "plane"
+    - a step mapped over a scenario axis (the what-if batch) cannot keep
+      the plane: there the point update is a gather and a scatter of one
+      block per scenario, which a v5e runs 17x slower than the whole
+      fused-terms program (128 scenarios x 10,000 nodes: 148 s a batch
+      against 8.7, PERF.md §6 PR 26), and a plane written out whole is
+      R x [S, N] of HBM traffic per slot. Its correction starts from zero,
+      so the sums can be resolved per node ahead of the node-wide pass;
+    - tier preemption keeps the summed terms, mapped or not: its correction
+      starts from the eviction term and drops evicted slots retroactively,
+      an order of f32 additions that neither a running plane nor a
+      resolved sum reproduces bit for bit."""
+    if st.preemption:
+        return "terms"
+    return "resolved_terms" if scenario_axis else "plane"
 
 
 def host_row_reads(scenario_axis: bool = False) -> str:

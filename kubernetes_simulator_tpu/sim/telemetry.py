@@ -226,7 +226,8 @@ class ReplayTelemetry:
     # the configured one (sim.granularity). None where no chunk loop ran.
     chunk_waves: Optional[int] = None
     # Form of the in-wave usage corrections the v3 chunk program was built
-    # with (ops.tpu3.inwave_corrections): "plane" or "terms". None for v2.
+    # with (ops.tpu3.inwave_corrections): "plane", "terms" or
+    # "resolved_terms". None for v2.
     inwave_corrections: Optional[str] = None
     # Whether a slot of the v3 chunk program finds the spread's zone
     # feasibility and its node in one node-wide reduce or in two
@@ -389,7 +390,8 @@ class ReplayTelemetry:
         tel.series = series
         # Engine-level counters: parts are disjoint scenario blocks of one
         # batch (or none carries them).
-        for key in ("chunk_waves", "select_form", "count_planes"):
+        for key in ("chunk_waves", "inwave_corrections", "select_form",
+                    "count_planes"):
             values = [getattr(p, key) for _, p in keep]
             if all(v == values[0] for v in values):
                 setattr(tel, key, values[0])

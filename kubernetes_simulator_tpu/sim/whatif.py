@@ -4027,6 +4027,9 @@ class WhatIfEngine:
                         traced_weights=self._policies is not None,
                         dyn_labels=self._dyn_dev is not None,
                     )
+                    fleet_local.inwave_corrections = V3.inwave_corrections(
+                        self.static3, scenario_axis=True
+                    )
                     fleet_local.count_planes = V3.count_planes(
                         self.static3, scenario_axis=True
                     )

@@ -208,15 +208,22 @@ def test_v3_plane_equals_terms_bit_for_bit(seed, monkeypatch):
     np.testing.assert_array_equal(plane.state.used, terms.state.used)
 
 
-def _eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs nested in it."""
+def _scoped_eqns(jaxpr, outer=""):
+    """(equation, its whole name stack) over a jaxpr and the jaxprs nested
+    in it: a nested jaxpr's name stacks are relative to its call."""
     for eqn in jaxpr.eqns:
-        yield eqn
+        scope = f"{outer}/{eqn.source_info.name_stack}"
+        yield eqn, scope
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
+                    yield from _scoped_eqns(sub, scope)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    return (eqn for eqn, _ in _scoped_eqns(jaxpr))
 
 
 def _node_wide_compares(ec, ep, wave_width):
@@ -259,8 +266,10 @@ def test_v3_node_wide_compares_grow_linearly_with_wave_width(monkeypatch):
 
 @pytest.mark.parametrize(
     "preemption, scenario_axis, form",
-    [(False, False, "plane"), (True, False, "terms"), (False, True, "terms")],
-    ids=["replay", "tier-preemption", "scenario-axis"],
+    [(False, False, "plane"), (True, False, "terms"),
+     (False, True, "resolved_terms"), (True, True, "terms")],
+    ids=["replay", "tier-preemption", "scenario-axis",
+         "tier-preemption-on-a-scenario-axis"],
 )
 def test_inwave_corrections_form_follows_how_the_step_is_built(
     preemption, scenario_axis, form
@@ -274,6 +283,207 @@ def test_inwave_corrections_form_follows_how_the_step_is_built(
         preemption=preemption,
     )
     assert V3.inwave_corrections(st, scenario_axis) == form
+
+
+# --- in-wave usage corrections resolved among scalars (ops.tpu3) ----------
+# A step mapped over a scenario axis keeps the k chosen-node compares of the
+# k-term form, but each picks R scalars that already hold the node's sum
+# (`resolve_usage`). Held to the plane and to the summed terms in the single
+# replay (the form forced on it), and to the summed terms under `vmap`.
+
+
+def _pileup(seed=0, n_pods=96):
+    """One node far larger than the other: LeastAllocated sends all 8 slots
+    of every wave to it until it is full, with requests no f32 holds."""
+    rng = np.random.default_rng(seed)
+    return _mini([50.3, 0.9], list(rng.choice([0.1, 0.3, 0.7, 1.1, 1.3], size=n_pods)))
+
+
+def _extended_resource_case(seed=0, n_nodes=12, n_pods=120):
+    """R = 4: every fourth node holds 8 `example.com/dev`, the others 0;
+    a third of the pods ask for 1 or 2 of them, all for a cpu request no
+    float32 holds exactly. The extended resource runs out first."""
+    from kubernetes_simulator_tpu.models.core import Cluster, Node, Pod
+
+    rng = np.random.default_rng(seed)
+    nodes = [
+        Node(f"n{i}", capacity={
+            "cpu": float(c), "memory": 8 * 2**30, "pods": 110,
+            **({"example.com/dev": 8} if i % 4 == 0 else {}),
+        })
+        for i, c in enumerate(rng.choice([3.3, 4.7, 6.1], size=n_nodes))
+    ]
+    pods = [
+        Pod(f"p{i}", arrival_time=float(i), requests={
+            "cpu": float(rng.choice([0.1, 0.3, 0.7, 1.1])),
+            **({"example.com/dev": int(rng.choice([1, 2]))} if i % 3 == 0 else {}),
+        })
+        for i in range(n_pods)
+    ]
+    return encode(Cluster(nodes=nodes), pods)
+
+
+# name -> (trace, the most slots of one wave that have to meet on one node)
+_COLLIDING = {
+    "extended-resource": (_extended_resource_case, 2),
+    "nondyadic-0": (lambda: _nondyadic(0), 2),
+    "nondyadic-1": (lambda: _nondyadic(1), 2),
+    "nondyadic-2": (lambda: _nondyadic(2), 2),
+    "pileup": (_pileup, 8),
+}
+
+
+def _most_slots_on_one_node(assignments, wave_width=8):
+    waves = assignments[: len(assignments) // wave_width * wave_width]
+    return max(
+        np.bincount(w[w >= 0]).max()
+        for w in waves.reshape(-1, wave_width) if (w >= 0).any()
+    )
+
+
+def _forced(monkeypatch, form):
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    monkeypatch.setattr(V3, "inwave_corrections", lambda *a, **k: form)
+
+
+def _whatif_arrivals(ec, ep, scenarios, chunk_waves=4):
+    """(result, the final device state, the engine's static facts) of an
+    arrivals-only what-if that hands every pod's node back."""
+    from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
+
+    eng = WhatIfEngine(ec, ep, scenarios, FrameworkConfig(), wave_width=8,
+                       chunk_waves=chunk_waves, collect_assignments=True)
+    assert eng.engine == "v3"
+    last, chunk_fn = {}, eng._chunk_fn
+
+    def spy(*args):
+        out = chunk_fn(*args)
+        last["state"] = out[0]
+        return out
+
+    eng._chunk_fn = spy
+    return eng.run(), last["state"], eng.static3
+
+
+def _whatif_used(ec, ep, scenarios):
+    """(result, final ``used`` [S, R, N]) of an arrivals-only what-if."""
+    res, state, _ = _whatif_arrivals(ec, ep, scenarios)
+    return res, np.asarray(state.used)
+
+
+@pytest.mark.parametrize("case", sorted(_COLLIDING))
+def test_v3_resolved_terms_equal_plane_and_terms_bit_for_bit(case, monkeypatch):
+    """96 pods on 5 nodes, or piled onto one, or 120 of which a third ask for
+    an extended resource (R = 4): 2 to 8 slots of a wave collide on one
+    node, with requests no float32 holds exactly. The single replay
+    built in all three forms gives the same placements and the same final
+    ``used``, to the bit."""
+    make, collide = _COLLIDING[case]
+    ec, ep = make()
+    cfg = FrameworkConfig()
+    runs = {"plane": JaxReplayEngine(ec, ep, cfg, engine="v3").replay()}
+    for form in ("resolved_terms", "terms"):
+        _forced(monkeypatch, form)
+        runs[form] = JaxReplayEngine(ec, ep, cfg, engine="v3").replay()
+    for form, run in runs.items():
+        assert run.telemetry.summary()["inwave_corrections"] == form
+    got = runs["resolved_terms"]
+    assert got.unschedulable > 0  # contended: fit edges are met
+    assert _most_slots_on_one_node(got.assignments) >= collide
+    for form in ("plane", "terms"):
+        np.testing.assert_array_equal(got.assignments, runs[form].assignments)
+        np.testing.assert_array_equal(got.state.used, runs[form].state.used)
+
+
+@pytest.mark.parametrize("case", sorted(_COLLIDING))
+def test_whatif_resolved_terms_equal_summed_terms_bit_for_bit(case, monkeypatch):
+    """The same traces under a scenario axis of 4 (`vmap`): every scenario's
+    placements and final ``used`` equal the summed terms', and scenario 0
+    the single replay's plane."""
+    make, collide = _COLLIDING[case]
+    ec, ep = make()
+    scen = _perturbed(ec.num_nodes, 4)
+    res, used = _whatif_used(ec, ep, scen)
+    assert res.fleet_telemetry.summary()["inwave_corrections"] == "resolved_terms"
+    single = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v3").replay()
+    np.testing.assert_array_equal(res.assignments[0], single.assignments)
+    np.testing.assert_array_equal(used[0], single.state.used.T)
+    assert _most_slots_on_one_node(res.assignments[0]) >= collide
+    assert (res.assignments[1:] != res.assignments[0]).any()
+    if case == "extended-resource":  # R = 4, and the fourth row decides
+        assert (ec.allocatable[:, 3] == 0).sum() == 9
+        assert (used[:, 3] > 0).any() and (res.unschedulable > 0).all()
+    _forced(monkeypatch, "terms")
+    parent, parent_used = _whatif_used(ec, ep, scen)
+    assert parent.fleet_telemetry.summary()["inwave_corrections"] == "terms"
+    np.testing.assert_array_equal(res.assignments, parent.assignments)
+    np.testing.assert_array_equal(used, parent_used)
+
+
+@pytest.mark.parametrize("trap", sorted(_WAVE_TRAPS))
+def test_whatif_resolved_terms_on_the_wave_traps(trap, monkeypatch):
+    """The wave traps under a scenario axis of 2: both copies of the
+    cluster place as the trap says, and as the summed terms do."""
+    from kubernetes_simulator_tpu.sim.whatif import Scenario
+
+    node_cpus, pod_cpus, groups, gangs, want = _WAVE_TRAPS[trap]
+    ec, ep = _mini(node_cpus, pod_cpus, groups, gangs)
+    res, used = _whatif_used(ec, ep, [Scenario(), Scenario()])
+    assert res.fleet_telemetry.summary()["inwave_corrections"] == "resolved_terms"
+    np.testing.assert_array_equal(res.assignments, [want, want])
+    _forced(monkeypatch, "terms")
+    parent, parent_used = _whatif_used(ec, ep, [Scenario(), Scenario()])
+    np.testing.assert_array_equal(res.assignments, parent.assignments)
+    np.testing.assert_array_equal(used, parent_used)
+
+
+def _vmapped_step_eqns(ec, ep, scenarios=2):
+    """Every equation of the what-if chunk program's jaxpr (the step under
+    `vmap`), with its name stack."""
+    import jax
+
+    from kubernetes_simulator_tpu.sim.whatif import Scenario, WhatIfEngine
+
+    eng = WhatIfEngine(ec, ep, [Scenario()] * scenarios, FrameworkConfig(),
+                       wave_width=8, chunk_waves=2, collect_assignments=True)
+    assert eng.engine == "v3"
+    seen, chunk_fn = {}, eng._chunk_fn
+
+    def spy(*args):
+        seen.setdefault("args", args)
+        return chunk_fn(*args)
+
+    eng._chunk_fn = spy
+    eng.run()
+    return list(_scoped_eqns(jax.make_jaxpr(chunk_fn)(*seen["args"]).jaxpr))
+
+
+def test_whatif_usage_terms_are_a_compare_and_selects_on_the_node_axis():
+    """In the vmapped program the node-wide chosen-node compares of
+    ``ksim.corrections`` stay W(W-1)/2 = 28, and nothing node-wide in that
+    stage is float arithmetic: a term is a compare and R selects. Keeps a
+    refactor from fusing the multiply-adds back in."""
+    import jax.numpy as jnp
+
+    S, N = 2, 37
+    ec, ep = _mini([4.0] * N, [1.0] * 64)
+    node_wide = [
+        eqn for eqn, scope in _vmapped_step_eqns(ec, ep, S)
+        if "ksim.corrections" in scope
+        and any(v.aval.shape[-1:] == (N,) for v in eqn.outvars)
+    ]
+    compares = [
+        eqn for eqn in node_wide
+        if eqn.primitive.name == "eq"
+        and jnp.issubdtype(eqn.invars[0].aval.dtype, jnp.integer)
+    ]
+    assert len(compares) == 28
+    arithmetic = {"add", "sub", "mul", "div", "dot_general", "convert_element_type"}
+    assert not [e.primitive.name for e in node_wide
+                if e.primitive.name in arithmetic]
+    selects = [e for e in node_wide if e.primitive.name == "select_n"]
+    assert len(selects) == 28 * ec.allocatable.shape[1]
 
 
 # --- one node-wide reduce a slot: the zone-packed select (ops.tpu3) -------
@@ -572,24 +782,9 @@ def _run_with_host_planes(ec, ep, mapping):
         res = eng.replay()
         return (res.assignments, res.state.match_count, res.state.anti_active,
                 eng.static3)
-    from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
-
-    scen = _perturbed(ec.num_nodes, 4)
-    eng = WhatIfEngine(ec, ep, scen, cfg, wave_width=8, chunk_waves=4,
-                       collect_assignments=True)
-    assert eng.engine == "v3"
-    last, chunk_fn = {}, eng._chunk_fn
-
-    def spy(*args):
-        out = chunk_fn(*args)
-        last["state"] = out[0]
-        return out
-
-    eng._chunk_fn = spy
-    res = eng.run()
-    st = last["state"]
+    res, st, static3 = _whatif_arrivals(ec, ep, _perturbed(ec.num_nodes, 4))
     return (res.assignments, np.asarray(st.mc_host, np.float32),
-            np.asarray(st.anti_host, np.float32), eng.static3)
+            np.asarray(st.anti_host, np.float32), static3)
 
 
 @pytest.mark.parametrize("mapping", ["replay", "whatif"])
