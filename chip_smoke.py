@@ -32,6 +32,10 @@ no result line (nothing here catches a phase's exception):
                   and the default plugin set on 160 nodes as an
                   arrivals-only what-if batch (``two_pass``, host-scale
                   count rows), scenario 0 against the single replay; and
+                  jobs of 16 workers at waveWidth 8 (pod groups wider than
+                  the wave, some rolled back) against the same trace at
+                  waveWidth 16, ``summary()["gangs"]`` and ``rollback_form``
+                  required, no pod group partly bound; and
                   the normalize rows' divisions against the integer division
   serve           serve examples/config20_service.yaml < 4 defrag queries
   mesh            only with >1 device: what-if
@@ -465,6 +469,65 @@ def phase_parity() -> dict:
         "select_form": select_form(r_plug, "default-plugins what-if",
                                    "two_pass"),
         "inwave_corrections": resolved_terms(r_plug, "default-plugins what-if"),
+    }
+    # (h) pod groups WIDER than the wave: jobs of 16 workers on 16 nodes (6
+    # with 8 nvidia.com/gpu) at waveWidth 8, two waves a group, the
+    # transaction carried by the scan (ops.tpu3.GangTxn) and some groups
+    # rolled back where they close; held to the same trace at waveWidth 16
+    # (one wave a group, the wave-local mask): two device programs against
+    # each other, in a what-if batch and in the single replay. No pod group
+    # may come back partly bound.
+    from kubernetes_simulator_tpu.models.encode import PAD
+    from kubernetes_simulator_tpu.sim.synthetic import make_workload
+    from kubernetes_simulator_tpu.sim.whatif import uniform_scenarios
+
+    gpu = "nvidia.com/gpu"
+    jobs, _ = make_workload(
+        400, seed=0, gang_sizes={1: 0.5, 4: 0.2, 16: 0.3},
+        job_extended_resource={
+            "resource": gpu, "counts": {1: 0.5, 2: 0.3, 8: 0.2}, "wideFrom": 8,
+            "smallJobFraction": 0.05, "wideJobFraction": 0.6})
+    ec, ep = encode(
+        make_cluster(16, seed=0, extended_resources={gpu: (8, 0.45)}), jobs)
+    scen_w = uniform_scenarios(ec, 4, seed=0)
+    wide = {}
+    for width in (8, 16):
+        wide[width] = WhatIfEngine(
+            ec, ep, scen_w, FrameworkConfig(), wave_width=width, chunk_waves=7,
+            collect_assignments=True).run()
+    r_wide = wide[8]
+    single = JaxReplayEngine(ec, ep, FrameworkConfig(), wave_width=8,
+                             chunk_waves=7).replay()
+    same(r_wide.assignments.reshape(-1), wide[16].assignments.reshape(-1),
+         "wide-group what-if at waveWidth 8", "the same trace's at waveWidth 16")
+    same(r_wide.assignments[0], single.assignments,
+         "wide-group what-if scenario 0", "the single replay's")
+    gangs = r_wide.fleet_telemetry.summary().get("gangs")
+    say(f"wide-group what-if: gangs {gangs}")
+    require(gangs is not None and gangs["rollback_form"] == "txn_plane",
+            f"wide-group what-if: no gangs counters or rollback_form ({gangs})")
+    require(gangs["wide_groups"] >= 20 and gangs["max_waves_spanned"] == 2,
+            f"wide-group what-if: not groups of 16 over two waves ({gangs})")
+    require(gangs["wide_rolled_back"] > 0 and gangs["pods_rolled_back"] > 0,
+            f"wide-group what-if: no group rolled back after a bind ({gangs})")
+    mine = single.telemetry.summary().get("gangs")
+    require(mine is not None and mine["pods_rolled_back"] > 0,
+            f"wide-group replay: no gangs counters ({mine})")
+    members = np.bincount(ep.group_id[ep.group_id != PAD])
+    for s in range(len(scen_w)):
+        a = r_wide.assignments[s]
+        bound = np.bincount(ep.group_id[(ep.group_id != PAD) & (a >= 0)],
+                            minlength=len(members))
+        partly = int(((bound > 0) & (bound < members)).sum())
+        require(partly == 0,
+                f"wide-group what-if scenario {s}: {partly} pod groups partly bound")
+        require(int((a >= 0).sum()) == int(r_wide.placed[s]),
+                f"wide-group what-if scenario {s}: placed differs from the "
+                "placements handed back")
+    out["wide_gangs_whatif"] = {
+        "nodes": 16, "pods": 400, "placed": [int(x) for x in r_wide.placed],
+        "gangs": gangs,
+        "inwave_corrections": resolved_terms(r_wide, "wide-group what-if"),
     }
     # (g) the normalize rows divide exactly. The chip's float32 division is
     # not correctly rounded (floor(6100 / 61) reads 99), which the CPU

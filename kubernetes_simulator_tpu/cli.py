@@ -624,6 +624,26 @@ def _service_errors(cfg) -> list:
     return errors
 
 
+def _wide_gang_errors(cfg, key: str, widest: int, ww: int, **on) -> list:
+    """What the device engines refuse of a gang wider than the wave
+    (``sim.waves.refuse_wide_gangs``, the one list), refused at ``validate``;
+    the CPU event engine takes a gang of any size."""
+    from .sim.waves import refuse_wide_gangs
+
+    if cfg.strategy == "cpu":
+        return []
+    pre = cfg.device_preemption
+    try:
+        refuse_wide_gangs(
+            ww, widest, retry_buffer=bool(cfg.whatif.retry_buffer),
+            kube_preemption=pre == "kube", tier_preemption=pre in (True, "tier"),
+            **on,
+        )
+    except ValueError as e:
+        return [f"{key}: {e}"]
+    return []
+
+
 def validate_config(cfg) -> list:
     """Structural checks → list of actionable error strings (empty = ok)."""
     from .framework.registry import available_strategies
@@ -667,11 +687,11 @@ def validate_config(cfg) -> list:
             errors.append("workload.borg.nodes: must be > 0")
         if cfg.borg.tasks <= 0:
             errors.append("workload.borg.tasks: must be > 0")
-        if cfg.borg.max_gang > ww:
-            errors.append(
-                f"workload.borg.maxGang ({cfg.borg.max_gang}) exceeds "
-                f"waveWidth ({ww}): a gang must fit in one wave"
-            )
+        # A Borg trace has durations, and releases know nothing of the open
+        # transaction of a gang wider than the wave.
+        errors += _wide_gang_errors(
+            cfg, "workload.borg.maxGang", cfg.borg.max_gang, ww, completions=True
+        )
         for p_attr, key in (
             ("trace_path", "tracePath"),
             ("instance_events", "instanceEvents"),
@@ -689,11 +709,42 @@ def validate_config(cfg) -> list:
         if wl is not None:
             if wl.pods <= 0:
                 errors.append("workload.pods: must be > 0")
-            if wl.gang_fraction and wl.gang_size > ww:
+            sizes = wl.gang_sizes or {}
+            bad = [
+                k for k, v in sizes.items()
+                if not (isinstance(k, int) and k >= 1
+                        and isinstance(v, (int, float)) and v >= 0)
+            ]
+            if bad or (wl.gang_sizes is not None and not sum(sizes.values())):
                 errors.append(
-                    f"workload.gangSize ({wl.gang_size}) exceeds waveWidth "
-                    f"({ww}): a gang must fit in one wave"
+                    "workload.gangSizes: a mapping {workers: share} of whole "
+                    f"worker counts >= 1 to shares >= 0, not all 0 (bad: {bad})"
                 )
+            if wl.job_extended_resource is not None:
+                jx = wl.job_extended_resource
+                missing = [
+                    k for k in ("resource", "counts", "wideFrom",
+                                "smallJobFraction", "wideJobFraction")
+                    if not isinstance(jx, dict) or k not in jx
+                ]
+                if missing:
+                    errors.append(
+                        f"workload.jobExtendedResource: missing {missing}"
+                    )
+                if not wl.gang_sizes:
+                    errors.append(
+                        "workload.jobExtendedResource is read with "
+                        "workload.gangSizes only"
+                    )
+            widest = max(
+                [k for k, v in sizes.items() if isinstance(k, int) and v]
+                or [wl.gang_size if wl.gang_fraction else 1]
+            )
+            errors += _wide_gang_errors(
+                cfg, "a gang of workload.gangSizes / gangSize", widest, ww,
+                completions=wl.duration_mean is not None,
+                count_planes=bool(wl.affinity or wl.spread),
+            )
     if cfg.whatif.scenarios < 0:
         errors.append("whatIf.scenarios: must be >= 0")
     if cfg.whatif.retry_buffer < 0:

@@ -127,8 +127,8 @@ def pack_waves_native(
     order: np.ndarray, group_of: np.ndarray, wave_width: int
 ) -> Optional[np.ndarray]:
     """[num_waves, W] i32 wave table (PAD=-1), or None if the native lib is
-    unavailable. Raises ValueError when a gang exceeds the wave width (same
-    contract as the Python packer)."""
+    unavailable. A gang wider than the wave fills consecutive waves from a
+    wave's first slot (same contract as the Python packer)."""
     lib = _lib()
     if lib is None:
         return None
@@ -140,7 +140,7 @@ def pack_waves_native(
         _i32p(order), n, _i32p(group_of), group_of.shape[0], wave_width, _i32p(out)
     )
     if waves < 0:
-        raise ValueError(f"gang exceeds wave width {wave_width}")
+        raise ValueError(f"wave width must be positive, got {wave_width}")
     return out[:waves].copy()
 
 

@@ -65,7 +65,7 @@ forks — sim/whatif.py gates and reports via ``WhatIfEngine.engine``).
 from __future__ import annotations
 
 import os
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -194,6 +194,35 @@ class V3Static:
     # ``host_pos`` a fact of the pod multiset.
     mc_h_one_row: bool = False
     anti_h_one_row: bool = False
+    # Pod groups WIDER than the wave (sim.waves.wide_gang_table: [P, 3] i32
+    # ``(pos, size, ordinal)`` per pod), or None where the trace has none at
+    # this wave width: the step then carries no transaction and is the
+    # wave-local program to the letter. Like ``has_gangs`` a static fact.
+    txn_tab: Optional[np.ndarray] = None
+    wave_width: int = 0  # the width ``txn_tab`` was laid out for
+    # Members of the largest pod group, whatever the width: a step built at
+    # a narrower wave without ``txn_tab`` would judge the group wave by wave,
+    # so ``make_wave_step3`` refuses to build it.
+    max_gang: int = 0
+
+    @property
+    def has_wide_gangs(self) -> bool:
+        return self.txn_tab is not None
+
+    @property
+    def wide_groups(self) -> int:
+        """How many pod groups are wider than the wave."""
+        return int(self.txn_tab[:, 2].max()) + 1 if self.has_wide_gangs else 0
+
+    @property
+    def max_wide(self) -> int:
+        """Members of the widest such group (0 without one)."""
+        return int(self.txn_tab[:, 1].max()) if self.has_wide_gangs else 0
+
+    @property
+    def max_waves_spanned(self) -> int:
+        """Waves the widest such group fills."""
+        return -(-self.max_wide // max(self.wave_width, 1))
 
     @property
     def KT(self) -> int:
@@ -240,10 +269,13 @@ class V3Static:
         preemption: bool = False,
         allow_bf16_host: bool = True,
         dcap_min: int = 0,
+        wave_width: Optional[int] = None,
     ) -> "V3Static":
         """``dcap_min``: widen the domain axis past the base cluster's
         count — labels_dirty what-if batches append per-scenario domain
-        ids for new label values (whatif.ScenarioDyn)."""
+        ids for new label values (whatif.ScenarioDyn). ``wave_width``: the
+        width the caller packs waves at; a pod group wider than it gets the
+        carried transaction (``txn_tab``)."""
         G = max(ec.num_groups, 1)
         gt = ec.group_topo[:G] if ec.group_topo.shape[0] >= G else np.full(G, PAD, np.int32)
         nd_g = np.where(gt >= 0, ec.num_domains[np.clip(gt, 0, None)], 0).astype(np.int32)
@@ -405,6 +437,18 @@ class V3Static:
             host_pos=host_pos,
             single_topo=single_topo,
         )
+        if spec.has_gangs:
+            from ..sim.waves import refuse_wide_gangs, wide_gang_table, widest_gang
+
+            out = replace(out, max_gang=widest_gang(ep))
+            tab = None if wave_width is None else wide_gang_table(ep, wave_width)
+            if tab is not None:
+                out = replace(out, txn_tab=tab, wave_width=int(wave_width))
+                refuse_wide_gangs(
+                    wave_width, out.max_gang, tier_preemption=preemption,
+                    count_planes=(out.maintain_mc or out.maintain_anti
+                                  or out.maintain_pref),
+                )
         if preemption and out.has_host_rows:
             raise ValueError(
                 "device preemption is not supported together with "
@@ -487,6 +531,29 @@ class Shared3(NamedTuple):
         )
 
 
+class GangTxn(NamedTuple):
+    """The open transaction of a pod group WIDER than the wave, carried by
+    the scan beside the planes (``DevState3.txn``; one a scenario under
+    ``vmap``), and what it has settled so far. Between two wide groups
+    ``plane`` is all zero, ``bound`` 0 and ``failed`` False."""
+
+    plane: jax.Array  # [R, N] f32 what the open group's tentative binds took
+    bound: jax.Array  # [] i32 how many they are
+    failed: jax.Array  # [] bool: a member so far fitted nowhere
+    log: jax.Array  # [Gw] bool verdict per wide group, by ordinal: rolled back
+    undone: jax.Array  # [] i32 binds given back so far
+
+    @classmethod
+    def init(cls, st: "V3Static", R: int, N: int) -> "GangTxn":
+        return cls(
+            plane=jnp.zeros((R, N), jnp.float32),
+            bound=jnp.zeros((), jnp.int32),
+            failed=jnp.zeros((), bool),
+            log=jnp.zeros((st.wide_groups,), bool),
+            undone=jnp.zeros((), jnp.int32),
+        )
+
+
 class DevState3(NamedTuple):
     """Carried state. Domain planes are [G, Dcap] (host-group rows stay
     zero); host planes are [H*, N] per plane kind.
@@ -508,6 +575,10 @@ class DevState3(NamedTuple):
     # counts by priority tier.
     used_tier: jax.Array  # [Tt, R, N] f32
     npods_tier: jax.Array  # [Tt, N] f32
+    # Only where the trace has a pod group wider than the wave
+    # (``V3Static.has_wide_gangs``); None is no leaf, so every other
+    # program's carry, and its text, is what it was.
+    txn: Optional[GangTxn] = None
 
     @classmethod
     def from_host(
@@ -556,6 +627,7 @@ class DevState3(NamedTuple):
             match_total=jnp.asarray(mt),
             used_tier=jnp.asarray(used_tier),
             npods_tier=jnp.asarray(npods_tier),
+            txn=GangTxn.init(st, R, N) if st.has_wide_gangs else None,
         )
 
     def to_host(self, ec: EncodedCluster, st: V3Static, D: int):
@@ -604,6 +676,12 @@ class SlotExtra(NamedTuple):
     tol_class: jax.Array  # i32 scalar
     na_class: jax.Array  # i32 scalar
     tier: jax.Array  # i32 scalar (0 when preemption off)
+    # [3] i32 (pos, size, ordinal) in a group wider than the wave, (-1, 0, 0)
+    # outside one; None (no leaf) where the trace has no such group.
+    txn: Optional[jax.Array] = None
+
+
+_NO_TXN = (-1, 0, 0)
 
 
 class ExtraSource(NamedTuple):
@@ -615,6 +693,7 @@ class ExtraSource(NamedTuple):
     tol_class: jax.Array  # [P]
     na_class: jax.Array  # [P]
     tier: jax.Array  # [P]
+    txn: Optional[jax.Array] = None  # [P, 3]
 
     @classmethod
     def build(cls, st: V3Static, num_pods: int) -> "ExtraSource":
@@ -629,6 +708,7 @@ class ExtraSource(NamedTuple):
                 st.na_class.astype(np.int32) if st.na_class.size else z
             ),
             tier=jnp.asarray(st.pod_tier.astype(np.int32) if st.Tt else z),
+            txn=jnp.asarray(st.txn_tab) if st.has_wide_gangs else None,
         )
 
     @classmethod
@@ -651,6 +731,14 @@ class ExtraSource(NamedTuple):
                 st.na_class[safe].astype(np.int32) if st.na_class.size else z
             ),
             tier=jnp.asarray(st.pod_tier[safe].astype(np.int32) if st.Tt else z),
+            txn=(
+                jnp.asarray(
+                    np.where((flat >= 0)[:, None], st.txn_tab[safe], _NO_TXN)
+                    .astype(np.int32)
+                )
+                if st.has_wide_gangs
+                else None
+            ),
         )
 
 
@@ -665,6 +753,11 @@ def gather_extra_device(src: ExtraSource, idx: jax.Array) -> SlotExtra:
         tol_class=src.tol_class[safe],
         na_class=src.na_class[safe],
         tier=src.tier[safe],
+        txn=(
+            None
+            if src.txn is None
+            else jnp.where(ok, src.txn[safe], jnp.asarray(_NO_TXN, jnp.int32))
+        ),
     )
 
 
@@ -680,6 +773,11 @@ def gather_extra(st: V3Static, idx: np.ndarray) -> SlotExtra:
         tol_class=jnp.asarray(tol_c.astype(np.int32)),
         na_class=jnp.asarray(na_c.astype(np.int32)),
         tier=jnp.asarray(tier.astype(np.int32)),
+        txn=(
+            jnp.asarray(np.where(ok, st.txn_tab[safe], _NO_TXN).astype(np.int32))
+            if st.has_wide_gangs
+            else None
+        ),
     )
 
 
@@ -1003,6 +1101,12 @@ def make_wave_step3(
     (``vmap``) — a static fact of how the program is built, which picks the
     form of the in-wave usage corrections (:func:`inwave_corrections`) and
     of the host-scale count row reads (:func:`host_row_reads`)."""
+    from ..sim.waves import refuse_wide_gangs
+
+    refuse_wide_gangs(
+        wave_width, st.max_gang,
+        no_transaction=not st.has_wide_gangs or st.wave_width != wave_width,
+    )
     cmasks = cmasks or {}
     G = st.G
     Dcap = st.Dcap
@@ -1794,6 +1898,15 @@ def make_wave_step3(
                 groups = sb.group
                 same = (groups[:, None] == groups[None, :]) & (groups[:, None] >= 0)
                 fail = jnp.any(same & ~placed[None, :], axis=1)
+                if st.has_wide_gangs:
+                    with stage("ksim.gang_txn"):
+                        # A wave's rows say where it stands in a group wider
+                        # than the wave: the same in every scenario. Such a
+                        # group's members bind tentatively whatever this
+                        # wave's other members did; the verdict falls where
+                        # the group closes (below).
+                        t_member = sx.txn[:, 0] >= 0  # [W]
+                        fail = fail & ~t_member
                 commit = placed & ~fail
             else:
                 commit = placed
@@ -1839,6 +1952,11 @@ def make_wave_step3(
                         )
                     rows_u.append(acc)
                 used = jnp.stack(rows_u)
+            txn = carry.txn
+            if st.has_wide_gangs:
+                used, txn = _gang_txn_close(
+                    used, txn, sb, sx.txn, choice, placed, commit_used, iota_n
+                )
             used_tier, npods_tier = carry.used_tier, carry.npods_tier
             if st.preemption:
                 # Eviction: free the wave-start lower-tier usage at the node.
@@ -1986,6 +2104,7 @@ def make_wave_step3(
                 used=used, mc_dom=mc_dom, anti_dom=anti_dom, pref_dom=pref_dom,
                 mc_host=mc_host, anti_host=anti_host, pref_host=pref_host,
                 match_total=match_total, used_tier=used_tier, npods_tier=npods_tier,
+                txn=txn,
             )
         if st.preemption:
             # Eviction event for the host fix-up walk: victims from PRIOR
@@ -1998,6 +2117,92 @@ def make_wave_step3(
         return new_state, final
 
     return wave_step
+
+
+def _gang_txn_close(
+    used, txn: GangTxn, sb, rows, choice, placed, commit_used, iota_n
+):
+    """The transaction of a pod group wider than the wave, at a wave's end:
+    ``(used, txn)`` after it. ``used`` holds the wave's binds already, the
+    group's tentative ones among them, so that its later waves and the pods
+    behind it in its closing wave are scheduled on them.
+
+    Every wave (``ksim.gang_txn``): what the wave's members took is added to
+    the group's own carried ``[R, N]`` plane, in the wave-end commit's form
+    (W compares and W x R select-adds over the nodes, in slot order), and a
+    member that fitted nowhere fails the group for this scenario. Where the
+    group closes (``ksim.gang_rollback``), a scenario in which it failed
+    takes the whole plane out of ``used`` again, and the plane starts from
+    zero for the next group: one masked pass, no list of binds, no loop and
+    no branch (``rollback_form`` ``"txn_plane"``). Whether a wave closes a
+    group is in its rows, the same in every scenario; whether the group
+    failed is the scenario's own. The verdict goes into the log at the
+    group's ordinal, for the hand-back.
+
+    On a v5e at 256 x 1,800 (PERF.md §6, PR 37) a loop that undid the
+    group's listed binds, a trip a wave of the group, ran the batch 35%
+    slower, and the same unrolled under a ``lax.cond`` 43%: the carried
+    state lives in VMEM through the scan and an inner loop or a conditional
+    took ``used`` out of it. ``used - plane`` is the sum given back in one
+    subtraction where the list gave it back bind by bind: the same float32
+    wherever a node's sums are exactly representable (the module's standing
+    caveat; bucketed requests are)."""
+    R = used.shape[0]
+    with stage("ksim.gang_txn"):
+        # the wave's rows (pos, size, ordinal), the same in every scenario
+        pos, size, ordinal = rows[:, 0], rows[:, 1], rows[0, 2]
+        member = pos >= 0
+        closes = jnp.any(member & (pos == size - 1))
+        tent = member & commit_used
+        coefs = tent.astype(jnp.float32)[:, None] * sb.req  # [W, R] tiny
+        plane_r = []
+        for r in range(R):
+            acc = txn.plane[r]
+            for w in range(choice.shape[0]):
+                acc = acc + jnp.where(iota_n == choice[w], coefs[w, r], 0.0)
+            plane_r.append(acc)
+        plane = jnp.stack(plane_r)
+        failed = txn.failed | jnp.any(member & sb.valid & ~placed)
+        bound = txn.bound + jnp.sum(tent, dtype=jnp.int32)
+        rolled = closes & failed
+    with stage("ksim.gang_rollback"):
+        used = jnp.where(rolled, used - plane, used)
+    with stage("ksim.gang_txn"):
+        was = jax.lax.dynamic_slice(txn.log, (ordinal,), (1,))
+        log = jax.lax.dynamic_update_slice(
+            txn.log, jnp.where(closes, failed, was[0])[None], (ordinal,)
+        )
+        txn = GangTxn(
+            plane=jnp.where(closes, 0.0, plane),
+            bound=jnp.where(closes, 0, bound),
+            failed=failed & ~closes,
+            log=log,
+            undone=txn.undone + jnp.where(rolled, bound, 0),
+        )
+    return used, txn
+
+
+def gangs_summary(st: V3Static, rolled_back: int, undone: int) -> dict:
+    """``telemetry.summary()["gangs"]`` of a replay or a what-if batch whose
+    trace has a pod group wider than the wave: the static layout and form,
+    and the verdicts counted on the device (``GangTxn.log`` / ``undone``,
+    summed over a batch's scenarios)."""
+    return {
+        "wide_groups": st.wide_groups,
+        "max_group": st.max_wide,
+        "max_waves_spanned": st.max_waves_spanned,
+        "rollback_form": rollback_form(st),
+        "wide_rolled_back": int(rolled_back),
+        "pods_rolled_back": int(undone),
+    }
+
+
+def rollback_form(st: V3Static) -> Optional[str]:
+    """How a step built from ``st`` gives a failed wide pod group's binds
+    back (:func:`_gang_txn_close`), or None where the trace has no group
+    wider than the wave — static per compiled program; a replay reports it
+    in ``telemetry.summary()["gangs"]``."""
+    return "txn_plane" if st.has_wide_gangs else None
 
 
 def resolve_usage(node_sums, choices, node, placed, req):

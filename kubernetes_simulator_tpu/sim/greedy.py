@@ -44,7 +44,7 @@ from ..models.encode import PAD, EncodedCluster, EncodedPods
 from ..models.state import bind, unbind
 from ..utils.metrics import fragmentation_gauges, utilization_means
 from .runtime import ReplayResult
-from .waves import WaveBatch, pack_waves
+from .waves import WaveBatch, pack_waves, refuse_wide_gangs, wide_gang_table
 
 
 def priority_tiers(ep: EncodedPods):
@@ -169,6 +169,19 @@ def greedy_replay(
     # but never count toward placed_total (they were not scheduled here).
     assignments = ops.assignments
     preemptions = 0  # tier evictions (kube evictions live in ops)
+    # A pod group WIDER than the wave (sim.waves.wide_gang_table): its
+    # members bind tentatively, wave after wave, and the verdict falls at
+    # the end of the wave that holds its last member — the device step's
+    # carried transaction (ops.tpu3.GangTxn), on the host.
+    wide = wide_gang_table(ep, waves.wave_width)
+    if wide is not None:
+        refuse_wide_gangs(
+            waves.wave_width, int(wide[:, 1].max()),
+            completions=bool(completions_chunk_waves),
+            kube_preemption=mode == "kube", tier_preemption=mode == "tier",
+        )
+    txn: List[int] = []  # the open wide group's members bound so far
+    txn_failed = False
     t0 = time.perf_counter()
     for wi, wave in enumerate(waves.idx):
         if completions_chunk_waves and wi % completions_chunk_waves == 0:
@@ -213,8 +226,21 @@ def greedy_replay(
             int(ep.group_id[p])
             for p, c in zip(slot_pods, slot_choice)
             if c == PAD and ep.group_id[p] != PAD
+            and (wide is None or wide[p, 0] < 0)
         }
+        closes = False
         for p, c in zip(slot_pods, slot_choice):
+            if wide is not None and wide[p, 0] >= 0:
+                # Tentative: bound (above) and handed back as placed until
+                # the group's verdict.
+                if c != PAD:
+                    txn.append(p)
+                    assignments[p] = c
+                    ops.placed_total += 1
+                else:
+                    txn_failed = True
+                closes |= wide[p, 0] == wide[p, 1] - 1
+                continue
             if p in evicted_in_wave:
                 continue  # evicted mid-wave: never committed
             g = int(ep.group_id[p])
@@ -229,6 +255,15 @@ def greedy_replay(
                 # Failed non-gang pod enters the retry buffer (slot
                 # order within the wave; overflow drops the newest).
                 ops.offer_failure(p)
+        if closes:
+            # The rollback falls at the closing wave's end: the pods behind
+            # the group in this wave were scheduled on its tentative binds.
+            if txn_failed:
+                for p in txn:
+                    unbind(ec, ep, st, p)
+                    assignments[p] = PAD
+                ops.placed_total -= len(txn)
+            txn, txn_failed = [], False
     if mode == "kube":
         # Trailing boundary: pods that failed in the LAST chunk still get
         # their PostFilter attempt (the CPU engine preempts at the failure

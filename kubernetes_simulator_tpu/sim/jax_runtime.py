@@ -53,7 +53,7 @@ from ..utils.profiling import (
 )
 from .runtime import ReplayResult, events_hash, validate_node_events
 from .telemetry import TelemetryCollector, TelemetryConfig
-from .waves import WaveBatch, pack_waves
+from .waves import WaveBatch, pack_waves, refuse_wide_gangs, widest_gang
 
 
 def _file_bytes(path: str) -> int:
@@ -955,7 +955,8 @@ class JaxReplayEngine:
         self.wave_width = wave_width
         if engine == "v3":
             self.static3 = V3.V3Static.build(
-                ec, pods, self.spec, dmax_coarse, preemption=self.preemption
+                ec, pods, self.spec, dmax_coarse, preemption=self.preemption,
+                wave_width=wave_width,
             )
             self.shared3 = V3.Shared3.build(ec, self.static3)
             self.chunk_fn = make_chunk_fn3_src(
@@ -964,6 +965,12 @@ class JaxReplayEngine:
             )
         else:
             self.chunk_fn = make_chunk_fn(wave_width, self.spec)
+        # A pod group wider than the wave runs on the v3 engine's plain
+        # arrivals-only replay (sim.waves.WIDE_GANG_UNSUPPORTED).
+        refuse_wide_gangs(
+            wave_width, widest_gang(pods), v2_engine=engine != "v3",
+            retry_buffer=bool(self.retry_buffer), kube_preemption=self.kube,
+        )
         self.waves = pack_waves(
             pods, wave_width,
             page_pods=(chunk_waves * wave_width if self.paged else None),
@@ -983,6 +990,12 @@ class JaxReplayEngine:
             if engine == "v3" and not self.paged
             else None
         )
+
+    @property
+    def _wide_gangs(self) -> bool:
+        """The trace has a pod group wider than the wave: the state carries
+        its transaction (``ops.tpu3.GangTxn``)."""
+        return self.engine == "v3" and self.static3.has_wide_gangs
 
     def _to_dev_state_v2(self, used, mc, aa, pw, mt) -> T.DevState:
         """Device v2 (node-space) state/delta from host planes."""
@@ -1778,6 +1791,7 @@ class JaxReplayEngine:
                 self.static3 = V3.V3Static.build(
                     self.ec, self.pods, self.spec, self.dmax_coarse,
                     preemption=self.preemption, allow_bf16_host=False,
+                    wave_width=self.wave_width,
                 )
                 self.shared3 = V3.Shared3.build(self.ec, self.static3)
                 self.chunk_fn = make_chunk_fn3_src(
@@ -1824,6 +1838,14 @@ class JaxReplayEngine:
                 # default "summary" granularity takes none of these branches and
                 # runs the exact same device program as before.
                 use_rej = tel is not None and tel.cfg.want_series
+                if use_rej and self._wide_gangs:
+                    log.info(
+                        "telemetry: rejection attribution is not available with "
+                        "a pod group wider than the wave (the instrumented "
+                        "program carries no transaction) — latency/phase "
+                        "telemetry still collected"
+                    )
+                    use_rej = False
                 if use_rej and self.preemption:
                     log.info(
                         "telemetry: rejection attribution is not available with "
@@ -1877,6 +1899,11 @@ class JaxReplayEngine:
                 completions_on = bool(
                     self.completions is not False  # None (the default) = on
                     and np.isfinite(rel_time).any()
+                )
+                refuse_wide_gangs(
+                    self.wave_width, widest_gang(self.pods),
+                    completions=completions_on,
+                    checkpoint=bool(checkpoint_path or resume),
                 )
                 wave_times = (
                     self._wave_start_times(idx)
@@ -2178,6 +2205,7 @@ class JaxReplayEngine:
                     self.dc = self.dc._replace(allocatable=jnp.asarray(saved_alloc))
 
                 preemptions = 0
+                gangs = None
                 to_schedule = int((idx >= 0).sum())
                 if self.preemption and completions_on:
                     # The incremental eviction-aware folds ARE the walk; finish
@@ -2218,7 +2246,18 @@ class JaxReplayEngine:
                     flat_choice = choices_np.reshape(-1)
                     valid = flat_idx >= 0
                     assignments[flat_idx[valid]] = flat_choice[valid]
-                    placed = int((flat_choice[valid] >= 0).sum())
+                    if self._wide_gangs:
+                        # A member of a group wider than the wave wrote its
+                        # node when its wave ran; the verdict of its group
+                        # is in the final state's log, by ordinal.
+                        tab = self.static3.txn_tab
+                        rolled = np.asarray(state.txn.log)
+                        members = np.nonzero(tab[:, 0] >= 0)[0]
+                        assignments[members[rolled[tab[members, 2]]]] = PAD
+                        gangs = V3.gangs_summary(
+                            self.static3, rolled.sum(), state.txn.undone
+                        )
+                    placed = int((assignments[flat_idx[valid]] >= 0).sum())
 
                 if tel is not None:
                     # Plain replay: every placement is a wave placement — bound in
@@ -2258,6 +2297,9 @@ class JaxReplayEngine:
                         rec.close({"placed": int(placed)})
                 if pager is not None:
                     pager.close()
+            tel_out = tel.result() if tel is not None else None
+            if tel_out is not None:
+                tel_out.gangs = gangs
             return ReplayResult(
                 assignments=assignments,
                 placed=placed,
@@ -2270,7 +2312,7 @@ class JaxReplayEngine:
                 utilization=util,
                 state=host_state,
                 fragmentation=frag,
-                telemetry=tel.result() if tel is not None else None,
+                telemetry=tel_out,
             )
 
 

@@ -47,9 +47,12 @@ def make_cluster(
     extended_resources: Optional[dict] = None,
 ) -> Cluster:
     """Heterogeneous nodes across zones/racks; optional taints and extended
-    resources (e.g. ``{"google.com/tpu": 8}`` on a fraction of nodes)."""
+    resources: ``{"google.com/tpu": (8, 0.25)}`` gives each node 8 with
+    probability 0.25; a whole number in the share's place
+    (``{"nvidia.com/gpu": (8, 810)}``) gives exactly that many nodes 8,
+    drawn without replacement once every node has its shape."""
     rng = np.random.default_rng(seed)
-    nodes: List[Node] = []
+    nodes: List[dict] = []
     for i in range(num_nodes):
         cpu, mem = MACHINE_SHAPES[rng.integers(len(MACHINE_SHAPES))]
         labels = {
@@ -64,11 +67,16 @@ def make_cluster(
         capacity = {"cpu": float(cpu), "memory": float(mem) * 2**30, "pods": 110}
         if extended_resources:
             for r, (count, frac) in extended_resources.items():
-                if rng.random() < frac:
+                if not isinstance(frac, int) and rng.random() < frac:
                     capacity[r] = float(count)
                     labels["accelerator"] = r.split("/")[-1]
-        nodes.append(Node(name=f"node-{i}", capacity=capacity, labels=labels, taints=taints))
-    return Cluster(nodes=nodes)
+        nodes.append(dict(name=f"node-{i}", capacity=capacity, labels=labels, taints=taints))
+    for r, (count, held) in (extended_resources or {}).items():
+        if isinstance(held, int):
+            for i in rng.choice(num_nodes, size=held, replace=False):
+                nodes[i]["capacity"][r] = float(count)
+                nodes[i]["labels"]["accelerator"] = r.split("/")[-1]
+    return Cluster(nodes=[Node(**kw) for kw in nodes])
 
 
 def make_workload(
@@ -83,9 +91,18 @@ def make_workload(
     gang_fraction: float = 0.0,
     gang_size: int = 4,
     extended_resource: Optional[Tuple[str, int, float]] = None,
+    gang_sizes: Optional[dict] = None,
+    job_extended_resource: Optional[dict] = None,
 ) -> Tuple[List[Pod], dict]:
     """Pods in arrival order with app labels; optional affinity/spread/
-    toleration terms, gangs, extended-resource requests."""
+    toleration terms, gangs, extended-resource requests. ``gang_sizes``
+    (a job-size mix, ``{workers: share}``) makes the trace job by job
+    instead (:func:`make_job_workload`)."""
+    if gang_sizes:
+        return make_job_workload(
+            num_pods, seed=seed, arrival_rate=arrival_rate, num_apps=num_apps,
+            gang_sizes=gang_sizes, job_extended_resource=job_extended_resource,
+        )
     rng = np.random.default_rng(seed + 1)
     pods: List[Pod] = []
     t = 0.0
@@ -164,6 +181,72 @@ def make_workload(
         pods.append(pod)
     meta = {"num_gangs": gang_id, "makespan": t}
     return pods, meta
+
+
+def make_job_workload(
+    num_pods: int,
+    seed: int = 0,
+    arrival_rate: float = 100.0,
+    num_apps: int = 20,
+    gang_sizes: Optional[dict] = None,
+    job_extended_resource: Optional[dict] = None,
+) -> Tuple[List[Pod], dict]:
+    """A training cluster's trace, job by job: a job's worker count is drawn
+    from ``gang_sizes`` (``{workers: share}``) until the pods are dealt out
+    (the last job takes what is left); a job of two or more workers is a pod
+    group with ``minMember`` = its size, its workers consecutive arrivals.
+    ``job_extended_resource`` (``{"resource", "counts": {count: share},
+    "wideFrom", "smallJobFraction", "wideJobFraction"}``): a job asks for the
+    extended resource with probability ``smallJobFraction`` below
+    ``wideFrom`` workers and ``wideJobFraction`` from there up, and all its
+    workers ask the same count. cpu and memory requests, priority, app and
+    role are :func:`make_workload`'s sets, a worker's draws in its order.
+    A job's draws (size, whether it asks, the count) come before its
+    workers'."""
+    rng = np.random.default_rng(seed + 1)
+    sizes = [int(k) for k in gang_sizes]
+    shares = np.asarray([float(gang_sizes[k]) for k in gang_sizes], np.float64)
+    shares = shares / shares.sum()
+    ext = job_extended_resource
+    if ext:
+        counts = [float(k) for k in ext["counts"]]
+        cshares = np.asarray([float(ext["counts"][k]) for k in ext["counts"]])
+        cshares = cshares / cshares.sum()
+    pods: List[Pod] = []
+    t = 0.0
+    jobs = gangs = 0
+    while len(pods) < num_pods:
+        size = min(int(rng.choice(sizes, p=shares)), num_pods - len(pods))
+        ask = 0.0
+        if ext:
+            frac = (
+                ext["wideJobFraction"] if size >= int(ext["wideFrom"])
+                else ext["smallJobFraction"]
+            )
+            if rng.random() < float(frac):
+                ask = float(rng.choice(counts, p=cshares))
+        for _ in range(size):
+            i = len(pods)
+            t += float(rng.exponential(1.0 / arrival_rate))
+            app = f"app-{int(rng.integers(num_apps))}"
+            role = "worker" if rng.random() < 0.8 else "leader"
+            requests = {
+                "cpu": float(rng.choice([0.25, 0.5, 1.0, 2.0, 4.0])),
+                "memory": float(rng.choice([0.5, 1.0, 2.0, 8.0])) * 2**30,
+            }
+            if ask:
+                requests[ext["resource"]] = ask
+            pods.append(Pod(
+                name=f"pod-{i}",
+                labels={"app": app, "role": role},
+                requests=requests,
+                priority=int(rng.choice([0, 0, 0, 100, 1000])),
+                arrival_time=t,
+                pod_group=f"gang-{gangs}" if size > 1 else None,
+            ))
+        jobs += 1
+        gangs += size > 1
+    return pods, {"num_jobs": jobs, "num_gangs": gangs, "makespan": t}
 
 
 def make_chaos_timeline(

@@ -254,12 +254,19 @@ class ReplayTelemetry:
     release_rounds: Optional[int] = None
     handback_bytes: Optional[int] = None
     mesh: Optional[Dict[str, object]] = None
+    # Only where the trace has a pod group wider than the wave (the v3
+    # step's carried transaction, ops.tpu3.GangTxn): ``wide_groups``,
+    # ``max_group``, ``max_waves_spanned`` and ``rollback_form`` are static
+    # per compiled program; ``wide_rolled_back`` (groups) and
+    # ``pods_rolled_back`` (binds given back) are counted on the device and
+    # fetched at gather, summed over a what-if batch's scenarios.
+    gangs: Optional[Dict[str, object]] = None
 
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
         for key in ("chunk_waves", "inwave_corrections", "select_form",
                     "count_planes", "scenarios", "release_buckets",
-                    "release_rounds", "handback_bytes", "mesh"):
+                    "release_rounds", "handback_bytes", "mesh", "gangs"):
             if getattr(self, key) is not None:
                 out[key] = getattr(self, key)
         if self.latency is not None:
@@ -400,6 +407,12 @@ class ReplayTelemetry:
                     if getattr(p, key) is not None]
             if have:
                 setattr(tel, key, sum(have))
+        gangs = [p.gangs for _, p in keep if p.gangs is not None]
+        if gangs:
+            # one layout, one program; the verdicts add up over the blocks
+            tel.gangs = dict(gangs[0])
+            for key in ("wide_rolled_back", "pods_rolled_back"):
+                tel.gangs[key] = sum(g[key] for g in gangs)
         buckets = [p.release_buckets for _, p in keep
                    if p.release_buckets is not None]
         if buckets:

@@ -1,13 +1,17 @@
 """Wave packing — the rectangular schedule the device scan walks.
 
 Pods (in arrival order) are packed into fixed-width "waves" of W slots such
-that no pod-group (gang) spans waves. The JAX engine scans waves; within a
-wave, slots are processed sequentially (pod k sees pod k-1's speculative
-bindings — SURVEY.md §7 hard part #1), and gang commit/rollback happens at
-the wave boundary as one masked update (hard part #3).
+that no pod-group (gang) of at most W members spans waves. The JAX engine
+scans waves; within a wave, slots are processed sequentially (pod k sees pod
+k-1's speculative bindings — SURVEY.md §7 hard part #1), and gang
+commit/rollback happens at the wave boundary as one masked update (hard
+part #3).
 
-Gangs larger than the wave width raise; callers size W from the trace's max
-group size (Borg alloc sets are small).
+A gang WIDER than the wave starts on a wave's first slot and fills
+ceil(size / W) consecutive waves; the rest of its last wave is open to the
+pods that follow, as a wave-local gang's is. The step carries such a gang's
+transaction across its waves and rolls it back where it closes
+(:func:`wide_gang_table`, ``ops.tpu3.GangTxn``).
 """
 
 from __future__ import annotations
@@ -68,12 +72,6 @@ def pack_waves(
         g = int(ep.group_id[p])
         if g != PAD:
             members.setdefault(g, []).append(int(p))
-    max_group = max((len(v) for v in members.values()), default=1)
-    if max_group > wave_width:
-        raise ValueError(
-            f"gang of size {max_group} exceeds wave width {wave_width}; "
-            f"use wave_width >= {max_group}"
-        )
     waves: List[List[int]] = []
     current: List[int] = []
     consumed = set()
@@ -92,6 +90,12 @@ def pack_waves(
         batch = [p] if g == PAD else members[g]
         if len(current) + len(batch) > wave_width:
             flush()
+        # A gang wider than the wave: whole waves of it, from a wave's
+        # first slot; what is left over stays open like any other wave.
+        while len(batch) > wave_width:
+            waves.append(batch[:wave_width])
+            consumed.update(batch[:wave_width])
+            batch = batch[wave_width:]
         current.extend(batch)
         consumed.update(batch)
     flush()
@@ -100,3 +104,83 @@ def pack_waves(
     for i, w in enumerate(waves):
         idx[i, : len(w)] = w
     return WaveBatch(idx=idx, wave_width=wave_width)
+
+
+def widest_gang(ep: EncodedPods) -> int:
+    """Members of the largest gang among the unbound pods (0 without one)."""
+    gid = ep.group_id[ep.bound_node == PAD]
+    gid = gid[gid != PAD]
+    return int(np.bincount(gid).max()) if gid.size else 0
+
+
+# What a gang wider than the wave does not run with, on any engine: the ONE
+# list (the step's builder, both device engines, the host twin and the CLI's
+# ``validate`` all refuse through :func:`refuse_wide_gangs`). The transaction
+# gives back resource usage and lives in the arrivals-only scan's state: a
+# release, a retry or boundary pass, an eviction, a fork or a checkpoint
+# would have to know of an open one, and count planes would have to be
+# rolled back with it.
+WIDE_GANG_UNSUPPORTED = {
+    "v2_engine": "the v2 engine",
+    "completions": "completions (finite pod durations; pass completions=False)",
+    "retry_buffer": "a retry buffer",
+    "kube_preemption": "kube preemption",
+    "tier_preemption": "tier preemption",
+    "fork_checkpoint": "a fork checkpoint",
+    "checkpoint": "checkpoint or resume",
+    "count_planes": "carried affinity / spread count planes",
+    "no_transaction": "a step built without the wave width "
+                      "(V3Static.build(..., wave_width=))",
+}
+
+
+def refuse_wide_gangs(wave_width: int, widest: int, **on) -> None:
+    """Raise where a gang of ``widest`` members is wider than the wave and
+    any of ``on`` (keys of :data:`WIDE_GANG_UNSUPPORTED`) holds."""
+    blockers = [WIDE_GANG_UNSUPPORTED[k] for k, v in on.items() if v]
+    if widest > wave_width and blockers:
+        raise ValueError(
+            f"a gang of {widest} exceeds the wave width ({wave_width}): a "
+            f"gang wider than the wave is not supported with "
+            f"{', '.join(blockers)}; raise the wave width to {widest} or use "
+            f"the CPU event engine"
+        )
+
+
+def wide_gang_table(
+    ep: EncodedPods, wave_width: int
+) -> Optional[np.ndarray]:
+    """``[P, 3]`` i32 per pod ``(pos, size, ordinal)`` of the gangs WIDER than
+    the wave among the unbound pods, or None where there is none: ``pos`` the
+    pod's place among its gang's members in arrival order (the order the
+    packer lays them out in: member ``pos`` sits in slot ``pos % W`` of the
+    gang's wave ``pos // W``), ``size`` the gang's member count, ``ordinal``
+    the gang's number among the wide ones, by group id. A pod in no wide
+    gang reads ``(-1, 0, 0)``. Static per (trace, wave width): the device
+    step reads a wave's rows to know whether a wide gang continues or closes
+    there, the same in every scenario."""
+    unbound = np.nonzero(ep.bound_node == PAD)[0]
+    gid = ep.group_id[unbound]
+    in_gang = gid != PAD
+    if not in_gang.any():
+        return None
+    sizes = np.bincount(gid[in_gang])
+    wide_g = np.nonzero(sizes > wave_width)[0]
+    if wide_g.size == 0:
+        return None
+    ordinal = np.full(sizes.shape[0], -1, np.int32)
+    ordinal[wide_g] = np.arange(wide_g.size, dtype=np.int32)
+    order = unbound[np.argsort(ep.arrival[unbound], kind="stable")]
+    g_o = ep.group_id[order]
+    wide_o = (g_o != PAD) & (ordinal[np.clip(g_o, 0, None)] >= 0)
+    members = order[wide_o]  # arrival order
+    by_gang = np.argsort(g_o[wide_o], kind="stable")
+    members = members[by_gang]  # gang by gang, arrival order inside
+    g_m = ep.group_id[members]
+    starts = np.concatenate([[0], np.cumsum(sizes[wide_g])[:-1]])
+    table = np.zeros((ep.num_pods, 3), np.int32)
+    table[:, 0] = -1
+    table[members, 0] = np.arange(members.size) - np.repeat(starts, sizes[wide_g])
+    table[members, 1] = sizes[g_m]
+    table[members, 2] = ordinal[g_m]
+    return table

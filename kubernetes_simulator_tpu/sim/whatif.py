@@ -49,7 +49,7 @@ from ..utils.profiling import register_call as _register_call
 from ..utils.profiling import shape_structs as _shape_structs
 from ..utils.profiling import stage
 from .jax_runtime import StepSpec, make_wave_step
-from .waves import pack_waves
+from .waves import pack_waves, refuse_wide_gangs, widest_gang
 
 # The release program's vmap axis: named so that the rank rounds of a block
 # run to ONE trip count, the largest among the scenarios (ops.release_planes).
@@ -908,6 +908,7 @@ class WhatIfEngine:
                 ec, pods, self.spec, preemption=preemption,
                 allow_bf16_host=not scales_pods,
                 dcap_min=(self._dyn.Dcap if self._dyn is not None else 0),
+                wave_width=self.wave_width,
             )
             self.shared3 = V3.Shared3.build(ec, self.static3)
             self.rep_slots = rep_slots_for(self.static3, pods)
@@ -1027,6 +1028,14 @@ class WhatIfEngine:
         # exists in practice, is singleton). Everything else keeps the
         # host pending-fold path.
         self._completions_dev = bool(self.completions_on and dev_ok)
+        # A pod group wider than the wave runs on the v3 engine's
+        # arrivals-only paths (sim.waves.WIDE_GANG_UNSUPPORTED).
+        refuse_wide_gangs(
+            self.wave_width, widest_gang(self.pods),
+            v2_engine=self.engine != "v3", completions=self.completions_on,
+            retry_buffer=bool(retry_buffer), kube_preemption=self.kube,
+            fork_checkpoint=fork_checkpoint is not None,
+        )
         if (
             self.completions_on
             and not self._completions_dev
@@ -1188,6 +1197,27 @@ class WhatIfEngine:
             if self.mesh is not None:
                 srcs = replicate_tree(self.mesh, srcs)
             self._slot_srcs = srcs
+
+    @property
+    def _wide_gangs(self) -> bool:
+        """The trace has a pod group wider than the wave: the state carries
+        its transaction (``ops.tpu3.GangTxn``)."""
+        return self.engine == "v3" and self.static3.has_wide_gangs
+
+    def _gangs_summary(self, txn) -> dict:
+        """``summary()["gangs"]`` of the batch that just ran: the static
+        layout, and the verdicts counted on the device and fetched here
+        (summed over the scenarios)."""
+        from ..ops import tpu3 as V3
+
+        rolled, undone = self._fetch(
+            self._jit_once("gangs", lambda: jax.jit(
+                lambda t: jnp.stack([
+                    t.log.sum(dtype=jnp.int32), t.undone.sum(dtype=jnp.int32)
+                ])
+            ))(txn)
+        )
+        return V3.gangs_summary(self.static3, rolled, undone)
 
     @property
     def release_path(self) -> Optional[str]:
@@ -2326,7 +2356,7 @@ class WhatIfEngine:
         return np.asarray(x)
 
     def _handback(
-        self, span, wave_order, pos, count: bool = False
+        self, span, wave_order, pos, count: bool = False, txn=None
     ) -> Tuple[np.ndarray, int, Optional[np.ndarray]]:
         """(assignments [S, P], bytes copied, placed [S] or None), under the
         run's ``span`` (a mesh's gather and fetch is ``mesh_fetch``): every
@@ -2354,25 +2384,44 @@ class WhatIfEngine:
         land (my chip run, PR 33). So one more program, ``jit_whatif_gather``,
         replicates the placements over the mesh (one all-gather over ICI,
         the one collective of a batch, after the chunk and hand-back
-        programs, which hold none) and the host fetches one device's copy."""
+        programs, which hold none) and the host fetches one device's copy.
+
+        ``txn`` (the final state's, where the trace has a pod group wider
+        than the wave): a member of such a group wrote its node when its
+        wave ran, before the group closed; the group's verdict per scenario
+        (``txn.log``), taken through the static map from task to group
+        ordinal inside the same program, hands a rolled-back group back
+        unplaced, member for member."""
         def build():
             pos_d = jnp.asarray(pos())
+            if txn is not None:
+                tab = self.static3.txn_tab
+                # a task in no wide group reads the False column at the end
+                ord_d = jnp.asarray(
+                    np.where(tab[:, 0] >= 0, tab[:, 2], self.static3.wide_groups)
+                )
 
-            def whatif_handback(buf):
+            def whatif_handback(buf, log=None):
                 if isinstance(buf, (list, tuple)):
                     flat = [c.reshape(c.shape[0], -1) for c in buf]
                     none = jnp.full((flat[0].shape[0], 1), PAD, flat[0].dtype)
                     buf = jnp.concatenate(flat + [none], axis=1)
-                return jnp.take(buf, pos_d, axis=1).astype(jnp.int32)
+                node = jnp.take(buf, pos_d, axis=1).astype(jnp.int32)
+                if log is None:
+                    return node
+                stood = jnp.zeros((log.shape[0], 1), bool)
+                rolled = jnp.take(
+                    jnp.concatenate([log, stood], axis=1), ord_d, axis=1
+                )
+                return jnp.where(rolled, PAD, node)
 
             return jax.jit(whatif_handback)
 
         fn = self._jit_once("handback", build)
+        args = (wave_order,) if txn is None else (wave_order, txn.log)
         if self._mesh_programs is not None:
-            self._mesh_programs["handback"] = (
-                fn, (_shape_structs(wave_order),)
-            )
-        placed = fn(wave_order)
+            self._mesh_programs["handback"] = (fn, _shape_structs(args))
+        placed = fn(*args)
         counts = None
         if count:
             counts = self._jit_once("handback_placed", lambda: jax.jit(
@@ -3954,6 +4003,9 @@ class WhatIfEngine:
                                 )
                             ))(outs)
                         ).astype(np.int32)
+                        if self._wide_gangs:
+                            # a wave counted its tentative binds
+                            placed -= self._fetch(states.txn.undone)
 
                 util = None
                 ri = self.ec.vocab._r.get("cpu")
@@ -3992,7 +4044,8 @@ class WhatIfEngine:
                 # copied once.
                 with span("handback"):
                     assignments, handback_bytes, placed = self._handback(
-                        span, outs, lambda: self._chunks_pos(idx), count=True)
+                        span, outs, lambda: self._chunks_pos(idx), count=True,
+                        txn=states.txn if self._wide_gangs else None)
                     prebound = self.pods.bound_node >= 0
                     if prebound.any() or self._fork_choices is not None:
                         assignments = np.array(assignments)  # the copy is read-only
@@ -4038,6 +4091,8 @@ class WhatIfEngine:
                     fleet_local.release_rounds = release_rounds
                 if dev_rel or self.collect_assignments:
                     fleet_local.handback_bytes = handback_bytes
+                if self._wide_gangs:
+                    fleet_local.gangs = self._gangs_summary(states.txn)
                 if self.mesh is not None:
                     fleet_local.mesh = self._mesh_summary()
                 # DCN checkpoint-publication attribution (round 16): the

@@ -64,6 +64,14 @@ class SyntheticWorkloadSpec:
     # [resource, count, fraction]: that fraction of the pods asks for 1, 2
     # or ``count`` of the extended resource (``make_workload``).
     extended_resource: Optional[list] = None
+    # A job-size mix ``{workers: share}``: the trace is then made job by job
+    # (``make_job_workload``), a job of two or more workers a pod group of
+    # its size, and ``gangFraction`` / ``gangSize`` are not read.
+    gang_sizes: Optional[Dict[int, float]] = None
+    # ``{resource, counts: {count: share}, wideFrom, smallJobFraction,
+    # wideJobFraction}``: which jobs ask for an extended resource, all their
+    # workers the same count (with ``gangSizes`` only).
+    job_extended_resource: Optional[Dict[str, Any]] = None
 
 
 @dataclass
@@ -414,6 +422,8 @@ class SimConfig:
                 duration_mean=syn.get("durationMean"),
                 num_apps=int(syn.get("numApps", 20)),
                 extended_resource=syn.get("extendedResource"),
+                gang_sizes=syn.get("gangSizes"),
+                job_extended_resource=syn.get("jobExtendedResource"),
             )
         prof = d.get("profile", {})
         plugins = prof.get("plugins")
@@ -633,6 +643,8 @@ def build_case(cfg: SimConfig):
              float(wl.extended_resource[2]))
             if wl.extended_resource else None
         ),
+        gang_sizes=wl.gang_sizes,
+        job_extended_resource=wl.job_extended_resource,
     )
     from ..plugins.builtin import inject_default_spread
 

@@ -41,6 +41,8 @@ STAGES = (
     "ksim.select",        # the pick: extrema, tie-break, pack-select
     "ksim.preempt",       # victim ranking and eviction marking
     "ksim.commit",        # wave-end commit, gang rollback mask
+    "ksim.gang_txn",      # a wide pod group's carried transaction: upkeep, verdict
+    "ksim.gang_rollback", # its binds given back where it closes
     "ksim.release",       # boundary release programs
 )
 

@@ -1,7 +1,8 @@
 // Native wave packer — C++ twin of kubernetes_simulator_tpu/sim/waves.py
 // (pack_waves). Packs pods (arrival order) into fixed-width waves such that
-// no pod-group (gang) spans waves; semantics must stay bit-identical to the
-// Python fallback (tests/test_native.py pins this).
+// no pod-group (gang) of at most wave_width members spans waves; a wider gang
+// starts on a wave's first slot and fills consecutive waves. Semantics must
+// stay bit-identical to the Python fallback (tests/test_native.py pins this).
 //
 // Part of the framework's native runtime layer: host-side ETL for the
 // device scan (SURVEY.md §3.1 "host feeds pod chunks"). At 1M pods the
@@ -17,7 +18,7 @@ extern "C" {
 // group_of:     [num_pods] group id per pod (-1 = none), indexed by pod id
 // wave_width:   W
 // out_idx:      [n * W] preallocated, filled with -1-padded waves
-// returns       number of waves, or -1 if a gang exceeds wave_width
+// returns       number of waves, or -1 if wave_width <= 0
 int64_t ksim_pack_waves(const int32_t* order, int64_t n,
                         const int32_t* group_of, int64_t num_pods,
                         int32_t wave_width, int32_t* out_idx) {
@@ -34,9 +35,6 @@ int64_t ksim_pack_waves(const int32_t* order, int64_t n,
     int32_t p = order[i];
     int32_t g = group_of[p];
     if (g >= 0) members[static_cast<size_t>(g)].push_back(p);
-  }
-  for (auto& m : members) {
-    if (static_cast<int32_t>(m.size()) > wave_width) return -1;
   }
   // Second pass: emit waves; a pod pulls its whole gang forward to its
   // first member's position (same as the Python packer's `members[g]`).
@@ -55,7 +53,7 @@ int64_t ksim_pack_waves(const int32_t* order, int64_t n,
       batch = members[static_cast<size_t>(g)].data();
       bsz = static_cast<int32_t>(members[static_cast<size_t>(g)].size());
     }
-    if (fill + bsz > wave_width) {
+    if (fill > 0 && fill + bsz > wave_width) {
       // flush
       ++wave;
       row = out_idx + wave * wave_width;
@@ -63,6 +61,13 @@ int64_t ksim_pack_waves(const int32_t* order, int64_t n,
       fill = 0;
     }
     for (int32_t k = 0; k < bsz; ++k) {
+      if (fill == wave_width) {
+        // a gang wider than the wave: on into the next wave
+        ++wave;
+        row = out_idx + wave * wave_width;
+        for (int64_t j = 0; j < wave_width; ++j) row[j] = -1;
+        fill = 0;
+      }
       row[fill++] = batch[k];
       consumed[static_cast<size_t>(batch[k])] = 1;
     }

@@ -31,6 +31,11 @@ _AS_LEFT_BY = {
     "test_whatif_cell__test_names_units_and_files": {
         "configs": "borg2019-10k-whatif", "workloads": "borg10k-whatif128",
         "per_layer": "whatif_handback_ms_per_batch"},
+    # PR 35's case holds its seven metrics to the END of ``per_layer``, the
+    # same way: it reads the lists as PR 35 left them, since PR 37 appended.
+    "test_program_span_metrics__test_every_new_metric_has_a_reader_and_an_entry_at_the_end": {
+        "configs": "multitenant-1k-mesh", "workloads": "multitenant-mesh4",
+        "per_layer": "mesh_fetch_ms_per_batch"},
 }
 
 

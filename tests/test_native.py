@@ -75,12 +75,17 @@ class TestNativeWavepack:
         assert got.shape == (1, 4)
         assert (got == PAD).all()
 
-    def test_oversized_gang_raises(self):
+    def test_oversized_gang_fills_consecutive_waves(self):
+        """Two gangs of 6 at width 4: each starts on a wave's first slot and
+        takes two waves, 4 + 2 (tests/test_wide_gangs.py has the rest)."""
         cluster = make_cluster(4, seed=0)
         pods, _ = make_workload(12, seed=0, gang_fraction=1.0, gang_size=6)
         _, ep = encode(cluster, pods)
-        with pytest.raises(ValueError):
-            pack_waves(ep, 4)
+        got = pack_waves(ep, 4).idx
+        np.testing.assert_array_equal(
+            got, [[0, 1, 2, 3], [4, 5, PAD, PAD], [6, 7, 8, 9], [10, 11, PAD, PAD]])
+        with pytest.raises(ValueError, match="must fit in one page"):
+            pack_waves(ep, 4, page_pods=4)
 
 
 class TestTraceRoundtrip:

@@ -198,7 +198,51 @@ class TestValidate:
         out = capsys.readouterr().out
         assert rc == 1
         assert "unknown plugin 'NoSuchPlugin'" in out
-        assert "exceeds" in out and "waveWidth" in out
+        # a gang wider than the wave runs on the device engines' arrivals-only
+        # paths; a Borg trace has durations, so this one is still refused
+        assert "workload.borg.maxGang: a gang of" in out
+        assert "exceeds the wave width (4)" in out
+        assert "not supported with completions" in out
+
+    @pytest.mark.parametrize("extra, refused", [
+        ({}, None),
+        ({"durationMean": 5.0}, "completions"),
+        ({"spread": True}, "carried affinity / spread count planes"),
+    ])
+    def test_a_gang_wider_than_the_wave(self, tmp_path, capsys, extra, refused):
+        """``gangSizes`` with jobs of 16 at waveWidth 8: accepted on the
+        device path (the carried transaction), refused with what the open
+        transaction cannot be combined with; the CPU engine takes any."""
+        from kubernetes_simulator_tpu.cli import main
+
+        wl = {"pods": 64, "gangSizes": {1: 0.5, 16: 0.5}, **extra}
+        for strategy in ("jax", "cpu"):
+            cfg = self._write(tmp_path, {
+                "strategy": strategy, "waveWidth": 8, "workload": {"synthetic": wl}})
+            rc = main(["validate", cfg])
+            out = capsys.readouterr().out
+            if refused and strategy == "jax":
+                assert rc == 1 and "a gang of 16 exceeds the wave width (8)" in out
+                assert refused in out
+            else:
+                assert rc == 0, out
+
+    def test_rejects_a_job_size_mix_that_is_none(self, tmp_path, capsys):
+        from kubernetes_simulator_tpu.cli import main
+
+        cfg = self._write(tmp_path, {"workload": {"synthetic": {
+            "gangSizes": {"many": 1.0},
+            "jobExtendedResource": {"resource": "nvidia.com/gpu"}}}})
+        assert main(["validate", cfg]) == 1
+        out = capsys.readouterr().out
+        assert "workload.gangSizes" in out
+        assert "workload.jobExtendedResource: missing" in out
+
+    def test_accepts_the_gpu_jobs_example(self, capsys):
+        from kubernetes_simulator_tpu.cli import main
+
+        assert main(["validate", "examples/config8_gpu_jobs_gangs.yaml"]) == 0
+        assert '"errors": []' in capsys.readouterr().out
 
     def test_rejects_missing_trace_file(self, tmp_path, capsys):
         from kubernetes_simulator_tpu.cli import main

@@ -53,7 +53,9 @@ def test_stage_tables_after_an_armed_replay(borg, tmp_path, monkeypatch):
     chunk = tables["jit_chunk_fn"]
     ran = {path.split("/")[0] for path in chunk.values()} - {""}
     # every stage this configuration runs: no preemption, releases apart
-    assert ran == set(profiling.STAGES) - {"ksim.preempt", "ksim.release"}
+    # (nor a pod group wider than the wave: tests/test_wide_gangs.py)
+    assert ran == set(profiling.STAGES) - {
+        "ksim.preempt", "ksim.release", "ksim.gang_txn", "ksim.gang_rollback"}
     assert {"ksim.filter_score/NodeResourcesFit",
             "ksim.filter_score/TaintToleration",
             "ksim.filter_score/PodTopologySpread"} <= set(chunk.values())
