@@ -453,6 +453,9 @@ def phase_parity() -> dict:
     require(planes["host_read_positions"] > 0,
             "default-plugins what-if: no position of the term axis reads a "
             f"host row by its index ({planes})")
+    require(0 < planes["expand_positions"] < planes["term_rows"],
+            "default-plugins what-if: a slot's domain-row expansion does not "
+            f"run over some positions of the term axis and not all ({planes})")
     require(planes["host_commit"] == {"rows": planes["host_rows"],
                                       "elementwise": 0, "dot": 0},
             "default-plugins what-if: its host rows are not all committed "

@@ -159,6 +159,12 @@ class V3Static:
     # Like ``has_dns`` a fact of the pod multiset, not of the arrival order:
     # every deal of the same pods finds the same program.
     host_pos: np.ndarray
+    # [KT] bool, the mirror fact: some pod's term at this position names a
+    # group that is NOT host-scale. Only such a position can hold a non-zero
+    # domain row, so only there does a slot expand one to node space
+    # (value_positions); a position may set both flags (a zone group for one
+    # pod, a hostname group for another) or neither.
+    coarse_pos: np.ndarray
     # All domain-bearing groups share one topology key (the Borg shape:
     # zone-only): bound-node domain lookups collapse to one shared [N] map.
     # ``topo0`` is that topology's id (PAD when no group carries domains);
@@ -399,7 +405,9 @@ class V3Static:
              ep.pref_aff[:, :PA], anti_midx, pref_midx],
             axis=1,
         )  # [P, KT] the group each pod names at each row position (PAD none)
-        host_pos = ((term_g >= 0) & is_host[np.clip(term_g, 0, G - 1)]).any(axis=0)
+        named_host = is_host[np.clip(term_g, 0, G - 1)]
+        host_pos = ((term_g >= 0) & named_host).any(axis=0)
+        coarse_pos = ((term_g >= 0) & ~named_host).any(axis=0)
         topo_groups = (gt >= 0) & (nd_g > 0)
         single_topo = bool(len(set(gt[topo_groups].tolist())) <= 1)
         topo0 = int(gt[topo_groups][0]) if topo_groups.any() else PAD
@@ -434,7 +442,7 @@ class V3Static:
             has_dns=bool(
                 SP and (ep.spread_dns[:, :SP] & (ep.spread_g[:, :SP] >= 0)).any()
             ),
-            host_pos=host_pos,
+            host_pos=host_pos, coarse_pos=coarse_pos,
             single_topo=single_topo,
         )
         if spec.has_gangs:
@@ -1057,10 +1065,33 @@ def _hi_lo_premasked(hi_in: jax.Array, lo_in: jax.Array):
 
 
 def _expand_rows(rows: jax.Array, dom_oh_k: jax.Array) -> jax.Array:
-    """[KT, Dcap] domain rows → [KT, N] node values: one-hot matmul against
+    """[Kc, Dcap] domain rows → [Kc, N] node values: one-hot matmul against
     the per-wave node→domain one-hot (exact selection; rides the MXU —
     gathers serialize on TPU). PAD map entries have all-zero one-hots → 0."""
     return jnp.einsum("kd,knd->kn", rows, dom_oh_k, precision=_HI)
+
+
+def count_rows(vals, lo: int, hi: int) -> jax.Array:
+    """[hi - lo, N]: a section of a slot's node-space count values, held as
+    one ``[KT, N]`` array or as a list of ``[N]`` rows, one a position."""
+    return jnp.stack(vals[lo:hi]) if isinstance(vals, list) else vals[lo:hi]
+
+
+def count_rows_add(vals, delta: jax.Array):
+    """``vals`` (either form of :func:`count_rows`) plus ``delta`` [KT, N]."""
+    if isinstance(vals, list):
+        return [v + delta[r] for r, v in enumerate(vals)]
+    return vals + delta
+
+
+def _at_positions(x: jax.Array, pos, axis: int) -> jax.Array:
+    """``x`` kept along ``axis`` at the static positions ``pos`` alone, by
+    static slices (no gather); ``x`` itself where that is every position."""
+    if list(pos) == list(range(x.shape[axis])):
+        return x
+    return jnp.stack(
+        [jax.lax.index_in_dim(x, r, axis, keepdims=False) for r in pos], axis
+    )
 
 
 def class_masks(dc: DevCluster, d: Derived, st: V3Static, spec, rep_slots):
@@ -1100,7 +1131,19 @@ def make_wave_step3(
     ``scenario_axis``: the caller maps the step over a scenario axis
     (``vmap``) — a static fact of how the program is built, which picks the
     form of the in-wave usage corrections (:func:`inwave_corrections`) and
-    of the host-scale count row reads (:func:`host_row_reads`)."""
+    of the host-scale count row reads (:func:`host_row_reads`).
+
+    Where a slot reads its host rows one by one (``host_row_reads`` "rows":
+    the step under a scenario axis) its node-space count values are built
+    per position of the term axis and only where that position can hold a
+    non-zero (:func:`value_positions`): the domain-row expansion at the
+    ``st.coarse_pos`` positions, the host rows and their in-wave terms at
+    the ``st.host_pos`` positions, handed on as ``[N]`` rows; no ``[KT, N]``
+    value over all positions is assembled. Under ``dyn`` every position
+    stays in both lists: the label corrections add to every position's
+    expansion. The single replay (the wave-start "contraction") keeps one
+    ``[KT, N]`` array over every position: at op latency the per-position
+    form loses a third (PERF.md §6 PR 38)."""
     from ..sim.waves import refuse_wide_gangs
 
     refuse_wide_gangs(
@@ -1140,6 +1183,14 @@ def make_wave_step3(
     corr_plane = corr_form == "plane"
     corr_resolved = corr_form == "resolved_terms"
     host_rows_read = host_row_reads(scenario_axis) == "rows"
+    # A slot's count values: per-position rows where it reads its host rows
+    # one by one (the step mapped over a scenario axis); one [KT, N] array
+    # over every position where they come from the wave-start contraction.
+    cpos, hpos = value_positions(
+        st, all_positions=dyn is not None or not host_rows_read
+    )
+    # The InterPodAffinity verdict gathered apart, behind a barrier (below).
+    verdict_apart = host_rows_read and bool(hpos)
     zone_select = (
         select_form(
             st, spec, dc.allocatable.shape[0],
@@ -1186,11 +1237,13 @@ def make_wave_step3(
                             "wkh,hn->wkn", pre.oh_pref_h, carry.pref_host, precision=_HI
                         )
                 totals0 = jnp.einsum("wkg,g->wk", pre.oh_row, carry.match_total, precision=_HI)
-                if need_vals:
-                    # Per-wave node→domain one-hot (scenario-shared) for expansion.
+                if need_vals and cpos:
+                    # Per-wave node→domain one-hot (scenario-shared) for the
+                    # expansion, at the positions that can hold a domain row.
                     dom_oh = (
-                        pre.dmap[..., None] == jnp.arange(Dcap, dtype=jnp.float32)
-                    ).astype(jnp.float32)  # [W, KT, N, Dcap]
+                        _at_positions(pre.dmap, cpos, 1)[..., None]
+                        == jnp.arange(Dcap, dtype=jnp.float32)
+                    ).astype(jnp.float32)  # [W, Kc, N, Dcap]
                 if spread_dom_hilo and not st.seg_mode:
                     # [W, N, Dcap+1]: spread-row domain one-hot + no-domain col
                     # (built from dmap directly — dom_oh may be skipped).
@@ -1271,8 +1324,8 @@ def make_wave_step3(
         for k in range(wave_width):
             with stage("ksim.reads"):
                 s = jax.tree.map(lambda a: a[k], sb)
-                if st.has_host_rows and host_rows_read:
-                    vals_h = host_rows_at(st, carry, pre.row_h[k])
+                if hpos and host_rows_read:
+                    vals_h = host_rows_at(st, carry, pre.row_h[k], hpos)
 
             # --- exact in-wave corrections from pods j<k -----------------
             # Usage: the running plane, or k terms rebuilt from the chosen-
@@ -1283,11 +1336,14 @@ def make_wave_step3(
             # carried values.
             with stage("ksim.corrections"):
                 rows_corr = jnp.zeros((st.KT, Dcap), jnp.float32) if st.KT else None
-                valh_corr = (
-                    jnp.zeros((st.KT, N), jnp.float32)
-                    if (st.KT and st.has_host_rows)
-                    else None
-                )
+                if host_rows_read:
+                    valh_corr = {r: jnp.zeros((N,), jnp.float32) for r in hpos}
+                else:
+                    valh_corr = (
+                        jnp.zeros((st.KT, N), jnp.float32)
+                        if (st.KT and st.has_host_rows)
+                        else None
+                    )
                 tot_corr = jnp.zeros((st.KT,), jnp.float32) if st.KT else None
                 if corr_plane:
                     used_corr_r = [used_corr[r, :N] for r in range(R)]
@@ -1335,7 +1391,29 @@ def make_wave_step3(
                             domat_r[:, None] == jnp.arange(Dcap, dtype=jnp.float32)
                         ).astype(jnp.float32)
                         rows_corr = rows_corr + (wj * ovr)[:, None] * oh_d
-                        if st.has_host_rows:
+                        # Domain-equality form: credits every node sharing
+                        # the bound node's domain (== the bound node alone
+                        # for singleton/hostname topologies).
+                        if host_rows_read:
+                            # One [N] row a position: its two scalars are made
+                            # for it alone, by a [G] dot; cut out of the [KT]
+                            # vectors above they cost a fusion each under a
+                            # scenario axis (PERF.md §6 PR 38).
+                            for r in hpos:
+                                domat_h = jnp.dot(
+                                    dom_ats[j], pre.oh_row[k, r], precision=_HI
+                                )
+                                ovh = (
+                                    wj
+                                    * pre.ov[j, k, r]
+                                    * (1.0 - pre.coarse_row[k, r])
+                                    * (pre.row_g[k, r] >= 0)
+                                    * (domat_h >= 0)
+                                )
+                                valh_corr[r] = valh_corr[r] + ovh * (
+                                    pre.dmap[k, r] == domat_h
+                                )
+                        elif st.has_host_rows:
                             ovh = (
                                 wj
                                 * pre.ov[j, k]
@@ -1343,9 +1421,6 @@ def make_wave_step3(
                                 * (pre.row_g[k] >= 0)
                                 * (domat_r >= 0)
                             )
-                            # Domain-equality form: credits every node sharing
-                            # the bound node's domain (== the bound node alone
-                            # for singleton/hostname topologies).
                             valh_corr = valh_corr + ovh[:, None] * (
                                 pre.dmap[k] == domat_r[:, None]
                             )
@@ -1427,11 +1502,30 @@ def make_wave_step3(
                     rows_k = rows0[k] + rows_corr  # [KT, Dcap]
                     totals = totals0[k] + tot_corr
                     if need_vals:
-                        vals = _expand_rows(rows_k, dom_oh[k])
-                        if st.has_host_rows:
-                            if not host_rows_read:
-                                vals_h = vals_h0[k]
-                            vals = vals + vals_h + valh_corr
+                        if host_rows_read:
+                            # One [N] row a position: its expansion row, its
+                            # host row plus its terms, their sum where the
+                            # position can name either scale, zeros where
+                            # none.
+                            vals = [None] * st.KT
+                            if cpos:
+                                expanded = _expand_rows(
+                                    _at_positions(rows_k, cpos, 0), dom_oh[k]
+                                )  # [Kc, N]
+                                for i, r in enumerate(cpos):
+                                    vals[r] = expanded[i]
+                            for r in hpos:
+                                vals[r] = (
+                                    vals_h[r] if vals[r] is None else vals[r] + vals_h[r]
+                                ) + valh_corr[r]
+                            vals = [
+                                jnp.zeros((N,), jnp.float32) if v is None else v
+                                for v in vals
+                            ]
+                        else:
+                            vals = _expand_rows(rows_k, dom_oh[k])
+                            if st.has_host_rows:
+                                vals = vals + vals_h0[k] + valh_corr
                         gvalid = pre.dmap[k] >= 0  # [KT, N]
                         if Kdyn:
                             # labels_dirty: corrections on top of the BASE
@@ -1462,7 +1556,7 @@ def make_wave_step3(
                                     at_ov,
                                     precision=_HI,
                                 )  # [2·KT, N]
-                                vals = vals + corr[: st.KT]
+                                vals = count_rows_add(vals, corr[: st.KT])
                                 gvalid = gvalid != (corr[st.KT :] > 0.5)
                             else:
                                 # No key-presence changes in the whole batch:
@@ -1470,27 +1564,39 @@ def make_wave_step3(
                                 corr = jnp.einsum(
                                     "rj,jn->rn", delta, at_ov, precision=_HI
                                 )
-                                vals = vals + corr
+                                vals = count_rows_add(vals, corr)
 
                 with jax.named_scope("InterPodAffinity"):
+                    # Where the host rows are per-position rows the plugin's
+                    # verdict is gathered apart and written out once a slot
+                    # (one bool a node): the host rows and their k terms are
+                    # then built in ONE fusion, its only reader, and not
+                    # again inside each of the slot's node-wide reduces,
+                    # whose operand lists they would swell (PERF.md §6 PR 38).
+                    ip_ok = jnp.ones(N, bool) if verdict_apart else nonfit
                     if spec.interpod and st.A:
-                        cnt = vals[o0:o1]
+                        cnt = count_rows(vals, o0, o1)
                         term_ok = (cnt >= 1) & gvalid[o0:o1]
                         boot = (totals[o0:o1] == 0) & pre.aff_selfm[k]
                         valid = (pre.row_g[k, o0:o1] >= 0)[:, None]
-                        nonfit = nonfit & jnp.all(
+                        ip_ok = ip_ok & jnp.all(
                             jnp.where(valid, term_ok | boot[:, None], True), axis=0
                         )
                     if spec.interpod and st.B:
-                        viol = (vals[o1:o2] >= 1) & gvalid[o1:o2]
+                        viol = (count_rows(vals, o1, o2) >= 1) & gvalid[o1:o2]
                         valid = (pre.row_g[k, o1:o2] >= 0)[:, None]
-                        nonfit = nonfit & jnp.all(jnp.where(valid, ~viol, True), axis=0)
+                        ip_ok = ip_ok & jnp.all(jnp.where(valid, ~viol, True), axis=0)
                     if spec.interpod and st.MA:
-                        blocked = jnp.sum(vals[o4:o5], axis=0) > 0.5
-                        nonfit = nonfit & ~blocked
+                        blocked = jnp.sum(count_rows(vals, o4, o5), axis=0) > 0.5
+                        ip_ok = ip_ok & ~blocked
+                    nonfit = (
+                        nonfit & jax.lax.optimization_barrier(ip_ok)
+                        if verdict_apart
+                        else ip_ok
+                    )
                 if spec.spread and st.SP and st.has_dns:
                     with jax.named_scope("PodTopologySpread"):
-                        cnts = vals[o2:o3]
+                        cnts = count_rows(vals, o2, o3)
                         gval = gvalid[o2:o3]
                         # Min over domains — every existing domain has ≥1 node, so
                         # min over valid domains == min over gvalid nodes. Coarse
@@ -1558,10 +1664,11 @@ def make_wave_step3(
                         raw = jnp.zeros(dc.allocatable.shape[0], jnp.float32)
                         if st.PA:
                             raw = raw + jnp.einsum(
-                                "p,pn->n", pre.row_w[k, o3:o4], vals[o3:o4], precision=_HI
+                                "p,pn->n", pre.row_w[k, o3:o4], count_rows(vals, o3, o4),
+                                precision=_HI,
                             )
                         if st.MP:
-                            raw = raw + jnp.sum(vals[o5:o6], axis=0)
+                            raw = raw + jnp.sum(count_rows(vals, o5, o6), axis=0)
                         rows_n.append((raw, _w("InterPodAffinity"), True, False))
                 sp_pack = None
                 if (
@@ -1573,7 +1680,7 @@ def make_wave_step3(
                     with jax.named_scope("PodTopologySpread"):
                         # Upstream scoring raw + ignored mask; extrema ride the
                         # shared stacked reduce as an extra ±inf-pre-masked row.
-                        cnts = vals[o2:o3]
+                        cnts = count_rows(vals, o2, o3)
                         gval = gvalid[o2:o3]
                         raw_sp = jnp.zeros(N, jnp.float32)
                         sp_ign = jnp.zeros(N, bool)
@@ -2428,6 +2535,11 @@ def count_planes(st: V3Static, scenario_axis: bool = False) -> dict:
     ``KT`` of the unified term axis; ``host_read_positions``: how many of
     the ``KT`` positions read a host row in every slot
     (:func:`host_rows_at`; the others can never name a host-scale group);
+    ``expand_positions``: how many can name a domain-scale group
+    (``st.coarse_pos``), the rows of a slot's domain-row expansion where the
+    step expands at all (:func:`value_positions`; the Borg traces' one
+    position is such a one and their step, a single ``ScheduleAnyway`` zone
+    spread scored in domain space, builds no node-space count value);
     ``host_commit``: host rows by the form of their wave-end commit
     (:func:`host_commit_form`; ``scenario_axis`` as the step was built)."""
     return {
@@ -2437,6 +2549,7 @@ def count_planes(st: V3Static, scenario_axis: bool = False) -> dict:
         "spread_rows": int(st.SP),
         "term_rows": int(st.KT),
         "host_read_positions": int(st.host_pos.sum()),
+        "expand_positions": int(st.coarse_pos.sum()),
         "host_commit": host_commit_form(st, scenario_axis),
     }
 
@@ -2485,29 +2598,57 @@ def host_named_row_add(
     return plane
 
 
-def host_rows_at(st: V3Static, carry: DevState3, row_h_k: jax.Array) -> jax.Array:
-    """[KT, N] f32: the wave-start values of the host-scale count rows one
-    slot's terms name (``row_h_k``: the slot's ``WavePre3.row_h``), zero at
-    a position that names none. Each is read from the carried plane of its
-    kind by row index: what a one-hot over the plane's rows selects, to the
-    bit (one non-zero term; a bf16 plane holds small whole numbers). A
-    position no pod's term can point at a host-scale group
-    (``st.host_pos``) reads nothing."""
+def value_positions(st: V3Static, all_positions: bool = False):
+    """``(expansion positions, host positions)``: the positions of the term
+    axis at which a slot of the step builds node-space count values, as two
+    static lists. The first (``st.coarse_pos``) can name a domain-scale
+    group: only there is a domain row non-zero, so only those rows are
+    expanded to node space. The second (``st.host_pos``) can name a
+    host-scale group: only there is a host row read and are its in-wave
+    terms built. At every other position each addend is ``+0.0`` and the
+    values it met are counts ``>= 0``, so leaving it out changes no bit.
+    ``all_positions`` keeps every position in both lists, the form of
+    before PR 38: label perturbations, whose corrections land on every
+    position's expansion, and the single replay, whose one ``[KT, N]`` array
+    from the wave-start contraction is cheaper at op latency than rows (a
+    third of a replay, PERF.md §6 PR 38). The second list is empty where
+    the trace has no host row."""
+    every = list(range(st.KT))
+    if all_positions:
+        return every, every if st.has_host_rows else []
+    return (
+        [r for r in every if st.coarse_pos[r]],
+        [r for r in every if st.host_pos[r]] if st.has_host_rows else [],
+    )
+
+
+def host_rows_at(st: V3Static, carry: DevState3, row_h_k: jax.Array, positions) -> dict:
+    """{position: [N] f32}: the wave-start values of the host-scale count
+    rows one slot's terms name (``row_h_k``: the slot's ``WavePre3.row_h``)
+    at the ``positions`` of the term axis that can name one
+    (:func:`value_positions`), zero where the slot's term names none. Each
+    is read from the carried plane of its kind by row index: what a one-hot
+    over the plane's rows selects, to the bit (one non-zero term; a bf16
+    plane holds small whole numbers). Every other position reads nothing and
+    is no key. The rows pass an ``optimization_barrier``: written out once,
+    in float32, for the one fusion a slot that reads them; the read fused
+    into that fusion loses 14% of a ``k8s5k-whatif256`` batch (PR 32 met the
+    same; PERF.md §6 PR 38)."""
     o0, o1, o2, o3, o4, o5, o6 = st.sections
-    rows = [jnp.zeros(carry.used.shape[1:], jnp.float32)] * st.KT
-    for lo, hi, plane in (
-        (o0, o4, carry.mc_host), (o4, o5, carry.anti_host),
-        (o5, o6, carry.pref_host),
-    ):
-        for r in range(lo, hi):
-            if st.host_pos[r]:
-                row = jax.lax.dynamic_index_in_dim(
-                    plane, jnp.clip(row_h_k[r], 0), 0, keepdims=False
-                )
-                rows[r] = jnp.where(
-                    row_h_k[r] >= 0, row.astype(jnp.float32), 0.0
-                )
-    return jnp.stack(rows)
+    rows = {}
+    for r in positions:
+        plane = (
+            carry.mc_host if r < o4 else carry.anti_host if r < o5
+            else carry.pref_host
+        )
+        if plane.shape[0]:
+            row = jax.lax.dynamic_index_in_dim(
+                plane, jnp.clip(row_h_k[r], 0), 0, keepdims=False
+            )
+            rows[r] = jnp.where(row_h_k[r] >= 0, row.astype(jnp.float32), 0.0)
+        else:
+            rows[r] = jnp.zeros(carry.used.shape[1:], jnp.float32)
+    return jax.lax.optimization_barrier(rows)
 
 
 def kind_masks(st: V3Static):

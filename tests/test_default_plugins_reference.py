@@ -150,7 +150,7 @@ def test_the_summary_reports_the_count_planes_the_form_and_the_handback(batch):
         "domain_rows": int((~st.is_host).sum()),
         "host_rows": len(st.mc_h_ids) + len(st.anti_h_ids),
         "dcap": 8, "spread_rows": 1, "term_rows": 4,
-        "host_read_positions": 2,
+        "host_read_positions": 2, "expand_positions": 2,
         "host_commit": {
             "rows": len(st.mc_h_ids) + len(st.anti_h_ids), "elementwise": 0,
             "dot": 0}}

@@ -681,12 +681,12 @@ def test_profiles_outside_the_gate_keep_the_parents_program(profile):
 # whole [H, N] plane, slot by slot and at EVERY position.
 
 
-def _host_rows_by_contraction(st, carry, row_h_k):
+def _host_rows_by_contraction(st, carry, row_h_k, positions):
     import jax
     import jax.numpy as jnp
 
     o = st.sections
-    rows = [jnp.zeros(carry.used.shape[1:], jnp.float32)] * st.KT
+    rows = {}
     for lo, hi, plane in ((o[0], o[4], carry.mc_host),
                           (o[4], o[5], carry.anti_host),
                           (o[5], o[6], carry.pref_host)):
@@ -697,7 +697,7 @@ def _host_rows_by_contraction(st, carry, row_h_k):
                     "h,hn->n", oh, plane, precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32,
                 )
-    return jnp.stack(rows)
+    return rows
 
 
 _SLOT = "custom/slot"
@@ -775,16 +775,10 @@ def _perturbed(N, count):
 def _run_with_host_planes(ec, ep, mapping):
     """(every pod's node, the final mc_host and anti_host planes, the static
     facts) of a single replay or of a 4-scenario arrivals-only what-if."""
-    cfg = FrameworkConfig()
-    if mapping == "replay":
-        eng = JaxReplayEngine(ec, ep, cfg, engine="v3", wave_width=8,
-                              chunk_waves=4)
-        res = eng.replay()
-        return (res.assignments, res.state.match_count, res.state.anti_active,
-                eng.static3)
-    res, st, static3 = _whatif_arrivals(ec, ep, _perturbed(ec.num_nodes, 4))
-    return (res.assignments, np.asarray(st.mc_host, np.float32),
-            np.asarray(st.anti_host, np.float32), static3)
+    nodes, planes, st = _run_with_count_planes(ec, ep, mapping)
+    mc, anti = (("match_count", "anti_active") if mapping == "replay"
+                else ("mc_host", "anti_host"))
+    return nodes, planes[mc], planes[anti], st
 
 
 @pytest.mark.parametrize("mapping", ["replay", "whatif"])
@@ -892,16 +886,22 @@ def test_whatif_host_row_reads_build_no_wave_wide_tensor_and_gather_nothing(
 
 
 @pytest.mark.parametrize(
-    "cell, nodes, host_pos",
-    [("k8s5k-whatif256", 136, (False, True, False, True)),
-     ("borg10k-whatif128", 64, (False,)), ("borg10k-replay1", 64, (False,))],
+    "cell, nodes, host_pos, coarse_pos",
+    [("k8s5k-whatif256", 136, (False, True, False, True),
+      (True, False, True, False)),
+     ("borg10k-whatif128", 64, (False,), (True,)),
+     ("borg10k-replay1", 64, (False,), (True,))],
 )
-def test_host_read_positions_of_the_cells_traces(cell, nodes, host_pos):
+def test_host_read_positions_of_the_cells_traces(cell, nodes, host_pos, coarse_pos):
     """``count_planes()["host_read_positions"]``: B (hostname anti-affinity,
     match counts) and MA (its symmetric check, holders) of the default-plugins
     trace's four positions A, B, SP, MA; none on the Borg trace, whose one
-    position is a zone spread. The same for twelve deals of one pod multiset:
-    a fact of the pods, so every seed finds one program."""
+    position is a zone spread. ``"expand_positions"``: A (zone affinity) and
+    SP (zone spread) there, the mirror half; 1 on the Borg trace, where the
+    counter reads what the pods' terms can name and not what the step does:
+    one ``ScheduleAnyway`` zone spread is scored in domain space and that
+    step expands nothing at all. The same for twelve deals of one pod
+    multiset: facts of the pods, so every seed finds one program."""
     from test_default_plugins_reference import bench
 
     from kubernetes_simulator_tpu.ops import tpu3 as V3
@@ -914,9 +914,225 @@ def test_host_read_positions_of_the_cells_traces(cell, nodes, host_pos):
         ec, ep = gen.to_program(gen.generate(config, nodes, 256, seed), config)
         st = V3.V3Static.build(
             ec, ep, StepSpec.from_config(ec, FrameworkConfig(), ep))
-        got.add((V3.count_planes(st)["host_read_positions"],
-                 tuple(st.host_pos), st.has_host_rows))
-    assert got == {(sum(host_pos), host_pos, any(host_pos))}
+        planes = V3.count_planes(st)
+        got.add((planes["host_read_positions"], tuple(st.host_pos),
+                 st.has_host_rows, planes["expand_positions"],
+                 tuple(st.coarse_pos), planes["term_rows"]))
+    assert got == {(sum(host_pos), host_pos, any(host_pos), sum(coarse_pos),
+                    coarse_pos, len(coarse_pos))}
+
+
+# --- a slot's node-space count values, position by position (ops.tpu3) ----
+# Where the step reads its host rows one by one (`host_row_reads` "rows": the
+# what-if's own form, forced on the single replay here) it expands a slot's
+# domain rows to node space at the positions of the term axis that can name a
+# domain-scale group (`V3Static.coarse_pos`) and reads host rows and builds
+# their in-wave terms at those that can name a host-scale one (`host_pos`;
+# `value_positions`). The form of before PR 38, every position in both lists,
+# is reached through the static facts alone: the same builder handed
+# `coarse_pos` and `host_pos` all True. The single replay's own form (the
+# wave-start contraction, one [KT, N] array over every position) is held to
+# both.
+
+_ZONE = "topology.kubernetes.io/zone"
+
+
+def _one_position_two_scales():
+    """12 nodes in 4 zones, two thirds of them with the singleton topology
+    `custom/slot`; pods whose ONE required anti-affinity term names a
+    hostname-scale group (app a against app d, on the slot), pods whose one
+    term names a zone group (app b against app e, on the zone), the plain
+    pods d and e they avoid, and pods c with a zone spread: the first
+    position of the anti section, and of its symmetric check, holds a group
+    of either scale. No term is against the pod's own app, so the term and
+    its symmetric check each decide placements the other does not; under
+    this deal of the 36 pods, leaving any ONE position out of either list
+    moves pods in the single replay."""
+    from kubernetes_simulator_tpu.models.core import (
+        Cluster, LabelSelector, Pod, PodAffinitySpec, PodAffinityTerm,
+        TopologySpreadConstraint,
+    )
+
+    nodes = _slot_nodes()
+    for i, node in enumerate(nodes):
+        node.labels[_ZONE] = f"z{i % 4}"
+    anti = {
+        "a": PodAffinitySpec(required=(_anti_on_slot(app="d"),)),
+        "b": PodAffinitySpec(required=(
+            PodAffinityTerm(LabelSelector.make({"app": "e"}), _ZONE),)),
+    }
+    spread = [TopologySpreadConstraint(
+        1, _ZONE, "DoNotSchedule", LabelSelector.make({"app": "c"}))]
+    pods = []
+    for i, kind in enumerate(np.random.default_rng(14).choice(
+            list("abcde"), 36, p=[0.3, 0.15, 0.1, 0.3, 0.15])):
+        pods.append(Pod(
+            f"p{i}", labels={"app": str(kind)}, requests={"cpu": 1.5},
+            arrival_time=float(i),
+            pod_anti_affinity=anti.get(str(kind), PodAffinitySpec()),
+            topology_spread=spread if kind == "c" else []))
+    return encode(Cluster(nodes=nodes), pods)
+
+
+_POSITION_TRACES = {
+    **_HOST_ROW_TRACES, "one-position-two-scales": (_one_position_two_scales, 4),
+}
+
+
+def _every_position(monkeypatch):
+    """Every later static names every position as one of either scale."""
+    import dataclasses
+
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    build = V3.V3Static.build
+
+    def every(*args, **kw):
+        st = build(*args, **kw)
+        return dataclasses.replace(st, coarse_pos=np.ones(st.KT, bool),
+                                   host_pos=np.ones(st.KT, bool))
+
+    monkeypatch.setattr(V3.V3Static, "build", every)
+
+
+def _run_with_count_planes(ec, ep, mapping):
+    """(every pod's node, the final count planes by name, the static facts)
+    of a single replay or of a 4-scenario arrivals-only what-if."""
+    if mapping == "replay":
+        eng = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v3",
+                              wave_width=8, chunk_waves=4)
+        res = eng.replay()
+        return (res.assignments, {"match_count": res.state.match_count,
+                                  "anti_active": res.state.anti_active},
+                eng.static3)
+    res, st, static3 = _whatif_arrivals(ec, ep, _perturbed(ec.num_nodes, 4))
+    return (res.assignments,
+            {k: np.asarray(getattr(st, k), np.float32)
+             for k in ("mc_dom", "anti_dom", "mc_host", "anti_host")},
+            static3)
+
+
+@pytest.mark.parametrize("mapping", ["replay", "whatif"])
+@pytest.mark.parametrize("trace", sorted(_POSITION_TRACES))
+def test_v3_position_split_equals_all_positions(trace, mapping, monkeypatch):
+    """Every pod's node and the final ``mc_dom`` / ``anti_dom`` / ``mc_host``
+    / ``anti_host`` planes of the step that builds a slot's node values at
+    the positions that can hold them equal, bit for bit, those of the same
+    step built with every position in both lists (the form of before PR 38)
+    and those of the single replay in its own form, whose nodes scenario 0 of
+    the what-if has too."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    make, dmax = _POSITION_TRACES[trace]
+    ec, ep = make()
+    if dmax is not None:
+        _force_host_planes(monkeypatch, dmax)
+    own = _run_with_count_planes(ec, ep, "replay")  # the contraction
+    monkeypatch.setattr(V3, "host_row_reads", lambda *a, **k: "rows")
+    nodes, planes, st = _run_with_count_planes(ec, ep, mapping)
+    assert st.has_host_rows and (nodes >= 0).any()
+    assert any(p.any() for p in planes.values())
+    assert (st.coarse_pos | st.host_pos).all()
+    both = st.coarse_pos & st.host_pos
+    if trace == "one-position-two-scales":
+        o = st.sections
+        assert both[o[1]] and both[o[4]] and not both[o[2]]
+    # some position is left out of one list, or the split changes nothing
+    assert not both.all()
+    np.testing.assert_array_equal(nodes[0] if mapping == "whatif" else nodes,
+                                  own[0])
+    if mapping == "replay":
+        for name, plane in planes.items():
+            np.testing.assert_array_equal(plane, own[1][name], err_msg=name)
+    _every_position(monkeypatch)
+    nodes_all, planes_all, st_all = _run_with_count_planes(ec, ep, mapping)
+    assert st_all.coarse_pos.all() and st_all.host_pos.all()
+    np.testing.assert_array_equal(nodes, nodes_all)
+    assert sorted(planes) == sorted(planes_all)
+    for name, plane in planes.items():
+        np.testing.assert_array_equal(plane, planes_all[name], err_msg=name)
+
+
+@pytest.mark.parametrize("which, drop", [
+    ("expansion", 0), ("expansion", 1), ("expansion", 2), ("host", 0), ("host", 1),
+])
+def test_every_position_of_the_two_scale_trace_decides_placements(
+    which, drop, monkeypatch
+):
+    """The trace above holds the split to something: a step that leaves ONE
+    of its positions out of the expansion's list (B, SP, MA) or out of the
+    host rows' (B, MA) places pods elsewhere in the single replay (built
+    in the what-if's form)."""
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+
+    ec, ep = _one_position_two_scales()
+    _force_host_planes(monkeypatch, 4)
+    monkeypatch.setattr(V3, "host_row_reads", lambda *a, **k: "rows")
+    nodes, _, st = _run_with_count_planes(ec, ep, "replay")
+    lists = V3.value_positions(st)
+    assert [len(p) for p in lists] == [3, 2]
+
+    def one_left_out(st, all_positions=False):
+        kept = [list(p) for p in lists]
+        del kept[which == "host"][drop]
+        return tuple(kept)
+
+    monkeypatch.setattr(V3, "value_positions", one_left_out)
+    assert (_run_with_count_planes(ec, ep, "replay")[0] != nodes).any()
+
+
+def test_whatif_slot_builds_no_count_value_over_all_positions(monkeypatch):
+    """The arrivals-only what-if chunk program (``jit_per_scenario_src``) on
+    the 136-node default-plugins trace, 5 scenarios: the wave scan holds no
+    float32 value shaped [S, KT, N] or [KT, S, N] (KT = 4: a slot's node
+    values over every position), and each slot's expansion, the one
+    contraction whose result carries the scenario and the node axis, has
+    ``coarse_pos.sum()`` = 2 rows. Built with every position in both lists
+    the same program holds both: what the test looks for is there to see."""
+    import jax
+
+    from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
+
+    ec, ep = _default_plugins_136()
+    S, W, N = 5, 8, ec.num_nodes
+
+    def traced():
+        eng = WhatIfEngine(ec, ep, _perturbed(N, S), FrameworkConfig(),
+                           wave_width=W, chunk_waves=4,
+                           collect_assignments=True)
+        st, seen, chunk_fn = eng.static3, {}, eng._chunk_fn
+
+        class Traced(Exception):
+            pass
+
+        def spy(*args):
+            seen["jaxpr"] = jax.make_jaxpr(chunk_fn)(*args).jaxpr
+            raise Traced
+
+        eng._chunk_fn = spy
+        with pytest.raises(Traced):
+            eng.run()
+        over_all, expansion_rows = 0, []
+        (scan,) = [e for e in _eqns(seen["jaxpr"]) if e.primitive.name == "scan"]
+        for eqn in _eqns(scan.params["jaxpr"].jaxpr):
+            for v in eqn.outvars:
+                shape = tuple(getattr(v.aval, "shape", ()))
+                if v.aval.dtype == np.float32 and shape in (
+                        (S, st.KT, N), (st.KT, S, N)):
+                    over_all += 1
+                if (eqn.primitive.name == "dot_general" and len(shape) == 3
+                        and S in shape and N in shape):
+                    (rows,) = [d for d in shape if d not in (S, N)]
+                    expansion_rows.append(rows)
+        return st, over_all, expansion_rows
+
+    st, over_all, expansion_rows = traced()
+    assert st.KT == 4 and tuple(st.coarse_pos) == (True, False, True, False)
+    assert over_all == 0
+    assert expansion_rows == [int(st.coarse_pos.sum())] * W == [2] * W
+    _every_position(monkeypatch)
+    st, over_all, expansion_rows = traced()
+    assert over_all > 0 and expansion_rows == [st.KT] * W
 
 
 # --- the wave-end commit of the host-scale count rows (ops.tpu3) ----------
