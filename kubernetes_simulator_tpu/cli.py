@@ -180,6 +180,7 @@ def cmd_whatif(args) -> int:
         preemption=cfg.device_preemption,
         completions=cfg.whatif.completions,
         retry_buffer=cfg.whatif.retry_buffer,
+        collect_assignments=cfg.whatif.placements,
         telemetry=cfg.telemetry.granularity,
     )
     # DCN: every process assembles the identical gathered result; each
@@ -702,6 +703,20 @@ def validate_config(cfg) -> list:
                 errors.append(f"workload.borg.{key}: file not found: {p}")
         if cfg.borg.cpu_scale <= 0 or cfg.borg.mem_scale <= 0:
             errors.append("workload.borg.cpuScale/memScale: must be > 0")
+        if cfg.borg.tasks_per_day < 0:
+            errors.append("workload.borg.tasksPerDay: must be >= 0")
+        fill, band = cfg.borg.resident_fill, cfg.borg.resident_band
+        if fill < 0 or fill + band > 1.0 or band < 0 or (fill and fill <= band):
+            errors.append(
+                "workload.borg.residentFill / residentBand: the fill is a share "
+                "of each node's cpu, drawn in fill +- band inside (0, 1]"
+            )
+        if fill and (cfg.borg.trace_path or cfg.borg.instance_events):
+            errors.append(
+                "workload.borg.residentFill: a trace file brings its own "
+                "resident set (a bound_node column); the generator's is for "
+                "the sampled workload"
+            )
     else:
         if cfg.cluster.nodes <= 0:
             errors.append("cluster.nodes: must be > 0")
@@ -771,6 +786,8 @@ def validate_config(cfg) -> list:
             "(the eager per-chunk folds would serialize the scenario "
             "axis); tier preemption runs under a mesh"
         )
+    if cfg.whatif.placements and cfg.whatif.scenarios <= 0:
+        errors.append("whatIf.placements: needs whatIf.scenarios > 0")
     if cfg.whatif.retry_buffer and cfg.whatif.completions is False:
         errors.append(
             "whatIf.retryBuffer requires the device-release path; remove "

@@ -53,8 +53,18 @@ def init_state(ec: EncodedCluster, pods: EncodedPods, apply_prebound: bool = Tru
         bound=np.full(pods.num_pods, PAD, dtype=np.int32),
     )
     if apply_prebound:
-        for p in np.nonzero(pods.bound_node >= 0)[0]:
-            bind(ec, pods, st, int(p), int(pods.bound_node[p]))
+        # One vectorized fold (a Borg window's resident set is 190,000
+        # pods): ``release_delta`` sums in pod order, as a loop of ``bind``
+        # would.
+        pre = np.nonzero(pods.bound_node >= 0)[0]
+        if pre.size:
+            at = pods.bound_node[pre].astype(np.int64)
+            used, mc, aa, pw = release_delta(ec, pods, pre, at)
+            st.used += used
+            st.match_count += mc
+            st.anti_active += aa
+            st.pref_wsum += pw
+            st.bound[pre] = at
     return st
 
 

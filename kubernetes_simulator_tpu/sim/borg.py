@@ -56,6 +56,15 @@ class BorgSpec:
     spread_app_fraction: float = 0.25
     toleration_fraction: float = 0.3
     mean_duration: float = 3600.0
+    # A cell that is full (examples/config_borg_backlog.yaml). ``tasks_per_day``
+    # > 0: the tasks are a WINDOW cut out of a day of that many tasks (they
+    # arrive at its rate and span tasks / rate seconds), not a day thinned to
+    # ``tasks``. ``resident_fill`` > 0: a resident set, long-running tasks
+    # bound before the window starts up to that share (+- ``resident_band``,
+    # drawn per node) of each node's cpu.
+    tasks_per_day: float = 0.0
+    resident_fill: float = 0.0
+    resident_band: float = 0.05
 
     @classmethod
     def from_spec(cls, spec) -> "BorgSpec":
@@ -70,6 +79,9 @@ class BorgSpec:
             gang_fraction=spec.gang_fraction,
             max_gang=spec.max_gang,
             num_apps=getattr(spec, "num_apps", 48),
+            tasks_per_day=getattr(spec, "tasks_per_day", 0.0),
+            resident_fill=getattr(spec, "resident_fill", 0.0),
+            resident_band=getattr(spec, "resident_band", 0.05),
         )
 
 
@@ -125,6 +137,14 @@ def _sample_cols(spec: BorgSpec) -> dict:
     arrival = np.cumsum(gaps)
     arrival *= 1.0 + 0.5 * np.sin((arrival + phase) * (2 * np.pi / 86400.0))
     arrival = np.sort(arrival).astype(np.float64)
+    if spec.tasks_per_day > 0:
+        # A window of the day at the day's own rate; it ends where the
+        # diurnal factor crosses 1, so it spans tasks / rate seconds.
+        wrng = np.random.default_rng(spec.seed + 1)
+        rate = spec.tasks_per_day / 86400.0
+        arrival = np.cumsum(wrng.exponential(1.0 / rate, size=P))
+        arrival *= 1.0 + 0.5 * np.sin((arrival - P / rate) * (2 * np.pi / 86400.0))
+        arrival = np.sort(arrival).astype(np.float64)
 
     # Alloc sets: contiguous gangs.
     group_id = np.full(P, PAD, dtype=np.int32)
@@ -155,7 +175,13 @@ def _sample_cols(spec: BorgSpec) -> dict:
 def encoded_from_cols(spec: BorgSpec, cols: dict) -> Tuple[EncodedCluster, EncodedPods, dict]:
     """Columnar trace → (EncodedCluster, EncodedPods, meta) by expanding the
     app/toleration templates through the normal Encoder. The inverse of
-    export_trace_csv; also the ingest path for external trace files."""
+    export_trace_csv; also the ingest path for external trace files.
+
+    An optional ``bound_node`` column is the window's resident set: a task
+    with ``bound_node >= 0`` holds that node at t = 0 (its ``arrival`` reads
+    0 and its ``duration`` counts from 0); the engines keep such tasks out
+    of the waves and release them from the static lists. Absent, every task
+    arrives unbound, as before."""
     cluster = make_cluster(spec.nodes, seed=spec.seed, taint_fraction=0.15)
     templates = _make_templates(spec)
     enc = Encoder()
@@ -185,6 +211,15 @@ def encoded_from_cols(spec: BorgSpec, cols: dict) -> Tuple[EncodedCluster, Encod
     requests[:, pi] = 1.0
 
     arrival = np.asarray(cols["arrival"], np.float64)
+    bound = np.full(P, PAD, dtype=np.int32)
+    if "bound_node" in cols:
+        bound = np.asarray(cols["bound_node"], np.int32).copy()
+        if bound.size and bound.max(initial=PAD) >= spec.nodes:
+            raise ValueError(
+                f"bound_node names node {int(bound.max())} of {spec.nodes}"
+            )
+        bound[bound < 0] = PAD
+        arrival = np.where(bound >= 0, 0.0, arrival)
     # int64 until after the remap: real Borg collection ids exceed 2^31.
     group_raw = np.asarray(cols["group_id"], np.int64)
     duration = np.asarray(cols["duration"], np.float32)
@@ -214,7 +249,7 @@ def encoded_from_cols(spec: BorgSpec, cols: dict) -> Tuple[EncodedCluster, Encod
         arrival=arrival,
         duration=duration,
         ns=tmpl_ep.ns[tidx],
-        bound_node=np.full(P, PAD, dtype=np.int32),
+        bound_node=bound,
         tol_key=tmpl_ep.tol_key[tidx],
         tol_kv=tmpl_ep.tol_kv[tidx],
         tol_effect=tmpl_ep.tol_effect[tidx],
@@ -239,13 +274,78 @@ def encoded_from_cols(spec: BorgSpec, cols: dict) -> Tuple[EncodedCluster, Encod
         "gang_pods": int((group_id >= 0).sum()),
         "num_groups": ec.num_groups,
         "makespan": float(arrival[-1]) if P else 0.0,
+        "resident": int((bound >= 0).sum()),
     }
     return ec, ep, meta
 
 
+RESIDENT_CPU = np.array([1.0, 2.0, 4.0, 8.0], dtype=np.float32)
+RESIDENT_TIER_PROBS = np.array([0.02, 0.08, 0.15, 0.6, 0.15])
+RESIDENT_MEAN_DURATION = 7 * 86400.0
+
+
+def _resident_cols(spec: BorgSpec) -> dict:
+    """The resident set of a full cell (``resident_fill``), as trace columns
+    with a ``bound_node``: node by node, tasks of the upper cpu buckets (at
+    the buckets' own odds) until the next would pass the node's fill, then
+    the largest buckets that still fit; memory from the buckets (a bucket
+    down where a node's memory would be passed); mostly production tiers;
+    on a tainted node only tasks that tolerate the taint; durations of a
+    week's mean. No resident is a gang member."""
+    cluster = make_cluster(spec.nodes, seed=spec.seed, taint_fraction=0.15)
+    ncpu = np.array([n.allocatable["cpu"] for n in cluster.nodes], np.float64)
+    nmem = np.array([n.allocatable["memory"] for n in cluster.nodes], np.float64)
+    tainted = np.array([bool(n.taints) for n in cluster.nodes])
+    rng = np.random.default_rng(spec.seed + 2)
+    odds = CPU_PROBS[np.searchsorted(CPU_BUCKETS, RESIDENT_CPU)]
+    odds = odds / odds.sum()
+    fill = rng.uniform(spec.resident_fill - spec.resident_band,
+                       spec.resident_fill + spec.resident_band, size=spec.nodes)
+    cpu, at = [], []
+    for n in range(spec.nodes):
+        target = float(fill[n]) * float(ncpu[n])
+        draw = rng.choice(RESIDENT_CPU, size=int(target) + 1, p=odds)
+        took = list(draw[np.cumsum(draw) <= target])
+        left = target - float(sum(took))
+        for b in RESIDENT_CPU[::-1]:
+            while b <= left and len(took) < 110:
+                took.append(b)
+                left -= float(b)
+        cpu.append(np.asarray(took, np.float32))
+        at.append(np.full(len(took), n, np.int32))
+    cpu, at = np.concatenate(cpu), np.concatenate(at)
+    R = len(cpu)
+    tier = rng.choice(len(PRIORITY_TIERS), size=R, p=RESIDENT_TIER_PROBS)
+    tier = np.where(tainted[at], rng.integers(0, 2, size=R), tier)
+    mem = rng.choice(MEM_BUCKETS, size=R, p=MEM_PROBS).astype(np.float32)
+    for _ in MEM_BUCKETS:
+        over = np.bincount(at, mem.astype(np.float64), spec.nodes) > spec.resident_fill * nmem
+        shrink = over[at] & (mem > MEM_BUCKETS[0])
+        if not shrink.any():
+            break
+        mem = np.where(shrink, mem / 2, mem).astype(np.float32)
+    app_probs = 1.0 / (np.arange(spec.num_apps) + 2.0)
+    app_probs /= app_probs.sum()
+    return {
+        "arrival": np.zeros(R, np.float64), "cpu": cpu, "mem": mem,
+        "priority": PRIORITY_TIERS[tier].astype(np.int32),
+        "group_id": np.full(R, PAD, np.int32),
+        "app_id": rng.choice(spec.num_apps, size=R, p=app_probs).astype(np.int32),
+        "tolerates": tainted[at].astype(np.int32),
+        "duration": rng.exponential(RESIDENT_MEAN_DURATION, size=R).astype(np.float32),
+        "bound_node": at,
+    }
+
+
 def make_borg_encoded(spec: BorgSpec) -> Tuple[EncodedCluster, EncodedPods, dict]:
-    """Vectorized trace build → (EncodedCluster, EncodedPods, meta)."""
-    return encoded_from_cols(spec, _sample_cols(spec))
+    """Vectorized trace build → (EncodedCluster, EncodedPods, meta); with
+    ``resident_fill`` the resident set stands first in every column."""
+    cols = _sample_cols(spec)
+    if spec.resident_fill > 0:
+        res = _resident_cols(spec)
+        cols["bound_node"] = np.full(spec.tasks, PAD, np.int32)
+        cols = {k: np.concatenate([res[k], cols[k]]) for k in res}
+    return encoded_from_cols(spec, cols)
 
 
 def export_trace_csv(spec: BorgSpec, path) -> dict:

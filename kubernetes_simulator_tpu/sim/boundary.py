@@ -11,7 +11,10 @@ passes run there, in order:
    is at or before the boundary's start time, bound in chunks ≤ b−2 (the
    one-chunk slack shared with the device pipeline).
 3. **Bounded retry / preemption pass** — the [K8S] activeQ analogue:
-   failed non-gang pods retry placement FIFO; under ``kube=True`` a pod
+   failed non-gang pods retry placement in kube's QueueSort order
+   (priority descending, then the order they entered the queue, which is
+   arrival order; one stable sort of the queue a boundary); under
+   ``kube=True`` a pod
    that still fails runs the EXACT kube PostFilter
    (``SchedulerFramework._post_filter_preempt``: fewest victims, lowest
    max victim priority, only the victims needed for THIS pod's fit,
@@ -134,7 +137,15 @@ class BoundaryOps:
         self.bind_chunk = np.full(P, _NEVER, np.int64)
         self.bind_chunk[ep.bound_node >= 0] = -2
         self.retry_q: List[int] = []
+        # Every retried bind with a finite release boundary, however many
+        # are outstanding (no cap: a bind that lost its release would hold
+        # its node to the end of the trace).
         self.pend: List[list] = []  # [relb, pod, node]
+        # Per pod: -1 bound in its arrival wave (or pre-bound), b >= 0
+        # bound by the retry pass of boundary b; ``bind_boundary_codes``
+        # adds the codes of the pods with no node.
+        self.bind_boundary = np.full(P, -1, np.int32)
+        self._dropped = np.zeros(P, bool)
         self.placed_total = 0
         self.preemptions = 0
         # [K8S] keeps every pending pod; the bounded analogue sheds load —
@@ -337,14 +348,30 @@ class BoundaryOps:
     # -- chunk-side hooks ---------------------------------------------------
 
     def offer_failure(self, p: int) -> None:
-        """A non-gang pod that missed placement enters the FIFO buffer
-        (overflow drops the newest — counted)."""
+        """A non-gang pod that missed placement enters the buffer, behind
+        the pods already there (overflow drops the newest — counted)."""
         if not self.retry_buffer or self.ep.group_id[p] != PAD:
             return
         if len(self.retry_q) < self.retry_buffer:
             self.retry_q.append(int(p))
         else:
             self.retry_dropped += 1
+            self._dropped[p] = True
+
+    def bind_boundary_codes(self) -> np.ndarray:
+        """[P] i32 beside ``assignments``: -1 bound in its arrival wave (or
+        pre-bound), ``b >= 0`` bound by the retry pass of boundary ``b``;
+        for a pod with no node -2 still queued at the end, -3 dropped at a
+        full buffer, -4 refused at arrival and never queued (a gang
+        member, or any pod without a retry buffer). The what-if engine's
+        ``bind_boundary`` hand-back, on the host."""
+        out = self.bind_boundary.copy()
+        none = self.assignments == PAD
+        out[none] = -4
+        out[none & self._dropped] = -3
+        queued = np.asarray(self.retry_q, np.int64)
+        out[queued[none[queued]]] = -2
+        return out
 
     def fold_chunk(self, ci: int, rows: np.ndarray, choices: np.ndarray) -> None:
         """Fold one device chunk's placements into the host mirror (batch
@@ -543,7 +570,9 @@ class BoundaryOps:
         tel = self.tel
         binds_l: List[Tuple[int, int]] = []
         evicts_l: List[Tuple[int, int]] = []
-        # 3. Bounded retry (+ kube preemption) pass, FIFO order. Victims
+        # 3. Bounded retry (+ kube preemption) pass in QueueSort order:
+        # priority descending, then the order of entering the queue (a
+        # stable sort: pods of one priority keep FIFO order). Victims
         # re-enter the walked queue and are attempted later in the SAME
         # pass — mirroring the CPU event engine, which requeues victims
         # into the activeQ at the preemption instant.
@@ -552,6 +581,7 @@ class BoundaryOps:
             # logged deltas must land first (rare path; quiet runs never
             # get here and never pay a fold).
             self.flush_planes()
+            self.retry_q.sort(key=lambda p: -int(ep.priority[p]))
             q = self.retry_q
             still_q: List[int] = []
             i = 0
@@ -598,6 +628,7 @@ class BoundaryOps:
                 bind(ec, ep, st, p, res.node)
                 binds_l.append((p, int(res.node)))
                 self.assignments[p] = res.node
+                self.bind_boundary[p] = b
                 if tel is not None:
                     tel.clear_episode(p)
                     t_bind = (
@@ -629,7 +660,7 @@ class BoundaryOps:
                 # Release schedule: f32 boundary search, >= b+1 — the pod
                 # STARTS now, not at arrival.
                 dur = np.float32(ep.duration[p])
-                if np.isfinite(dur) and len(self.pend) < self.retry_buffer:
+                if np.isfinite(dur):
                     rb = int(
                         np.searchsorted(
                             self.tb32,

@@ -125,17 +125,19 @@ def greedy_replay(
     a completed pod ran to completion, it is not unschedulable).
 
     ``retry_buffer`` (round 4, [K8S] activeQ flush-on-event analogue):
-    non-gang pods that miss placement enter a FIFO retry buffer (capacity
+    non-gang pods that miss placement enter a retry buffer (capacity
     ``retry_buffer``; overflow drops the newest — they stay permanently
     unscheduled as before). At each chunk boundary, AFTER releases apply,
-    one bounded retry pass re-attempts every buffered pod in order;
+    one bounded retry pass re-attempts every buffered pod in kube's
+    QueueSort order (priority descending, then the order of arrival);
     placed pods leave the buffer and start at the boundary's time — they
     release at the first boundary whose start time reaches ``t_b +
     duration`` (computed in f32, exactly as the device does; at least
-    ``b+1``), through a pending list also capped at ``retry_buffer``
-    (overflow = the release is dropped and the pod holds its resources to
-    the end). Requires ``completions_chunk_waves``. Mirrors
-    WhatIfEngine(retry_buffer=...)'s device semantics exactly."""
+    ``b+1``), however many are outstanding. The result's
+    ``bind_boundary`` says, pod by pod, which boundary's pass bound it
+    (``BoundaryOps.bind_boundary_codes``). Requires
+    ``completions_chunk_waves``. Mirrors WhatIfEngine(retry_buffer=...)'s
+    device semantics exactly."""
     from .boundary import BoundaryOps
 
     from dataclasses import replace as dc_replace
@@ -294,4 +296,5 @@ def greedy_replay(
         state=st,
         retry_dropped=ops.retry_dropped,
         fragmentation=frag,
+        bind_boundary=ops.bind_boundary_codes() if retry_buffer else None,
     )

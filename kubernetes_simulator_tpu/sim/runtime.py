@@ -163,6 +163,10 @@ class ReplayResult:
     # CPU engine ↔ device paths. None only on legacy callers that build
     # the result by hand.
     fragmentation: Optional[dict] = None
+    # Under a retry buffer (greedy_replay): per pod the boundary whose retry
+    # pass bound it, -1 for an arrival bind, -2 / -3 / -4 for a pod with no
+    # node (sim.boundary.BoundaryOps.bind_boundary_codes).
+    bind_boundary: Optional[np.ndarray] = None
     # Telemetry (sim.telemetry.ReplayTelemetry) — None at granularity
     # "off". Latency histograms, rejection attribution, series, phase
     # timers; see the telemetry module docstring for cross-engine

@@ -261,12 +261,20 @@ class ReplayTelemetry:
     # ``pods_rolled_back`` (binds given back) are counted on the device and
     # fetched at gather, summed over a what-if batch's scenarios.
     gangs: Optional[Dict[str, object]] = None
+    # Only on the what-if device retry path (``retry_buffer``): the buffer,
+    # the passes made, and per scenario mean / max of ``retry_placed``,
+    # ``retry_dropped``, the queue's depth at the boundaries (``depth_max``,
+    # ``depth_at_end``) and ``release_leaked`` (re-tried binds whose release
+    # fell inside the trace and never ran: 0 by construction), with
+    # scenario 0's own numbers under ``scenario0``.
+    retry: Optional[Dict[str, object]] = None
 
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
         for key in ("chunk_waves", "inwave_corrections", "select_form",
                     "count_planes", "scenarios", "release_buckets",
-                    "release_rounds", "handback_bytes", "mesh", "gangs"):
+                    "release_rounds", "handback_bytes", "mesh", "gangs",
+                    "retry"):
             if getattr(self, key) is not None:
                 out[key] = getattr(self, key)
         if self.latency is not None:
@@ -413,6 +421,17 @@ class ReplayTelemetry:
             tel.gangs = dict(gangs[0])
             for key in ("wide_rolled_back", "pods_rolled_back"):
                 tel.gangs[key] = sum(g[key] for g in gangs)
+        retries = [p.retry for _, p in keep if p.retry is not None]
+        if retries:
+            # disjoint scenario blocks: the largest of the maxes, the means
+            # averaged block by block; scenario 0 lies in the first block
+            tel.retry = dict(retries[0])
+            for key, v in retries[0].items():
+                if isinstance(v, dict) and "max" in v:
+                    tel.retry[key] = {
+                        "mean": float(np.mean([r[key]["mean"] for r in retries])),
+                        "max": max(r[key]["max"] for r in retries),
+                    }
         buckets = [p.release_buckets for _, p in keep
                    if p.release_buckets is not None]
         if buckets:

@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field, replace as dc_replace
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +54,7 @@ from .waves import pack_waves, refuse_wide_gangs, widest_gang
 # The release program's vmap axis: named so that the rank rounds of a block
 # run to ONE trip count, the largest among the scenarios (ops.release_planes).
 _RELEASE_VMAP = "release_scenarios"
+_RETRY_VMAP = "retry_scenarios"
 
 @dataclass
 class Perturbation:
@@ -442,6 +443,43 @@ class ScenarioDyn:
         return self.ov_nodes.shape[1]
 
 
+class RetryQueue(NamedTuple):
+    """What the device retry path carries from one chunk call to the next,
+    per scenario (a leading ``[S]`` on every leaf): the pending queue and
+    the record of every bind its passes made.
+
+    ``ids`` / ``prio`` / ``dur`` ``[RB]``: the queued tasks in kube's
+    QueueSort order (priority descending, then arrival; -1 an empty slot)
+    with what a pass needs of each, sorted along so that nothing is
+    gathered by task id. ``t_*`` ``[boundaries, ..., RB]``: row ``b`` holds
+    what boundary ``b``'s pass bound, by queue position: the task, its node
+    (-1: this slot bound nothing), the boundary it releases at (``1 << 30``
+    never) and the rows its release rewinds (requests, matched groups, and
+    the anti / preferred terms where the trace carries them, else None).
+    The record IS the pending-release table: a boundary holds every earlier
+    row against its own index, so no bind can lose its release to a full
+    list, and the hand-back reads each re-tried task's node and boundary
+    from it. ``owed`` less ``released`` at the end of a run is
+    ``release_leaked`` (0 by construction)."""
+
+    ids: jax.Array
+    prio: jax.Array
+    dur: jax.Array
+    count: jax.Array
+    dropped: jax.Array
+    depth_max: jax.Array
+    owed: jax.Array
+    released: jax.Array
+    t_id: jax.Array
+    t_node: jax.Array
+    t_relb: jax.Array
+    t_req: jax.Array
+    t_mg: jax.Array
+    t_an: Optional[jax.Array] = None
+    t_pf: Optional[jax.Array] = None
+    t_pw: Optional[jax.Array] = None
+
+
 @dataclass
 class WhatIfResult:
     placed: np.ndarray  # [S] i32
@@ -450,6 +488,12 @@ class WhatIfResult:
     wall_clock_s: float
     placements_per_sec: float  # aggregate over all scenarios
     assignments: Optional[np.ndarray] = None  # [S, P] when collected
+    # Beside ``assignments`` under the device ``retry_buffer``: [S, P] i32,
+    # -1 bound in its arrival wave (or resident), b >= 0 bound by the retry
+    # pass of boundary b; for a task with no node -2 still queued at the
+    # end, -3 dropped at a full buffer, -4 refused at arrival and never
+    # queued (a gang member).
+    bind_boundary: Optional[np.ndarray] = None
     utilization_cpu: Optional[np.ndarray] = None  # [S]
     # Which semantics this batch actually ran under (round 4: two batches
     # evaluated under different semantics must be programmatically
@@ -461,7 +505,7 @@ class WhatIfResult:
     # buffer CAPACITY, not infeasibility (VERDICT r4 weak #2). Round 6:
     # ``retry_dropped`` is reported by EVERY engine that can drop pods —
     # the kube host mirrors AND the non-kube device retry path (its
-    # in-scan FIFO counts overflow exactly like the host analogue).
+    # queue counts overflow exactly like the host analogue).
     preemptions: Optional[np.ndarray] = None  # [S] i32
     retry_dropped: Optional[np.ndarray] = None  # [S] i32
     # Per-scenario chaos disruption (kube batches, round 7): node_down
@@ -538,9 +582,10 @@ class WhatIfEngine:
         path: on the device-release path the placements are copied from
         the on-device wave-order buffer once, when the last chunk is done
         (the ``handback`` phase); on the other paths they come from the
-        per-chunk choices those paths fetch anyway. The device
-        ``retry_buffer`` refuses it (retried placements are not kept per
-        task on the device).
+        per-chunk choices those paths fetch anyway. With the device
+        ``retry_buffer`` a second array comes back beside it,
+        ``WhatIfResult.bind_boundary``: which boundary's retry pass bound
+        each task (-1: its arrival wave; -2 / -3 / -4: no node, and why).
 
         ``fork_checkpoint``: path to a JaxReplayEngine checkpoint — the
         what-if FORK POINT (SURVEY.md §5 checkpoint/resume): every scenario
@@ -572,15 +617,19 @@ class WhatIfEngine:
 
         ``retry_buffer`` (round 4): device-path unschedulable RETRY — the
         [K8S] activeQ flush-on-event analogue. Non-gang pods that miss
-        placement enter a per-scenario FIFO buffer (capacity rounded up
-        to a wave multiple; overflow drops the newest); at every chunk
+        placement enter a per-scenario buffer (capacity rounded up to a
+        wave multiple; overflow drops the newest); at every chunk
         boundary, after releases apply, one bounded retry pass re-runs
-        the normal wave step over the buffer. Pods placed on retry start
+        the normal wave step over the buffer in kube's QueueSort order
+        (priority descending, then arrival). Pods placed on retry start
         AT THE BOUNDARY: they release at the first boundary whose start
-        time reaches ``t_b + duration`` (f32), at least ``b+1``, via a
-        pending list capped at the same size (its releases ride the same
-        commit-block core as the static lists, so the full default
-        plugin set is covered). Semantics anchored by
+        time reaches ``t_b + duration`` (f32), at least ``b+1``, however
+        many are outstanding: every pass's binds are recorded
+        (``RetryQueue``) and each boundary holds the whole record against
+        its index (the releases ride the same commit-block core as the
+        static lists, so the full default plugin set is covered).
+        ``summary()["retry"]`` counts the passes' binds, the drops, the
+        queue's depth and ``release_leaked``. Semantics anchored by
         ``greedy_replay(retry_buffer=...)``. Requires the device-release
         completions path without DynTables; 0 = off (the r01–r03
         semantics).
@@ -1106,18 +1155,6 @@ class WhatIfEngine:
                     "without label-perturbation DynTables (meshes are "
                     "supported since round 10)"
                 )
-            if collect_assignments and not self.kube:
-                # A placement made by the boundary retry pass never enters
-                # the on-device placement buffer (its release rides the
-                # pending list), so that buffer handed back would call a
-                # retried task unplaced.
-                raise ValueError(
-                    "collect_assignments is not supported with the device "
-                    "retry_buffer: retried placements are not kept per "
-                    "task on the device. Use preemption='kube' (host "
-                    "mirrors) or greedy_replay(retry_buffer=...) for "
-                    "per-task placements under retry"
-                )
         # Host-side completions need per-scenario choices even when the
         # caller only wants counts; the device-release path never fetches
         # them per chunk: asked for placements, it hands its on-device
@@ -1175,6 +1212,7 @@ class WhatIfEngine:
         # batch in flight; and the collectives counted in the compiled
         # chunk, hand-back and gather programs (read once per engine).
         self._dc_mesh = None
+        self._state_one = None
         self._state_one_mesh = None
         self._idx_chunks_mesh: Optional[list] = None
         self._mesh_batch: Optional[Dict[str, float]] = None
@@ -1489,169 +1527,190 @@ class WhatIfEngine:
                     BIG = 1 << 30
 
                     rel_core = self._release_core()
+                    want_an, want_pf = rel_core.want_an, rel_core.want_pf
 
                     def per_scenario_retry(
                         dc, state, src, xsrc, mgt, antit, preft,
-                        prefwt, durt, tbt,
+                        prefwt, durt, priot, tbt,
                         idx, t_b, b,
-                        vassign, rbuf, rcount,
-                        pend_id, pend_node, pend_relb, rdrop,
+                        vassign, rq,
                     ):
                         """The device-release chunk call with the
                         bounded unschedulable-retry pass (semantics:
                         sim.greedy.greedy_replay(retry_buffer=...)).
                         Static releases ran in the separate bucketed
-                        _release_fn before this call. Order here:
-                        pend releases → retry pass → buffer
-                        compaction → main chunk scan (with failure
-                        appends) → assignment fold."""
+                        _release_fn before this call. Order here: the
+                        releases of re-tried binds that are due -> the
+                        retry pass over the queue (in QueueSort order
+                        since the last call's upkeep) and its record ->
+                        the main chunk scan -> the queue's upkeep (the
+                        chunk's failures join, one stable sort by
+                        priority) -> the assignment fold. ``rq`` is the
+                        scenario's ``RetryQueue``."""
                         d = T.Derived.build(dc)
                         cmasks = V3.class_masks(dc, d, st3, spec, reps)
                         wave_step = V3.make_wave_step3(
                             dc, d, sh3, st3, wave_width, spec, cmasks,
                             scenario_axis=True,
                         )
-                        # 1. releases of retried-placed pods whose
-                        # boundary arrived (relb encodes the f32 time
-                        # comparison already).
-                        due_p = (pend_id >= 0) & (pend_relb <= b)
-                        safe_p = jnp.clip(pend_id, 0)
-                        nd_p = jnp.where(due_p, pend_node, -1)
-                        with stage("ksim.release"):
-                            state, _, _ = rel_core(
-                                state, nd_p, src.requests[safe_p],
-                                mgt[safe_p], antit[safe_p],
-                                preft[safe_p], prefwt[safe_p],
+                        row = lambda t, r: jax.lax.dynamic_index_in_dim(
+                            t, r, keepdims=False
+                        )
+                        put = lambda t, v: jax.lax.dynamic_update_index_in_dim(
+                            t, v.astype(t.dtype), b, 0
+                        )
+                        none_i = jnp.full((RB, 1), PAD, jnp.int32)
+                        none_f = jnp.zeros((RB, 1), jnp.float32)
+
+                        # 1. a re-tried bind is released at the boundary
+                        # its record names (t_relb, the f32 comparison made
+                        # when it bound): every earlier pass's row is held
+                        # against b, however many binds are outstanding.
+                        def rel_row(r, carry):
+                            st, n = carry
+                            due = row(rq.t_relb, r) == b
+                            st, _, _ = rel_core(
+                                st,
+                                jnp.where(due, row(rq.t_node, r), -1),
+                                row(rq.t_req, r).T,
+                                row(rq.t_mg, r).T,
+                                row(rq.t_an, r).T if want_an else none_i,
+                                row(rq.t_pf, r).T if want_pf else none_i,
+                                row(rq.t_pw, r).T if want_pf else none_f,
+                                axis_name=_RETRY_VMAP,
                             )
-                        # 2. bounded retry pass: the NORMAL wave step
-                        # over the buffer (empty slots are invalid
-                        # no-ops), FIFO order preserved by the wave
-                        # packing below.
-                        rb_waves = rbuf.reshape(RBW, wave_width)
-                        slots_r = T.gather_slots_device(src, rb_waves)
-                        extra_r = V3.gather_extra_device(xsrc, rb_waves)
-                        state, choices_r = jax.lax.scan(
-                            wave_step, state, (slots_r, extra_r)
-                        )
-                        flat_cr = choices_r.reshape(RB)
-                        placed_r = (flat_cr >= 0) & (rbuf >= 0)
-                        retry_placed = placed_r.sum().astype(jnp.int32)
-                        # 3. pend append (placed pods start NOW: f32
-                        # boundary search, at least b+1) + stable
-                        # compaction, drop-newest on overflow.
-                        dur_r = durt[jnp.clip(rbuf, 0)]
-                        rbn = jnp.searchsorted(
-                            tbt, t_b + dur_r, side="left"
-                        )
-                        relb_new = jnp.where(
-                            placed_r & (rbn < tbt.shape[0]),
-                            jnp.maximum(rbn, b + 1),
-                            BIG,
-                        ).astype(jnp.int32)
-                        add = placed_r & (relb_new < BIG)
-                        keep_old = (pend_id >= 0) & ~due_p
-                        ids_cat = jnp.concatenate([
-                            jnp.where(keep_old, pend_id, -1),
-                            jnp.where(add, rbuf, -1),
-                        ])
-                        node_cat = jnp.concatenate(
-                            [pend_node, flat_cr]
-                        )
-                        relb_cat = jnp.concatenate(
-                            [pend_relb, relb_new]
-                        )
-                        op = jnp.argsort(ids_cat < 0, stable=True)[:RB]
-                        pend_id = jnp.where(
-                            ids_cat[op] >= 0, ids_cat[op], -1
-                        ).astype(jnp.int32)
-                        pend_node = node_cat[op].astype(jnp.int32)
-                        pend_relb = relb_cat[op].astype(jnp.int32)
-                        # 4. rbuf compaction: placed pods leave; the
-                        # rest keep FIFO order.
-                        keep_q = (rbuf >= 0) & (flat_cr < 0)
-                        oq = jnp.argsort(~keep_q, stable=True)
-                        rbuf = jnp.where(
-                            keep_q[oq], rbuf[oq], -1
-                        ).astype(jnp.int32)
-                        rcount = keep_q.sum().astype(jnp.int32)
-                        # 5. main chunk scan with failure appends.
+                            return st, n + due.sum(dtype=jnp.int32)
+
+                        with stage("ksim.release"):
+                            state, released = jax.lax.fori_loop(
+                                0, b, rel_row, (state, rq.released)
+                            )
+                        # 2. the retry pass: the NORMAL wave step over the
+                        # queue (empty slots are invalid no-ops), then the
+                        # record of its binds in row b: task, node, release
+                        # boundary (placed pods start NOW: f32 boundary
+                        # search, at least b+1) and the rows the release
+                        # rewinds.
+                        with stage("ksim.retry"):
+                            q = rq.ids
+                            rb_waves = q.reshape(RBW, wave_width)
+                            slots_r = T.gather_slots_device(src, rb_waves)
+                            extra_r = V3.gather_extra_device(xsrc, rb_waves)
+                            state, choices_r = jax.lax.scan(
+                                wave_step, state, (slots_r, extra_r)
+                            )
+                            flat_cr = choices_r.reshape(RB)
+                            placed_r = (flat_cr >= 0) & (q >= 0)
+                            retry_placed = placed_r.sum(dtype=jnp.int32)
+                            rbn = jnp.searchsorted(
+                                tbt, t_b + rq.dur, side="left"
+                            )
+                            relb = jnp.where(
+                                placed_r & (rbn < tbt.shape[0]),
+                                jnp.maximum(rbn, b + 1),
+                                BIG,
+                            ).astype(jnp.int32)
+                            safe = jnp.clip(q, 0)
+                            rq = rq._replace(
+                                t_id=put(rq.t_id, jnp.where(placed_r, q, -1)),
+                                t_node=put(
+                                    rq.t_node, jnp.where(placed_r, flat_cr, -1)
+                                ),
+                                t_relb=put(rq.t_relb, relb),
+                                t_req=put(
+                                    rq.t_req, slots_r.req.reshape(RB, -1).T
+                                ),
+                                t_mg=put(rq.t_mg, mgt[safe].T),
+                                owed=rq.owed
+                                + (relb < BIG).sum(dtype=jnp.int32),
+                                released=released,
+                                depth_max=jnp.maximum(rq.depth_max, rq.count),
+                            )
+                            if want_an:
+                                rq = rq._replace(
+                                    t_an=put(rq.t_an, antit[safe].T)
+                                )
+                            if want_pf:
+                                rq = rq._replace(
+                                    t_pf=put(rq.t_pf, preft[safe].T),
+                                    t_pw=put(rq.t_pw, prefwt[safe].T),
+                                )
+                            ids = jnp.where(placed_r, -1, q)
+                            count = rq.count - retry_placed
+                        # 3. the main chunk scan, as without a queue.
                         slots = T.gather_slots_device(src, idx)
                         extra = V3.gather_extra_device(xsrc, idx)
 
-                        def step(carry, xs):
-                            st, rbuf, rcount, rdrop = carry
-                            slots_w, extra_w, rows = xs
-                            st, choices = wave_step(
-                                st, (slots_w, extra_w)
-                            )
+                        def step(st, batch):
+                            st, choices = wave_step(st, batch)
                             placed_w = jnp.sum(
-                                (choices >= 0) & slots_w.valid
+                                (choices >= 0) & batch[0].valid
                             ).astype(jnp.int32)
-                            fail = (
-                                (choices < 0)
-                                & slots_w.valid
-                                & (slots_w.group < 0)
-                            )
-                            posk = (
-                                rcount
-                                + jnp.cumsum(fail.astype(jnp.int32))
-                                - 1
-                            )
-                            pos = jnp.where(
-                                fail & (posk < RB), posk, RB
-                            )
-                            rbuf = rbuf.at[pos].set(rows, mode="drop")
-                            nfail = fail.sum().astype(jnp.int32)
-                            # Overflow drops the newest — COUNTED,
-                            # like the host BoundaryOps analogue
-                            # (pend overflow is not: there the pod
-                            # keeps its resources, not dropped).
-                            rdrop = rdrop + jnp.maximum(
-                                rcount + nfail - RB, 0
-                            )
-                            rcount = jnp.minimum(
-                                rcount + nfail, RB
-                            ).astype(jnp.int32)
-                            return (st, rbuf, rcount, rdrop), (
-                                choices, placed_w
-                            )
+                            return st, (choices, placed_w)
 
-                        (state, rbuf, rcount, rdrop), (
-                            choices, counts
-                        ) = jax.lax.scan(
-                            step,
-                            (state, rbuf, rcount, rdrop),
-                            (slots, extra, idx),
+                        state, (choices, counts) = jax.lax.scan(
+                            step, state, (slots, extra)
                         )
-                        # 6. fold arrival-chunk placements at their
-                        # flat wave positions (retried placements do
-                        # NOT enter vassign: their releases ride pend
-                        # exclusively, and their arrival slot keeps
-                        # PAD so the static entry never fires).
-                        vassign = jax.lax.dynamic_update_slice(
-                            vassign,
-                            choices.reshape(-1),
-                            (b * idx.size,),
-                        )
-                        return (
-                            state, vassign, rbuf, rcount,
-                            pend_id, pend_node, pend_relb, rdrop,
-                            (counts, retry_placed),
-                        )
+                        # 4. the queue's upkeep: the chunk's failed
+                        # non-gang tasks join behind the tasks that stay,
+                        # in arrival order, as far as there is room
+                        # (overflow drops the newest, COUNTED); ONE stable
+                        # sort by priority then leaves the queue in kube's
+                        # QueueSort order (priority descending, then
+                        # arrival) with the holes of the placed at its end.
+                        with stage("ksim.retry"):
+                            rows = idx.reshape(-1)
+                            fail = (
+                                (choices < 0) & slots.valid & (slots.group < 0)
+                            ).reshape(-1)
+                            room = RB - count
+                            nfail = fail.sum(dtype=jnp.int32)
+                            take = fail & (
+                                jnp.cumsum(fail.astype(jnp.int32)) <= room
+                            )
+                            rsafe = jnp.clip(rows, 0)
+                            cat_ids = jnp.concatenate(
+                                [ids, jnp.where(take, rows, -1)]
+                            )
+                            cat_prio = jnp.concatenate([rq.prio, priot[rsafe]])
+                            cat_dur = jnp.concatenate([rq.dur, durt[rsafe]])
+                            key = jnp.where(
+                                cat_ids >= 0, -cat_prio, jnp.iinfo(jnp.int32).max
+                            )
+                            _, cat_ids, cat_prio, cat_dur = jax.lax.sort(
+                                (key, cat_ids, cat_prio, cat_dur),
+                                num_keys=1, is_stable=True,
+                            )
+                            rq = rq._replace(
+                                ids=cat_ids[:RB], prio=cat_prio[:RB],
+                                dur=cat_dur[:RB],
+                                count=count + jnp.minimum(nfail, room),
+                                dropped=rq.dropped
+                                + jnp.maximum(nfail - room, 0),
+                            )
+                        # 5. fold arrival-chunk placements at their flat
+                        # wave positions (re-tried placements stay in the
+                        # queue's record: their arrival slot keeps PAD so
+                        # the static release entry never fires).
+                        with stage("ksim.release"):
+                            vassign = jax.lax.dynamic_update_slice(
+                                vassign,
+                                choices.reshape(-1),
+                                (b * idx.size,),
+                            )
+                        return state, vassign, rq, (counts, retry_placed)
 
                     axes_retry = (
                         0, 0, None, None, None, None, None,
+                        None, None, None, None,
                         None, None, None,
-                        None, None, None,
-                        0, 0, 0, 0, 0, 0, 0,
+                        0, 0,
                     )
                     vmapped_retry = jax.vmap(
-                        per_scenario_retry, in_axes=axes_retry
+                        per_scenario_retry, in_axes=axes_retry,
+                        axis_name=_RETRY_VMAP,
                     )
-                    return finalize(
-                        vmapped_retry, axes_retry,
-                        (1, 13, 14, 15, 16, 17, 18, 19),
-                    )
+                    return finalize(vmapped_retry, axes_retry, (1, 14, 15))
 
                 # vmap matches in_axes against the args actually
                 # passed; with policies on, a literal None rides the
@@ -2029,6 +2088,8 @@ class WhatIfEngine:
             self._fork_waves_done, self._fork_choices = 0, None
             return self._run_jits["states"](self._state_one_mesh)
         self._load_fork_or_init()  # sets fork bookkeeping
+        if self._state_one is not None:
+            return self._run_jits["states"](self._state_one)
         if self.fork_checkpoint:
             ck = self._fork_ck
             host = init_state(self.ec, self.pods, apply_prebound=False)
@@ -2063,6 +2124,10 @@ class WhatIfEngine:
             ))
             if self.mesh is not None and not self.fork_checkpoint:
                 self._state_one_mesh = one
+            elif not self.fork_checkpoint:
+                # static per engine, as under a mesh: the host fold of the
+                # pre-bound pods and the upload run once
+                self._state_one = one
             return states_fn(one)
         G, D = host.match_count.shape[0], self.D
         # Domain dim may have grown (label perturbations) → pad.
@@ -2452,6 +2517,77 @@ class WhatIfEngine:
             counts = self._fetch(counts).astype(np.int32)
         return out, int(out.nbytes), counts
 
+    def _handback_retry(
+        self, span, vassign_d, rq: RetryQueue
+    ) -> Tuple[np.ndarray, np.ndarray, int]:
+        """(assignments [S, P], bind_boundary [S, P], bytes copied) of a
+        batch on the device retry path. One program puts the arrival binds
+        into task order (``vassign`` through the static ``pos`` map, the
+        residents in its tail) and writes beside each task what it reads
+        if nothing else is known of it: -1 where it has a node, -4 for an
+        unplaced gang member (never queued), -3 for any other (dropped at a
+        full buffer). Both arrays and the queue's record come to the host
+        once; there the re-tried binds (a few thousand a scenario, named
+        by task in ``t_id``) overwrite their tasks' entries with the node
+        and the row's boundary, and the tasks still queued read -2. The
+        record is sparse and addressed by task: on the device that is a
+        scatter a scenario, which serialises under ``vmap``."""
+        def build():
+            pos_d = jnp.asarray(self._dev_rel_stage["pos"])
+            gang_d = jnp.asarray(self.pods.group_id >= 0)
+
+            def whatif_handback_retry(buf):
+                node = jnp.take(buf, pos_d, axis=1).astype(jnp.int32)
+                code = jnp.where(
+                    node >= 0, -1, jnp.where(gang_d[None, :], -4, -3)
+                ).astype(jnp.int32)
+                return node, code
+
+            return jax.jit(whatif_handback_retry)
+
+        node_d, code_d = self._jit_once("handback_retry", build)(vassign_d)
+        # the fetched copies are read-only
+        assignments = np.array(self._fetch(node_d))
+        bind_boundary = np.array(self._fetch(code_d))
+        t_id, t_node, queued = (
+            self._fetch(a) for a in (rq.t_id, rq.t_node, rq.ids)
+        )
+        s, b, j = np.nonzero(t_id >= 0)
+        tasks = t_id[s, b, j]
+        assignments[s, tasks] = t_node[s, b, j]
+        bind_boundary[s, tasks] = b
+        s, j = np.nonzero(queued >= 0)
+        bind_boundary[s, queued[s, j]] = -2
+        copied = sum(
+            int(a.nbytes)
+            for a in (assignments, bind_boundary, t_id, t_node, queued)
+        )
+        return assignments, bind_boundary, copied
+
+    def _retry_summary(self, rq: RetryQueue, outs, dropped) -> dict:
+        """``summary()["retry"]`` of the batch that just ran: the buffer,
+        the passes made (one a boundary), and per scenario (mean and max
+        over the batch, scenario 0's own under ``scenario0``) the tasks the
+        passes bound, the tasks dropped at a full buffer, the queue's depth
+        at the boundaries (its largest, and what is still queued at the
+        end) and ``release_leaked``: re-tried binds with a release boundary
+        inside the trace that no boundary released (0 by construction: the
+        counter that says no release was lost)."""
+        keys = ("retry_placed", "depth_max", "depth_at_end", "release_leaked")
+        got = self._fetch(self._jit_once(
+            "retry_counts", lambda: jax.jit(lambda o, q: jnp.stack([
+                jnp.stack([r for _, r in o], axis=1).sum(axis=1, dtype=jnp.int32),
+                q.depth_max, q.count, q.owed - q.released,
+            ]))
+        )(outs, rq))  # [4, S] in one copy
+        per = dict(zip(keys, got), retry_dropped=dropped)
+        out: dict = {"buffer": int(self.retry_buffer), "passes": len(outs)}
+        for k, v in per.items():
+            v = np.asarray(v)
+            out[k] = {"mean": float(v.mean()), "max": int(v.max())}
+        out["scenario0"] = {k: int(np.asarray(v)[0]) for k, v in per.items()}
+        return out
+
     def _mesh_summary(self) -> dict:
         """``summary()["mesh"]`` of the batch that just ran: devices,
         scenarios a device, bytes put on the devices and fetched from them
@@ -2483,6 +2619,36 @@ class WhatIfEngine:
             "fetch_s": round(b["fetch_s"], 6),
             "collectives": dict(self._mesh_collectives),
         }
+
+    def _retry_queue(self, boundaries: int) -> RetryQueue:
+        """A batch's empty ``RetryQueue``, ``[S]`` in front of every leaf:
+        nothing queued, no bind recorded in any of the ``boundaries`` rows."""
+        S, RB = self.S, self.retry_buffer
+        core = self._release_core()
+        stg = self._dev_rel_stage
+        full = lambda shape, fill, dt: jnp.full((S,) + shape, fill, dt)
+        tab = lambda width, fill, dt: full((boundaries, width, RB), fill, dt)
+        return self._jit_once("retry_queue", lambda: jax.jit(lambda: RetryQueue(
+            ids=full((RB,), PAD, jnp.int32),
+            prio=full((RB,), 0, jnp.int32),
+            dur=full((RB,), 0.0, jnp.float32),
+            count=full((), 0, jnp.int32),
+            dropped=full((), 0, jnp.int32),
+            depth_max=full((), 0, jnp.int32),
+            owed=full((), 0, jnp.int32),
+            released=full((), 0, jnp.int32),
+            t_id=full((boundaries, RB), PAD, jnp.int32),
+            t_node=full((boundaries, RB), PAD, jnp.int32),
+            t_relb=full((boundaries, RB), 1 << 30, jnp.int32),
+            t_req=tab(self.ec.num_resources, 0.0, jnp.float32),
+            t_mg=tab(stg["mgt"].shape[1], PAD, jnp.int32),
+            t_an=tab(stg["antit"].shape[1], PAD, jnp.int32)
+            if core.want_an else None,
+            t_pf=tab(stg["preft"].shape[1], PAD, jnp.int32)
+            if core.want_pf else None,
+            t_pw=tab(stg["prefwt"].shape[1], 0.0, jnp.float32)
+            if core.want_pf else None,
+        )))()
 
     def _chunks_pos(self, idx: np.ndarray) -> np.ndarray:
         """[P] each task's place in the chunks' wave order; a task in no
@@ -2612,6 +2778,7 @@ class WhatIfEngine:
             stg["preft"] = jnp.asarray(pref_t)
             stg["prefwt"] = jnp.asarray(prefw_t)
             stg["durt"] = jnp.asarray(self.pods.duration.astype(np.float32))
+            stg["priot"] = jnp.asarray(self.pods.priority.astype(np.int32))
             stg["tbt"] = jnp.asarray(tb_all[:nfin].astype(np.float32))
             stg["tb_c"] = [
                 jnp.asarray(np.float32(tb_all[b])) for b in range(nchunks)
@@ -3053,15 +3220,10 @@ class WhatIfEngine:
                             if self.mesh is not None
                             else (lambda a: a)
                         )
-                        zs = lambda fill, dt: sh_s(jnp.full(
-                            (self.S, RB), fill, dtype=dt
-                        ))
-                        rbuf_d = zs(PAD, jnp.int32)
-                        rcount_d = sh_s(jnp.zeros(self.S, jnp.int32))
-                        pend_id_d = zs(PAD, jnp.int32)
-                        pend_node_d = zs(PAD, jnp.int32)
-                        pend_relb_d = zs(0, jnp.int32)
-                        rdrop_d = sh_s(jnp.zeros(self.S, jnp.int32))
+                        priot_d = stg["priot"]
+                        rq_d = jax.tree.map(
+                            sh_s, self._retry_queue(len(stg["b_c"]))
+                        )
                 pending_fold = None  # (rows, choices) of the not-yet-folded chunk
                 if comp_on:
                     from .jax_runtime import wave_start_times
@@ -3452,10 +3614,7 @@ class WhatIfEngine:
                     if dev_rel:
                         c["vassign"] = vassign_d
                         if self.retry_buffer:
-                            c["retry"] = (
-                                rbuf_d, rcount_d, pend_id_d, pend_node_d,
-                                pend_relb_d, rdrop_d,
-                            )
+                            c["retry"] = rq_d
                     return c
 
                 _ck_sig = [
@@ -3541,10 +3700,7 @@ class WhatIfEngine:
                         if dev_rel:
                             vassign_d = carr["vassign"]
                             if self.retry_buffer:
-                                (
-                                    rbuf_d, rcount_d, pend_id_d, pend_node_d,
-                                    pend_relb_d, rdrop_d,
-                                ) = carr["retry"]
+                                rq_d = carr["retry"]
                         outs = list(pay["outs"])
                         start_ci = int(pay["cursor"])
                         _log.warning(
@@ -3740,16 +3896,12 @@ class WhatIfEngine:
                     if dev_rel and self.retry_buffer:
                         args = (
                             dc, states, srcs[0], srcs[1], mgt_d, antit_d,
-                            preft_d, prefwt_d, durt_d, tbt_d,
+                            preft_d, prefwt_d, durt_d, priot_d, tbt_d,
                             idx_chunks[ci], tb_c[ci], b_c[ci],
-                            vassign_d, rbuf_d, rcount_d,
-                            pend_id_d, pend_node_d, pend_relb_d, rdrop_d,
+                            vassign_d, rq_d,
                         )
                         _reg(self._chunk_fn, args)
-                        (
-                            states, vassign_d, rbuf_d, rcount_d,
-                            pend_id_d, pend_node_d, pend_relb_d, rdrop_d, out,
-                        ) = self._chunk_fn(*args)
+                        states, vassign_d, rq_d, out = self._chunk_fn(*args)
                     elif dev_rel:
                         args = (
                             dc, states, srcs[0], srcs[1], idx_chunks[ci],
@@ -4028,12 +4180,23 @@ class WhatIfEngine:
                 if dropped is None and dev_rel and self.retry_buffer:
                     # The device retry path counts overflow drops in-scan now
                     # (round 6): every drop-capable engine reports them.
-                    dropped = np.asarray(self._fetch(rdrop_d)).astype(np.int32)
+                    dropped = np.asarray(self._fetch(rq_d.dropped)).astype(np.int32)
                 release_rounds = (
                     int(np.max(self._fetch(rounds_d))) if dev_rel else None
                 )
+                retry_block = None
+                if dev_rel and self.retry_buffer and not self.kube:
+                    retry_block = self._retry_summary(rq_d, outs, dropped)
             handback_bytes = 0
-            if self.collect_assignments and dev_rel:
+            bind_boundary = None
+            if self.collect_assignments and dev_rel and self.retry_buffer:
+                # Two arrays: the arrival binds from the wave-order buffer,
+                # the re-tried ones from the queue's record.
+                with span("handback"):
+                    assignments, bind_boundary, handback_bytes = (
+                        self._handback_retry(span, vassign_d, rq_d)
+                    )
+            elif self.collect_assignments and dev_rel:
                 # The device-release path's placements: the wave-order buffer
                 # comes to the host once, after the last chunk.
                 with span("handback"):
@@ -4091,6 +4254,8 @@ class WhatIfEngine:
                     fleet_local.release_rounds = release_rounds
                 if dev_rel or self.collect_assignments:
                     fleet_local.handback_bytes = handback_bytes
+                if dev_rel and self.retry_buffer and not self.kube:
+                    fleet_local.retry = retry_block
                 if self._wide_gangs:
                     fleet_local.gangs = self._gangs_summary(states.txn)
                 if self.mesh is not None:
@@ -4211,6 +4376,7 @@ class WhatIfEngine:
                     dict(
                         placed=placed,
                         assignments=assignments,
+                        bind_boundary=bind_boundary,
                         util=util,
                         preemptions=kube_preempt,
                         dropped=dropped,
@@ -4252,6 +4418,7 @@ class WhatIfEngine:
 
                 placed = _cat("placed")
                 assignments = _cat("assignments")
+                bind_boundary = _cat("bind_boundary")
                 util = _cat("util")
                 kube_preempt = _cat("preemptions")
                 dropped = _cat("dropped")
@@ -4311,6 +4478,7 @@ class WhatIfEngine:
                 wall_clock_s=wall,
                 placements_per_sec=total / wall if wall > 0 else 0.0,
                 assignments=assignments,
+                bind_boundary=bind_boundary,
                 utilization_cpu=util,
                 completions_on=self.completions_on,
                 engine=self.engine,

@@ -89,6 +89,13 @@ class BorgWorkloadSpec:
     collection_events: Optional[str] = None
     cpu_scale: float = 8.0
     mem_scale: float = 16.0 * 2**30
+    # A cell that is full (sim.borg.BorgSpec): ``tasksPerDay`` cuts the tasks
+    # as a window out of a day of that many (0: the day thinned to ``tasks``);
+    # ``residentFill`` binds a resident set before the window starts, up to
+    # that share (+- ``residentBand``) of each node's cpu (0: none).
+    tasks_per_day: float = 0.0
+    resident_fill: float = 0.0
+    resident_band: float = 0.05
 
 
 @dataclass
@@ -104,6 +111,11 @@ class WhatIfSpec:
     completions: object = None
     # Device-path unschedulable retry buffer width (0 = off).
     retry_buffer: int = 0
+    # Hand every task's node back (WhatIfEngine collect_assignments); with
+    # ``retryBuffer`` on the device path also the boundary that bound it.
+    # The scenario rows then count the re-tried binds and the tasks still
+    # queued or dropped.
+    placements: bool = False
 
 
 @dataclass
@@ -407,6 +419,9 @@ class SimConfig:
                 collection_events=b.get("collectionEvents"),
                 cpu_scale=float(b.get("cpuScale", 8.0)),
                 mem_scale=float(b.get("memScale", 16.0 * 2**30)),
+                tasks_per_day=float(b.get("tasksPerDay", 0.0)),
+                resident_fill=float(b.get("residentFill", 0.0)),
+                resident_band=float(b.get("residentBand", 0.05)),
             )
         else:
             syn = wl.get("synthetic", wl) or {}
@@ -448,6 +463,7 @@ class SimConfig:
             # validate_config.
             completions=_coerce_completions(wi.get("completions")),
             retry_buffer=int(wi.get("retryBuffer", 0)),
+            placements=bool(wi.get("placements", False)),
         )
         tu = d.get("tune")
         if tu is not None:

@@ -343,6 +343,7 @@ def whatif_rows(res, extra: Optional[dict] = None) -> Iterable[dict]:
     evi = getattr(res, "evictions", None)
     lat50 = getattr(res, "latency_p50", None)
     str_cpu = getattr(res, "stranded_cpu", None)
+    bound_at = getattr(res, "bind_boundary", None)
     for s in range(res.placed.shape[0]):
         row = {
             "kind": "whatif-scenario",
@@ -354,6 +355,11 @@ def whatif_rows(res, extra: Optional[dict] = None) -> Iterable[dict]:
             ),
             **base,
         }
+        if bound_at is not None:
+            # device retry path with placements: the queue's outcome
+            row["retry_placed"] = int((bound_at[s] >= 0).sum())
+            row["queued_at_end"] = int((bound_at[s] == -2).sum())
+            row["retry_dropped"] = int((bound_at[s] == -3).sum())
         if pre is not None:
             # kube batches: drops mean placements lost to buffer
             # capacity, not infeasibility.

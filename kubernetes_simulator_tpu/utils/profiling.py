@@ -44,7 +44,13 @@ STAGES = (
     "ksim.gang_txn",      # a wide pod group's carried transaction: upkeep, verdict
     "ksim.gang_rollback", # its binds given back where it closes
     "ksim.release",       # boundary release programs
+    "ksim.retry",         # a boundary's retry pass: its scan and the queue's upkeep
 )
+
+#: Scopes that wrap whole wave steps, not a stage of one: an instruction
+#: inside one is filed under ``<pass>/<its stage path>`` (``ksim.retry/
+#: ksim.select``), so the pass's time can be told from the arrival waves'.
+PASSES = ("ksim.retry",)
 
 # A stage path inside an HLO ``op_name``: the ``ksim.`` component and the
 # CamelCase components after it (plugin names). JAX's own components
@@ -227,8 +233,9 @@ def register_call(fn, args) -> None:
 def parse_stage_table(hlo_text: str) -> Dict[str, str]:
     """{instruction name: stage path} of one executable's HLO text: the
     innermost ``ksim.`` scope of its ``op_name`` (a scan issued under
-    ``ksim.gather`` keeps that stage for its own slicing only); an
-    instruction under no ``ksim.`` scope maps to ``""``."""
+    ``ksim.gather`` keeps that stage for its own slicing only), behind the
+    pass that wraps it where one does (:data:`PASSES`); an instruction
+    under no ``ksim.`` scope maps to ``""``."""
     table: Dict[str, str] = {}
     for line in hlo_text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
@@ -236,7 +243,9 @@ def parse_stage_table(hlo_text: str) -> Dict[str, str]:
             continue
         op = _HLO_OP_NAME.search(line)
         paths = _STAGE_PATH.findall(op.group(1)) if op else ()
-        table[m.group(1)] = paths[-1] if paths else ""
+        path = paths[-1] if paths else ""
+        outer = next((p for p in paths[:-1] if p in PASSES), None)
+        table[m.group(1)] = f"{outer}/{path}" if outer else path
     return table
 
 

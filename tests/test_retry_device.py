@@ -347,3 +347,159 @@ def test_host_and_device_retry_paths_agree():
         retry_buffer=8,
     ).run()
     assert int(dev.placed[0]) == host.placed
+
+
+# -- PR 41: the queue's order, the per-task hand-back, no leaked release -----
+
+
+def _contended(pods_n=140, nodes=3, seed=11, priorities=(0,), resident=0):
+    """A contended trace (tight capacity, short durations) with the given
+    priority tiers dealt over its pods and ``resident`` pods bound before
+    t = 0, encoded; requests are dyadic, so every sum is exact."""
+    cluster = make_cluster(nodes, seed=seed)
+    pods, _ = make_workload(
+        pods_n, seed=seed, arrival_rate=60.0, duration_mean=1.5,
+        with_spread=True, with_tolerations=True,
+    )
+    rng = np.random.default_rng(seed)
+    for p in pods:
+        p.priority = int(rng.choice(priorities))
+    ec, ep = encode(cluster, pods)
+    if resident:
+        ep.bound_node[:resident] = np.arange(resident) % nodes
+        ep.arrival[:resident] = 0.0
+        ep.duration[:resident] = rng.exponential(3.0, size=resident)
+    return ec, ep
+
+
+def _device_and_anchor(ec, ep, W=4, C=4, RB=8, scenarios=None):
+    cfg = FrameworkConfig()
+    anchor = greedy_replay(
+        ec, ep, cfg, wave_width=W, completions_chunk_waves=C, retry_buffer=RB,
+    )
+    eng = WhatIfEngine(
+        ec, ep, scenarios or [Scenario()], cfg, wave_width=W, chunk_waves=C,
+        retry_buffer=RB, collect_assignments=True,
+    )
+    assert eng.release_path == "device"
+    return eng, eng.run(), anchor
+
+
+RETRY_CASES = {
+    "one_priority": dict(),
+    "mixed_priorities": dict(priorities=(0, 100, 200, 360, 450)),
+    "resident_set": dict(priorities=(0, 200), resident=24),
+    "small_buffer_overflows": dict(priorities=(0, 100, 200)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RETRY_CASES))
+def test_retry_handback_equals_the_anchor_task_for_task(case):
+    """``assignments`` and ``bind_boundary`` of the device retry path are
+    ``greedy_replay``'s, task for task: the queue in priority-then-arrival
+    order, residents held from t = 0, drops at a full buffer, and every
+    code of a task with no node."""
+    ec, ep = _contended(**RETRY_CASES[case])
+    RB = 4 if case == "small_buffer_overflows" else 16
+    eng, res, anchor = _device_and_anchor(ec, ep, RB=RB)
+    np.testing.assert_array_equal(res.assignments[0], anchor.assignments)
+    np.testing.assert_array_equal(res.bind_boundary[0], anchor.bind_boundary)
+    assert int(res.placed[0]) == anchor.placed
+    assert int(res.retry_dropped[0]) == anchor.retry_dropped
+    bb = res.bind_boundary[0]
+    assert (bb >= 0).any()  # the retry pass bound something
+    assert ((bb >= -1) == (res.assignments[0] >= 0)).all()
+    scheduled = ep.bound_node < 0
+    assert int(res.placed[0]) == int((res.assignments[0][scheduled] >= 0).sum())
+    assert int(res.placed[0] + res.unschedulable[0]) == int(scheduled.sum())
+    if case == "small_buffer_overflows":
+        assert anchor.retry_dropped > 0 and (bb == -3).any()
+    if case == "resident_set":
+        assert (bb[: RETRY_CASES[case]["resident"]] == -1).all()
+    retry = res.fleet_telemetry.summary()["retry"]
+    assert retry["buffer"] == RB and retry["release_leaked"]["max"] == 0
+    assert retry["scenario0"]["retry_placed"] == int((bb >= 0).sum())
+    assert retry["scenario0"]["retry_dropped"] == anchor.retry_dropped
+    # a second batch on the same engine answers the same
+    again = eng.run()
+    np.testing.assert_array_equal(again.assignments, res.assignments)
+    np.testing.assert_array_equal(again.bind_boundary, res.bind_boundary)
+
+
+def test_retry_queue_order_is_priority_then_arrival():
+    """Two pods wait for the one cpu; the later one has the higher
+    priority and gets it (kube's QueueSort), the earlier one the next."""
+    cluster = Cluster(nodes=[Node("n0", {"cpu": 1})])
+    pods = [
+        Pod("a", requests={"cpu": 1}, arrival_time=0.0, duration=2.0),
+        Pod("lo", requests={"cpu": 1}, arrival_time=0.5, duration=2.0,
+            priority=0),
+        Pod("hi", requests={"cpu": 1}, arrival_time=0.6, duration=2.0,
+            priority=100),
+    ] + [Pod(f"f{i}", requests={}, arrival_time=3.0 + 2.5 * i) for i in range(4)]
+    ec, ep = encode(cluster, pods)
+    cfg = FrameworkConfig(plugins=[{"name": "NodeResourcesFit"}])
+    anchor = greedy_replay(
+        ec, ep, cfg, wave_width=1, completions_chunk_waves=1, retry_buffer=2
+    )
+    assert 0 <= anchor.bind_boundary[2] < anchor.bind_boundary[1]
+    res = WhatIfEngine(
+        ec, ep, [Scenario()], cfg, wave_width=1, chunk_waves=1,
+        retry_buffer=2, collect_assignments=True,
+    ).run()
+    np.testing.assert_array_equal(res.bind_boundary[0], anchor.bind_boundary)
+
+
+def test_retry_more_outstanding_binds_than_the_buffer_release_all():
+    """More re-tried binds outstanding than ``retry_buffer`` holds: every
+    one is released when it is due (until PR 41 the pending list was capped
+    at the buffer and the rest held their nodes to the end). The anchor's
+    final usage is the device's, and ``release_leaked`` reads 0."""
+    cluster = Cluster(nodes=[Node("n0", {"cpu": 8})])
+    # Eight residents hold the 8 cpus and leave in pairs at t = 2, 4, 6, 8.
+    # Pairs of 1-cpu pods arrive just before each pair leaves, wait in the
+    # buffer of 2 and bind at the next boundary for 20 s each: from t = 9
+    # all eight are outstanding, 4x the buffer. Tickers of no request make
+    # a boundary a second; by t = 30 every bind is due and the node empty.
+    pods = [Pod(f"r{i}", requests={"cpu": 1}, arrival_time=0.0,
+                duration=2.0 + 2.0 * (i // 2)) for i in range(8)]
+    pods += [Pod(f"w{i}", requests={"cpu": 1},
+                 arrival_time=1.0 + 2.0 * (i // 2) + 0.1 * (i % 2),
+                 duration=20.0) for i in range(8)]
+    pods += [Pod(f"t{i}", requests={}, arrival_time=0.5 + i, duration=0.25)
+             for i in range(45)]
+    ec, ep = encode(cluster, pods)
+    cfg = FrameworkConfig(plugins=[{"name": "NodeResourcesFit"}])
+    kw = dict(wave_width=1, retry_buffer=2)
+    anchor = greedy_replay(ec, ep, cfg, completions_chunk_waves=1, **kw)
+    res = WhatIfEngine(
+        ec, ep, [Scenario()], cfg, chunk_waves=1, collect_assignments=True,
+        **kw,
+    ).run()
+    np.testing.assert_array_equal(res.assignments[0], anchor.assignments)
+    np.testing.assert_array_equal(res.bind_boundary[0], anchor.bind_boundary)
+    retry = res.fleet_telemetry.summary()["retry"]
+    assert retry["release_leaked"] == {"mean": 0.0, "max": 0}
+    bound_late = anchor.bind_boundary[8:16]
+    assert (bound_late >= 0).all() and retry["scenario0"]["retry_placed"] == 8
+    assert res.utilization_cpu[0] == anchor.utilization["cpu"] == 0.0
+
+
+def test_retry_perturbed_scenarios_hand_back_their_own_queues():
+    """Scenario 0 is the anchor; a scenario with half its cpu queues more
+    and, with a small buffer, drops."""
+    from kubernetes_simulator_tpu.sim.whatif import Perturbation
+
+    ec, ep = _contended(priorities=(0, 100, 200))
+    scen = [Scenario(), Scenario([Perturbation(
+        "scale_capacity", nodes=np.arange(3), resource="cpu", factor=0.5)])]
+    _, res, anchor = _device_and_anchor(ec, ep, RB=8, scenarios=scen)
+    np.testing.assert_array_equal(res.assignments[0], anchor.assignments)
+    np.testing.assert_array_equal(res.bind_boundary[0], anchor.bind_boundary)
+    assert (res.bind_boundary[1] != res.bind_boundary[0]).any()
+    retry = res.fleet_telemetry.summary()["retry"]
+    assert retry["depth_max"]["max"] >= retry["scenario0"]["depth_max"]
+    for s in range(2):
+        none = res.assignments[s] < 0
+        assert (res.bind_boundary[s][none] <= -2).all()
+        assert int((res.bind_boundary[s] == -3).sum()) == int(res.retry_dropped[s])

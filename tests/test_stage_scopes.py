@@ -54,8 +54,10 @@ def test_stage_tables_after_an_armed_replay(borg, tmp_path, monkeypatch):
     ran = {path.split("/")[0] for path in chunk.values()} - {""}
     # every stage this configuration runs: no preemption, releases apart
     # (nor a pod group wider than the wave: tests/test_wide_gangs.py)
+    # nor a retry pass: tests/test_retry_device.py
     assert ran == set(profiling.STAGES) - {
-        "ksim.preempt", "ksim.release", "ksim.gang_txn", "ksim.gang_rollback"}
+        "ksim.preempt", "ksim.release", "ksim.gang_txn", "ksim.gang_rollback",
+        "ksim.retry"}
     assert {"ksim.filter_score/NodeResourcesFit",
             "ksim.filter_score/TaintToleration",
             "ksim.filter_score/PodTopologySpread"} <= set(chunk.values())
@@ -82,6 +84,13 @@ def test_parse_stage_table_takes_the_innermost_scope():
         "p.1": "", "fusion.3": "ksim.filter_score/NodeResourcesFit",
         "dynamic-slice.7": "ksim.gather", "copy.9": "ksim.select",
     }
+    # a pass that wraps whole wave steps stays in front of their stages
+    retry = """
+  %fusion.5 = s32[]{:T(128)} fusion(%a), kind=kLoop, metadata={op_name="jit(f)/vmap()/ksim.retry/while/body/closed_call/ksim.select/reduce"}
+  %sort.2 = s32[8]{0} sort(%k), metadata={op_name="jit(f)/vmap()/ksim.retry/sort"}
+"""
+    assert profiling.parse_stage_table(retry) == {
+        "fusion.5": "ksim.retry/ksim.select", "sort.2": "ksim.retry"}
     with pytest.raises(ValueError, match="unknown stage"):
         profiling.stage("ksim.pick")
 

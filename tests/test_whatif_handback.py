@@ -190,12 +190,26 @@ def test_later_runs_compile_nothing_under_a_mesh(case):
     np.testing.assert_array_equal(first.assignments, plain.assignments)
 
 
-def test_device_retry_buffer_refuses_placements_loudly(case):
+@pytest.mark.parametrize("s", [0, 2, 4])
+def test_device_retry_buffer_hands_back_placements_and_boundaries(case, s):
+    """Until PR 41 the device ``retry_buffer`` refused ``collect_assignments``
+    (re-tried placements were not kept per task). Now both arrays come
+    back, and each scenario's are the CPU replay's of its own cluster:
+    gangs (never queued), a tolerated taint, the perturbations."""
     ec, ep, cfg, scen = case
-    with pytest.raises(ValueError, match="retried placements"):
-        WhatIfEngine(ec, ep, scen, cfg, wave_width=W, chunk_waves=C,
-                     completions=True, collect_assignments=True,
-                     retry_buffer=16)
+    eng = device_engine(ec, ep, cfg, scen, retry_buffer=16)
+    res = eng.run()
+    own = ScenarioSet(ec, scen, keep_host_stacks=True).host_clusters(ec)[s]
+    ref = greedy_replay(own, ep, cfg, wave_width=W,
+                        completions_chunk_waves=eng.chunk_waves,
+                        retry_buffer=16)
+    np.testing.assert_array_equal(res.assignments[s], ref.assignments)
+    np.testing.assert_array_equal(res.bind_boundary[s], ref.bind_boundary)
+    assert int(res.placed[s]) == ref.placed
+    assert int(res.retry_dropped[s]) == ref.retry_dropped
+    assert (res.bind_boundary[s] >= 0).any()
+    gang = ep.group_id >= 0
+    assert set(np.unique(res.bind_boundary[s][gang])) <= {-1, -4}
 
 
 def test_phases_cover_a_whatif_run(answered):
