@@ -28,7 +28,11 @@ no result line (nothing here catches a phase's exception):
                   against what-if scenario 0; each case prints its
                   ``select_form``, ``zone_packed`` required of the zoned one,
                   and every what-if batch ``inwave_corrections``
-                  ``resolved_terms``;
+                  ``resolved_terms``; the Borg case again with a pending
+                  queue re-tried on the device (``retry_buffer``), nodes and
+                  bind boundaries against the host reference, the pass
+                  required to read its class rows as a select
+                  (``class_row_reads``) and the arrival waves as a slice;
                   and the default plugin set on 160 nodes as an
                   arrivals-only what-if batch (``two_pass``, host-scale
                   count rows), scenario 0 against the single replay; and
@@ -80,6 +84,8 @@ PARITY_HEAD_PODS = 2000
 # Borg case: few nodes and long tasks, so the cluster runs full (some tasks
 # stay unschedulable) and fit decisions sit on what the releases freed.
 BORG_NODES, BORG_TASKS, BORG_MEAN_DURATION, BORG_CHUNK_WAVES = 12, 2048, 15000.0, 16
+# The same case with a pending queue of this many tasks (a multiple of the wave).
+RETRY_BUFFER = 64
 # Release-heavy what-if case: short tasks and wide chunks on the same 12 nodes,
 # so a boundary releases hundreds of tasks and every 128-row block of the
 # release program adds many releases to one node, in rank order.
@@ -242,6 +248,7 @@ def phase_parity() -> dict:
     from kubernetes_simulator_tpu.sim.whatif import (
         Perturbation,
         Scenario,
+        ScenarioSet,
         WhatIfEngine,
     )
     from kubernetes_simulator_tpu.utils.config import SimConfig, build_case
@@ -357,6 +364,53 @@ def phase_parity() -> dict:
         "placed": [int(x) for x in r_dev.placed],
         "select_form": select_form(r_dev, "borg what-if"),
         "inwave_corrections": resolved_terms(r_dev, "borg what-if"),
+    }
+
+    # (c2) the same case with a pending queue: ``retry_buffer`` re-tries the
+    # tasks that failed at every chunk boundary, in priority order, on the
+    # device (``jit_per_scenario_retry``). Every scenario's nodes AND bind
+    # boundaries against the host reference with the same buffer on the
+    # equally perturbed cluster, one scenario with a taint that nobody
+    # tolerates. The pass walks each scenario's own queue, so it has to
+    # read a slot's toleration class row as a select among the plane's rows
+    # (ops.tpu3.class_row_reads "select"); the arrival waves, whose slots
+    # every scenario shares, by the dynamic index ("slice").
+    q_scen = scen + [Scenario([Perturbation(
+        "add_taint", nodes=np.array([1, 6]), key="whatif", value="cordon")])]
+    q_refs = [
+        greedy_replay(e, ep, fw, wave_width=8, retry_buffer=RETRY_BUFFER,
+                      completions_chunk_waves=BORG_CHUNK_WAVES)
+        for e in ScenarioSet(ec, q_scen, keep_host_stacks=True).host_clusters(ec)
+    ]
+    require(all((r.bind_boundary >= 0).any() for r in q_refs),
+            "retry what-if: some scenario's passes bind nothing")
+    queued = WhatIfEngine(ec, ep, q_scen, fw, completions=True,
+                          retry_buffer=RETRY_BUFFER,
+                          collect_assignments=True, **kw)
+    require(queued.release_path == "device",
+            "retry what-if did not take the device release path")
+    r_q = queued.run()
+    for s, ref in enumerate(q_refs):
+        same(r_q.assignments[s], ref.assignments,
+             f"retry what-if scenario {s}")
+        same(r_q.bind_boundary[s], ref.bind_boundary,
+             f"retry what-if scenario {s}: bind boundaries")
+    reads = r_q.fleet_telemetry.summary()["class_row_reads"]
+    say(f"retry what-if: class_row_reads {reads}")
+    require(reads["retry"] == "select" and reads["arrival"] == "slice"
+            and reads["tol_classes"] == 2,
+            "retry what-if: the pass does not select its class rows, or the "
+            f"arrival waves do not slice theirs ({reads})")
+    no_queue = r_dev.fleet_telemetry.summary()["class_row_reads"]
+    require(no_queue["arrival"] == "slice" and "retry" not in no_queue,
+            f"borg what-if: class_row_reads {no_queue} in a batch with no queue")
+    out["retry_whatif"] = {
+        "scenarios": len(q_scen), "buffer": RETRY_BUFFER,
+        "placed": [int(x) for x in r_q.placed],
+        "retry_placed": [int((r_q.bind_boundary[s] >= 0).sum())
+                         for s in range(len(q_scen))],
+        "class_row_reads": reads,
+        "inwave_corrections": resolved_terms(r_q, "retry what-if"),
     }
 
     # (d) the release-heavy case. The CPU backend's dot is exact and

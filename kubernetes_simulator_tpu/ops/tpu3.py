@@ -1100,8 +1100,10 @@ def class_masks(dc: DevCluster, d: Derived, st: V3Static, spec, rep_slots):
     gathered host-side at engine build). Computed ONCE per chunk."""
     tol_reps, na_reps = rep_slots
     out = {}
-    # 0/1 masks are bf16-exact; the per-pod row reads (dynamic_index in the
-    # wave step) then cost half the bytes. Raw score planes stay f32.
+    # 0/1 masks are bf16-exact; the per-pod row reads in the wave step
+    # (class_row: a dynamic index by the slot's class id, or, where the
+    # slots differ by scenario, a select among the few rows: class_row_reads)
+    # then cost half the bytes. Raw score planes stay f32.
     if spec.taints and st.use_tol_classes:
         out["tol_ok"] = jax.vmap(lambda s: T2.taint_mask(dc, s))(tol_reps).astype(
             jnp.bfloat16
@@ -1119,6 +1121,7 @@ def make_wave_step3(
     dc: DevCluster, d: Derived, sh: Shared3, st: V3Static,
     wave_width: int, spec, cmasks=None, dyn: Optional[DynTables] = None,
     dyn_flip: bool = True, wvec=None, scenario_axis: bool = False,
+    slots_by_scenario: bool = False,
 ):
     """Scan body over (PodSlot, SlotExtra) wave batches. Bit-identical to
     the v2 step; see module docstring for the traffic model. ``cmasks``:
@@ -1132,6 +1135,11 @@ def make_wave_step3(
     (``vmap``) — a static fact of how the program is built, which picks the
     form of the in-wave usage corrections (:func:`inwave_corrections`) and
     of the host-scale count row reads (:func:`host_row_reads`).
+    ``slots_by_scenario``: under that axis the step's slots are mapped too
+    (the what-if retry pass, whose queue is the scenario's own), so a
+    slot's class id is a scalar of the scenario and not of the batch, also
+    static, which picks the form of a slot's toleration and node-affinity
+    class row read (:func:`class_row_reads`).
 
     Where a slot reads its host rows one by one (``host_row_reads`` "rows":
     the step under a scenario axis) its node-space count values are built
@@ -1183,6 +1191,7 @@ def make_wave_step3(
     corr_plane = corr_form == "plane"
     corr_resolved = corr_form == "resolved_terms"
     host_rows_read = host_row_reads(scenario_axis) == "rows"
+    row_form = class_row_reads(st, scenario_axis, slots_by_scenario)
     # A slot's count values: per-position rows where it reads its host rows
     # one by one (the step mapped over a scenario axis); one [KT, N] array
     # over every position where they come from the wave-start contraction.
@@ -1454,13 +1463,11 @@ def make_wave_step3(
                             # north-star profile.) Values identical: one-hot × f32
                             # picked the same row exactly.
                             tok_k = (
-                                jax.lax.dynamic_index_in_dim(
-                                    cmasks["tol_ok"], sx.tol_class[k], 0, keepdims=False
-                                )
+                                class_row(cmasks["tol_ok"], sx.tol_class[k], row_form)
                                 > 0.5
                             )
-                            traw_k = jax.lax.dynamic_index_in_dim(
-                                cmasks["tol_raw"], sx.tol_class[k], 0, keepdims=False
+                            traw_k = class_row(
+                                cmasks["tol_raw"], sx.tol_class[k], row_form
                             )
                         else:
                             tok_k, traw_k = pre.taint_ok[k], pre.taint_raw[k]
@@ -1469,13 +1476,11 @@ def make_wave_step3(
                     with jax.named_scope("NodeAffinity"):
                         if st.use_na_classes:
                             naok_k = (
-                                jax.lax.dynamic_index_in_dim(
-                                    cmasks["na_ok"], sx.na_class[k], 0, keepdims=False
-                                )
+                                class_row(cmasks["na_ok"], sx.na_class[k], row_form)
                                 > 0.5
                             )
-                            naraw_k = jax.lax.dynamic_index_in_dim(
-                                cmasks["na_raw"], sx.na_class[k], 0, keepdims=False
+                            naraw_k = class_row(
+                                cmasks["na_raw"], sx.na_class[k], row_form
                             )
                         else:
                             naok_k, naraw_k = pre.na_ok[k], pre.na_raw[k]
@@ -2400,6 +2405,78 @@ def host_row_reads(scenario_axis: bool = False) -> str:
     5,000 nodes: 1.1272 s a replay against 1.2239; read once at wave start
     for all slots, 11%; same PR)."""
     return "rows" if scenario_axis else "contraction"
+
+
+# Most class rows a plane may have for a slot to pick its row by a chain of
+# selects (class_row_reads "select"): a row more is a compare and a select
+# more a node in the slot's reduce, and another [N] row read.
+CLASS_SELECT_MAX = 4
+
+
+def class_row_reads(
+    st: V3Static, scenario_axis: bool = False, slots_by_scenario: bool = False
+) -> str:
+    """How a slot of the step reads the row of its toleration / node-affinity
+    class from the per-chunk class planes (:func:`class_masks`: ``[C, N]`` a
+    scenario) — static per compiled program and, like
+    :func:`inwave_corrections` and :func:`host_row_reads`, a fact of how the
+    program is built, never a switch; a what-if batch reports it as
+    ``summary()["class_row_reads"]``. Both forms pick a row and sum nothing:
+    the same values to the bit (:func:`class_row`).
+
+    ``"slice"``: ``dynamic_index_in_dim(plane, c)``. Every step whose slots
+    are scenario-shared, and the single replay: the class id is one scalar
+    for the whole batch, so the read is a true dynamic slice of the planes
+    and fuses into the slot's node-wide reduce (0.001 ms a wave in
+    ``borg10k-backlog128``'s arrival waves).
+
+    ``"select"``: the row built elementwise from the ``C`` static rows and
+    ``c == i``. The step whose slots differ by scenario
+    (``slots_by_scenario``: the what-if retry pass walks each scenario's own
+    queue): there the class id is batched, ``vmap`` turns the dynamic index
+    into a gather with a batch dimension, one a slot a plane, materialised
+    outside the reduce (0.154 ms a pass wave for the two rows of that cell,
+    PERF.md §5, PR 41), where a compare and a select fuse as the slice does.
+    Only while every class plane has at most ``CLASS_SELECT_MAX`` rows (read
+    off ``st.tol_rep`` / ``st.na_rep``): a longer chain of selects reads
+    every row a node, and the gather stays."""
+    if not (scenario_axis and slots_by_scenario):
+        return "slice"
+    rows = max(
+        len(st.tol_rep) if st.use_tol_classes else 0,
+        len(st.na_rep) if st.use_na_classes else 0,
+    )
+    return "select" if rows <= CLASS_SELECT_MAX else "slice"
+
+
+def class_row(plane: jax.Array, c: jax.Array, form: str) -> jax.Array:
+    """Row ``c`` of the ``[C, N]`` class plane in the form
+    :func:`class_row_reads` names; ``c`` an int32 scalar in ``[0, C)``, as
+    every slot's class id is (an empty slot carries task 0's:
+    :func:`gather_extra_device` clips the id it gathers by)."""
+    if form == "slice":
+        return jax.lax.dynamic_index_in_dim(plane, c, 0, keepdims=False)
+    # static slices (``plane[i]`` would lower to a dynamic one by a constant)
+    rows = [
+        jax.lax.index_in_dim(plane, i, 0, keepdims=False)
+        for i in range(plane.shape[0])
+    ]
+    row = rows[0]
+    for i, row_i in enumerate(rows[1:], 1):
+        row = jnp.where(c == i, row_i, row)
+    return row
+
+
+def class_planes(st: V3Static, spec) -> dict:
+    """Rows of the class planes :func:`class_masks` builds for a step of
+    these static facts under this profile (0: no such plane), as
+    ``summary()["class_row_reads"]`` carries them."""
+    return {
+        "tol_classes": len(st.tol_rep) if spec.taints and st.use_tol_classes else 0,
+        "na_classes": (
+            len(st.na_rep) if spec.node_affinity and st.use_na_classes else 0
+        ),
+    }
 
 
 def pack_select_ok(spec, w_cfg, n_nodes: int) -> bool:

@@ -249,6 +249,13 @@ class ReplayTelemetry:
     # compiled programs (``chunk`` and ``handback``: 0 expected; ``gather``:
     # the one all-gather that brings the placements to one device).
     count_planes: Optional[Dict[str, object]] = None
+    # What-if batches only: the form in which a slot reads the row of its
+    # toleration / node-affinity class (ops.tpu3.class_row_reads), "slice"
+    # or "select": ``arrival`` for the chunk's arrival waves, ``retry`` for
+    # the retry pass (only with ``retry_buffer`` on the device path), and
+    # the rows of the two class planes (``tol_classes``, ``na_classes``; 0:
+    # the program holds no such plane). Static per compiled program.
+    class_row_reads: Optional[Dict[str, object]] = None
     scenarios: Optional[int] = None
     release_buckets: Optional[List[int]] = None
     release_rounds: Optional[int] = None
@@ -272,9 +279,9 @@ class ReplayTelemetry:
     def summary(self) -> dict:
         out: dict = {"granularity": self.granularity, "phases": self.phases}
         for key in ("chunk_waves", "inwave_corrections", "select_form",
-                    "count_planes", "scenarios", "release_buckets",
-                    "release_rounds", "handback_bytes", "mesh", "gangs",
-                    "retry"):
+                    "count_planes", "class_row_reads", "scenarios",
+                    "release_buckets", "release_rounds", "handback_bytes",
+                    "mesh", "gangs", "retry"):
             if getattr(self, key) is not None:
                 out[key] = getattr(self, key)
         if self.latency is not None:
@@ -406,7 +413,7 @@ class ReplayTelemetry:
         # Engine-level counters: parts are disjoint scenario blocks of one
         # batch (or none carries them).
         for key in ("chunk_waves", "inwave_corrections", "select_form",
-                    "count_planes"):
+                    "count_planes", "class_row_reads"):
             values = [getattr(p, key) for _, p in keep]
             if all(v == values[0] for v in values):
                 setattr(tel, key, values[0])

@@ -1553,6 +1553,13 @@ class WhatIfEngine:
                             dc, d, sh3, st3, wave_width, spec, cmasks,
                             scenario_axis=True,
                         )
+                        # The pass walks the scenario's own queue: its
+                        # slots differ by scenario, the arrival scan's do
+                        # not (V3.class_row_reads).
+                        retry_step = V3.make_wave_step3(
+                            dc, d, sh3, st3, wave_width, spec, cmasks,
+                            scenario_axis=True, slots_by_scenario=True,
+                        )
                         row = lambda t, r: jax.lax.dynamic_index_in_dim(
                             t, r, keepdims=False
                         )
@@ -1597,7 +1604,7 @@ class WhatIfEngine:
                             slots_r = T.gather_slots_device(src, rb_waves)
                             extra_r = V3.gather_extra_device(xsrc, rb_waves)
                             state, choices_r = jax.lax.scan(
-                                wave_step, state, (slots_r, extra_r)
+                                retry_step, state, (slots_r, extra_r)
                             )
                             flat_cr = choices_r.reshape(RB)
                             placed_r = (flat_cr >= 0) & (q >= 0)
@@ -4249,6 +4256,14 @@ class WhatIfEngine:
                     fleet_local.count_planes = V3.count_planes(
                         self.static3, scenario_axis=True
                     )
+                    reads = {"arrival": V3.class_row_reads(self.static3, True)}
+                    if retry_block is not None:  # the batch ran the retry pass
+                        reads["retry"] = V3.class_row_reads(
+                            self.static3, True, slots_by_scenario=True
+                        )
+                    fleet_local.class_row_reads = {
+                        **reads, **V3.class_planes(self.static3, self.spec)
+                    }
                 if dev_rel:
                     fleet_local.release_buckets = sorted(rel_buckets)
                     fleet_local.release_rounds = release_rounds

@@ -503,3 +503,63 @@ def test_retry_perturbed_scenarios_hand_back_their_own_queues():
         none = res.assignments[s] < 0
         assert (res.bind_boundary[s][none] <= -2).all()
         assert int((res.bind_boundary[s] == -3).sum()) == int(res.retry_dropped[s])
+
+
+def test_retry_pass_selects_its_class_rows_and_hands_back_what_the_gather_does(
+    monkeypatch,
+):
+    """The pass walks each scenario's own queue, so its slots' class ids
+    differ by scenario: it reads a slot's toleration row as a select among
+    the plane's two rows (``class_row_reads`` "select"), the arrival scan by
+    the dynamic index. On a tainted cluster with two toleration classes,
+    perturbed per scenario (half the cpu; a taint nobody tolerates; both),
+    every task's node and bind boundary are what the parent's form, the
+    gather, hands back, and scenario 0 is the anchor."""
+    from kubernetes_simulator_tpu.models.core import Toleration
+    from kubernetes_simulator_tpu.ops import tpu3 as V3
+    from kubernetes_simulator_tpu.sim.whatif import Perturbation
+
+    cluster = make_cluster(6, seed=11, taint_fraction=0.5)
+    pods, _ = make_workload(
+        220, seed=11, arrival_rate=60.0, duration_mean=1.5, with_spread=True,
+    )
+    rng = np.random.default_rng(11)
+    for p in pods:  # three in five tolerate, so that both classes queue
+        p.priority = int(rng.choice((0, 100, 200)))
+        if rng.random() < 0.6:
+            p.tolerations.append(
+                Toleration(key="dedicated", operator="Equal", value="batch"))
+    ec, ep = encode(cluster, pods)
+    assert ec.taint_key.size and (ec.taint_key >= 0).any()  # tainted nodes
+    half = Perturbation("scale_capacity", nodes=np.arange(6), resource="cpu",
+                        factor=0.5)
+    cordon = lambda nodes: Perturbation(
+        "add_taint", nodes=np.asarray(nodes), key="whatif", value="cordon")
+    scen = [Scenario(), Scenario([half]), Scenario([cordon([0, 3])]),
+            Scenario([cordon([1]), half])]
+    eng, res, anchor = _device_and_anchor(ec, ep, RB=16, scenarios=scen)
+    assert res.fleet_telemetry.summary()["class_row_reads"] == {
+        "arrival": "slice", "retry": "select", "tol_classes": 2,
+        "na_classes": 0,
+    }
+    np.testing.assert_array_equal(res.assignments[0], anchor.assignments)
+    np.testing.assert_array_equal(res.bind_boundary[0], anchor.bind_boundary)
+    tol_class = eng.static3.tol_class
+    tainted = np.nonzero((ec.taint_key >= 0).any(axis=1))[0]
+    on_tainted = 0
+    for s in range(len(scen)):
+        retried = res.bind_boundary[s] >= 0
+        # both toleration classes among the binds every scenario's pass made
+        assert set(tol_class[retried]) == {0, 1}
+        on_tainted += int(np.isin(res.assignments[s][retried], tainted).sum())
+        assert s == 0 or (res.bind_boundary[s] != res.bind_boundary[0]).any()
+    assert on_tainted > 0  # the row that was read decided placements
+    # the parent's form, through the function that names it
+    monkeypatch.setattr(V3, "class_row_reads", lambda *a, **k: "slice")
+    gather, by_gather, _ = _device_and_anchor(ec, ep, RB=16, scenarios=scen)
+    assert by_gather.fleet_telemetry.summary()["class_row_reads"]["retry"] == "slice"
+    assert gather is not eng
+    np.testing.assert_array_equal(res.assignments, by_gather.assignments)
+    np.testing.assert_array_equal(res.bind_boundary, by_gather.bind_boundary)
+    np.testing.assert_array_equal(res.placed, by_gather.placed)
+    np.testing.assert_array_equal(res.retry_dropped, by_gather.retry_dropped)
