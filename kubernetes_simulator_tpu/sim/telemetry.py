@@ -271,8 +271,10 @@ class ReplayTelemetry:
     # Only on the what-if device retry path (``retry_buffer``): the buffer,
     # the passes made, and per scenario mean / max of ``retry_placed``,
     # ``retry_dropped``, the queue's depth at the boundaries (``depth_max``,
-    # ``depth_at_end``) and ``release_leaked`` (re-tried binds whose release
-    # fell inside the trace and never ran: 0 by construction), with
+    # ``depth_at_end``), ``release_leaked`` (re-tried binds whose release
+    # fell inside the trace and never ran: 0 by construction) and, where the
+    # placements were handed back, ``handback_merged`` (the re-tried binds
+    # the hand-back program wrote on the device: ``retry_placed``), with
     # scenario 0's own numbers under ``scenario0``.
     retry: Optional[Dict[str, object]] = None
 

@@ -458,9 +458,11 @@ class RetryQueue(NamedTuple):
     the anti / preferred terms where the trace carries them, else None).
     The record IS the pending-release table: a boundary holds every earlier
     row against its own index, so no bind can lose its release to a full
-    list, and the hand-back reads each re-tried task's node and boundary
-    from it. ``owed`` less ``released`` at the end of a run is
-    ``release_leaked`` (0 by construction)."""
+    list, and the hand-back program writes each re-tried task's node and
+    boundary from it into the task-order arrays, on the device
+    (``_handback_retry``; the record never comes to the host). ``owed``
+    less ``released`` at the end of a run is ``release_leaked`` (0 by
+    construction)."""
 
     ids: jax.Array
     prio: jax.Array
@@ -487,12 +489,15 @@ class WhatIfResult:
     total_placed: int
     wall_clock_s: float
     placements_per_sec: float  # aggregate over all scenarios
-    assignments: Optional[np.ndarray] = None  # [S, P] when collected
+    # [S, P] when collected. On the device paths the array is the fetched
+    # copy itself, read-only and this run's own: copy it to write into it.
+    assignments: Optional[np.ndarray] = None
     # Beside ``assignments`` under the device ``retry_buffer``: [S, P] i32,
     # -1 bound in its arrival wave (or resident), b >= 0 bound by the retry
     # pass of boundary b; for a task with no node -2 still queued at the
     # end, -3 dropped at a full buffer, -4 refused at arrival and never
-    # queued (a gang member).
+    # queued (a gang member). Final as it leaves the device, and read-only
+    # as ``assignments`` is.
     bind_boundary: Optional[np.ndarray] = None
     utilization_cpu: Optional[np.ndarray] = None  # [S]
     # Which semantics this batch actually ran under (round 4: two batches
@@ -585,7 +590,10 @@ class WhatIfEngine:
         per-chunk choices those paths fetch anyway. With the device
         ``retry_buffer`` a second array comes back beside it,
         ``WhatIfResult.bind_boundary``: which boundary's retry pass bound
-        each task (-1: its arrival wave; -2 / -3 / -4: no node, and why).
+        each task (-1: its arrival wave; -2 / -3 / -4: no node, and why);
+        the same program merges the re-tried binds into both, so the host
+        fetches two final arrays and no record. What the device paths hand
+        back are the fetched copies, read-only, every ``run()`` its own.
 
         ``fork_checkpoint``: path to a JaxReplayEngine checkpoint — the
         what-if FORK POINT (SURVEY.md §5 checkpoint/resume): every scenario
@@ -2525,70 +2533,137 @@ class WhatIfEngine:
         return out, int(out.nbytes), counts
 
     def _handback_retry(
-        self, span, vassign_d, rq: RetryQueue
-    ) -> Tuple[np.ndarray, np.ndarray, int]:
-        """(assignments [S, P], bind_boundary [S, P], bytes copied) of a
-        batch on the device retry path. One program puts the arrival binds
-        into task order (``vassign`` through the static ``pos`` map, the
-        residents in its tail) and writes beside each task what it reads
-        if nothing else is known of it: -1 where it has a node, -4 for an
-        unplaced gang member (never queued), -3 for any other (dropped at a
-        full buffer). Both arrays and the queue's record come to the host
-        once; there the re-tried binds (a few thousand a scenario, named
-        by task in ``t_id``) overwrite their tasks' entries with the node
-        and the row's boundary, and the tasks still queued read -2. The
-        record is sparse and addressed by task: on the device that is a
-        scatter a scenario, which serialises under ``vmap``."""
+        self, vassign_d, rq: RetryQueue, retry_placed: np.ndarray
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+        """(assignments [S, P], bind_boundary [S, P], merged [S], bytes
+        copied) of a batch on the device retry path: ONE program, one fetch
+        an array, and the host rewrites nothing. The program puts the
+        arrival binds into task order (``vassign`` through the static
+        ``pos`` map, the residents in its tail) and writes beside each task
+        what it reads if nothing else is known of it: -1 where it has a
+        node, -4 for an unplaced gang member (never queued), -3 for any
+        other (dropped at a full buffer). Then, under ``ksim.handback``, it
+        merges the queue's record and the queue into both arrays: every
+        record row ``(b, j)`` with a task writes that task's node and ``b``,
+        and every task still queued reads -2 (a task that failed in the
+        last chunk is queued untried). The indices are unique: a task
+        leaves the queue when a pass binds it, so it is in at most one
+        record row, and a queued task is in none; so the queue rides as one
+        more row and the order of the writes is free.
+
+        The form, by measurement at 128 x 337,430 tasks, 22 rows of 4,096
+        (PERF.md §6, PR 43): a scatter costs 7 ns a row, empty or not, and
+        seven rows in eight are empty. So ONE sort a scenario by task id
+        (an empty slot last) brings the filled slots to the front, and a
+        loop scatters one block of ``RB`` columns of the whole batch a
+        trip, the scenario a column of the index into ``[S, P]`` (no
+        ``vmap``, no flat ``s * P + task`` that would have to fit int32),
+        as many trips as the fullest scenario needs: 7 or 8 of 23 there,
+        78 ms with the sort where all 23 rows at once take 163-172. The
+        arrays the loop carries are the program's own temporaries, updated
+        in place: it holds no third ``[S, P]`` array (and no input has an
+        output's shape, so there is nothing to donate). The scatters do not
+        claim ``indices_are_sorted``: with dropped rows among them the
+        chip's sorted path wrote wrong entries.
+
+        ``merged`` is ``(bind_boundary >= 0).sum(axis=1)``, the re-tried
+        binds written, counted on the device; it has to be ``retry_placed``
+        scenario for scenario, and a batch where it is not raises: a lost
+        bind is a wrong answer. Both copies to the host are started before
+        the first is waited for, and the arrays come back as fetched,
+        read-only, each run's own."""
         def build():
+            S, RB = self.S, self.retry_buffer
             pos_d = jnp.asarray(self._dev_rel_stage["pos"])
             gang_d = jnp.asarray(self.pods.group_id >= 0)
+            none = jnp.iinfo(jnp.int32).max  # past every task: dropped
 
-            def whatif_handback_retry(buf):
+            def whatif_handback_retry(buf, rq):
                 node = jnp.take(buf, pos_d, axis=1).astype(jnp.int32)
                 code = jnp.where(
                     node >= 0, -1, jnp.where(gang_d[None, :], -4, -3)
                 ).astype(jnp.int32)
-                return node, code
+                with jax.named_scope("ksim.handback"):
+                    boundary = jnp.arange(rq.t_id.shape[1], dtype=jnp.int32)
+                    # the queue rides as one more row: no node, code -2
+                    rows = lambda record, queue: jnp.concatenate(
+                        [record, queue[:, None]], axis=1
+                    ).reshape(S, -1)
+                    task = rows(rq.t_id, rq.ids)
+                    task, wrote, on = jax.lax.sort((
+                        jnp.where(task >= 0, task, none),
+                        rows(jnp.broadcast_to(boundary[None, :, None],
+                                              rq.t_id.shape),
+                             jnp.full_like(rq.ids, -2)),
+                        rows(rq.t_node, jnp.full_like(rq.ids, PAD)),
+                    ), dimension=1, num_keys=1, is_stable=False)
+                    filled = (task < none).sum(axis=1).max()
+                    scen = jax.lax.broadcasted_iota(jnp.int32, (S, RB), 0)
+
+                    def block(i, arrays):
+                        node, code = arrays
+                        cols = lambda a: jax.lax.dynamic_slice(
+                            a, (0, i * RB), (S, RB)
+                        )
+                        t, b = cols(task), cols(wrote)
+                        put = dict(mode="drop", unique_indices=True)
+                        return (
+                            node.at[scen, jnp.where(b >= 0, t, none)].set(
+                                cols(on), **put),
+                            code.at[scen, t].set(b, **put),
+                        )
+
+                    node, code = jax.lax.fori_loop(
+                        0, -(-filled // RB), block, (node, code)
+                    )
+                return node, code, (code >= 0).sum(axis=1, dtype=jnp.int32)
 
             return jax.jit(whatif_handback_retry)
 
-        node_d, code_d = self._jit_once("handback_retry", build)(vassign_d)
-        # the fetched copies are read-only
-        assignments = np.array(self._fetch(node_d))
-        bind_boundary = np.array(self._fetch(code_d))
-        t_id, t_node, queued = (
-            self._fetch(a) for a in (rq.t_id, rq.t_node, rq.ids)
-        )
-        s, b, j = np.nonzero(t_id >= 0)
-        tasks = t_id[s, b, j]
-        assignments[s, tasks] = t_node[s, b, j]
-        bind_boundary[s, tasks] = b
-        s, j = np.nonzero(queued >= 0)
-        bind_boundary[s, queued[s, j]] = -2
-        copied = sum(
-            int(a.nbytes)
-            for a in (assignments, bind_boundary, t_id, t_node, queued)
-        )
-        return assignments, bind_boundary, copied
+        got = self._jit_once("handback_retry", build)(vassign_d, rq)
+        if not self._mesh_spans_procs:
+            for a in got:  # the second array's copy overlaps the first's
+                a.copy_to_host_async()
+        assignments, bind_boundary, merged = (self._fetch(a) for a in got)
+        if not np.array_equal(merged, retry_placed):
+            s = int(np.argmax(merged != retry_placed))
+            raise RuntimeError(
+                "the retry hand-back merged another number of re-tried binds "
+                f"than the passes made: scenario {s} has {int(merged[s])} in "
+                f"bind_boundary and {int(retry_placed[s])} in retry_placed"
+            )
+        return (assignments, bind_boundary, merged,
+                int(assignments.nbytes + bind_boundary.nbytes))
 
-    def _retry_summary(self, rq: RetryQueue, outs, dropped) -> dict:
-        """``summary()["retry"]`` of the batch that just ran: the buffer,
-        the passes made (one a boundary), and per scenario (mean and max
-        over the batch, scenario 0's own under ``scenario0``) the tasks the
-        passes bound, the tasks dropped at a full buffer, the queue's depth
-        at the boundaries (its largest, and what is still queued at the
-        end) and ``release_leaked``: re-tried binds with a release boundary
-        inside the trace that no boundary released (0 by construction: the
-        counter that says no release was lost)."""
+    def _retry_counts(self, rq: RetryQueue, outs, dropped) -> dict:
+        """What ``summary()["retry"]`` holds per scenario (``[S]`` each) of
+        the batch that just ran, in one copy from the device: the tasks the
+        passes bound, the queue's depth at the boundaries (its largest, and
+        what is still queued at the end) and ``release_leaked``, with the
+        drops the run has already fetched."""
         keys = ("retry_placed", "depth_max", "depth_at_end", "release_leaked")
         got = self._fetch(self._jit_once(
             "retry_counts", lambda: jax.jit(lambda o, q: jnp.stack([
                 jnp.stack([r for _, r in o], axis=1).sum(axis=1, dtype=jnp.int32),
                 q.depth_max, q.count, q.owed - q.released,
             ]))
-        )(outs, rq))  # [4, S] in one copy
-        per = dict(zip(keys, got), retry_dropped=dropped)
-        out: dict = {"buffer": int(self.retry_buffer), "passes": len(outs)}
+        )(outs, rq))  # [4, S]
+        return dict(zip(keys, got), retry_dropped=dropped)
+
+    def _retry_summary(self, per: dict, passes: int) -> dict:
+        """``summary()["retry"]`` from ``_retry_counts``: the buffer, the
+        passes made (one a boundary), and per scenario (mean and max over
+        the batch, scenario 0's own under ``scenario0``) the tasks the
+        passes bound, the tasks dropped at a full buffer, the queue's depth
+        at the boundaries (its largest, and what is still queued at the
+        end), ``release_leaked``: re-tried binds with a release boundary
+        inside the trace that no boundary released (0 by construction: the
+        counter that says no release was lost), and, where the placements
+        were handed back, ``handback_merged``: the re-tried binds the
+        hand-back program wrote into ``bind_boundary`` (``retry_placed`` by
+        construction: the counter that says the merge ran on the device
+        and lost nothing)."""
+        out: dict = {"buffer": int(self.retry_buffer), "passes": passes}
         for k, v in per.items():
             v = np.asarray(v)
             out[k] = {"mean": float(v.mean()), "max": int(v.max())}
@@ -4191,18 +4266,21 @@ class WhatIfEngine:
                 release_rounds = (
                     int(np.max(self._fetch(rounds_d))) if dev_rel else None
                 )
-                retry_block = None
+                retry_per = None
                 if dev_rel and self.retry_buffer and not self.kube:
-                    retry_block = self._retry_summary(rq_d, outs, dropped)
+                    retry_per = self._retry_counts(rq_d, outs, dropped)
             handback_bytes = 0
             bind_boundary = None
             if self.collect_assignments and dev_rel and self.retry_buffer:
-                # Two arrays: the arrival binds from the wave-order buffer,
-                # the re-tried ones from the queue's record.
+                # Two arrays, final as they land: the arrival binds from the
+                # wave-order buffer, the re-tried ones and the tasks still
+                # queued merged in from the queue's record on the device.
                 with span("handback"):
-                    assignments, bind_boundary, handback_bytes = (
-                        self._handback_retry(span, vassign_d, rq_d)
+                    assignments, bind_boundary, merged, handback_bytes = (
+                        self._handback_retry(
+                            vassign_d, rq_d, retry_per["retry_placed"])
                     )
+                    retry_per["handback_merged"] = merged
             elif self.collect_assignments and dev_rel:
                 # The device-release path's placements: the wave-order buffer
                 # comes to the host once, after the last chunk.
@@ -4226,6 +4304,10 @@ class WhatIfEngine:
                         pch = self._fork_choices.reshape(-1)
                         pv = pidx >= 0
                         assignments[:, pidx[pv]] = pch[pv][None, :]
+            retry_block = (
+                None if retry_per is None
+                else self._retry_summary(retry_per, len(outs))
+            )
             # This process's partial fleet telemetry (round 12): per-scenario
             # collectors merged same-process (phases key-wise summed would be
             # wrong here — the fleet view wants the ENGINE's wall clocks, so

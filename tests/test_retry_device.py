@@ -426,6 +426,53 @@ def test_retry_handback_equals_the_anchor_task_for_task(case):
     np.testing.assert_array_equal(again.bind_boundary, res.bind_boundary)
 
 
+@pytest.mark.parametrize("case", sorted(RETRY_CASES))
+def test_retry_handback_merges_every_bind_the_passes_made(case):
+    """``summary()["retry"]["handback_merged"]``, the re-tried binds the
+    hand-back program wrote into ``bind_boundary`` on the device, is
+    ``retry_placed`` scenario for scenario (two scenarios: the mean, the
+    max and scenario 0's own fix both)."""
+    from kubernetes_simulator_tpu.sim.whatif import Perturbation
+
+    ec, ep = _contended(**RETRY_CASES[case])
+    scen = [Scenario(), Scenario([Perturbation(
+        "scale_capacity", nodes=np.arange(3), resource="cpu", factor=0.5)])]
+    RB = 4 if case == "small_buffer_overflows" else 16
+    _, res, _ = _device_and_anchor(ec, ep, RB=RB, scenarios=scen)
+    retry = res.fleet_telemetry.summary()["retry"]
+    merged = (res.bind_boundary >= 0).sum(axis=1)
+    assert merged.min() > 0
+    assert retry["handback_merged"] == retry["retry_placed"] == {
+        "mean": float(merged.mean()), "max": int(merged.max())}
+    assert (retry["scenario0"]["handback_merged"]
+            == retry["scenario0"]["retry_placed"] == int(merged[0]))
+    assert (res.bind_boundary == -2).sum(axis=1).tolist() == [
+        retry["scenario0"]["depth_at_end"],
+        2 * retry["depth_at_end"]["mean"] - retry["scenario0"]["depth_at_end"]]
+
+
+def test_retry_handback_that_loses_a_bind_raises(monkeypatch):
+    """A record row lost between the pass that wrote it and the hand-back
+    is a wrong answer, not a slow one: ``run()`` raises and names the
+    scenario."""
+    import jax.numpy as jnp
+
+    ec, ep = _contended(priorities=(0, 100, 200))
+    eng, _, _ = _device_and_anchor(ec, ep, RB=8, scenarios=[Scenario()] * 2)
+    handback = WhatIfEngine._handback_retry
+
+    def lossy(self, vassign_d, rq, retry_placed):
+        t_id = np.array(rq.t_id)
+        b, j = np.argwhere(t_id[1] >= 0)[0]
+        t_id[1, b, j] = PAD
+        return handback(
+            self, vassign_d, rq._replace(t_id=jnp.asarray(t_id)), retry_placed)
+
+    monkeypatch.setattr(WhatIfEngine, "_handback_retry", lossy)
+    with pytest.raises(RuntimeError, match="scenario 1 has"):
+        eng.run()
+
+
 def test_retry_queue_order_is_priority_then_arrival():
     """Two pods wait for the one cpu; the later one has the higher
     priority and gets it (kube's QueueSort), the earlier one the next."""

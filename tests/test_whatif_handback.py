@@ -70,6 +70,15 @@ def device_engine(ec, ep, cfg, scen, **kw):
     return eng
 
 
+def backend_compiles():
+    """A list that every backend compile from now on appends its event to."""
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, _, **kw: compiles.append(name)
+        if name == "/jax/core/compile/backend_compile_duration" else None)
+    return compiles
+
+
 @pytest.fixture(scope="module")
 def answered(case):
     ec, ep, cfg, scen = case
@@ -160,10 +169,7 @@ def test_prebound_tasks_come_back_from_the_tail_region(case):
 def test_later_runs_compile_nothing_and_answer_the_same(case, answered):
     ec, ep, cfg, scen = case
     eng, first = answered
-    compiles = []
-    jax.monitoring.register_event_duration_secs_listener(
-        lambda name, _, **kw: compiles.append(name)
-        if name == "/jax/core/compile/backend_compile_duration" else None)
+    compiles = backend_compiles()
     for _ in range(2):
         again = eng.run()
         np.testing.assert_array_equal(again.assignments, first.assignments)
@@ -179,10 +185,7 @@ def test_later_runs_compile_nothing_under_a_mesh(case):
     ec, ep, cfg, scen = case
     eng = device_engine(ec, ep, cfg, scen[:4], mesh=make_mesh(2))
     first = eng.run()
-    compiles = []
-    jax.monitoring.register_event_duration_secs_listener(
-        lambda name, _, **kw: compiles.append(name)
-        if name == "/jax/core/compile/backend_compile_duration" else None)
+    compiles = backend_compiles()
     again = eng.run()
     assert compiles == []
     np.testing.assert_array_equal(again.assignments, first.assignments)
@@ -210,6 +213,150 @@ def test_device_retry_buffer_hands_back_placements_and_boundaries(case, s):
     assert (res.bind_boundary[s] >= 0).any()
     gang = ep.group_id >= 0
     assert set(np.unique(res.bind_boundary[s][gang])) <= {-1, -4}
+
+
+@pytest.fixture(scope="module")
+def retrying(case):
+    """The case's six scenarios with a pending queue of 16, and its first
+    batch."""
+    ec, ep, cfg, scen = case
+    eng = device_engine(ec, ep, cfg, scen, retry_buffer=16)
+    return eng, eng.run()
+
+
+def host_merge(eng, vassign, t_id, t_node, ids):
+    """The hand-back of the device retry path as the host wrote it until
+    PR 43, kept as the oracle of the program that took its place: the
+    arrival binds in task order and each task's default code, then the
+    record's binds by fancy index, then the tasks still queued."""
+    node = np.take(vassign, eng._dev_rel_stage["pos"], axis=1).astype(np.int32)
+    gang = eng.pods.group_id >= 0
+    code = np.where(node >= 0, -1, np.where(gang[None, :], -4, -3)).astype(np.int32)
+    s, b, j = np.nonzero(t_id >= 0)
+    tasks = t_id[s, b, j]
+    node[s, tasks] = t_node[s, b, j]
+    code[s, tasks] = b
+    s, j = np.nonzero(ids >= 0)
+    code[s, ids[s, j]] = -2
+    return node, code
+
+
+# name -> (S, B, RB) -> ({scenario: [(boundary, slot, nth free task)]},
+#                        {scenario: how many free tasks are queued})
+MERGE_RECORDS = {
+    "empty_record": lambda S, B, RB: ({}, {}),
+    "a_row_bound_full": lambda S, B, RB: (
+        {1: [(2, j, j) for j in range(RB)]}, {}),
+    "bound_by_the_last_boundary": lambda S, B, RB: (
+        {s: [(B - 1, 3, 0)] for s in range(S)}, {}),
+    "still_queued_at_the_end": lambda S, B, RB: (
+        {}, {s: 1 + s for s in range(S)}),
+    "dropped_and_gang_members_untouched": lambda S, B, RB: (
+        {s: [(0, 0, 0), (1, 5, 1)] for s in range(S)}, {s: 2 for s in range(S)}),
+    "pad_rows_between_valid_ones": lambda S, B, RB: (
+        {0: [(b, j, nth) for nth, (b, j) in enumerate(
+            (b, j) for b in range(0, B, 2) for j in range(0, RB, 2))]}, {}),
+    "queues_differ_by_scenario": lambda S, B, RB: (
+        {0: [(0, 1, 0), (3, 0, 1), (3, 1, 2)], 3: [(1, RB - 1, 0)]},
+        {0: RB, 3: 4}),
+}
+
+
+@pytest.mark.parametrize("record", sorted(MERGE_RECORDS))
+def test_retry_handback_program_is_the_host_merge(retrying, record):
+    """The hand-back program of the device retry path against the host
+    merge it replaced (PR 43), over made-up wave-order buffers and records:
+    both arrays equal entry for entry, the count of re-tried binds is the
+    program's own, and what comes back is read-only."""
+    eng, _ = retrying
+    rng = np.random.default_rng(5)
+    S, N, RB = eng.S, eng.ec.num_nodes, eng.retry_buffer
+    rq = eng._retry_queue(0)  # the engine's own, empty
+    B = rq.t_id.shape[1]
+    pos = eng._dev_rel_stage["pos"]
+    va = rng.integers(0, N, size=(S, int(pos.max()) + 1)).astype(np.int32)
+    va[rng.random(va.shape) < 0.5] = PAD
+    va[:, -1] = PAD  # the slot of a task in no wave
+    gang = eng.pods.group_id >= 0
+    unplaced = np.take(va, pos, axis=1) < 0
+    # as in a run: only a task with no node from its arrival wave, and no
+    # gang member, is ever queued
+    free = [rng.permutation(np.nonzero(unplaced[s] & ~gang)[0]) for s in range(S)]
+    binds, queued = MERGE_RECORDS[record](S, B, RB)
+    t_id = np.full((S, B, RB), PAD, np.int32)
+    t_node = np.full((S, B, RB), PAD, np.int32)
+    ids = np.full((S, RB), PAD, np.int32)
+    for s, rows in binds.items():
+        for b, j, nth in rows:
+            t_id[s, b, j] = free[s][nth]
+            t_node[s, b, j] = rng.integers(0, N)
+    for s, k in queued.items():
+        ids[s, :k] = free[s][-k:]  # the record takes from the front
+    want_node, want_code = host_merge(eng, va, t_id, t_node, ids)
+    want_merged = (want_code >= 0).sum(axis=1)
+    assert want_merged.sum() == sum(len(r) for r in binds.values())
+    node, code, merged, copied = eng._handback_retry(
+        jax.numpy.asarray(va),
+        rq._replace(t_id=jax.numpy.asarray(t_id),
+                    t_node=jax.numpy.asarray(t_node),
+                    ids=jax.numpy.asarray(ids)),
+        want_merged,
+    )
+    np.testing.assert_array_equal(node, want_node)
+    np.testing.assert_array_equal(code, want_code)
+    np.testing.assert_array_equal(merged, want_merged)
+    assert node.dtype == code.dtype == np.int32
+    assert copied == node.nbytes + code.nbytes == 2 * S * eng.pods.num_pods * 4
+    assert not node.flags.writeable and not code.flags.writeable
+    # the codes the merge may not touch are there to be touched
+    assert (code[:, gang] == -4).any() and (code == -3).any() and (code == -1).any()
+    assert (code == -2).sum() == sum(queued.values())
+
+
+def test_retry_handback_is_each_runs_own_and_compiles_once(case, retrying):
+    """Two later batches of one engine hand back equal arrays that share no
+    memory with an earlier batch's (a caller keeps the first batch's and
+    compares), read-only as on the other paths, and compile nothing; the
+    bytes handed back are the two arrays' and the re-tried binds merged on
+    the device are the ones the passes made."""
+    ec, ep, cfg, scen = case
+    eng, first = retrying
+    compiles = backend_compiles()
+    kept = [first]
+    for _ in range(2):
+        again = eng.run()
+        for a, b in ((again.assignments, first.assignments),
+                     (again.bind_boundary, first.bind_boundary)):
+            np.testing.assert_array_equal(a, b)
+            assert not any(np.shares_memory(a, getattr(k, n))
+                           for k in kept for n in ("assignments", "bind_boundary"))
+            assert not a.flags.writeable
+        kept.append(again)
+    assert compiles == []
+    got = again.fleet_telemetry.summary()
+    assert got["handback_bytes"] == 2 * len(scen) * ep.num_pods * 4
+    assert got["handback_bytes"] == (again.assignments.nbytes
+                                     + again.bind_boundary.nbytes)
+    retry = got["retry"]
+    merged = (again.bind_boundary >= 0).sum(axis=1)
+    assert retry["handback_merged"] == retry["retry_placed"] == {
+        "mean": float(merged.mean()), "max": int(merged.max())}
+    assert retry["scenario0"]["handback_merged"] == int(merged[0])
+
+
+def test_retry_handback_under_a_mesh_is_the_unmeshed(case, retrying):
+    """The merge indexes the whole batch at once; with the scenario axis
+    sharded over two devices it hands back what one device does."""
+    from kubernetes_simulator_tpu.parallel.mesh import make_mesh
+
+    ec, ep, cfg, scen = case
+    _, plain = retrying
+    res = device_engine(ec, ep, cfg, scen[:4], retry_buffer=16,
+                        mesh=make_mesh(2)).run()
+    np.testing.assert_array_equal(res.assignments, plain.assignments[:4])
+    np.testing.assert_array_equal(res.bind_boundary, plain.bind_boundary[:4])
+    retry = res.fleet_telemetry.summary()["retry"]
+    assert retry["handback_merged"] == retry["retry_placed"]
 
 
 def test_phases_cover_a_whatif_run(answered):
