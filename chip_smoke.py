@@ -597,10 +597,11 @@ def phase_parity() -> dict:
 
     w = np.arange(1, 100, dtype=np.float32)
     got = np.asarray(jax.vmap(
-        lambda x: T.normalize_max(jnp.stack([x, 0 * x]), jnp.ones(2, bool))
+        lambda x: T._normalize_row(jnp.stack([x, 0 * x]), None, x, None,
+                                   minmax=False, reverse=False)
     )(jnp.asarray(w)))
     require((got == [100.0, 0.0]).all(),
-            f"normalize_max: weights {w[got[:, 0] != 100].tolist()} of "
+            f"max-normalize: weights {w[got[:, 0] != 100].tolist()} of "
             "themselves are not 100")
     hi, lo, sc = np.meshgrid(np.arange(1, 400), np.arange(0, 400, 7),
                              np.arange(0, 400, 3), indexing="ij")

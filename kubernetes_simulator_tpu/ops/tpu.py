@@ -1,11 +1,15 @@
-"""Scheduling kernels — JAX device edition (SURVEY.md §3.5).
+"""Containers and helpers of the device wave step (SURVEY.md §3.5).
 
-Same math as :mod:`.cpu`, re-expressed for XLA: everything is static-shape
-jnp over ``[N]``/``[G, N]`` tensors, composable under ``jit``/``vmap``/
-``lax.scan``. One pending pod (a "slot" row pytree) is evaluated against
-all nodes at once; the mutable scheduling state is a small pytree updated
-by masked elementwise adds so the whole replay runs as one compiled scan
-on device.
+What :mod:`.tpu3` (``make_wave_step3``, the one device program) imports:
+the cluster and slot containers (``DevCluster``, ``PodSlot``,
+``SlotSource`` with the in-jit and the host slot gathers, ``Derived``), the
+state-independent Filter and Score rows (taints, node affinity), the
+normalize arithmetic (``_normalize_row``, ``spread_norm_from_extrema``,
+``floor_div_f32``), the selects (``select_node`` and its packed forms,
+``masked_argmin``), the policy-vector columns, and the two host converters
+between the domain-space ``[G, D]`` and node-space ``[G, N]`` count
+layouts. Same math as :mod:`.cpu`, static-shape jnp, composable under
+``jit``/``vmap``/``lax.scan``.
 
 Design notes (TPU-first):
 - **No gathers or scatters anywhere in the hot loop.** Batched
@@ -14,10 +18,6 @@ Design notes (TPU-first):
   the math). Every dynamic-index access is instead expressed as a one-hot
   contraction (MXU matvec) or a masked elementwise update (VPU), which are
   effectively free at these shapes.
-- Count-group state lives in **node space** ``[G, N]`` (the value each node
-  *sees*: ``count[g, domain_of(g, n)]``), not domain space ``[G, D]``.
-  Reads become row contractions; a bind updates every node in the bound
-  node's domain via an equality mask — one fused elementwise op.
 - masks stay bool, scores f32; per-pod term loops (tolerations, affinity
   terms, spread constraints) are python-unrolled over SMALL static widths.
 - no data-dependent shapes: padded slots are neutralized with `where`, a
@@ -126,33 +126,6 @@ class DevCluster(NamedTuple):
             expr_vals=jnp.asarray(ec.expr_vals),
             expr_num=jnp.asarray(ec.expr_num),
             group_topo=jnp.asarray(ec.group_topo),
-        )
-
-
-class DevState(NamedTuple):
-    """Mutable scheduling state carried through lax.scan (device twin of
-    models.state.SchedState, **node space**): ``match_count[g, n]`` is the
-    number of placed pods matching group g in node n's domain under g's
-    topology key (0 where the node has no domain). ``match_total[g]`` is the
-    cluster-wide count (needed for the bootstrap self-match rule — a plain
-    sum over node space would overcount domains with many nodes)."""
-
-    used: jax.Array  # [N, R] f32
-    match_count: jax.Array  # [G, N] f32
-    anti_active: jax.Array  # [G, N] f32
-    pref_wsum: jax.Array  # [G, N] f32
-    match_total: jax.Array  # [G] f32
-
-    @classmethod
-    def init(cls, ec: EncodedCluster) -> "DevState":
-        G = max(ec.num_groups, 1)
-        N = ec.num_nodes
-        return cls(
-            used=jnp.zeros((N, ec.num_resources), jnp.float32),
-            match_count=jnp.zeros((G, N), jnp.float32),
-            anti_active=jnp.zeros((G, N), jnp.float32),
-            pref_wsum=jnp.zeros((G, N), jnp.float32),
-            match_total=jnp.zeros((G,), jnp.float32),
         )
 
 
@@ -392,10 +365,6 @@ def _term_onehot(gs: jax.Array, G: int) -> jax.Array:
 # Filters
 # ---------------------------------------------------------------------------
 
-def fit_mask(dc: DevCluster, st: DevState, s: PodSlot) -> jax.Array:
-    return jnp.all(st.used + s.req[None, :] <= dc.allocatable + 1e-6, axis=1)
-
-
 def taint_untolerated(dc: DevCluster, s: PodSlot, effects) -> jax.Array:
     t_eff = dc.taint_effect  # [N, TT]
     active = (dc.taint_key != PAD)
@@ -443,121 +412,6 @@ def node_affinity_score(d: Derived, s: PodSlot) -> jax.Array:
     per_expr = d.M[:, safe] | (terms[None, :, :] < 0)
     per_term = jnp.all(per_expr, axis=2) & valid_term[None, :]
     return jnp.sum(per_term * s.na_pref_w[None, :], axis=1).astype(jnp.float32)
-
-
-def _term_rows(st_counts: jax.Array, oh: jax.Array) -> jax.Array:
-    """[A, N] — node-space count rows for A term groups (one-hot matmul —
-    exact: each output is a single selected element)."""
-    return jnp.einsum("ag,gn->an", oh, st_counts, precision=_HI)
-
-
-def interpod_filter_mask(d: Derived, st: DevState, s: PodSlot) -> jax.Array:
-    """Required (anti-)affinity + the SYMMETRIC existing-pods'-anti check,
-    all as one-hot contractions over node-space counts — no gathers."""
-    G = st.match_count.shape[0]
-    N = d.gdom_f.shape[1]
-    pmg_f = s.pmg.astype(jnp.float32)
-    ok = jnp.ones(N, dtype=bool)
-    gvalid_all = d.gdom_f >= 0  # [G, N]
-
-    ohA = _term_onehot(s.aff_req, G)  # [A, G]
-    if ohA.shape[0]:
-        cnt = _term_rows(st.match_count, ohA)  # [A, N]
-        gvalid = jnp.einsum("ag,gn->an", ohA, gvalid_all.astype(jnp.float32), precision=_HI) > 0.5
-        total = jnp.einsum("ag,g->a", ohA, st.match_total, precision=_HI)  # [A]
-        selfm = jnp.einsum("ag,g->a", ohA, pmg_f, precision=_HI) > 0.5  # [A]
-        boot = (total == 0) & selfm
-        term_ok = (cnt >= 1) & gvalid
-        ok = ok & jnp.all(
-            jnp.where((s.aff_req >= 0)[:, None], term_ok | boot[:, None], True), axis=0
-        )
-
-    ohB = _term_onehot(s.anti_req, G)
-    if ohB.shape[0]:
-        cntb = _term_rows(st.match_count, ohB)
-        gvalidb = jnp.einsum("ag,gn->an", ohB, gvalid_all.astype(jnp.float32), precision=_HI) > 0.5
-        viol = (cntb >= 1) & gvalidb
-        ok = ok & jnp.all(jnp.where((s.anti_req >= 0)[:, None], ~viol, True), axis=0)
-
-    # Symmetric: a node is blocked if any placed pod with a required anti
-    # term g sits in its domain and this pod matches g.
-    blocked = (
-        jnp.einsum("g,gn->n", pmg_f, (st.anti_active > 0).astype(jnp.float32), precision=_HI)
-        > 0.5
-    )
-    return ok & ~blocked
-
-
-def interpod_score(d: Derived, st: DevState, s: PodSlot, has_symmetric_pref: bool = True) -> jax.Array:
-    G = st.match_count.shape[0]
-    N = d.gdom_f.shape[1]
-    raw = jnp.zeros(N, dtype=jnp.float32)
-    ohP = _term_onehot(s.pref_aff, G)
-    if ohP.shape[0]:
-        cnt = _term_rows(st.match_count, ohP)  # [P, N]
-        w = jnp.where(s.pref_aff >= 0, s.pref_aff_w, 0.0)
-        raw = raw + jnp.einsum("p,pn->n", w, cnt, precision=_HI)
-    if has_symmetric_pref:
-        # pref_wsum is already node-space — the old [G, N] sweep is now a
-        # single matvec.
-        raw = raw + jnp.einsum(
-            "g,gn->n", s.pmg.astype(jnp.float32), st.pref_wsum, precision=_HI
-        )
-    return raw
-
-
-def spread_filter_mask(d: Derived, st: DevState, s: PodSlot) -> jax.Array:
-    G = st.match_count.shape[0]
-    N = d.gdom_f.shape[1]
-    ohS = _term_onehot(s.spread_g, G)  # [A, G]
-    if not ohS.shape[0]:
-        return jnp.ones(N, dtype=bool)
-    cnt = _term_rows(st.match_count, ohS)  # [A, N]
-    gvalid = jnp.einsum("ag,gn->an", ohS, (d.gdom_f >= 0).astype(jnp.float32), precision=_HI) > 0.5
-    # min over valid domains == min over nodes that have a domain (every
-    # domain has ≥1 node by construction).
-    minv = jnp.min(jnp.where(gvalid, cnt, jnp.inf), axis=1)  # [A]
-    has_domains = jnp.isfinite(minv)
-    selfm = jnp.einsum("ag,g->a", ohS, s.pmg.astype(jnp.float32), precision=_HI)
-    c_ok = (
-        gvalid
-        & has_domains[:, None]
-        & (cnt + selfm[:, None] - jnp.where(has_domains, minv, 0.0)[:, None]
-           <= s.spread_skew[:, None])
-    )
-    return jnp.all(jnp.where(((s.spread_g >= 0) & s.spread_dns)[:, None], c_ok, True), axis=0)
-
-
-def spread_score_upstream(d: Derived, st: DevState, s: PodSlot, w_g) -> tuple:
-    """Upstream podtopologyspread raw score (mirrors ops.cpu.spread_score):
-    ``floor(Σ_scored cnt·log(size+2) + (maxSkew−1))`` per node over the
-    ScheduleAnyway constraints, plus the ignored mask (node missing a
-    scored key) and the dynamic any-scored flag (PreScore Skip). ``w_g`` is
-    the static [G] weight table."""
-    G = st.match_count.shape[0]
-    N = d.gdom_f.shape[1]
-    ohS = _term_onehot(s.spread_g, G)
-    if not ohS.shape[0]:
-        return (
-            jnp.zeros(N, jnp.float32),
-            jnp.zeros(N, bool),
-            jnp.zeros((), bool),
-        )
-    cnt = _term_rows(st.match_count, ohS)  # [A, N]
-    gvalid = (
-        jnp.einsum("ag,gn->an", ohS, (d.gdom_f >= 0).astype(jnp.float32), precision=_HI)
-        > 0.5
-    )
-    scored = (s.spread_g >= 0) & ~s.spread_dns  # [A]
-    wrow = jnp.einsum("ag,g->a", ohS, jnp.asarray(w_g, jnp.float32), precision=_HI)
-    raw = jnp.zeros(N, jnp.float32)
-    ignored = jnp.zeros(N, bool)
-    for i in range(ohS.shape[0]):
-        contrib = cnt[i] * wrow[i] + (s.spread_skew[i].astype(jnp.float32) - 1.0)
-        raw = raw + jnp.where(scored[i], contrib, 0.0)
-        ignored = ignored | (scored[i] & ~gvalid[i])
-    # Upstream int64(math.Round(score)): floor(x+0.5), non-negative x.
-    return jnp.floor(raw + 0.5), ignored, jnp.any(scored)
 
 
 def floor_div_f32(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -611,63 +465,14 @@ def spread_norm_from_extrema(raw, ignored, hi, lo, any_scored, f32ok=False) -> j
     return jnp.where(ignored | ~has | ~any_scored, 0.0, out)
 
 
-def spread_upstream_normalize(raw, ignored, feasible, any_scored, f32ok=False) -> jax.Array:
-    """Upstream two-pass NormalizeScore (mirrors ops.cpu.spread_normalize
-    bit-for-bit): int32-exact ``100·(max+min−s) // max`` with extrema over
-    non-ignored feasible nodes; ignored → 0; max == 0 → 100; no scored
-    constraints → all 0."""
-    okn = feasible & ~ignored
-    hi = jnp.max(jnp.where(okn, raw, -jnp.inf))
-    lo = jnp.min(jnp.where(okn, raw, jnp.inf))
-    return spread_norm_from_extrema(raw, ignored, hi, lo, any_scored, f32ok)
-
-
 # ---------------------------------------------------------------------------
-# Resource scores
+# Scores
 # ---------------------------------------------------------------------------
 
 # Scores are INTEGER-valued f32, floored through single-op chains — nothing
 # XLA can FMA-fuse — so device scores are bit-identical to ops.cpu and
 # argmax ties break the same way (SURVEY.md §7 hard part #6). Mirrors
 # upstream's int64 node scores.
-
-
-def _int_resource_score(frac: jax.Array, weights) -> jax.Array:
-    s = jnp.floor(frac * np.float32(MAX_NODE_SCORE))  # [N, R], integral
-    acc = jnp.zeros(frac.shape[0], dtype=jnp.float32)
-    wsum = 0.0
-    for r in range(frac.shape[1]):
-        w = float(weights[r])
-        if w != 0:
-            acc = acc + s[:, r] * np.float32(w)  # exact: small ints
-            wsum += w
-    if wsum == 0:
-        return acc
-    return jnp.floor(acc / np.float32(wsum))
-
-
-def least_allocated_score_from_used(dc: DevCluster, used: jax.Array, s: PodSlot, weights) -> jax.Array:
-    alloc = dc.allocatable
-    denom = jnp.where(alloc > 0, alloc, 1.0)
-    frac = jnp.where(alloc > 0, (alloc - used - s.req[None, :]) / denom, 0.0)
-    frac = jnp.clip(frac, 0.0, 1.0)
-    return _int_resource_score(frac, weights)
-
-
-def least_allocated_score(dc: DevCluster, st: DevState, s: PodSlot, weights) -> jax.Array:
-    return least_allocated_score_from_used(dc, st.used, s, weights)
-
-
-def most_allocated_score_from_used(dc: DevCluster, used: jax.Array, s: PodSlot, weights) -> jax.Array:
-    alloc = dc.allocatable
-    denom = jnp.where(alloc > 0, alloc, 1.0)
-    frac = jnp.where(alloc > 0, (used + s.req[None, :]) / denom, 0.0)
-    frac = jnp.clip(frac, 0.0, 1.0)
-    return _int_resource_score(frac, weights)
-
-
-def most_allocated_score(dc: DevCluster, st: DevState, s: PodSlot, weights) -> jax.Array:
-    return most_allocated_score_from_used(dc, st.used, s, weights)
 
 
 def piecewise_interp_int(util: jax.Array, xs, ys) -> jax.Array:
@@ -682,36 +487,8 @@ def piecewise_interp_int(util: jax.Array, xs, ys) -> jax.Array:
     return jnp.where(util <= np.float32(xs[0]), np.float32(ys[0]), out).astype(jnp.float32)
 
 
-def requested_to_capacity_ratio_score(
-    dc: DevCluster, st: DevState, s: PodSlot, weights, shape_x, shape_y
-) -> jax.Array:
-    return requested_to_capacity_ratio_score_from_used(
-        dc, st.used, s, weights, shape_x, shape_y
-    )
-
-
-def requested_to_capacity_ratio_score_from_used(
-    dc: DevCluster, used: jax.Array, s: PodSlot, weights, shape_x, shape_y
-) -> jax.Array:
-    alloc = dc.allocatable
-    denom = jnp.where(alloc > 0, alloc, 1.0)
-    frac = jnp.where(alloc > 0, (used + s.req[None, :]) / denom, 0.0)
-    util = jnp.floor(jnp.clip(frac, 0.0, 1.0) * np.float32(100.0))
-    score_r = piecewise_interp_int(util, list(shape_x), list(shape_y))
-    acc = jnp.zeros(alloc.shape[0], dtype=jnp.float32)
-    wsum = 0.0
-    for r in range(score_r.shape[1]):
-        w = float(weights[r])
-        if w != 0:
-            acc = acc + score_r[:, r] * np.float32(w)
-            wsum += w
-    if wsum == 0:
-        return acc
-    return jnp.floor(acc / np.float32(wsum))
-
-
 # ---------------------------------------------------------------------------
-# Normalization + selection + state update
+# Normalization + selection
 # ---------------------------------------------------------------------------
 
 def _normalize_row(raw, lo, hi, any_f, minmax: bool, reverse: bool) -> jax.Array:
@@ -742,20 +519,6 @@ def _normalize_row(raw, lo, hi, any_f, minmax: bool, reverse: bool) -> jax.Array
                 pos, np.float32(MAX_NODE_SCORE) - out, np.float32(MAX_NODE_SCORE)
             )
     return out.astype(jnp.float32)
-
-
-def normalize_max(raw: jax.Array, feasible: jax.Array, reverse: bool = False) -> jax.Array:
-    """Mirror of ops.cpu.normalize_max: floor(raw·100/max), integer scores."""
-    mx = jnp.max(jnp.where(feasible, raw, 0.0))
-    return _normalize_row(raw, None, mx, None, False, reverse)
-
-
-def normalize_min_max(raw: jax.Array, feasible: jax.Array, reverse: bool = False) -> jax.Array:
-    """Mirror of ops.cpu.normalize_min_max: floor((raw−lo)·(100/span))."""
-    any_f = jnp.any(feasible)
-    lo = jnp.min(jnp.where(feasible, raw, jnp.inf)).astype(jnp.float32)
-    hi = jnp.max(jnp.where(feasible, raw, -jnp.inf)).astype(jnp.float32)
-    return _normalize_row(raw, lo, hi, any_f, True, reverse)
 
 
 def select_node(scores: jax.Array, feasible: jax.Array):
@@ -812,23 +575,6 @@ def masked_argmin(scores: jax.Array, mask: jax.Array):
     )
     ok = mx > NEG_INF
     return jnp.where(ok, choice.astype(jnp.int32), PAD), ok
-
-
-def first_reject_counts(masks, failed) -> jax.Array:
-    """[K] i32 — per-plugin first-reject node counts for one slot, the
-    device form of the kube "0/N nodes available" attribution
-    (ops.cpu.first_reject_update is the host edition). ``masks`` is the
-    ordered list of per-plugin [N] bool masks from the fused eval;
-    ``failed`` gates the whole vector (a placed or PAD slot charges
-    nothing). Only fully-failed attempts are ever counted, so the K
-    entries always sum to N per counted slot — matching the event
-    engine's episode semantics at W=1/C=1."""
-    so_far = jnp.ones_like(masks[0])
-    outs = []
-    for m in masks:
-        outs.append(jnp.sum(so_far & ~m).astype(jnp.int32))
-        so_far = so_far & m
-    return jnp.where(failed, jnp.stack(outs), 0)
 
 
 # Packed-select bounds: scores are packed as total·2^14 + (2^14−1−n), which
@@ -935,24 +681,6 @@ def select_node_zone_packed(zone_best: jax.Array, zone_scores: jax.Array):
     return _unpack(jnp.max(zone_best + zone_scores * np.float32(PACK_SHIFT)))
 
 
-def _bind_deltas(d: Derived, node: jax.Array):
-    """Shared pieces of a masked bind: the node one-hot, the [G, N]
-    domain-equality mask (node n is in the same domain as `node` under
-    group g's topology key), and the [G] has-domain flags for the bound
-    node."""
-    N = d.gdom_f.shape[1]
-    oh_n = ((jnp.arange(N) == node) & (node >= 0)).astype(jnp.float32)  # [N]
-    # Domain id of the bound node per group (one selected element — exact).
-    gdom_at = jnp.einsum("gn,n->g", d.gdom_f, oh_n, precision=_HI)  # [G]
-    node_has_dom = (
-        jnp.einsum("gn,n->g", (d.gdom_f >= 0).astype(jnp.float32), oh_n, precision=_HI) > 0.5
-    )
-    dom_sel = (
-        (d.gdom_f == gdom_at[:, None]) & node_has_dom[:, None] & (d.gdom_f >= 0)
-    ).astype(jnp.float32)  # [G, N]
-    return oh_n, dom_sel, node_has_dom.astype(jnp.float32)
-
-
 def _pod_group_vectors(s: PodSlot, G: int):
     """([..., G] anti-term one-hot sum, [..., G] pref weight sum); term axes
     may carry a leading wave axis."""
@@ -962,71 +690,6 @@ def _pod_group_vectors(s: PodSlot, G: int):
     w = jnp.where(s.pref_aff >= 0, s.pref_aff_w, 0.0)
     pref_g = jnp.einsum("...a,...ag->...g", w, ohP, precision=_HI)
     return anti_g, pref_g
-
-
-def apply_binding(
-    d: Derived, st: DevState, s: PodSlot, node: jax.Array, on: jax.Array
-) -> DevState:
-    """Masked bind. ``on`` is a bool scalar; when False the update is a
-    no-op — keeps the scan branch-free. All updates are elementwise (no
-    scatters). Gang rollback goes through :func:`apply_unbind_wave`."""
-    G = st.match_count.shape[0]
-    w = jnp.where(on & s.valid, 1.0, 0.0).astype(jnp.float32)
-    oh_n, dom_sel, has_dom = _bind_deltas(d, node)
-    used = st.used + (w * oh_n)[:, None] * s.req[None, :]
-    pmg_f = s.pmg.astype(jnp.float32)
-    match_count = st.match_count + (w * pmg_f)[:, None] * dom_sel
-    # Total counts only domain-carrying binds — it must stay exactly
-    # sum-over-domains of match_count (ops.cpu's bootstrap total).
-    match_total = st.match_total + w * pmg_f * has_dom
-    anti_g, pref_g = _pod_group_vectors(s, G)
-    anti = st.anti_active + (w * anti_g)[:, None] * dom_sel
-    pref = st.pref_wsum + (w * pref_g)[:, None] * dom_sel
-    return DevState(
-        used=used, match_count=match_count, anti_active=anti, pref_wsum=pref,
-        match_total=match_total,
-    )
-
-
-# ---------------------------------------------------------------------------
-# Fused wave evaluation (the hot path)
-#
-# The naive per-pod chain (eval_pod in sim.jax_runtime) issues ~30
-# non-fusable ops per pod (einsums + reductions); at ~1-3 µs fixed cost per
-# op inside a TPU scan, the replay is dispatch-latency-bound, not
-# FLOP-bound.  Two fixes, both exact (bit-identical results):
-#
-# 1. Everything state-INDEPENDENT (taint matrices, node-affinity expression
-#    matching, term one-hots, bind vectors) is precomputed for the whole
-#    wave in one batched shot (WavePre) — W pods' worth of the biggest
-#    tensors leave the sequential chain.
-# 2. The per-pod state reads collapse into ONE stacked one-hot matmul
-#    against match_count (+3 small matvecs), and the per-plugin score
-#    normalizations collapse into one stacked masked min+max pair.
-# ---------------------------------------------------------------------------
-
-
-class WavePre(NamedTuple):
-    """Per-wave precomputed tensors (leading axis W). Static widths:
-    A = #required-affinity terms, B = #required-anti terms, SP = #spread
-    constraints; lhs row layout is [A aff | B anti | SP spread | 1 pref]."""
-
-    lhs: jax.Array  # [W, K, G] f32 stacked one-hot rows (K = A+B+SP+1 or 0)
-    gvalid: jax.Array  # [W, KT, N] bool (KT = A+B+SP) domain-valid per term row
-    taint_ok: jax.Array  # [W, N] bool
-    taint_raw: jax.Array  # [W, N] f32 (PreferNoSchedule counts)
-    na_ok: jax.Array  # [W, N] bool
-    na_raw: jax.Array  # [W, N] f32
-    aff_valid: jax.Array  # [W, A] bool
-    aff_selfm: jax.Array  # [W, A] bool (pod matches its own aff term)
-    anti_valid: jax.Array  # [W, B] bool
-    sp_valid: jax.Array  # [W, SP] bool
-    sp_dns: jax.Array  # [W, SP] bool (valid & DoNotSchedule)
-    sp_scored: jax.Array  # [W, SP] bool (valid & ScheduleAnyway — scoring rows)
-    sp_selfm: jax.Array  # [W, SP] f32
-    sp_skew: jax.Array  # [W, SP] f32
-    sp_w: jax.Array  # [W, SP] f32 (upstream log(size+2) topology weights)
-    pmg_f: jax.Array  # [W, G] f32
 
 
 def _padded_w_table(sp_w_g, G: int) -> np.ndarray:
@@ -1039,261 +702,3 @@ def _padded_w_table(sp_w_g, G: int) -> np.ndarray:
     return tab
 
 
-def wave_widths(s: "PodSlot", spec) -> tuple:
-    """(A, B, SP) static term widths after spec gating."""
-    A = s.aff_req.shape[-1] if spec.interpod else 0
-    B = s.anti_req.shape[-1] if spec.interpod else 0
-    SP = s.spread_g.shape[-1] if spec.spread else 0
-    return A, B, SP
-
-
-def build_wave_pre(dc: DevCluster, d: Derived, sb: PodSlot, spec) -> WavePre:
-    """Batched (over the wave axis) precompute of every state-independent
-    piece of eval. ``sb`` fields carry a leading W axis."""
-    W = sb.pod_id.shape[0]
-    G = d.gdom_f.shape[0]
-    N = d.gdom_f.shape[1]
-    A, B, SP = wave_widths(sb, spec)
-    pmg_f = sb.pmg.astype(jnp.float32)  # [W, G]
-
-    pieces = []
-    if spec.interpod:
-        ohA = _term_onehot(sb.aff_req, G)  # [W, A, G]
-        ohB = _term_onehot(sb.anti_req, G)
-        pieces += [ohA, ohB]
-    else:
-        ohA = jnp.zeros((W, 0, G), jnp.float32)
-        ohB = ohA
-    if spec.spread:
-        ohS = _term_onehot(sb.spread_g, G)
-        pieces.append(ohS)
-    else:
-        ohS = jnp.zeros((W, 0, G), jnp.float32)
-    if spec.interpod:
-        ohP = _term_onehot(sb.pref_aff, G)  # [W, PA, G]
-        wp = jnp.where(sb.pref_aff >= 0, sb.pref_aff_w, 0.0)
-        pref_row = jnp.einsum("wp,wpg->wg", wp, ohP, precision=_HI)[:, None, :]
-        pieces.append(pref_row)
-    lhs = (
-        jnp.concatenate(pieces, axis=1)
-        if pieces
-        else jnp.zeros((W, 0, G), jnp.float32)
-    )
-    terms = lhs[:, : A + B + SP]
-    gvalid = (
-        jnp.einsum(
-            "wkg,gn->wkn", terms, (d.gdom_f >= 0).astype(jnp.float32), precision=_HI
-        )
-        > 0.5
-        if A + B + SP
-        else jnp.zeros((W, 0, N), bool)
-    )
-
-    if spec.taints:
-        taint_ok = jax.vmap(lambda s: taint_mask(dc, s))(sb)
-        taint_raw = jax.vmap(lambda s: taint_prefer_count(dc, s))(sb)
-    else:
-        taint_ok = jnp.ones((W, N), bool)
-        taint_raw = jnp.zeros((W, N), jnp.float32)
-    if spec.node_affinity:
-        na_ok = jax.vmap(lambda s: node_affinity_mask(d, s))(sb)
-        na_raw = jax.vmap(lambda s: node_affinity_score(d, s))(sb)
-    else:
-        na_ok = jnp.ones((W, N), bool)
-        na_raw = jnp.zeros((W, N), jnp.float32)
-
-    return WavePre(
-        lhs=lhs,
-        gvalid=gvalid,
-        taint_ok=taint_ok,
-        taint_raw=taint_raw,
-        na_ok=na_ok,
-        na_raw=na_raw,
-        aff_valid=sb.aff_req[:, :A] >= 0,
-        aff_selfm=jnp.einsum("wag,wg->wa", ohA, pmg_f, precision=_HI) > 0.5,
-        anti_valid=sb.anti_req[:, :B] >= 0,
-        sp_valid=sb.spread_g[:, :SP] >= 0,
-        sp_dns=(sb.spread_g[:, :SP] >= 0) & sb.spread_dns[:, :SP],
-        sp_scored=(sb.spread_g[:, :SP] >= 0) & ~sb.spread_dns[:, :SP],
-        sp_selfm=jnp.einsum("wag,wg->wa", ohS, pmg_f, precision=_HI),
-        sp_skew=sb.spread_skew[:, :SP].astype(jnp.float32),
-        sp_w=jnp.einsum(
-            "wag,g->wa", ohS, _padded_w_table(spec.sp_w_g, G), precision=_HI
-        )
-        if SP
-        else jnp.zeros((W, 0), jnp.float32),
-        pmg_f=pmg_f,
-    )
-
-
-def eval_pod_fused(
-    dc: DevCluster,
-    d: Derived,
-    st: DevState,
-    s: PodSlot,
-    p: WavePre,
-    spec,
-    widths: tuple,
-    wvec=None,
-):
-    """Fused Filter+Score for one slot using wave-precomputed tensors.
-    Bit-identical to the reference chain (sim.jax_runtime.eval_pod) — the
-    parity suites pin this. Returns (feasible [N], scores [N], any_f).
-
-    ``wvec`` (optional [len(POLICY_COLS)] traced f32) swaps the static
-    config weights for per-scenario policy-vector columns (round 9 tuner);
-    filtering is weight-independent and unchanged."""
-    N = dc.allocatable.shape[0]
-    A, B, SP = widths
-    K = p.lhs.shape[0]
-
-    used1 = st.used + s.req[None, :]  # shared by fit mask + fit score
-    feasible = jnp.ones(N, dtype=bool)
-    if spec.fit:
-        feasible = jnp.all(used1 <= dc.allocatable + 1e-6, axis=1)
-    if spec.taints:
-        feasible = feasible & p.taint_ok
-    if spec.node_affinity:
-        feasible = feasible & p.na_ok
-
-    reads = (
-        jnp.einsum("kg,gn->kn", p.lhs, st.match_count, precision=_HI)
-        if K
-        else jnp.zeros((0, N), jnp.float32)
-    )
-    if spec.interpod:
-        if A:
-            totals = jnp.einsum("ag,g->a", p.lhs[:A], st.match_total, precision=_HI)
-            boot = (totals == 0) & p.aff_selfm  # bootstrap self-match
-            term_ok = (reads[:A] >= 1) & p.gvalid[:A]
-            feasible = feasible & jnp.all(
-                jnp.where(p.aff_valid[:, None], term_ok | boot[:, None], True), axis=0
-            )
-        if B:
-            viol = (reads[A : A + B] >= 1) & p.gvalid[A : A + B]
-            feasible = feasible & jnp.all(
-                jnp.where(p.anti_valid[:, None], ~viol, True), axis=0
-            )
-        blocked = (
-            jnp.einsum("g,gn->n", p.pmg_f, st.anti_active, precision=_HI) > 0.5
-        )  # symmetric: anti_active entries are non-negative counts
-        feasible = feasible & ~blocked
-    if spec.spread and SP:
-        cnts = reads[A + B : A + B + SP]  # [SP, N]
-        gval = p.gvalid[A + B : A + B + SP]
-        minv = jnp.min(jnp.where(gval, cnts, jnp.inf), axis=1)
-        has = jnp.isfinite(minv)
-        c_ok = (
-            gval
-            & has[:, None]
-            & (cnts + p.sp_selfm[:, None] - jnp.where(has, minv, 0.0)[:, None]
-               <= p.sp_skew[:, None])
-        )
-        feasible = feasible & jnp.all(
-            jnp.where(p.sp_dns[:, None], c_ok, True), axis=0
-        )
-
-    any_f = jnp.any(feasible)
-
-    # ---- scores: stack raw rows, one masked min+max, per-row normalize ----
-    _w, _on = policy_weight_fns(spec, wvec)
-    total = jnp.zeros(N, dtype=jnp.float32)
-    if spec.fit and _on("NodeResourcesFit"):
-        rw = np.asarray(spec.resource_weights, dtype=np.float32)
-        if spec.fit_strategy not in ("LeastAllocated", "MostAllocated"):
-            raw = requested_to_capacity_ratio_score(
-                dc, st, s, rw, spec.shape_x, spec.shape_y
-            )
-        elif wvec is None:
-            raw = (
-                least_allocated_score(dc, st, s, rw)
-                if spec.fit_strategy == "LeastAllocated"
-                else most_allocated_score(dc, st, s, rw)
-            )
-        else:
-            raw = jnp.where(
-                wvec[IDX_FIT_LEAST] > 0.5,
-                least_allocated_score(dc, st, s, rw),
-                most_allocated_score(dc, st, s, rw),
-            )
-        total = total + _w("NodeResourcesFit") * raw
-
-    # (raw, weight, minmax?, reverse?) rows, in the reference accumulation
-    # order: taint, node-affinity, interpod, spread.
-    rows = []
-    if spec.taints and spec.taint_score and _on("TaintToleration"):
-        rows.append((p.taint_raw, _w("TaintToleration"), False, True))
-    if spec.node_affinity and _on("NodeAffinity"):
-        rows.append((p.na_raw, _w("NodeAffinity"), False, False))
-    if spec.interpod and _on("InterPodAffinity"):
-        raw = reads[A + B + SP]
-        if spec.has_symmetric_pref:
-            raw = raw + jnp.einsum("g,gn->n", p.pmg_f, st.pref_wsum, precision=_HI)
-        rows.append((raw, _w("InterPodAffinity"), True, False))
-    sp_pack = None
-    if spec.spread and _on("PodTopologySpread") and SP:
-        # Upstream scoring: raw + ignored mask computed here; the extrema
-        # (over feasible & ~ignored) ride the shared stacked reduce below
-        # as an extra row with the ignored nodes pre-masked to ±inf.
-        cnts = reads[A + B : A + B + SP]
-        gval = p.gvalid[A + B : A + B + SP]
-        raw_sp = jnp.zeros(N, jnp.float32)
-        ignored = jnp.zeros(N, bool)
-        for i in range(SP):
-            contrib = cnts[i] * p.sp_w[i] + (p.sp_skew[i] - 1.0)
-            raw_sp = raw_sp + jnp.where(p.sp_scored[i], contrib, 0.0)
-            ignored = ignored | (p.sp_scored[i] & ~gval[i])
-        sp_pack = (jnp.floor(raw_sp + 0.5), ignored)
-    if rows or sp_pack is not None:
-        hi_rows = [r[0] for r in rows]
-        lo_rows = list(hi_rows)
-        if sp_pack is not None:
-            raw_sp, ignored = sp_pack
-            hi_rows.append(jnp.where(ignored, -jnp.inf, raw_sp))
-            lo_rows.append(jnp.where(ignored, jnp.inf, raw_sp))
-        hi_stack = jnp.where(feasible[None, :], jnp.stack(hi_rows), -jnp.inf)
-        lo_stack = jnp.where(feasible[None, :], jnp.stack(lo_rows), jnp.inf)
-        hi = jnp.max(hi_stack, axis=1)
-        lo = jnp.min(lo_stack, axis=1)
-        for i, (raw, wt, minmax, reverse) in enumerate(rows):
-            out = _normalize_row(raw, lo[i], hi[i], any_f, minmax, reverse)
-            total = total + wt * out
-        if sp_pack is not None:
-            raw_sp, ignored = sp_pack
-            out = spread_norm_from_extrema(
-                raw_sp, ignored, hi[-1], lo[-1], jnp.any(p.sp_scored),
-                getattr(spec, "sp_norm_f32", False),
-            )
-            total = total + _w("PodTopologySpread") * out
-    return feasible, total, any_f
-
-
-def apply_unbind_wave(
-    d: Derived, st: DevState, sb: PodSlot, choice: jax.Array, revert: jax.Array
-) -> DevState:
-    """Batched gang rollback: subtract every reverted slot's bind in ONE
-    set of elementwise updates (sb fields have leading wave axis W)."""
-    G = st.match_count.shape[0]
-    N = d.gdom_f.shape[1]
-    w = jnp.where(revert & sb.valid, 1.0, 0.0).astype(jnp.float32)  # [W]
-    oh = ((jnp.arange(N)[None, :] == choice[:, None]) & (choice[:, None] >= 0)).astype(
-        jnp.float32
-    )  # [W, N]
-    used = st.used - jnp.einsum("w,wn,wr->nr", w, oh, sb.req, precision=_HI)
-    gdom_at = jnp.einsum("gn,wn->wg", d.gdom_f, oh, precision=_HI)  # [W, G]
-    has_dom = jnp.einsum("gn,wn->wg", (d.gdom_f >= 0).astype(jnp.float32), oh, precision=_HI) > 0.5
-    dom_sel = (
-        (d.gdom_f[None] == gdom_at[:, :, None]) & has_dom[:, :, None] & (d.gdom_f >= 0)[None]
-    ).astype(jnp.float32)  # [W, G, N]
-    pmg_f = sb.pmg.astype(jnp.float32)  # [W, G]
-    match_count = st.match_count - jnp.einsum("w,wg,wgn->gn", w, pmg_f, dom_sel, precision=_HI)
-    match_total = st.match_total - jnp.einsum(
-        "w,wg->g", w, pmg_f * has_dom.astype(jnp.float32), precision=_HI
-    )
-    anti_wg, pref_wg = _pod_group_vectors(sb, G)  # [W, G] each
-    anti = st.anti_active - jnp.einsum("w,wg,wgn->gn", w, anti_wg, dom_sel, precision=_HI)
-    pref = st.pref_wsum - jnp.einsum("w,wg,wgn->gn", w, pref_wg, dom_sel, precision=_HI)
-    return DevState(
-        used=used, match_count=match_count, anti_active=anti, pref_wsum=pref,
-        match_total=match_total,
-    )

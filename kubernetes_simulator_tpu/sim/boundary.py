@@ -66,6 +66,54 @@ def _empty_pairs() -> PairArrays:
     return np.zeros(0, np.int64), np.zeros(0, np.int64)
 
 
+def apply_planes(ec, ep, st, sign: float, pods, nodes) -> None:
+    """Add (``sign`` > 0) or subtract the aggregate contribution of
+    ``pods`` bound at ``nodes`` to a host mirror's planes."""
+    du, dmc, daa, dpw = release_delta(ec, ep, pods, nodes)
+    if sign > 0:
+        st.used += du
+        st.match_count += dmc
+        st.anti_active += daa
+        st.pref_wsum += dpw
+    else:
+        st.used -= du
+        st.match_count -= dmc
+        st.anti_active -= daa
+        st.pref_wsum -= dpw
+
+
+def charge_first_rejects(fw: SchedulerFramework, st, pods, tel) -> None:
+    """First-reject attribution (telemetry ``series``), the one rule of
+    the boundary mirror's chunk fold and of the plain replay's: each
+    failed valid slot in ``pods`` is charged, node by node, to the first
+    plugin of the CPU framework's filter chain that rejects the node on
+    ``st``. A slot whose mask is not empty charges nothing: the pod itself
+    was feasible and a gang revert took its bind — the CPU engine records
+    no attempt for those either."""
+    for p in pods:
+        rc: Dict[str, int] = {}
+        if not fw.feasible_mask(st, int(p), reject_counts=rc).any():
+            tel.rejection(int(p), rc)
+
+
+def fold_answers(fw: SchedulerFramework, st, rows, choices, tel) -> None:
+    """Fold one chunk's final answers into the plain replay's mirror
+    ``st`` in slot order, charging each failed valid slot on the state its
+    turn found (``charge_first_rejects``). Exact at any wave width but for
+    a slot that stands behind a rolled-back gang member of its own wave:
+    that member's tentative bind is in no answer."""
+    v = rows >= 0
+    ids, nd = rows[v], np.asarray(choices).reshape(rows.shape)[v]
+    lo = 0
+    for k in (*np.nonzero(nd < 0)[0].tolist(), ids.size):
+        if k > lo:
+            apply_planes(fw.ec, fw.pods, st, 1.0, ids[lo:k], nd[lo:k])
+            st.bound[ids[lo:k]] = nd[lo:k]
+        if k < ids.size:
+            charge_first_rejects(fw, st, ids[k : k + 1], tel)
+        lo = k + 1
+
+
 class BoundaryOps:
     """Host bookkeeping + boundary passes shared by the greedy anchor and
     the device engine. All semantics here are THE semantics — the two
@@ -307,18 +355,7 @@ class BoundaryOps:
     # -- plane folds (eager or logged) --------------------------------------
 
     def _apply_planes(self, sign: float, pods: np.ndarray, nodes: np.ndarray):
-        du, dmc, daa, dpw = release_delta(self.ec, self.ep, pods, nodes)
-        st = self.st
-        if sign > 0:
-            st.used += du
-            st.match_count += dmc
-            st.anti_active += daa
-            st.pref_wsum += dpw
-        else:
-            st.used -= du
-            st.match_count -= dmc
-            st.anti_active -= daa
-            st.pref_wsum -= dpw
+        apply_planes(self.ec, self.ep, self.st, sign, pods, nodes)
         self.plane_folds += 1
 
     def _plane_op(self, key: tuple, sign: float, pods, nodes) -> None:
@@ -390,14 +427,9 @@ class BoundaryOps:
             # First-reject attribution for the chunk's failed slots,
             # computed against the pre-chunk mirror state (exact at
             # W=1/C=1 where a chunk IS one slot; chunk-granular
-            # otherwise). A failed slot whose mirror mask is non-empty is
-            # a gang revert (the pod itself was feasible) — the CPU
-            # engine records no attempt for those either.
+            # otherwise).
             self.flush_planes()  # attribution reads the count planes
-            for p in ids[~placed]:
-                rc: Dict[str, int] = {}
-                if not self.fw.feasible_mask(self.st, int(p), reject_counts=rc).any():
-                    tel.rejection(int(p), rc)
+            charge_first_rejects(self.fw, self.st, ids[~placed], tel)
         if pid.size:
             self._plane_op((ci, 1), 1.0, pid, pnd)
             self.st.bound[pid] = pnd

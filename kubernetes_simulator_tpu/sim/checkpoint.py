@@ -5,8 +5,8 @@ doubles as a what-if fork point (snapshot → perturb → fan out).
 Plain ``.npz`` — the state is four dense tensors plus a cursor; orbax would
 add dependency weight for no benefit at this size. Count tensors are stored
 in DOMAIN space ``[G, D]`` (the canonical semantic form — scenario-
-independent), and converted to/from the device engine's node space
-``[G, N]`` at save/load (see ops.tpu.DevState).
+independent); the device carry converts to and from it
+(``ops.tpu3.DevState3.to_host`` / ``from_host``).
 """
 
 from __future__ import annotations
@@ -78,32 +78,3 @@ class ReplayCheckpoint:
                 released=z["released"] if "released" in z.files else None,
                 boundary=bd or None,
             )
-
-
-def state_to_checkpoint(
-    state, gdom: np.ndarray, D: int, cursor: int, outs: List[np.ndarray]
-) -> ReplayCheckpoint:
-    from ..ops.tpu import node_space_to_domain
-
-    return ReplayCheckpoint(
-        chunk_cursor=cursor,
-        used=np.asarray(state.used),
-        match_count=node_space_to_domain(np.asarray(state.match_count), gdom, D),
-        anti_active=node_space_to_domain(np.asarray(state.anti_active), gdom, D),
-        pref_wsum=node_space_to_domain(np.asarray(state.pref_wsum), gdom, D),
-        outs=[np.asarray(o) for o in outs],
-    )
-
-
-def checkpoint_to_state(ckpt: ReplayCheckpoint, gdom: np.ndarray):
-    import jax.numpy as jnp
-
-    from ..ops.tpu import DevState, domain_to_node_space
-
-    return DevState(
-        used=jnp.asarray(ckpt.used),
-        match_count=jnp.asarray(domain_to_node_space(ckpt.match_count, gdom)),
-        anti_active=jnp.asarray(domain_to_node_space(ckpt.anti_active, gdom)),
-        pref_wsum=jnp.asarray(domain_to_node_space(ckpt.pref_wsum, gdom)),
-        match_total=jnp.asarray(ckpt.match_count.sum(axis=1).astype(np.float32)),
-    )

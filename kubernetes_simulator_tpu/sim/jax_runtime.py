@@ -208,219 +208,6 @@ def _spread_w_table(ec: EncodedCluster) -> Tuple[float, ...]:
     return tuple(float(x) for x in w)
 
 
-def spec_plugin_names(spec: StepSpec) -> Tuple[str, ...]:
-    """Active Filter plugins in evaluation order — the key order for the
-    in-scan rejection counters. Must stay aligned with both
-    :func:`eval_pod`'s mask chain and the CPU ``make_plugins`` default
-    order (plugins.builtin.PLUGIN_FACTORIES)."""
-    names = []
-    if spec.fit:
-        names.append("NodeResourcesFit")
-    if spec.taints:
-        names.append("TaintToleration")
-    if spec.node_affinity:
-        names.append("NodeAffinity")
-    if spec.interpod:
-        names.append("InterPodAffinity")
-    if spec.spread:
-        names.append("PodTopologySpread")
-    return tuple(names)
-
-
-def eval_pod(
-    dc: T.DevCluster,
-    d: T.Derived,
-    st: T.DevState,
-    s: T.PodSlot,
-    spec: StepSpec,
-    want_masks: bool = False,
-):
-    """Fused Filter + Score for one slot against all nodes → (feasible [N],
-    scores [N]). Mirrors SchedulerFramework.feasible_mask/score_nodes.
-    ``want_masks=True`` (telemetry instrumentation) additionally returns
-    the ordered per-plugin masks for first-reject attribution."""
-    N = dc.allocatable.shape[0]
-    masks = []
-    feasible = jnp.ones(N, dtype=bool)
-    if spec.fit:
-        m = T.fit_mask(dc, st, s)
-        masks.append(m)
-        feasible = feasible & m
-    if spec.taints:
-        m = T.taint_mask(dc, s)
-        masks.append(m)
-        feasible = feasible & m
-    if spec.node_affinity:
-        m = T.node_affinity_mask(d, s)
-        masks.append(m)
-        feasible = feasible & m
-    if spec.interpod:
-        m = T.interpod_filter_mask(d, st, s)
-        masks.append(m)
-        feasible = feasible & m
-    if spec.spread:
-        m = T.spread_filter_mask(d, st, s)
-        masks.append(m)
-        feasible = feasible & m
-
-    w = dict(spec.weights)
-    total = jnp.zeros(N, dtype=jnp.float32)
-    if spec.fit and w.get("NodeResourcesFit", 1.0) != 0:
-        rw = np.asarray(spec.resource_weights, dtype=np.float32)  # static
-        if spec.fit_strategy == "LeastAllocated":
-            raw = T.least_allocated_score(dc, st, s, rw)
-        elif spec.fit_strategy == "MostAllocated":
-            raw = T.most_allocated_score(dc, st, s, rw)
-        else:
-            raw = T.requested_to_capacity_ratio_score(
-                dc, st, s, rw, spec.shape_x, spec.shape_y
-            )
-        total = total + w.get("NodeResourcesFit", 1.0) * raw
-    if spec.taints and spec.taint_score and w.get("TaintToleration", 1.0) != 0:
-        raw = T.taint_prefer_count(dc, s)
-        total = total + w.get("TaintToleration", 1.0) * T.normalize_max(raw, feasible, reverse=True)
-    if spec.node_affinity and w.get("NodeAffinity", 1.0) != 0:
-        raw = T.node_affinity_score(d, s)
-        total = total + w.get("NodeAffinity", 1.0) * T.normalize_max(raw, feasible)
-    if spec.interpod and w.get("InterPodAffinity", 1.0) != 0:
-        raw = T.interpod_score(d, st, s, spec.has_symmetric_pref)
-        total = total + w.get("InterPodAffinity", 1.0) * T.normalize_min_max(raw, feasible)
-    if spec.spread and w.get("PodTopologySpread", 1.0) != 0:
-        raw, ignored, any_sp = T.spread_score_upstream(
-            d, st, s, T._padded_w_table(spec.sp_w_g, d.gdom_f.shape[0])
-        )
-        total = total + w.get("PodTopologySpread", 1.0) * T.spread_upstream_normalize(
-            raw, ignored, feasible, any_sp, spec.sp_norm_f32
-        )
-    if want_masks:
-        return feasible, total, masks
-    return feasible, total
-
-
-def make_wave_step(
-    dc: T.DevCluster, d: T.Derived, wave_width: int, spec: StepSpec, wvec=None
-):
-    """Build the scan body: one wave = W sequential slot placements +
-    wave-boundary gang commit (SURVEY.md §3.3 Permit-as-masked-commit).
-
-    ``wvec``: optional traced policy vector (ops.tpu.POLICY_COLS) replacing
-    the static score weights — the round 9 tuner's population axis.
-
-    ``dc``/``d`` are loop invariants CLOSED OVER, not carried — keeping them
-    out of the scan carry stops XLA copying ~10s of MB per iteration (the
-    single biggest perf bug in the earlier [G, D]-carry design).
-
-    The per-slot evaluation is the fused path (ops.tpu.build_wave_pre +
-    eval_pod_fused): all state-independent tensors are computed for the
-    whole wave in one batched shot, and each slot's sequential chain is
-    ~12 non-fusable ops instead of ~30 — bit-identical to :func:`eval_pod`
-    (pinned by the parity suites)."""
-
-    def wave_step(st: T.DevState, slot_batch: T.PodSlot):
-        with stage("ksim.reads"):
-            pre = T.build_wave_pre(dc, d, slot_batch, spec)
-            widths = T.wave_widths(slot_batch, spec)
-        choices, placeds = [], []
-        for wslot in range(wave_width):
-            s = jax.tree.map(lambda a: a[wslot], slot_batch)
-            p = jax.tree.map(lambda a: a[wslot], pre)
-            with stage("ksim.filter_score"):
-                feasible, scores, any_f = T.eval_pod_fused(
-                    dc, d, st, s, p, spec, widths, wvec=wvec
-                )
-            with stage("ksim.select"):
-                node, _ = T.select_node(scores, feasible)  # XLA CSEs the any()
-                placed = any_f & s.valid
-            with stage("ksim.commit"):
-                st = T.apply_binding(d, st, s, node, placed)
-            choices.append(node)
-            placeds.append(placed)
-        with stage("ksim.commit"):
-            choice = jnp.stack(choices)  # [W]
-            placed = jnp.stack(placeds)  # [W]
-            if spec.has_gangs:
-                groups = slot_batch.group  # [W]
-                same = (groups[:, None] == groups[None, :]) & (groups[:, None] >= 0)
-                fail = jnp.any(same & ~placed[None, :], axis=1)  # gang all-or-nothing
-                revert = placed & fail
-                st = T.apply_unbind_wave(d, st, slot_batch, choice, revert)
-                final = jnp.where(placed & ~fail, choice, PAD).astype(jnp.int32)
-            else:
-                final = jnp.where(placed, choice, PAD).astype(jnp.int32)
-        return st, final
-
-    return wave_step
-
-
-def make_chunk_fn(wave_width: int, spec: StepSpec):
-    """jit-compiled: (DevCluster, DevState, slots[C, W]) → (DevState,
-    choices[C, W]). Derived tensors are rebuilt inside jit from the cluster
-    tensors, so perturbed clusters reuse the same executable. The state
-    buffers are donated — the carry updates in place across chunk calls."""
-
-    def chunk_fn(dc: T.DevCluster, state: T.DevState, slots: T.PodSlot):
-        with stage("ksim.derive"):
-            d = T.Derived.build(dc)
-        wave_step = make_wave_step(dc, d, wave_width, spec)
-        state, choices = jax.lax.scan(wave_step, state, slots)
-        return state, choices
-
-    return jax.jit(chunk_fn, donate_argnums=(1,))
-
-
-def make_wave_step_rej(dc: T.DevCluster, d: T.Derived, wave_width: int, spec: StepSpec):
-    """Instrumented v2 wave step (telemetry ``series``+ on the plain
-    path): same placements as :func:`make_wave_step` — via the reference
-    :func:`eval_pod`, bit-identical to the fused path by the parity
-    suites — plus a carried [K] i32 vector of in-scan first-reject
-    counts (ops.tpu.first_reject_counts) in ``spec_plugin_names`` order.
-    Only fully-failed VALID slots charge counts; gang-reverted members
-    (individually feasible, rolled back by Permit) charge nothing —
-    matching the CPU engine, which records no attempt for them."""
-
-    def wave_step(carry, slot_batch: T.PodSlot):
-        st, rej = carry
-        choices, placeds = [], []
-        for wslot in range(wave_width):
-            s = jax.tree.map(lambda a: a[wslot], slot_batch)
-            feasible, scores, masks = eval_pod(dc, d, st, s, spec, want_masks=True)
-            node, placed_any = T.select_node(scores, feasible)
-            placed = placed_any & s.valid
-            rej = rej + T.first_reject_counts(masks, (~placed_any) & s.valid)
-            st = T.apply_binding(d, st, s, node, placed)
-            choices.append(node)
-            placeds.append(placed)
-        choice = jnp.stack(choices)  # [W]
-        placed = jnp.stack(placeds)  # [W]
-        if spec.has_gangs:
-            groups = slot_batch.group  # [W]
-            same = (groups[:, None] == groups[None, :]) & (groups[:, None] >= 0)
-            fail = jnp.any(same & ~placed[None, :], axis=1)
-            revert = placed & fail
-            st = T.apply_unbind_wave(d, st, slot_batch, choice, revert)
-            final = jnp.where(placed & ~fail, choice, PAD).astype(jnp.int32)
-        else:
-            final = jnp.where(placed, choice, PAD).astype(jnp.int32)
-        return (st, rej), final
-
-    return wave_step
-
-
-def make_chunk_fn_rej(wave_width: int, spec: StepSpec):
-    """jit: (DevCluster, DevState, rej [K] i32, slots [C, W]) → (DevState,
-    rej, choices[C, W]) — :func:`make_chunk_fn` with the rejection counter
-    threaded through the scan carry and fetched once per replay, never per
-    pod. Built lazily by ``replay()`` only at telemetry ``series``+."""
-
-    def chunk_fn(dc: T.DevCluster, state: T.DevState, rej, slots: T.PodSlot):
-        d = T.Derived.build(dc)
-        wave_step = make_wave_step_rej(dc, d, wave_width, spec)
-        (state, rej), choices = jax.lax.scan(wave_step, (state, rej), slots)
-        return state, rej, choices
-
-    return jax.jit(chunk_fn, donate_argnums=(1, 2))
-
-
 def replicated_resident_bytes(
     ec: EncodedCluster, pods: EncodedPods, pods_resident: bool = True
 ) -> int:
@@ -811,7 +598,6 @@ class JaxReplayEngine:
         config: Optional[FrameworkConfig] = None,
         wave_width: int = 8,
         chunk_waves: int = 2048,
-        engine: str = "v3",
         dmax_coarse: int = 128,
         preemption=False,
         completions: Optional[bool] = None,
@@ -823,11 +609,10 @@ class JaxReplayEngine:
         paged: bool = False,
         flight_recorder=None,
     ):
-        """``engine``: "v3" (domain-space state, wave-deferred commits — the
-        fast path) or "v2" (node-space planes; also the whatif fallback when
-        label perturbations change topology domains). ``preemption``:
+        """The device program is :func:`ops.tpu3.make_wave_step3`
+        (domain-space state, wave-deferred commits). ``preemption``:
         ``"tier"``/``True`` = the greedy engines' in-scan tier preemption
-        (sim.greedy docstring), v3 only; ``"kube"`` (round 5) = the EXACT
+        (sim.greedy docstring); ``"kube"`` (round 5) = the EXACT
         kube minimal-victims PostFilter run at chunk boundaries through the
         retry buffer (sim.boundary docstring — the device program is
         unchanged; victims/binds land on the carry as rank-1 plane deltas).
@@ -864,10 +649,12 @@ class JaxReplayEngine:
         tests/test_double_buffer.py).
         ``telemetry``: granularity knob (str | sim.telemetry.TelemetryConfig
         | None → "summary"). "summary" never changes any device program
-        (latency bookkeeping + phase timers only); "series" adds rejection
-        attribution — through the boundary mirror in retry/kube modes,
-        via an instrumented reference (v2) chunk program on the plain
-        path — plus boundary-sampled depth series; "timeline" adds the
+        (latency bookkeeping + phase timers only), and neither does any
+        other: "series" adds rejection attribution on the host, from the
+        CPU framework's own filter chain over a mirror of the program's
+        answers (sim.boundary.charge_first_rejects; the boundary mirror in
+        retry/kube modes, one kept beside the chunk loop on the plain
+        path), plus boundary-sampled depth series; "timeline" adds the
         event log for the Chrome-trace export. "off" disables everything
         (``ReplayResult.telemetry`` is None).
         ``flight_recorder`` (round 16): None (default, off), a JSONL path,
@@ -880,8 +667,6 @@ class JaxReplayEngine:
         from .greedy import normalize_preemption
 
         mode = normalize_preemption(preemption)
-        if mode == "tier" and engine != "v3":
-            raise ValueError("device tier preemption requires engine='v3'")
         if mode == "tier" and retry_buffer:
             raise ValueError(
                 "retry_buffer is not supported with tier preemption"
@@ -905,7 +690,9 @@ class JaxReplayEngine:
         self.spec = StepSpec.from_config(ec, config, pods)
         self._config = config
         self.chunk_waves = chunk_waves
-        self.engine = engine
+        # The one device engine: what the flight recorder's meta and the
+        # JSONL rows stamp.
+        self.engine = "v3"
         self.dmax_coarse = dmax_coarse
         # self.preemption stays the TIER flag (the in-scan feature the
         # compiled program and the what-if collect paths key off).
@@ -937,7 +724,7 @@ class JaxReplayEngine:
         budget = os.environ.get("KSIM_MAX_REPLICATED_BYTES")
         if budget:
             est = replicated_resident_bytes(
-                ec, pods, pods_resident=(engine == "v3" and not self.paged)
+                ec, pods, pods_resident=not self.paged
             )
             if est > int(budget):
                 raise ValueError(
@@ -953,22 +740,19 @@ class JaxReplayEngine:
         if wave_width == "auto":
             wave_width = 8
         self.wave_width = wave_width
-        if engine == "v3":
-            self.static3 = V3.V3Static.build(
-                ec, pods, self.spec, dmax_coarse, preemption=self.preemption,
-                wave_width=wave_width,
-            )
-            self.shared3 = V3.Shared3.build(ec, self.static3)
-            self.chunk_fn = make_chunk_fn3_src(
-                self.static3, self.shared3, rep_slots_for(self.static3, pods),
-                wave_width, self.spec,
-            )
-        else:
-            self.chunk_fn = make_chunk_fn(wave_width, self.spec)
-        # A pod group wider than the wave runs on the v3 engine's plain
-        # arrivals-only replay (sim.waves.WIDE_GANG_UNSUPPORTED).
+        self.static3 = V3.V3Static.build(
+            ec, pods, self.spec, dmax_coarse, preemption=self.preemption,
+            wave_width=wave_width,
+        )
+        self.shared3 = V3.Shared3.build(ec, self.static3)
+        self.chunk_fn = make_chunk_fn3_src(
+            self.static3, self.shared3, rep_slots_for(self.static3, pods),
+            wave_width, self.spec,
+        )
+        # A pod group wider than the wave runs on the plain arrivals-only
+        # replay (sim.waves.WIDE_GANG_UNSUPPORTED).
         refuse_wide_gangs(
-            wave_width, widest_gang(pods), v2_engine=engine != "v3",
+            wave_width, widest_gang(pods),
             retry_buffer=bool(self.retry_buffer), kube_preemption=self.kube,
         )
         self.waves = pack_waves(
@@ -977,63 +761,29 @@ class JaxReplayEngine:
         )
         # Slot data lives on device once; chunks gather rows inside jit
         # (ops.tpu.SlotSource) — only wave indices cross the host boundary.
-        # v3-only: the v2 fallback engine still host-gathers, so the device
-        # copies would be dead HBM weight there. Paged mode keeps slots on
-        # host and streams per-chunk pages instead (SlotSource.page).
-        self._slot_src = (
-            T.SlotSource.build(pods)
-            if engine == "v3" and not self.paged
-            else None
-        )
+        # Paged mode keeps slots on host and streams per-chunk pages
+        # instead (SlotSource.page).
+        self._slot_src = None if self.paged else T.SlotSource.build(pods)
         self._extra_src = (
-            V3.ExtraSource.build(self.static3, pods.num_pods)
-            if engine == "v3" and not self.paged
-            else None
+            None
+            if self.paged
+            else V3.ExtraSource.build(self.static3, pods.num_pods)
         )
 
     @property
     def _wide_gangs(self) -> bool:
         """The trace has a pod group wider than the wave: the state carries
         its transaction (``ops.tpu3.GangTxn``)."""
-        return self.engine == "v3" and self.static3.has_wide_gangs
+        return self.static3.has_wide_gangs
 
-    def _to_dev_state_v2(self, used, mc, aa, pw, mt) -> T.DevState:
-        """Device v2 (node-space) state/delta from host planes."""
-        return T.DevState(
-            used=jnp.asarray(used),
-            match_count=jnp.asarray(mc),
-            anti_active=jnp.asarray(aa),
-            pref_wsum=jnp.asarray(pw),
-            match_total=jnp.asarray(mt),
-        )
-
-    def _v2_to_host(self, state: T.DevState):
-        """(used, match_count, anti_active, pref_wsum) of a v2 carry in the
-        host's domain-space layout: ``DevState3.to_host``'s twin."""
-        return (np.asarray(state.used),) + tuple(
-            T.node_space_to_domain(np.asarray(p), self._gdom, self._Dhost)
-            for p in (state.match_count, state.anti_active, state.pref_wsum)
-        )
-
-    def _init_dev_state(self, force_v2: bool = False):
+    def _init_dev_state(self):
         from ..ops import tpu3 as V3
-        from ..ops.cpu import _group_dom_per_node
 
         host = init_state(self.ec, self.pods)  # applies pre-bound pods
-        gdom = _group_dom_per_node(self.ec)
-        self._gdom = gdom
         self._Dhost = host.match_count.shape[1]
-        if self.engine == "v3" and not force_v2:
-            return V3.DevState3.from_host(
-                host.used, host.match_count, host.anti_active, host.pref_wsum,
-                self.ec, self.static3, ep=self.pods,
-            )
-        return self._to_dev_state_v2(
-            host.used,
-            T.domain_to_node_space(host.match_count, gdom),
-            T.domain_to_node_space(host.anti_active, gdom),
-            T.domain_to_node_space(host.pref_wsum, gdom),
-            host.match_count.sum(axis=1).astype(np.float32),
+        return V3.DevState3.from_host(
+            host.used, host.match_count, host.anti_active, host.pref_wsum,
+            self.ec, self.static3, ep=self.pods,
         )
 
     def _open_recorder(self):
@@ -1058,8 +808,7 @@ class JaxReplayEngine:
             "chunk_waves": int(self.chunk_waves),
             "resident_bytes": int(
                 replicated_resident_bytes(
-                    self.ec, self.pods,
-                    pods_resident=(self.engine == "v3" and not self.paged),
+                    self.ec, self.pods, pods_resident=not self.paged
                 )
             ),
         }
@@ -1068,22 +817,14 @@ class JaxReplayEngine:
 
     def _save_checkpoint(self, state, cursor: int, all_choices, path: str,
                          released=None, boundary=None) -> None:
-        from .checkpoint import ReplayCheckpoint, state_to_checkpoint
+        from .checkpoint import ReplayCheckpoint
 
-        if self.engine == "v3":
-            used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
-            ReplayCheckpoint(
-                used=used, match_count=mc, anti_active=aa, pref_wsum=pw,
-                chunk_cursor=cursor, outs=[np.asarray(o) for o in all_choices],
-                released=released, boundary=boundary,
-            ).save(path)
-        else:
-            ck = state_to_checkpoint(
-                state, self._gdom, self._Dhost, cursor, all_choices,
-            )
-            ck.released = released
-            ck.boundary = boundary
-            ck.save(path)
+        used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
+        ReplayCheckpoint(
+            used=used, match_count=mc, anti_active=aa, pref_wsum=pw,
+            chunk_cursor=cursor, outs=[np.asarray(o) for o in all_choices],
+            released=released, boundary=boundary,
+        ).save(path)
 
     def _preemption_walk(self, idx: np.ndarray, finals: np.ndarray,
                          ev_node: np.ndarray, ev_tier: np.ndarray):
@@ -1097,64 +838,49 @@ class JaxReplayEngine:
         placed = int((assignments[scheduled] >= 0).sum())
         return assignments, placed
 
-    def _apply_release(
-        self, state, rel_idx: np.ndarray, rel_nodes: np.ndarray,
-        as_v2: bool = False,
-    ):
+    def _apply_release(self, state, rel_idx: np.ndarray, rel_nodes: np.ndarray):
         """Subtract the completed pods' aggregate contribution (resources +
         count planes) from the carried device state — the device twin of
-        models.state.unbind, applied at a chunk boundary. ``as_v2``: the
-        caller is carrying a node-space DevState even though the engine is
-        v3 (the instrumented telemetry program)."""
+        models.state.unbind, applied at a chunk boundary."""
         from ..models.state import release_delta
         from ..ops import tpu3 as V3
 
         used_d, mc_d, aa_d, pw_d = release_delta(
             self.ec, self.pods, rel_idx, rel_nodes
         )
-        if self.engine == "v3" and not as_v2:
-            delta = V3.DevState3.from_host(
-                used_d, mc_d, aa_d, pw_d, self.ec, self.static3
-            )
-            if self.preemption and len(rel_idx):
-                # Tier planes drop completed pods too (pod tiers are
-                # static, so releases ARE attributable — the former
-                # exclusivity only held for evicted pods, which never
-                # release because their assignment is PAD by walk time).
-                # NON-GANG ONLY: the tier planes never accumulate gang
-                # pods (gangs are not evictable — the wave step and
-                # from_host both gate on group_id == PAD), so a gang
-                # completion must not be subtracted from them either.
-                st3 = self.static3
-                ng = self.pods.group_id[rel_idx] == PAD
-                ng_idx = np.asarray(rel_idx)[ng]
-                ng_nodes = np.asarray(rel_nodes)[ng]
-                R, N = self.ec.num_resources, self.ec.num_nodes
-                ut = np.zeros((st3.Tt, R, N), np.float32)
-                nt = np.zeros((st3.Tt, N), np.float32)
-                if ng_idx.size:
-                    t_arr = st3.pod_tier[ng_idx]
-                    np.add.at(nt, (t_arr, ng_nodes), 1.0)
-                    np.add.at(
-                        ut,
-                        (
-                            t_arr[:, None],
-                            np.arange(R)[None, :],
-                            ng_nodes[:, None],
-                        ),
-                        self.pods.requests[ng_idx],
-                    )
-                delta = delta._replace(
-                    used_tier=jnp.asarray(ut), npods_tier=jnp.asarray(nt)
+        delta = V3.DevState3.from_host(
+            used_d, mc_d, aa_d, pw_d, self.ec, self.static3
+        )
+        if self.preemption and len(rel_idx):
+            # Tier planes drop completed pods too (pod tiers are
+            # static, so releases ARE attributable — the former
+            # exclusivity only held for evicted pods, which never
+            # release because their assignment is PAD by walk time).
+            # NON-GANG ONLY: the tier planes never accumulate gang
+            # pods (gangs are not evictable — the wave step and
+            # from_host both gate on group_id == PAD), so a gang
+            # completion must not be subtracted from them either.
+            st3 = self.static3
+            ng = self.pods.group_id[rel_idx] == PAD
+            ng_idx = np.asarray(rel_idx)[ng]
+            ng_nodes = np.asarray(rel_nodes)[ng]
+            R, N = self.ec.num_resources, self.ec.num_nodes
+            ut = np.zeros((st3.Tt, R, N), np.float32)
+            nt = np.zeros((st3.Tt, N), np.float32)
+            if ng_idx.size:
+                t_arr = st3.pod_tier[ng_idx]
+                np.add.at(nt, (t_arr, ng_nodes), 1.0)
+                np.add.at(
+                    ut,
+                    (
+                        t_arr[:, None],
+                        np.arange(R)[None, :],
+                        ng_nodes[:, None],
+                    ),
+                    self.pods.requests[ng_idx],
                 )
-        else:
-            gdom = self._gdom
-            delta = self._to_dev_state_v2(
-                used_d,
-                T.domain_to_node_space(mc_d, gdom),
-                T.domain_to_node_space(aa_d, gdom),
-                T.domain_to_node_space(pw_d, gdom),
-                mc_d.sum(axis=1),
+            delta = delta._replace(
+                used_tier=jnp.asarray(ut), npods_tier=jnp.asarray(nt)
             )
         return self._donated_subtract(state, delta)
 
@@ -1178,9 +904,7 @@ class JaxReplayEngine:
     def _program_forms(self) -> dict:
         """The static forms the resident chunk program was built with, as
         the collector's keywords: the in-wave usage corrections and the
-        select (ops.tpu3). The v2 step binds per pod and has neither."""
-        if self.engine != "v3":
-            return {}
+        select (ops.tpu3)."""
         from ..ops import tpu3 as V3
 
         return {
@@ -1225,31 +949,18 @@ class JaxReplayEngine:
         du, dmc, daa, dpw = release_delta(self.ec, self.pods, s_idx, s_nodes)
         au, amc, aaa, apw = release_delta(self.ec, self.pods, a_idx, a_nodes)
         net = (du - au, dmc - amc, daa - aaa, dpw - apw)
-        if self.engine == "v3":
-            delta = V3.DevState3.from_host(*net, self.ec, self.static3)
-        else:
-            gdom = self._gdom
-            delta = self._to_dev_state_v2(
-                net[0],
-                T.domain_to_node_space(net[1], gdom),
-                T.domain_to_node_space(net[2], gdom),
-                T.domain_to_node_space(net[3], gdom),
-                net[1].sum(axis=1),
-            )
+        delta = V3.DevState3.from_host(*net, self.ec, self.static3)
         return self._donated_subtract(state, delta)
 
     def _state_from_checkpoint(self, ck):
         """Device carry from a ReplayCheckpoint (shared by the plain and
         boundary resume paths)."""
         from ..ops import tpu3 as V3
-        from .checkpoint import checkpoint_to_state
 
-        if self.engine == "v3":
-            return V3.DevState3.from_host(
-                ck.used, ck.match_count, ck.anti_active, ck.pref_wsum,
-                self.ec, self.static3,
-            )
-        return checkpoint_to_state(ck, self._gdom)
+        return V3.DevState3.from_host(
+            ck.used, ck.match_count, ck.anti_active, ck.pref_wsum,
+            self.ec, self.static3,
+        )
 
     def _replay_boundary(
         self, _tick, node_events=None, chunk_req: Optional[int] = None,
@@ -1370,11 +1081,9 @@ class JaxReplayEngine:
                             )
                     pending_events = pending_events[ev_applied:]
             wave_times = self._wave_start_times(idx)
-            idx_chunks = (
-                [jnp.asarray(idx[c0 : c0 + C]) for c0 in range(0, idx.shape[0], C)]
-                if self.engine == "v3"
-                else None
-            )
+            idx_chunks = [
+                jnp.asarray(idx[c0 : c0 + C]) for c0 in range(0, idx.shape[0], C)
+            ]
             self._register_programs(state, idx_chunks, True)
             # Scalar boundary summary: count of failed NON-GANG slots (the only
             # failures that enter the retry buffer — gang failures never do).
@@ -1531,23 +1240,12 @@ class JaxReplayEngine:
                             binds,
                         )
                 with _tick("dispatch"), _tick.mark(f"chunk:{ci}"):
-                    if self.engine == "v3":
-                        state, choices = self.chunk_fn(
-                            self.dc, state, self._slot_src, self._extra_src,
-                            idx_chunks[ci],
-                        )
-                    else:
-                        state, choices = self.chunk_fn(
-                            self.dc, state,
-                            T.gather_slots(self.pods, idx[c0 : c0 + C]),
-                        )
-                if lazy:
-                    ix_dev = (
-                        idx_chunks[ci]
-                        if idx_chunks is not None
-                        else jnp.asarray(idx[c0 : c0 + C])
+                    state, choices = self.chunk_fn(
+                        self.dc, state, self._slot_src, self._extra_src,
+                        idx_chunks[ci],
                     )
-                    nf_d = self._bfail_fn(choices, ix_dev, ng_dev)
+                if lazy:
+                    nf_d = self._bfail_fn(choices, idx_chunks[ci], ng_dev)
                     if hasattr(choices, "copy_to_host_async"):
                         choices.copy_to_host_async()
                     # Quiet previous chunk: fold it now — its D2H copy was
@@ -1657,10 +1355,7 @@ class JaxReplayEngine:
             to_schedule = int((idx >= 0).sum())
             assignments = bops.assignments
             placed = bops.placed_total
-            if self.engine == "v3":
-                used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
-            else:
-                used, mc, aa, pw = self._v2_to_host(state)
+            used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
             util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
             pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
             frag = fragmentation_gauges(
@@ -1744,7 +1439,7 @@ class JaxReplayEngine:
         _tick = make_span()
         n, self._replay_calls = self._replay_calls, self._replay_calls + 1
         with _tick.mark(f"replay:{n}"):
-            from .checkpoint import ReplayCheckpoint, checkpoint_to_state, state_to_checkpoint
+            from .checkpoint import ReplayCheckpoint
 
             validate_node_events(node_events, self.ec.num_nodes)
             if self.preemption and (checkpoint_path or resume):
@@ -1780,7 +1475,6 @@ class JaxReplayEngine:
                 )
             if (
                 node_events
-                and self.engine == "v3"
                 and (self.static3.mc_h_bf16 or self.static3.anti_h_bf16)
                 and any(e.kind == "capacity_scale" for e in node_events)
             ):
@@ -1832,52 +1526,43 @@ class JaxReplayEngine:
             rec, rec_own = self._open_recorder()
             _tick.timers = getattr(tel if tel is not None else rec, "phases", None)
             with _tick("stage"):
-                # In-scan rejection attribution (series+): thread a [K] i32 reject
-                # counter through the scan carry via the instrumented reference
-                # chunk program — one extra fetch per REPLAY, never per pod. The
-                # default "summary" granularity takes none of these branches and
-                # runs the exact same device program as before.
-                use_rej = tel is not None and tel.cfg.want_series
-                if use_rej and self._wide_gangs:
+                # First-reject attribution (series+) happens on the host, on a
+                # mirror of the program's answers kept beside the chunk loop
+                # (sim.boundary.fold_answers: the boundary path's rule). The
+                # device program is the one every granularity runs; series
+                # costs one blocking fetch per chunk.
+                attribute = tel is not None and tel.cfg.want_series
+                if attribute and self._wide_gangs:
                     log.info(
                         "telemetry: rejection attribution is not available with "
-                        "a pod group wider than the wave (the instrumented "
-                        "program carries no transaction) — latency/phase "
-                        "telemetry still collected"
+                        "a pod group wider than the wave (a member's answer is "
+                        "final only at the end of its group's transaction) — "
+                        "latency/phase telemetry still collected"
                     )
-                    use_rej = False
-                if use_rej and self.preemption:
+                    attribute = False
+                if attribute and self.preemption:
                     log.info(
                         "telemetry: rejection attribution is not available with "
-                        "in-scan tier preemption (the instrumented program has no "
-                        "tier planes) — latency/phase telemetry still collected"
+                        "in-scan tier preemption (the host mirror cannot follow "
+                        "the scan's evictions) — latency/phase telemetry still "
+                        "collected"
                     )
-                    use_rej = False
-                if use_rej and (checkpoint_path or resume):
+                    attribute = False
+                if attribute and (checkpoint_path or resume):
                     log.info(
                         "telemetry: rejection attribution is disabled under "
-                        "checkpoint/resume (the instrumented carry is not part of "
+                        "checkpoint/resume (the host mirror is not part of "
                         "checkpoints) — latency/phase telemetry still collected"
                     )
-                    use_rej = False
-                rej_dev = None
-                if use_rej:
-                    if self.engine == "v3":
-                        log.info(
-                            "telemetry series: plain v3 replay uses the reference "
-                            "(v2) chunk program for in-scan rejection attribution "
-                            "— placements are bit-identical (parity-pinned), "
-                            "throughput is the v2 envelope"
-                        )
-                    if not hasattr(self, "_chunk_fn_rej"):
-                        self._chunk_fn_rej = make_chunk_fn_rej(
-                            self.wave_width, self.spec
-                        )
-                    rej_dev = jnp.zeros(
-                        len(spec_plugin_names(self.spec)), jnp.int32
-                    )
+                    attribute = False
+                if attribute:
+                    from ..framework.framework import SchedulerFramework
+                    from .boundary import apply_planes, fold_answers
 
-                state = self._init_dev_state(force_v2=use_rej)
+                    fw = SchedulerFramework(self.ec, self.pods, self._config)
+                    mirror = init_state(self.ec, self.pods)
+
+                state = self._init_dev_state()
                 all_choices = []
                 start_chunk = 0
                 if resume and checkpoint_path:
@@ -1907,11 +1592,11 @@ class JaxReplayEngine:
                 )
                 wave_times = (
                     self._wave_start_times(idx)
-                    # use_rej: series telemetry also samples utilization at chunk
+                    # attribute: series telemetry also samples utilization at chunk
                     # boundaries, which needs the chunk start times. The recorder
                     # stamps the chunk's virtual time on every row (host numpy
                     # only — no program effect).
-                    if (pending_events or completions_on or use_rej or rec is not None)
+                    if (pending_events or completions_on or attribute or rec is not None)
                     else None
                 )
                 pending_fold = None  # (rows, choices) of the not-yet-folded chunk
@@ -1961,41 +1646,35 @@ class JaxReplayEngine:
                 # Pre-stage the per-chunk wave indices on device (a few MB total):
                 # the timed loop then issues ONE call per chunk with no H2D.
                 idx_chunks = (
-                    [
+                    None
+                    if self.paged
+                    else [
                         jnp.asarray(idx[c0 : c0 + C])
                         for c0 in range(0, idx.shape[0], C)
                     ]
-                    if self.engine == "v3" and not use_rej and not self.paged
-                    else None
                 )
                 self._register_programs(state, idx_chunks, completions_on)
                 # Paged pod waves (round 14): per-chunk pages of the slot planes
                 # stream host->device with one-chunk prefetch instead of whole-trace
-                # residency. v3 pages carry page-LOCAL row indices (the kernels only
+                # residency. Pages carry page-LOCAL row indices (the kernels only
                 # consume pod_id as a width, never as an identity).
                 pager = None
-                if self.paged and not use_rej:
-                    if self.engine == "v3":
-                        def _fetch_page(pci):
-                            rows = idx[pci * C : (pci + 1) * C]
-                            flat = rows.reshape(-1)
-                            local = np.where(
-                                rows >= 0,
-                                np.arange(
-                                    rows.size, dtype=np.int32
-                                ).reshape(rows.shape),
-                                PAD,
-                            ).astype(np.int32)
-                            return (
-                                T.SlotSource.page(self.pods, flat),
-                                V3.ExtraSource.page(self.static3, flat),
-                                jnp.asarray(local),
-                            )
-                    else:
-                        def _fetch_page(pci):
-                            return T.gather_slots(
-                                self.pods, idx[pci * C : (pci + 1) * C]
-                            )
+                if self.paged:
+                    def _fetch_page(pci):
+                        rows = idx[pci * C : (pci + 1) * C]
+                        flat = rows.reshape(-1)
+                        local = np.where(
+                            rows >= 0,
+                            np.arange(
+                                rows.size, dtype=np.int32
+                            ).reshape(rows.shape),
+                            PAD,
+                        ).astype(np.int32)
+                        return (
+                            T.SlotSource.page(self.pods, flat),
+                            V3.ExtraSource.page(self.static3, flat),
+                            jnp.asarray(local),
+                        )
                     pager = _PodPager(_fetch_page, threaded=_pager_thread_enabled())
                 rec_valid = (
                     np.add.accumulate(
@@ -2025,6 +1704,11 @@ class JaxReplayEngine:
                     due = [e for e in pending_events if e.time <= chunk_t]
                     if due:
                         self._apply_node_events(due, saved_alloc)
+                        if attribute:
+                            # The mirror's plugins read ec.allocatable live.
+                            self.ec.allocatable[:] = np.asarray(
+                                self.dc.allocatable
+                            )
                         if tel is not None and tel.cfg.want_timeline:
                             for ev in due:
                                 if ev.kind in ("node_down", "node_up"):
@@ -2064,56 +1748,53 @@ class JaxReplayEngine:
                             due_p = np.nonzero(due_m)[0]
                             if due_p.size:
                                 state = self._apply_release(
-                                    state, due_p, host_assign[due_p],
-                                    as_v2=use_rej,
+                                    state, due_p, host_assign[due_p]
                                 )
+                                if attribute:
+                                    apply_planes(
+                                        self.ec, self.pods, mirror, -1.0,
+                                        due_p, host_assign[due_p],
+                                    )
+                                    mirror.bound[due_p] = PAD
                                 released[due_p] = True
-                if use_rej and wave_times is not None and np.isfinite(
-                    wave_times[c0]
-                ):
+                if attribute and np.isfinite(wave_times[c0]):
                     # Utilization economics (round 13): chunk-boundary sample
                     # of the committed device state (binds through chunk ci-1
-                    # plus the releases applied above). The fetch blocks on
-                    # the in-flight chunk — a series-mode-only sync; summary
-                    # runs the untouched program. The instrumented-rej carry
-                    # guarantees node-space [N, R] state.used here.
+                    # plus the releases applied above), read off the carry
+                    # itself: the mirror's float32 sums are added in another
+                    # order. A series-mode-only fetch; summary never syncs
+                    # here.
                     with _tick("host_mirror"):
                         tel.sample(
                             float(wave_times[c0]),
                             **series_gauges(
-                                np.asarray(state.used),
+                                state.to_host(
+                                    self.ec, self.static3, self._Dhost
+                                )[0],
                                 np.asarray(self.dc.allocatable),
                                 self.ec.vocab._r,
                             ),
                         )
                 with _tick("dispatch"), _tick.mark(f"chunk:{ci}"):
-                    if use_rej:
-                        state, rej_dev, choices = self._chunk_fn_rej(
-                            self.dc, state, rej_dev,
-                            T.gather_slots(self.pods, idx[c0 : c0 + C]),
+                    if pager is not None:
+                        src, xsrc, lidx = pager.get(ci)
+                        state, choices = self.chunk_fn(
+                            self.dc, state, src, xsrc, lidx
                         )
-                    elif self.engine == "v3":
-                        if pager is not None:
-                            src, xsrc, lidx = pager.get(ci)
-                            state, choices = self.chunk_fn(
-                                self.dc, state, src, xsrc, lidx
-                            )
-                        else:
-                            state, choices = self.chunk_fn(
-                                self.dc, state, self._slot_src, self._extra_src,
-                                idx_chunks[ci],
-                            )
                     else:
                         state, choices = self.chunk_fn(
-                            self.dc, state,
-                            pager.get(ci)
-                            if pager is not None
-                            else T.gather_slots(self.pods, idx[c0 : c0 + C]),
+                            self.dc, state, self._slot_src, self._extra_src,
+                            idx_chunks[ci],
                         )
                 if pager is not None and c0 + C < idx.shape[0]:
                     # Stage the next page while this chunk is still on device.
                     pager.prefetch(ci + 1)
                 all_choices.append(choices)
+                if attribute:
+                    with _tick("device_wait"):
+                        ch_np = np.asarray(choices)
+                    with _tick("boundary_fold"):
+                        fold_answers(fw, mirror, idx[c0 : c0 + C], ch_np, tel)
                 if completions_on and self.preemption:
                     pending_fold = (idx[c0 : c0 + C], choices)
                 elif completions_on:
@@ -2203,6 +1884,8 @@ class JaxReplayEngine:
             with _tick("gather"):
                 if node_events:
                     self.dc = self.dc._replace(allocatable=jnp.asarray(saved_alloc))
+                    if attribute:
+                        self.ec.allocatable[:] = saved_alloc
 
                 preemptions = 0
                 gangs = None
@@ -2264,15 +1947,8 @@ class JaxReplayEngine:
                     # the same chunk it arrived in, zero virtual-time latency by
                     # the chunk-granular convention (SURVEY.md §5).
                     tel.bind_zero(placed)
-                    if use_rej:
-                        tel.rejection_bulk(
-                            spec_plugin_names(self.spec), np.asarray(rej_dev)
-                        )
 
-                if self.engine == "v3" and not use_rej:
-                    used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
-                else:
-                    used, mc, aa, pw = self._v2_to_host(state)
+                used, mc, aa, pw = state.to_host(self.ec, self.static3, self._Dhost)
                 util = utilization_means(used, self.ec.allocatable, self.ec.vocab._r)
                 pending_m = (self.pods.bound_node == PAD) & (assignments == PAD)
                 frag = fragmentation_gauges(

@@ -225,13 +225,13 @@ class ReplayTelemetry:
     # Chunk width the device replay ran: the granularity guard may shrink
     # the configured one (sim.granularity). None where no chunk loop ran.
     chunk_waves: Optional[int] = None
-    # Form of the in-wave usage corrections the v3 chunk program was built
+    # Form of the in-wave usage corrections the chunk program was built
     # with (ops.tpu3.inwave_corrections): "plane", "terms" or
-    # "resolved_terms". None for v2.
+    # "resolved_terms".
     inwave_corrections: Optional[str] = None
-    # Whether a slot of the v3 chunk program finds the spread's zone
+    # Whether a slot of the chunk program finds the spread's zone
     # feasibility and its node in one node-wide reduce or in two
-    # (ops.tpu3.select_form): "zone_packed" or "two_pass". None for v2.
+    # (ops.tpu3.select_form): "zone_packed" or "two_pass".
     select_form: Optional[str] = None
     # What-if batches only: the count planes of the compiled problem
     # (ops.tpu3.count_planes: rows at domain scale and at host scale, the
@@ -516,15 +516,6 @@ class TelemetryCollector:
         if pod not in self._attributed:
             self._attributed.add(pod)
             for k, v in counts.items():
-                self._reasons[k] = self._reasons.get(k, 0) + int(v)
-
-    def rejection_bulk(self, names: Sequence[str], vec) -> None:
-        """In-scan device counters: [K] totals in plugin order. On the plain
-        path every failure is both terminal and a fresh episode, so the
-        vector feeds both counters."""
-        for k, v in zip(names, np.asarray(vec).tolist()):
-            if v:
-                self._attempts[k] = self._attempts.get(k, 0) + int(v)
                 self._reasons[k] = self._reasons.get(k, 0) + int(v)
 
     def clear_episode(self, pod: int) -> None:

@@ -113,7 +113,7 @@ def widest_gang(ep: EncodedPods) -> int:
     return int(np.bincount(gid).max()) if gid.size else 0
 
 
-# What a gang wider than the wave does not run with, on any engine: the ONE
+# What a gang wider than the wave does not run with: the ONE
 # list (the step's builder, both device engines, the host twin and the CLI's
 # ``validate`` all refuse through :func:`refuse_wide_gangs`). The transaction
 # gives back resource usage and lives in the arrivals-only scan's state: a
@@ -121,7 +121,6 @@ def widest_gang(ep: EncodedPods) -> int:
 # would have to know of an open one, and count planes would have to be
 # rolled back with it.
 WIDE_GANG_UNSUPPORTED = {
-    "v2_engine": "the v2 engine",
     "completions": "completions (finite pod durations; pass completions=False)",
     "retry_buffer": "a retry buffer",
     "kube_preemption": "kube preemption",

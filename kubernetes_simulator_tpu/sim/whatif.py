@@ -48,7 +48,7 @@ from ..utils.profiling import make_span as _make_span
 from ..utils.profiling import register_call as _register_call
 from ..utils.profiling import shape_structs as _shape_structs
 from ..utils.profiling import stage
-from .jax_runtime import StepSpec, make_wave_step
+from .jax_runtime import StepSpec
 from .waves import pack_waves, refuse_wide_gangs, widest_gang
 
 # The release program's vmap axis: named so that the rank rounds of a block
@@ -201,12 +201,12 @@ class ScenarioSet:
                     ndom[si, ti] = len(uniq)
         self.max_domains = max(int(ndom.max()) if ndom.size else 1, ec.max_domains, 1)
         self.labels_dirty = bool(labels_dirty.any())
-        # v3 with per-scenario DynTables (round 3): keep the base (shared)
+        # Per-scenario DynTables (round 3): keep the base (shared)
         # expansion tables and thread tiny per-scenario corrections through
         # the wave step. Domain ids are APPEND-style — existing label values
         # keep their base ids, new values get ids past the base count.
         # Internal ids are semantics-free (all consumers use per-domain
-        # counts / existence / sizes), so this differs from the v2 path's
+        # counts / existence / sizes), so this differs from a re-encode's
         # rank-style re-derivation without changing any observable result.
         self.dyn = None
         if self.labels_dirty:
@@ -327,7 +327,7 @@ class ScenarioSet:
         # Per-domain node counts → existence. Coarse topologies only:
         # host-scale ones (hostname at Borg scale) would make this an
         # O(S·T·N) allocation, and they never change here (host_changed
-        # forces v2 otherwise) — their nd_exist is the base count.
+        # refuses the batch otherwise) — their nd_exist is the base count.
         cnt = np.zeros((S, Tn, Dext), np.int64)
         for t in range(Tn):
             if not coarse_t[t]:
@@ -373,7 +373,7 @@ class ScenarioSet:
                 nd_exist[:, t] = base_nd[t]  # unchanged (host_changed gate)
         # A perturbation that moves a node's domain under a HOST-scale
         # topology cannot be corrected (host planes are node-space) — the
-        # engine must fall back to v2 for the whole batch.
+        # engine refuses the whole batch.
         # PAD-padded slots have new == old == PAD, so the inequality
         # alone suffices.
         host_changed = any(
@@ -872,73 +872,53 @@ class WhatIfEngine:
             if self.S % ndev != 0:
                 raise ValueError(f"num scenarios {self.S} must divide over {ndev} devices")
         self.D = max(self.sset.max_domains, 1)
-        # v3 unless the labels_dirty batch falls outside the DynTables
-        # envelope (per-scenario domain tables; round 3): host-scale
-        # topologies, pre-bound pods, preemption, forks and >32 perturbed
-        # nodes per scenario stay on the v2 parity engine.
+        # The one device engine: what WhatIfResult.engine and the JSONL
+        # rows stamp.
         self.engine = "v3"
+        # A labels_dirty batch runs on per-scenario domain tables
+        # (DynTables; round 3) or not at all: the envelope is the list of
+        # reasons below, each one a predicate of the gate.
         self._dyn = None
         if self.sset.labels_dirty:
             # DynTables batches honor completions on the DEVICE-release
             # path since round 4 (per-scenario release domain
             # corrections); off that path the gate below WARNS/raises.
-            # Either way prefer the ~4× faster DynTables v3 over v2.
             dyn = self.sset.dyn
-            if (
-                dyn is not None
-                and dyn.K <= 32
-                and not dyn.host_changed
-                and not preemption
-                and fork_checkpoint is None
-                and not bool((pods.bound_node >= 0).any())
-            ):
-                self._dyn = dyn
+            reasons = []
+            if dyn is None:
+                reasons.append("no DynTables")
             else:
-                self.engine = "v2"
-                # The fallback costs ~4× — say so (VERDICT r3 weak #3:
-                # an adversarial 33-node relabel silently cost it). The
-                # reasons mirror the gate's predicates one-for-one; a
-                # future gate condition the list doesn't cover reports
-                # "unhandled gate condition" rather than mislabeling.
-                reasons = []
-                if dyn is None:
-                    reasons.append("no DynTables")
-                else:
-                    if dyn.host_changed:
-                        reasons.append("host-scale topology change")
-                    if dyn.K > 32:
-                        reasons.append(
-                            f">{32} perturbed nodes/scenario (K={dyn.K})"
-                        )
-                if preemption:
-                    reasons.append("preemption")
-                if fork_checkpoint is not None:
-                    reasons.append("fork checkpoint")
-                if bool((pods.bound_node >= 0).any()):
-                    reasons.append("pre-bound pods")
-                reason = (
-                    ", ".join(reasons) if reasons
-                    else "unhandled gate condition"
+                if dyn.host_changed:
+                    reasons.append("host-scale topology change")
+                if dyn.K > 32:
+                    reasons.append(
+                        f">{32} perturbed nodes/scenario (K={dyn.K})"
+                    )
+            if preemption:
+                reasons.append("preemption")
+            if fork_checkpoint is not None:
+                reasons.append("fork checkpoint")
+            if bool((pods.bound_node >= 0).any()):
+                reasons.append("pre-bound pods")
+            if reasons:
+                raise ValueError(
+                    "what-if: set_label batch outside the DynTables "
+                    f"envelope ({', '.join(reasons)}). Run one single replay "
+                    "per scenario instead: apply the scenario's "
+                    "perturbations to a copy of the Cluster, encode() it and "
+                    "replay it with JaxReplayEngine"
                 )
-                from ..utils.metrics import log
-
-                log.info(
-                    "what-if: labels_dirty batch outside the DynTables "
-                    "envelope (%s) — v2 fallback engine (~4x slower); "
-                    "WhatIfResult.engine reports it",
-                    reason,
-                )
+            self._dyn = dyn
         self.preemption = preemption
-        if self.kube and (self.engine != "v3" or self.sset.labels_dirty):
+        if self.kube and self.sset.labels_dirty:
             raise ValueError(
-                "kube preemption requires the v3 engine with no label "
+                "kube preemption requires a batch with no label "
                 "perturbations (the per-scenario host mirrors share the "
                 "base topology-domain tables)"
             )
-        if preemption and (self.engine != "v3" or fork_checkpoint):
+        if preemption and fork_checkpoint:
             raise ValueError(
-                "what-if preemption requires the v3 engine (no label "
-                "perturbations) and no fork checkpoint"
+                "what-if preemption requires no fork checkpoint"
             )
         if preemption and bool((pods.bound_node >= 0).any()):
             # The aggregate tally cannot distinguish pre-bound victims from
@@ -946,32 +926,28 @@ class WhatIfEngine:
             raise ValueError(
                 "what-if preemption does not support pre-bound pods"
             )
-        self._scales_pods = False
-        if self.engine == "v3":
-            from ..ops import tpu3 as V3
-            from .jax_runtime import rep_slots_for
+        from ..ops import tpu3 as V3
+        from .jax_runtime import rep_slots_for
 
-            # Perturbations that scale the "pods" capacity can exceed the
-            # bf16 host-plane exactness bound.
-            scales_pods = self._dcn_scales_pods or any(
-                pt.op == "scale_capacity" and pt.resource == "pods" and pt.factor > 1
-                for sc in scenarios
-                for pt in sc.perturbations
-            )
-            # Remembered so set_scenarios can refuse a swapped-in batch
-            # that needs the f32 host plane this engine was built without.
-            self._scales_pods = scales_pods
-            self.static3 = V3.V3Static.build(
-                ec, pods, self.spec, preemption=preemption,
-                allow_bf16_host=not scales_pods,
-                dcap_min=(self._dyn.Dcap if self._dyn is not None else 0),
-                wave_width=self.wave_width,
-            )
-            self.shared3 = V3.Shared3.build(ec, self.static3)
-            self.rep_slots = rep_slots_for(self.static3, pods)
-        if self.engine == "v3" and self._dyn is not None:
-            from ..ops import tpu3 as V3
-
+        # Perturbations that scale the "pods" capacity can exceed the
+        # bf16 host-plane exactness bound.
+        scales_pods = self._dcn_scales_pods or any(
+            pt.op == "scale_capacity" and pt.resource == "pods" and pt.factor > 1
+            for sc in scenarios
+            for pt in sc.perturbations
+        )
+        # Remembered so set_scenarios can refuse a swapped-in batch
+        # that needs the f32 host plane this engine was built without.
+        self._scales_pods = scales_pods
+        self.static3 = V3.V3Static.build(
+            ec, pods, self.spec, preemption=preemption,
+            allow_bf16_host=not scales_pods,
+            dcap_min=(self._dyn.Dcap if self._dyn is not None else 0),
+            wave_width=self.wave_width,
+        )
+        self.shared3 = V3.Shared3.build(ec, self.static3)
+        self.rep_slots = rep_slots_for(self.static3, pods)
+        if self._dyn is not None:
             d = self._dyn
             self._dyn_dev = V3.DynTables(
                 ov_nodes=jnp.asarray(d.ov_nodes),
@@ -1012,32 +988,25 @@ class WhatIfEngine:
         # for the gate below and to decide whether a DynTables batch can
         # honor completions at all — the host fold path cannot apply
         # per-scenario domain corrections, the device commit blocks can).
-        dev_ok = False
-        if self.engine == "v3":
-            s3 = self.static3
-            # Round 10: the device-release path runs UNDER A MESH too —
-            # the bucketed release fns and the vassign fold are
-            # per-scenario programs, so shard_map wraps them like the
-            # chunk program (replicated release tables, sharded
-            # state/vassign). Only label-perturbation DynTables batches
-            # stay off it there: their per-scenario domain-override
-            # corrections would need the override tables threaded through
-            # every bucketed release call's shard specs.
-            dev_ok = bool(
-                not preemption
-                and not self.kube  # BoundaryOps owns releases in kube mode
-                and fork_checkpoint is None
-                and (self.mesh is None or self._dyn is None)
-                and s3.single_g[s3.mc_h_ids].all()
-                and s3.single_g[s3.anti_h_ids].all()
-                and s3.single_g[s3.pref_h_ids].all()
-            )
+        s3 = self.static3
+        # Round 10: the device-release path runs UNDER A MESH too —
+        # the bucketed release fns and the vassign fold are
+        # per-scenario programs, so shard_map wraps them like the
+        # chunk program (replicated release tables, sharded
+        # state/vassign). Only label-perturbation DynTables batches
+        # stay off it there: their per-scenario domain-override
+        # corrections would need the override tables threaded through
+        # every bucketed release call's shard specs.
+        dev_ok = bool(
+            not preemption
+            and not self.kube  # BoundaryOps owns releases in kube mode
+            and fork_checkpoint is None
+            and (self.mesh is None or self._dyn is None)
+            and s3.single_g[s3.mc_h_ids].all()
+            and s3.single_g[s3.anti_h_ids].all()
+            and s3.single_g[s3.pref_h_ids].all()
+        )
         blockers = []
-        if self.engine != "v3":
-            blockers.append(
-                "the v2 fallback engine (label perturbations outside the "
-                "DynTables envelope)"
-            )
         # Tier preemption × completions is SUPPORTED since round 5 on the
         # no-mesh batch path (eager eviction-aware host folds, the
         # single-replay round-4 mechanism S-stacked; VERDICT r4 next #4).
@@ -1089,15 +1058,11 @@ class WhatIfEngine:
         # arrivals-only paths (sim.waves.WIDE_GANG_UNSUPPORTED).
         refuse_wide_gangs(
             self.wave_width, widest_gang(self.pods),
-            v2_engine=self.engine != "v3", completions=self.completions_on,
+            completions=self.completions_on,
             retry_buffer=bool(retry_buffer), kube_preemption=self.kube,
             fork_checkpoint=fork_checkpoint is not None,
         )
-        if (
-            self.completions_on
-            and not self._completions_dev
-            and self.engine == "v3"
-        ):
+        if self.completions_on and not self._completions_dev:
             # The device-release fast path is gated — say WHY (VERDICT r4
             # missing #6: the non-singleton host-scale regroup gate was
             # silent; the host pending-fold path honors the same
@@ -1232,23 +1197,19 @@ class WhatIfEngine:
         # Scenario-shared, so under a mesh they replicate ONCE and every
         # device gathers its chunk rows locally (round 10: the mesh path
         # stopped host-gathering slots per chunk).
-        self._slot_srcs = None
-        if self.engine == "v3":
-            from ..ops import tpu3 as V3
-
-            srcs = (
-                T.SlotSource.build(pods),
-                V3.ExtraSource.build(self.static3, pods.num_pods),
-            )
-            if self.mesh is not None:
-                srcs = replicate_tree(self.mesh, srcs)
-            self._slot_srcs = srcs
+        srcs = (
+            T.SlotSource.build(pods),
+            V3.ExtraSource.build(self.static3, pods.num_pods),
+        )
+        if self.mesh is not None:
+            srcs = replicate_tree(self.mesh, srcs)
+        self._slot_srcs = srcs
 
     @property
     def _wide_gangs(self) -> bool:
         """The trace has a pod group wider than the wave: the state carries
         its transaction (``ops.tpu3.GangTxn``)."""
-        return self.engine == "v3" and self.static3.has_wide_gangs
+        return self.static3.has_wide_gangs
 
     def _gangs_summary(self, txn) -> dict:
         """``summary()["gangs"]`` of the batch that just ran: the static
@@ -1323,11 +1284,6 @@ class WhatIfEngine:
                 "set_scenarios is single-process only: a DCN-sliced "
                 "engine owns a contiguous block of a global batch and "
                 "cannot swap scenarios underneath the slice bookkeeping"
-            )
-        if self.engine != "v3":
-            raise ValueError(
-                "set_scenarios requires the v3 engine (the v2 parity "
-                "fallback rebuilds per-batch state at trace time)"
             )
         if self.sset.labels_dirty:
             raise ValueError(
@@ -1442,336 +1398,316 @@ class WhatIfEngine:
                 donate_argnums=donate,
             )
 
-        if self.engine == "v3":
-            from ..ops import tpu3 as V3
+        from ..ops import tpu3 as V3
 
-            st3, sh3, reps = self.static3, self.shared3, self.rep_slots
+        st3, sh3, reps = self.static3, self.shared3, self.rep_slots
 
-            pre_on = self.preemption
-            dyn_on = self._dyn_dev is not None
-            narrow = self.ec.num_nodes < 2**15 - 1
-            dev_rel = self._completions_dev
-            dyn_flip = bool(
-                self._dyn is not None
-                and getattr(self._dyn, "has_presence_change", True)
+        pre_on = self.preemption
+        dyn_on = self._dyn_dev is not None
+        narrow = self.ec.num_nodes < 2**15 - 1
+        dev_rel = self._completions_dev
+        dyn_flip = bool(
+            self._dyn is not None
+            and getattr(self._dyn, "has_presence_change", True)
+        )
+
+        def per_scenario(dc, state, slots, extra, dyn=None, wvec=None):
+            with stage("ksim.derive"):
+                d = T.Derived.build(dc)
+                cmasks = V3.class_masks(dc, d, st3, spec, reps)
+            wave_step = V3.make_wave_step3(
+                dc, d, sh3, st3, wave_width, spec, cmasks, dyn=dyn,
+                dyn_flip=dyn_flip, wvec=wvec, scenario_axis=True,
             )
 
-            def per_scenario(dc, state, slots, extra, dyn=None, wvec=None):
-                with stage("ksim.derive"):
+            def step(st, batch):
+                st, out = wave_step(st, batch)
+                if pre_on:
+                    choices, ev_node, ev_tier, ev_prior, ev_total = out
+                    placed_w = (
+                        jnp.sum((choices >= 0) & batch[0].valid) - ev_prior
+                    ).astype(jnp.int32)
+                    out = (
+                        (choices, ev_node, ev_tier)
+                        if collect
+                        else placed_w
+                    )
+                    return st, out
+                choices = out
+                placed_w = jnp.sum((choices >= 0) & batch[0].valid).astype(jnp.int32)
+                if dev_rel:
+                    # Device-release path: choices stay ON DEVICE for
+                    # the assignment fold; counts ride along.
+                    return st, (choices, placed_w)
+                if collect and narrow:
+                    # Completions fetch choices back every chunk; with
+                    # N < 2^15 an int16 stream halves the D2H volume.
+                    choices = choices.astype(jnp.int16)
+                return st, (choices if collect else placed_w)
+
+            state, outs = jax.lax.scan(step, state, (slots, extra))
+            return state, outs
+
+        # Device-side slot gathers INSIDE the jitted program: one
+        # dispatch per chunk, only indices as per-chunk input
+        # (scenario-shared → gathered once, not per scenario).
+        def per_scenario_src(dc, state, src, xsrc, idx, dyn=None, wvec=None):
+            with stage("ksim.gather"):
+                slots = T.gather_slots_device(src, idx)
+                extra = V3.gather_extra_device(xsrc, idx)
+            return per_scenario(dc, state, slots, extra, dyn, wvec)
+
+        if self._completions_dev:
+            def per_scenario_rel(
+                dc, state, src, xsrc, idx, b, vassign, dyn=None,
+                wvec=None,
+            ):
+                # Static releases run in the separate bucketed
+                # _release_fn BEFORE this call (ordering by data
+                # dependency on state/vassign). Here: the normal
+                # chunk scan + the WAVE-ORDER assignment fold —
+                # a dynamic_update_slice (pure DMA), not a
+                # [C·W]-index scatter: choices land at their flat
+                # wave positions, which is exactly how the static
+                # release lists address them (rel_pos).
+                state, out = per_scenario_src(
+                    dc, state, src, xsrc, idx, dyn, wvec
+                )
+                choices, counts = out
+                with stage("ksim.release"):
+                    vassign = jax.lax.dynamic_update_slice(
+                        vassign,
+                        choices.reshape(-1),
+                        (b * idx.size,),
+                    )
+                return state, vassign, counts
+
+            if self.retry_buffer:
+                RB = self.retry_buffer
+                RBW = RB // wave_width
+                BIG = 1 << 30
+
+                rel_core = self._release_core()
+                want_an, want_pf = rel_core.want_an, rel_core.want_pf
+
+                def per_scenario_retry(
+                    dc, state, src, xsrc, mgt, antit, preft,
+                    prefwt, durt, priot, tbt,
+                    idx, t_b, b,
+                    vassign, rq,
+                ):
+                    """The device-release chunk call with the
+                    bounded unschedulable-retry pass (semantics:
+                    sim.greedy.greedy_replay(retry_buffer=...)).
+                    Static releases ran in the separate bucketed
+                    _release_fn before this call. Order here: the
+                    releases of re-tried binds that are due -> the
+                    retry pass over the queue (in QueueSort order
+                    since the last call's upkeep) and its record ->
+                    the main chunk scan -> the queue's upkeep (the
+                    chunk's failures join, one stable sort by
+                    priority) -> the assignment fold. ``rq`` is the
+                    scenario's ``RetryQueue``."""
                     d = T.Derived.build(dc)
                     cmasks = V3.class_masks(dc, d, st3, spec, reps)
-                wave_step = V3.make_wave_step3(
-                    dc, d, sh3, st3, wave_width, spec, cmasks, dyn=dyn,
-                    dyn_flip=dyn_flip, wvec=wvec, scenario_axis=True,
-                )
-
-                def step(st, batch):
-                    st, out = wave_step(st, batch)
-                    if pre_on:
-                        choices, ev_node, ev_tier, ev_prior, ev_total = out
-                        placed_w = (
-                            jnp.sum((choices >= 0) & batch[0].valid) - ev_prior
-                        ).astype(jnp.int32)
-                        out = (
-                            (choices, ev_node, ev_tier)
-                            if collect
-                            else placed_w
-                        )
-                        return st, out
-                    choices = out
-                    placed_w = jnp.sum((choices >= 0) & batch[0].valid).astype(jnp.int32)
-                    if dev_rel:
-                        # Device-release path: choices stay ON DEVICE for
-                        # the assignment fold; counts ride along.
-                        return st, (choices, placed_w)
-                    if collect and narrow:
-                        # Completions fetch choices back every chunk; with
-                        # N < 2^15 an int16 stream halves the D2H volume.
-                        choices = choices.astype(jnp.int16)
-                    return st, (choices if collect else placed_w)
-
-                state, outs = jax.lax.scan(step, state, (slots, extra))
-                return state, outs
-
-            # Device-side slot gathers INSIDE the jitted program: one
-            # dispatch per chunk, only indices as per-chunk input
-            # (scenario-shared → gathered once, not per scenario).
-            def per_scenario_src(dc, state, src, xsrc, idx, dyn=None, wvec=None):
-                from ..ops import tpu3 as V3m
-
-                with stage("ksim.gather"):
-                    slots = T.gather_slots_device(src, idx)
-                    extra = V3m.gather_extra_device(xsrc, idx)
-                return per_scenario(dc, state, slots, extra, dyn, wvec)
-
-            if self._completions_dev:
-                def per_scenario_rel(
-                    dc, state, src, xsrc, idx, b, vassign, dyn=None,
-                    wvec=None,
-                ):
-                    # Static releases run in the separate bucketed
-                    # _release_fn BEFORE this call (ordering by data
-                    # dependency on state/vassign). Here: the normal
-                    # chunk scan + the WAVE-ORDER assignment fold —
-                    # a dynamic_update_slice (pure DMA), not a
-                    # [C·W]-index scatter: choices land at their flat
-                    # wave positions, which is exactly how the static
-                    # release lists address them (rel_pos).
-                    state, out = per_scenario_src(
-                        dc, state, src, xsrc, idx, dyn, wvec
+                    wave_step = V3.make_wave_step3(
+                        dc, d, sh3, st3, wave_width, spec, cmasks,
+                        scenario_axis=True,
                     )
-                    choices, counts = out
+                    # The pass walks the scenario's own queue: its
+                    # slots differ by scenario, the arrival scan's do
+                    # not (V3.class_row_reads).
+                    retry_step = V3.make_wave_step3(
+                        dc, d, sh3, st3, wave_width, spec, cmasks,
+                        scenario_axis=True, slots_by_scenario=True,
+                    )
+                    row = lambda t, r: jax.lax.dynamic_index_in_dim(
+                        t, r, keepdims=False
+                    )
+                    put = lambda t, v: jax.lax.dynamic_update_index_in_dim(
+                        t, v.astype(t.dtype), b, 0
+                    )
+                    none_i = jnp.full((RB, 1), PAD, jnp.int32)
+                    none_f = jnp.zeros((RB, 1), jnp.float32)
+
+                    # 1. a re-tried bind is released at the boundary
+                    # its record names (t_relb, the f32 comparison made
+                    # when it bound): every earlier pass's row is held
+                    # against b, however many binds are outstanding.
+                    def rel_row(r, carry):
+                        st, n = carry
+                        due = row(rq.t_relb, r) == b
+                        st, _, _ = rel_core(
+                            st,
+                            jnp.where(due, row(rq.t_node, r), -1),
+                            row(rq.t_req, r).T,
+                            row(rq.t_mg, r).T,
+                            row(rq.t_an, r).T if want_an else none_i,
+                            row(rq.t_pf, r).T if want_pf else none_i,
+                            row(rq.t_pw, r).T if want_pf else none_f,
+                            axis_name=_RETRY_VMAP,
+                        )
+                        return st, n + due.sum(dtype=jnp.int32)
+
+                    with stage("ksim.release"):
+                        state, released = jax.lax.fori_loop(
+                            0, b, rel_row, (state, rq.released)
+                        )
+                    # 2. the retry pass: the NORMAL wave step over the
+                    # queue (empty slots are invalid no-ops), then the
+                    # record of its binds in row b: task, node, release
+                    # boundary (placed pods start NOW: f32 boundary
+                    # search, at least b+1) and the rows the release
+                    # rewinds.
+                    with stage("ksim.retry"):
+                        q = rq.ids
+                        rb_waves = q.reshape(RBW, wave_width)
+                        slots_r = T.gather_slots_device(src, rb_waves)
+                        extra_r = V3.gather_extra_device(xsrc, rb_waves)
+                        state, choices_r = jax.lax.scan(
+                            retry_step, state, (slots_r, extra_r)
+                        )
+                        flat_cr = choices_r.reshape(RB)
+                        placed_r = (flat_cr >= 0) & (q >= 0)
+                        retry_placed = placed_r.sum(dtype=jnp.int32)
+                        rbn = jnp.searchsorted(
+                            tbt, t_b + rq.dur, side="left"
+                        )
+                        relb = jnp.where(
+                            placed_r & (rbn < tbt.shape[0]),
+                            jnp.maximum(rbn, b + 1),
+                            BIG,
+                        ).astype(jnp.int32)
+                        safe = jnp.clip(q, 0)
+                        rq = rq._replace(
+                            t_id=put(rq.t_id, jnp.where(placed_r, q, -1)),
+                            t_node=put(
+                                rq.t_node, jnp.where(placed_r, flat_cr, -1)
+                            ),
+                            t_relb=put(rq.t_relb, relb),
+                            t_req=put(
+                                rq.t_req, slots_r.req.reshape(RB, -1).T
+                            ),
+                            t_mg=put(rq.t_mg, mgt[safe].T),
+                            owed=rq.owed
+                            + (relb < BIG).sum(dtype=jnp.int32),
+                            released=released,
+                            depth_max=jnp.maximum(rq.depth_max, rq.count),
+                        )
+                        if want_an:
+                            rq = rq._replace(
+                                t_an=put(rq.t_an, antit[safe].T)
+                            )
+                        if want_pf:
+                            rq = rq._replace(
+                                t_pf=put(rq.t_pf, preft[safe].T),
+                                t_pw=put(rq.t_pw, prefwt[safe].T),
+                            )
+                        ids = jnp.where(placed_r, -1, q)
+                        count = rq.count - retry_placed
+                    # 3. the main chunk scan, as without a queue.
+                    slots = T.gather_slots_device(src, idx)
+                    extra = V3.gather_extra_device(xsrc, idx)
+
+                    def step(st, batch):
+                        st, choices = wave_step(st, batch)
+                        placed_w = jnp.sum(
+                            (choices >= 0) & batch[0].valid
+                        ).astype(jnp.int32)
+                        return st, (choices, placed_w)
+
+                    state, (choices, counts) = jax.lax.scan(
+                        step, state, (slots, extra)
+                    )
+                    # 4. the queue's upkeep: the chunk's failed
+                    # non-gang tasks join behind the tasks that stay,
+                    # in arrival order, as far as there is room
+                    # (overflow drops the newest, COUNTED); ONE stable
+                    # sort by priority then leaves the queue in kube's
+                    # QueueSort order (priority descending, then
+                    # arrival) with the holes of the placed at its end.
+                    with stage("ksim.retry"):
+                        rows = idx.reshape(-1)
+                        fail = (
+                            (choices < 0) & slots.valid & (slots.group < 0)
+                        ).reshape(-1)
+                        room = RB - count
+                        nfail = fail.sum(dtype=jnp.int32)
+                        take = fail & (
+                            jnp.cumsum(fail.astype(jnp.int32)) <= room
+                        )
+                        rsafe = jnp.clip(rows, 0)
+                        cat_ids = jnp.concatenate(
+                            [ids, jnp.where(take, rows, -1)]
+                        )
+                        cat_prio = jnp.concatenate([rq.prio, priot[rsafe]])
+                        cat_dur = jnp.concatenate([rq.dur, durt[rsafe]])
+                        key = jnp.where(
+                            cat_ids >= 0, -cat_prio, jnp.iinfo(jnp.int32).max
+                        )
+                        _, cat_ids, cat_prio, cat_dur = jax.lax.sort(
+                            (key, cat_ids, cat_prio, cat_dur),
+                            num_keys=1, is_stable=True,
+                        )
+                        rq = rq._replace(
+                            ids=cat_ids[:RB], prio=cat_prio[:RB],
+                            dur=cat_dur[:RB],
+                            count=count + jnp.minimum(nfail, room),
+                            dropped=rq.dropped
+                            + jnp.maximum(nfail - room, 0),
+                        )
+                    # 5. fold arrival-chunk placements at their flat
+                    # wave positions (re-tried placements stay in the
+                    # queue's record: their arrival slot keeps PAD so
+                    # the static release entry never fires).
                     with stage("ksim.release"):
                         vassign = jax.lax.dynamic_update_slice(
                             vassign,
                             choices.reshape(-1),
                             (b * idx.size,),
                         )
-                    return state, vassign, counts
+                    return state, vassign, rq, (counts, retry_placed)
 
-                if self.retry_buffer:
-                    RB = self.retry_buffer
-                    RBW = RB // wave_width
-                    BIG = 1 << 30
-
-                    rel_core = self._release_core()
-                    want_an, want_pf = rel_core.want_an, rel_core.want_pf
-
-                    def per_scenario_retry(
-                        dc, state, src, xsrc, mgt, antit, preft,
-                        prefwt, durt, priot, tbt,
-                        idx, t_b, b,
-                        vassign, rq,
-                    ):
-                        """The device-release chunk call with the
-                        bounded unschedulable-retry pass (semantics:
-                        sim.greedy.greedy_replay(retry_buffer=...)).
-                        Static releases ran in the separate bucketed
-                        _release_fn before this call. Order here: the
-                        releases of re-tried binds that are due -> the
-                        retry pass over the queue (in QueueSort order
-                        since the last call's upkeep) and its record ->
-                        the main chunk scan -> the queue's upkeep (the
-                        chunk's failures join, one stable sort by
-                        priority) -> the assignment fold. ``rq`` is the
-                        scenario's ``RetryQueue``."""
-                        d = T.Derived.build(dc)
-                        cmasks = V3.class_masks(dc, d, st3, spec, reps)
-                        wave_step = V3.make_wave_step3(
-                            dc, d, sh3, st3, wave_width, spec, cmasks,
-                            scenario_axis=True,
-                        )
-                        # The pass walks the scenario's own queue: its
-                        # slots differ by scenario, the arrival scan's do
-                        # not (V3.class_row_reads).
-                        retry_step = V3.make_wave_step3(
-                            dc, d, sh3, st3, wave_width, spec, cmasks,
-                            scenario_axis=True, slots_by_scenario=True,
-                        )
-                        row = lambda t, r: jax.lax.dynamic_index_in_dim(
-                            t, r, keepdims=False
-                        )
-                        put = lambda t, v: jax.lax.dynamic_update_index_in_dim(
-                            t, v.astype(t.dtype), b, 0
-                        )
-                        none_i = jnp.full((RB, 1), PAD, jnp.int32)
-                        none_f = jnp.zeros((RB, 1), jnp.float32)
-
-                        # 1. a re-tried bind is released at the boundary
-                        # its record names (t_relb, the f32 comparison made
-                        # when it bound): every earlier pass's row is held
-                        # against b, however many binds are outstanding.
-                        def rel_row(r, carry):
-                            st, n = carry
-                            due = row(rq.t_relb, r) == b
-                            st, _, _ = rel_core(
-                                st,
-                                jnp.where(due, row(rq.t_node, r), -1),
-                                row(rq.t_req, r).T,
-                                row(rq.t_mg, r).T,
-                                row(rq.t_an, r).T if want_an else none_i,
-                                row(rq.t_pf, r).T if want_pf else none_i,
-                                row(rq.t_pw, r).T if want_pf else none_f,
-                                axis_name=_RETRY_VMAP,
-                            )
-                            return st, n + due.sum(dtype=jnp.int32)
-
-                        with stage("ksim.release"):
-                            state, released = jax.lax.fori_loop(
-                                0, b, rel_row, (state, rq.released)
-                            )
-                        # 2. the retry pass: the NORMAL wave step over the
-                        # queue (empty slots are invalid no-ops), then the
-                        # record of its binds in row b: task, node, release
-                        # boundary (placed pods start NOW: f32 boundary
-                        # search, at least b+1) and the rows the release
-                        # rewinds.
-                        with stage("ksim.retry"):
-                            q = rq.ids
-                            rb_waves = q.reshape(RBW, wave_width)
-                            slots_r = T.gather_slots_device(src, rb_waves)
-                            extra_r = V3.gather_extra_device(xsrc, rb_waves)
-                            state, choices_r = jax.lax.scan(
-                                retry_step, state, (slots_r, extra_r)
-                            )
-                            flat_cr = choices_r.reshape(RB)
-                            placed_r = (flat_cr >= 0) & (q >= 0)
-                            retry_placed = placed_r.sum(dtype=jnp.int32)
-                            rbn = jnp.searchsorted(
-                                tbt, t_b + rq.dur, side="left"
-                            )
-                            relb = jnp.where(
-                                placed_r & (rbn < tbt.shape[0]),
-                                jnp.maximum(rbn, b + 1),
-                                BIG,
-                            ).astype(jnp.int32)
-                            safe = jnp.clip(q, 0)
-                            rq = rq._replace(
-                                t_id=put(rq.t_id, jnp.where(placed_r, q, -1)),
-                                t_node=put(
-                                    rq.t_node, jnp.where(placed_r, flat_cr, -1)
-                                ),
-                                t_relb=put(rq.t_relb, relb),
-                                t_req=put(
-                                    rq.t_req, slots_r.req.reshape(RB, -1).T
-                                ),
-                                t_mg=put(rq.t_mg, mgt[safe].T),
-                                owed=rq.owed
-                                + (relb < BIG).sum(dtype=jnp.int32),
-                                released=released,
-                                depth_max=jnp.maximum(rq.depth_max, rq.count),
-                            )
-                            if want_an:
-                                rq = rq._replace(
-                                    t_an=put(rq.t_an, antit[safe].T)
-                                )
-                            if want_pf:
-                                rq = rq._replace(
-                                    t_pf=put(rq.t_pf, preft[safe].T),
-                                    t_pw=put(rq.t_pw, prefwt[safe].T),
-                                )
-                            ids = jnp.where(placed_r, -1, q)
-                            count = rq.count - retry_placed
-                        # 3. the main chunk scan, as without a queue.
-                        slots = T.gather_slots_device(src, idx)
-                        extra = V3.gather_extra_device(xsrc, idx)
-
-                        def step(st, batch):
-                            st, choices = wave_step(st, batch)
-                            placed_w = jnp.sum(
-                                (choices >= 0) & batch[0].valid
-                            ).astype(jnp.int32)
-                            return st, (choices, placed_w)
-
-                        state, (choices, counts) = jax.lax.scan(
-                            step, state, (slots, extra)
-                        )
-                        # 4. the queue's upkeep: the chunk's failed
-                        # non-gang tasks join behind the tasks that stay,
-                        # in arrival order, as far as there is room
-                        # (overflow drops the newest, COUNTED); ONE stable
-                        # sort by priority then leaves the queue in kube's
-                        # QueueSort order (priority descending, then
-                        # arrival) with the holes of the placed at its end.
-                        with stage("ksim.retry"):
-                            rows = idx.reshape(-1)
-                            fail = (
-                                (choices < 0) & slots.valid & (slots.group < 0)
-                            ).reshape(-1)
-                            room = RB - count
-                            nfail = fail.sum(dtype=jnp.int32)
-                            take = fail & (
-                                jnp.cumsum(fail.astype(jnp.int32)) <= room
-                            )
-                            rsafe = jnp.clip(rows, 0)
-                            cat_ids = jnp.concatenate(
-                                [ids, jnp.where(take, rows, -1)]
-                            )
-                            cat_prio = jnp.concatenate([rq.prio, priot[rsafe]])
-                            cat_dur = jnp.concatenate([rq.dur, durt[rsafe]])
-                            key = jnp.where(
-                                cat_ids >= 0, -cat_prio, jnp.iinfo(jnp.int32).max
-                            )
-                            _, cat_ids, cat_prio, cat_dur = jax.lax.sort(
-                                (key, cat_ids, cat_prio, cat_dur),
-                                num_keys=1, is_stable=True,
-                            )
-                            rq = rq._replace(
-                                ids=cat_ids[:RB], prio=cat_prio[:RB],
-                                dur=cat_dur[:RB],
-                                count=count + jnp.minimum(nfail, room),
-                                dropped=rq.dropped
-                                + jnp.maximum(nfail - room, 0),
-                            )
-                        # 5. fold arrival-chunk placements at their flat
-                        # wave positions (re-tried placements stay in the
-                        # queue's record: their arrival slot keeps PAD so
-                        # the static release entry never fires).
-                        with stage("ksim.release"):
-                            vassign = jax.lax.dynamic_update_slice(
-                                vassign,
-                                choices.reshape(-1),
-                                (b * idx.size,),
-                            )
-                        return state, vassign, rq, (counts, retry_placed)
-
-                    axes_retry = (
-                        0, 0, None, None, None, None, None,
-                        None, None, None, None,
-                        None, None, None,
-                        0, 0,
-                    )
-                    vmapped_retry = jax.vmap(
-                        per_scenario_retry, in_axes=axes_retry,
-                        axis_name=_RETRY_VMAP,
-                    )
-                    return finalize(vmapped_retry, axes_retry, (1, 14, 15))
-
-                # vmap matches in_axes against the args actually
-                # passed; with policies on, a literal None rides the
-                # dyn slot (no leaves — its axis spec is inert) and
-                # the [S, K] policy matrix maps on axis 0.
-                axes_rel = [0, 0, None, None, None, None, 0]
-                if dyn_on:
-                    axes_rel.append(0)
-                elif pol_on:
-                    axes_rel.append(None)
-                if pol_on:
-                    axes_rel.append(0)
-                vmapped_rel = jax.vmap(
-                    per_scenario_rel, in_axes=tuple(axes_rel)
+                axes_retry = (
+                    0, 0, None, None, None, None, None,
+                    None, None, None, None,
+                    None, None, None,
+                    0, 0,
                 )
-                return finalize(vmapped_rel, tuple(axes_rel), (1, 6))
-            # vmap matches in_axes against the args actually passed,
-            # so the defaulted dyn arg needs no wrapper.
-            axes_src = [0, 0, None, None, None]
+                vmapped_retry = jax.vmap(
+                    per_scenario_retry, in_axes=axes_retry,
+                    axis_name=_RETRY_VMAP,
+                )
+                return finalize(vmapped_retry, axes_retry, (1, 14, 15))
+
+            # vmap matches in_axes against the args actually
+            # passed; with policies on, a literal None rides the
+            # dyn slot (no leaves — its axis spec is inert) and
+            # the [S, K] policy matrix maps on axis 0.
+            axes_rel = [0, 0, None, None, None, None, 0]
             if dyn_on:
-                axes_src.append(0)
+                axes_rel.append(0)
             elif pol_on:
-                axes_src.append(None)
+                axes_rel.append(None)
             if pol_on:
-                axes_src.append(0)
-            vmapped_src = jax.vmap(
-                per_scenario_src, in_axes=tuple(axes_src)
+                axes_rel.append(0)
+            vmapped_rel = jax.vmap(
+                per_scenario_rel, in_axes=tuple(axes_rel)
             )
-            return finalize(vmapped_src, tuple(axes_src), (1,))
-
-        def per_scenario(dc, state, slots, wvec=None):
-            d = T.Derived.build(dc)
-            wave_step = make_wave_step(dc, d, wave_width, spec, wvec=wvec)
-
-            def step(st, slot_batch):
-                st, choices = wave_step(st, slot_batch)
-                placed_w = jnp.sum((choices >= 0) & slot_batch.valid).astype(jnp.int32)
-                out = choices if collect else placed_w
-                return st, out
-
-            state, outs = jax.lax.scan(step, state, slots)
-            return state, outs
-
-        axes_v2 = (0, 0, None, 0) if pol_on else (0, 0, None)
-        vmapped = jax.vmap(per_scenario, in_axes=axes_v2)
-        return finalize(vmapped, axes_v2, (1,))
+            return finalize(vmapped_rel, tuple(axes_rel), (1, 6))
+        # vmap matches in_axes against the args actually passed,
+        # so the defaulted dyn arg needs no wrapper.
+        axes_src = [0, 0, None, None, None]
+        if dyn_on:
+            axes_src.append(0)
+        elif pol_on:
+            axes_src.append(None)
+        if pol_on:
+            axes_src.append(0)
+        vmapped_src = jax.vmap(
+            per_scenario_src, in_axes=tuple(axes_src)
+        )
+        return finalize(vmapped_src, tuple(axes_src), (1,))
 
     def _release_core(self):
         """Shared device release-update core (cached): subtract a K-list
@@ -2019,18 +1955,16 @@ class WhatIfEngine:
         return fn
 
     def _state_proto(self):
-        if self.engine == "v3":
-            from ..ops import tpu3 as V3
+        from ..ops import tpu3 as V3
 
-            # Real domain width: host_part indexes planes with actual
-            # domain ids, so width-1 placeholders would go out of bounds.
-            D = max(self.ec.max_domains, 1)
-            z = np.zeros((self.static3.G, D), np.float32)
-            return V3.DevState3.from_host(
-                np.zeros((self.ec.num_nodes, self.ec.num_resources), np.float32),
-                z, z, z, self.ec, self.static3,
-            )
-        return T.DevState.init(self.ec)
+        # Real domain width: host_part indexes planes with actual
+        # domain ids, so width-1 placeholders would go out of bounds.
+        D = max(self.ec.max_domains, 1)
+        z = np.zeros((self.static3.G, D), np.float32)
+        return V3.DevState3.from_host(
+            np.zeros((self.ec.num_nodes, self.ec.num_resources), np.float32),
+            z, z, z, self.ec, self.static3,
+        )
 
     def _load_fork_or_init(self):
         """Fork bookkeeping shared by every engine path: (used, match_count)
@@ -2091,7 +2025,7 @@ class WhatIfEngine:
                 self._mesh_batch["put_s"] += time.perf_counter() - t
         return out
 
-    def _init_states(self, span=None) -> T.DevState:
+    def _init_states(self, span=None):
         """The batch's initial [S, ...] state stack. ``span`` is the run's
         span primitive (for ``mesh_put``); a caller outside ``run()`` gets
         one of its own."""
@@ -2114,62 +2048,35 @@ class WhatIfEngine:
             host.pref_wsum = ck.pref_wsum
         else:
             host = init_state(self.ec, self.pods)  # pre-bound pods
-        if self.engine == "v3":
-            from ..ops import tpu3 as V3
+        from ..ops import tpu3 as V3
 
-            one = V3.DevState3.from_host(
-                host.used, host.match_count, host.anti_active, host.pref_wsum,
-                self.ec, self.static3, ep=self.pods,
-            )
-            # ONE jitted broadcast dispatch instead of a jnp.repeat
-            # round-trip per leaf. Under a mesh the one state is replicated
-            # and every device broadcasts its own scenarios' share: the
-            # [S, ...] stack is born sharded and never lies whole on one
-            # device to be dealt out again.
-            S = self.S
-            _bc = lambda s: jax.tree.map(
-                lambda a: jnp.broadcast_to(a[None], (S,) + a.shape), s
-            )
-            if self.mesh is not None:
-                one = self._mesh_put(span, one, replicate=True)
-            states_fn = self._jit_once("states", lambda: (
-                jax.jit(_bc, out_shardings=scenario_sharding(self.mesh))
-                if self.mesh is not None
-                else jax.jit(_bc)
-            ))
-            if self.mesh is not None and not self.fork_checkpoint:
-                self._state_one_mesh = one
-            elif not self.fork_checkpoint:
-                # static per engine, as under a mesh: the host fold of the
-                # pre-bound pods and the upload run once
-                self._state_one = one
-            return states_fn(one)
-        G, D = host.match_count.shape[0], self.D
-        # Domain dim may have grown (label perturbations) → pad.
-        mc = np.zeros((G, D), np.float32)
-        mc[:, : host.match_count.shape[1]] = host.match_count
-        aa = np.zeros((G, D), np.float32)
-        aa[:, : host.anti_active.shape[1]] = host.anti_active
-        pw = np.zeros((G, D), np.float32)
-        pw[:, : host.pref_wsum.shape[1]] = host.pref_wsum
-        # Node-space state depends on each scenario's node→domain table
-        # (label perturbations change domains).
-        nd = np.asarray(self.sset.dc.node_domain)  # [S, T, N]
-        gt = np.clip(self.ec.group_topo, 0, None)
-        gdom_s = np.where(
-            self.ec.group_topo[None, :, None] >= 0, nd[:, gt, :], PAD
-        )  # [S, G, N]
-        to_nodes = lambda arr: jnp.asarray(
-            np.stack([T.domain_to_node_space(arr, gdom_s[s]) for s in range(self.S)])
+        one = V3.DevState3.from_host(
+            host.used, host.match_count, host.anti_active, host.pref_wsum,
+            self.ec, self.static3, ep=self.pods,
         )
-        rep = lambda a: jnp.asarray(np.repeat(a[None], self.S, axis=0))
-        return T.DevState(
-            used=rep(host.used),
-            match_count=to_nodes(mc),
-            anti_active=to_nodes(aa),
-            pref_wsum=to_nodes(pw),
-            match_total=rep(mc.sum(axis=1).astype(np.float32)),
+        # ONE jitted broadcast dispatch instead of a jnp.repeat
+        # round-trip per leaf. Under a mesh the one state is replicated
+        # and every device broadcasts its own scenarios' share: the
+        # [S, ...] stack is born sharded and never lies whole on one
+        # device to be dealt out again.
+        S = self.S
+        _bc = lambda s: jax.tree.map(
+            lambda a: jnp.broadcast_to(a[None], (S,) + a.shape), s
         )
+        if self.mesh is not None:
+            one = self._mesh_put(span, one, replicate=True)
+        states_fn = self._jit_once("states", lambda: (
+            jax.jit(_bc, out_shardings=scenario_sharding(self.mesh))
+            if self.mesh is not None
+            else jax.jit(_bc)
+        ))
+        if self.mesh is not None and not self.fork_checkpoint:
+            self._state_one_mesh = one
+        elif not self.fork_checkpoint:
+            # static per engine, as under a mesh: the host fold of the
+            # pre-bound pods and the upload run once
+            self._state_one = one
+        return states_fn(one)
 
     def _subtract_stacked_planes(self, states, used_d, mc_d, aa_d, pw_d):
         """Scenario-stacked host-layout delta planes ([S, N, R] /
@@ -3253,13 +3160,11 @@ class WhatIfEngine:
                 if self.mesh is not None:
                     # The scenario tables are static per scenario batch: sharded
                     # over the devices at the engine's first run and kept (each
-                    # run() used to deal them out again from device 0). The v3
+                    # run() used to deal them out again from device 0). The
                     # state stack is born sharded (_init_states).
                     if self._dc_mesh is None:
                         self._dc_mesh = self._mesh_put(span, dc)
                     dc = self._dc_mesh
-                    if self.engine != "v3":
-                        states = self._mesh_put(span, states)
                 comp_on = (
                     self.completions_on
                     and not self._completions_dev
@@ -3402,10 +3307,9 @@ class WhatIfEngine:
                     if self.mesh is not None:
                         pol_d = shard_scenario_tree(self.mesh, pol_d)
                 srcs = self._slot_srcs
-                idx_chunks = None
-                if srcs is not None and self._idx_chunks_mesh is not None:
+                if self._idx_chunks_mesh is not None:
                     idx_chunks = self._idx_chunks_mesh
-                elif srcs is not None:
+                else:
                     idx_chunks = [
                         jnp.asarray(idx[c0 : c0 + C])
                         for c0 in range(0, idx.shape[0], C)
@@ -3673,7 +3577,7 @@ class WhatIfEngine:
                 # cadence, publish a compressed host snapshot of the loop
                 # carriers so a survivor can resume THIS block mid-replay after
                 # a host loss. Supported on the device-carrier paths (plain
-                # v3/v2 and device-release ± retry, where the whole block state
+                # and device-release ± retry, where the whole block state
                 # lives in `states`/`vassign`/retry tensors plus `outs`); the
                 # host-fold modes (completions host path, kube mirrors) carry
                 # state in per-scenario host structures instead — a claimed
@@ -3997,7 +3901,7 @@ class WhatIfEngine:
                             args = args + (pol_d,)
                         _reg(self._chunk_fn, args)
                         states, vassign_d, out = self._chunk_fn(*args)
-                    elif self.engine == "v3":
+                    else:
                         # Fused device-side gather + wave scan: one dispatch per
                         # chunk, indices pre-staged (ops.tpu.SlotSource). Under a
                         # mesh the sources are replicated once per engine and
@@ -4010,14 +3914,6 @@ class WhatIfEngine:
                         if pol_d is not None:
                             args = args + (pol_d,)
                         _reg(self._chunk_fn, args)
-                        states, out = self._chunk_fn(*args)
-                    else:
-                        slots = T.gather_slots(self.pods, idx[c0 : c0 + C])
-                        if self.mesh is not None:
-                            slots = replicate_tree(self.mesh, slots)
-                        args = (dc, states, slots)
-                        if pol_d is not None:
-                            args = args + (pol_d,)
                         states, out = self._chunk_fn(*args)
                 if pre_comp:
                     # Deferred eviction-aware fold (round 6): fetch only the
@@ -4042,12 +3938,7 @@ class WhatIfEngine:
                     # only the [S] failure count is fetched per chunk; the
                     # full choices land after the next dispatch (or eagerly
                     # at the next boundary if any retry pass needs them).
-                    ix_dev = (
-                        idx_chunks[ci]
-                        if idx_chunks is not None
-                        else jnp.asarray(idx[c0 : c0 + C])
-                    )
-                    nf_d = self._kfail_jit(out, ix_dev, kube_ng)
+                    nf_d = self._kfail_jit(out, idx_chunks[ci], kube_ng)
                     if hasattr(out, "copy_to_host_async"):
                         out.copy_to_host_async()
                     _kfold_pending()
@@ -4244,11 +4135,9 @@ class WhatIfEngine:
                 util = None
                 ri = self.ec.vocab._r.get("cpu")
                 if ri is not None:
-                    v3_layout = self.engine == "v3"
-
                     def _util(used, alloc):
                         a = alloc[:, :, ri]  # [S, N]
-                        u_row = used[:, ri, :] if v3_layout else used[:, :, ri]
+                        u_row = used[:, ri, :]
                         u = jnp.where(a > 0, u_row / jnp.where(a > 0, a, 1.0), 0.0)
                         return u.mean(axis=1)
 
@@ -4324,28 +4213,27 @@ class WhatIfEngine:
                 fleet_local.phases = run_phases.summary()
                 fleet_local.chunk_waves = int(C)
                 fleet_local.scenarios = int(self.S)
-                if self.engine == "v3":
-                    from ..ops import tpu3 as V3
+                from ..ops import tpu3 as V3
 
-                    fleet_local.select_form = V3.select_form(
-                        self.static3, self.spec, self.ec.num_nodes,
-                        traced_weights=self._policies is not None,
-                        dyn_labels=self._dyn_dev is not None,
+                fleet_local.select_form = V3.select_form(
+                    self.static3, self.spec, self.ec.num_nodes,
+                    traced_weights=self._policies is not None,
+                    dyn_labels=self._dyn_dev is not None,
+                )
+                fleet_local.inwave_corrections = V3.inwave_corrections(
+                    self.static3, scenario_axis=True
+                )
+                fleet_local.count_planes = V3.count_planes(
+                    self.static3, scenario_axis=True
+                )
+                reads = {"arrival": V3.class_row_reads(self.static3, True)}
+                if retry_block is not None:  # the batch ran the retry pass
+                    reads["retry"] = V3.class_row_reads(
+                        self.static3, True, slots_by_scenario=True
                     )
-                    fleet_local.inwave_corrections = V3.inwave_corrections(
-                        self.static3, scenario_axis=True
-                    )
-                    fleet_local.count_planes = V3.count_planes(
-                        self.static3, scenario_axis=True
-                    )
-                    reads = {"arrival": V3.class_row_reads(self.static3, True)}
-                    if retry_block is not None:  # the batch ran the retry pass
-                        reads["retry"] = V3.class_row_reads(
-                            self.static3, True, slots_by_scenario=True
-                        )
-                    fleet_local.class_row_reads = {
-                        **reads, **V3.class_planes(self.static3, self.spec)
-                    }
+                fleet_local.class_row_reads = {
+                    **reads, **V3.class_planes(self.static3, self.spec)
+                }
                 if dev_rel:
                     fleet_local.release_buckets = sorted(rel_buckets)
                     fleet_local.release_rounds = release_rounds

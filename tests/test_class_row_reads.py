@@ -208,7 +208,7 @@ def _lowered_text(program):
     ec, ep = _two_class_planes()
     cfg = FrameworkConfig()
     if program == "replay":
-        eng = JaxReplayEngine(ec, ep, cfg, engine="v3", wave_width=W,
+        eng = JaxReplayEngine(ec, ep, cfg, wave_width=W,
                               chunk_waves=C)
         name = "chunk_fn"
     else:

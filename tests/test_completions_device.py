@@ -74,7 +74,7 @@ def test_completion_decrements_count_planes():
     np.testing.assert_array_equal(res.assignments, anchor.assignments)
 
 
-def test_completions_parity_random_both_engines():
+def test_completions_parity_random():
     cluster = make_cluster(12, seed=3, taint_fraction=0.2)
     pods, _ = make_workload(
         80, seed=3, arrival_rate=10.0, duration_mean=2.0,
@@ -83,11 +83,8 @@ def test_completions_parity_random_both_engines():
     ec, ep = encode(cluster, pods)
     cfg = FrameworkConfig()
     anchor = greedy_replay(ec, ep, cfg, wave_width=4, completions_chunk_waves=4)
-    for engine in ("v3", "v2"):
-        dev = JaxReplayEngine(
-            ec, ep, cfg, wave_width=4, chunk_waves=4, engine=engine
-        ).replay()
-        np.testing.assert_array_equal(dev.assignments, anchor.assignments), engine
+    dev = JaxReplayEngine(ec, ep, cfg, wave_width=4, chunk_waves=4).replay()
+    np.testing.assert_array_equal(dev.assignments, anchor.assignments)
     # Releases must actually matter on this trace, or the test is vacuous.
     off = greedy_replay(ec, ep, cfg, wave_width=4)
     assert (anchor.assignments != off.assignments).any()

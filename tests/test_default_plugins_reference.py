@@ -253,5 +253,6 @@ def test_the_device_normalize_divides_exactly():
         np.testing.assert_array_equal(np.asarray(got), want)
     w = jnp.arange(1, 100, dtype=jnp.float32)
     for x in np.asarray(w):
-        row = T.normalize_max(jnp.asarray([x, 0.0]), jnp.ones(2, bool))
+        row = T._normalize_row(jnp.asarray([x, 0.0]), None, x, None,
+                               minmax=False, reverse=False)
         assert row.tolist() == [100.0, 0.0]

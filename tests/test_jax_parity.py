@@ -143,43 +143,3 @@ def test_parity_bootstrap_on_domainless_node():
     cpu_res, jax_res = assert_parity(Cluster(nodes=nodes), pods)
     # Both pods bootstrap onto the bare node; neither may be unschedulable.
     assert cpu_res.placed == 2
-
-
-@pytest.mark.slow
-def test_fused_eval_matches_reference_chain():
-    """eval_pod_fused must be BIT-identical to the straight-line reference
-    chain eval_pod — walks real waves, comparing mask and (feasible-masked)
-    scores at every slot. This is what licenses the 'bit-identical' claims
-    in ops/tpu.py and keeps the reference chain from rotting."""
-    import jax
-
-    from kubernetes_simulator_tpu.ops import tpu as T
-    from kubernetes_simulator_tpu.sim.jax_runtime import StepSpec, eval_pod
-    from kubernetes_simulator_tpu.sim.waves import pack_waves
-
-    for seed in range(2):
-        cluster = make_cluster(50, seed=seed, taint_fraction=0.3)
-        pods, _ = make_workload(
-            160, seed=seed, with_affinity=True, with_spread=True,
-            with_tolerations=True, gang_fraction=0.1, gang_size=3,
-        )
-        ec, ep = encode(cluster, pods)
-        spec = StepSpec.from_config(ec, FrameworkConfig(), ep)
-        dc = T.DevCluster.from_encoded(ec)
-        d = T.Derived.build(dc)
-        sb = T.gather_slots(ep, pack_waves(ep, 8).idx)
-        st = T.DevState.init(ec)
-        for wi in range(sb.pod_id.shape[0]):
-            slot_batch = jax.tree.map(lambda a: a[wi], sb)
-            pre = T.build_wave_pre(dc, d, slot_batch, spec)
-            widths = T.wave_widths(slot_batch, spec)
-            for k in range(8):
-                s = jax.tree.map(lambda a: a[k], slot_batch)
-                p = jax.tree.map(lambda a: a[k], pre)
-                f0, sc0 = eval_pod(dc, d, st, s, spec)
-                f1, sc1, _ = T.eval_pod_fused(dc, d, st, s, p, spec, widths)
-                np.testing.assert_array_equal(np.asarray(f0), np.asarray(f1))
-                m = np.asarray(f0)
-                np.testing.assert_array_equal(np.asarray(sc0)[m], np.asarray(sc1)[m])
-                node, placed = T.select_node(sc0, f0)
-                st = T.apply_binding(d, st, s, node, placed & s.valid)

@@ -83,8 +83,6 @@ def test_whatif_preemption_matches_single_replay():
 def test_preemption_guards():
     ec, ep = _tight_case(0)
     with pytest.raises(ValueError):
-        JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v2", preemption=True)
-    with pytest.raises(ValueError):
         JaxReplayEngine(ec, ep, FrameworkConfig(), preemption=True).replay(
             checkpoint_path="/tmp/x.npz", checkpoint_every=1
         )

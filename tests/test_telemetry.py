@@ -5,9 +5,9 @@ Chrome-trace exporter.
 The cross-engine contracts under test: at W=1 / C=1 on queue-trivial
 traces the CPU event engine and the device path produce bit-identical
 latency summaries and per-episode rejection reasons (the device is
-chunk-granular but the crafted instants coincide); ``summary``
-granularity never changes a device program; telemetry state never leaks
-into checkpoint blobs."""
+chunk-granular but the crafted instants coincide); no granularity
+changes a device program; telemetry state never leaks into checkpoint
+blobs."""
 
 import json
 
@@ -120,7 +120,7 @@ def test_phase_timer_names_stable():
     assert set(res.telemetry.phases) == set(PHASE_NAMES) - {"handback"}
 
 
-# -- rejection attribution parity (plain path, in-scan counters) ----------
+# -- rejection attribution parity (plain path, host mirror) ---------------
 
 
 def _reject_trace(num_pods=10):
@@ -139,17 +139,16 @@ def _reject_trace(num_pods=10):
     return encode(Cluster(nodes=nodes), pods)
 
 
-@pytest.mark.parametrize("engine", ["v2", "v3"])
-def test_plain_rejection_attribution_matches_cpu(engine):
-    """Device in-scan [K] reject counters (series granularity) bit-match
-    the CPU event engine's per-episode reasons at W=1/C=1 — including the
-    v3 path, which swaps in the v2-reference instrumented program."""
+def test_plain_rejection_attribution_matches_cpu():
+    """The plain path's first-reject counts (series granularity: the CPU
+    framework's filter chain over a host mirror of the device program's
+    answers) bit-match the CPU event engine's per-episode reasons at
+    W=1/C=1."""
     ec, ep = _reject_trace()
     cfg = FrameworkConfig()
     cpu = CpuReplayEngine(ec, ep, cfg, telemetry="series").replay()
     dev = JaxReplayEngine(
-        ec, ep, cfg, wave_width=1, chunk_waves=1, engine=engine,
-        telemetry="series",
+        ec, ep, cfg, wave_width=1, chunk_waves=1, telemetry="series",
     ).replay()
     np.testing.assert_array_equal(cpu.assignments, dev.assignments)
     assert cpu.telemetry.reasons == dev.telemetry.reasons
@@ -165,22 +164,103 @@ def test_plain_rejection_attribution_matches_cpu(engine):
     assert cpu.telemetry.latency == dev.telemetry.latency
 
 
-def test_summary_granularity_keeps_device_program():
-    """The default granularity must never swap in the instrumented chunk
-    program (bench safety): the engine reuses the plain chunk_fn and the
-    placements equal the off-telemetry run."""
+@pytest.mark.parametrize("gran", ["summary", "series", "timeline"])
+def test_no_granularity_builds_a_second_chunk_program(gran):
+    """Every granularity runs the engine's ONE chunk program (bench
+    safety): one compiled executable after the replay, no other jitted
+    chunk function on the engine, and the placements of the off-telemetry
+    run."""
+    from kubernetes_simulator_tpu.sim.jax_runtime import compiled_cache_size
+
     ec, ep = _reject_trace()
     eng = JaxReplayEngine(
         ec, ep, FrameworkConfig(), wave_width=1, chunk_waves=1,
-        telemetry="summary",
+        telemetry=gran,
     )
+    chunk_fn = eng.chunk_fn
     res = eng.replay()
-    assert not hasattr(eng, "_chunk_fn_rej")  # never built
+    assert eng.chunk_fn is chunk_fn and compiled_cache_size(chunk_fn) == 1
+    assert [k for k in vars(eng) if "chunk_fn" in k] == ["chunk_fn"]
     off = JaxReplayEngine(
         ec, ep, FrameworkConfig(), wave_width=1, chunk_waves=1,
         telemetry="off",
     ).replay()
     np.testing.assert_array_equal(res.assignments, off.assignments)
+
+
+def _gang_reject_trace():
+    """Two nodes under W=8, ten 2-cpu pods: p0-p2 fill n0 (cpu=6); p3 and
+    p4 fail in the same wave on the state those binds left
+    (NodeResourcesFit charged n0, TaintToleration the tainted n1); p5-p7
+    are a pod group that tolerates the taint, of which n1 (cpu=4) holds
+    two: rolled back; p8 and p9, a wave later, fail as p3 did."""
+    from kubernetes_simulator_tpu.models.core import Toleration
+
+    nodes = [
+        Node("n0", {"cpu": 6.0}),
+        Node("n1", {"cpu": 4.0},
+             taints=[Taint("dedicated", "infra", "NoSchedule")]),
+    ]
+    pods = [
+        Pod(f"p{i}", requests={"cpu": 2.0}, arrival_time=float(i),
+            pod_group="g" if 5 <= i < 8 else None,
+            tolerations=([Toleration("dedicated", "Equal", "infra")]
+                         if 5 <= i < 8 else []))
+        for i in range(10)
+    ]
+    return encode(Cluster(nodes=nodes), pods)
+
+
+def test_series_attribution_at_a_wide_wave_is_the_cpu_frameworks():
+    """At W=8 the plain path's counts are the CPU framework's own on the
+    same answers, folded in slot order: the rolled-back group charges
+    nothing, and a slot is charged on the binds of the slots before it in
+    its own wave (the state before the chunk would call p3 feasible)."""
+    from kubernetes_simulator_tpu.framework.framework import SchedulerFramework
+    from kubernetes_simulator_tpu.models.encode import PAD
+    from kubernetes_simulator_tpu.models.state import bind, init_state
+
+    ec, ep = _gang_reject_trace()
+    cfg = FrameworkConfig()
+    eng = JaxReplayEngine(ec, ep, cfg, wave_width=8, chunk_waves=1,
+                          telemetry="series")
+    res = eng.replay()
+    assert (res.assignments[:3] == 0).all() and (res.assignments[3:] == PAD).all()
+    assert res.telemetry.latency["count"] == res.placed == 3
+    fw, st = SchedulerFramework(ec, ep, cfg), init_state(ec, ep)
+    want = {}
+    for p in eng.waves.idx[eng.waves.idx >= 0]:
+        if res.assignments[p] >= 0:
+            bind(ec, ep, st, int(p), int(res.assignments[p]))
+            continue
+        rc = {}
+        if not fw.feasible_mask(st, int(p), reject_counts=rc).any():
+            for k, v in rc.items():
+                want[k] = want.get(k, 0) + v
+    assert res.telemetry.reasons == want
+    assert want == {"NodeResourcesFit": 4, "TaintToleration": 4}
+    assert res.telemetry.rejection_attempts == want
+
+
+@pytest.mark.parametrize("wave_width, chunk_waves", [(8, 1), (4, 1), (4, 2)])
+def test_series_places_as_summary(wave_width, chunk_waves):
+    """``series`` runs the program ``summary`` runs: the same placements,
+    bit for bit, on a trace with failures and a rolled-back group."""
+    ec, ep = _gang_reject_trace()
+    runs = {
+        gran: JaxReplayEngine(
+            ec, ep, FrameworkConfig(), wave_width=wave_width,
+            chunk_waves=chunk_waves, telemetry=gran,
+        ).replay()
+        for gran in ("summary", "series")
+    }
+    np.testing.assert_array_equal(
+        runs["summary"].assignments, runs["series"].assignments
+    )
+    assert runs["series"].unschedulable > 0 and runs["series"].telemetry.reasons
+    np.testing.assert_array_equal(
+        runs["summary"].state.used, runs["series"].state.used
+    )
 
 
 # -- boundary-retry latency parity ---------------------------------------
@@ -327,14 +407,14 @@ def test_chrome_trace_export(tmp_path):
 
 def test_series_attribution_fallback_notes(caplog, tmp_path):
     """series+ attribution fallback pin: in-scan tier preemption and
-    checkpoint/resume each disable the instrumented chunk program with a
-    log note — placements stay unchanged and latency/phase telemetry is
-    still collected; only ``reasons`` goes dark."""
+    checkpoint/resume each disable the host mirror with a log note —
+    placements stay unchanged and latency/phase telemetry is still
+    collected; only ``reasons`` goes dark."""
     import logging
 
     ec, ep = _reject_trace()
     cfg = FrameworkConfig()
-    # Tier preemption: the instrumented program has no tier planes.
+    # Tier preemption: the mirror cannot follow the scan's evictions.
     ref = JaxReplayEngine(ec, ep, cfg, wave_width=1, chunk_waves=1,
                           preemption=True, telemetry="summary").replay()
     with caplog.at_level(logging.INFO, logger="k8sim"):
@@ -344,7 +424,7 @@ def test_series_attribution_fallback_notes(caplog, tmp_path):
     np.testing.assert_array_equal(ref.assignments, res.assignments)
     assert res.telemetry is not None and not res.telemetry.reasons
     assert res.telemetry.latency["count"] == res.placed
-    # Checkpointing: the instrumented carry is not part of checkpoints.
+    # Checkpointing: the mirror is not part of checkpoints.
     caplog.clear()
     plain = JaxReplayEngine(ec, ep, cfg, wave_width=1, chunk_waves=1,
                             telemetry="series").replay()
@@ -356,7 +436,7 @@ def test_series_attribution_fallback_notes(caplog, tmp_path):
     assert "disabled under checkpoint/resume" in caplog.text
     np.testing.assert_array_equal(plain.assignments, ck.assignments)
     assert ck.telemetry is not None and not ck.telemetry.reasons
-    assert plain.telemetry.reasons is not None  # instrumented run still works
+    assert plain.telemetry.reasons  # the plain run still attributes
 
 
 # -- round 12: mergeable telemetry / fleet observability -------------------

@@ -1,7 +1,6 @@
-"""v3 engine (domain-space state, wave-deferred commits) must match the
-v2 node-space engine and the CPU greedy oracle EXACTLY — including with
-the host-plane path forced on (tiny dmax_coarse) and with the class-mask
-fallback disabled/enabled."""
+"""The device engine (domain-space state, wave-deferred commits) must match
+the CPU greedy oracle EXACTLY — including with the host-plane path forced
+on (tiny dmax_coarse) and with the class-mask fallback disabled/enabled."""
 
 import numpy as np
 import pytest
@@ -25,13 +24,11 @@ def _case(seed, n_nodes=60, n_pods=240):
 def _assert_same(ec, ep, **kw):
     cfg = FrameworkConfig()
     cpu = greedy_replay(ec, ep, cfg)
-    v2 = JaxReplayEngine(ec, ep, cfg, engine="v2").replay()
-    v3 = JaxReplayEngine(ec, ep, cfg, engine="v3", **kw).replay()
-    np.testing.assert_array_equal(cpu.assignments, v2.assignments)
+    v3 = JaxReplayEngine(ec, ep, cfg, **kw).replay()
     np.testing.assert_array_equal(cpu.assignments, v3.assignments)
-    np.testing.assert_allclose(v2.state.used, v3.state.used, atol=1e-3)
-    np.testing.assert_allclose(v2.state.match_count, v3.state.match_count, atol=1e-5)
-    np.testing.assert_allclose(v2.state.anti_active, v3.state.anti_active, atol=1e-5)
+    np.testing.assert_allclose(cpu.state.used, v3.state.used, atol=1e-3)
+    np.testing.assert_allclose(cpu.state.match_count, v3.state.match_count, atol=1e-5)
+    np.testing.assert_allclose(cpu.state.anti_active, v3.state.anti_active, atol=1e-5)
     return v3
 
 
@@ -39,7 +36,7 @@ def _assert_same(ec, ep, **kw):
     "seed", [0, pytest.param(1, marks=pytest.mark.slow),
              pytest.param(2, marks=pytest.mark.slow)]
 )
-def test_v3_matches_v2_and_cpu(seed):
+def test_v3_matches_cpu(seed):
     ec, ep = _case(seed)
     _assert_same(ec, ep)
 
@@ -198,9 +195,9 @@ def test_v3_plane_equals_terms_bit_for_bit(seed, monkeypatch):
 
     ec, ep = _nondyadic(seed)
     cfg = FrameworkConfig()
-    plane = JaxReplayEngine(ec, ep, cfg, engine="v3").replay()
+    plane = JaxReplayEngine(ec, ep, cfg).replay()
     monkeypatch.setattr(V3, "inwave_corrections", lambda *a, **k: "terms")
-    terms = JaxReplayEngine(ec, ep, cfg, engine="v3").replay()
+    terms = JaxReplayEngine(ec, ep, cfg).replay()
     assert plane.telemetry.summary()["inwave_corrections"] == "plane"
     assert terms.telemetry.summary()["inwave_corrections"] == "terms"
     assert plane.unschedulable > 0  # contended: fit edges are met
@@ -234,7 +231,7 @@ def _node_wide_compares(ec, ep, wave_width):
     import jax.numpy as jnp
 
     eng = JaxReplayEngine(
-        ec, ep, FrameworkConfig(), engine="v3", wave_width=wave_width
+        ec, ep, FrameworkConfig(), wave_width=wave_width
     )
     N = ec.num_nodes
     args = (eng.dc, eng._init_dev_state(), eng._slot_src, eng._extra_src,
@@ -382,10 +379,10 @@ def test_v3_resolved_terms_equal_plane_and_terms_bit_for_bit(case, monkeypatch):
     make, collide = _COLLIDING[case]
     ec, ep = make()
     cfg = FrameworkConfig()
-    runs = {"plane": JaxReplayEngine(ec, ep, cfg, engine="v3").replay()}
+    runs = {"plane": JaxReplayEngine(ec, ep, cfg).replay()}
     for form in ("resolved_terms", "terms"):
         _forced(monkeypatch, form)
-        runs[form] = JaxReplayEngine(ec, ep, cfg, engine="v3").replay()
+        runs[form] = JaxReplayEngine(ec, ep, cfg).replay()
     for form, run in runs.items():
         assert run.telemetry.summary()["inwave_corrections"] == form
     got = runs["resolved_terms"]
@@ -406,7 +403,7 @@ def test_whatif_resolved_terms_equal_summed_terms_bit_for_bit(case, monkeypatch)
     scen = _perturbed(ec.num_nodes, 4)
     res, used = _whatif_used(ec, ep, scen)
     assert res.fleet_telemetry.summary()["inwave_corrections"] == "resolved_terms"
-    single = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v3").replay()
+    single = JaxReplayEngine(ec, ep, FrameworkConfig()).replay()
     np.testing.assert_array_equal(res.assignments[0], single.assignments)
     np.testing.assert_array_equal(used[0], single.state.used.T)
     assert _most_slots_on_one_node(res.assignments[0]) >= collide
@@ -555,7 +552,7 @@ def test_v3_zone_packed_select_on_the_wave_traps(trap, monkeypatch):
     assert tel["select_form"] == "zone_packed"
     assert tel["inwave_corrections"] == "plane"
     _two_pass(monkeypatch)
-    parent = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v3").replay()
+    parent = JaxReplayEngine(ec, ep, FrameworkConfig()).replay()
     assert parent.telemetry.summary()["select_form"] == "two_pass"
     np.testing.assert_array_equal(v3.assignments, parent.assignments)
     np.testing.assert_array_equal(v3.state.used, parent.state.used)
@@ -608,7 +605,7 @@ def _lowered_sha(ec, ep, cfg):
 
     import jax.numpy as jnp
 
-    eng = JaxReplayEngine(ec, ep, cfg, engine="v3", wave_width=4, chunk_waves=4)
+    eng = JaxReplayEngine(ec, ep, cfg, wave_width=4, chunk_waves=4)
     args = (eng.dc, eng._init_dev_state(), eng._slot_src, eng._extra_src,
             jnp.asarray(eng.waves.idx[:4]))
     text = eng.chunk_fn.lower(*args).as_text()
@@ -999,7 +996,7 @@ def _run_with_count_planes(ec, ep, mapping):
     """(every pod's node, the final count planes by name, the static facts)
     of a single replay or of a 4-scenario arrivals-only what-if."""
     if mapping == "replay":
-        eng = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v3",
+        eng = JaxReplayEngine(ec, ep, FrameworkConfig(),
                               wave_width=8, chunk_waves=4)
         res = eng.replay()
         return (res.assignments, {"match_count": res.state.match_count,
@@ -1495,7 +1492,7 @@ def test_a_preferred_hostname_term_keeps_the_dot_and_the_one_hot():
     for pod in pods[::5]:
         pod.pod_affinity = near
     ec, ep = encode(cluster, pods)
-    eng = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v3", wave_width=8,
+    eng = JaxReplayEngine(ec, ep, FrameworkConfig(), wave_width=8,
                           chunk_waves=4)
     st = eng.static3
     assert len(st.pref_h_ids) and st.single_g[st.pref_h_ids].all()
@@ -1503,6 +1500,6 @@ def test_a_preferred_hostname_term_keeps_the_dot_and_the_one_hot():
     assert form["dot"] == len(st.pref_h_ids) and form["rows"] == 0
     assert form["elementwise"] == len(st.mc_h_ids) + len(st.anti_h_ids) > 0
     assert V3.host_commit_form(st, scenario_axis=True)["dot"] == form["dot"]
-    v2 = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v2").replay()
+    cpu = greedy_replay(ec, ep, FrameworkConfig())
     v3 = eng.replay()
-    np.testing.assert_array_equal(v2.assignments, v3.assignments)
+    np.testing.assert_array_equal(cpu.assignments, v3.assignments)

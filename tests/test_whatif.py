@@ -167,23 +167,78 @@ def test_injected_prefer_taint_reenables_score_row():
     np.testing.assert_array_equal(res.assignments[1], ref.assignments)
 
 
-def _force_v2(ec, ep, scen, cfg, **kw):
-    """The v2 node-space engine as the labels_dirty parity pin."""
-    eng = WhatIfEngine(ec, ep, scen, cfg, **kw)
-    if eng.engine != "v2":
-        eng.engine = "v2"
-        eng._dyn = None
-        eng._dyn_dev = None
-        eng._slot_srcs = None
-        eng._chunk_fn = eng._build_chunk_fn()
-    return eng
+ZONE = "topology.kubernetes.io/zone"
+
+
+def _relabel(nodes, key=ZONE, value="zone-1"):
+    return Scenario([Perturbation(
+        "set_label", nodes=np.asarray(nodes, np.int64), key=key, value=value)])
+
+
+def _envelope_case(reason, tmp_path):
+    """(ec, ep, scenarios, engine keywords) of a small ``set_label`` batch
+    outside the DynTables envelope for ``reason`` alone."""
+    kw = {}
+    cluster = make_cluster(40, seed=3)
+    pods, _ = make_workload(30, seed=3, with_spread=True)
+    scen = [Scenario(), _relabel([0, 4])]
+    if reason == "host-scale topology change":
+        # 150 hostnames: a host-scale topology (ops.tpu3.DMAX_COARSE = 128)
+        # that the affinity terms name.
+        cluster = make_cluster(150, seed=1)
+        pods, _ = make_workload(50, seed=1, with_affinity=True)
+        scen = [Scenario(), _relabel([0], "kubernetes.io/hostname", "elsewhere")]
+    elif reason == ">32 perturbed nodes/scenario (K=33)":
+        scen = [Scenario(), _relabel(np.arange(33))]
+    elif reason == "fork checkpoint":
+        ec, ep = encode(cluster, pods)
+        kw["fork_checkpoint"] = str(tmp_path / "ck.npz")
+        JaxReplayEngine(ec, ep, FrameworkConfig(), chunk_waves=2).replay(
+            checkpoint_path=kw["fork_checkpoint"], checkpoint_every=1)
+    elif reason == "pre-bound pods":
+        pods[0].node_name = cluster.nodes[5].name
+    elif reason == "no DynTables":
+        scen = [Scenario(), _relabel([])]
+    elif reason == "preemption":
+        kw["preemption"] = True
+    return (*encode(cluster, pods), scen, kw)
+
+
+@pytest.mark.parametrize("reason", [
+    "host-scale topology change",
+    ">32 perturbed nodes/scenario (K=33)",
+    "fork checkpoint",
+    "pre-bound pods",
+    "no DynTables",
+    "preemption",
+])
+def test_set_label_outside_the_envelope_is_refused(reason, tmp_path):
+    """A ``set_label`` batch the per-scenario domain tables cannot carry is
+    refused when the engine is built, with the reason and the way that
+    still runs it; the same batch without the relabel builds."""
+    ec, ep, scen, kw = _envelope_case(reason, tmp_path)
+    with pytest.raises(ValueError, match="outside the DynTables envelope") as exc:
+        WhatIfEngine(ec, ep, scen, FrameworkConfig(), **kw)
+    assert f"({reason})" in str(exc.value)
+    assert "one single replay per scenario" in str(exc.value)
+    WhatIfEngine(ec, ep, [Scenario(), Scenario()], FrameworkConfig(), **kw)
+
+
+def test_the_envelope_refusal_names_every_reason():
+    ec, ep, scen, _ = _envelope_case(">32 perturbed nodes/scenario (K=33)", None)
+    ep.bound_node[0] = 5
+    with pytest.raises(
+        ValueError,
+        match=r"\(>32 perturbed nodes/scenario \(K=33\), pre-bound pods\)",
+    ):
+        WhatIfEngine(ec, ep, scen, FrameworkConfig())
 
 
 @pytest.mark.slow
-def test_labels_dirty_runs_v3_and_matches_v2_and_scratch():
-    """Round-3 DynTables: label-perturbation batches stay on the v3 engine
-    and must match BOTH the v2 parity engine and a from-scratch replay of
-    each explicitly perturbed cluster. Cases: move to an existing value,
+def test_labels_dirty_runs_v3_and_matches_scratch():
+    """Round-3 DynTables: label-perturbation batches run on per-scenario
+    domain tables and must match a from-scratch replay of each explicitly
+    perturbed cluster. Cases: move to an existing value,
     a NEW value (appended domain id), emptying a domain (its last node
     moves out — the spread min must exclude it), a node GAINING the key,
     and mixed taint/capacity perturbations in the same batch."""
@@ -225,11 +280,6 @@ def test_labels_dirty_runs_v3_and_matches_v2_and_scratch():
     eng = WhatIfEngine(ec, ep, scen, cfg, chunk_waves=4, collect_assignments=True)
     assert eng.engine == "v3" and eng._dyn is not None
     res = eng.run()
-
-    v2 = _force_v2(ec, ep, scen, cfg, chunk_waves=4, collect_assignments=True)
-    assert v2.engine == "v2"
-    res2 = v2.run()
-    np.testing.assert_array_equal(res.assignments, res2.assignments)
 
     # From-scratch replay of each perturbed cluster (label/taint/capacity
     # applied to a copy, re-encoded) — chunk sizes aligned.

@@ -228,7 +228,7 @@ def test_without_a_wide_group_the_chunk_program_is_the_parents(engine):
                             extended_resource=(GPU, 8, 0.3))
     ec, ep = encode(cluster, pods)
     if engine == "replay":
-        eng = JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v3",
+        eng = JaxReplayEngine(ec, ep, FrameworkConfig(),
                               wave_width=8, chunk_waves=4)
         fn, state = eng.chunk_fn, eng._init_dev_state()
         args = (eng.dc, state, eng._slot_src, eng._extra_src)
@@ -266,8 +266,6 @@ def test_what_an_open_transaction_cannot_be_combined_with_is_refused(sixteens):
         WhatIfEngine(*timed, scen, FrameworkConfig(), wave_width=8)
     with pytest.raises(ValueError, match="wider than the wave.*completions"):
         JaxReplayEngine(*timed, FrameworkConfig(), wave_width=8).replay()
-    with pytest.raises(ValueError, match="wider than the wave.*v2 engine"):
-        JaxReplayEngine(ec, ep, FrameworkConfig(), engine="v2", wave_width=8)
     with pytest.raises(ValueError, match="wider than the wave.*retry"):
         JaxReplayEngine(ec, ep, FrameworkConfig(), wave_width=8, retry_buffer=8)
     with pytest.raises(ValueError, match="wider than the wave"):
