@@ -1,0 +1,4 @@
+"""drain_retry_ms_per_boundary: what ``backlog_retry_ms_per_boundary`` reads, in the drained Borg cell (the retry pass re-binds the evicted there), under a name of its own because
+the accepted metric lists its cells and cannot be edited."""
+
+from layer_metrics.backlog_retry_ms_per_boundary import read  # noqa: F401

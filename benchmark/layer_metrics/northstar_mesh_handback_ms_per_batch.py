@@ -1,0 +1,4 @@
+"""northstar_mesh_handback_ms_per_batch: what ``mesh_handback_ms_per_batch`` reads, in the four-chip north-star cell, under a name of its own because
+the accepted metric lists its cells and cannot be edited."""
+
+from layer_metrics.mesh_handback_ms_per_batch import read  # noqa: F401

@@ -821,11 +821,19 @@ def validate_config(cfg) -> list:
                 "— without the boundary retry pass node_down only blocks "
                 "future placements (no NoExecute eviction of bound pods)"
             )
-        if cfg.whatif.scenarios > 0 and cfg.device_preemption != "kube":
+        if (
+            cfg.whatif.scenarios > 0
+            and cfg.device_preemption != "kube"
+            and (cfg.whatif.mesh or cfg.whatif.completions is False)
+        ):
+            # With whatIf.retryBuffer (checked above) the timelines run on
+            # the device retry path: the eviction program, no host mirror.
             errors.append(
-                "chaos what-if sweeps require devicePreemption: kube "
-                "(per-scenario timelines apply through the kube-mode "
-                "host mirrors at chunk boundaries)"
+                "chaos what-if sweeps without devicePreemption: kube run "
+                "on the device retry path, which takes no mesh and needs "
+                "completions (whatIf.mesh: false, whatIf.completions not "
+                "false); with devicePreemption: kube the timelines apply "
+                "through the per-scenario host mirrors"
             )
     tu = cfg.tune
     if tu is not None:

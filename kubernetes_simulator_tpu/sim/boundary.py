@@ -208,6 +208,13 @@ class BoundaryOps:
         self.evict_rescheduled = 0
         self._evict_lat_sum = 0.0
         self._evict_time: Dict[int, float] = {}
+        # Every eviction, in the order made: (boundary, pod, the node it
+        # held, the boundary whose pass had bound it; -1 its arrival wave
+        # or pre-bound). With ``assignments`` and ``bind_boundary_codes``
+        # a pod's history is whole; the what-if device path hands back the
+        # same rows (``WhatIfResult.eviction_log``).
+        self.evict_log: List[Tuple[int, int, int, int]] = []
+        self._evicted_gang = np.zeros(P, bool)
         # Boundary start times: f64 for the static release schedule, f32
         # finite prefix for the retry pend schedule (matching the device's
         # staged f32 table bit-for-bit).
@@ -399,12 +406,15 @@ class BoundaryOps:
         """[P] i32 beside ``assignments``: -1 bound in its arrival wave (or
         pre-bound), ``b >= 0`` bound by the retry pass of boundary ``b``;
         for a pod with no node -2 still queued at the end, -3 dropped at a
-        full buffer, -4 refused at arrival and never queued (a gang
-        member, or any pod without a retry buffer). The what-if engine's
-        ``bind_boundary`` hand-back, on the host."""
+        full buffer (at its arrival or at its eviction), -4 refused at
+        arrival and never queued (a gang member, or any pod without a
+        retry buffer), -5 a gang member evicted with its node and stranded
+        (``evict_node``). The what-if engine's ``bind_boundary`` hand-back,
+        on the host."""
         out = self.bind_boundary.copy()
         none = self.assignments == PAD
         out[none] = -4
+        out[none & self._evicted_gang] = -5
         out[none & self._dropped] = -3
         queued = np.asarray(self.retry_q, np.int64)
         out[queued[none[queued]]] = -2
@@ -504,6 +514,9 @@ class BoundaryOps:
             unbind(ec, ep, st, v)
             self.evictions += 1
             self._evict_time[v] = float(t_chunk)
+            self.evict_log.append(
+                (int(b), v, int(node), int(self.bind_boundary[v]))
+            )
             # Same bookkeeping as a preemption victim: a displaced pod's
             # pending release no longer frees anything, and a later
             # re-placement starts at THAT boundary — the arrival-based
@@ -519,6 +532,9 @@ class BoundaryOps:
                     self.retry_q.append(v)
                 else:
                     self.retry_dropped += 1
+                    self._dropped[v] = True
+            elif ep.group_id[v] != PAD:
+                self._evicted_gang[v] = True
         return victims.astype(np.int64), np.full(
             victims.size, int(node), np.int64
         )

@@ -150,8 +150,13 @@ PHASE_NAMES = (
 #: ticks its timer, a what-if block's checkpoint publication, and under a
 #: mesh the puts on the devices (inside ``stage``) and the placements'
 #: gather and fetch (inside ``handback``), which carry ``bytes=`` as the
-#: event's stats. Trace readers import these and hold no list of their own.
-HOST_SPAN_NAMES = PHASE_NAMES + ("checkpoint", "mesh_put", "mesh_fetch")
+#: event's stats; ``host_events``: what the host does for a boundary's
+#: timeline events on the what-if device path (the eviction program's
+#: arguments; its call is ``boundary_fold``'s). Trace readers import these
+#: and hold no list of their own.
+HOST_SPAN_NAMES = PHASE_NAMES + (
+    "checkpoint", "mesh_put", "mesh_fetch", "host_events",
+)
 #: ``chunk:<i>``: the dispatch of chunk ``i``, inside ``dispatch``.
 CHUNK_SPAN = "chunk"
 #: ``replay:<n>`` / ``whatif_run:<n>``: one root around everything the

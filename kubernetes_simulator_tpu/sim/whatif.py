@@ -55,6 +55,7 @@ from .waves import pack_waves, refuse_wide_gangs, widest_gang
 # run to ONE trip count, the largest among the scenarios (ops.release_planes).
 _RELEASE_VMAP = "release_scenarios"
 _RETRY_VMAP = "retry_scenarios"
+_EVICT_VMAP = "evict_scenarios"
 
 @dataclass
 class Perturbation:
@@ -74,11 +75,17 @@ class Perturbation:
 class Scenario:
     perturbations: List[Perturbation] = field(default_factory=list)
     # Timed failure/recovery timeline (chaos campaigns, round 7): a list
-    # of sim.runtime.NodeEvent applied to THIS scenario at chunk
-    # boundaries through its host mirror — node_down evicts bound pods
-    # (NoExecute) into the retry buffer, node_up/capacity_scale re-shape
-    # allocatable mid-replay. Requires kube mode (the mirrors); static
-    # t=0 perturbations above need no mirror and work everywhere.
+    # of sim.runtime.NodeEvent applied to THIS scenario at the first chunk
+    # boundary at or after each event's time: node_down zeroes the node's
+    # allocatable and evicts its bound pods (NoExecute) into the retry
+    # buffer, node_up gives the node its own allocatable back, empty.
+    # Where it runs: with ``retry_buffer > 0`` on the device-release path
+    # the eviction is a device program (``WhatIfEngine._evict_fn``: no host
+    # mirror, nothing of [S, P] size leaves the device between chunks; a
+    # mesh, several processes and capacity_scale are refused there); with
+    # ``preemption="kube"`` through the per-scenario host mirrors
+    # (``sim.boundary``), capacity_scale too. Static t=0 perturbations
+    # above evict nothing and work everywhere.
     events: List = field(default_factory=list)
 
 
@@ -480,6 +487,44 @@ class RetryQueue(NamedTuple):
     t_an: Optional[jax.Array] = None
     t_pf: Optional[jax.Array] = None
     t_pw: Optional[jax.Array] = None
+    # Only where the scenarios carry timelines: ``[RB]`` beside ``ids``,
+    # the boundary that evicted the queued task (-1: it failed at its
+    # arrival), sorted along with it.
+    ev_at: Optional[jax.Array] = None
+
+
+# The counters of ``EvictState.n``, by place.
+_EV_COUNTERS = (
+    "logged",         # rows written to the log (the cursor)
+    "lost",           # evictions past the victims' slots or the log's room (the run is redone)
+    "evictions",      # every task taken off a leaving node
+    "evict_gang",     # ... a gang member: not queued, stranded
+    "evict_dropped",  # ... that found the buffer full
+    "evict_arriving", # ... no resident: it leaves ``placed``
+    "evict_retried",  # ... bound by an earlier retry pass (its record row goes)
+    "rebound",        # evicted tasks a retry pass bound again
+    "rebound_same",   # ... in the pass of the boundary that evicted them
+    "rebound_resident",  # ... residents (never counted in ``placed``)
+    "wait_sum",       # boundaries from eviction to the re-bind, summed
+    "wait_max",       # ... the greatest
+    "pass_waves",     # wave steps the retry passes executed (one count a batch)
+)
+_EV = {k: i for i, k in enumerate(_EV_COUNTERS)}
+
+
+class EvictState(NamedTuple):
+    """What the device eviction path carries from one boundary to the next,
+    per scenario (a leading ``[S]`` on every leaf): ``down [N]`` the nodes
+    that are out now (their allocatable reads 0 in the chunk call), ``log
+    [4, cap]`` every eviction in the order made (boundary, task, the node
+    it held, the boundary whose pass had bound it or -1), ``n`` the
+    counters ``_EV_COUNTERS`` names, ``wait_s`` the virtual seconds from
+    eviction to re-bind, summed."""
+
+    down: jax.Array
+    log: jax.Array
+    n: jax.Array
+    wait_s: jax.Array
 
 
 @dataclass
@@ -496,9 +541,16 @@ class WhatIfResult:
     # -1 bound in its arrival wave (or resident), b >= 0 bound by the retry
     # pass of boundary b; for a task with no node -2 still queued at the
     # end, -3 dropped at a full buffer, -4 refused at arrival and never
-    # queued (a gang member). Final as it leaves the device, and read-only
-    # as ``assignments`` is.
+    # queued (a gang member), -5 a gang member evicted with its node and
+    # stranded. Of a task's LAST bind where timelines evict. Final as it
+    # leaves the device, and read-only as ``assignments`` is.
     bind_boundary: Optional[np.ndarray] = None
+    # Where the scenarios carry timelines on the device path: [S, E, 4]
+    # i32, every eviction of a scenario in the order made, padded with -1
+    # to the longest: (boundary, task, the node it held, the boundary whose
+    # retry pass had bound it; -1 its arrival wave or a resident).
+    # ``evictions[s]`` rows are filled.
+    eviction_log: Optional[np.ndarray] = None
     utilization_cpu: Optional[np.ndarray] = None  # [S]
     # Which semantics this batch actually ran under (round 4: two batches
     # evaluated under different semantics must be programmatically
@@ -513,8 +565,9 @@ class WhatIfResult:
     # queue counts overflow exactly like the host analogue).
     preemptions: Optional[np.ndarray] = None  # [S] i32
     retry_dropped: Optional[np.ndarray] = None  # [S] i32
-    # Per-scenario chaos disruption (kube batches, round 7): node_down
-    # NoExecute evictions through the host mirrors, DISTINCT from
+    # Per-scenario chaos disruption (timelines: kube batches through the
+    # host mirrors, round 7; device retry batches through the eviction
+    # program): node_down NoExecute evictions, DISTINCT from
     # scheduler-initiated `preemptions`. `evict_latency_mean` is the mean
     # virtual eviction→re-bind time (boundary-granular).
     evictions: Optional[np.ndarray] = None  # [S] i32
@@ -823,30 +876,13 @@ class WhatIfEngine:
                 )
         mesh = dcn.localize_mesh(mesh)
         # Per-scenario timed failure/recovery timelines (chaos campaigns,
-        # round 7): applied through the per-scenario host mirrors at
-        # chunk boundaries — which only exist in kube mode.
-        # Validation enforces time-sortedness, so the lists are kept as
-        # given (an unsorted timeline must ERROR, not be silently fixed).
+        # round 7), applied at chunk boundaries. Kept as given: validation
+        # (``_check_timelines``, once the batch's path is known) enforces
+        # time-sortedness, and an unsorted timeline must ERROR, not be
+        # silently fixed.
         self._timelines = [
             list(getattr(sc, "events", None) or []) for sc in scenarios
         ]
-        if any(self._timelines):
-            if not self.kube:
-                raise ValueError(
-                    "per-scenario timed event timelines (Scenario.events) "
-                    "require preemption='kube' with retry_buffer > 0: "
-                    "events apply through the per-scenario host mirrors "
-                    "at chunk boundaries, and node_down evictions requeue "
-                    "victims through the boundary retry pass. Use static "
-                    "t=0 Perturbations for mirror-free batches."
-                )
-            from .runtime import validate_node_events
-
-            for si, tl in enumerate(self._timelines):
-                try:
-                    validate_node_events(tl, ec.num_nodes)
-                except ValueError as e:
-                    raise ValueError(f"scenario {si}: {e}") from None
         self.ec = ec
         self.pods = pods
         self._config = config
@@ -1128,6 +1164,14 @@ class WhatIfEngine:
                     "without label-perturbation DynTables (meshes are "
                     "supported since round 10)"
                 )
+        self._check_timelines(self._timelines)
+        # Timelines on the device path: the eviction program, its carry and
+        # the queue's ``ev_at`` exist only in such a batch (fixed here: an
+        # engine built without them cannot be handed them later).
+        self._events_dev = bool(any(self._timelines) and not self.kube)
+        self._evict_stage: Optional[dict] = None
+        self._evict_sizes: Optional[dict] = None
+        self._evict_scale = 1
         # Host-side completions need per-scenario choices even when the
         # caller only wants counts; the device-release path never fetches
         # them per chunk: asked for placements, it hands its on-device
@@ -1204,6 +1248,46 @@ class WhatIfEngine:
         if self.mesh is not None:
             srcs = replicate_tree(self.mesh, srcs)
         self._slot_srcs = srcs
+
+    def _check_timelines(self, timelines) -> None:
+        """Validate a batch's per-scenario timelines (``Scenario.events``),
+        or say why this engine cannot run them. They run where a task can
+        be evicted and offered again: through the per-scenario host mirrors
+        (``preemption="kube"``, every event kind), or on the device retry
+        path (``retry_buffer > 0``: ``node_down`` / ``node_up``, one
+        process, no mesh)."""
+        if not any(timelines):
+            return
+        reasons = []
+        if not self.kube:
+            if not self.retry_buffer:
+                reasons.append(
+                    "retry_buffer > 0 (an evicted task re-enters the "
+                    "pending queue and is re-bound by a boundary's retry "
+                    "pass; static t=0 Perturbations evict nothing and need "
+                    "none)"
+                )
+            if self.mesh is not None:
+                reasons.append("no mesh (the eviction program is unmeshed)")
+            if jax.process_count() > 1:
+                reasons.append("a single process (no DCN slicing)")
+            if any(ev.kind == "capacity_scale" for tl in timelines for ev in tl):
+                reasons.append(
+                    "no capacity_scale event without preemption='kube' "
+                    "(the device path moves nodes out and back, whole)"
+                )
+        if reasons:
+            raise ValueError(
+                "per-scenario timed event timelines (Scenario.events) "
+                "require " + "; ".join(reasons)
+            )
+        from .runtime import validate_node_events
+
+        for si, tl in enumerate(timelines):
+            try:
+                validate_node_events(tl, self.ec.num_nodes)
+            except ValueError as e:
+                raise ValueError(f"scenario {si}: {e}") from None
 
     @property
     def _wide_gangs(self) -> bool:
@@ -1301,20 +1385,13 @@ class WhatIfEngine:
         timelines = [
             list(getattr(sc, "events", None) or []) for sc in scenarios
         ]
-        if any(timelines):
-            if not self.kube:
-                raise ValueError(
-                    "per-scenario timed event timelines (Scenario."
-                    "events) require preemption='kube' with "
-                    "retry_buffer > 0"
-                )
-            from .runtime import validate_node_events
-
-            for si, tl in enumerate(timelines):
-                try:
-                    validate_node_events(tl, self.ec.num_nodes)
-                except ValueError as e:
-                    raise ValueError(f"scenario {si}: {e}") from None
+        self._check_timelines(timelines)
+        if any(timelines) and not self.kube and not self._events_dev:
+            raise ValueError(
+                "scenario batch carries timed event timelines but the "
+                "engine was built without any (the eviction program and "
+                "its carry are compiled in) — rebuild the engine"
+            )
         sset = ScenarioSet(self.ec, scenarios, keep_host_stacks=self.kube)
         if sset.labels_dirty:
             raise ValueError(
@@ -1361,6 +1438,7 @@ class WhatIfEngine:
         self.sset = sset
         self._dc_mesh = None
         self._timelines = timelines
+        self._evict_stage = None
 
     def _build_chunk_fn(self):
         collect = self._need_choices
@@ -1489,12 +1567,13 @@ class WhatIfEngine:
 
                 rel_core = self._release_core()
                 want_an, want_pf = rel_core.want_an, rel_core.want_pf
+                ev_on = self._events_dev
 
                 def per_scenario_retry(
                     dc, state, src, xsrc, mgt, antit, preft,
                     prefwt, durt, priot, tbt,
                     idx, t_b, b,
-                    vassign, rq,
+                    vassign, rq, ev=None, resd=None,
                 ):
                     """The device-release chunk call with the
                     bounded unschedulable-retry pass (semantics:
@@ -1507,7 +1586,16 @@ class WhatIfEngine:
                     the main chunk scan -> the queue's upkeep (the
                     chunk's failures join, one stable sort by
                     priority) -> the assignment fold. ``rq`` is the
-                    scenario's ``RetryQueue``."""
+                    scenario's ``RetryQueue``. Where the scenarios carry
+                    timelines (``ev``, the scenario's ``EvictState``; the
+                    eviction program ran before the static releases): a
+                    node that is out reads allocatable 0 here, the queue's
+                    ``ev_at`` rides its sorts, and the pass counts the
+                    evicted tasks it binds again."""
+                    if ev_on:
+                        dc = dc._replace(allocatable=jnp.where(
+                            ev.down[:, None], 0.0, dc.allocatable
+                        ))
                     d = T.Derived.build(dc)
                     cmasks = V3.class_masks(dc, d, st3, spec, reps)
                     wave_step = V3.make_wave_step3(
@@ -1564,9 +1652,36 @@ class WhatIfEngine:
                         rb_waves = q.reshape(RBW, wave_width)
                         slots_r = T.gather_slots_device(src, rb_waves)
                         extra_r = V3.gather_extra_device(xsrc, rb_waves)
-                        state, choices_r = jax.lax.scan(
-                            retry_step, state, (slots_r, extra_r)
-                        )
+                        if ev_on:
+                            # The queue stands at the front of its buffer
+                            # (the sorts put the holes last), so the pass
+                            # ends with the fullest scenario's last queued
+                            # wave: a buffer sized for an eviction burst
+                            # costs its steps only where one is queued.
+                            trips = jax.lax.pmax(
+                                -(-rq.count // wave_width), _RETRY_VMAP
+                            )
+
+                            def pass_wave(i, carry):
+                                st, out = carry
+                                st, picks = retry_step(st, jax.tree.map(
+                                    lambda a: jax.lax.dynamic_index_in_dim(
+                                        a, i, keepdims=False
+                                    ), (slots_r, extra_r),
+                                ))
+                                return st, jax.lax.dynamic_update_index_in_dim(
+                                    out, picks.astype(out.dtype), i, 0
+                                )
+
+                            state, choices_r = jax.lax.fori_loop(
+                                0, trips, pass_wave, (state, jnp.full(
+                                    (RBW, wave_width), PAD, jnp.int32
+                                )),
+                            )
+                        else:
+                            state, choices_r = jax.lax.scan(
+                                retry_step, state, (slots_r, extra_r)
+                            )
                         flat_cr = choices_r.reshape(RB)
                         placed_r = (flat_cr >= 0) & (q >= 0)
                         retry_placed = placed_r.sum(dtype=jnp.int32)
@@ -1605,6 +1720,26 @@ class WhatIfEngine:
                             )
                         ids = jnp.where(placed_r, -1, q)
                         count = rq.count - retry_placed
+                        if ev_on:
+                            back = placed_r & (rq.ev_at >= 0)
+                            wait = jnp.where(back, b - rq.ev_at, 0)
+                            tally = lambda m: m.sum(dtype=jnp.int32)
+                            delta = {
+                                "rebound": tally(back),
+                                "rebound_same": tally(back & (rq.ev_at == b)),
+                                "rebound_resident": tally(back & resd[safe]),
+                                "wait_sum": wait.sum(dtype=jnp.int32),
+                                "pass_waves": trips,
+                            }
+                            n = ev.n + jnp.stack([
+                                delta.get(k, jnp.int32(0)) for k in _EV_COUNTERS
+                            ])
+                            ev = ev._replace(
+                                n=n.at[_EV["wait_max"]].max(wait.max()),
+                                wait_s=ev.wait_s + jnp.where(
+                                    back, t_b - tbt[jnp.clip(rq.ev_at, 0)], 0.0
+                                ).sum(),
+                            )
                     # 3. the main chunk scan, as without a queue.
                     slots = T.gather_slots_device(src, idx)
                     extra = V3.gather_extra_device(xsrc, idx)
@@ -1645,9 +1780,13 @@ class WhatIfEngine:
                         key = jnp.where(
                             cat_ids >= 0, -cat_prio, jnp.iinfo(jnp.int32).max
                         )
-                        _, cat_ids, cat_prio, cat_dur = jax.lax.sort(
-                            (key, cat_ids, cat_prio, cat_dur),
-                            num_keys=1, is_stable=True,
+                        rides = (cat_ids, cat_prio, cat_dur)
+                        if ev_on:
+                            rides += (jnp.concatenate(
+                                [rq.ev_at, jnp.full_like(rows, -1)]
+                            ),)
+                        _, cat_ids, cat_prio, cat_dur, *cat_ev = jax.lax.sort(
+                            (key,) + rides, num_keys=1, is_stable=True,
                         )
                         rq = rq._replace(
                             ids=cat_ids[:RB], prio=cat_prio[:RB],
@@ -1656,6 +1795,8 @@ class WhatIfEngine:
                             dropped=rq.dropped
                             + jnp.maximum(nfail - room, 0),
                         )
+                        if ev_on:
+                            rq = rq._replace(ev_at=cat_ev[0][:RB])
                     # 5. fold arrival-chunk placements at their flat
                     # wave positions (re-tried placements stay in the
                     # queue's record: their arrival slot keeps PAD so
@@ -1666,6 +1807,8 @@ class WhatIfEngine:
                             choices.reshape(-1),
                             (b * idx.size,),
                         )
+                    if ev_on:
+                        return state, vassign, rq, ev, (counts, retry_placed)
                     return state, vassign, rq, (counts, retry_placed)
 
                 axes_retry = (
@@ -1673,12 +1816,15 @@ class WhatIfEngine:
                     None, None, None, None,
                     None, None, None,
                     0, 0,
-                )
+                ) + ((0, None) if ev_on else ())
                 vmapped_retry = jax.vmap(
                     per_scenario_retry, in_axes=axes_retry,
                     axis_name=_RETRY_VMAP,
                 )
-                return finalize(vmapped_retry, axes_retry, (1, 14, 15))
+                return finalize(
+                    vmapped_retry, axes_retry,
+                    (1, 14, 15) + ((16,) if ev_on else ()),
+                )
 
             # vmap matches in_axes against the args actually
             # passed; with policies on, a literal None rides the
@@ -2475,8 +2621,11 @@ class WhatIfEngine:
 
         ``merged`` is ``(bind_boundary >= 0).sum(axis=1)``, the re-tried
         binds written, counted on the device; it has to be ``retry_placed``
-        scenario for scenario, and a batch where it is not raises: a lost
-        bind is a wrong answer. Both copies to the host are started before
+        (what the passes bound, less the re-tried binds a timeline evicted
+        again) scenario for scenario, and a batch where it is not raises: a
+        lost bind is a wrong answer. A gang member that a timeline evicted
+        (-2 in ``vassign``) reads no node and -5. Both copies to the host
+        are started before
         the first is waited for, and the arrays come back as fetched,
         read-only, each run's own."""
         def build():
@@ -2490,6 +2639,10 @@ class WhatIfEngine:
                 code = jnp.where(
                     node >= 0, -1, jnp.where(gang_d[None, :], -4, -3)
                 ).astype(jnp.int32)
+                if self._events_dev:
+                    # the eviction program left -2 where a gang member stood
+                    code = jnp.where(node == -2, -5, code)
+                    node = jnp.maximum(node, PAD)
                 with jax.named_scope("ksim.handback"):
                     boundary = jnp.arange(rq.t_id.shape[1], dtype=jnp.int32)
                     # the queue rides as one more row: no node, code -2
@@ -2557,6 +2710,47 @@ class WhatIfEngine:
         )(outs, rq))  # [4, S]
         return dict(zip(keys, got), retry_dropped=dropped)
 
+    def _evict_counts(self, ev_n: np.ndarray) -> dict:
+        """What ``summary()["retry"]`` holds per scenario (``[S]`` each) of
+        a batch whose timelines evict, from ``EvictState.n`` as fetched:
+        the evictions; of them the tasks a retry pass bound again, in the
+        pass of the boundary that evicted them or in a later one; the
+        stranded (evicted and with no node at the end: still queued,
+        dropped at a full buffer, or a gang member), the last two kinds
+        on their own; the re-tried binds among the victims; how long the
+        re-bound waited, in boundaries (mean, and the longest); and the
+        wave steps the batch's retry passes executed (``pass_waves``: a
+        pass ends with the fullest scenario's last queued wave, so one
+        number a batch, the same in every scenario)."""
+        col = lambda k: ev_n[:, _EV[k]]
+        back = col("rebound")
+        return {
+            "evictions": col("evictions"),
+            "evict_rebound_same_boundary": col("rebound_same"),
+            "evict_rebound_later": back - col("rebound_same"),
+            "evict_stranded": col("evictions") - back,
+            "evict_dropped": col("evict_dropped"),
+            "evict_gang_stranded": col("evict_gang"),
+            "evict_retried": col("evict_retried"),
+            "evict_wait_boundaries_mean": col("wait_sum") / np.maximum(back, 1),
+            "evict_wait_boundaries_max": col("wait_max"),
+            "pass_waves": col("pass_waves"),
+        }
+
+    def _handback_log(self, ev: EvictState, evictions) -> np.ndarray:
+        """``WhatIfResult.eviction_log`` ``[S, E, 4]``: the log's filled
+        columns (to the longest scenario's, rounded up to 1,024 so that a
+        batch made again compiles nothing), turned on the device and
+        fetched once, cut on the host to the longest; -1 where a scenario
+        has fewer."""
+        longest = int(np.max(evictions, initial=0))
+        cap = int(ev.log.shape[2])
+        width = min(cap, -(-max(longest, 1) // 1024) * 1024)
+        turn = self._jit_once(f"evict_log:{width}", lambda: jax.jit(
+            lambda log: jnp.swapaxes(log[:, :, :width], 1, 2)
+        ))
+        return self._fetch(turn(ev.log))[:, :longest]
+
     def _retry_summary(self, per: dict, passes: int) -> dict:
         """``summary()["retry"]`` from ``_retry_counts``: the buffer, the
         passes made (one a boundary), and per scenario (mean and max over
@@ -2569,12 +2763,15 @@ class WhatIfEngine:
         were handed back, ``handback_merged``: the re-tried binds the
         hand-back program wrote into ``bind_boundary`` (``retry_placed`` by
         construction: the counter that says the merge ran on the device
-        and lost nothing)."""
+        and lost nothing). Where timelines evict, ``_evict_counts``'
+        besides, and ``retry_dropped`` counts the evicted that found the
+        buffer full too."""
         out: dict = {"buffer": int(self.retry_buffer), "passes": passes}
+        plain = lambda x: float(x) if isinstance(x, np.floating) else int(x)
         for k, v in per.items():
             v = np.asarray(v)
-            out[k] = {"mean": float(v.mean()), "max": int(v.max())}
-        out["scenario0"] = {k: int(np.asarray(v)[0]) for k, v in per.items()}
+            out[k] = {"mean": float(v.mean()), "max": plain(v.max())}
+        out["scenario0"] = {k: plain(np.asarray(v)[0]) for k, v in per.items()}
         return out
 
     def _mesh_summary(self) -> dict:
@@ -2637,7 +2834,263 @@ class WhatIfEngine:
             if core.want_pf else None,
             t_pw=tab(stg["prefwt"].shape[1], 0.0, jnp.float32)
             if core.want_pf else None,
+            ev_at=full((RB,), -1, jnp.int32) if self._events_dev else None,
         )))()
+
+    def _stage_events(self) -> dict:
+        """The batch's timelines as what the device path takes: per
+        boundary (the first whose start reaches an event's time, as every
+        event of this engine) and scenario, the nodes that LEAVE there
+        (every ``node_down`` due, in timeline order: the order their tasks
+        join the queue in) and the nodes that are BACK (the last event due
+        is a ``node_up``), ``[S, L]`` each, -1 padded, on the device; None
+        for a boundary at which no scenario has an event. With them the
+        sizes the eviction program is compiled for: ``L``, the victims a
+        boundary can take (``E``) and the log's room (both reckoned from
+        the timelines at the trace's mean tasks a node; a run that finds
+        either too small doubles ``_evict_scale`` and is made again).
+        Static per scenario batch: staged once and kept."""
+        if self._evict_stage is not None:
+            return self._evict_stage
+        tb = self._dev_rel_stage["tb_host"]
+        S, nb = self.S, len(tb)
+        leave = [[[] for _ in range(nb)] for _ in range(S)]
+        back = [[[] for _ in range(nb)] for _ in range(S)]
+        for s, tl in enumerate(self._timelines):
+            at = np.searchsorted(tb, [float(e.time) for e in tl], side="left")
+            last: Dict[tuple, str] = {}
+            for e, bb in zip(tl, at.tolist()):
+                if bb >= nb:
+                    break  # past the last boundary: never applied
+                node = int(e.node)
+                if e.kind == "node_down" and node not in leave[s][bb]:
+                    leave[s][bb].append(node)
+                last[(bb, node)] = e.kind
+            for (bb, node), kind in last.items():
+                if kind == "node_up":
+                    back[s][bb].append(node)
+        widest = max(len(x) for per in (leave, back) for row in per for x in row)
+        L = 1 << max(3, (max(widest, 1) - 1).bit_length())
+        # a batch swapped in (``set_scenarios``) that needs no more room
+        # keeps the sizes the programs were compiled for
+        kept = self._evict_sizes or {"L": 0, "E": 0, "cap": 0}
+        L = max(L, kept["L"])
+        P, N = self.pods.num_pods, self.ec.num_nodes
+        per_node = -(-P // N)
+        most = max(len(x) for row in leave for x in row)
+        scale = self._evict_scale
+        E = max(kept["E"], 128, 1 << (
+            max(most * per_node * scale, 1) - 1).bit_length())
+        total = max(sum(len(x) for x in row) for row in leave)
+        cap = total * per_node * scale + 2 * E
+        cap = max(kept["cap"], -(-cap // 128) * 128)
+        self._evict_sizes = {"L": L, "E": E, "cap": cap}
+        pad = lambda rows: np.asarray(
+            [r + [-1] * (L - len(r)) for r in rows], np.int32
+        )
+        calls = []
+        for bb in range(nb):
+            if not any(leave[s][bb] or back[s][bb] for s in range(S)):
+                calls.append(None)
+                continue
+            calls.append((
+                jnp.asarray(pad([leave[s][bb] for s in range(S)])),
+                jnp.asarray(pad([back[s][bb] for s in range(S)])),
+            ))
+        self._evict_stage = {
+            "calls": calls, "L": L, "E": E, "cap": cap,
+        }
+        return self._evict_stage
+
+    def _evict_state(self) -> EvictState:
+        """A batch's ``EvictState`` at its start: no node out, an empty log."""
+        S, N, cap = self.S, self.ec.num_nodes, self._stage_events()["cap"]
+        return self._jit_once("evict_state", lambda: jax.jit(lambda: EvictState(
+            down=jnp.zeros((S, N), bool),
+            log=jnp.full((S, 4, cap), -1, jnp.int32),
+            n=jnp.zeros((S, len(_EV_COUNTERS)), jnp.int32),
+            wait_s=jnp.zeros((S,), jnp.float32),
+        )))()
+
+    def _evict_fn(self):
+        """The eviction program of a boundary (``jit_whatif_evict``; device
+        retry path, scenarios with timelines), called before the boundary's
+        static releases: ``(state, vassign, rq, ev, leave, back, b) ->
+        (state, vassign, rq, ev)`` with ``leave`` / ``back`` ``[S, L]`` the
+        nodes that go out and come back there. Per scenario, under
+        ``ksim.evict``:
+
+        * the victims: every LIVE bind on a leaving node, in the two places
+          the device holds a bind: ``vassign`` (arrival binds, the residents
+          in its tail; live until the boundary its static release is due
+          at, this one included: the events come first) and the record's
+          ``t_node`` rows (re-tried binds; live while ``t_relb >= b``).
+          Each place is compared with the ``L`` leaving nodes: a node mask
+          read by the place's node is a gather a scenario, 786 ms at the
+          Borg cell's shape where the compare takes 6.6 (PERF.md §6, PR 45).
+        * they are brought to the front BY RANK, with no sort over the
+          places and no scatter: the places are cut into blocks of 128 and
+          counted; output slot j finds its block by comparing j with the
+          blocks' running offsets, reads that block's row and takes the
+          lane whose count within the row is its rank (19.6 ms there; one
+          sort of key and source over all 520,192 places 131.5,
+          ``jnp.nonzero`` 593.8). ``E`` slots: where a scenario has more
+          victims the run is made again with twice the room
+          (``_evict_scale``). The ``E`` victims alone are then sorted
+          into the order ``BoundaryOps.evict_node`` makes them: the leaving
+          nodes in timeline order, a node's tasks by id.
+        * the binds go where they stand: ``vassign`` reads PAD (-2 for a
+          gang member: the hand-back's code -5), so the static release
+          finds nothing; the record row reads no task, no node and no
+          release, and ``owed`` gives the cancelled release back
+          (``release_leaked`` stays 0).
+        * their usage and counts are rewound through the release core (a
+          node's victims dealt over the blocks of the list, so that its
+          rank rounds stay few), the non-gang ones join the queue behind
+          what is there, as far as there is room (the rest dropped,
+          counted), one stable sort by priority; the victims go into the
+          log behind its cursor.
+        * a node that left holds nothing: its ``used`` reads 0.0, exactly
+          (the rewind's float residue goes with it), and ``down`` takes the
+          nodes that left and gives back the ones that returned."""
+        def build():
+            stg, evs = self._dev_rel_stage, self._stage_events()
+            L, E, cap = evs["L"], evs["E"], evs["cap"]
+            RB, N = self.retry_buffer, self.ec.num_nodes
+            BIG, NONE = 1 << 30, jnp.iinfo(jnp.int32).max
+            rel_core = self._release_core()
+            want_an, want_pf = rel_core.want_an, rel_core.want_pf
+            relb_pos, task_pos, gang_pos, resd = (
+                stg["relb_pos"], stg["task_pos"], stg["gang_pos"], stg["resd"]
+            )
+            mgt, antit, preft, prefwt = (
+                stg["mgt"], stg["antit"], stg["preft"], stg["prefwt"]
+            )
+            durt, priot = stg["durt"], stg["priot"]
+            req_t = self._slot_srcs[0].requests
+            gang_t = self._slot_srcs[0].group_id >= 0
+            V = int(relb_pos.shape[0])
+            ar_L = jnp.arange(L, dtype=jnp.int32)
+            ar_N = jnp.arange(N, dtype=jnp.int32)
+            slot = jnp.arange(E, dtype=jnp.int32)
+            # deal a node's run of victims over the blocks of the list
+            deal = lambda a: a.reshape((128, E // 128) + a.shape[1:]).swapaxes(
+                0, 1).reshape(a.shape)
+
+            def evict_one(state, vassign, rq, ev, leave, back, b):
+                def on_leaving(x):
+                    eq = (x[..., None] == leave) & (leave >= 0)
+                    return eq.any(-1), (eq * ar_L).sum(-1, dtype=jnp.int32)
+
+                hv, lv = on_leaving(vassign)
+                hv &= relb_pos >= b
+                hr, lr = on_leaving(rq.t_node)
+                hr &= rq.t_relb >= b
+                # the places, vassign's then the record's, in blocks of 128
+                cat = lambda v, r, fill: jnp.concatenate([
+                    v, r.reshape(-1),
+                    jnp.full((-(V + r.size) % 128,), fill, v.dtype),
+                ]).reshape(-1, 128)
+                hit = cat(hv, hr, False)
+                count = hit.sum(1, dtype=jnp.int32)
+                start = jnp.cumsum(count) - count
+                hits = count.sum()
+                block = (start[None, :] <= slot[:, None]).sum(
+                    1, dtype=jnp.int32) - 1
+                row = hit[block]
+                upto = jnp.cumsum(row.astype(jnp.int32), axis=1)
+                lane = jnp.argmax(
+                    (upto == (slot - start[block])[:, None] + 1) & row, axis=1
+                ).astype(jnp.int32)
+                ok = slot < hits
+                at = jnp.where(ok, block * 128 + lane, 0)
+                task = jnp.where(ok, cat(task_pos, rq.t_id, 0).reshape(-1)[at], 0)
+                walk = jnp.where(ok, cat(lv, lr, 0).reshape(-1)[at], L)
+                # the anchor's order: a node's place in the timeline, then
+                # the task's id (E victims: a small sort)
+                walk, task, at = jax.lax.sort(
+                    (walk, task, at), num_keys=2, is_stable=False
+                )
+                node = jnp.where(ok, leave[jnp.clip(walk, 0, L - 1)], -1)
+                bound_at = jnp.where(ok & (at >= V), (at - V) // RB, -1)
+                vassign = jnp.where(
+                    hv, jnp.where(gang_pos, -2, PAD), vassign
+                ).astype(vassign.dtype)
+                none_i = jnp.full((E, 1), PAD, jnp.int32)
+                state, _, _ = rel_core(
+                    state, deal(node), deal(req_t[task]), deal(mgt[task]),
+                    deal(antit[task]) if want_an else none_i,
+                    deal(preft[task]) if want_pf else none_i,
+                    deal(prefwt[task]) if want_pf
+                    else jnp.zeros((E, 1), jnp.float32),
+                    axis_name=_EVICT_VMAP,
+                )
+                gang = gang_t[task]
+                asks = ok & ~gang
+                room = RB - rq.count
+                take = asks & (jnp.cumsum(asks.astype(jnp.int32)) <= room)
+                nasks = asks.sum(dtype=jnp.int32)
+                cat_ids = jnp.concatenate([rq.ids, jnp.where(take, task, -1)])
+                cat_prio = jnp.concatenate([rq.prio, priot[task]])
+                key = jnp.where(cat_ids >= 0, -cat_prio, NONE)
+                _, cat_ids, cat_prio, cat_dur, cat_ev = jax.lax.sort(
+                    (key, cat_ids, cat_prio,
+                     jnp.concatenate([rq.dur, durt[task]]),
+                     jnp.concatenate([rq.ev_at, jnp.full((E,), b, jnp.int32)])),
+                    num_keys=1, is_stable=True,
+                )
+                rows = jnp.stack([
+                    jnp.where(ok, b, -1), jnp.where(ok, task, -1), node, bound_at,
+                ])
+                logged = ev.n[_EV["logged"]]
+                lost = jnp.maximum(hits - E, 0) + jnp.where(
+                    (logged + E > cap) & (hits > 0), hits, 0)
+                tally = lambda m: m.sum(dtype=jnp.int32)
+                delta = {
+                    "logged": tally(ok), "lost": lost,
+                    "evictions": tally(ok),
+                    "evict_gang": tally(ok & gang),
+                    "evict_dropped": jnp.maximum(nasks - room, 0),
+                    "evict_arriving": tally(ok & ~resd[task]),
+                    "evict_retried": tally(ok & (bound_at >= 0)),
+                }
+                member = lambda nodes: (
+                    (ar_N[:, None] == nodes) & (nodes >= 0)
+                ).any(-1)
+                left = member(leave)
+                state = state._replace(
+                    used=jnp.where(left[None, :], 0.0, state.used)
+                )
+                rq = rq._replace(
+                    t_id=jnp.where(hr, -1, rq.t_id),
+                    t_node=jnp.where(hr, -1, rq.t_node),
+                    t_relb=jnp.where(hr, BIG, rq.t_relb),
+                    owed=rq.owed - (hr & (rq.t_relb < BIG)).sum(dtype=jnp.int32),
+                    ids=cat_ids[:RB], prio=cat_prio[:RB], dur=cat_dur[:RB],
+                    ev_at=cat_ev[:RB], count=rq.count + jnp.minimum(nasks, room),
+                    dropped=rq.dropped + jnp.maximum(nasks - room, 0),
+                )
+                ev = ev._replace(
+                    down=(ev.down | left) & ~member(back),
+                    log=jax.lax.dynamic_update_slice(
+                        ev.log, rows, (0, jnp.minimum(logged, cap - E))),
+                    n=ev.n + jnp.stack(
+                        [delta.get(c, jnp.int32(0)) for c in _EV_COUNTERS]),
+                )
+                return state, vassign, rq, ev
+
+            fn_v = jax.vmap(
+                evict_one, in_axes=(0, 0, 0, 0, 0, 0, None),
+                axis_name=_EVICT_VMAP,
+            )
+
+            def whatif_evict(*args):
+                with stage("ksim.evict"):
+                    return fn_v(*args)
+
+            return jax.jit(whatif_evict, donate_argnums=(0, 1, 2, 3))
+
+        return self._jit_once("evict", build)
 
     def _chunks_pos(self, idx: np.ndarray) -> np.ndarray:
         """[P] each task's place in the chunks' wave order; a task in no
@@ -2772,6 +3225,22 @@ class WhatIfEngine:
             stg["tb_c"] = [
                 jnp.asarray(np.float32(tb_all[b])) for b in range(nchunks)
             ]
+        if self._events_dev:
+            # What the eviction program reads of a place in vassign: the
+            # task there, the boundary its static release is due at (a bind
+            # is live until then), whether it is a gang member; and, by
+            # task, whether it is a resident. The boundaries' start times
+            # on the host's clock place a timeline's events.
+            relb_pos = np.full(SENT + 1, 1 << 30, np.int64)
+            relb_pos[pos_of[pods_ok]] = b_ok
+            task_pos = np.zeros(SENT + 1, np.int64)
+            have = np.nonzero(pos_of >= 0)[0]
+            task_pos[pos_of[have]] = have
+            stg["relb_pos"] = jnp.asarray(relb_pos.astype(np.int32))
+            stg["task_pos"] = jnp.asarray(task_pos.astype(np.int32))
+            stg["gang_pos"] = jnp.asarray(self.pods.group_id[task_pos] >= 0)
+            stg["resd"] = jnp.asarray(self.pods.bound_node >= 0)
+            stg["tb_host"] = tb_all[:nchunks]
         return stg
 
     def _dcn_recover_block(self, dead_pid: int, gen: int = 0) -> dict:
@@ -3211,6 +3680,9 @@ class WhatIfEngine:
                         rq_d = jax.tree.map(
                             sh_s, self._retry_queue(len(stg["b_c"]))
                         )
+                        if self._events_dev:
+                            ev_calls = self._stage_events()["calls"]
+                            ev_d = self._evict_state()
                 pending_fold = None  # (rows, choices) of the not-yet-folded chunk
                 if comp_on:
                     from .jax_runtime import wave_start_times
@@ -3603,6 +4075,8 @@ class WhatIfEngine:
                             c["retry"] = rq_d
                     return c
 
+                evicting = dev_rel and self._events_dev
+
                 _ck_sig = [
                     self.engine, bool(dev_rel), int(self.retry_buffer),
                     int(self.S), int(C), int(n_chunks),
@@ -3856,6 +4330,19 @@ class WhatIfEngine:
                             states = self._apply_releases(
                                 states, host_assign, released, cand_b
                             )
+                if evicting and ev_calls[ci] is not None:
+                    # The events due by this boundary come first: the nodes
+                    # that leave give up their tasks (into the queue) before
+                    # anything is released or re-tried. What the host sends
+                    # is the boundary's leaving and returning nodes.
+                    with span.mark("host_events"):
+                        args = (states, vassign_d, rq_d, ev_d) + ev_calls[ci] + (
+                            b_c[ci],
+                        )
+                        evict_fn = self._evict_fn()
+                        _reg(evict_fn, args)
+                    with span("boundary_fold"):
+                        states, vassign_d, rq_d, ev_d = evict_fn(*args)
                 if dev_rel:
                     # Static releases first (the bucketed fn; ordering is by
                     # data dependency on states/vassign), then the chunk.
@@ -3886,8 +4373,15 @@ class WhatIfEngine:
                             idx_chunks[ci], tb_c[ci], b_c[ci],
                             vassign_d, rq_d,
                         )
-                        _reg(self._chunk_fn, args)
-                        states, vassign_d, rq_d, out = self._chunk_fn(*args)
+                        if evicting:
+                            args += (ev_d, stg["resd"])
+                            _reg(self._chunk_fn, args)
+                            states, vassign_d, rq_d, ev_d, out = self._chunk_fn(
+                                *args
+                            )
+                        else:
+                            _reg(self._chunk_fn, args)
+                            states, vassign_d, rq_d, out = self._chunk_fn(*args)
                     elif dev_rel:
                         args = (
                             dc, states, srcs[0], srcs[1], idx_chunks[ci],
@@ -3988,6 +4482,7 @@ class WhatIfEngine:
                     hs["alloc"][...] = ksaved_alloc
             with span("device_wait"):
                 jax.block_until_ready(states)
+            self._last_states = states  # probe: the batch's final carry (tests)
             if ck_every:
                 # Round-19 durable-cursor boundary: every queued background
                 # publication must be on the KV plane before this process
@@ -4118,6 +4613,31 @@ class WhatIfEngine:
                                 )
                             ))(outs)
                         ).astype(np.int32)
+                        if evicting:
+                            # An eviction takes an arriving task's bind out
+                            # of ``placed`` again; a resident's re-bind was
+                            # never in it (the single replay's count).
+                            ev_n = self._fetch(ev_d.n).astype(np.int64)
+                            if ev_n[:, _EV["lost"]].any():
+                                # The log was reckoned too small for what
+                                # these timelines evict: twice the room
+                                # (another shape: the programs compile
+                                # anew), and the batch is made again.
+                                self._evict_scale *= 2
+                                self._evict_stage = None
+                                for k in ("evict", "evict_state"):
+                                    self._run_jits.pop(k, None)
+                                return self.run()
+                            placed = (
+                                placed - ev_n[:, _EV["evict_arriving"]]
+                                - ev_n[:, _EV["rebound_resident"]]
+                            ).astype(np.int32)
+                            kube_evict = ev_n[:, _EV["evictions"]].astype(np.int32)
+                            kube_resched = ev_n[:, _EV["rebound"]].astype(np.int32)
+                            kube_stranded = kube_evict - kube_resched
+                            kube_lat = self._fetch(ev_d.wait_s).astype(
+                                np.float64
+                            ) / np.maximum(kube_resched, 1)
                     else:
                         # Device-side reduce, ONE small D2H instead of one
                         # np.asarray round-trip per array.
@@ -4158,18 +4678,27 @@ class WhatIfEngine:
                 retry_per = None
                 if dev_rel and self.retry_buffer and not self.kube:
                     retry_per = self._retry_counts(rq_d, outs, dropped)
+                    if evicting:
+                        retry_per.update(self._evict_counts(ev_n))
             handback_bytes = 0
-            bind_boundary = None
+            bind_boundary = eviction_log = None
             if self.collect_assignments and dev_rel and self.retry_buffer:
                 # Two arrays, final as they land: the arrival binds from the
                 # wave-order buffer, the re-tried ones and the tasks still
-                # queued merged in from the queue's record on the device.
+                # queued merged in from the queue's record on the device;
+                # where timelines evict, the log beside them.
                 with span("handback"):
+                    standing = retry_per["retry_placed"]
+                    if evicting:  # an evicted re-tried bind left the record
+                        standing = standing - retry_per["evict_retried"]
                     assignments, bind_boundary, merged, handback_bytes = (
-                        self._handback_retry(
-                            vassign_d, rq_d, retry_per["retry_placed"])
+                        self._handback_retry(vassign_d, rq_d, standing)
                     )
                     retry_per["handback_merged"] = merged
+                    if evicting:
+                        eviction_log = self._handback_log(
+                            ev_d, retry_per["evictions"])
+                        handback_bytes += int(eviction_log.nbytes)
             elif self.collect_assignments and dev_rel:
                 # The device-release path's placements: the wave-order buffer
                 # comes to the host once, after the last chunk.
@@ -4464,6 +4993,7 @@ class WhatIfEngine:
                 placements_per_sec=total / wall if wall > 0 else 0.0,
                 assignments=assignments,
                 bind_boundary=bind_boundary,
+                eviction_log=eviction_log,
                 utilization_cpu=util,
                 completions_on=self.completions_on,
                 engine=self.engine,

@@ -43,6 +43,7 @@ STAGES = (
     "ksim.commit",        # wave-end commit, gang rollback mask
     "ksim.gang_txn",      # a wide pod group's carried transaction: upkeep, verdict
     "ksim.gang_rollback", # its binds given back where it closes
+    "ksim.evict",         # a boundary's eviction program (what-if timelines)
     "ksim.release",       # boundary release programs
     "ksim.retry",         # a boundary's retry pass: its scan and the queue's upkeep
 )

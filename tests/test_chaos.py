@@ -163,13 +163,35 @@ def test_whatif_per_scenario_timelines(tmp_path):
     np.testing.assert_array_equal(res.evictions, res2.evictions)
 
 
-def test_whatif_timeline_guards():
+def _refused_batches():
+    """What a what-if batch with timelines is still refused for, off the
+    kube mirrors: (engine keywords, timeline, what the message names)."""
+    from kubernetes_simulator_tpu.parallel.mesh import make_mesh
+
+    scale = [NodeEvent(time=1.0, kind="capacity_scale", node=0, scale=0.5)]
+    return {
+        "no_retry_buffer": (dict(), EVS, "retry_buffer > 0"),
+        "mesh": (dict(retry_buffer=8, mesh=make_mesh(1)), EVS, "no mesh"),
+        "capacity_scale": (dict(retry_buffer=8), scale, "capacity_scale"),
+    }
+
+
+@pytest.mark.parametrize("case", ["no_retry_buffer", "mesh", "capacity_scale"])
+def test_whatif_timeline_guards(case):
+    """Timelines run on the device retry path (tests/
+    test_whatif_events_device.py) or through the kube mirrors; the rest
+    stays refused, with the reason."""
     ec, ep = _light_trace(num_pods=4, num_nodes=2)
-    with pytest.raises(ValueError, match="kube"):
+    kw, events, names = _refused_batches()[case]
+    with pytest.raises(ValueError, match=names):
         WhatIfEngine(
-            ec, ep, [Scenario(events=EVS)], FIT_ONLY(), wave_width=1,
-            chunk_waves=1,
+            ec, ep, [Scenario(events=events)], FIT_ONLY(), wave_width=1,
+            chunk_waves=1, **kw,
         )
+
+
+def test_whatif_timeline_validation_names_the_scenario():
+    ec, ep = _light_trace(num_pods=4, num_nodes=2)
     with pytest.raises(ValueError, match="scenario 1"):
         WhatIfEngine(
             ec, ep,

@@ -54,10 +54,13 @@ def test_stage_tables_after_an_armed_replay(borg, tmp_path, monkeypatch):
     ran = {path.split("/")[0] for path in chunk.values()} - {""}
     # every stage this configuration runs: no preemption, releases apart
     # (nor a pod group wider than the wave: tests/test_wide_gangs.py)
-    # nor a retry pass: tests/test_retry_device.py
+    # nor a retry pass: tests/test_retry_device.py; nor a what-if timeline's
+    # eviction program: tests/test_whatif_events_device.py
     assert ran == set(profiling.STAGES) - {
         "ksim.preempt", "ksim.release", "ksim.gang_txn", "ksim.gang_rollback",
-        "ksim.retry"}
+        "ksim.retry", "ksim.evict"}
+    assert profiling.STAGES.index("ksim.evict") < profiling.STAGES.index(
+        "ksim.release")  # program order: a boundary's events come first
     assert {"ksim.filter_score/NodeResourcesFit",
             "ksim.filter_score/TaintToleration",
             "ksim.filter_score/PodTopologySpread"} <= set(chunk.values())
