@@ -404,12 +404,31 @@ def phase_parity() -> dict:
     no_queue = r_dev.fleet_telemetry.summary()["class_row_reads"]
     require(no_queue["arrival"] == "slice" and "retry" not in no_queue,
             f"borg what-if: class_row_reads {no_queue} in a batch with no queue")
+    # Every pass ends with the fullest scenario's last queued wave (a loop
+    # whose trip count is read from the queue): ``pass_waves`` against the
+    # depths the references' answers give (a task stands in pass b's queue
+    # if it failed in a chunk before b, was not dropped and no earlier pass
+    # bound it).
+    w_idx = np.asarray(queued.waves.idx)
+    since = np.full(ep.num_pods, 1 << 30)
+    since[w_idx[w_idx >= 0]] = np.nonzero(w_idx >= 0)[0] // BORG_CHUNK_WAVES + 1
+    retry = r_q.fleet_telemetry.summary()["retry"]
+    deepest = np.max([[((since <= b) & ((r.bind_boundary >= b)
+                                        | (r.bind_boundary == -2))).sum()
+                       for b in range(retry["passes"])] for r in q_refs], axis=0)
+    walked, whole = int((-(-deepest // 8)).sum()), retry["passes"] * RETRY_BUFFER // 8
+    say(f"retry what-if: pass_waves {retry['pass_waves']} of {whole} compiled")
+    require(retry["pass_waves"] == {"mean": float(walked), "max": walked}
+            and 0 < walked < whole,
+            f"retry what-if: the passes walked {retry['pass_waves']} wave "
+            f"steps, the references' queues give {walked} (of {whole})")
     out["retry_whatif"] = {
         "scenarios": len(q_scen), "buffer": RETRY_BUFFER,
         "placed": [int(x) for x in r_q.placed],
         "retry_placed": [int((r_q.bind_boundary[s] >= 0).sum())
                          for s in range(len(q_scen))],
         "class_row_reads": reads,
+        "pass_waves": walked, "pass_waves_compiled": whole,
         "inwave_corrections": resolved_terms(r_q, "retry what-if"),
     }
 

@@ -469,7 +469,10 @@ class RetryQueue(NamedTuple):
     boundary from it into the task-order arrays, on the device
     (``_handback_retry``; the record never comes to the host). ``owed``
     less ``released`` at the end of a run is ``release_leaked`` (0 by
-    construction)."""
+    construction). ``pass_waves`` counts the wave steps the batch's retry
+    passes executed: every pass ends with the last queued wave of the
+    fullest scenario it is vmapped with, so the count is one number for all
+    the scenarios of a device, and ``buffer / W`` a pass is its ceiling."""
 
     ids: jax.Array
     prio: jax.Array
@@ -479,6 +482,7 @@ class RetryQueue(NamedTuple):
     depth_max: jax.Array
     owed: jax.Array
     released: jax.Array
+    pass_waves: jax.Array
     t_id: jax.Array
     t_node: jax.Array
     t_relb: jax.Array
@@ -507,7 +511,6 @@ _EV_COUNTERS = (
     "rebound_resident",  # ... residents (never counted in ``placed``)
     "wait_sum",       # boundaries from eviction to the re-bind, summed
     "wait_max",       # ... the greatest
-    "pass_waves",     # wave steps the retry passes executed (one count a batch)
 )
 _EV = {k: i for i, k in enumerate(_EV_COUNTERS)}
 
@@ -690,7 +693,9 @@ class WhatIfEngine:
         its index (the releases ride the same commit-block core as the
         static lists, so the full default plugin set is covered).
         ``summary()["retry"]`` counts the passes' binds, the drops, the
-        queue's depth and ``release_leaked``. Semantics anchored by
+        queue's depth, ``release_leaked`` and the wave steps the passes
+        executed (``pass_waves``: a pass ends with the fullest scenario's
+        last queued wave, not with the buffer's). Semantics anchored by
         ``greedy_replay(retry_buffer=...)``. Requires the device-release
         completions path without DynTables; 0 = off (the r01–r03
         semantics).
@@ -1586,7 +1591,11 @@ class WhatIfEngine:
                     the main chunk scan -> the queue's upkeep (the
                     chunk's failures join, one stable sort by
                     priority) -> the assignment fold. ``rq`` is the
-                    scenario's ``RetryQueue``. Where the scenarios carry
+                    scenario's ``RetryQueue``. EVERY pass ends early: its
+                    trip count is read from the queue (the deepest among
+                    the scenarios vmapped together, one ``pmax``), never
+                    from the buffer's size, and ``rq.pass_waves`` sums the
+                    trips. Where the scenarios carry
                     timelines (``ev``, the scenario's ``EvictState``; the
                     eviction program ran before the static releases): a
                     node that is out reads allocatable 0 here, the queue's
@@ -1652,36 +1661,32 @@ class WhatIfEngine:
                         rb_waves = q.reshape(RBW, wave_width)
                         slots_r = T.gather_slots_device(src, rb_waves)
                         extra_r = V3.gather_extra_device(xsrc, rb_waves)
-                        if ev_on:
-                            # The queue stands at the front of its buffer
-                            # (the sorts put the holes last), so the pass
-                            # ends with the fullest scenario's last queued
-                            # wave: a buffer sized for an eviction burst
-                            # costs its steps only where one is queued.
-                            trips = jax.lax.pmax(
-                                -(-rq.count // wave_width), _RETRY_VMAP
+                        # The queue stands at the front of its buffer (the
+                        # sorts put the holes last), so the pass ends with
+                        # the fullest scenario's last queued wave: a buffer
+                        # sized for the deepest backlog or an eviction burst
+                        # costs its steps only where one is queued, and a
+                        # wave not walked reads as no bind.
+                        trips = jax.lax.pmax(
+                            -(-rq.count // wave_width), _RETRY_VMAP
+                        )
+
+                        def pass_wave(i, carry):
+                            st, out = carry
+                            st, picks = retry_step(st, jax.tree.map(
+                                lambda a: jax.lax.dynamic_index_in_dim(
+                                    a, i, keepdims=False
+                                ), (slots_r, extra_r),
+                            ))
+                            return st, jax.lax.dynamic_update_index_in_dim(
+                                out, picks.astype(out.dtype), i, 0
                             )
 
-                            def pass_wave(i, carry):
-                                st, out = carry
-                                st, picks = retry_step(st, jax.tree.map(
-                                    lambda a: jax.lax.dynamic_index_in_dim(
-                                        a, i, keepdims=False
-                                    ), (slots_r, extra_r),
-                                ))
-                                return st, jax.lax.dynamic_update_index_in_dim(
-                                    out, picks.astype(out.dtype), i, 0
-                                )
-
-                            state, choices_r = jax.lax.fori_loop(
-                                0, trips, pass_wave, (state, jnp.full(
-                                    (RBW, wave_width), PAD, jnp.int32
-                                )),
-                            )
-                        else:
-                            state, choices_r = jax.lax.scan(
-                                retry_step, state, (slots_r, extra_r)
-                            )
+                        state, choices_r = jax.lax.fori_loop(
+                            0, trips, pass_wave, (state, jnp.full(
+                                (RBW, wave_width), PAD, jnp.int32
+                            )),
+                        )
                         flat_cr = choices_r.reshape(RB)
                         placed_r = (flat_cr >= 0) & (q >= 0)
                         retry_placed = placed_r.sum(dtype=jnp.int32)
@@ -1708,6 +1713,7 @@ class WhatIfEngine:
                             + (relb < BIG).sum(dtype=jnp.int32),
                             released=released,
                             depth_max=jnp.maximum(rq.depth_max, rq.count),
+                            pass_waves=rq.pass_waves + trips,
                         )
                         if want_an:
                             rq = rq._replace(
@@ -1729,7 +1735,6 @@ class WhatIfEngine:
                                 "rebound_same": tally(back & (rq.ev_at == b)),
                                 "rebound_resident": tally(back & resd[safe]),
                                 "wait_sum": wait.sum(dtype=jnp.int32),
-                                "pass_waves": trips,
                             }
                             n = ev.n + jnp.stack([
                                 delta.get(k, jnp.int32(0)) for k in _EV_COUNTERS
@@ -2699,15 +2704,19 @@ class WhatIfEngine:
         """What ``summary()["retry"]`` holds per scenario (``[S]`` each) of
         the batch that just ran, in one copy from the device: the tasks the
         passes bound, the queue's depth at the boundaries (its largest, and
-        what is still queued at the end) and ``release_leaked``, with the
-        drops the run has already fetched."""
-        keys = ("retry_placed", "depth_max", "depth_at_end", "release_leaked")
+        what is still queued at the end), ``release_leaked`` and
+        ``pass_waves`` (the wave steps the passes executed: a pass ends with
+        the last queued wave of the fullest scenario on its device, so the
+        number is the same in every scenario of a device and differs by
+        device under a mesh), with the drops the run has already fetched."""
+        keys = ("retry_placed", "depth_max", "depth_at_end", "release_leaked",
+                "pass_waves")
         got = self._fetch(self._jit_once(
             "retry_counts", lambda: jax.jit(lambda o, q: jnp.stack([
                 jnp.stack([r for _, r in o], axis=1).sum(axis=1, dtype=jnp.int32),
-                q.depth_max, q.count, q.owed - q.released,
+                q.depth_max, q.count, q.owed - q.released, q.pass_waves,
             ]))
-        )(outs, rq))  # [4, S]
+        )(outs, rq))  # [5, S]
         return dict(zip(keys, got), retry_dropped=dropped)
 
     def _evict_counts(self, ev_n: np.ndarray) -> dict:
@@ -2718,10 +2727,9 @@ class WhatIfEngine:
         stranded (evicted and with no node at the end: still queued,
         dropped at a full buffer, or a gang member), the last two kinds
         on their own; the re-tried binds among the victims; how long the
-        re-bound waited, in boundaries (mean, and the longest); and the
-        wave steps the batch's retry passes executed (``pass_waves``: a
-        pass ends with the fullest scenario's last queued wave, so one
-        number a batch, the same in every scenario)."""
+        re-bound waited, in boundaries (mean, and the longest).
+        (``pass_waves`` is no eviction counter: every retry batch has it,
+        from the ``RetryQueue``, through ``_retry_counts``.)"""
         col = lambda k: ev_n[:, _EV[k]]
         back = col("rebound")
         return {
@@ -2734,7 +2742,6 @@ class WhatIfEngine:
             "evict_retried": col("evict_retried"),
             "evict_wait_boundaries_mean": col("wait_sum") / np.maximum(back, 1),
             "evict_wait_boundaries_max": col("wait_max"),
-            "pass_waves": col("pass_waves"),
         }
 
     def _handback_log(self, ev: EvictState, evictions) -> np.ndarray:
@@ -2759,8 +2766,10 @@ class WhatIfEngine:
         at the boundaries (its largest, and what is still queued at the
         end), ``release_leaked``: re-tried binds with a release boundary
         inside the trace that no boundary released (0 by construction: the
-        counter that says no release was lost), and, where the placements
-        were handed back, ``handback_merged``: the re-tried binds the
+        counter that says no release was lost), ``pass_waves``: the wave
+        steps the passes executed, to hold against ``passes * buffer / W``,
+        what passes that walked the whole buffer would take, and, where the
+        placements were handed back, ``handback_merged``: the re-tried binds the
         hand-back program wrote into ``bind_boundary`` (``retry_placed`` by
         construction: the counter that says the merge ran on the device
         and lost nothing). Where timelines evict, ``_evict_counts``'
@@ -2823,6 +2832,7 @@ class WhatIfEngine:
             depth_max=full((), 0, jnp.int32),
             owed=full((), 0, jnp.int32),
             released=full((), 0, jnp.int32),
+            pass_waves=full((), 0, jnp.int32),
             t_id=full((boundaries, RB), PAD, jnp.int32),
             t_node=full((boundaries, RB), PAD, jnp.int32),
             t_relb=full((boundaries, RB), 1 << 30, jnp.int32),

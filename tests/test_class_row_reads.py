@@ -201,10 +201,10 @@ def _scenarios(n_nodes):
     ]
 
 
-def _lowered_text(program):
-    """The lowered text of the first chunk call of a program kind on the
-    two-class trace: the engine's own jitted function over the arguments
-    its own batch hands it."""
+def _lowered(program):
+    """The first chunk call of a program kind on the two-class trace,
+    lowered: the engine's own jitted function over the arguments its own
+    batch hands it."""
     ec, ep = _two_class_planes()
     cfg = FrameworkConfig()
     if program == "replay":
@@ -233,7 +233,7 @@ def _lowered_text(program):
     setattr(eng, name, capture)
     with pytest.raises(Captured):
         eng.replay() if program == "replay" else eng.run()
-    return real.lower(*box["args"]).as_text()
+    return real.lower(*box["args"])
 
 
 @pytest.mark.parametrize("program", sorted(_PARENT_PROGRAMS))
@@ -241,7 +241,7 @@ def test_steps_with_shared_slots_keep_the_parents_program(program):
     """No step but the retry pass's is built with ``slots_by_scenario``: the
     single replay's chunk program and both what-if chunk programs without a
     queue lower to the parent's text, the text of the ``"slice"`` form."""
-    text = _lowered_text(program)
+    text = _lowered(program).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_PROGRAMS[program]
 
 
@@ -249,14 +249,31 @@ def test_the_retry_program_loses_the_pass_gathers_and_nothing_else(monkeypatch):
     """The retry program holds the wave step twice. Under ``vmap`` every
     dynamic index of a class plane is written as a gather (one whose index
     every scenario shares folds to a dynamic slice in the compiler; one by
-    the queue's per-scenario class id does not). As shipped the program has
-    the pass's W slots x 3 live planes fewer of them than with the pass's
-    read named ``"slice"``, the parent's form: ``tol_ok``, ``na_ok`` and
-    ``na_raw`` (``tol_raw`` is dead without a PreferNoSchedule taint)."""
-    gathers = lambda text: text.count('"stablehlo.gather"(')
-    shipped = _lowered_text("whatif-retry")
+    the queue's per-scenario class id does not). As shipped the COMPILED
+    program has the pass's W slots x 3 live planes fewer of them than with
+    the pass's read named ``"slice"``, the parent's form: ``tol_ok``,
+    ``na_ok`` and ``na_raw`` (``tol_raw`` is dead without a PreferNoSchedule
+    taint).
+
+    Since PR 46 the pass is a ``while`` whose trip count is read from the
+    queue, and the LOWERED text differs by W x 4: jax 0.9.0 has a dead-code
+    rule for ``scan`` (which pruned the dead ``tol_raw`` read from the
+    pass's body before lowering, so the lowered text read W x 3 while the
+    pass was a scan) and none for ``while``, whose body is lowered as
+    written; XLA drops the read when it compiles. Both counts are held:
+    the lowered one says all four planes' reads took the select form, the
+    compiled one what the device is spared."""
+    from jax._src.interpreters import partial_eval as pe
+    from jax._src.lax.control_flow import loops
+
+    assert loops.scan_p in pe.dce_rules and loops.while_p not in pe.dce_rules
+    lowered = lambda text: text.count('"stablehlo.gather"(')
+    compiled = lambda text: text.count(" gather(")
+    shipped = _lowered("whatif-retry")
     monkeypatch.setattr(V3, "class_row_reads", lambda *a, **k: "slice")
-    parents = _lowered_text("whatif-retry")
-    assert gathers(parents) - gathers(shipped) == 3 * W
+    parents = _lowered("whatif-retry")
+    assert lowered(parents.as_text()) - lowered(shipped.as_text()) == 4 * W
+    assert (compiled(parents.compile().as_text())
+            - compiled(shipped.compile().as_text())) == 3 * W
     # the arrival scan's reads are in both
-    assert gathers(_lowered_text("whatif-release")) >= 3 * W
+    assert lowered(_lowered("whatif-release").as_text()) >= 3 * W

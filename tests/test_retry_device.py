@@ -451,6 +451,120 @@ def test_retry_handback_merges_every_bind_the_passes_made(case):
         2 * retry["depth_at_end"]["mean"] - retry["scenario0"]["depth_at_end"]]
 
 
+def queue_depths(ep, bind_boundary, W, C, log=()):
+    """``[boundaries]``: how many tasks stand in each pass's queue, worked
+    out on the host from ONE scenario's answers (never from the program).
+    A task that failed in chunk c (its arrival chunk from ``pack_waves``)
+    stands in the queue of every pass from c + 1 to the pass that bound it
+    (``bind_boundary`` >= 0), or to the end (-2); one that bound in its
+    arrival wave (-1), was dropped at a full buffer (-3) or is a gang member
+    (-4, -5) never stood there. Under a timeline, ``log`` is the scenario's
+    eviction log (rows of boundary, task, node, the pass that had bound it
+    or -1): a row ends the stay on a node that the task's previous stay in
+    the queue led to, and starts a stay in the queue at the row's boundary,
+    which ends as above with the next row's binding pass or, after the last
+    row, with ``bind_boundary`` (of a task's LAST bind)."""
+    from kubernetes_simulator_tpu.sim.waves import pack_waves
+
+    idx = pack_waves(ep, W).idx
+    B = -(-idx.shape[0] // C)
+    joins = {}  # task -> the boundaries it joined the queue at, in order
+    ends = {}   # task -> what ended each of those stays
+    w, k = np.nonzero(idx >= 0)
+    for t, c in zip(idx[w, k].tolist(), (w // C).tolist()):
+        joins[t], ends[t] = [c + 1], []
+    for b, t, _, by in np.asarray(log, np.int64).reshape(-1, 4).tolist():
+        if t not in joins:  # a resident: never queued before its first row
+            joins[t], ends[t] = [], []
+        else:
+            ends[t].append(by)
+        joins[t].append(b)
+    depth = np.zeros(B, np.int64)
+    for t, starts in joins.items():
+        for start, end in zip(starts, ends[t] + [int(bind_boundary[t])]):
+            if end >= 0:
+                depth[start:end + 1] += 1
+            elif end == -2:
+                depth[start:] += 1
+    return depth
+
+
+def pass_waves_of(depths, W):
+    """The wave steps the passes of scenarios vmapped together execute: a
+    pass ends with the fullest scenario's last queued wave."""
+    return int((-(-np.max(depths, axis=0) // W)).sum())
+
+
+def _perturbed(n_nodes):
+    """The base cluster and three perturbed ones, of which only the one with
+    half its cpu queues deep."""
+    from kubernetes_simulator_tpu.sim.whatif import Perturbation
+
+    scale = lambda f: Perturbation("scale_capacity", nodes=np.arange(n_nodes),
+                                   resource="cpu", factor=f)
+    return [Scenario(), Scenario([scale(1.25)]), Scenario([scale(0.5)]),
+            Scenario([scale(1.5)])]
+
+
+# name -> (the trace, the buffer, the scenarios or None for the base alone)
+PASS_WAVE_CASES = {
+    **{name: (trace, 4 if name == "small_buffer_overflows" else 16, None)
+       for name, trace in RETRY_CASES.items()},
+    "queue_never_passes_a_quarter_of_its_buffer": (dict(), 64, None),
+    "one_of_several_scenarios_fills_its_buffer": (
+        dict(priorities=(0, 100, 200)), 16, _perturbed),
+    "queue_stays_empty": (dict(nodes=40), 16, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PASS_WAVE_CASES))
+def test_retry_passes_end_with_the_fullest_scenarios_last_queued_wave(case):
+    """``summary()["retry"]["pass_waves"]`` is the sum over the boundaries of
+    ``ceil(deepest scenario's queue / W)``, the depths from each scenario's
+    anchor (``greedy_replay`` on its own cluster): no pass walks the rest of
+    its buffer, and a wave not walked is no bind, so both hand-back arrays
+    stay the anchor's, scenario for scenario."""
+    from kubernetes_simulator_tpu.sim.whatif import ScenarioSet
+
+    trace, RB, perturb = PASS_WAVE_CASES[case]
+    ec, ep = _contended(**trace)
+    scen = perturb(ec.num_nodes) if perturb else [Scenario()]
+    W = C = 4
+    _, res, base = _device_and_anchor(ec, ep, W=W, C=C, RB=RB, scenarios=scen)
+    retry = res.fleet_telemetry.summary()["retry"]
+    own = ScenarioSet(ec, scen, keep_host_stacks=True).host_clusters(ec)
+    anchors = [base] + [
+        greedy_replay(c, ep, FrameworkConfig(), wave_width=W,
+                      completions_chunk_waves=C, retry_buffer=RB)
+        for c in own[1:]
+    ]
+    depths = []
+    for s, anchor in enumerate(anchors):
+        np.testing.assert_array_equal(res.assignments[s], anchor.assignments)
+        np.testing.assert_array_equal(res.bind_boundary[s], anchor.bind_boundary)
+        depths.append(queue_depths(ep, anchor.bind_boundary, W, C))
+    depths = np.array(depths)
+    assert depths.shape == (len(scen), retry["passes"])
+    want = pass_waves_of(depths, W)
+    # one count for all the scenarios vmapped together
+    assert retry["pass_waves"] == {"mean": float(want), "max": want}
+    assert retry["scenario0"]["pass_waves"] == want
+    assert retry["depth_max"]["max"] == depths.max() <= RB
+    whole = retry["passes"] * RB // W
+    if case == "queue_stays_empty":
+        assert want == 0 and not (res.bind_boundary >= 0).any()
+    elif case == "queue_never_passes_a_quarter_of_its_buffer":
+        assert 0 < depths.max() <= RB // 4 and 0 < want <= whole // 4
+    elif case == "one_of_several_scenarios_fills_its_buffer":
+        full = depths.max(axis=1) == RB
+        assert full.tolist() == [False, False, True, False]
+        # the deep scenario alone sets every pass's length
+        assert want == pass_waves_of(depths[full], W) > pass_waves_of(depths[~full], W)
+        assert want < whole  # its queue is empty for the first boundaries
+    else:
+        assert 0 < want < whole
+
+
 def test_retry_handback_that_loses_a_bind_raises(monkeypatch):
     """A record row lost between the pass that wrote it and the hand-back
     is a wrong answer, not a slow one: ``run()`` raises and names the

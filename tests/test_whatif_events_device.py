@@ -80,10 +80,20 @@ def batch(buffer):
     return CELL[buffer]
 
 
-def anchor(ec, ep, events, buffer):
-    rep = JaxReplayEngine(ec, ep, FrameworkConfig(), wave_width=W,
-                          chunk_waves=C, completions=True, retry_buffer=buffer)
-    return rep.replay(node_events=events), rep._last_bops
+ANCHOR = {}
+
+
+def anchor(name, buffer):
+    """The single replay of one plan at ``buffer`` and its boundary mirror:
+    made once, read by every test that holds the batch to it."""
+    if (name, buffer) not in ANCHOR:
+        ec, ep, plans = batch(buffer)[:3]
+        rep = JaxReplayEngine(ec, ep, FrameworkConfig(), wave_width=W,
+                              chunk_waves=C, completions=True,
+                              retry_buffer=buffer)
+        ANCHOR[name, buffer] = (rep.replay(node_events=plans[name]),
+                                rep._last_bops)
+    return ANCHOR[name, buffer]
 
 
 CASES = [(name, buffer) for buffer in (512, 64) for name in (
@@ -95,7 +105,7 @@ CASES = [(name, buffer) for buffer in (512, 64) for name in (
 def test_the_three_answers_are_the_single_replays(name, buffer):
     ec, ep, plans, eng, res, states = batch(buffer)
     s = list(plans).index(name)
-    single, bops = anchor(ec, ep, plans[name], buffer)
+    single, bops = anchor(name, buffer)
     np.testing.assert_array_equal(res.assignments[s], single.assignments)
     np.testing.assert_array_equal(res.bind_boundary[s], bops.bind_boundary_codes())
     log = np.asarray(bops.evict_log, np.int32).reshape(-1, 4)
@@ -115,6 +125,39 @@ def test_the_three_answers_are_the_single_replays(name, buffer):
     assert retry["release_leaked"]["max"] == 0
     if name != "base":
         assert len(log) > 0
+
+
+@pytest.mark.parametrize("buffer", [512, 64])
+def test_a_timelines_batch_counts_its_pass_waves_like_every_retry_batch(buffer):
+    """``summary()["retry"]["pass_waves"]`` comes from the ``RetryQueue``
+    (``_EV_COUNTERS`` has lost the name: it is no eviction counter) and is
+    what the depths of the plans' queues give, the evicted standing in them
+    from their boundary on: the sum over the boundaries of ``ceil(deepest
+    plan's queue / W)``, each plan's depths from its single replay's answers
+    and eviction log."""
+    from test_retry_device import pass_waves_of, queue_depths
+
+    from kubernetes_simulator_tpu.sim import whatif
+
+    assert "pass_waves" not in whatif._EV_COUNTERS
+    assert "pass_waves" in whatif.RetryQueue._fields
+    ec, ep, plans, eng, res, _ = batch(buffer)
+    depths = []
+    for s, name in enumerate(plans):
+        single, bops = anchor(name, buffer)
+        codes = bops.bind_boundary_codes()
+        np.testing.assert_array_equal(res.bind_boundary[s], codes)
+        np.testing.assert_array_equal(res.assignments[s], single.assignments)
+        depths.append(queue_depths(ep, codes, W, C, log=bops.evict_log))
+    depths = np.array(depths)
+    retry = res.fleet_telemetry.summary()["retry"]
+    want = pass_waves_of(depths, W)
+    assert retry["pass_waves"] == {"mean": float(want), "max": want}
+    assert retry["scenario0"]["pass_waves"] == want
+    assert retry["depth_max"]["max"] == depths.max()
+    assert depths.shape[1] == retry["passes"]
+    # the evicted are in the count: the base plan alone would end sooner
+    assert 0 < pass_waves_of(depths[:1], W) < want < retry["passes"] * buffer // W
 
 
 def test_what_the_plans_exercise():

@@ -359,6 +359,44 @@ def test_retry_handback_under_a_mesh_is_the_unmeshed(case, retrying):
     assert retry["handback_merged"] == retry["retry_placed"]
 
 
+def test_retry_passes_under_a_mesh_end_with_their_own_devices_fullest(case, retrying):
+    """With the scenario axis over two devices each device's passes end with
+    ITS fullest scenario's last queued wave (the ``pmax`` is over the vmapped
+    axis of one device's slice: no collective crosses devices,
+    tests/test_mesh_hlo.py): scenarios 0 and 1 queue little, scenario 3 fills
+    its buffer on the other device, and each pair reports its own device's
+    ``pass_waves``, worked out from the anchors' answers. The hand-back is
+    the unmeshed run's."""
+    from test_retry_device import pass_waves_of, queue_depths
+
+    from kubernetes_simulator_tpu.parallel.mesh import make_mesh
+
+    ec, ep, cfg, scen = case
+    _, plain = retrying
+    res = device_engine(ec, ep, cfg, scen[:4], retry_buffer=16,
+                        mesh=make_mesh(2)).run()
+    np.testing.assert_array_equal(res.assignments, plain.assignments[:4])
+    np.testing.assert_array_equal(res.bind_boundary, plain.bind_boundary[:4])
+    own = ScenarioSet(ec, scen, keep_host_stacks=True).host_clusters(ec)
+    depths = []
+    for s in range(6):
+        ref = greedy_replay(own[s], ep, cfg, wave_width=W,
+                            completions_chunk_waves=C, retry_buffer=16)
+        np.testing.assert_array_equal(plain.bind_boundary[s], ref.bind_boundary)
+        depths.append(queue_depths(ep, ref.bind_boundary, W, C))
+    depths = np.array(depths)
+    here, there = pass_waves_of(depths[:2], W), pass_waves_of(depths[2:4], W)
+    assert depths[:2].max() < 16 == depths[3].max() and 0 < here < there
+    retry = res.fleet_telemetry.summary()["retry"]
+    assert retry["scenario0"]["pass_waves"] == here
+    assert retry["pass_waves"] == {"mean": (here + there) / 2, "max": there}
+    # on one device all six end together, with the deepest of them
+    unmeshed = plain.fleet_telemetry.summary()["retry"]
+    whole = pass_waves_of(depths, W)
+    assert unmeshed["pass_waves"] == {"mean": float(whole), "max": whole}
+    assert there < whole < unmeshed["passes"] * 16 // W
+
+
 def test_phases_cover_a_whatif_run(answered):
     """As tests/test_stage_scopes.py holds the replay: the phases are
     sequential on one thread and what run() spends outside them is small.
