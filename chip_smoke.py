@@ -236,6 +236,7 @@ def phase_run_config2(placed_scenario0: int) -> dict:
 
 def phase_parity() -> dict:
     import dataclasses
+    import re
 
     import numpy as np
 
@@ -251,6 +252,7 @@ def phase_parity() -> dict:
         ScenarioSet,
         WhatIfEngine,
     )
+    from kubernetes_simulator_tpu.utils import profiling
     from kubernetes_simulator_tpu.utils.config import SimConfig, build_case
 
     def same(dev, ref, what, ref_name="the host reference"):
@@ -368,7 +370,13 @@ def phase_parity() -> dict:
 
     # (c2) the same case with a pending queue: ``retry_buffer`` re-tries the
     # tasks that failed at every chunk boundary, in priority order, on the
-    # device (``jit_per_scenario_retry``). Every scenario's nodes AND bind
+    # device: two programs a boundary, the pass (``jit_per_scenario_retry``)
+    # and the arrival scan with the queue's upkeep
+    # (``jit_per_scenario_arrivals``), each with ONE loop that carries the
+    # state, so that the compiler keeps the step's node planes on chip in
+    # both (printed below from the compiled texts: at this size for the
+    # record, the full cell's are held by tests/test_retry_memory_spaces.py
+    # and PERF.md §7). Every scenario's nodes AND bind
     # boundaries against the host reference with the same buffer on the
     # equally perturbed cluster, one scenario with a taint that nobody
     # tolerates. The pass walks each scenario's own queue, so it has to
@@ -389,7 +397,33 @@ def phase_parity() -> dict:
                           collect_assignments=True, **kw)
     require(queued.release_path == "device",
             "retry what-if did not take the device release path")
+    programs = {}
+    for attr in ("_retry_fn", "_chunk_fn"):
+        def first(*args, _attr=attr, _real=getattr(queued, attr)):
+            if _attr not in programs:  # before the call: it donates its buffers
+                programs[_attr] = _real.lower(*args)
+            return _real(*args)
+
+        setattr(queued, attr, first)
     r_q = queued.run()
+    require(sorted(programs) == ["_chunk_fn", "_retry_fn"],
+            f"retry what-if: the boundary's programs were {sorted(programs)}")
+    S_q, (N_q, R_q) = len(q_scen), ec.allocatable.shape
+    planes = {"used": f"f32[{S_q},{R_q},{N_q}]",
+              "allocatable": f"f32[{S_q},{N_q},{R_q}]",
+              "class_mask": f"bf16[{S_q},2,{N_q}]"}
+    # plane by plane: at this size the compiler may fold one into another
+    # form, and a loop is reported for the planes it does carry
+    texts = {re.search(r"module @(\w+)", low.as_text()).group(1):
+             low.compile().as_text() for low in programs.values()}
+    spaces = {
+        module: {name: {loop: got[shape] for loop, got in
+                        profiling.loop_memory_spaces(text, [shape]).items()}
+                 for name, shape in planes.items()}
+        for module, text in texts.items()
+    }
+    say(f"retry what-if: memory space of {planes} in each loop that carries "
+        f"it (1 = on chip, 0 = HBM): {spaces}")
     for s, ref in enumerate(q_refs):
         same(r_q.assignments[s], ref.assignments,
              f"retry what-if scenario {s}")
@@ -429,6 +463,7 @@ def phase_parity() -> dict:
                          for s in range(len(q_scen))],
         "class_row_reads": reads,
         "pass_waves": walked, "pass_waves_compiled": whole,
+        "loop_memory_spaces": spaces,
         "inwave_corrections": resolved_terms(r_q, "retry what-if"),
     }
 

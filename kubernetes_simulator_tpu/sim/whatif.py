@@ -1240,7 +1240,10 @@ class WhatIfEngine:
         self._mesh_batch: Optional[Dict[str, float]] = None
         self._mesh_collectives: Optional[Dict[str, int]] = None
         self._mesh_programs: Optional[Dict[str, tuple]] = None
-        self._chunk_fn = self._build_chunk_fn()
+        # With a ``retry_buffer`` on the device-release path a boundary is
+        # TWO programs: the retry pass (``_retry_fn``, else None), then the
+        # chunk.
+        self._retry_fn, self._chunk_fn = self._build_chunk_fns()
         # Device-resident slot sources (one upload per engine): the chunk
         # loop then gathers rows on device — see ops.tpu.SlotSource.
         # Scenario-shared, so under a mesh they replicate ONCE and every
@@ -1445,7 +1448,8 @@ class WhatIfEngine:
         self._timelines = timelines
         self._evict_stage = None
 
-    def _build_chunk_fn(self):
+    def _build_chunk_fns(self):
+        """(the retry pass program or None, the chunk program)."""
         collect = self._need_choices
         spec, wave_width = self.spec, self.wave_width
         pol_on = self._policies is not None
@@ -1574,43 +1578,46 @@ class WhatIfEngine:
                 want_an, want_pf = rel_core.want_an, rel_core.want_pf
                 ev_on = self._events_dev
 
+                def derive(dc, down):
+                    """What both retry programs build of a scenario's
+                    tables: where the scenarios carry timelines a node
+                    that is out reads allocatable 0."""
+                    if ev_on:
+                        dc = dc._replace(allocatable=jnp.where(
+                            down[:, None], 0.0, dc.allocatable
+                        ))
+                    d = T.Derived.build(dc)
+                    return dc, d, V3.class_masks(dc, d, st3, spec, reps)
+
                 def per_scenario_retry(
-                    dc, state, src, xsrc, mgt, antit, preft,
-                    prefwt, durt, priot, tbt,
-                    idx, t_b, b,
-                    vassign, rq, ev=None, resd=None,
+                    dc, state, src, xsrc, mgt, antit, preft, prefwt, tbt,
+                    t_b, b, rq, ev=None, resd=None,
                 ):
-                    """The device-release chunk call with the
-                    bounded unschedulable-retry pass (semantics:
-                    sim.greedy.greedy_replay(retry_buffer=...)).
-                    Static releases ran in the separate bucketed
-                    _release_fn before this call. Order here: the
-                    releases of re-tried binds that are due -> the
-                    retry pass over the queue (in QueueSort order
-                    since the last call's upkeep) and its record ->
-                    the main chunk scan -> the queue's upkeep (the
-                    chunk's failures join, one stable sort by
-                    priority) -> the assignment fold. ``rq`` is the
-                    scenario's ``RetryQueue``. EVERY pass ends early: its
+                    """The FIRST of the two programs a boundary of a batch
+                    with a ``retry_buffer`` dispatches (semantics:
+                    sim.greedy.greedy_replay(retry_buffer=...)); the
+                    second is ``per_scenario_arrivals``. Each holds ONE
+                    loop that carries ``state``: in one program the pass
+                    loop followed by the arrival scan leave the step's
+                    node planes in HBM (ROADMAP S2.5). Static releases ran
+                    in the separate bucketed _release_fn before this call.
+                    Order here: the releases of re-tried binds that are
+                    due -> the retry pass over the queue (in QueueSort
+                    order since the last boundary's upkeep) and its record.
+                    ``rq`` is the scenario's ``RetryQueue``; it goes back
+                    with the placed marked -1 in ``ids`` and taken off
+                    ``count``, ``prio`` / ``dur`` / ``ev_at`` untouched and
+                    still aligned, for the upkeep of the second program.
+                    EVERY pass ends early: its
                     trip count is read from the queue (the deepest among
                     the scenarios vmapped together, one ``pmax``), never
                     from the buffer's size, and ``rq.pass_waves`` sums the
                     trips. Where the scenarios carry
                     timelines (``ev``, the scenario's ``EvictState``; the
                     eviction program ran before the static releases): a
-                    node that is out reads allocatable 0 here, the queue's
-                    ``ev_at`` rides its sorts, and the pass counts the
-                    evicted tasks it binds again."""
-                    if ev_on:
-                        dc = dc._replace(allocatable=jnp.where(
-                            ev.down[:, None], 0.0, dc.allocatable
-                        ))
-                    d = T.Derived.build(dc)
-                    cmasks = V3.class_masks(dc, d, st3, spec, reps)
-                    wave_step = V3.make_wave_step3(
-                        dc, d, sh3, st3, wave_width, spec, cmasks,
-                        scenario_axis=True,
-                    )
+                    node that is out reads allocatable 0 here, and the
+                    pass counts the evicted tasks it binds again."""
+                    dc, d, cmasks = derive(dc, ev.down if ev_on else None)
                     # The pass walks the scenario's own queue: its
                     # slots differ by scenario, the arrival scan's do
                     # not (V3.class_row_reads).
@@ -1700,6 +1707,8 @@ class WhatIfEngine:
                         ).astype(jnp.int32)
                         safe = jnp.clip(q, 0)
                         rq = rq._replace(
+                            ids=jnp.where(placed_r, -1, q),
+                            count=rq.count - retry_placed,
                             t_id=put(rq.t_id, jnp.where(placed_r, q, -1)),
                             t_node=put(
                                 rq.t_node, jnp.where(placed_r, flat_cr, -1)
@@ -1724,8 +1733,6 @@ class WhatIfEngine:
                                 t_pf=put(rq.t_pf, preft[safe].T),
                                 t_pw=put(rq.t_pw, prefwt[safe].T),
                             )
-                        ids = jnp.where(placed_r, -1, q)
-                        count = rq.count - retry_placed
                         if ev_on:
                             back = placed_r & (rq.ev_at >= 0)
                             wait = jnp.where(back, b - rq.ev_at, 0)
@@ -1745,6 +1752,28 @@ class WhatIfEngine:
                                     back, t_b - tbt[jnp.clip(rq.ev_at, 0)], 0.0
                                 ).sum(),
                             )
+                    if ev_on:
+                        return state, rq, ev, retry_placed
+                    return state, rq, retry_placed
+
+                def per_scenario_arrivals(
+                    dc, state, src, xsrc, durt, priot, idx, b,
+                    vassign, rq, down=None,
+                ):
+                    """The SECOND program of a boundary with a
+                    ``retry_buffer``, dispatched on the arrays
+                    ``per_scenario_retry`` hands back (nothing reaches the
+                    host between them): the main chunk scan -> the queue's
+                    upkeep (the chunk's failures join behind what the pass
+                    left, one stable sort by priority; ``ev_at`` rides
+                    it) -> the assignment fold. ``down`` is the
+                    ``EvictState``'s where the scenarios carry timelines:
+                    a node that is out reads allocatable 0 here too."""
+                    dc, d, cmasks = derive(dc, down)
+                    wave_step = V3.make_wave_step3(
+                        dc, d, sh3, st3, wave_width, spec, cmasks,
+                        scenario_axis=True,
+                    )
                     # 3. the main chunk scan, as without a queue.
                     slots = T.gather_slots_device(src, idx)
                     extra = V3.gather_extra_device(xsrc, idx)
@@ -1771,14 +1800,14 @@ class WhatIfEngine:
                         fail = (
                             (choices < 0) & slots.valid & (slots.group < 0)
                         ).reshape(-1)
-                        room = RB - count
+                        room = RB - rq.count
                         nfail = fail.sum(dtype=jnp.int32)
                         take = fail & (
                             jnp.cumsum(fail.astype(jnp.int32)) <= room
                         )
                         rsafe = jnp.clip(rows, 0)
                         cat_ids = jnp.concatenate(
-                            [ids, jnp.where(take, rows, -1)]
+                            [rq.ids, jnp.where(take, rows, -1)]
                         )
                         cat_prio = jnp.concatenate([rq.prio, priot[rsafe]])
                         cat_dur = jnp.concatenate([rq.dur, durt[rsafe]])
@@ -1796,7 +1825,7 @@ class WhatIfEngine:
                         rq = rq._replace(
                             ids=cat_ids[:RB], prio=cat_prio[:RB],
                             dur=cat_dur[:RB],
-                            count=count + jnp.minimum(nfail, room),
+                            count=rq.count + jnp.minimum(nfail, room),
                             dropped=rq.dropped
                             + jnp.maximum(nfail - room, 0),
                         )
@@ -1812,23 +1841,23 @@ class WhatIfEngine:
                             choices.reshape(-1),
                             (b * idx.size,),
                         )
-                    if ev_on:
-                        return state, vassign, rq, ev, (counts, retry_placed)
-                    return state, vassign, rq, (counts, retry_placed)
+                    return state, vassign, rq, counts
 
-                axes_retry = (
-                    0, 0, None, None, None, None, None,
-                    None, None, None, None,
-                    None, None, None,
-                    0, 0,
-                ) + ((0, None) if ev_on else ())
-                vmapped_retry = jax.vmap(
-                    per_scenario_retry, in_axes=axes_retry,
-                    axis_name=_RETRY_VMAP,
+                axes_retry = (0, 0) + (None,) * 9 + (0,) + (
+                    (0, None) if ev_on else ()
+                )
+                axes_arr = (0, 0) + (None,) * 6 + (0, 0) + (
+                    (0,) if ev_on else ()
                 )
                 return finalize(
-                    vmapped_retry, axes_retry,
-                    (1, 14, 15) + ((16,) if ev_on else ()),
+                    jax.vmap(
+                        per_scenario_retry, in_axes=axes_retry,
+                        axis_name=_RETRY_VMAP,
+                    ),
+                    axes_retry, (1, 11) + ((12,) if ev_on else ()),
+                ), finalize(
+                    jax.vmap(per_scenario_arrivals, in_axes=axes_arr),
+                    axes_arr, (1, 8, 9),
                 )
 
             # vmap matches in_axes against the args actually
@@ -1845,7 +1874,7 @@ class WhatIfEngine:
             vmapped_rel = jax.vmap(
                 per_scenario_rel, in_axes=tuple(axes_rel)
             )
-            return finalize(vmapped_rel, tuple(axes_rel), (1, 6))
+            return None, finalize(vmapped_rel, tuple(axes_rel), (1, 6))
         # vmap matches in_axes against the args actually passed,
         # so the defaulted dyn arg needs no wrapper.
         axes_src = [0, 0, None, None, None]
@@ -1858,7 +1887,7 @@ class WhatIfEngine:
         vmapped_src = jax.vmap(
             per_scenario_src, in_axes=tuple(axes_src)
         )
-        return finalize(vmapped_src, tuple(axes_src), (1,))
+        return None, finalize(vmapped_src, tuple(axes_src), (1,))
 
     def _release_core(self):
         """Shared device release-update core (cached): subtract a K-list
@@ -2788,7 +2817,8 @@ class WhatIfEngine:
         scenarios a device, bytes put on the devices and fetched from them
         (and the host seconds of those calls, nested in ``stage`` and
         ``handback``), and the cross-device instructions in the compiled
-        programs: 0 in ``chunk`` and ``handback``, the scenario axis is
+        programs: 0 in ``chunk`` (and in ``retry``, the pass program of a
+        batch with a ``retry_buffer``) and ``handback``, the scenario axis is
         embarrassingly parallel; ``gather``, which brings the placements
         to one device for the fetch (``_handback``), is the batch's one
         all-gather. The programs are read once per engine, at
@@ -4189,10 +4219,10 @@ class WhatIfEngine:
                 # it donates its buffers).
                 registered: set = set()
 
-                def _reg(fn, args):
+                def _reg(fn, args, kind="chunk"):
                     if self._mesh_programs is not None:
                         self._mesh_programs.setdefault(
-                            "chunk", (fn, _shape_structs(args))
+                            kind, (fn, _shape_structs(args))
                         )
                     if span.armed and fn not in registered:
                         registered.add(fn)
@@ -4377,21 +4407,29 @@ class WhatIfEngine:
                 # one branch), with the chunk's marker inside it.
                 with span("dispatch"), span.mark(f"chunk:{ci}"):
                     if dev_rel and self.retry_buffer:
+                        # Two programs, the second dispatched on the
+                        # first's arrays: nothing comes to the host between.
                         args = (
                             dc, states, srcs[0], srcs[1], mgt_d, antit_d,
-                            preft_d, prefwt_d, durt_d, priot_d, tbt_d,
-                            idx_chunks[ci], tb_c[ci], b_c[ci],
-                            vassign_d, rq_d,
+                            preft_d, prefwt_d, tbt_d, tb_c[ci], b_c[ci], rq_d,
                         )
                         if evicting:
                             args += (ev_d, stg["resd"])
-                            _reg(self._chunk_fn, args)
-                            states, vassign_d, rq_d, ev_d, out = self._chunk_fn(
-                                *args
-                            )
+                        _reg(self._retry_fn, args, "retry")
+                        got = self._retry_fn(*args)
+                        if evicting:
+                            states, rq_d, ev_d, retry_placed = got
                         else:
-                            _reg(self._chunk_fn, args)
-                            states, vassign_d, rq_d, out = self._chunk_fn(*args)
+                            states, rq_d, retry_placed = got
+                        args = (
+                            dc, states, srcs[0], srcs[1], durt_d, priot_d,
+                            idx_chunks[ci], b_c[ci], vassign_d, rq_d,
+                        )
+                        if evicting:
+                            args += (ev_d.down,)
+                        _reg(self._chunk_fn, args)
+                        states, vassign_d, rq_d, counts = self._chunk_fn(*args)
+                        out = (counts, retry_placed)
                     elif dev_rel:
                         args = (
                             dc, states, srcs[0], srcs[1], idx_chunks[ci],

@@ -22,6 +22,10 @@
   scope sits in the executable's HLO text; the engines hand the programs of
   an armed replay over by XLA module name, and ``stage_tables()`` joins the
   two after the run.
+- ``loop_memory_spaces(hlo_text, shapes)``: which memory space the compiler
+  gave the arrays a ``while`` of a compiled program carries (``S(1)`` in a
+  layout: the TPU's on-chip memory; none: HBM). A loop whose planes sit in
+  HBM reads them at HBM speed in every iteration.
 """
 
 from __future__ import annotations
@@ -59,6 +63,10 @@ PASSES = ("ksim.retry",)
 _STAGE_PATH = re.compile(r"ksim\.\w+(?:/[A-Z]\w*)*")
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+_HLO_WHILE = re.compile(r"=\s*\((.*)\)\s+while\(")
+_HLO_ARRAY = re.compile(r"(\w+\[[\d,]*\])\{([^}]*)\}")
+_HLO_SPACE = re.compile(r"S\((\d+)\)")
 
 # XLA module name -> thunk lowering the jitted program on the shapes of the
 # call an armed replay made.
@@ -248,6 +256,32 @@ def parse_stage_table(hlo_text: str) -> Dict[str, str]:
         outer = next((p for p in paths[:-1] if p in PASSES), None)
         table[m.group(1)] = f"{outer}/{path}" if outer else path
     return table
+
+
+def loop_memory_spaces(hlo_text: str, shapes) -> Dict[str, Dict[str, int]]:
+    """{``op_name`` of a ``while``: {shape: memory space}} for every
+    ``while`` of one compiled program's text whose carried tuple holds an
+    array of EACH of ``shapes`` (``"f32[128,3,10000]"``: dtype and
+    dimensions as the text prints them). The space is the ``S(n)`` of the
+    element's layout, 0 where it has none: on a TPU 1 is the on-chip memory
+    and 0 HBM; the CPU backend's text names no space. Of a shape the tuple
+    holds twice, the lesser."""
+    found: Dict[str, Dict[str, int]] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_WHILE.search(line)
+        if not m:
+            continue
+        spaces: Dict[str, int] = {}
+        for shape, layout in _HLO_ARRAY.findall(m.group(1)):
+            if shape in shapes:
+                s = _HLO_SPACE.search(layout)
+                spaces[shape] = min(
+                    spaces.get(shape, 1 << 30), int(s.group(1)) if s else 0
+                )
+        if len(spaces) == len(set(shapes)):
+            op = _HLO_OP_NAME.search(line)
+            found[op.group(1) if op else line.split("=")[0].strip()] = spaces
+    return found
 
 
 def stage_tables() -> Dict[str, Dict[str, str]]:

@@ -220,7 +220,8 @@ def _lowered(program):
         assert eng.engine == "v3"
         assert eng.release_path == (None if program == "whatif-arrivals"
                                     else "device")
-        name = "_chunk_fn"
+        # a boundary with a queue dispatches the pass first (PR 47)
+        name = "_retry_fn" if program == "whatif-retry" else "_chunk_fn"
     real, box = getattr(eng, name), {}
 
     class Captured(Exception):
@@ -246,7 +247,9 @@ def test_steps_with_shared_slots_keep_the_parents_program(program):
 
 
 def test_the_retry_program_loses_the_pass_gathers_and_nothing_else(monkeypatch):
-    """The retry program holds the wave step twice. Under ``vmap`` every
+    """The pass program (``jit_per_scenario_retry``; since PR 47 the arrival
+    scan is a program of its own) holds the step that walks each scenario's
+    own queue. Under ``vmap`` every
     dynamic index of a class plane is written as a gather (one whose index
     every scenario shares folds to a dynamic slice in the compiler; one by
     the queue's per-scenario class id does not). As shipped the COMPILED

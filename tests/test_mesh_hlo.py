@@ -204,9 +204,10 @@ def test_mesh_retry_chunk_program_has_no_collectives():
     """A batch with a ``retry_buffer`` under a mesh: every retry pass ends
     with the fullest scenario's last queued wave, a ``pmax`` over the vmapped
     scenarios of ONE device's slice (``shard_map`` outside, ``vmap`` inside),
-    so each device's passes end on their own and the chunk program
-    ``jit_per_scenario_retry`` holds no cross-device instruction; the
-    engine's own count, read from the compiled programs, agrees."""
+    so each device's passes end on their own and NEITHER of the boundary's
+    two programs, the pass ``jit_per_scenario_retry`` and the arrival scan
+    ``jit_per_scenario_arrivals`` (PR 47), holds a cross-device instruction;
+    the engine's own count, read from the compiled programs, agrees."""
     cluster = make_cluster(3, seed=11)
     pods, _ = make_workload(140, seed=11, arrival_rate=60.0, duration_mean=1.5,
                             with_spread=True, with_tolerations=True)
@@ -216,23 +217,27 @@ def test_mesh_retry_chunk_program_has_no_collectives():
                        wave_width=4, chunk_waves=4, retry_buffer=16,
                        collect_assignments=True, telemetry="summary")
     assert eng.release_path == "device"
-    real, calls = eng._chunk_fn, []
+    calls = {}
+    for attr in ("_retry_fn", "_chunk_fn"):
+        real = getattr(eng, attr)
 
-    def chunk_fn(*args):
-        if not calls:  # taken before the call: it donates its buffers
-            calls.append(real.lower(*args))
-        return real(*args)
+        def program(*args, _attr=attr, _real=real):
+            if _attr not in calls:  # taken before the call: it donates its buffers
+                calls[_attr] = _real.lower(*args)
+            return _real(*args)
 
-    chunk_fn.lower = real.lower  # the engine's own count lowers it again
-    eng._chunk_fn = chunk_fn
+        program.lower = real.lower  # the engine's own count lowers it again
+        setattr(eng, attr, program)
     got = eng.run().fleet_telemetry.summary()
     assert got["mesh"]["devices"] == 8 and got["mesh"]["scenarios_per_device"] == 2
     # (the retry hand-back is one program over the whole batch, PR 43: it is
     # not among the programs the engine counts)
-    assert got["mesh"]["collectives"] == {"chunk": 0}
+    assert got["mesh"]["collectives"] == {"retry": 0, "chunk": 0}
     # the passes ran, and not to the end of the buffer
     retry = got["retry"]
     assert 0 < retry["pass_waves"]["max"] < retry["passes"] * 16 // 4
     assert retry["retry_placed"]["max"] > 0
-    assert "jit_per_scenario_retry" in calls[0].as_text()
-    _assert_no_collectives(calls[0].compile().as_text())
+    assert "jit_per_scenario_retry" in calls["_retry_fn"].as_text()
+    assert "jit_per_scenario_arrivals" in calls["_chunk_fn"].as_text()
+    for lowered in calls.values():
+        _assert_no_collectives(lowered.compile().as_text())

@@ -724,3 +724,92 @@ def test_retry_pass_selects_its_class_rows_and_hands_back_what_the_gather_does(
     np.testing.assert_array_equal(res.bind_boundary, by_gather.bind_boundary)
     np.testing.assert_array_equal(res.placed, by_gather.placed)
     np.testing.assert_array_equal(res.retry_dropped, by_gather.retry_dropped)
+
+
+def _sha(a):
+    import hashlib
+
+    return hashlib.sha256(np.ascontiguousarray(a).tobytes()).hexdigest()[:16]
+
+
+def test_a_boundary_with_a_retry_buffer_is_two_programs(tmp_path, monkeypatch):
+    """The retry pass and the arrival scan are a device program each (PR 47:
+    held in one, their two state-carrying loops push the step's node planes
+    out of the chip's on-chip memory): every boundary dispatches
+    ``jit_per_scenario_retry`` and then ``jit_per_scenario_arrivals``, both
+    under names the benchmark's chunk readers find, and an armed run files
+    the instructions of both: the pass and its record under ``ksim.retry``
+    in the first, the wave step's stages and the queue's upkeep in the
+    second."""
+    import re
+
+    from kubernetes_simulator_tpu.utils import profiling
+
+    ec, ep = _contended(priorities=(0, 100, 200))
+    eng = WhatIfEngine(
+        ec, ep, _perturbed(ec.num_nodes), FrameworkConfig(), wave_width=4,
+        chunk_waves=4, retry_buffer=16, collect_assignments=True,
+    )
+    order, lowered = [], {}
+    for attr in ("_retry_fn", "_chunk_fn"):
+        real = getattr(eng, attr)
+
+        def spy(*args, _attr=attr, _real=real):
+            order.append(_attr)
+            if _attr not in lowered:  # before the call: it donates its buffers
+                lowered[_attr] = _real.lower(*args).as_text()
+            return _real(*args)
+
+        spy.__name__, spy.lower = real.__name__, real.lower
+        setattr(eng, attr, spy)
+    profiling._PROGRAMS.clear()
+    monkeypatch.setenv("KSIM_PROFILE_DIR", str(tmp_path))
+    res = eng.run()
+    monkeypatch.delenv("KSIM_PROFILE_DIR")
+    boundaries = res.fleet_telemetry.summary()["retry"]["passes"]
+    assert boundaries == 9 and order == ["_retry_fn", "_chunk_fn"] * boundaries
+    # what the benchmark's readers look for (layer_metrics/chunk_ms_per_wave.py)
+    chunk_program = re.compile(r"^jit_(per_scenario\w*|chunk_fn\w*)\(")
+    modules = {attr: re.search(r"module @(\w+)", text).group(1)
+               for attr, text in lowered.items()}
+    assert modules == {"_retry_fn": "jit_per_scenario_retry",
+                       "_chunk_fn": "jit_per_scenario_arrivals"}
+    assert all(chunk_program.match(name + "(7)") for name in modules.values())
+    tables = profiling.stage_tables()
+    profiling._PROGRAMS.clear()
+    assert set(modules.values()) <= set(tables)
+    first = set(tables["jit_per_scenario_retry"].values())
+    second = set(tables["jit_per_scenario_arrivals"].values())
+    # the pass: releases of re-tried binds, then the step under the pass
+    assert {"ksim.release", "ksim.retry", "ksim.retry/ksim.select",
+            "ksim.retry/ksim.commit"} <= first
+    assert not any(p.startswith("ksim.") and not p.startswith(
+        ("ksim.retry", "ksim.release", "ksim.derive")) for p in first)
+    # the arrival scan's step under no pass, the upkeep, the fold
+    assert {"ksim.select", "ksim.commit", "ksim.filter_score/NodeResourcesFit",
+            "ksim.retry", "ksim.release"} <= second
+    assert not any(p.startswith("ksim.retry/") for p in second)
+
+
+def test_the_two_programs_answer_what_the_one_program_answered():
+    """Value-exact: on the contended trace with one deep scenario among four,
+    both hand-back arrays and ``summary()["retry"]`` are what the tree before
+    PR 47 (d4a9348: pass, arrival scan and upkeep in one program) gave."""
+    ec, ep = _contended(priorities=(0, 100, 200))
+    _, res, _ = _device_and_anchor(
+        ec, ep, W=4, C=4, RB=16, scenarios=_perturbed(ec.num_nodes))
+    assert _sha(res.assignments) == "0af030a33c914dbf"
+    assert _sha(res.bind_boundary) == "b82416b669c0ea00"
+    assert res.fleet_telemetry.summary()["retry"] == {
+        "buffer": 16, "passes": 9,
+        "depth_at_end": {"max": 16, "mean": 7.0},
+        "depth_max": {"max": 16, "mean": 6.25},
+        "handback_merged": {"max": 35, "mean": 11.5},
+        "pass_waves": {"max": 22, "mean": 22.0},
+        "release_leaked": {"max": 0, "mean": 0.0},
+        "retry_dropped": {"max": 41, "mean": 10.25},
+        "retry_placed": {"max": 35, "mean": 11.5},
+        "scenario0": {"depth_at_end": 12, "depth_max": 8, "handback_merged": 10,
+                      "pass_waves": 22, "release_leaked": 0, "retry_dropped": 0,
+                      "retry_placed": 10},
+    }

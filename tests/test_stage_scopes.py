@@ -98,6 +98,29 @@ def test_parse_stage_table_takes_the_innermost_scope():
         profiling.stage("ksim.pick")
 
 
+def test_loop_memory_spaces_reads_the_whiles_that_carry_every_shape():
+    """Lines as the TPU compiler prints them: a layout's ``S(1)`` is the
+    on-chip memory, none is HBM; a loop that carries only some of the shapes
+    is no answer, and of a shape carried twice the lesser space counts."""
+    text = """
+  %while.98 = (s32[]{:T(128)}, f32[128,3,10000]{0,2,1:T(8,128)}, f32[10000,8]{0,1:T(8,128)}) while(%tuple.1), condition=%c.1, body=%b.1, metadata={op_name="jit(f)/vmap(ksim.release)/while" stack_frame_id=8}
+  %while.100 = (s32[]{:T(128)}, f32[128,3,10000]{0,2,1:T(8,128)}, f32[128,10000,3]{0,1,2:T(8,128)S(1)}, bf16[128,2,10000]{0,2,1:T(8,128)(2,1)S(1)}, f32[10000]{0:T(1024)S(1)}) while(%tuple.2), condition=%c.2, body=%b.2, metadata={op_name="jit(f)/vmap(ksim.retry)/while" stack_frame_id=140}
+  ROOT %while.99 = (f32[128,3,10000]{0,2,1:T(8,128)S(1)}, f32[128,10000,3]{0,1,2:T(8,128)S(1)}, f32[128,10000,3]{0,1,2:T(8,128)S(1)}, bf16[128,2,10000]{0,2,1:T(8,128)(2,1)S(1)}) while(%tuple.3), condition=%c.3, body=%b.3, metadata={op_name="jit(f)/vmap()/while"}
+  %while.7 = (f32[128,3,10000]{0,2,1:T(8,128)S(1)}, f32[128,10000,3]{0,1,2:T(8,128)}, f32[128,10000,3]{0,1,2:T(8,128)S(1)}, bf16[128,2,10000]{0,2,1}) while(%tuple.4), condition=%c.4, body=%b.4
+  %fusion.1 = f32[128,3,10000]{0,2,1:T(8,128)S(1)} fusion(%while.99), kind=kLoop
+"""
+    used, alloc, mask = "f32[128,3,10000]", "f32[128,10000,3]", "bf16[128,2,10000]"
+    assert profiling.loop_memory_spaces(text, [used, alloc, mask]) == {
+        "jit(f)/vmap(ksim.retry)/while": {used: 0, alloc: 1, mask: 1},
+        "jit(f)/vmap()/while": {used: 1, alloc: 1, mask: 1},
+        "%while.7": {used: 1, alloc: 0, mask: 0},
+    }
+    assert set(profiling.loop_memory_spaces(text, [used])) == {
+        "jit(f)/vmap(ksim.release)/while", "jit(f)/vmap(ksim.retry)/while",
+        "jit(f)/vmap()/while", "%while.7"}
+    assert profiling.loop_memory_spaces(text, ["f32[7]"]) == {}
+
+
 @pytest.mark.parametrize("completions", [False, True],
                          ids=["plain", "completions"])
 def test_phases_cover_the_replay_call(borg, completions):

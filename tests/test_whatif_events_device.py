@@ -203,6 +203,7 @@ def test_a_batch_made_again_and_a_batch_swapped_compile_nothing():
     ec, ep, plans, eng, res, _ = batch(512)
     sizes = dict(eng._evict_sizes)
     chunk, evict = eng._chunk_fn._cache_size(), eng._evict_fn()._cache_size()
+    retry = eng._retry_fn._cache_size()
     again = eng.run()
     np.testing.assert_array_equal(again.assignments, res.assignments)
     np.testing.assert_array_equal(again.bind_boundary, res.bind_boundary)
@@ -216,6 +217,7 @@ def test_a_batch_made_again_and_a_batch_swapped_compile_nothing():
     np.testing.assert_array_equal(back.eviction_log, res.eviction_log)
     assert eng._evict_sizes == sizes
     assert eng._chunk_fn._cache_size() == chunk
+    assert eng._retry_fn._cache_size() == retry
     assert eng._evict_fn()._cache_size() == evict
 
 
@@ -263,3 +265,53 @@ def test_the_eviction_program_carries_its_stage_scope(tmp_path, monkeypatch):
     tables = profiling.stage_tables()
     assert "ksim.evict" in set(tables["jit_whatif_evict"].values())
     assert "ksim.evict" in profiling.STAGES
+    # the boundary's two chunk programs under timelines (PR 47): the pass,
+    # which counts the evicted it binds again, and the arrival scan
+    assert "ksim.retry/ksim.select" in set(tables["jit_per_scenario_retry"].values())
+    assert {"ksim.select", "ksim.retry"} <= set(
+        tables["jit_per_scenario_arrivals"].values())
+
+
+def test_the_two_programs_answer_what_the_one_program_answered():
+    """Value-exact under timelines: the three hand-back arrays of the six
+    plans at buffer 64 and ``summary()["retry"]``, the events' counters in
+    it, are what the tree before PR 47 (d4a9348: one chunk program a
+    boundary) gave."""
+    import hashlib
+
+    res = batch(64)[4]
+    sha = lambda a: hashlib.sha256(
+        np.ascontiguousarray(a).tobytes()).hexdigest()[:16]
+    assert sha(res.assignments) == "94ff73674528097f"
+    assert sha(res.bind_boundary) == "4982ac2248a6953e"
+    assert sha(res.eviction_log) == "38f209657d60365c"
+    got = res.fleet_telemetry.summary()["retry"]
+    assert got.pop("scenario0") == {
+        "depth_at_end": 0, "depth_max": 64, "evict_dropped": 0,
+        "evict_gang_stranded": 0, "evict_rebound_later": 0,
+        "evict_rebound_same_boundary": 0, "evict_retried": 0,
+        "evict_stranded": 0, "evict_wait_boundaries_max": 0,
+        "evict_wait_boundaries_mean": 0.0, "evictions": 0,
+        "handback_merged": 194, "pass_waves": 94, "release_leaked": 0,
+        "retry_dropped": 3, "retry_placed": 194}
+    assert got.pop("buffer") == 64 and got.pop("passes") == 13
+    assert {k: v["max"] for k, v in got.items()} == {
+        "depth_at_end": 41, "depth_max": 64, "evict_dropped": 999,
+        "evict_gang_stranded": 8, "evict_rebound_later": 22,
+        "evict_rebound_same_boundary": 110, "evict_retried": 40,
+        "evict_stranded": 1002, "evict_wait_boundaries_max": 10,
+        "evict_wait_boundaries_mean": 0.8035714285714286, "evictions": 1130,
+        "handback_merged": 435, "pass_waves": 94, "release_leaked": 0,
+        "retry_dropped": 1052, "retry_placed": 435}
+    assert {k: v["mean"] for k, v in got.items()} == pytest.approx({
+        "depth_at_end": 9.0, "depth_max": 64.0,
+        "evict_dropped": 269.1666666666667, "evict_gang_stranded": 2.0,
+        "evict_rebound_later": 10.5,
+        "evict_rebound_same_boundary": 51.666666666666664,
+        "evict_retried": 6.833333333333333,
+        "evict_stranded": 271.3333333333333,
+        "evict_wait_boundaries_max": 3.8333333333333335,
+        "evict_wait_boundaries_mean": 0.30117527521761395,
+        "evictions": 333.5, "handback_merged": 251.66666666666666,
+        "pass_waves": 94.0, "release_leaked": 0.0,
+        "retry_dropped": 358.3333333333333, "retry_placed": 258.5})
