@@ -137,6 +137,48 @@ def cmd_run(args) -> int:
     return 0
 
 
+def _drain_scenarios(cfg, ec, ep, scen) -> int:
+    """``drain:`` (utils.config.DrainSpec): give every scenario s > 0 its
+    walk of ``node_cordon`` events, merged into what ``chaos:`` gave it, and
+    its ``DisruptionBudget``. Returns the cordons written."""
+    import numpy as np
+
+    from .sim.jax_runtime import wave_start_times
+    from .sim.runtime import DisruptionBudget, NodeEvent
+    from .sim.waves import pack_waves
+
+    dr = cfg.drain
+    if ep.app_id is None:
+        raise ValueError(
+            "drain: needs a workload that names each task's application "
+            "(workload.borg); this one has none"
+        )
+    app = np.asarray(ep.app_id, np.int32)
+    most = np.maximum(
+        1, np.floor(dr.max_unavailable_share * np.bincount(app))
+    ).astype(np.int32)
+    starts = wave_start_times(ep, pack_waves(ep, cfg.wave_width).idx)[
+        0 :: cfg.chunk_waves
+    ]
+    N, n_ev = ec.num_nodes, 0
+    for s in range(1, len(scen)):
+        place = int(np.random.default_rng(dr.seed + s).integers(0, N))
+        walk = np.roll(np.arange(N), -place)
+        cordons = [
+            NodeEvent(float(starts[b]), "node_cordon", int(n))
+            for b in range(dr.first, len(starts))
+            for n in walk[(b - dr.first) * dr.step : (b - dr.first + 1) * dr.step]
+        ]
+        n_ev += len(cordons)
+        scen[s].events = sorted(
+            list(scen[s].events) + cordons, key=lambda e: e.time
+        )
+        scen[s].budget = DisruptionBudget(
+            app, most, grace=dr.grace, out_for=dr.out_for
+        )
+    return n_ev
+
+
 def cmd_whatif(args) -> int:
     from .parallel.mesh import make_mesh
     from .sim.whatif import WhatIfEngine, uniform_scenarios
@@ -167,6 +209,12 @@ def cmd_whatif(args) -> int:
         log.info(
             "chaos: %d timed events across %d scenario timelines",
             n_ev, len(scen) - 1,
+        )
+    if cfg.drain is not None and cfg.drain.enabled:
+        log.info(
+            "drain: %d node_cordon events across %d scenario timelines, "
+            "under disruption budgets",
+            _drain_scenarios(cfg, ec, ep, scen), len(scen) - 1,
         )
     mesh = make_mesh() if cfg.whatif.mesh else None
     eng = WhatIfEngine(
@@ -834,6 +882,33 @@ def validate_config(cfg) -> list:
                 "completions (whatIf.mesh: false, whatIf.completions not "
                 "false); with devicePreemption: kube the timelines apply "
                 "through the per-scenario host mirrors"
+            )
+    dr = cfg.drain
+    if dr is not None and dr.enabled:
+        if dr.step <= 0 or dr.first < 0:
+            errors.append("drain.step: must be > 0 and drain.first >= 0")
+        if dr.grace < 0 or dr.out_for < 1:
+            errors.append("drain.grace: must be >= 0 and drain.outFor >= 1")
+        if not 0.0 < dr.max_unavailable_share <= 1.0:
+            errors.append("drain.maxUnavailableShare: must be in (0, 1]")
+        if cfg.whatif.scenarios <= 0:
+            errors.append("drain: is a what-if section (whatIf.scenarios > 0)")
+        if (
+            not cfg.whatif.retry_buffer
+            or cfg.device_preemption == "kube"
+            or cfg.whatif.mesh
+            or cfg.whatif.completions is False
+        ):
+            errors.append(
+                "drain: node_cordon events and disruption budgets run on the "
+                "device retry path: whatIf.retryBuffer > 0, no "
+                "devicePreemption: kube, whatIf.mesh: false, "
+                "whatIf.completions not false"
+            )
+        if cfg.borg is None:
+            errors.append(
+                "drain: needs a workload that names each task's application "
+                "(workload.borg)"
             )
     tu = cfg.tune
     if tu is not None:

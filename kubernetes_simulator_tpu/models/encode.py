@@ -206,6 +206,10 @@ class EncodedPods:
     group_id: np.ndarray  # [P] i32 (PAD = not in a pod group)
     pg_min_member: np.ndarray  # [NG] i32
     pg_names: List[str]
+    # The trace's application of each task, where the workload names one
+    # (the Borg ingest does): what a disruption budget selects by
+    # (``sim.runtime.DisruptionBudget.app_of``). No plugin reads it.
+    app_id: Optional[np.ndarray] = None  # [P] i32
 
 
 class Encoder:

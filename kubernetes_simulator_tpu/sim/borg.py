@@ -268,6 +268,7 @@ def encoded_from_cols(spec: BorgSpec, cols: dict) -> Tuple[EncodedCluster, Encod
         group_id=group_id,
         pg_min_member=pg_min,
         pg_names=[f"alloc-set-{j}" for j in range(len(gang_sizes))] or ["none"],
+        app_id=app.astype(np.int32),
     )
     meta = {
         "num_gangs": len(gang_sizes),

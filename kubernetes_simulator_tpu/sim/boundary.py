@@ -61,6 +61,20 @@ PairArrays = Tuple[np.ndarray, np.ndarray]
 
 _NEVER = 1 << 30  # bind_chunk sentinel: never statically released
 
+# What a budgeted drain's log says of an eviction (``BoundaryOps.evict_kind``,
+# the fifth column of ``WhatIfResult.eviction_log``), and what it counts
+# (``summary()["retry"]`` of a what-if batch; ``BoundaryOps.budget_counts``).
+EVICT_KINDS = {"voluntary": 0, "deadline": 1, "failure": 2}
+BUDGET_COUNTERS = (
+    "evict_voluntary",        # evictions a budget allowed
+    "evict_forced_deadline",  # ... made at a drain's deadline, past the budgets
+    "evict_forced_failure",   # ... made by a node_down, past the budgets
+    "evict_deferred",         # candidate turns refused (asked again next boundary)
+    "budget_spent_max",       # the most tasks down at once after a boundary's evictions
+    "nodes_drained",          # cordoned nodes that went out holding nothing
+    "nodes_forced",           # ... that lost a task at their deadline
+)
+
 
 def _empty_pairs() -> PairArrays:
     return np.zeros(0, np.int64), np.zeros(0, np.int64)
@@ -214,6 +228,7 @@ class BoundaryOps:
         # a pod's history is whole; the what-if device path hands back the
         # same rows (``WhatIfResult.eviction_log``).
         self.evict_log: List[Tuple[int, int, int, int]] = []
+        self.budget = None  # ``set_budget``
         self._evicted_gang = np.zeros(P, bool)
         # Boundary start times: f64 for the static release schedule, f32
         # finite prefix for the retry pend schedule (matching the device's
@@ -497,8 +512,16 @@ class BoundaryOps:
         Returns the (pods, nodes) pair for the device carry delta; the
         caller must have the mirror current through chunk ``b-1``
         (``fold_chunk``/``_fold_pending``) before calling."""
+        return self._evict(np.nonzero(self.st.bound == node)[0], node, b, t_chunk)
+
+    def _evict(self, victims, node: int, b: int, t_chunk: float,
+               kind: Optional[int] = None) -> PairArrays:
+        """``evict_node``'s body over ``victims``, pods bound on ``node``
+        (ascending). ``kind`` (budgeted drains only): what the log says of
+        the rows beside them, ``EVICT_KINDS``; each victim with an
+        application is counted down in ``unavail``."""
         ec, ep, st = self.ec, self.ep, self.st
-        victims = np.nonzero(st.bound == node)[0]
+        victims = np.asarray(victims, np.int64)
         if not victims.size:
             return _empty_pairs()
         # unbind reads/writes the live count planes — logged deltas must
@@ -517,6 +540,10 @@ class BoundaryOps:
             self.evict_log.append(
                 (int(b), v, int(node), int(self.bind_boundary[v]))
             )
+            if kind is not None:
+                self.evict_kind.append(int(kind))
+                if self.budget.app_of[v] >= 0:
+                    self.unavail[self.budget.app_of[v]] += 1
             # Same bookkeeping as a preemption victim: a displaced pod's
             # pending release no longer frees anything, and a later
             # re-placement starts at THAT boundary — the arrival-based
@@ -535,9 +562,141 @@ class BoundaryOps:
                     self._dropped[v] = True
             elif ep.group_id[v] != PAD:
                 self._evicted_gang[v] = True
-        return victims.astype(np.int64), np.full(
-            victims.size, int(node), np.int64
-        )
+        return victims, np.full(victims.size, int(node), np.int64)
+
+    # -- a drain under disruption budgets ------------------------------------
+
+    def set_budget(self, budget) -> None:
+        """Arm the budgeted drain (``sim.runtime.DisruptionBudget``): from
+        here on the caller hands every boundary's due events to
+        ``budget_events`` instead of calling ``evict_node``. A node is in
+        service, cordoned (``cordon_at >= 0``: the boundary of its cordon;
+        ``walk_place``: its place among the cordoned, the order of the
+        ``node_cordon`` events) or out (``until >= 0``: the boundary it is
+        back at, ``_NEVER`` for a failed node, which waits for its
+        ``node_up``); ``closed`` is what takes no bind."""
+        N = self.ec.num_nodes
+        self.budget = budget
+        self.cordon_at = np.full(N, -1, np.int64)
+        self.walk_place = np.zeros(N, np.int64)
+        self._walked = 0
+        self.until = np.full(N, -1, np.int64)
+        # the fourth answer: the boundary a cordoned node went out (empty,
+        # at its deadline or by a failure), -1 never
+        self.node_out_at = np.full(N, -1, np.int32)
+        self.unavail = np.zeros(len(budget.max_unavailable), np.int64)
+        self.evict_kind: List[int] = []  # beside evict_log: EVICT_KINDS
+        self.budget_counts = dict.fromkeys(BUDGET_COUNTERS, 0)
+
+    @property
+    def closed(self) -> np.ndarray:
+        """[N] bool: the nodes that take no bind now, cordoned or out."""
+        return (self.cordon_at >= 0) | (self.until >= 0)
+
+    def cordon_node(self, node: int, b: int) -> None:
+        """``node_cordon`` at boundary ``b``: of a node that is out or
+        cordoned already, nothing (its maintenance counts as done, or is
+        under way)."""
+        if self.until[node] < 0 and self.cordon_at[node] < 0:
+            self.cordon_at[node] = b
+            self.walk_place[node] = self._walked
+            self._walked += 1
+
+    def budget_events(self, b: int, t_chunk: float, due) -> List[PairArrays]:
+        """The events of boundary ``b`` under the budget, BEFORE its
+        releases and its retry pass (``due``: the timeline's events that
+        fall here, in its order), as the what-if eviction program makes
+        them:
+
+        1. back: a node whose outage ends at ``b`` (``until == b``, or the
+           last ``node_down`` / ``node_up`` due for it is a ``node_up``) is
+           in service, empty, no longer cordoned;
+        2. forced: every ``node_down`` due (timeline order), then every
+           cordoned node at its deadline (walk order), loses every live
+           bind, whatever the budgets say; the evictions count against
+           them. A failed node waits for its ``node_up`` (one due here
+           already: it stays in service); a node at its deadline is out for
+           ``out_for`` boundaries;
+        3. cordon: the ``node_cordon`` s due, except of a node that failed
+           here;
+        4. voluntary: the live binds on the cordoned nodes, in walk order
+           and a node's by id, each evicted iff its application has fewer
+           than ``max_unavailable`` down at its turn;
+        5. a cordoned node that holds nothing now goes out for ``out_for``.
+
+        Returns the (pods, nodes) pairs evicted, for the device delta."""
+        bud, cnt = self.budget, self.budget_counts
+        downs: List[int] = []
+        cordons: List[int] = []
+        last: Dict[int, str] = {}
+        for ev in due:
+            n = int(ev.node)
+            if ev.kind == "node_down" and n not in downs:
+                downs.append(n)
+            if ev.kind == "node_cordon" and n not in cordons:
+                cordons.append(n)
+            if ev.kind in ("node_down", "node_up"):
+                last[n] = ev.kind
+        ups = [n for n, k in last.items() if k == "node_up"]
+        pairs: List[PairArrays] = []
+        on = lambda n: np.nonzero(self.st.bound == n)[0]
+        # 1.
+        back = np.concatenate([np.nonzero(self.until == b)[0],
+                               np.asarray(ups, np.int64)]).astype(np.int64)
+        self.until[back] = -1
+        self.cordon_at[back] = -1
+        # 2.
+        for n in downs:
+            got = self._evict(on(n), n, b, t_chunk, kind=EVICT_KINDS["failure"])
+            pairs.append(got)
+            cnt["evict_forced_failure"] += len(got[0])
+            if self.cordon_at[n] >= 0:
+                self.node_out_at[n] = b
+                self.cordon_at[n] = -1
+            if n not in ups:
+                self.until[n] = _NEVER
+        cord = self._cordoned()
+        for n in cord[(self.cordon_at[cord] < b)
+                      & (self.cordon_at[cord] + bud.grace <= b)].tolist():
+            got = self._evict(on(n), n, b, t_chunk, kind=EVICT_KINDS["deadline"])
+            pairs.append(got)
+            cnt["evict_forced_deadline"] += len(got[0])
+            cnt["nodes_forced" if len(got[0]) else "nodes_drained"] += 1
+            self._goes_out(n, b)
+        # 3.
+        for n in cordons:
+            if n not in downs:
+                self.cordon_node(n, b)
+        # 4. and 5.
+        for n in self._cordoned().tolist():
+            asked = on(n)
+            take = np.zeros(len(asked), bool)
+            spent = self.unavail.copy()
+            for i, v in enumerate(asked.tolist()):
+                a = int(bud.app_of[v])
+                take[i] = a < 0 or spent[a] < bud.max_unavailable[a]
+                if take[i] and a >= 0:
+                    spent[a] += 1
+            pairs.append(self._evict(asked[take], n, b, t_chunk,
+                                     kind=EVICT_KINDS["voluntary"]))
+            cnt["evict_voluntary"] += int(take.sum())
+            cnt["evict_deferred"] += int((~take).sum())
+            if take.all():
+                cnt["nodes_drained"] += 1
+                self._goes_out(n, b)
+        cnt["budget_spent_max"] = max(cnt["budget_spent_max"],
+                                      int(self.unavail.sum()))
+        return [p for p in pairs if p[0].size]
+
+    def _cordoned(self) -> np.ndarray:
+        """The cordoned nodes, in walk order."""
+        cord = np.nonzero(self.cordon_at >= 0)[0]
+        return cord[np.argsort(self.walk_place[cord], kind="stable")]
+
+    def _goes_out(self, node: int, b: int) -> None:
+        self.cordon_at[node] = -1
+        self.until[node] = b + int(self.budget.out_for)
+        self.node_out_at[node] = b
 
     # -- the boundary -------------------------------------------------------
 
@@ -703,6 +862,10 @@ class BoundaryOps:
                     # start time contributes 0 — the re-bind still counts).
                     t_ev = self._evict_time.pop(p)
                     self.evict_rescheduled += 1
+                    if self.budget is not None and self.budget.app_of[p] >= 0:
+                        # its application has one task fewer down: the next
+                        # boundary's evictions see it
+                        self.unavail[self.budget.app_of[p]] -= 1
                     if np.isfinite(t_chunk):
                         self._evict_lat_sum += float(t_chunk) - t_ev
                 # Release schedule: f32 boundary search, >= b+1 — the pod

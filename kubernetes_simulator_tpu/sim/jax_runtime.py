@@ -966,7 +966,7 @@ class JaxReplayEngine:
         self, _tick, node_events=None, chunk_req: Optional[int] = None,
         retry_req: Optional[int] = None,
         checkpoint_path: Optional[str] = None, checkpoint_every: int = 0,
-        resume: bool = False,
+        resume: bool = False, budget=None,
     ) -> ReplayResult:
         """Replay with the host boundary pass active (``retry_buffer`` > 0
         and/or ``preemption='kube'``; :mod:`.boundary`).
@@ -1030,6 +1030,8 @@ class JaxReplayEngine:
                 telemetry=tel,
             )
             self._last_bops = bops  # probe for the quiet-path tests/bench
+            if budget is not None:
+                bops.set_budget(budget)
             state = self._init_dev_state()
             pending_events = sorted(node_events or [], key=lambda e: e.time)
             ev_hash = events_hash(pending_events)
@@ -1147,6 +1149,7 @@ class JaxReplayEngine:
                         pending_events
                         and pending_events[0].time <= wave_times[c0]
                     )
+                    and budget is None
                 ):
                     # Double-buffer (round 10): run boundary ci's RELEASE
                     # passes before blocking on chunk ci-1's failure
@@ -1177,7 +1180,27 @@ class JaxReplayEngine:
                     _fold_pending()
                 chaos_p: List[np.ndarray] = []
                 chaos_n: List[np.ndarray] = []
-                if pending_events:
+                if budget is not None:
+                    # A drain under budgets: what leaves, and when a node
+                    # goes out and is back, follows from the mirror's own
+                    # state at every boundary, not from the events alone.
+                    chunk_t = wave_times[c0]
+                    due = [e for e in pending_events if e.time <= chunk_t]
+                    if due or bops.closed.any():
+                        _fold_pending()
+                        for cp, cn in bops.budget_events(ci, float(chunk_t), due):
+                            chaos_p.append(cp)
+                            chaos_n.append(cn)
+                        alloc = np.where(
+                            bops.closed[:, None], 0.0, saved_alloc
+                        ).astype(saved_alloc.dtype)
+                        self.dc = self.dc._replace(allocatable=jnp.asarray(alloc))
+                        self.ec.allocatable[:] = np.where(
+                            bops.closed[:, None], 0.0, saved_alloc_ec
+                        )
+                    pending_events = pending_events[len(due):]
+                    ev_applied += len(due)
+                elif pending_events:
                     chunk_t = wave_times[c0]
                     due = [e for e in pending_events if e.time <= chunk_t]
                     if due:
@@ -1402,7 +1425,7 @@ class JaxReplayEngine:
         mirror exists to requeue victims through."""
         alloc = np.asarray(self.dc.allocatable).copy()
         for ev in events:
-            if ev.kind == "node_down":
+            if ev.kind in ("node_down", "node_cordon"):
                 alloc[ev.node] = 0.0
             elif ev.kind == "node_up":
                 alloc[ev.node] = saved_alloc[ev.node]
@@ -1416,6 +1439,7 @@ class JaxReplayEngine:
         checkpoint_every: int = 0,
         resume: bool = False,
         node_events=None,
+        budget=None,
     ) -> ReplayResult:
         """Run the replay; optionally snapshot the carry every K chunks to
         ``checkpoint_path`` and/or resume from it (SURVEY.md §5).
@@ -1426,7 +1450,12 @@ class JaxReplayEngine:
         smaller chunks for finer timing). With ``retry_buffer``/``kube``
         active, ``node_down`` additionally evicts bound pods (NoExecute)
         through the boundary mirror; without a retry buffer only future
-        placements are affected.
+        placements are affected. ``budget`` (a
+        ``sim.runtime.DisruptionBudget``; needs the retry buffer) makes the
+        timeline a maintenance drain: a ``node_cordon`` closes a node, its
+        tasks leave as the budgets allow and the node goes out when it is
+        empty or at its deadline (``BoundaryOps.budget_events``, the host
+        twin of the what-if eviction program).
 
         With profiling armed the whole call lies under one root span
         ``replay:<n>``, ``n`` this engine's call ordinal: the phases are
@@ -1442,6 +1471,27 @@ class JaxReplayEngine:
             from .checkpoint import ReplayCheckpoint
 
             validate_node_events(node_events, self.ec.num_nodes)
+            if budget is not None:
+                if not self.retry_buffer or self.kube:
+                    raise ValueError(
+                        "a disruption budget requires retry_buffer > 0 and "
+                        "no kube preemption (the evicted re-enter the queue)"
+                    )
+                if checkpoint_path or resume or any(
+                    e.kind == "capacity_scale" for e in node_events or []
+                ):
+                    raise ValueError(
+                        "a disruption budget runs without checkpoints and "
+                        "without capacity_scale events"
+                    )
+            elif any(e.kind == "node_cordon" for e in node_events or []) and (
+                self.retry_buffer or self.kube
+            ):
+                raise ValueError(
+                    "node_cordon on the boundary path is the start of a "
+                    "budgeted drain: pass budget= (sim.runtime."
+                    "DisruptionBudget)"
+                )
             if self.preemption and (checkpoint_path or resume):
                 raise ValueError(
                     "checkpoint/resume is not supported with device preemption "
@@ -1472,6 +1522,7 @@ class JaxReplayEngine:
                     _tick, node_events=node_events, chunk_req=chunk_req,
                     retry_req=retry_req, checkpoint_path=checkpoint_path,
                     checkpoint_every=checkpoint_every, resume=resume,
+                    budget=budget,
                 )
             if (
                 node_events

@@ -49,12 +49,62 @@ class NodeEvent:
     """Cluster perturbation at a virtual timestamp (failure injection)."""
 
     time: float
-    kind: str  # "node_down" | "node_up" | "capacity_scale"
+    kind: str  # one of _EVENT_KINDS
     node: int
     scale: float = 1.0
 
 
-_EVENT_KINDS = ("node_down", "node_up", "capacity_scale")
+# ``node_cordon``: the node takes no new bind from the event on and keeps
+# what runs there (``kubectl cordon``). Under a ``DisruptionBudget`` it is
+# the start of the node's drain; without one it only closes the node until
+# a ``node_up``.
+_EVENT_KINDS = ("node_down", "node_up", "capacity_scale", "node_cordon")
+
+
+@dataclass
+class DisruptionBudget:
+    """What a maintenance drain may do to the applications of a trace: the
+    PodDisruptionBudget rule at chunk-boundary granularity (Kubernetes docs,
+    Disruptions / Safely Drain a Node; Borg's "tasks from a job that can be
+    simultaneously down", Verma et al. 2015 §4). Data of a scenario
+    (``sim.whatif.Scenario.budget``) or of one replay
+    (``JaxReplayEngine.replay(budget=...)``); the rule itself is
+    ``BoundaryOps.budget_events`` on the host and
+    ``WhatIfEngine._evict_fn`` on the device.
+
+    ``app_of`` ``[P]``: each task's application (a budget's selector,
+    resolved; -1: under no budget, never refused). ``max_unavailable``
+    ``[A]``: how many tasks of an application may be down at once by
+    evictions, voluntary or forced, and not re-bound since (a dropped or
+    stranded evicted task stays counted: its replacement is Pending). A
+    cordoned node's tasks are evicted in walk order (the order of the
+    ``node_cordon`` events, a node's tasks by id) while their application's
+    count is below its limit; the rest are asked again at the next boundary.
+    A cordoned node that holds nothing goes out and is back ``out_for``
+    boundaries later; one that still holds tasks ``grace`` boundaries after
+    its cordon (at the next boundary where ``grace`` is 0) goes out there
+    and loses them whatever the budgets say. A ``node_down`` evicts past the
+    budgets too and counts against them."""
+
+    app_of: np.ndarray
+    max_unavailable: np.ndarray
+    grace: int = 0
+    out_for: int = 1
+
+    def __post_init__(self):
+        self.app_of = np.asarray(self.app_of, np.int32)
+        self.max_unavailable = np.asarray(self.max_unavailable, np.int32)
+        if self.app_of.ndim != 1 or self.max_unavailable.ndim != 1:
+            raise ValueError("budget: app_of [P] and max_unavailable [A]")
+        if self.app_of.size and int(self.app_of.max()) >= len(self.max_unavailable):
+            raise ValueError(
+                f"budget: app_of names application {int(self.app_of.max())} "
+                f"of {len(self.max_unavailable)}"
+            )
+        if (self.max_unavailable < 0).any():
+            raise ValueError("budget: max_unavailable must be >= 0")
+        if int(self.grace) < 0 or int(self.out_for) < 1:
+            raise ValueError("budget: grace >= 0 and out_for >= 1 boundaries")
 
 
 def validate_node_events(
@@ -64,7 +114,10 @@ def validate_node_events(
     what-if timelines): a malformed timeline raises an actionable
     ``ValueError`` instead of silently misbehaving mid-replay. Checks:
     known kind, node index in range, finite non-negative non-decreasing
-    times, ``node_up`` only after a ``node_down`` on the same node, and a
+    times, ``node_up`` only after a ``node_down`` on the same node (it
+    clears a ``node_cordon`` of the node too; a ``node_down`` may follow a
+    ``node_cordon``: a cordoned node fails like any other; a second
+    ``node_cordon`` is no error: a drained node comes back by itself), and a
     non-negative ``capacity_scale`` factor. Returns the (unmodified)
     list for chaining."""
     events = events or []
@@ -385,6 +438,10 @@ class CpuReplayEngine:
                             ec.allocatable[ev.node] = saved_alloc[ev.node]
                             if want_timeline:
                                 tel.event("node_up", now, -1, int(ev.node))
+                        elif ev.kind == "node_cordon":
+                            # No new bind (every request fails the fit
+                            # filter); what runs there keeps running.
+                            ec.allocatable[ev.node] = 0.0
                         elif ev.kind == "capacity_scale":
                             ec.allocatable[ev.node] = saved_alloc[ev.node] * ev.scale
                         progressed_cluster = True

@@ -48,6 +48,7 @@ from ..utils.profiling import make_span as _make_span
 from ..utils.profiling import register_call as _register_call
 from ..utils.profiling import shape_structs as _shape_structs
 from ..utils.profiling import stage
+from .boundary import BUDGET_COUNTERS, EVICT_KINDS
 from .jax_runtime import StepSpec
 from .waves import pack_waves, refuse_wide_gangs, widest_gang
 
@@ -85,8 +86,21 @@ class Scenario:
     # mesh, several processes and capacity_scale are refused there); with
     # ``preemption="kube"`` through the per-scenario host mirrors
     # (``sim.boundary``), capacity_scale too. Static t=0 perturbations
-    # above evict nothing and work everywhere.
+    # above evict nothing and work everywhere. A fourth kind, node_cordon
+    # (device path only), closes a node to new binds and starts its drain
+    # under ``budget``.
     events: List = field(default_factory=list)
+    # A ``sim.runtime.DisruptionBudget``: the scenario's timeline is a
+    # maintenance drain. A cordoned node's tasks leave only as fast as each
+    # application's ``max_unavailable`` allows, the node goes out when the
+    # device finds it empty (back ``out_for`` boundaries later) or, with
+    # what it still holds, ``grace`` boundaries after its cordon; a
+    # node_down evicts past the budgets and counts against them; a re-bind
+    # of an evicted task gives its application's allowance back. Data of
+    # the scenario; a batch's budgets share ``app_of`` (the trace's). A
+    # scenario without one in a batch that has some is never refused
+    # anything (every limit infinite, grace 0).
+    budget: Optional[object] = None
 
 
 class ScenarioSet:
@@ -513,21 +527,41 @@ _EV_COUNTERS = (
     "wait_max",       # ... the greatest
 )
 _EV = {k: i for i, k in enumerate(_EV_COUNTERS)}
+_BN = {k: i for i, k in enumerate(BUDGET_COUNTERS)}
+# What an entry of a budgeted boundary's node list is (``_stage_budget_events``).
+_LK = {"pad": 0, "failure": 1, "new": 2, "cordoned": 3, "deadline": 4}
 
 
 class EvictState(NamedTuple):
     """What the device eviction path carries from one boundary to the next,
     per scenario (a leading ``[S]`` on every leaf): ``down [N]`` the nodes
-    that are out now (their allocatable reads 0 in the chunk call), ``log
-    [4, cap]`` every eviction in the order made (boundary, task, the node
-    it held, the boundary whose pass had bound it or -1), ``n`` the
-    counters ``_EV_COUNTERS`` names, ``wait_s`` the virtual seconds from
-    eviction to re-bind, summed."""
+    that TAKE NO BIND now, out or (under budgets) cordoned: their
+    allocatable reads 0 in the two chunk programs, which read this one
+    mask. ``log [4, cap]`` every eviction in the order made (boundary,
+    task, the node it held, the boundary whose pass had bound it or -1),
+    ``n`` the counters ``_EV_COUNTERS`` names, ``wait_s`` the virtual
+    seconds from eviction to re-bind, summed.
+
+    Only where the scenarios carry budgets (None otherwise, so a batch
+    without them keeps its programs): the log has a fifth row, the
+    eviction's kind (``sim.boundary.EVICT_KINDS``); ``until [N]`` which of
+    the ``down`` are OUT and the boundary they are back at (-1: in service
+    or cordoned; ``1 << 30``: failed, back by a ``node_up``); ``out_at
+    [N]`` the boundary a cordoned node went out, -1 never
+    (``WhatIfResult.node_out_at``); ``unavail [A]`` the tasks of each
+    application evicted and not re-bound since (the retry pass gives back
+    what it re-binds); ``bn`` the counters ``BUDGET_COUNTERS`` names. The
+    planes stay with the eviction program: the retry program is handed the
+    state without them."""
 
     down: jax.Array
     log: jax.Array
     n: jax.Array
     wait_s: jax.Array
+    until: Optional[jax.Array] = None
+    out_at: Optional[jax.Array] = None
+    unavail: Optional[jax.Array] = None
+    bn: Optional[jax.Array] = None
 
 
 @dataclass
@@ -552,8 +586,13 @@ class WhatIfResult:
     # i32, every eviction of a scenario in the order made, padded with -1
     # to the longest: (boundary, task, the node it held, the boundary whose
     # retry pass had bound it; -1 its arrival wave or a resident).
-    # ``evictions[s]`` rows are filled.
+    # ``evictions[s]`` rows are filled. Under disruption budgets a row has
+    # a fifth number, the eviction's kind (``sim.boundary.EVICT_KINDS``: 0
+    # voluntary, 1 forced at a drain's deadline, 2 forced by a node_down).
     eviction_log: Optional[np.ndarray] = None
+    # Under disruption budgets: [S, N] i32, the boundary a cordoned node
+    # went out (empty, at its deadline, or by a failure), -1 never.
+    node_out_at: Optional[np.ndarray] = None
     utilization_cpu: Optional[np.ndarray] = None  # [S]
     # Which semantics this batch actually ran under (round 4: two batches
     # evaluated under different semantics must be programmatically
@@ -888,6 +927,11 @@ class WhatIfEngine:
         self._timelines = [
             list(getattr(sc, "events", None) or []) for sc in scenarios
         ]
+        self._budgets = [getattr(sc, "budget", None) for sc in scenarios]
+        # the applications the programs are compiled for: a batch swapped in
+        # without budgets (nothing is refused there) keeps them
+        self._budget_proto = next(
+            (b for b in self._budgets if b is not None), None)
         self.ec = ec
         self.pods = pods
         self._config = config
@@ -1169,11 +1213,15 @@ class WhatIfEngine:
                     "without label-perturbation DynTables (meshes are "
                     "supported since round 10)"
                 )
-        self._check_timelines(self._timelines)
+        self._check_timelines(self._timelines, self._budgets)
         # Timelines on the device path: the eviction program, its carry and
         # the queue's ``ev_at`` exist only in such a batch (fixed here: an
         # engine built without them cannot be handed them later).
         self._events_dev = bool(any(self._timelines) and not self.kube)
+        # ... under disruption budgets: the program's admission, its node
+        # planes and counters (fixed here as well).
+        self._budget_on = self._events_dev and any(
+            b is not None for b in self._budgets)
         self._evict_stage: Optional[dict] = None
         self._evict_sizes: Optional[dict] = None
         self._evict_scale = 1
@@ -1257,14 +1305,17 @@ class WhatIfEngine:
             srcs = replicate_tree(self.mesh, srcs)
         self._slot_srcs = srcs
 
-    def _check_timelines(self, timelines) -> None:
-        """Validate a batch's per-scenario timelines (``Scenario.events``),
-        or say why this engine cannot run them. They run where a task can
-        be evicted and offered again: through the per-scenario host mirrors
-        (``preemption="kube"``, every event kind), or on the device retry
-        path (``retry_buffer > 0``: ``node_down`` / ``node_up``, one
-        process, no mesh)."""
-        if not any(timelines):
+    def _check_timelines(self, timelines, budgets=()) -> None:
+        """Validate a batch's per-scenario timelines (``Scenario.events``)
+        and budgets (``Scenario.budget``), or say why this engine cannot
+        run them. They run where a task can be evicted and offered again:
+        through the per-scenario host mirrors (``preemption="kube"``:
+        ``node_down`` / ``node_up`` / ``capacity_scale``), or on the device
+        retry path (``retry_buffer > 0``: ``node_down`` / ``node_up`` /
+        ``node_cordon`` and budgets, one process, no mesh)."""
+        budgeted = any(b is not None for b in budgets)
+        cordons = any(ev.kind == "node_cordon" for tl in timelines for ev in tl)
+        if not any(timelines) and not budgeted:
             return
         reasons = []
         if not self.kube:
@@ -1284,9 +1335,18 @@ class WhatIfEngine:
                     "no capacity_scale event without preemption='kube' "
                     "(the device path moves nodes out and back, whole)"
                 )
+        elif cordons or budgeted:
+            reasons.append(
+                "no preemption='kube' with a node_cordon event or a "
+                "disruption budget (the budgeted drain is the device "
+                "path's; JaxReplayEngine.replay(budget=...) is its host "
+                "twin, one replay at a time)"
+            )
         if reasons:
             raise ValueError(
-                "per-scenario timed event timelines (Scenario.events) "
+                "per-scenario timed event timelines (Scenario.events: "
+                "node_down, node_up, node_cordon, capacity_scale) and "
+                "disruption budgets (Scenario.budget) "
                 "require " + "; ".join(reasons)
             )
         from .runtime import validate_node_events
@@ -1296,6 +1356,23 @@ class WhatIfEngine:
                 validate_node_events(tl, self.ec.num_nodes)
             except ValueError as e:
                 raise ValueError(f"scenario {si}: {e}") from None
+        if cordons and not budgeted:
+            raise ValueError(
+                "a node_cordon event starts a drain under Scenario.budget "
+                "(sim.runtime.DisruptionBudget): the batch carries none"
+            )
+        given = [b for b in budgets if b is not None]
+        first = self._budget_proto or (given[0] if given else None)
+        for b in given:
+            if len(b.app_of) != self.pods.num_pods or len(
+                b.max_unavailable
+            ) != len(first.max_unavailable) or not np.array_equal(
+                b.app_of, first.app_of
+            ):
+                raise ValueError(
+                    "the budgets of a batch share app_of [P] (the trace's "
+                    "applications) and the number of applications"
+                )
 
     @property
     def _wide_gangs(self) -> bool:
@@ -1393,12 +1470,19 @@ class WhatIfEngine:
         timelines = [
             list(getattr(sc, "events", None) or []) for sc in scenarios
         ]
-        self._check_timelines(timelines)
+        budgets = [getattr(sc, "budget", None) for sc in scenarios]
+        self._check_timelines(timelines, budgets)
         if any(timelines) and not self.kube and not self._events_dev:
             raise ValueError(
                 "scenario batch carries timed event timelines but the "
                 "engine was built without any (the eviction program and "
                 "its carry are compiled in) — rebuild the engine"
+            )
+        if any(b is not None for b in budgets) and not self._budget_on:
+            raise ValueError(
+                "scenario batch carries disruption budgets but the engine "
+                "was built without any (the admission and its node planes "
+                "are compiled in) — rebuild the engine"
             )
         sset = ScenarioSet(self.ec, scenarios, keep_host_stacks=self.kube)
         if sset.labels_dirty:
@@ -1446,6 +1530,7 @@ class WhatIfEngine:
         self.sset = sset
         self._dc_mesh = None
         self._timelines = timelines
+        self._budgets = budgets
         self._evict_stage = None
 
     def _build_chunk_fns(self):
@@ -1577,6 +1662,7 @@ class WhatIfEngine:
                 rel_core = self._release_core()
                 want_an, want_pf = rel_core.want_an, rel_core.want_pf
                 ev_on = self._events_dev
+                bud_on = self._budget_on
 
                 def derive(dc, down):
                     """What both retry programs build of a scenario's
@@ -1591,7 +1677,7 @@ class WhatIfEngine:
 
                 def per_scenario_retry(
                     dc, state, src, xsrc, mgt, antit, preft, prefwt, tbt,
-                    t_b, b, rq, ev=None, resd=None,
+                    t_b, b, rq, ev=None, resd=None, app_t=None,
                 ):
                     """The FIRST of the two programs a boundary of a batch
                     with a ``retry_buffer`` dispatches (semantics:
@@ -1616,7 +1702,11 @@ class WhatIfEngine:
                     timelines (``ev``, the scenario's ``EvictState``; the
                     eviction program ran before the static releases): a
                     node that is out reads allocatable 0 here, and the
-                    pass counts the evicted tasks it binds again."""
+                    pass counts the evicted tasks it binds again. Under
+                    disruption budgets (``app_t``, each task's
+                    application) such a re-bind gives its application's
+                    allowance back: ``ev.unavail`` loses one there, and the
+                    next boundary's eviction program reads it."""
                     dc, d, cmasks = derive(dc, ev.down if ev_on else None)
                     # The pass walks the scenario's own queue: its
                     # slots differ by scenario, the arrival scan's do
@@ -1752,6 +1842,12 @@ class WhatIfEngine:
                                     back, t_b - tbt[jnp.clip(rq.ev_at, 0)], 0.0
                                 ).sum(),
                             )
+                            if bud_on:
+                                app_r = jnp.where(back, app_t[safe], -1)
+                                ev = ev._replace(unavail=ev.unavail - (
+                                    app_r[:, None] == jnp.arange(
+                                        ev.unavail.shape[0], dtype=jnp.int32)
+                                ).sum(0, dtype=jnp.int32))
                     if ev_on:
                         return state, rq, ev, retry_placed
                     return state, rq, retry_placed
@@ -1845,7 +1941,7 @@ class WhatIfEngine:
 
                 axes_retry = (0, 0) + (None,) * 9 + (0,) + (
                     (0, None) if ev_on else ()
-                )
+                ) + ((None,) if bud_on else ())
                 axes_arr = (0, 0) + (None,) * 6 + (0, 0) + (
                     (0,) if ev_on else ()
                 )
@@ -2774,7 +2870,8 @@ class WhatIfEngine:
         }
 
     def _handback_log(self, ev: EvictState, evictions) -> np.ndarray:
-        """``WhatIfResult.eviction_log`` ``[S, E, 4]``: the log's filled
+        """``WhatIfResult.eviction_log`` ``[S, E, 4]`` (``5`` under
+        disruption budgets: the kind last): the log's filled
         columns (to the longest scenario's, rounded up to 1,024 so that a
         batch made again compiles nothing), turned on the device and
         fetched once, cut on the host to the longest; -1 where a scenario
@@ -2892,6 +2989,8 @@ class WhatIfEngine:
         Static per scenario batch: staged once and kept."""
         if self._evict_stage is not None:
             return self._evict_stage
+        if self._budget_on:
+            return self._stage_budget_events()
         tb = self._dev_rel_stage["tb_host"]
         S, nb = self.S, len(tb)
         leave = [[[] for _ in range(nb)] for _ in range(S)]
@@ -2942,15 +3041,142 @@ class WhatIfEngine:
         }
         return self._evict_stage
 
+    def _stage_budget_events(self) -> dict:
+        """``_stage_events`` for a batch under disruption budgets. Per
+        boundary and scenario ONE list of nodes ``[S, L]`` with each
+        entry's ``kind`` beside it (``_LK``): the ``node_down`` s due
+        (timeline order), then the nodes that may be DRAINING there, those
+        cordoned at ``cb`` with ``cb <= b <= cb + max(grace, 1)``, in walk
+        order (the order of their ``node_cordon`` events; one that fails at
+        ``b`` stands among the failures only): cordoned here, cordoned
+        earlier, or at their deadline. Whether such a node is still
+        cordoned, when it went out and when it is back is the device's to
+        know (``EvictState.until``), so there is no list of returns but the
+        ``node_up`` s' (``back`` ``[S, Lb]``); a boundary is dispatched
+        while any scenario may still have a node cordoned or out by its
+        drain. A boundary's call carries the budgets as arrays too (``max_u
+        [S, A]``, ``out_for [S]``); with them ``app_t [P]`` and the sizes: ``E`` candidates a
+        boundary can bring to the front, ``Ea`` evictions it can make (the
+        release core's list: forced ones and what the budgets admit), the
+        log's room; a run that finds one too small doubles
+        ``_evict_scale`` and is made again."""
+        tb = self._dev_rel_stage["tb_host"]
+        S, nb = self.S, len(tb)
+        P, N = self.pods.num_pods, self.ec.num_nodes
+        proto = self._budget_proto
+        A = len(proto.max_unavailable)
+        FREE = 1 << 30
+        max_u = np.full((S, A), FREE, np.int32)
+        grace = np.zeros(S, np.int64)
+        out_for = np.ones(S, np.int32)
+        for s, bud in enumerate(self._budgets):
+            if bud is not None:
+                max_u[s] = np.minimum(bud.max_unavailable, FREE)
+                grace[s], out_for[s] = int(bud.grace), int(bud.out_for)
+        fails = [[[] for _ in range(nb)] for _ in range(S)]
+        back = [[[] for _ in range(nb)] for _ in range(S)]
+        drain = [[[] for _ in range(nb)] for _ in range(S)]
+        live = np.zeros(nb + 1, bool)  # a boundary some scenario needs
+        for s, tl in enumerate(self._timelines):
+            at = np.searchsorted(tb, [float(e.time) for e in tl], side="left")
+            last: Dict[tuple, str] = {}
+            window: Dict[int, int] = {}
+            reach = max(int(grace[s]), 1)
+            for e, bb in zip(tl, at.tolist()):
+                if bb >= nb:
+                    break  # past the last boundary: never applied
+                node = int(e.node)
+                live[bb] = True
+                if e.kind == "node_down" and node not in fails[s][bb]:
+                    fails[s][bb].append(node)
+                if e.kind in ("node_down", "node_up"):
+                    last[(bb, node)] = e.kind
+                if e.kind == "node_cordon":
+                    if window.get(node, -1) >= bb:
+                        raise ValueError(
+                            f"scenario {s}: node {node} is cordoned again "
+                            f"at boundary {bb}, inside the drain its last "
+                            f"node_cordon began"
+                        )
+                    window[node] = bb + reach
+                    for b in range(bb, min(bb + reach, nb - 1) + 1):
+                        kind = _LK["new"] if b == bb else (
+                            _LK["deadline"] if b >= bb + grace[s]
+                            else _LK["cordoned"])
+                        drain[s][b].append((node, kind))
+                    live[bb:min(bb + reach + int(out_for[s]), nb - 1) + 1] = True
+            for (bb, node), kind in last.items():
+                if kind == "node_up":
+                    back[s][bb].append(node)
+        lists = [[
+            [(n, _LK["failure"]) for n in fails[s][b]]
+            + [x for x in drain[s][b] if x[0] not in fails[s][b]]
+            for b in range(nb)] for s in range(S)]
+        kept = self._evict_sizes or {"L": 0, "Lb": 0, "E": 0, "Ea": 0, "cap": 0}
+        widest = max((len(x) for row in lists for x in row), default=0)
+        L = max(kept["L"], -(-max(widest, 1) // 128) * 128)
+        Lb = max(kept["Lb"], 1 << max(3, (max(
+            (len(x) for row in back for x in row), default=1) - 1).bit_length()))
+        per_node = -(-P // N)
+        scale = self._evict_scale
+        pow2 = lambda x: 1 << (max(int(x), 1) - 1).bit_length()
+        fresh = lambda row: sum(k in (_LK["failure"], _LK["new"]) for _, k in row)
+        # a node cordoned earlier still holds what its budgets refused: half
+        # a node's tasks is the reckoning, the redo rule the guard
+        most = max((fresh(x) + (len(x) - fresh(x)) / 2
+                    for row in lists for x in row), default=0)
+        E = max(kept["E"], 128, pow2(most * per_node * scale))
+        budget_room = np.minimum(max_u.astype(np.int64).sum(axis=1), FREE)
+        most_a = max((
+            sum(k == _LK["failure"] for _, k in x) * per_node
+            + sum(k == _LK["deadline"] for _, k in x) * per_node / 4
+            + min(int(budget_room[s]),
+                  sum(k != _LK["failure"] for _, k in x) * per_node)
+            for s, row in enumerate(lists) for x in row), default=0)
+        Ea = min(E, max(kept["Ea"], 128, pow2(most_a * scale)))
+        total = max((len({n for x in row for n, _ in x}) for row in lists),
+                    default=0)
+        cap = total * per_node * scale + 2 * Ea
+        cap = max(kept["cap"], -(-cap // 128) * 128)
+        self._evict_sizes = {"L": L, "Lb": Lb, "E": E, "Ea": Ea, "cap": cap}
+        pad = lambda rows, width, fill: jnp.asarray(
+            [r + [fill] * (width - len(r)) for r in rows], jnp.int32
+        )
+        budgets = (jnp.asarray(max_u), jnp.asarray(out_for))
+        calls = [
+            (pad([[n for n, _ in lists[s][b]] for s in range(S)], L, -1),
+             pad([[k for _, k in lists[s][b]] for s in range(S)], L, _LK["pad"]),
+             pad([back[s][b] for s in range(S)], Lb, -1)) + budgets
+            if live[b] else None for b in range(nb)
+        ]
+        self._evict_stage = {
+            "calls": calls, "L": L, "Lb": Lb, "E": E, "Ea": Ea, "cap": cap,
+            "A": A, "app_t": jnp.asarray(proto.app_of),
+        }
+        return self._evict_stage
+
     def _evict_state(self) -> EvictState:
         """A batch's ``EvictState`` at its start: no node out, an empty log."""
-        S, N, cap = self.S, self.ec.num_nodes, self._stage_events()["cap"]
-        return self._jit_once("evict_state", lambda: jax.jit(lambda: EvictState(
-            down=jnp.zeros((S, N), bool),
-            log=jnp.full((S, 4, cap), -1, jnp.int32),
-            n=jnp.zeros((S, len(_EV_COUNTERS)), jnp.int32),
-            wait_s=jnp.zeros((S,), jnp.float32),
-        )))()
+        evs = self._stage_events()
+        S, N, cap = self.S, self.ec.num_nodes, evs["cap"]
+        bud = self._budget_on
+
+        def empty():
+            planes = {
+                "until": jnp.full((S, N), -1, jnp.int32),
+                "out_at": jnp.full((S, N), -1, jnp.int32),
+                "unavail": jnp.zeros((S, evs["A"]), jnp.int32),
+                "bn": jnp.zeros((S, len(BUDGET_COUNTERS)), jnp.int32),
+            } if bud else {}
+            return EvictState(
+                down=jnp.zeros((S, N), bool),
+                log=jnp.full((S, 5 if bud else 4, cap), -1, jnp.int32),
+                n=jnp.zeros((S, len(_EV_COUNTERS)), jnp.int32),
+                wait_s=jnp.zeros((S,), jnp.float32),
+                **planes,
+            )
+
+        return self._jit_once("evict_state", lambda: jax.jit(empty))()
 
     def _evict_fn(self):
         """The eviction program of a boundary (``jit_whatif_evict``; device
@@ -2992,30 +3218,25 @@ class WhatIfEngine:
           log behind its cursor.
         * a node that left holds nothing: its ``used`` reads 0.0, exactly
           (the rewind's float residue goes with it), and ``down`` takes the
-          nodes that left and gives back the ones that returned."""
+          nodes that left and gives back the ones that returned.
+
+        Under disruption budgets the program is ``_evict_budget_fn``'s."""
+        if self._budget_on:
+            return self._evict_budget_fn()
+
         def build():
             stg, evs = self._dev_rel_stage, self._stage_events()
             L, E, cap = evs["L"], evs["E"], evs["cap"]
             RB, N = self.retry_buffer, self.ec.num_nodes
-            BIG, NONE = 1 << 30, jnp.iinfo(jnp.int32).max
-            rel_core = self._release_core()
-            want_an, want_pf = rel_core.want_an, rel_core.want_pf
+            BIG = 1 << 30
             relb_pos, task_pos, gang_pos, resd = (
                 stg["relb_pos"], stg["task_pos"], stg["gang_pos"], stg["resd"]
             )
-            mgt, antit, preft, prefwt = (
-                stg["mgt"], stg["antit"], stg["preft"], stg["prefwt"]
-            )
-            durt, priot = stg["durt"], stg["priot"]
-            req_t = self._slot_srcs[0].requests
-            gang_t = self._slot_srcs[0].group_id >= 0
             V = int(relb_pos.shape[0])
             ar_L = jnp.arange(L, dtype=jnp.int32)
             ar_N = jnp.arange(N, dtype=jnp.int32)
             slot = jnp.arange(E, dtype=jnp.int32)
-            # deal a node's run of victims over the blocks of the list
-            deal = lambda a: a.reshape((128, E // 128) + a.shape[1:]).swapaxes(
-                0, 1).reshape(a.shape)
+            rewind, join = self._evict_tail(E)
 
             def evict_one(state, vassign, rq, ev, leave, back, b):
                 def on_leaving(x):
@@ -3056,29 +3277,9 @@ class WhatIfEngine:
                 vassign = jnp.where(
                     hv, jnp.where(gang_pos, -2, PAD), vassign
                 ).astype(vassign.dtype)
-                none_i = jnp.full((E, 1), PAD, jnp.int32)
-                state, _, _ = rel_core(
-                    state, deal(node), deal(req_t[task]), deal(mgt[task]),
-                    deal(antit[task]) if want_an else none_i,
-                    deal(preft[task]) if want_pf else none_i,
-                    deal(prefwt[task]) if want_pf
-                    else jnp.zeros((E, 1), jnp.float32),
-                    axis_name=_EVICT_VMAP,
-                )
-                gang = gang_t[task]
-                asks = ok & ~gang
-                room = RB - rq.count
-                take = asks & (jnp.cumsum(asks.astype(jnp.int32)) <= room)
-                nasks = asks.sum(dtype=jnp.int32)
-                cat_ids = jnp.concatenate([rq.ids, jnp.where(take, task, -1)])
-                cat_prio = jnp.concatenate([rq.prio, priot[task]])
-                key = jnp.where(cat_ids >= 0, -cat_prio, NONE)
-                _, cat_ids, cat_prio, cat_dur, cat_ev = jax.lax.sort(
-                    (key, cat_ids, cat_prio,
-                     jnp.concatenate([rq.dur, durt[task]]),
-                     jnp.concatenate([rq.ev_at, jnp.full((E,), b, jnp.int32)])),
-                    num_keys=1, is_stable=True,
-                )
+                state = rewind(state, node, task)
+                gang, nasks, room, (cat_ids, cat_prio, cat_dur, cat_ev) = join(
+                    rq, ok, task, b)
                 rows = jnp.stack([
                     jnp.where(ok, b, -1), jnp.where(ok, task, -1), node, bound_at,
                 ])
@@ -3121,6 +3322,305 @@ class WhatIfEngine:
 
             fn_v = jax.vmap(
                 evict_one, in_axes=(0, 0, 0, 0, 0, 0, None),
+                axis_name=_EVICT_VMAP,
+            )
+
+            def whatif_evict(*args):
+                with stage("ksim.evict"):
+                    return fn_v(*args)
+
+            return jax.jit(whatif_evict, donate_argnums=(0, 1, 2, 3))
+
+        return self._jit_once("evict", build)
+
+    def _evict_tail(self, width: int):
+        """What both eviction programs do with the ``width`` tasks that
+        leave: ``rewind(state, node, task)`` takes their usage and counts
+        back through the release core (a node's tasks dealt over the blocks
+        of the list, so that its rank rounds stay few), and ``join(rq, go,
+        task, b)`` queues the non-gang ones behind what is there, as far as
+        there is room, one stable sort by priority: ``(gang, nasks, room,
+        the sorted ids / prio / dur / ev_at, to be cut to the buffer)``."""
+        stg = self._dev_rel_stage
+        RB = self.retry_buffer
+        NONE = jnp.iinfo(jnp.int32).max
+        rel_core = self._release_core()
+        want_an, want_pf = rel_core.want_an, rel_core.want_pf
+        mgt, antit, preft, prefwt = (
+            stg["mgt"], stg["antit"], stg["preft"], stg["prefwt"]
+        )
+        durt, priot = stg["durt"], stg["priot"]
+        req_t = self._slot_srcs[0].requests
+        gang_t = self._slot_srcs[0].group_id >= 0
+        deal = lambda a: a.reshape((128, width // 128) + a.shape[1:]).swapaxes(
+            0, 1).reshape(a.shape)
+
+        def rewind(state, node, task):
+            none_i = jnp.full((width, 1), PAD, jnp.int32)
+            state, _, _ = rel_core(
+                state, deal(node), deal(req_t[task]), deal(mgt[task]),
+                deal(antit[task]) if want_an else none_i,
+                deal(preft[task]) if want_pf else none_i,
+                deal(prefwt[task]) if want_pf
+                else jnp.zeros((width, 1), jnp.float32),
+                axis_name=_EVICT_VMAP,
+            )
+            return state
+
+        def join(rq, go, task, b):
+            gang = gang_t[task]
+            asks = go & ~gang
+            room = RB - rq.count
+            take = asks & (jnp.cumsum(asks.astype(jnp.int32)) <= room)
+            nasks = asks.sum(dtype=jnp.int32)
+            cat_ids = jnp.concatenate([rq.ids, jnp.where(take, task, -1)])
+            cat_prio = jnp.concatenate([rq.prio, priot[task]])
+            key = jnp.where(cat_ids >= 0, -cat_prio, NONE)
+            _, *queue = jax.lax.sort(
+                (key, cat_ids, cat_prio,
+                 jnp.concatenate([rq.dur, durt[task]]),
+                 jnp.concatenate([rq.ev_at, jnp.full((width,), b, jnp.int32)])),
+                num_keys=1, is_stable=True,
+            )
+            return gang, nasks, room, queue
+
+        return rewind, join
+
+    def _evict_budget_fn(self):
+        """``_evict_fn`` for a batch under disruption budgets (the same
+        program name, ``jit_whatif_evict``): ``(state, vassign, rq, ev,
+        nodes, kind, back, max_u, out_for, b) -> (state, vassign, rq, ev)``
+        with ``nodes`` / ``kind`` ``[S, L]`` the boundary's list
+        (``_stage_budget_events``) and ``back`` ``[S, Lb]`` its
+        ``node_up`` s. Per scenario, ``BoundaryOps.budget_events``' rule:
+
+        * what each entry of the list IS now follows from the planes: a
+          node is back where ``until == b`` or a ``node_up`` names it; an
+          entry is FORCED where it fails or stands cordoned at its
+          deadline, and ASKS where it is cordoned here (unless it is out)
+          or was cordoned earlier and is not out.
+        * the candidates, every live bind on a forced or an asking entry,
+          are found by the compare and brought to the front by rank as
+          ``_evict_fn``'s victims are (the block of a slot found in two
+          steps of 128, the list being longer), ``E`` of them, and sorted:
+          the forced first (list order), then the asking (walk order), a
+          node's tasks by id.
+        * the ADMISSION, under ``ksim.evict/Budget``: a forced candidate
+          leaves; an asking one iff its rank among its application's
+          asking candidates is below ``max_u - unavail`` (the forced of
+          this boundary counted first): one running count over ``[E, A]``.
+          The admitted are brought to the front (a stable sort on one
+          bit) and cut to ``Ea``: the rewind, the queue's merge and the log
+          take that list, no longer than a boundary can evict.
+        * only the admitted binds go: their places in ``vassign`` and in
+          the record are written by index (the one scatter: ``Ea`` unique
+          places).
+        * an asking entry of which every candidate left holds nothing: it
+          goes out here, back at ``b + out_for``, as an entry at its
+          deadline does; a failed node waits for its ``node_up``. ``down``
+          (no bind) keeps the cordoned and the out, ``used`` reads 0.0 on
+          a node that went out, ``unavail`` takes what left."""
+        def build():
+            stg, evs = self._dev_rel_stage, self._stage_events()
+            L, E, Ea, cap, A = (evs[k] for k in ("L", "E", "Ea", "cap", "A"))
+            app_t = evs["app_t"]
+            RB, N = self.retry_buffer, self.ec.num_nodes
+            BIG = 1 << 30
+            relb_pos, task_pos, gang_pos, resd = (
+                stg["relb_pos"], stg["task_pos"], stg["gang_pos"], stg["resd"]
+            )
+            V = int(relb_pos.shape[0])
+            ar_L = jnp.arange(L, dtype=jnp.int32)
+            ar_N = jnp.arange(N, dtype=jnp.int32)
+            ar_A = jnp.arange(A, dtype=jnp.int32)
+            slot = jnp.arange(E, dtype=jnp.int32)
+            rewind, join = self._evict_tail(Ea)
+            tally = lambda m: m.sum(dtype=jnp.int32)
+            K = _LK
+
+            def evict_one(state, vassign, rq, ev, nodes, kind, back, max_u,
+                          out_for, b):
+                # -- what each entry is now
+                at_l = jnp.clip(nodes, 0)
+                in_ups = ((nodes[:, None] == back[None, :])
+                          & (back[None, :] >= 0)).any(-1)
+                until_l, down_l = ev.until[at_l], ev.down[at_l]
+                back_l = in_ups | (until_l == b)
+                until_l = jnp.where(back_l, -1, until_l)
+                cord_l = down_l & ~back_l & (until_l < 0)
+                fail_l = kind == K["failure"]
+                dead_l = (kind == K["deadline"]) & cord_l
+                forced_l = fail_l | dead_l
+                asks_l = (until_l < 0) & (
+                    (kind == K["new"]) | ((kind == K["cordoned"]) & cord_l))
+                on_l = forced_l | asks_l
+
+                def on_list(x):
+                    eq = (x[..., None] == nodes) & on_l
+                    return eq.any(-1), (eq * ar_L).sum(-1, dtype=jnp.int32)
+
+                hv, lv = on_list(vassign)
+                hv &= relb_pos >= b
+                hr, lr = on_list(rq.t_node)
+                hr &= rq.t_relb >= b
+                # the places, vassign's then the record's, in blocks of 128,
+                # the blocks in rows of 128
+                cat = lambda v, r, fill: jnp.concatenate([
+                    v, r.reshape(-1),
+                    jnp.full((-(V + r.size) % (128 * 128),), fill, v.dtype),
+                ]).reshape(-1, 128)
+                hit = cat(hv, hr, False)
+                count = hit.sum(1, dtype=jnp.int32)
+                start = jnp.cumsum(count) - count
+                hits = count.sum()
+                rows = count.reshape(-1, 128).sum(1, dtype=jnp.int32)
+                row_start = jnp.cumsum(rows) - rows
+                sup = (row_start[None, :] <= slot[:, None]).sum(
+                    1, dtype=jnp.int32) - 1
+                block = sup * 128 + (
+                    start.reshape(-1, 128)[sup] <= slot[:, None]
+                ).sum(1, dtype=jnp.int32) - 1
+                row = hit[block]
+                upto = jnp.cumsum(row.astype(jnp.int32), axis=1)
+                lane = jnp.argmax(
+                    (upto == (slot - start[block])[:, None] + 1) & row, axis=1
+                ).astype(jnp.int32)
+                ok = slot < hits
+                at = jnp.where(ok, block * 128 + lane, 0)
+                task = jnp.where(ok, cat(task_pos, rq.t_id, 0).reshape(-1)[at], 0)
+                walk = jnp.where(ok, cat(lv, lr, 0).reshape(-1)[at], 0)
+                # the anchor's order: the forced before the asking, an
+                # entry's place in the list, then the task's id
+                turn = jnp.where(ok, jnp.where(forced_l[walk], walk, L + walk),
+                                 2 * L)
+                turn, task, at = jax.lax.sort(
+                    (turn, task, at), num_keys=2, is_stable=False
+                )
+                ok = turn < 2 * L
+                walk = jnp.where(ok, turn % L, 0)
+                asking = ok & (turn >= L)
+                with stage("ksim.evict/Budget"):
+                    app = jnp.where(ok, app_t[task], -1)
+                    of_app = (app[:, None] == ar_A)
+                    spent = ev.unavail + (
+                        of_app & (ok & ~asking)[:, None]
+                    ).sum(0, dtype=jnp.int32)
+                    mine = of_app & asking[:, None]
+                    before = jnp.cumsum(mine.astype(jnp.int32), axis=0) - mine
+                    room = jnp.where(mine, (max_u - spent)[None, :] - before, 0)
+                    admit = ok & (~asking | (app < 0) | (room.sum(1) > 0))
+                    n_admit = tally(admit)
+                    # who leaves, at the front, in the order made
+                    _, turn_a, task_a, at_a = jax.lax.sort(
+                        (~admit, turn, task, at), num_keys=1, is_stable=True
+                    )
+                    turn_a, task_a, at_a = turn_a[:Ea], task_a[:Ea], at_a[:Ea]
+                    go = jnp.arange(Ea, dtype=jnp.int32) < n_admit
+                    walk_a = jnp.where(go, turn_a % L, 0)
+                    # what an entry held, and what it lost
+                    of_entry = walk[:, None] == ar_L
+                    held_l = (of_entry & ok[:, None]).sum(0, dtype=jnp.int32)
+                    lost_l = (of_entry & admit[:, None]).sum(0, dtype=jnp.int32)
+                    app_a = jnp.where(go, app_t[task_a], -1)
+                    unavail = ev.unavail + (app_a[:, None] == ar_A).sum(
+                        0, dtype=jnp.int32)
+                task = jnp.where(go, task_a, 0)
+                kind_a = jnp.where(
+                    turn_a >= L, EVICT_KINDS["voluntary"], jnp.where(
+                        kind[walk_a] == K["failure"], EVICT_KINDS["failure"],
+                        EVICT_KINDS["deadline"]))
+                node = jnp.where(go, nodes[walk_a], -1)
+                bound_at = jnp.where(go & (at_a >= V), (at_a - V) // RB, -1)
+                # the binds go where they stand: only the admitted
+                # (a place that is not written gets an index of its own past
+                # the end: dropped, and the indices stay unique)
+                past = jnp.arange(Ea, dtype=jnp.int32)
+                v_at = jnp.where(go & (at_a < V), at_a, V + past)
+                vassign = vassign.at[v_at].set(
+                    jnp.where(gang_pos[jnp.clip(v_at, 0, V - 1)], -2, PAD
+                              ).astype(vassign.dtype),
+                    mode="drop", unique_indices=True,
+                )
+                r_at = jnp.where(go & (at_a >= V), at_a - V,
+                                 rq.t_id.size + past)
+                gone = jnp.zeros((rq.t_id.size,), bool).at[r_at].set(
+                    True, mode="drop", unique_indices=True
+                ).reshape(rq.t_id.shape)
+                state = rewind(state, node, task)
+                gang, nasks, room, (cat_ids, cat_prio, cat_dur, cat_ev) = join(
+                    rq, go, task, b)
+                rows = jnp.stack([
+                    jnp.where(go, b, -1), jnp.where(go, task, -1), node,
+                    bound_at, jnp.where(go, kind_a, -1),
+                ])
+                logged = ev.n[_EV["logged"]]
+                lost = jnp.maximum(hits - E, 0) + jnp.maximum(
+                    n_admit - Ea, 0) + jnp.where(
+                    (logged + Ea > cap) & (n_admit > 0), n_admit, 0)
+                delta = {
+                    "logged": tally(go), "lost": lost,
+                    "evictions": tally(go),
+                    "evict_gang": tally(go & gang),
+                    "evict_dropped": jnp.maximum(nasks - room, 0),
+                    "evict_arriving": tally(go & ~resd[task]),
+                    "evict_retried": tally(go & (bound_at >= 0)),
+                }
+                # -- the nodes: who goes out, who stays cordoned, who is back
+                empty_l = asks_l & (held_l == lost_l)
+                out_l = dead_l | empty_l
+                gone_l = fail_l & ~in_ups
+                member = lambda flag: (
+                    (ar_N[:, None] == nodes) & flag
+                ).any(-1)
+                backm = ((ar_N[:, None] == back) & (back >= 0)).any(-1) | (
+                    ev.until == b)
+                m_out, m_gone = member(out_l), member(gone_l)
+                state = state._replace(used=jnp.where(
+                    (m_out | member(fail_l))[None, :], 0.0, state.used
+                ))
+                until = jnp.where(backm, -1, ev.until)
+                until = jnp.where(m_out, b + out_for, until)
+                until = jnp.where(m_gone, BIG, until)
+                bdelta = {
+                    "evict_voluntary": tally(
+                        go & (kind_a == EVICT_KINDS["voluntary"])),
+                    "evict_forced_deadline": tally(
+                        go & (kind_a == EVICT_KINDS["deadline"])),
+                    "evict_forced_failure": tally(
+                        go & (kind_a == EVICT_KINDS["failure"])),
+                    "evict_deferred": tally(ok & ~admit),
+                    "nodes_drained": tally(empty_l) + tally(
+                        dead_l & (held_l == 0)),
+                    "nodes_forced": tally(dead_l & (held_l > 0)),
+                }
+                bn = ev.bn + jnp.stack(
+                    [bdelta.get(c, jnp.int32(0)) for c in BUDGET_COUNTERS])
+                rq = rq._replace(
+                    t_id=jnp.where(gone, -1, rq.t_id),
+                    t_node=jnp.where(gone, -1, rq.t_node),
+                    t_relb=jnp.where(gone, BIG, rq.t_relb),
+                    owed=rq.owed - (gone & (rq.t_relb < BIG)).sum(dtype=jnp.int32),
+                    ids=cat_ids[:RB], prio=cat_prio[:RB], dur=cat_dur[:RB],
+                    ev_at=cat_ev[:RB], count=rq.count + jnp.minimum(nasks, room),
+                    dropped=rq.dropped + jnp.maximum(nasks - room, 0),
+                )
+                ev = ev._replace(
+                    down=(ev.down & ~backm) | m_out | m_gone | member(
+                        asks_l & ~empty_l),
+                    log=jax.lax.dynamic_update_slice(
+                        ev.log, rows, (0, jnp.minimum(logged, cap - Ea))),
+                    n=ev.n + jnp.stack(
+                        [delta.get(c, jnp.int32(0)) for c in _EV_COUNTERS]),
+                    until=until,
+                    out_at=jnp.where(m_out | member(fail_l & cord_l), b,
+                                     ev.out_at),
+                    unavail=unavail,
+                    bn=bn.at[_BN["budget_spent_max"]].max(unavail.sum()),
+                )
+                return state, vassign, rq, ev
+
+            fn_v = jax.vmap(
+                evict_one, in_axes=(0,) * 9 + (None,),
                 axis_name=_EVICT_VMAP,
             )
 
@@ -3721,7 +4221,8 @@ class WhatIfEngine:
                             sh_s, self._retry_queue(len(stg["b_c"]))
                         )
                         if self._events_dev:
-                            ev_calls = self._stage_events()["calls"]
+                            evs = self._stage_events()
+                            ev_calls = evs["calls"]
                             ev_d = self._evict_state()
                 pending_fold = None  # (rows, choices) of the not-yet-folded chunk
                 if comp_on:
@@ -4414,11 +4915,18 @@ class WhatIfEngine:
                             preft_d, prefwt_d, tbt_d, tb_c[ci], b_c[ci], rq_d,
                         )
                         if evicting:
-                            args += (ev_d, stg["resd"])
+                            # the eviction program's node planes stay out of
+                            # the pass program
+                            planes = {"until": ev_d.until, "out_at": ev_d.out_at}
+                            args += (ev_d._replace(until=None, out_at=None),
+                                     stg["resd"])
+                            if self._budget_on:
+                                args += (evs["app_t"],)
                         _reg(self._retry_fn, args, "retry")
                         got = self._retry_fn(*args)
                         if evicting:
                             states, rq_d, ev_d, retry_placed = got
+                            ev_d = ev_d._replace(**planes)
                         else:
                             states, rq_d, retry_placed = got
                         args = (
@@ -4728,8 +5236,12 @@ class WhatIfEngine:
                     retry_per = self._retry_counts(rq_d, outs, dropped)
                     if evicting:
                         retry_per.update(self._evict_counts(ev_n))
+                    if evicting and self._budget_on:
+                        bn = self._fetch(ev_d.bn).astype(np.int64)
+                        retry_per.update(
+                            {k: bn[:, i] for k, i in _BN.items()})
             handback_bytes = 0
-            bind_boundary = eviction_log = None
+            bind_boundary = eviction_log = node_out_at = None
             if self.collect_assignments and dev_rel and self.retry_buffer:
                 # Two arrays, final as they land: the arrival binds from the
                 # wave-order buffer, the re-tried ones and the tasks still
@@ -4747,6 +5259,9 @@ class WhatIfEngine:
                         eviction_log = self._handback_log(
                             ev_d, retry_per["evictions"])
                         handback_bytes += int(eviction_log.nbytes)
+                    if evicting and self._budget_on:
+                        node_out_at = self._fetch(ev_d.out_at)
+                        handback_bytes += int(node_out_at.nbytes)
             elif self.collect_assignments and dev_rel:
                 # The device-release path's placements: the wave-order buffer
                 # comes to the host once, after the last chunk.
@@ -5042,6 +5557,7 @@ class WhatIfEngine:
                 assignments=assignments,
                 bind_boundary=bind_boundary,
                 eviction_log=eviction_log,
+                node_out_at=node_out_at,
                 utilization_cpu=util,
                 completions_on=self.completions_on,
                 engine=self.engine,

@@ -135,6 +135,28 @@ class ChaosSpec:
 
 
 @dataclass
+class DrainSpec:
+    """A maintenance drain under disruption budgets (``drain:`` YAML section,
+    what-if only): scenario 0 stays the clean cell; every other scenario
+    cordons ``step`` nodes a chunk boundary from boundary ``first`` on, in
+    node index order from a place drawn from ``seed + s``
+    (``node_cordon`` events), under a ``sim.runtime.DisruptionBudget``: an
+    application (the workload's ``app_id``: Borg workloads) may have
+    ``max(1, floor(maxUnavailableShare x its tasks))`` tasks down at once,
+    a node still holding tasks ``grace`` boundaries after its cordon is
+    forced out, a drained node is out for ``outFor`` boundaries. With a
+    ``chaos:`` section beside it the failures spend the same budgets."""
+
+    enabled: bool = False
+    seed: int = 0
+    step: int = 25
+    first: int = 1
+    grace: int = 2
+    out_for: int = 1
+    max_unavailable_share: float = 0.005
+
+
+@dataclass
 class TuneSpec:
     """Policy-tuner section (``tune:`` YAML, round 9 — sim.tuner). Drives
     ``cmd_tune`` / ``Simulator.tune()``: a seeded search over the Score
@@ -363,6 +385,7 @@ class SimConfig:
     whatif: WhatIfSpec = field(default_factory=WhatIfSpec)
     tune: Optional[TuneSpec] = None
     chaos: Optional[ChaosSpec] = None
+    drain: Optional[DrainSpec] = None
     dcn_recovery: Optional[DcnRecoverySpec] = None
     dcn_workqueue: Optional[DcnWorkQueueSpec] = None
     dcn_durable: Optional[DcnDurableSpec] = None
@@ -509,6 +532,18 @@ class SimConfig:
                     int(ch["maxEvents"]) if ch.get("maxEvents") is not None
                     else None
                 ),
+            )
+        dr = d.get("drain")
+        if dr is not None:
+            cfg.drain = DrainSpec(
+                enabled=bool(dr.get("enabled", True)),
+                seed=int(dr.get("seed", 0)),
+                step=int(dr.get("step", 25)),
+                first=int(dr.get("first", 1)),
+                grace=int(dr.get("grace", 2)),
+                out_for=int(dr.get("outFor", 1)),
+                max_unavailable_share=float(
+                    dr.get("maxUnavailableShare", 0.005)),
             )
         dc = d.get("dcn")
         if dc is not None:

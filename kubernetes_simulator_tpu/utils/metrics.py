@@ -374,6 +374,17 @@ def whatif_rows(res, extra: Optional[dict] = None) -> Iterable[dict]:
             row["evict_latency_mean"] = round(
                 float(res.evict_latency_mean[s]), 4
             )
+        out_at = getattr(res, "node_out_at", None)
+        if out_at is not None:
+            # a drain under disruption budgets: what the budgets let go,
+            # what was forced out (at a deadline, by a failure), the nodes
+            # that went out and the boundary the last of them did
+            kinds = res.eviction_log[s][:, 4]
+            row["evict_voluntary"] = int((kinds == 0).sum())
+            row["evict_forced_deadline"] = int((kinds == 1).sum())
+            row["evict_forced_failure"] = int((kinds == 2).sum())
+            row["nodes_out"] = int((out_at[s] >= 0).sum())
+            row["last_node_out_at"] = int(out_at[s].max())
         if lat50 is not None:
             # Telemetry layer: per-scenario first-bind latency quantiles
             # (virtual seconds); None when the scenario bound nothing.

@@ -52,6 +52,13 @@ STAGES = (
     "ksim.retry",         # a boundary's retry pass: its scan and the queue's upkeep
 )
 
+#: A stage's own sub-scopes, opened inside it (a plugin's beneath
+#: ``ksim.filter_score`` carries the plugin's registry name and is not
+#: listed): a stage path names them as ``parse_stage_table`` reads them.
+SUB_STAGES = (
+    "ksim.evict/Budget",  # the eviction program's admission under disruption budgets
+)
+
 #: Scopes that wrap whole wave steps, not a stage of one: an instruction
 #: inside one is filed under ``<pass>/<its stage path>`` (``ksim.retry/
 #: ksim.select``), so the pass's time can be told from the arrival waves'.
@@ -199,10 +206,11 @@ def device_trace(log_dir: Optional[str]):
 
 
 def stage(name: str):
-    """``jax.named_scope`` for a member of :data:`STAGES`."""
+    """``jax.named_scope`` for a member of :data:`STAGES` or of
+    :data:`SUB_STAGES`."""
     import jax
 
-    if name not in STAGES:
+    if name not in STAGES and name not in SUB_STAGES:
         raise ValueError(f"unknown stage {name!r} (have {STAGES})")
     return jax.named_scope(name)
 

@@ -1,5 +1,6 @@
 """Where the compiler puts the wave step's node planes in the two programs
-of a retry boundary (PR 47), with no chip: the drain cell's engine at the
+of a retry boundary (PR 47), with no chip: the drain cell's engine, and the
+budgeted drain's (PR 49), at the
 cell's own size (128 plans x 10,000 nodes, ``retry_buffer`` 8,192), both
 programs compiled for a DESCRIBED v5e, and the memory space of the planes
 the state-carrying loop of each carries read from the compiled text
@@ -26,7 +27,7 @@ import run as bench  # noqa: E402
 
 from kubernetes_simulator_tpu.utils import profiling  # noqa: E402
 
-CELL = "borg10k-drain128"
+CELLS = ("borg10k-drain128", "borg10k-budget128")
 LIMIT_S = 900.0  # prepare 5 s + compiles of 10 s and 40 s on a free host
 
 
@@ -71,9 +72,16 @@ def _first_boundary(eng):
     return calls
 
 
-def test_each_program_of_a_retry_boundary_keeps_the_node_planes_on_chip(one_chip):
+@pytest.mark.parametrize("cell", CELLS)
+def test_each_program_of_a_retry_boundary_keeps_the_node_planes_on_chip(
+        one_chip, cell):
+    """The drain cell's engine, and the budgeted drain's: its eviction
+    program alone holds the planes that say which nodes are out and until
+    when, so the two loop programs read the ONE ``down`` mask they read in
+    the drain cell (a fourth plane carried into them is what tipped the
+    compiler, PR 47)."""
     deadline = time.monotonic() + LIMIT_S
-    _, _, config, traffic = bench.load_cell(CELL)
+    _, _, config, traffic = bench.load_cell(cell)
     _, _, adapter = bench.prepare(config, traffic, 7, False, {})
     eng = adapter.engine
     calls = _first_boundary(eng)

@@ -255,6 +255,9 @@ def test_an_engine_built_without_timelines_takes_none_later():
 def test_the_eviction_program_carries_its_stage_scope(tmp_path, monkeypatch):
     from kubernetes_simulator_tpu.utils import profiling
 
+    # the registry is the process's: an armed run of an earlier file may have
+    # left a program there that cannot be lowered again (a stub)
+    profiling._PROGRAMS.clear()
     monkeypatch.setenv("KSIM_PROFILE_DIR", str(tmp_path))
     ec, ep, tb = cell()
     eng = WhatIfEngine(
