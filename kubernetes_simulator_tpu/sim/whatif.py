@@ -530,6 +530,11 @@ _EV = {k: i for i, k in enumerate(_EV_COUNTERS)}
 _BN = {k: i for i, k in enumerate(BUDGET_COUNTERS)}
 # What an entry of a budgeted boundary's node list is (``_stage_budget_events``).
 _LK = {"pad": 0, "failure": 1, "new": 2, "cordoned": 3, "deadline": 4}
+# ``evict_search`` compares a slot with every block's offset while the blocks
+# are no more than this (both Borg cells: 4,077 and 4,096), and in two steps
+# past it: one step costs what its blocks cost, the second step's gather does
+# not, and they met near 4,800 blocks on the chip (PERF.md §6, PR 50).
+_SEARCH_BLOCKS = 4096
 
 
 class EvictState(NamedTuple):
@@ -562,6 +567,81 @@ class EvictState(NamedTuple):
     out_at: Optional[jax.Array] = None
     unavail: Optional[jax.Array] = None
     bn: Optional[jax.Array] = None
+
+
+def evict_search(vassign, live_v, t_node, live_r, task_v, t_id, nodes, on, E):
+    """The candidate search of both eviction programs, one scenario's, under
+    ``ksim.evict/Search``: every LIVE bind on a listed node, in the two
+    places the device holds a bind, ``vassign [V]`` (whose tasks are
+    ``task_v``) then the record's ``t_node`` (whose tasks are ``t_id``),
+    brought to the front in the places' order. ``nodes [L]`` is the
+    boundary's list, a node at most once, ``on [L]`` the entries that count
+    here; ``live_v`` / ``live_r`` say which places still hold their bind.
+    ``-> (hv, hr, hits, ok, at, task, walk)``: the hit bit of every place,
+    their count, and for each of ``E`` slots whether it holds a candidate,
+    its place (``vassign``'s first), its task and its entry of the list; a
+    slot past ``hits`` reads place 0, task 0 and entry ``L``.
+
+    * ONE compare pass over the places gives the hit bit, a compare and an
+      or a pair (an entry that is off names a node no place holds): a node
+      mask read by the place's node is a gather a scenario, 786 ms at the
+      Borg cell's shape (PERF.md §6, PR 45). Which entry a place is on is
+      worked out for the ``E`` candidates alone, from the candidate's node:
+      the list holds a node once.
+    * the candidates come to the front BY RANK, with no sort over the places
+      and no scatter (one sort of key and source over all places 131.5 ms
+      there, ``jnp.nonzero`` 593.8): the places are cut into blocks of 128
+      and counted, a slot finds its block by comparing itself with the
+      blocks' running offsets and takes the lane of the block's hit row
+      whose count within the row is its rank. The block's own offset is the
+      largest the slot has reached, a lane of what it holds. Past
+      ``_SEARCH_BLOCKS`` blocks the offsets are compared in two steps, rows
+      of 128 blocks and then the row's own, read by a gather of its own.
+    * a slot GATHERS twice: its block's hit row, and its place's task and
+      node from one stacked table (a read by place costs three times a read
+      by block: the table is every place). ``scripts/
+      chip_forms_evict_search.py`` ranks the forms on the chip at both
+      cells' shapes (PERF.md §6, PR 50)."""
+    with stage("ksim.evict/Search"):
+        L, V = nodes.shape[0], vassign.shape[0]
+        slot = jnp.arange(E, dtype=jnp.int32)
+        entry = jnp.where(on, nodes, jnp.iinfo(jnp.int32).min)
+        listed = lambda x: (
+            entry.reshape((L,) + (1,) * x.ndim) == x[None]).any(0)
+        hv = listed(vassign) & live_v
+        hr = listed(t_node) & live_r
+        places = V + t_node.size
+        two = -(-places // 128) > _SEARCH_BLOCKS
+        pad = -places % (128 * 128 if two else 128)  # whole blocks, whole rows
+        cat = lambda v, r, fill: jnp.concatenate(
+            [v, r.reshape(-1), jnp.full((pad,), fill, v.dtype)])
+        hit = cat(hv, hr, False).reshape(-1, 128)
+        count = hit.sum(1, dtype=jnp.int32)
+        start = jnp.cumsum(count) - count
+        hits = count.sum()
+        if two:
+            rows = count.reshape(-1, 128).sum(1, dtype=jnp.int32)
+            row_start = jnp.cumsum(rows) - rows
+            sup = (row_start[None, :] <= slot[:, None]).sum(
+                1, dtype=jnp.int32) - 1
+            offsets, base = start.reshape(-1, 128)[sup], sup * 128
+        else:
+            offsets, base = start[None, :], 0
+        reached = offsets <= slot[:, None]
+        block = base + reached.sum(1, dtype=jnp.int32) - 1
+        rank = slot - jnp.where(reached, offsets, 0).max(1)
+        row = hit[block]
+        upto = jnp.cumsum(row.astype(jnp.int32), axis=1)
+        lane = jnp.argmax((upto == rank[:, None] + 1) & row, axis=1).astype(
+            jnp.int32)
+        ok = slot < hits
+        at = jnp.where(ok, block * 128 + lane, 0)
+        task, node = jnp.stack(
+            [cat(task_v, t_id, 0), cat(vassign, t_node, -1)])[:, at]
+        walk = ((node[:, None] == entry)
+                * jnp.arange(L, dtype=jnp.int32)).sum(-1, dtype=jnp.int32)
+        return (hv, hr, hits, ok, at, jnp.where(ok, task, 0),
+                jnp.where(ok, walk, L))
 
 
 @dataclass
@@ -3190,21 +3270,14 @@ class WhatIfEngine:
           the device holds a bind: ``vassign`` (arrival binds, the residents
           in its tail; live until the boundary its static release is due
           at, this one included: the events come first) and the record's
-          ``t_node`` rows (re-tried binds; live while ``t_relb >= b``).
-          Each place is compared with the ``L`` leaving nodes: a node mask
-          read by the place's node is a gather a scenario, 786 ms at the
-          Borg cell's shape where the compare takes 6.6 (PERF.md §6, PR 45).
-        * they are brought to the front BY RANK, with no sort over the
-          places and no scatter: the places are cut into blocks of 128 and
-          counted; output slot j finds its block by comparing j with the
-          blocks' running offsets, reads that block's row and takes the
-          lane whose count within the row is its rank (19.6 ms there; one
-          sort of key and source over all 520,192 places 131.5,
-          ``jnp.nonzero`` 593.8). ``E`` slots: where a scenario has more
-          victims the run is made again with twice the room
-          (``_evict_scale``). The ``E`` victims alone are then sorted
-          into the order ``BoundaryOps.evict_node`` makes them: the leaving
-          nodes in timeline order, a node's tasks by id.
+          ``t_node`` rows (re-tried binds; live while ``t_relb >= b``),
+          found and brought to the front by ``evict_search``, the one
+          search of both eviction programs (``ksim.evict/Search``): ``E``
+          slots, and where a scenario has more victims the run is made
+          again with twice the room (``_evict_scale``). The ``E`` victims
+          alone are then sorted into the order ``BoundaryOps.evict_node``
+          makes them: the leaving nodes in timeline order, a node's tasks
+          by id.
         * the binds go where they stand: ``vassign`` reads PAD (-2 for a
           gang member: the hand-back's code -5), so the static release
           finds nothing; the record row reads no task, no node and no
@@ -3233,40 +3306,13 @@ class WhatIfEngine:
                 stg["relb_pos"], stg["task_pos"], stg["gang_pos"], stg["resd"]
             )
             V = int(relb_pos.shape[0])
-            ar_L = jnp.arange(L, dtype=jnp.int32)
             ar_N = jnp.arange(N, dtype=jnp.int32)
-            slot = jnp.arange(E, dtype=jnp.int32)
             rewind, join = self._evict_tail(E)
 
             def evict_one(state, vassign, rq, ev, leave, back, b):
-                def on_leaving(x):
-                    eq = (x[..., None] == leave) & (leave >= 0)
-                    return eq.any(-1), (eq * ar_L).sum(-1, dtype=jnp.int32)
-
-                hv, lv = on_leaving(vassign)
-                hv &= relb_pos >= b
-                hr, lr = on_leaving(rq.t_node)
-                hr &= rq.t_relb >= b
-                # the places, vassign's then the record's, in blocks of 128
-                cat = lambda v, r, fill: jnp.concatenate([
-                    v, r.reshape(-1),
-                    jnp.full((-(V + r.size) % 128,), fill, v.dtype),
-                ]).reshape(-1, 128)
-                hit = cat(hv, hr, False)
-                count = hit.sum(1, dtype=jnp.int32)
-                start = jnp.cumsum(count) - count
-                hits = count.sum()
-                block = (start[None, :] <= slot[:, None]).sum(
-                    1, dtype=jnp.int32) - 1
-                row = hit[block]
-                upto = jnp.cumsum(row.astype(jnp.int32), axis=1)
-                lane = jnp.argmax(
-                    (upto == (slot - start[block])[:, None] + 1) & row, axis=1
-                ).astype(jnp.int32)
-                ok = slot < hits
-                at = jnp.where(ok, block * 128 + lane, 0)
-                task = jnp.where(ok, cat(task_pos, rq.t_id, 0).reshape(-1)[at], 0)
-                walk = jnp.where(ok, cat(lv, lr, 0).reshape(-1)[at], L)
+                hv, hr, hits, ok, at, task, walk = evict_search(
+                    vassign, relb_pos >= b, rq.t_node, rq.t_relb >= b,
+                    task_pos, rq.t_id, leave, leave >= 0, E)
                 # the anchor's order: a node's place in the timeline, then
                 # the task's id (E victims: a small sort)
                 walk, task, at = jax.lax.sort(
@@ -3400,11 +3446,9 @@ class WhatIfEngine:
           deadline, and ASKS where it is cordoned here (unless it is out)
           or was cordoned earlier and is not out.
         * the candidates, every live bind on a forced or an asking entry,
-          are found by the compare and brought to the front by rank as
-          ``_evict_fn``'s victims are (the block of a slot found in two
-          steps of 128, the list being longer), ``E`` of them, and sorted:
-          the forced first (list order), then the asking (walk order), a
-          node's tasks by id.
+          are ``evict_search``'s, as ``_evict_fn``'s victims are, ``E`` of
+          them, and sorted: the forced first (list order), then the asking
+          (walk order), a node's tasks by id.
         * the ADMISSION, under ``ksim.evict/Budget``: a forced candidate
           leaves; an asking one iff its rank among its application's
           asking candidates is below ``max_u - unavail`` (the forced of
@@ -3433,7 +3477,6 @@ class WhatIfEngine:
             ar_L = jnp.arange(L, dtype=jnp.int32)
             ar_N = jnp.arange(N, dtype=jnp.int32)
             ar_A = jnp.arange(A, dtype=jnp.int32)
-            slot = jnp.arange(E, dtype=jnp.int32)
             rewind, join = self._evict_tail(Ea)
             tally = lambda m: m.sum(dtype=jnp.int32)
             K = _LK
@@ -3455,44 +3498,13 @@ class WhatIfEngine:
                     (kind == K["new"]) | ((kind == K["cordoned"]) & cord_l))
                 on_l = forced_l | asks_l
 
-                def on_list(x):
-                    eq = (x[..., None] == nodes) & on_l
-                    return eq.any(-1), (eq * ar_L).sum(-1, dtype=jnp.int32)
-
-                hv, lv = on_list(vassign)
-                hv &= relb_pos >= b
-                hr, lr = on_list(rq.t_node)
-                hr &= rq.t_relb >= b
-                # the places, vassign's then the record's, in blocks of 128,
-                # the blocks in rows of 128
-                cat = lambda v, r, fill: jnp.concatenate([
-                    v, r.reshape(-1),
-                    jnp.full((-(V + r.size) % (128 * 128),), fill, v.dtype),
-                ]).reshape(-1, 128)
-                hit = cat(hv, hr, False)
-                count = hit.sum(1, dtype=jnp.int32)
-                start = jnp.cumsum(count) - count
-                hits = count.sum()
-                rows = count.reshape(-1, 128).sum(1, dtype=jnp.int32)
-                row_start = jnp.cumsum(rows) - rows
-                sup = (row_start[None, :] <= slot[:, None]).sum(
-                    1, dtype=jnp.int32) - 1
-                block = sup * 128 + (
-                    start.reshape(-1, 128)[sup] <= slot[:, None]
-                ).sum(1, dtype=jnp.int32) - 1
-                row = hit[block]
-                upto = jnp.cumsum(row.astype(jnp.int32), axis=1)
-                lane = jnp.argmax(
-                    (upto == (slot - start[block])[:, None] + 1) & row, axis=1
-                ).astype(jnp.int32)
-                ok = slot < hits
-                at = jnp.where(ok, block * 128 + lane, 0)
-                task = jnp.where(ok, cat(task_pos, rq.t_id, 0).reshape(-1)[at], 0)
-                walk = jnp.where(ok, cat(lv, lr, 0).reshape(-1)[at], 0)
+                _, _, hits, ok, at, task, walk = evict_search(
+                    vassign, relb_pos >= b, rq.t_node, rq.t_relb >= b,
+                    task_pos, rq.t_id, nodes, on_l, E)
+                forced = ((walk[:, None] == ar_L) & forced_l).any(-1)
                 # the anchor's order: the forced before the asking, an
                 # entry's place in the list, then the task's id
-                turn = jnp.where(ok, jnp.where(forced_l[walk], walk, L + walk),
-                                 2 * L)
+                turn = jnp.where(ok, jnp.where(forced, walk, L + walk), 2 * L)
                 turn, task, at = jax.lax.sort(
                     (turn, task, at), num_keys=2, is_stable=False
                 )

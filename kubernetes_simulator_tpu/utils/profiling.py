@@ -56,6 +56,7 @@ STAGES = (
 #: ``ksim.filter_score`` carries the plugin's registry name and is not
 #: listed): a stage path names them as ``parse_stage_table`` reads them.
 SUB_STAGES = (
+    "ksim.evict/Search",  # the eviction program's candidate search (sim.whatif.evict_search)
     "ksim.evict/Budget",  # the eviction program's admission under disruption budgets
 )
 

@@ -191,3 +191,27 @@ def test_stage_tables_see_through_a_cache_filled_by_another_tree(tmp_path):
         stages.append(set(json.loads(out.splitlines()[-1])["jit_f"].values()))
         assert list(tmp_path.glob("jit_f-*"))
     assert stages == [{""}, {"", "ksim.reads"}]
+
+
+@pytest.mark.parametrize("kind", ("drain", "budget"))
+def test_the_eviction_programs_search_carries_its_own_scope(kind):
+    """``ksim.evict/Search`` (PR 50): the one candidate search of both
+    eviction programs, so that a traced run of either splits into the
+    search, the admission (``ksim.evict/Budget``, the budgeted program's
+    alone) and the rest under ``ksim.evict``."""
+    import test_evict_search as searched
+
+    assert "ksim.evict/Search" in profiling.SUB_STAGES
+    _, fn, structs = searched.program(kind)
+    profiling._PROGRAMS.clear()
+    profiling.register_program("jit_whatif_evict", lambda: fn.lower(*structs))
+    table = profiling.stage_tables()["jit_whatif_evict"]
+    profiling._PROGRAMS.clear()
+    assert set(table.values()) - {""} == {"ksim.evict", "ksim.evict/Search"} | (
+        {"ksim.evict/Budget"} if kind == "budget" else set())
+    # the search's ops: a slot's two gathers are filed under it
+    text = fn.lower(*structs).compile(
+        compiler_options={"xla_dump_disable_metadata": False}).as_text()
+    assert len([m for m in re.finditer(r'op_name="([^"]*)"', text)
+                if "ksim.evict/Search" in m.group(1)
+                and m.group(1).endswith("/gather")]) >= 2

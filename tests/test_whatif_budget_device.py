@@ -250,10 +250,12 @@ def test_infinite_budgets_and_no_grace_answer_what_the_drain_rule_answers():
 
 # sha256 of ``.lower().as_text()`` of the three programs a boundary of
 # ``test_whatif_events_device.batch(512)`` dispatches, on the parent commit
-# (c04e4fa, PR 47): a batch without budgets has to keep them.
+# (c04e4fa, PR 47): a batch without budgets has to keep them. The eviction
+# program's is PR 50's, whose one search both eviction programs call (until
+# then dcc741d2...ba75267, c04e4fa's): budgets still add nothing to it.
 PARENTS = {
     "jit_whatif_evict":
-        "dcc741d228b721b64e78cbd121b2353a4e28b3c89bf8067b00b4fc709ba75267",
+        "f619a16f915967c9f3490a3b1d3fa90356eb68324ad3ff00b35b55936e96bbf6",
     "jit_per_scenario_retry":
         "66ceeb0f1885176097f4edd75a0d01be0a492cd768b9a8673a5dda4da1776d0b",
     "jit_per_scenario_arrivals":
