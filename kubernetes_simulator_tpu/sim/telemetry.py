@@ -152,10 +152,15 @@ PHASE_NAMES = (
 #: gather and fetch (inside ``handback``), which carry ``bytes=`` as the
 #: event's stats; ``host_events``: what the host does for a boundary's
 #: timeline events on the what-if device path (the eviction program's
-#: arguments; its call is ``boundary_fold``'s). Trace readers import these
-#: and hold no list of their own.
+#: arguments; its call is ``boundary_fold``'s); ``handback_wait`` and
+#: ``handback_fetch``, inside ``handback`` of a batch with a
+#: ``retry_buffer``: the hand-back program from its dispatch to its outputs
+#: being ready, then one span an answer brought to the host (``answer=``
+#: its name, ``bytes=`` its size). Trace readers import these and hold no
+#: list of their own.
 HOST_SPAN_NAMES = PHASE_NAMES + (
     "checkpoint", "mesh_put", "mesh_fetch", "host_events",
+    "handback_wait", "handback_fetch",
 )
 #: ``chunk:<i>``: the dispatch of chunk ``i``, inside ``dispatch``.
 CHUNK_SPAN = "chunk"

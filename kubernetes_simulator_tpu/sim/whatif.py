@@ -1836,8 +1836,9 @@ class WhatIfEngine:
                     with stage("ksim.retry"):
                         q = rq.ids
                         rb_waves = q.reshape(RBW, wave_width)
-                        slots_r = T.gather_slots_device(src, rb_waves)
-                        extra_r = V3.gather_extra_device(xsrc, rb_waves)
+                        with stage("ksim.retry/Gather"):
+                            slots_r = T.gather_slots_device(src, rb_waves)
+                            extra_r = V3.gather_extra_device(xsrc, rb_waves)
                         # The queue stands at the front of its buffer (the
                         # sorts put the holes last), so the pass ends with
                         # the fullest scenario's last queued wave: a buffer
@@ -1864,70 +1865,71 @@ class WhatIfEngine:
                                 (RBW, wave_width), PAD, jnp.int32
                             )),
                         )
-                        flat_cr = choices_r.reshape(RB)
-                        placed_r = (flat_cr >= 0) & (q >= 0)
-                        retry_placed = placed_r.sum(dtype=jnp.int32)
-                        rbn = jnp.searchsorted(
-                            tbt, t_b + rq.dur, side="left"
-                        )
-                        relb = jnp.where(
-                            placed_r & (rbn < tbt.shape[0]),
-                            jnp.maximum(rbn, b + 1),
-                            BIG,
-                        ).astype(jnp.int32)
-                        safe = jnp.clip(q, 0)
-                        rq = rq._replace(
-                            ids=jnp.where(placed_r, -1, q),
-                            count=rq.count - retry_placed,
-                            t_id=put(rq.t_id, jnp.where(placed_r, q, -1)),
-                            t_node=put(
-                                rq.t_node, jnp.where(placed_r, flat_cr, -1)
-                            ),
-                            t_relb=put(rq.t_relb, relb),
-                            t_req=put(
-                                rq.t_req, slots_r.req.reshape(RB, -1).T
-                            ),
-                            t_mg=put(rq.t_mg, mgt[safe].T),
-                            owed=rq.owed
-                            + (relb < BIG).sum(dtype=jnp.int32),
-                            released=released,
-                            depth_max=jnp.maximum(rq.depth_max, rq.count),
-                            pass_waves=rq.pass_waves + trips,
-                        )
-                        if want_an:
+                        with stage("ksim.retry/Record"):
+                            flat_cr = choices_r.reshape(RB)
+                            placed_r = (flat_cr >= 0) & (q >= 0)
+                            retry_placed = placed_r.sum(dtype=jnp.int32)
+                            rbn = jnp.searchsorted(
+                                tbt, t_b + rq.dur, side="left"
+                            )
+                            relb = jnp.where(
+                                placed_r & (rbn < tbt.shape[0]),
+                                jnp.maximum(rbn, b + 1),
+                                BIG,
+                            ).astype(jnp.int32)
+                            safe = jnp.clip(q, 0)
                             rq = rq._replace(
-                                t_an=put(rq.t_an, antit[safe].T)
+                                ids=jnp.where(placed_r, -1, q),
+                                count=rq.count - retry_placed,
+                                t_id=put(rq.t_id, jnp.where(placed_r, q, -1)),
+                                t_node=put(
+                                    rq.t_node, jnp.where(placed_r, flat_cr, -1)
+                                ),
+                                t_relb=put(rq.t_relb, relb),
+                                t_req=put(
+                                    rq.t_req, slots_r.req.reshape(RB, -1).T
+                                ),
+                                t_mg=put(rq.t_mg, mgt[safe].T),
+                                owed=rq.owed
+                                + (relb < BIG).sum(dtype=jnp.int32),
+                                released=released,
+                                depth_max=jnp.maximum(rq.depth_max, rq.count),
+                                pass_waves=rq.pass_waves + trips,
                             )
-                        if want_pf:
-                            rq = rq._replace(
-                                t_pf=put(rq.t_pf, preft[safe].T),
-                                t_pw=put(rq.t_pw, prefwt[safe].T),
-                            )
-                        if ev_on:
-                            back = placed_r & (rq.ev_at >= 0)
-                            wait = jnp.where(back, b - rq.ev_at, 0)
-                            tally = lambda m: m.sum(dtype=jnp.int32)
-                            delta = {
-                                "rebound": tally(back),
-                                "rebound_same": tally(back & (rq.ev_at == b)),
-                                "rebound_resident": tally(back & resd[safe]),
-                                "wait_sum": wait.sum(dtype=jnp.int32),
-                            }
-                            n = ev.n + jnp.stack([
-                                delta.get(k, jnp.int32(0)) for k in _EV_COUNTERS
-                            ])
-                            ev = ev._replace(
-                                n=n.at[_EV["wait_max"]].max(wait.max()),
-                                wait_s=ev.wait_s + jnp.where(
-                                    back, t_b - tbt[jnp.clip(rq.ev_at, 0)], 0.0
-                                ).sum(),
-                            )
-                            if bud_on:
-                                app_r = jnp.where(back, app_t[safe], -1)
-                                ev = ev._replace(unavail=ev.unavail - (
-                                    app_r[:, None] == jnp.arange(
-                                        ev.unavail.shape[0], dtype=jnp.int32)
-                                ).sum(0, dtype=jnp.int32))
+                            if want_an:
+                                rq = rq._replace(
+                                    t_an=put(rq.t_an, antit[safe].T)
+                                )
+                            if want_pf:
+                                rq = rq._replace(
+                                    t_pf=put(rq.t_pf, preft[safe].T),
+                                    t_pw=put(rq.t_pw, prefwt[safe].T),
+                                )
+                            if ev_on:
+                                back = placed_r & (rq.ev_at >= 0)
+                                wait = jnp.where(back, b - rq.ev_at, 0)
+                                tally = lambda m: m.sum(dtype=jnp.int32)
+                                delta = {
+                                    "rebound": tally(back),
+                                    "rebound_same": tally(back & (rq.ev_at == b)),
+                                    "rebound_resident": tally(back & resd[safe]),
+                                    "wait_sum": wait.sum(dtype=jnp.int32),
+                                }
+                                n = ev.n + jnp.stack([
+                                    delta.get(k, jnp.int32(0)) for k in _EV_COUNTERS
+                                ])
+                                ev = ev._replace(
+                                    n=n.at[_EV["wait_max"]].max(wait.max()),
+                                    wait_s=ev.wait_s + jnp.where(
+                                        back, t_b - tbt[jnp.clip(rq.ev_at, 0)], 0.0
+                                    ).sum(),
+                                )
+                                if bud_on:
+                                    app_r = jnp.where(back, app_t[safe], -1)
+                                    ev = ev._replace(unavail=ev.unavail - (
+                                        app_r[:, None] == jnp.arange(
+                                            ev.unavail.shape[0], dtype=jnp.int32)
+                                    ).sum(0, dtype=jnp.int32))
                     if ev_on:
                         return state, rq, ev, retry_placed
                     return state, rq, retry_placed
@@ -2795,17 +2797,26 @@ class WhatIfEngine:
             counts = self._fetch(counts).astype(np.int32)
         return out, int(out.nbytes), counts
 
+    def _fetch_answer(self, span, answer: str, x) -> np.ndarray:
+        """``_fetch`` of one answer of a retry batch's hand-back, under a
+        ``handback_fetch`` span that carries which answer it is and the
+        bytes the copy brings to the host."""
+        with span.mark("handback_fetch", answer=answer, bytes=int(x.nbytes)):
+            return self._fetch(x)
+
     def _handback_retry(
-        self, vassign_d, rq: RetryQueue, retry_placed: np.ndarray
+        self, span, vassign_d, rq: RetryQueue, retry_placed: np.ndarray
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
         """(assignments [S, P], bind_boundary [S, P], merged [S], bytes
         copied) of a batch on the device retry path: ONE program, one fetch
-        an array, and the host rewrites nothing. The program puts the
+        an array, and the host rewrites nothing. Under the run's ``span``
+        the program's time is ``handback_wait`` (its dispatch to its outputs
+        being ready) and each answer's copy a ``handback_fetch``. The program puts the
         arrival binds into task order (``vassign`` through the static
         ``pos`` map, the residents in its tail) and writes beside each task
         what it reads if nothing else is known of it: -1 where it has a
         node, -4 for an unplaced gang member (never queued), -3 for any
-        other (dropped at a full buffer). Then, under ``ksim.handback``, it
+        other (dropped at a full buffer). Then it
         merges the queue's record and the queue into both arrays: every
         record row ``(b, j)`` with a task writes that task's node and ``b``,
         and every task still queued reads -2 (a task that failed in the
@@ -2853,48 +2864,53 @@ class WhatIfEngine:
                     # the eviction program left -2 where a gang member stood
                     code = jnp.where(node == -2, -5, code)
                     node = jnp.maximum(node, PAD)
-                with jax.named_scope("ksim.handback"):
-                    boundary = jnp.arange(rq.t_id.shape[1], dtype=jnp.int32)
-                    # the queue rides as one more row: no node, code -2
-                    rows = lambda record, queue: jnp.concatenate(
-                        [record, queue[:, None]], axis=1
-                    ).reshape(S, -1)
-                    task = rows(rq.t_id, rq.ids)
-                    task, wrote, on = jax.lax.sort((
-                        jnp.where(task >= 0, task, none),
-                        rows(jnp.broadcast_to(boundary[None, :, None],
-                                              rq.t_id.shape),
-                             jnp.full_like(rq.ids, -2)),
-                        rows(rq.t_node, jnp.full_like(rq.ids, PAD)),
-                    ), dimension=1, num_keys=1, is_stable=False)
-                    filled = (task < none).sum(axis=1).max()
-                    scen = jax.lax.broadcasted_iota(jnp.int32, (S, RB), 0)
+                boundary = jnp.arange(rq.t_id.shape[1], dtype=jnp.int32)
+                # the queue rides as one more row: no node, code -2
+                rows = lambda record, queue: jnp.concatenate(
+                    [record, queue[:, None]], axis=1
+                ).reshape(S, -1)
+                task = rows(rq.t_id, rq.ids)
+                task, wrote, on = jax.lax.sort((
+                    jnp.where(task >= 0, task, none),
+                    rows(jnp.broadcast_to(boundary[None, :, None],
+                                          rq.t_id.shape),
+                         jnp.full_like(rq.ids, -2)),
+                    rows(rq.t_node, jnp.full_like(rq.ids, PAD)),
+                ), dimension=1, num_keys=1, is_stable=False)
+                filled = (task < none).sum(axis=1).max()
+                scen = jax.lax.broadcasted_iota(jnp.int32, (S, RB), 0)
 
-                    def block(i, arrays):
-                        node, code = arrays
-                        cols = lambda a: jax.lax.dynamic_slice(
-                            a, (0, i * RB), (S, RB)
-                        )
-                        t, b = cols(task), cols(wrote)
-                        put = dict(mode="drop", unique_indices=True)
-                        return (
-                            node.at[scen, jnp.where(b >= 0, t, none)].set(
-                                cols(on), **put),
-                            code.at[scen, t].set(b, **put),
-                        )
-
-                    node, code = jax.lax.fori_loop(
-                        0, -(-filled // RB), block, (node, code)
+                def block(i, arrays):
+                    node, code = arrays
+                    cols = lambda a: jax.lax.dynamic_slice(
+                        a, (0, i * RB), (S, RB)
                     )
+                    t, b = cols(task), cols(wrote)
+                    put = dict(mode="drop", unique_indices=True)
+                    return (
+                        node.at[scen, jnp.where(b >= 0, t, none)].set(
+                            cols(on), **put),
+                        code.at[scen, t].set(b, **put),
+                    )
+
+                node, code = jax.lax.fori_loop(
+                    0, -(-filled // RB), block, (node, code)
+                )
                 return node, code, (code >= 0).sum(axis=1, dtype=jnp.int32)
 
             return jax.jit(whatif_handback_retry)
 
-        got = self._jit_once("handback_retry", build)(vassign_d, rq)
-        if not self._mesh_spans_procs:
-            for a in got:  # the second array's copy overlaps the first's
-                a.copy_to_host_async()
-        assignments, bind_boundary, merged = (self._fetch(a) for a in got)
+        fn = self._jit_once("handback_retry", build)
+        with span.mark("handback_wait"):
+            got = fn(vassign_d, rq)
+            if not self._mesh_spans_procs:
+                for a in got:  # the second array's copy overlaps the first's
+                    a.copy_to_host_async()
+            # the copies are under way: the fetches below wait for them alone
+            jax.block_until_ready(got)
+        assignments = self._fetch_answer(span, "assignments", got[0])
+        bind_boundary = self._fetch_answer(span, "bind_boundary", got[1])
+        merged = self._fetch(got[2])
         if not np.array_equal(merged, retry_placed):
             s = int(np.argmax(merged != retry_placed))
             raise RuntimeError(
@@ -2949,7 +2965,7 @@ class WhatIfEngine:
             "evict_wait_boundaries_max": col("wait_max"),
         }
 
-    def _handback_log(self, ev: EvictState, evictions) -> np.ndarray:
+    def _handback_log(self, span, ev: EvictState, evictions) -> np.ndarray:
         """``WhatIfResult.eviction_log`` ``[S, E, 4]`` (``5`` under
         disruption budgets: the kind last): the log's filled
         columns (to the longest scenario's, rounded up to 1,024 so that a
@@ -2962,7 +2978,8 @@ class WhatIfEngine:
         turn = self._jit_once(f"evict_log:{width}", lambda: jax.jit(
             lambda log: jnp.swapaxes(log[:, :, :width], 1, 2)
         ))
-        return self._fetch(turn(ev.log))[:, :longest]
+        return self._fetch_answer(
+            span, "eviction_log", turn(ev.log))[:, :longest]
 
     def _retry_summary(self, per: dict, passes: int) -> dict:
         """``summary()["retry"]`` from ``_retry_counts``: the buffer, the
@@ -3307,7 +3324,7 @@ class WhatIfEngine:
             )
             V = int(relb_pos.shape[0])
             ar_N = jnp.arange(N, dtype=jnp.int32)
-            rewind, join = self._evict_tail(E)
+            rewind, join, cut = self._evict_tail(E)
 
             def evict_one(state, vassign, rq, ev, leave, back, b):
                 hv, hr, hits, ok, at, task, walk = evict_search(
@@ -3315,55 +3332,58 @@ class WhatIfEngine:
                     task_pos, rq.t_id, leave, leave >= 0, E)
                 # the anchor's order: a node's place in the timeline, then
                 # the task's id (E victims: a small sort)
-                walk, task, at = jax.lax.sort(
-                    (walk, task, at), num_keys=2, is_stable=False
-                )
-                node = jnp.where(ok, leave[jnp.clip(walk, 0, L - 1)], -1)
-                bound_at = jnp.where(ok & (at >= V), (at - V) // RB, -1)
-                vassign = jnp.where(
-                    hv, jnp.where(gang_pos, -2, PAD), vassign
-                ).astype(vassign.dtype)
+                with stage("ksim.evict/Sort"):
+                    walk, task, at = jax.lax.sort(
+                        (walk, task, at), num_keys=2, is_stable=False
+                    )
+                    node = jnp.where(ok, leave[jnp.clip(walk, 0, L - 1)], -1)
+                    bound_at = jnp.where(ok & (at >= V), (at - V) // RB, -1)
+                with stage("ksim.evict/Write"):
+                    vassign = jnp.where(
+                        hv, jnp.where(gang_pos, -2, PAD), vassign
+                    ).astype(vassign.dtype)
                 state = rewind(state, node, task)
-                gang, nasks, room, (cat_ids, cat_prio, cat_dur, cat_ev) = join(
-                    rq, ok, task, b)
-                rows = jnp.stack([
-                    jnp.where(ok, b, -1), jnp.where(ok, task, -1), node, bound_at,
-                ])
-                logged = ev.n[_EV["logged"]]
-                lost = jnp.maximum(hits - E, 0) + jnp.where(
-                    (logged + E > cap) & (hits > 0), hits, 0)
-                tally = lambda m: m.sum(dtype=jnp.int32)
-                delta = {
-                    "logged": tally(ok), "lost": lost,
-                    "evictions": tally(ok),
-                    "evict_gang": tally(ok & gang),
-                    "evict_dropped": jnp.maximum(nasks - room, 0),
-                    "evict_arriving": tally(ok & ~resd[task]),
-                    "evict_retried": tally(ok & (bound_at >= 0)),
-                }
-                member = lambda nodes: (
-                    (ar_N[:, None] == nodes) & (nodes >= 0)
-                ).any(-1)
-                left = member(leave)
-                state = state._replace(
-                    used=jnp.where(left[None, :], 0.0, state.used)
-                )
-                rq = rq._replace(
-                    t_id=jnp.where(hr, -1, rq.t_id),
-                    t_node=jnp.where(hr, -1, rq.t_node),
-                    t_relb=jnp.where(hr, BIG, rq.t_relb),
-                    owed=rq.owed - (hr & (rq.t_relb < BIG)).sum(dtype=jnp.int32),
-                    ids=cat_ids[:RB], prio=cat_prio[:RB], dur=cat_dur[:RB],
-                    ev_at=cat_ev[:RB], count=rq.count + jnp.minimum(nasks, room),
-                    dropped=rq.dropped + jnp.maximum(nasks - room, 0),
-                )
-                ev = ev._replace(
-                    down=(ev.down | left) & ~member(back),
-                    log=jax.lax.dynamic_update_slice(
-                        ev.log, rows, (0, jnp.minimum(logged, cap - E))),
-                    n=ev.n + jnp.stack(
-                        [delta.get(c, jnp.int32(0)) for c in _EV_COUNTERS]),
-                )
+                gang, nasks, room, queue = join(rq, ok, task, b)
+                with stage("ksim.evict/Write"):
+                    rows = jnp.stack([
+                        jnp.where(ok, b, -1), jnp.where(ok, task, -1), node,
+                        bound_at,
+                    ])
+                    logged = ev.n[_EV["logged"]]
+                    lost = jnp.maximum(hits - E, 0) + jnp.where(
+                        (logged + E > cap) & (hits > 0), hits, 0)
+                    tally = lambda m: m.sum(dtype=jnp.int32)
+                    delta = {
+                        "logged": tally(ok), "lost": lost,
+                        "evictions": tally(ok),
+                        "evict_gang": tally(ok & gang),
+                        "evict_dropped": jnp.maximum(nasks - room, 0),
+                        "evict_arriving": tally(ok & ~resd[task]),
+                        "evict_retried": tally(ok & (bound_at >= 0)),
+                    }
+                    member = lambda nodes: (
+                        (ar_N[:, None] == nodes) & (nodes >= 0)
+                    ).any(-1)
+                    left = member(leave)
+                    state = state._replace(
+                        used=jnp.where(left[None, :], 0.0, state.used)
+                    )
+                    rq = rq._replace(
+                        t_id=jnp.where(hr, -1, rq.t_id),
+                        t_node=jnp.where(hr, -1, rq.t_node),
+                        t_relb=jnp.where(hr, BIG, rq.t_relb),
+                        owed=rq.owed
+                        - (hr & (rq.t_relb < BIG)).sum(dtype=jnp.int32),
+                    )
+                rq = cut(rq, queue, nasks, room)
+                with stage("ksim.evict/Write"):
+                    ev = ev._replace(
+                        down=(ev.down | left) & ~member(back),
+                        log=jax.lax.dynamic_update_slice(
+                            ev.log, rows, (0, jnp.minimum(logged, cap - E))),
+                        n=ev.n + jnp.stack(
+                            [delta.get(c, jnp.int32(0)) for c in _EV_COUNTERS]),
+                    )
                 return state, vassign, rq, ev
 
             fn_v = jax.vmap(
@@ -3386,7 +3406,10 @@ class WhatIfEngine:
         of the list, so that its rank rounds stay few), and ``join(rq, go,
         task, b)`` queues the non-gang ones behind what is there, as far as
         there is room, one stable sort by priority: ``(gang, nasks, room,
-        the sorted ids / prio / dur / ev_at, to be cut to the buffer)``."""
+        the sorted ids / prio / dur / ev_at)``, which ``cut(rq, queue,
+        nasks, room)`` cuts to the buffer. Each under its own sub-scope of
+        ``ksim.evict`` (``/Rewind``, ``/Join``): a traced run splits the
+        program by them (``utils.profiling.SUB_STAGES``)."""
         stg = self._dev_rel_stage
         RB = self.retry_buffer
         NONE = jnp.iinfo(jnp.int32).max
@@ -3402,35 +3425,47 @@ class WhatIfEngine:
             0, 1).reshape(a.shape)
 
         def rewind(state, node, task):
-            none_i = jnp.full((width, 1), PAD, jnp.int32)
-            state, _, _ = rel_core(
-                state, deal(node), deal(req_t[task]), deal(mgt[task]),
-                deal(antit[task]) if want_an else none_i,
-                deal(preft[task]) if want_pf else none_i,
-                deal(prefwt[task]) if want_pf
-                else jnp.zeros((width, 1), jnp.float32),
-                axis_name=_EVICT_VMAP,
-            )
+            with stage("ksim.evict/Rewind"):
+                none_i = jnp.full((width, 1), PAD, jnp.int32)
+                state, _, _ = rel_core(
+                    state, deal(node), deal(req_t[task]), deal(mgt[task]),
+                    deal(antit[task]) if want_an else none_i,
+                    deal(preft[task]) if want_pf else none_i,
+                    deal(prefwt[task]) if want_pf
+                    else jnp.zeros((width, 1), jnp.float32),
+                    axis_name=_EVICT_VMAP,
+                )
             return state
 
         def join(rq, go, task, b):
-            gang = gang_t[task]
-            asks = go & ~gang
-            room = RB - rq.count
-            take = asks & (jnp.cumsum(asks.astype(jnp.int32)) <= room)
-            nasks = asks.sum(dtype=jnp.int32)
-            cat_ids = jnp.concatenate([rq.ids, jnp.where(take, task, -1)])
-            cat_prio = jnp.concatenate([rq.prio, priot[task]])
-            key = jnp.where(cat_ids >= 0, -cat_prio, NONE)
-            _, *queue = jax.lax.sort(
-                (key, cat_ids, cat_prio,
-                 jnp.concatenate([rq.dur, durt[task]]),
-                 jnp.concatenate([rq.ev_at, jnp.full((width,), b, jnp.int32)])),
-                num_keys=1, is_stable=True,
-            )
+            with stage("ksim.evict/Join"):
+                gang = gang_t[task]
+                asks = go & ~gang
+                room = RB - rq.count
+                take = asks & (jnp.cumsum(asks.astype(jnp.int32)) <= room)
+                nasks = asks.sum(dtype=jnp.int32)
+                cat_ids = jnp.concatenate([rq.ids, jnp.where(take, task, -1)])
+                cat_prio = jnp.concatenate([rq.prio, priot[task]])
+                key = jnp.where(cat_ids >= 0, -cat_prio, NONE)
+                _, *queue = jax.lax.sort(
+                    (key, cat_ids, cat_prio,
+                     jnp.concatenate([rq.dur, durt[task]]),
+                     jnp.concatenate(
+                         [rq.ev_at, jnp.full((width,), b, jnp.int32)])),
+                    num_keys=1, is_stable=True,
+                )
             return gang, nasks, room, queue
 
-        return rewind, join
+        def cut(rq, queue, nasks, room):
+            ids, prio, dur, ev_at = queue
+            with stage("ksim.evict/Join"):
+                return rq._replace(
+                    ids=ids[:RB], prio=prio[:RB], dur=dur[:RB],
+                    ev_at=ev_at[:RB], count=rq.count + jnp.minimum(nasks, room),
+                    dropped=rq.dropped + jnp.maximum(nasks - room, 0),
+                )
+
+        return rewind, join, cut
 
     def _evict_budget_fn(self):
         """``_evict_fn`` for a batch under disruption budgets (the same
@@ -3477,7 +3512,7 @@ class WhatIfEngine:
             ar_L = jnp.arange(L, dtype=jnp.int32)
             ar_N = jnp.arange(N, dtype=jnp.int32)
             ar_A = jnp.arange(A, dtype=jnp.int32)
-            rewind, join = self._evict_tail(Ea)
+            rewind, join, cut = self._evict_tail(Ea)
             tally = lambda m: m.sum(dtype=jnp.int32)
             K = _LK
 
@@ -3501,16 +3536,17 @@ class WhatIfEngine:
                 _, _, hits, ok, at, task, walk = evict_search(
                     vassign, relb_pos >= b, rq.t_node, rq.t_relb >= b,
                     task_pos, rq.t_id, nodes, on_l, E)
-                forced = ((walk[:, None] == ar_L) & forced_l).any(-1)
-                # the anchor's order: the forced before the asking, an
-                # entry's place in the list, then the task's id
-                turn = jnp.where(ok, jnp.where(forced, walk, L + walk), 2 * L)
-                turn, task, at = jax.lax.sort(
-                    (turn, task, at), num_keys=2, is_stable=False
-                )
-                ok = turn < 2 * L
-                walk = jnp.where(ok, turn % L, 0)
-                asking = ok & (turn >= L)
+                with stage("ksim.evict/Sort"):
+                    forced = ((walk[:, None] == ar_L) & forced_l).any(-1)
+                    # the anchor's order: the forced before the asking, an
+                    # entry's place in the list, then the task's id
+                    turn = jnp.where(ok, jnp.where(forced, walk, L + walk), 2 * L)
+                    turn, task, at = jax.lax.sort(
+                        (turn, task, at), num_keys=2, is_stable=False
+                    )
+                    ok = turn < 2 * L
+                    walk = jnp.where(ok, turn % L, 0)
+                    asking = ok & (turn >= L)
                 with stage("ksim.evict/Budget"):
                     app = jnp.where(ok, app_t[task], -1)
                     of_app = (app[:, None] == ar_A)
@@ -3536,99 +3572,100 @@ class WhatIfEngine:
                     app_a = jnp.where(go, app_t[task_a], -1)
                     unavail = ev.unavail + (app_a[:, None] == ar_A).sum(
                         0, dtype=jnp.int32)
-                task = jnp.where(go, task_a, 0)
-                kind_a = jnp.where(
-                    turn_a >= L, EVICT_KINDS["voluntary"], jnp.where(
-                        kind[walk_a] == K["failure"], EVICT_KINDS["failure"],
-                        EVICT_KINDS["deadline"]))
-                node = jnp.where(go, nodes[walk_a], -1)
-                bound_at = jnp.where(go & (at_a >= V), (at_a - V) // RB, -1)
-                # the binds go where they stand: only the admitted
-                # (a place that is not written gets an index of its own past
-                # the end: dropped, and the indices stay unique)
-                past = jnp.arange(Ea, dtype=jnp.int32)
-                v_at = jnp.where(go & (at_a < V), at_a, V + past)
-                vassign = vassign.at[v_at].set(
-                    jnp.where(gang_pos[jnp.clip(v_at, 0, V - 1)], -2, PAD
-                              ).astype(vassign.dtype),
-                    mode="drop", unique_indices=True,
-                )
-                r_at = jnp.where(go & (at_a >= V), at_a - V,
-                                 rq.t_id.size + past)
-                gone = jnp.zeros((rq.t_id.size,), bool).at[r_at].set(
-                    True, mode="drop", unique_indices=True
-                ).reshape(rq.t_id.shape)
+                with stage("ksim.evict/Write"):
+                    task = jnp.where(go, task_a, 0)
+                    kind_a = jnp.where(
+                        turn_a >= L, EVICT_KINDS["voluntary"], jnp.where(
+                            kind[walk_a] == K["failure"], EVICT_KINDS["failure"],
+                            EVICT_KINDS["deadline"]))
+                    node = jnp.where(go, nodes[walk_a], -1)
+                    bound_at = jnp.where(go & (at_a >= V), (at_a - V) // RB, -1)
+                    # the binds go where they stand: only the admitted
+                    # (a place that is not written gets an index of its own past
+                    # the end: dropped, and the indices stay unique)
+                    past = jnp.arange(Ea, dtype=jnp.int32)
+                    v_at = jnp.where(go & (at_a < V), at_a, V + past)
+                    vassign = vassign.at[v_at].set(
+                        jnp.where(gang_pos[jnp.clip(v_at, 0, V - 1)], -2, PAD
+                                  ).astype(vassign.dtype),
+                        mode="drop", unique_indices=True,
+                    )
+                    r_at = jnp.where(go & (at_a >= V), at_a - V,
+                                     rq.t_id.size + past)
+                    gone = jnp.zeros((rq.t_id.size,), bool).at[r_at].set(
+                        True, mode="drop", unique_indices=True
+                    ).reshape(rq.t_id.shape)
                 state = rewind(state, node, task)
-                gang, nasks, room, (cat_ids, cat_prio, cat_dur, cat_ev) = join(
-                    rq, go, task, b)
-                rows = jnp.stack([
-                    jnp.where(go, b, -1), jnp.where(go, task, -1), node,
-                    bound_at, jnp.where(go, kind_a, -1),
-                ])
-                logged = ev.n[_EV["logged"]]
-                lost = jnp.maximum(hits - E, 0) + jnp.maximum(
-                    n_admit - Ea, 0) + jnp.where(
-                    (logged + Ea > cap) & (n_admit > 0), n_admit, 0)
-                delta = {
-                    "logged": tally(go), "lost": lost,
-                    "evictions": tally(go),
-                    "evict_gang": tally(go & gang),
-                    "evict_dropped": jnp.maximum(nasks - room, 0),
-                    "evict_arriving": tally(go & ~resd[task]),
-                    "evict_retried": tally(go & (bound_at >= 0)),
-                }
-                # -- the nodes: who goes out, who stays cordoned, who is back
-                empty_l = asks_l & (held_l == lost_l)
-                out_l = dead_l | empty_l
-                gone_l = fail_l & ~in_ups
-                member = lambda flag: (
-                    (ar_N[:, None] == nodes) & flag
-                ).any(-1)
-                backm = ((ar_N[:, None] == back) & (back >= 0)).any(-1) | (
-                    ev.until == b)
-                m_out, m_gone = member(out_l), member(gone_l)
-                state = state._replace(used=jnp.where(
-                    (m_out | member(fail_l))[None, :], 0.0, state.used
-                ))
-                until = jnp.where(backm, -1, ev.until)
-                until = jnp.where(m_out, b + out_for, until)
-                until = jnp.where(m_gone, BIG, until)
-                bdelta = {
-                    "evict_voluntary": tally(
-                        go & (kind_a == EVICT_KINDS["voluntary"])),
-                    "evict_forced_deadline": tally(
-                        go & (kind_a == EVICT_KINDS["deadline"])),
-                    "evict_forced_failure": tally(
-                        go & (kind_a == EVICT_KINDS["failure"])),
-                    "evict_deferred": tally(ok & ~admit),
-                    "nodes_drained": tally(empty_l) + tally(
-                        dead_l & (held_l == 0)),
-                    "nodes_forced": tally(dead_l & (held_l > 0)),
-                }
-                bn = ev.bn + jnp.stack(
-                    [bdelta.get(c, jnp.int32(0)) for c in BUDGET_COUNTERS])
-                rq = rq._replace(
-                    t_id=jnp.where(gone, -1, rq.t_id),
-                    t_node=jnp.where(gone, -1, rq.t_node),
-                    t_relb=jnp.where(gone, BIG, rq.t_relb),
-                    owed=rq.owed - (gone & (rq.t_relb < BIG)).sum(dtype=jnp.int32),
-                    ids=cat_ids[:RB], prio=cat_prio[:RB], dur=cat_dur[:RB],
-                    ev_at=cat_ev[:RB], count=rq.count + jnp.minimum(nasks, room),
-                    dropped=rq.dropped + jnp.maximum(nasks - room, 0),
-                )
-                ev = ev._replace(
-                    down=(ev.down & ~backm) | m_out | m_gone | member(
-                        asks_l & ~empty_l),
-                    log=jax.lax.dynamic_update_slice(
-                        ev.log, rows, (0, jnp.minimum(logged, cap - Ea))),
-                    n=ev.n + jnp.stack(
-                        [delta.get(c, jnp.int32(0)) for c in _EV_COUNTERS]),
-                    until=until,
-                    out_at=jnp.where(m_out | member(fail_l & cord_l), b,
-                                     ev.out_at),
-                    unavail=unavail,
-                    bn=bn.at[_BN["budget_spent_max"]].max(unavail.sum()),
-                )
+                gang, nasks, room, queue = join(rq, go, task, b)
+                with stage("ksim.evict/Write"):
+                    rows = jnp.stack([
+                        jnp.where(go, b, -1), jnp.where(go, task, -1), node,
+                        bound_at, jnp.where(go, kind_a, -1),
+                    ])
+                    logged = ev.n[_EV["logged"]]
+                    lost = jnp.maximum(hits - E, 0) + jnp.maximum(
+                        n_admit - Ea, 0) + jnp.where(
+                        (logged + Ea > cap) & (n_admit > 0), n_admit, 0)
+                    delta = {
+                        "logged": tally(go), "lost": lost,
+                        "evictions": tally(go),
+                        "evict_gang": tally(go & gang),
+                        "evict_dropped": jnp.maximum(nasks - room, 0),
+                        "evict_arriving": tally(go & ~resd[task]),
+                        "evict_retried": tally(go & (bound_at >= 0)),
+                    }
+                    # -- the nodes: who goes out, who stays cordoned, who is back
+                    empty_l = asks_l & (held_l == lost_l)
+                    out_l = dead_l | empty_l
+                    gone_l = fail_l & ~in_ups
+                    member = lambda flag: (
+                        (ar_N[:, None] == nodes) & flag
+                    ).any(-1)
+                    backm = ((ar_N[:, None] == back) & (back >= 0)).any(-1) | (
+                        ev.until == b)
+                    m_out, m_gone = member(out_l), member(gone_l)
+                    state = state._replace(used=jnp.where(
+                        (m_out | member(fail_l))[None, :], 0.0, state.used
+                    ))
+                    until = jnp.where(backm, -1, ev.until)
+                    until = jnp.where(m_out, b + out_for, until)
+                    until = jnp.where(m_gone, BIG, until)
+                    bdelta = {
+                        "evict_voluntary": tally(
+                            go & (kind_a == EVICT_KINDS["voluntary"])),
+                        "evict_forced_deadline": tally(
+                            go & (kind_a == EVICT_KINDS["deadline"])),
+                        "evict_forced_failure": tally(
+                            go & (kind_a == EVICT_KINDS["failure"])),
+                        "evict_deferred": tally(ok & ~admit),
+                        "nodes_drained": tally(empty_l) + tally(
+                            dead_l & (held_l == 0)),
+                        "nodes_forced": tally(dead_l & (held_l > 0)),
+                    }
+                    bn = ev.bn + jnp.stack(
+                        [bdelta.get(c, jnp.int32(0)) for c in BUDGET_COUNTERS])
+                    rq = rq._replace(
+                        t_id=jnp.where(gone, -1, rq.t_id),
+                        t_node=jnp.where(gone, -1, rq.t_node),
+                        t_relb=jnp.where(gone, BIG, rq.t_relb),
+                        owed=rq.owed
+                        - (gone & (rq.t_relb < BIG)).sum(dtype=jnp.int32),
+                    )
+                rq = cut(rq, queue, nasks, room)
+                with stage("ksim.evict/Write"):
+                    ev = ev._replace(
+                        down=(ev.down & ~backm) | m_out | m_gone | member(
+                            asks_l & ~empty_l),
+                        log=jax.lax.dynamic_update_slice(
+                            ev.log, rows, (0, jnp.minimum(logged, cap - Ea))),
+                        n=ev.n + jnp.stack(
+                            [delta.get(c, jnp.int32(0)) for c in _EV_COUNTERS]),
+                        until=until,
+                        out_at=jnp.where(m_out | member(fail_l & cord_l), b,
+                                         ev.out_at),
+                        unavail=unavail,
+                        bn=bn.at[_BN["budget_spent_max"]].max(unavail.sum()),
+                    )
                 return state, vassign, rq, ev
 
             fn_v = jax.vmap(
@@ -5264,15 +5301,16 @@ class WhatIfEngine:
                     if evicting:  # an evicted re-tried bind left the record
                         standing = standing - retry_per["evict_retried"]
                     assignments, bind_boundary, merged, handback_bytes = (
-                        self._handback_retry(vassign_d, rq_d, standing)
+                        self._handback_retry(span, vassign_d, rq_d, standing)
                     )
                     retry_per["handback_merged"] = merged
                     if evicting:
                         eviction_log = self._handback_log(
-                            ev_d, retry_per["evictions"])
+                            span, ev_d, retry_per["evictions"])
                         handback_bytes += int(eviction_log.nbytes)
                     if evicting and self._budget_on:
-                        node_out_at = self._fetch(ev_d.out_at)
+                        node_out_at = self._fetch_answer(
+                            span, "node_out_at", ev_d.out_at)
                         handback_bytes += int(node_out_at.nbytes)
             elif self.collect_assignments and dev_rel:
                 # The device-release path's placements: the wave-order buffer

@@ -11,7 +11,6 @@
   spans never change results (pinned in tests/test_telemetry.py).
   The names a span can carry are ``sim.telemetry``'s, beside
   ``PHASE_NAMES``: ``HOST_SPAN_NAMES`` / ``CHUNK_SPAN`` / ``ROOT_SPANS``.
-- ``live_buffer_stats()``: live-buffer / memory watermark gauge.
 - ``STAGES`` / ``stage(name)``: the one vocabulary of the device programs'
   stages, written into the HLO by ``jax.named_scope`` (trace-time metadata,
   no op). Beneath ``ksim.filter_score`` a plugin's own work sits under its
@@ -58,6 +57,12 @@ STAGES = (
 SUB_STAGES = (
     "ksim.evict/Search",  # the eviction program's candidate search (sim.whatif.evict_search)
     "ksim.evict/Budget",  # the eviction program's admission under disruption budgets
+    "ksim.evict/Sort",    # the candidates sorted into BoundaryOps.evict_node's order
+    "ksim.evict/Rewind",  # the leaving tasks' rows read by task, the release core
+    "ksim.evict/Join",    # the queue's join, its stable sort, the cut to the buffer
+    "ksim.evict/Write",   # binds cleared where they stand, the node planes, the log
+    "ksim.retry/Gather",  # the pass program's slot gathers over the queue's ids
+    "ksim.retry/Record",  # the pass's row written into the record, its counters
 )
 
 #: Scopes that wrap whole wave steps, not a stage of one: an instruction
@@ -151,39 +156,6 @@ def make_span(timers=None) -> Span:
     return Span(annotation, timers)
 
 
-def live_buffer_stats(collect: bool = True) -> dict:
-    """Live-buffer / memory watermark gauge (round 12): the count and
-    total bytes of ``jax.live_arrays()`` — the same counter machinery as
-    tests/test_donation.py's leak pin — plus the backend's
-    ``peak_bytes_in_use`` watermark where it reports one (TPU/GPU; CPU
-    devices return nothing and the key is simply absent). ``collect``
-    runs ``gc.collect()`` first so the count reflects reachable buffers,
-    not garbage awaiting a cycle — skip it on hot paths."""
-    try:
-        import jax
-
-        if collect:
-            import gc
-
-            gc.collect()
-        arrs = jax.live_arrays()
-        out: dict = {
-            "count": len(arrs),
-            "bytes": int(
-                sum(int(getattr(a, "nbytes", 0) or 0) for a in arrs)
-            ),
-        }
-    except Exception:
-        return {}
-    try:
-        ms = jax.local_devices()[0].memory_stats()
-        if ms and "peak_bytes_in_use" in ms:
-            out["peak_bytes_in_use"] = int(ms["peak_bytes_in_use"])
-    except Exception:
-        pass
-    return out
-
-
 @contextlib.contextmanager
 def device_trace(log_dir: Optional[str]):
     """``jax.profiler.trace(log_dir)`` with ``KSIM_PROFILE_DIR`` set to it
@@ -263,7 +235,9 @@ def parse_stage_table(hlo_text: str) -> Dict[str, str]:
         paths = _STAGE_PATH.findall(op.group(1)) if op else ()
         path = paths[-1] if paths else ""
         outer = next((p for p in paths[:-1] if p in PASSES), None)
-        table[m.group(1)] = f"{outer}/{path}" if outer else path
+        if outer and path != outer and not path.startswith(outer + "/"):
+            path = f"{outer}/{path}"
+        table[m.group(1)] = path
     return table
 
 
@@ -303,7 +277,9 @@ def stage_tables() -> Dict[str, Dict[str, str]]:
     ``Lowered.compile()`` hands back the executable the process already
     holds for the module unless it is given compiler options: it gets one
     at its default value. That costs one compile per program and cache
-    directory. Instruction names are the same in all of them: metadata
+    directory (a later call in the same run loads what the first compiled:
+    0.4-0.6 s for the seven to nine programs of a retry cell on the chip,
+    PERF.md §5). Instruction names are the same in all of them: metadata
     steers no pass."""
     import jax
 

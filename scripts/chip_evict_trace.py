@@ -5,8 +5,13 @@ beside its two lines what the benchmark does not print (PR 50):
 
 * the eviction program's device time split by stage path
   (``ksim.evict/Search``, ``ksim.evict/Budget``, the rest under
-  ``ksim.evict``): ``benchmark/layer_metrics/_stages.py`` splits the chunk
-  programs only;
+  ``ksim.evict``). Since PR 52 the ledger holds this split a boundary, in
+  both eviction cells: ``evict_search_`` / ``evict_sort_`` /
+  ``evict_rewind_`` / ``evict_join_`` / ``evict_write_ms_per_boundary``,
+  ``budget_admit_ms_per_boundary`` and ``evict_unscoped_share``
+  (``benchmark/layer_metrics/_program_stages.py``; its stderr lines name
+  every path). The script keeps its own join: it also runs trees (``--root``)
+  whose benchmark has no such reader;
 * the program's fifteen largest ops with their stage;
 * the sha256 of every answer the last batch handed back, ``summary()["retry"]``
   and the sizes the program was compiled for: two trees on one seed have to

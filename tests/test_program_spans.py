@@ -3,8 +3,9 @@ a real ``jax.profiler.trace``, read back through the benchmark's reader
 (``benchmark/layer_metrics/_program_spans.py``): a rehearsal-size engine of
 each kind the benchmark's cells run (the single replay with completions, the
 what-if on the device-release path, the arrivals-only what-if, the meshed
-what-if over 4 of conftest's host devices), three armed batches each, the
-engine's first among them."""
+what-if over 4 of conftest's host devices, the budgeted drain with its retry
+buffer and four answers), three armed batches each, the engine's first among
+them."""
 
 import collections
 import contextlib
@@ -37,7 +38,11 @@ CELLS = {  # cell: (root, the phases a batch of it has to tick)
         "stage", "dispatch", "device_wait", "gather", "handback"}),
     "multitenant-mesh4": ("whatif_run", {
         "stage", "dispatch", "device_wait", "gather", "handback"}),
+    "borg10k-budget128": ("whatif_run", {
+        "stage", "dispatch", "device_wait", "boundary_fold", "gather",
+        "handback"}),
 }
+RETRY_ANSWERS = ("assignments", "bind_boundary", "eviction_log", "node_out_at")
 BATCHES = 3
 
 
@@ -152,6 +157,36 @@ def test_the_mesh_spans_carry_the_bytes_the_summary_counts(traced):
         assert mesh["fetch_bytes"] == res.assignments.nbytes
 
 
+def test_the_retry_handback_is_a_wait_and_one_fetch_an_answer(traced):
+    """Inside ``handback`` of a batch with a ``retry_buffer``: the hand-back
+    program's ``handback_wait``, then one ``handback_fetch`` an answer in the
+    order they come to the host, each naming its answer and its bytes; the
+    two metrics over them read the trace, and together hold most of the
+    span. No other batch writes either name."""
+    names = {e[0] for e in traced.spans["events"]}
+    if traced.cell != "borg10k-budget128":
+        assert not names & {"handback_wait", "handback_fetch"}
+        return
+    read = lambda m: bench.load_part("layer_metrics", m).read(dict(traced.ctx))
+    for batch, res in zip(traced.spans["batches"], traced.results):
+        of = lambda n: [e for e in batch["children"] if e[0] == n]
+        (handback,), (wait,) = of("handback"), of("handback_wait")
+        fetches = of("handback_fetch")
+        assert inside(wait, handback) and all(
+            inside(f, handback) and f[1] >= wait[1] + wait[2] for f in fetches)
+        assert tuple(f[4]["answer"] for f in fetches) == RETRY_ANSWERS
+        for f in fetches:
+            answer = getattr(res, f[4]["answer"])
+            # the log comes whole to a multiple of 1,024 rows and is cut here
+            assert f[4]["bytes"] >= answer.nbytes if (
+                f[4]["answer"] == "eviction_log") else (
+                f[4]["bytes"] == answer.nbytes)
+    wait_ms, fetch_ms = (read(f"retry_handback_{k}_ms_per_batch")
+                         for k in ("wait", "fetch"))
+    whole = read("budget_handback_ms_per_batch")
+    assert 0 < wait_ms and 0 < fetch_ms and wait_ms + fetch_ms <= whole
+
+
 def test_armed_and_unarmed_answer_the_same(traced):
     want = traced.adapter.answers(traced.unarmed)
     for res in traced.results:
@@ -159,6 +194,14 @@ def test_armed_and_unarmed_answer_the_same(traced):
         assert got["placed"] == want["placed"]
         assert got["unschedulable"] == want["unschedulable"]
         np.testing.assert_array_equal(got["assignments"], want["assignments"])
+        if traced.cell == "borg10k-budget128":  # every answer, byte for byte
+            for answer in RETRY_ANSWERS:
+                a, b = getattr(res, answer), getattr(traced.unarmed, answer)
+                assert a.dtype == b.dtype and a.tobytes() == b.tobytes(), answer
+            summary = lambda r: {
+                k: v for k, v in r.fleet_telemetry.summary().items()
+                if k != "phases"}
+            assert summary(res) == summary(traced.unarmed)
 
 
 def test_the_new_metrics_read_the_rehearsal(traced):
@@ -178,10 +221,11 @@ class Spy:
     """In ``jax.profiler.TraceAnnotation``'s place: what was opened, inside
     what, and what is still open."""
 
-    opened, stack = [], []
+    opened, stack, counts = [], [], []
 
     def __init__(self, name, **counts):
         self.name = name
+        Spy.counts.append((name, counts))
 
     def __enter__(self):
         Spy.opened.append((self.name, tuple(Spy.stack)))
@@ -227,10 +271,47 @@ def test_a_call_that_raises_mid_chunk_leaves_no_span_open(
     profiling._PROGRAMS.clear()
 
 
+def test_each_handback_fetch_carries_the_bytes_its_copy_brought(
+        monkeypatch, tmp_path):
+    """``bytes`` on a ``handback_fetch`` span is the ``nbytes`` of the array
+    that very ``_fetch`` handed the host (the log's before the host cuts it
+    to the longest scenario's), and the spans open inside ``handback``."""
+    from kubernetes_simulator_tpu.sim.whatif import WhatIfEngine
+
+    _, _, config, traffic = bench.load_cell("borg10k-budget128")
+    _, _, adapter = bench.prepare(config, traffic, 11, True, {})
+    adapter.batch()
+    monkeypatch.setenv("KSIM_PROFILE_DIR", str(tmp_path))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+    for attr in ("opened", "stack", "counts"):
+        monkeypatch.setattr(Spy, attr, [])
+    fetch, brought = WhatIfEngine._fetch, []
+
+    def fetch_and_keep(self, x):
+        out = fetch(self, x)
+        if Spy.stack[-1:] == ["handback_fetch"]:
+            brought.append(out.nbytes)
+        return out
+
+    monkeypatch.setattr(WhatIfEngine, "_fetch", fetch_and_keep)
+    res = adapter.batch()
+    profiling._PROGRAMS.clear()
+    root = Spy.opened[0][0]
+    inside_handback = [n for n, stack in Spy.opened
+                       if stack == (root, "handback")]
+    assert inside_handback == ["handback_wait"] + ["handback_fetch"] * 4
+    spans = [c for n, c in Spy.counts if n == "handback_fetch"]
+    assert [c["answer"] for c in spans] == list(RETRY_ANSWERS)
+    assert [c["bytes"] for c in spans] == brought
+    assert brought[0] == res.assignments.nbytes == brought[1]
+    assert brought[2] >= res.eviction_log.nbytes > 0
+    assert not Spy.stack
+
+
 def test_the_exported_names_hold_every_phase():
     assert set(telemetry.PHASE_NAMES) < set(telemetry.HOST_SPAN_NAMES)
-    assert {"checkpoint", "mesh_put", "mesh_fetch"} < set(
-        telemetry.HOST_SPAN_NAMES)
+    assert {"checkpoint", "mesh_put", "mesh_fetch", "handback_wait",
+            "handback_fetch"} < set(telemetry.HOST_SPAN_NAMES)
     kept, root = _program_spans.span_names()
     assert all(kept.match(n) for n in telemetry.HOST_SPAN_NAMES)
     assert kept.match("chunk:12") and kept.match("bench:batch:0")

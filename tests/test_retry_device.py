@@ -575,12 +575,13 @@ def test_retry_handback_that_loses_a_bind_raises(monkeypatch):
     eng, _, _ = _device_and_anchor(ec, ep, RB=8, scenarios=[Scenario()] * 2)
     handback = WhatIfEngine._handback_retry
 
-    def lossy(self, vassign_d, rq, retry_placed):
+    def lossy(self, span, vassign_d, rq, retry_placed):
         t_id = np.array(rq.t_id)
         b, j = np.argwhere(t_id[1] >= 0)[0]
         t_id[1, b, j] = PAD
         return handback(
-            self, vassign_d, rq._replace(t_id=jnp.asarray(t_id)), retry_placed)
+            self, span, vassign_d, rq._replace(t_id=jnp.asarray(t_id)),
+            retry_placed)
 
     monkeypatch.setattr(WhatIfEngine, "_handback_retry", lossy)
     with pytest.raises(RuntimeError, match="scenario 1 has"):

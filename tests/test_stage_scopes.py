@@ -3,12 +3,15 @@
 PHASE_NAMES): the join a traced run makes between device op events and the
 program's stages, and the phases that cover a replay() call."""
 
+import contextlib
+import hashlib
 import json
 import os
 import re
 import subprocess
 import sys
 import time
+from pathlib import Path
 
 import jax.numpy as jnp
 import pytest
@@ -94,6 +97,19 @@ def test_parse_stage_table_takes_the_innermost_scope():
 """
     assert profiling.parse_stage_table(retry) == {
         "fusion.5": "ksim.retry/ksim.select", "sort.2": "ksim.retry"}
+    # a pass's own sub-stage, opened inside the pass, names the pass once;
+    # a stage's sub-stage inside the stage is the innermost scope as ever
+    nested = """
+  %gather.4 = s32[8]{0} gather(%t, %i), metadata={op_name="jit(f)/vmap(ksim.retry/ksim.retry/Gather)/gather"}
+  %fusion.6 = s32[8]{0} fusion(%a), kind=kLoop, metadata={op_name="jit(f)/vmap(ksim.retry/ksim.retry/Record)/select_n"}
+  %fusion.7 = s32[]{:T(128)} fusion(%a), kind=kLoop, metadata={op_name="jit(f)/vmap()/ksim.retry/while/body/closed_call/ksim.filter_score/NodeResourcesFit/add"}
+  %sort.8 = s32[8]{0} sort(%k), metadata={op_name="jit(f)/ksim.evict/vmap(ksim.evict/Join)/sort"}
+  %broadcast.9 = s32[8]{0} broadcast(%c), metadata={op_name="jit(f)/vmap(ksim.retry)/broadcast_in_dim;jit(f)/vmap(ksim.retry)/broadcast_in_dim"}
+"""
+    assert profiling.parse_stage_table(nested) == {
+        "gather.4": "ksim.retry/Gather", "fusion.6": "ksim.retry/Record",
+        "fusion.7": "ksim.retry/ksim.filter_score/NodeResourcesFit",
+        "sort.8": "ksim.evict/Join", "broadcast.9": "ksim.retry"}
     with pytest.raises(ValueError, match="unknown stage"):
         profiling.stage("ksim.pick")
 
@@ -207,7 +223,9 @@ def test_the_eviction_programs_search_carries_its_own_scope(kind):
     profiling.register_program("jit_whatif_evict", lambda: fn.lower(*structs))
     table = profiling.stage_tables()["jit_whatif_evict"]
     profiling._PROGRAMS.clear()
-    assert set(table.values()) - {""} == {"ksim.evict", "ksim.evict/Search"} | (
+    assert set(table.values()) - {""} == {
+        "ksim.evict", "ksim.evict/Search", "ksim.evict/Sort",
+        "ksim.evict/Rewind", "ksim.evict/Join", "ksim.evict/Write"} | (
         {"ksim.evict/Budget"} if kind == "budget" else set())
     # the search's ops: a slot's two gathers are filed under it
     text = fn.lower(*structs).compile(
@@ -215,3 +233,148 @@ def test_the_eviction_programs_search_carries_its_own_scope(kind):
     assert len([m for m in re.finditer(r'op_name="([^"]*)"', text)
                 if "ksim.evict/Search" in m.group(1)
                 and m.group(1).endswith("/gather")]) >= 2
+
+
+# -- the programs around the wave step: their own sub-scopes (PR 52) ----------
+
+EVICT_SCOPES = ("ksim.evict/Sort", "ksim.evict/Rewind", "ksim.evict/Join",
+                "ksim.evict/Write")
+PASS_SCOPES = ("ksim.retry/Gather", "ksim.retry/Record")
+RETRY_PROGRAMS = ("jit_whatif_evict", "jit_per_scenario_retry",
+                  "jit_per_scenario_arrivals", "jit_whatif_handback_retry")
+
+
+def _boundary_programs(kind, tmp_path, sub_scopes=True):
+    """One armed batch of ``test_evict_search.small_engine(kind)``: (the
+    stage tables of what it registered, sha256 of the lowered text of a
+    boundary's programs and of the hand-back's). Without ``sub_scopes`` the
+    six scopes of PR 52 are not opened: the tree before it."""
+    import test_evict_search as searched
+    from kubernetes_simulator_tpu.sim import whatif
+
+    eng = searched.small_engine(kind)
+    handback, kept = eng._handback_retry, {}
+
+    def keep_the_call(*args):  # (span, vassign_d, rq, retry_placed)
+        kept["structs"] = profiling.shape_structs(args[-3:-1])
+        return handback(*args)
+
+    eng._handback_retry = keep_the_call
+    profiling._PROGRAMS.clear()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("KSIM_PROFILE_DIR", str(tmp_path))
+        if not sub_scopes:
+            mp.setattr(whatif, "stage", lambda name: (
+                contextlib.nullcontext() if name in EVICT_SCOPES + PASS_SCOPES
+                else profiling.stage(name)))
+        eng.run()
+        tables = profiling.stage_tables()
+    lowered = {name: profiling._PROGRAMS[name]() for name in RETRY_PROGRAMS[:3]}
+    lowered[RETRY_PROGRAMS[3]] = eng._run_jits["handback_retry"].lower(
+        *kept["structs"])
+    profiling._PROGRAMS.clear()
+    return tables, {name: hashlib.sha256(low.as_text().encode()).hexdigest()
+                    for name, low in lowered.items()}
+
+
+@pytest.fixture(scope="module")
+def boundary_programs(tmp_path_factory):
+    return {kind: _boundary_programs(kind, tmp_path_factory.mktemp(kind))
+            for kind in ("drain", "budget")}
+
+
+@pytest.mark.parametrize("kind", ("drain", "budget"))
+@pytest.mark.parametrize("scope", EVICT_SCOPES + PASS_SCOPES)
+def test_a_program_around_the_wave_step_files_its_sub_scope(
+        boundary_programs, scope, kind):
+    """Every sub-scope of PR 52 is a path of its own in the table of the
+    program that should carry it, in the plain and in the budgeted batch; a
+    pass's sub-scope names the pass once."""
+    tables, _ = boundary_programs[kind]
+    module = ("jit_whatif_evict" if scope in EVICT_SCOPES
+              else "jit_per_scenario_retry")
+    filed = [i for i, path in tables[module].items() if path == scope]
+    assert filed, sorted(set(tables[module].values()))
+    for table in tables.values():
+        assert not any("ksim.retry/ksim.retry" in path
+                       or "ksim.evict/ksim.evict" in path
+                       for path in table.values())
+    # the loop's wave steps still file under the pass, the upkeep under it bare
+    assert "ksim.retry/ksim.select" in tables["jit_per_scenario_retry"].values()
+    assert not any(path.startswith("ksim.retry/") for path in
+                   tables["jit_per_scenario_arrivals"].values())
+
+
+def test_the_admissions_instructions_are_what_they_were(
+        boundary_programs, tmp_path):
+    """``layer_metrics/_budget.py`` reads ``ksim.evict/Budget`` by its exact
+    path: the sub-scopes around it take no instruction from it and give it
+    none, nothing is nested inside it, and ``ksim.evict/Search`` keeps its
+    own too."""
+    tables, _ = boundary_programs["budget"]
+    before, _ = _boundary_programs("budget", tmp_path, sub_scopes=False)
+    under = lambda t, scope: {i for i, path in t["jit_whatif_evict"].items()
+                              if path == scope}
+    for scope in ("ksim.evict/Budget", "ksim.evict/Search"):
+        assert under(tables, scope) == under(before, scope) != set()
+    assert not any(path.startswith("ksim.evict/Budget/")
+                   for path in tables["jit_whatif_evict"].values())
+    assert not set(before["jit_whatif_evict"].values()) & set(EVICT_SCOPES)
+    # what the sub-scopes name was under the bare stage, or fused under none
+    named = {i for i, path in tables["jit_whatif_evict"].items()
+             if path in EVICT_SCOPES}
+    assert named <= under(before, "ksim.evict") | under(before, "")
+
+
+# sha256 of ``Lowered.as_text()`` (jax 0.9.0) of the programs of a boundary of
+# ``test_evict_search.small_engine(kind)`` and of its hand-back, on the parent
+# of PR 52 (23aecca): a scope is metadata, and the text does not print it.
+PARENT_PROGRAMS = {
+    "drain": {
+        "jit_whatif_evict":
+            "8db066e6e3041db5de07586b45286a9409583794377db500df5bd31c231fb53b",
+        "jit_per_scenario_retry":
+            "f0eec650ab1e3d49237ee1151a9a28da27d96fb1c5e52d3aad3f90842272eb85",
+        "jit_per_scenario_arrivals":
+            "ffa8e586b75963a8d1bad138d472554567caacdff7954b45d62dcf206b8b0751",
+        "jit_whatif_handback_retry":
+            "7f06c9eaa593b1d4d099c051a12842bbe7944c4285544b761f17b6829f5c51b2",
+    },
+    "budget": {
+        "jit_whatif_evict":
+            "f440d471a39c2b60bbf553963b5d8e5dc933417764c86c656e2c3d976f58355b",
+        "jit_per_scenario_retry":
+            "a5dd11e015aea6c2203a955b76a36182de2ebc755f51d2480dd8343485e06964",
+        "jit_per_scenario_arrivals":
+            "ffa8e586b75963a8d1bad138d472554567caacdff7954b45d62dcf206b8b0751",
+        "jit_whatif_handback_retry":
+            "7f06c9eaa593b1d4d099c051a12842bbe7944c4285544b761f17b6829f5c51b2",
+    },
+}
+
+
+@pytest.mark.parametrize("program", RETRY_PROGRAMS)
+@pytest.mark.parametrize("kind", ("drain", "budget"))
+def test_the_sub_scopes_leave_the_lowered_programs_as_they_were(
+        boundary_programs, kind, program):
+    _, sha = boundary_programs[kind]
+    assert sha[program] == PARENT_PROGRAMS[kind][program]
+
+
+def test_tracing_is_code_every_scope_and_span_has_a_reader():
+    """Every sub-scope of the vocabulary and both spans of the retry
+    hand-back are named by a file under ``benchmark/layer_metrics/``, and no
+    stage scope is written outside the vocabulary."""
+    from kubernetes_simulator_tpu.sim import telemetry
+
+    root = Path(__file__).resolve().parents[1]
+    readers = "".join(p.read_text() for p in sorted(
+        (root / "benchmark" / "layer_metrics").glob("*.py")))
+    for name in profiling.SUB_STAGES + ("handback_wait", "handback_fetch"):
+        assert f'"{name}"' in readers, name
+    assert {"handback_wait", "handback_fetch"} < set(telemetry.HOST_SPAN_NAMES)
+    literal = re.compile(r"named_scope\(\s*[\"']ksim\.")
+    outside = [str(p.relative_to(root)) for p in sorted(
+        (root / "kubernetes_simulator_tpu").rglob("*.py"))
+        if p.name != "profiling.py" and literal.search(p.read_text())]
+    assert not outside

@@ -682,11 +682,3 @@ def test_profiler_annotations_bit_parity(tmp_path, monkeypatch):
         np.asarray(woff.latency_p50, np.float64),
         np.asarray(won.latency_p50, np.float64),
     )
-
-
-def test_live_buffer_stats_gauge():
-    from kubernetes_simulator_tpu.utils.profiling import live_buffer_stats
-
-    s = live_buffer_stats()
-    assert isinstance(s.get("count"), int) and s["count"] >= 0
-    assert isinstance(s.get("bytes"), int) and s["bytes"] >= 0

@@ -296,7 +296,7 @@ def test_retry_handback_program_is_the_host_merge(retrying, record):
     want_merged = (want_code >= 0).sum(axis=1)
     assert want_merged.sum() == sum(len(r) for r in binds.values())
     node, code, merged, copied = eng._handback_retry(
-        jax.numpy.asarray(va),
+        profiling.make_span(), jax.numpy.asarray(va),
         rq._replace(t_id=jax.numpy.asarray(t_id),
                     t_node=jax.numpy.asarray(t_node),
                     ids=jax.numpy.asarray(ids)),
