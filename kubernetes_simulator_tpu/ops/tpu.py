@@ -280,6 +280,89 @@ def gather_slots_device(src: SlotSource, idx: jax.Array) -> PodSlot:
     )
 
 
+@jax.tree_util.register_pytree_node_class
+class PackedRows:
+    """A pytree of per-task columns (``[P, ...]`` each: ``int32``,
+    ``float32`` or ``bool``) laid side by side as ONE ``int32 [P, C]``
+    table, so that a read of many columns BY TASK ID is one gather of one
+    row an id: on the chip a gather costs by the index, not by the byte
+    (PERF.md §5, the retry pass). ``float32`` goes in and out through a
+    bitcast and ``bool`` as 0 / 1, both exact; trailing axes lie flattened
+    and a zero-wide column takes no room. The layout (``cols``: shape
+    behind the task axis and dtype a leaf) is static and rides the pytree
+    as aux data: the table is the one leaf."""
+
+    def __init__(self, table: jax.Array, treedef, cols: tuple):
+        self.table, self.treedef, self.cols = table, treedef, cols
+
+    def tree_flatten(self):
+        return (self.table,), (self.treedef, self.cols)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], *aux)
+
+    @classmethod
+    def pack(cls, tree) -> "PackedRows":
+        leaves, treedef = jax.tree.flatten(tree)
+        flat = []
+        for a in leaves:
+            if a.dtype == jnp.float32:
+                a = jax.lax.bitcast_convert_type(a, jnp.int32)
+            elif a.dtype == jnp.bool_:
+                a = a.astype(jnp.int32)
+            elif a.dtype != jnp.int32:
+                raise TypeError(f"no packed form for a {a.dtype} column")
+            flat.append(a.reshape(a.shape[0], -1))
+        return cls(
+            jnp.concatenate(flat, axis=1), treedef,
+            tuple((a.shape[1:], a.dtype.name) for a in leaves),
+        )
+
+    def take(self, ids: jax.Array):
+        """The packed tree's rows at ``ids`` (any shape, every id in
+        range): each leaf what ``leaf[ids]`` reads, dtype, shape and
+        bits."""
+        cols = jnp.moveaxis(self.table[ids], -1, 0)
+        leaves, at = [], 0
+        for tail, dtype in self.cols:
+            n = int(np.prod(tail, dtype=np.int64))
+            a = jnp.moveaxis(cols[at:at + n], 0, -1)
+            at += n
+            if dtype == "float32":
+                a = jax.lax.bitcast_convert_type(a, jnp.float32)
+            elif dtype == "bool":
+                a = a != 0
+            leaves.append(a.reshape(ids.shape + tail))
+        return jax.tree.unflatten(self.treedef, leaves)
+
+
+def slots_of_rows(rows: SlotSource, idx: jax.Array) -> PodSlot:
+    """What :func:`gather_slots_device` gives at ``idx``, from ``rows``: the
+    source's rows already read at ``clip(idx, 0)`` (``PackedRows.take``)."""
+    return PodSlot(
+        pod_id=idx.astype(jnp.int32),
+        valid=idx >= 0,
+        req=rows.requests,
+        tol_key=rows.tol_key,
+        tol_kv=rows.tol_kv,
+        tol_effect=rows.tol_effect,
+        na_req=rows.na_req,
+        na_has_req=rows.na_has_req,
+        na_pref=rows.na_pref,
+        na_pref_w=rows.na_pref_w,
+        aff_req=rows.aff_req,
+        anti_req=rows.anti_req,
+        pref_aff=rows.pref_aff,
+        pref_aff_w=rows.pref_aff_w,
+        spread_g=rows.spread_g,
+        spread_skew=rows.spread_skew,
+        spread_dns=rows.spread_dns,
+        pmg=rows.pmg,
+        group=jnp.where(idx >= 0, rows.group_id, PAD).astype(jnp.int32),
+    )
+
+
 def gather_slots(ep: EncodedPods, idx: np.ndarray) -> PodSlot:
     """Host-side gather of pod rows at ``idx`` (any leading shape); PAD ids
     become invalid slots."""

@@ -769,6 +769,24 @@ def gather_extra_device(src: ExtraSource, idx: jax.Array) -> SlotExtra:
     )
 
 
+def extra_of_rows(rows: ExtraSource, idx: jax.Array) -> SlotExtra:
+    """What :func:`gather_extra_device` gives at ``idx``, from ``rows``: the
+    source's rows already read at ``clip(idx, 0)`` (``PackedRows.take``)."""
+    ok = (idx >= 0)[..., None]
+    return SlotExtra(
+        anti_midx=jnp.where(ok, rows.anti_midx, PAD).astype(jnp.int32),
+        pref_midx=jnp.where(ok, rows.pref_midx, PAD).astype(jnp.int32),
+        tol_class=rows.tol_class,
+        na_class=rows.na_class,
+        tier=rows.tier,
+        txn=(
+            None
+            if rows.txn is None
+            else jnp.where(ok, rows.txn, jnp.asarray(_NO_TXN, jnp.int32))
+        ),
+    )
+
+
 def gather_extra(st: V3Static, idx: np.ndarray) -> SlotExtra:
     safe = np.clip(idx, 0, None)
     ok = (idx >= 0)[..., None]

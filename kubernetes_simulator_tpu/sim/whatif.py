@@ -644,6 +644,28 @@ def evict_search(vassign, live_v, t_node, live_r, task_v, t_id, nodes, on, E):
                 jnp.where(ok, walk, L))
 
 
+def queued_rows(tasks: T.PackedRows, q, wave_width: int):
+    """Everything the retry pass reads of its queue's tasks BY THEIR ID, from
+    ONE gather of one packed row a slot: ``tasks`` packs ``(SlotSource,
+    ExtraSource, the record's rows)`` (``_stage_dev_rel``'s ``rows``), ``q``
+    ``[RB]`` is the queue (``PAD`` an empty slot). ``(slots, extra, rec)``:
+    the first two what ``gather_slots_device`` / ``gather_extra_device`` give
+    at ``q`` laid out in waves (``[RB / W, W, ...]``), leaf for leaf and bit
+    for bit; ``rec`` the record's rows ``[RB, ...]``, each ``table[clip(q,
+    0)]``. A gather costs by the index on the chip, not by the byte: a column
+    at a time this read was 86 ms of a boundary at ``RB`` 8,192 (PERF.md §5)."""
+    from ..ops import tpu3 as V3
+
+    src_r, xsrc_r, rec = tasks.take(jnp.clip(q, 0))
+    waves = lambda a: a.reshape(
+        (q.shape[0] // wave_width, wave_width) + a.shape[1:]
+    )
+    slots, extra = jax.tree.map(
+        waves, (T.slots_of_rows(src_r, q), V3.extra_of_rows(xsrc_r, q))
+    )
+    return slots, extra, rec
+
+
 @dataclass
 class WhatIfResult:
     placed: np.ndarray  # [S] i32
@@ -1756,8 +1778,7 @@ class WhatIfEngine:
                     return dc, d, V3.class_masks(dc, d, st3, spec, reps)
 
                 def per_scenario_retry(
-                    dc, state, src, xsrc, mgt, antit, preft, prefwt, tbt,
-                    t_b, b, rq, ev=None, resd=None, app_t=None,
+                    dc, state, tasks, tbt, t_b, b, rq, ev=None,
                 ):
                     """The FIRST of the two programs a boundary of a batch
                     with a ``retry_buffer`` dispatches (semantics:
@@ -1770,6 +1791,11 @@ class WhatIfEngine:
                     Order here: the releases of re-tried binds that are
                     due -> the retry pass over the queue (in QueueSort
                     order since the last boundary's upkeep) and its record.
+                    ``tasks`` is everything the program reads of a queued
+                    task BY ITS ID (``_stage_dev_rel``'s ``rows``: both
+                    slot sources and the record's rows as one
+                    ``ops.tpu.PackedRows`` table), read ONCE, one row a
+                    slot of the queue.
                     ``rq`` is the scenario's ``RetryQueue``; it goes back
                     with the placed marked -1 in ``ids`` and taken off
                     ``count``, ``prio`` / ``dur`` / ``ev_at`` untouched and
@@ -1783,7 +1809,7 @@ class WhatIfEngine:
                     eviction program ran before the static releases): a
                     node that is out reads allocatable 0 here, and the
                     pass counts the evicted tasks it binds again. Under
-                    disruption budgets (``app_t``, each task's
+                    disruption budgets (the rows' ``app``, each task's
                     application) such a re-bind gives its application's
                     allowance back: ``ev.unavail`` loses one there, and the
                     next boundary's eviction program reads it."""
@@ -1835,10 +1861,10 @@ class WhatIfEngine:
                     # rewinds.
                     with stage("ksim.retry"):
                         q = rq.ids
-                        rb_waves = q.reshape(RBW, wave_width)
                         with stage("ksim.retry/Gather"):
-                            slots_r = T.gather_slots_device(src, rb_waves)
-                            extra_r = V3.gather_extra_device(xsrc, rb_waves)
+                            slots_r, extra_r, rec_r = queued_rows(
+                                tasks, q, wave_width
+                            )
                         # The queue stands at the front of its buffer (the
                         # sorts put the holes last), so the pass ends with
                         # the fullest scenario's last queued wave: a buffer
@@ -1877,7 +1903,6 @@ class WhatIfEngine:
                                 jnp.maximum(rbn, b + 1),
                                 BIG,
                             ).astype(jnp.int32)
-                            safe = jnp.clip(q, 0)
                             rq = rq._replace(
                                 ids=jnp.where(placed_r, -1, q),
                                 count=rq.count - retry_placed,
@@ -1889,7 +1914,7 @@ class WhatIfEngine:
                                 t_req=put(
                                     rq.t_req, slots_r.req.reshape(RB, -1).T
                                 ),
-                                t_mg=put(rq.t_mg, mgt[safe].T),
+                                t_mg=put(rq.t_mg, rec_r["mg"].T),
                                 owed=rq.owed
                                 + (relb < BIG).sum(dtype=jnp.int32),
                                 released=released,
@@ -1898,12 +1923,12 @@ class WhatIfEngine:
                             )
                             if want_an:
                                 rq = rq._replace(
-                                    t_an=put(rq.t_an, antit[safe].T)
+                                    t_an=put(rq.t_an, rec_r["an"].T)
                                 )
                             if want_pf:
                                 rq = rq._replace(
-                                    t_pf=put(rq.t_pf, preft[safe].T),
-                                    t_pw=put(rq.t_pw, prefwt[safe].T),
+                                    t_pf=put(rq.t_pf, rec_r["pf"].T),
+                                    t_pw=put(rq.t_pw, rec_r["pw"].T),
                                 )
                             if ev_on:
                                 back = placed_r & (rq.ev_at >= 0)
@@ -1912,7 +1937,7 @@ class WhatIfEngine:
                                 delta = {
                                     "rebound": tally(back),
                                     "rebound_same": tally(back & (rq.ev_at == b)),
-                                    "rebound_resident": tally(back & resd[safe]),
+                                    "rebound_resident": tally(back & rec_r["resd"]),
                                     "wait_sum": wait.sum(dtype=jnp.int32),
                                 }
                                 n = ev.n + jnp.stack([
@@ -1925,7 +1950,7 @@ class WhatIfEngine:
                                     ).sum(),
                                 )
                                 if bud_on:
-                                    app_r = jnp.where(back, app_t[safe], -1)
+                                    app_r = jnp.where(back, rec_r["app"], -1)
                                     ev = ev._replace(unavail=ev.unavail - (
                                         app_r[:, None] == jnp.arange(
                                             ev.unavail.shape[0], dtype=jnp.int32)
@@ -2021,9 +2046,9 @@ class WhatIfEngine:
                         )
                     return state, vassign, rq, counts
 
-                axes_retry = (0, 0) + (None,) * 9 + (0,) + (
-                    (0, None) if ev_on else ()
-                ) + ((None,) if bud_on else ())
+                axes_retry = (0, 0) + (None,) * 4 + (0,) + (
+                    (0,) if ev_on else ()
+                )
                 axes_arr = (0, 0) + (None,) * 6 + (0, 0) + (
                     (0,) if ev_on else ()
                 )
@@ -2032,7 +2057,7 @@ class WhatIfEngine:
                         per_scenario_retry, in_axes=axes_retry,
                         axis_name=_RETRY_VMAP,
                     ),
-                    axes_retry, (1, 11) + ((12,) if ev_on else ()),
+                    axes_retry, (1, 6) + ((7,) if ev_on else ()),
                 ), finalize(
                     jax.vmap(per_scenario_arrivals, in_axes=axes_arr),
                     axes_arr, (1, 8, 9),
@@ -3830,6 +3855,22 @@ class WhatIfEngine:
             stg["gang_pos"] = jnp.asarray(self.pods.group_id[task_pos] >= 0)
             stg["resd"] = jnp.asarray(self.pods.bound_node >= 0)
             stg["tb_host"] = tb_all[:nchunks]
+        if self.retry_buffer:
+            # Everything the pass program reads of a queued task BY ITS ID,
+            # side by side: the pass reads ONE row a slot of the queue.
+            core = self._release_core()
+            rec = {"mg": stg["mgt"]}
+            if core.want_an:
+                rec["an"] = stg["antit"]
+            if core.want_pf:
+                rec["pf"], rec["pw"] = stg["preft"], stg["prefwt"]
+            if self._events_dev:
+                rec["resd"] = stg["resd"]
+            if self._budget_on:
+                rec["app"] = jnp.asarray(self._budget_proto.app_of)
+            stg["rows"] = jax.jit(T.PackedRows.pack)(
+                (*self._slot_srcs, rec)
+            )
         return stg
 
     def _dcn_recover_block(self, dead_pid: int, gen: int = 0) -> dict:
@@ -4253,10 +4294,7 @@ class WhatIfEngine:
                         rounds_d = self._mesh_put(span, rounds_d)
                     if self.retry_buffer:
                         RB = self.retry_buffer
-                        mgt_d, durt_d = stg["mgt"], stg["durt"]
-                        antit_d, preft_d, prefwt_d = (
-                            stg["antit"], stg["preft"], stg["prefwt"]
-                        )
+                        durt_d = stg["durt"]
                         tbt_d, tb_c = stg["tbt"], stg["tb_c"]
                         sh_s = (
                             (lambda a: jax.device_put(
@@ -4960,17 +4998,14 @@ class WhatIfEngine:
                         # Two programs, the second dispatched on the
                         # first's arrays: nothing comes to the host between.
                         args = (
-                            dc, states, srcs[0], srcs[1], mgt_d, antit_d,
-                            preft_d, prefwt_d, tbt_d, tb_c[ci], b_c[ci], rq_d,
+                            dc, states, stg["rows"], tbt_d, tb_c[ci], b_c[ci],
+                            rq_d,
                         )
                         if evicting:
                             # the eviction program's node planes stay out of
                             # the pass program
                             planes = {"until": ev_d.until, "out_at": ev_d.out_at}
-                            args += (ev_d._replace(until=None, out_at=None),
-                                     stg["resd"])
-                            if self._budget_on:
-                                args += (evs["app_t"],)
+                            args += (ev_d._replace(until=None, out_at=None),)
                         _reg(self._retry_fn, args, "retry")
                         got = self._retry_fn(*args)
                         if evicting:

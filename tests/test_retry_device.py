@@ -792,6 +792,77 @@ def test_a_boundary_with_a_retry_buffer_is_two_programs(tmp_path, monkeypatch):
     assert not any(p.startswith("ksim.retry/") for p in second)
 
 
+class _Lowered(Exception):
+    pass
+
+
+@pytest.mark.parametrize("kind", ("backlog", "drain", "budget"))
+def test_the_pass_reads_a_queued_tasks_rows_in_one_gather(kind):
+    """The pass program reads everything it reads of a queued task BY ITS ID
+    in ONE gather (PR 53; a column at a time it was 86 ms of a boundary at
+    ``retry_buffer`` 8,192, a gather costing by the index on the chip): in its
+    lowered text the instructions under ``ksim.retry/Gather`` hold exactly
+    one, of the packed table's rows at the queues' ids, and none under
+    ``ksim.retry/Record`` reads a per-task table. The table the engine staged
+    is its own columns side by side."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    if kind == "backlog":
+        ec, ep = _contended(priorities=(0, 100, 200))
+        eng = WhatIfEngine(
+            ec, ep, _perturbed(ec.num_nodes), FrameworkConfig(), wave_width=4,
+            chunk_waves=4, retry_buffer=16, collect_assignments=True,
+        )
+    else:
+        import test_evict_search as searched
+
+        eng = searched.small_engine(kind)
+    real, kept = eng._retry_fn, {}
+
+    def lower_the_first_call(*args):
+        kept["text"] = real.lower(*args).as_text(debug_info=True)
+        raise _Lowered
+
+    lower_the_first_call.__name__ = real.__name__
+    eng._retry_fn = lower_the_first_call
+    with pytest.raises(_Lowered):
+        eng.run()
+    stg, (src, xsrc) = eng._dev_rel_stage, eng._slot_srcs
+    rows, n_tasks = stg["rows"], eng.pods.num_pods
+    rec = {"mg": stg["mgt"]}
+    if kind != "backlog":
+        rec["resd"] = stg["resd"]
+    if kind == "budget":
+        rec["app"] = eng._stage_events()["app_t"]
+    want = (src, xsrc, rec)
+    back = rows.take(jnp.arange(n_tasks))
+    assert jax.tree.structure(back) == jax.tree.structure(want)
+    for got, col in zip(jax.tree.leaves(back), jax.tree.leaves(want)):
+        assert got.dtype == col.dtype and got.shape == col.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(col))
+    C = rows.table.shape[1]
+    assert C == sum(int(np.prod(a.shape[1:])) for a in jax.tree.leaves(want))
+    # the lowered text: every gather with the scope it was traced under
+    text = kept["text"]
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    gathers = {"ksim.retry/Gather": [], "ksim.retry/Record": []}
+    for types, loc in re.findall(
+            r'"stablehlo\.gather"\(.*?: \((.*?)\) -> .*? loc\((#loc\d+)\)', text):
+        for scope in gathers:
+            if f"/{scope}/" in names.get(loc, ""):
+                gathers[scope].append(types)
+    RB = eng.retry_buffer
+    assert gathers["ksim.retry/Gather"] == [
+        f"tensor<{n_tasks}x{C}xi32>, tensor<{eng.S}x{RB}x1xi32>"]
+    assert not any(f"tensor<{n_tasks}x" in types or f"tensor<{n_tasks}>" in types
+                   for types in gathers["ksim.retry/Record"])
+    assert f"tensor<{n_tasks}x" not in text.replace(
+        f"tensor<{n_tasks}x{C}xi32>", "")  # no other per-task table comes in
+
+
 def test_the_two_programs_answer_what_the_one_program_answered():
     """Value-exact: on the contended trace with one deep scenario among four,
     both hand-back arrays and ``summary()["retry"]`` are what the tree before

@@ -329,12 +329,15 @@ def test_the_admissions_instructions_are_what_they_were(
 # sha256 of ``Lowered.as_text()`` (jax 0.9.0) of the programs of a boundary of
 # ``test_evict_search.small_engine(kind)`` and of its hand-back, on the parent
 # of PR 52 (23aecca): a scope is metadata, and the text does not print it.
+# The pass program's are PR 53's, which reads a queued task's rows in one
+# gather (until then f0eec650...2272eb85 and a5dd11e0...85e06964, 23aecca's);
+# the three programs around it keep 23aecca's text.
 PARENT_PROGRAMS = {
     "drain": {
         "jit_whatif_evict":
             "8db066e6e3041db5de07586b45286a9409583794377db500df5bd31c231fb53b",
         "jit_per_scenario_retry":
-            "f0eec650ab1e3d49237ee1151a9a28da27d96fb1c5e52d3aad3f90842272eb85",
+            "8050b0426432ede7228d1ac7bc230a68de19bb4b68282db3210be292d23c5b91",
         "jit_per_scenario_arrivals":
             "ffa8e586b75963a8d1bad138d472554567caacdff7954b45d62dcf206b8b0751",
         "jit_whatif_handback_retry":
@@ -344,7 +347,7 @@ PARENT_PROGRAMS = {
         "jit_whatif_evict":
             "f440d471a39c2b60bbf553963b5d8e5dc933417764c86c656e2c3d976f58355b",
         "jit_per_scenario_retry":
-            "a5dd11e015aea6c2203a955b76a36182de2ebc755f51d2480dd8343485e06964",
+            "4942f089d1bbc06fb05487267ad7bdba96846a64401f0b506454b32d625b9ee1",
         "jit_per_scenario_arrivals":
             "ffa8e586b75963a8d1bad138d472554567caacdff7954b45d62dcf206b8b0751",
         "jit_whatif_handback_retry":

@@ -252,12 +252,14 @@ def test_infinite_budgets_and_no_grace_answer_what_the_drain_rule_answers():
 # ``test_whatif_events_device.batch(512)`` dispatches, on the parent commit
 # (c04e4fa, PR 47): a batch without budgets has to keep them. The eviction
 # program's is PR 50's, whose one search both eviction programs call (until
-# then dcc741d2...ba75267, c04e4fa's): budgets still add nothing to it.
+# then dcc741d2...ba75267, c04e4fa's): budgets still add nothing to it. The
+# pass program's is PR 53's, whose one packed row a slot holds ``app`` under
+# budgets alone (until then 66ceeb0f...a1776d0b, c04e4fa's).
 PARENTS = {
     "jit_whatif_evict":
         "f619a16f915967c9f3490a3b1d3fa90356eb68324ad3ff00b35b55936e96bbf6",
     "jit_per_scenario_retry":
-        "66ceeb0f1885176097f4edd75a0d01be0a492cd768b9a8673a5dda4da1776d0b",
+        "fca751621fd7d8ce67ea2a29317d146183f94907071f73fa7460662a3305c49d",
     "jit_per_scenario_arrivals":
         "4737374757b7bb09c2bf9541d9a2da6678b2e08160c80ed49b30c119f056bb94",
 }
