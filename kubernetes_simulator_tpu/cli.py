@@ -228,6 +228,7 @@ def cmd_whatif(args) -> int:
         preemption=cfg.device_preemption,
         completions=cfg.whatif.completions,
         retry_buffer=cfg.whatif.retry_buffer,
+        retry_groups=cfg.whatif.retry_groups,
         collect_assignments=cfg.whatif.placements,
         telemetry=cfg.telemetry.granularity,
     )
@@ -684,7 +685,8 @@ def _wide_gang_errors(cfg, key: str, widest: int, ww: int, **on) -> list:
     pre = cfg.device_preemption
     try:
         refuse_wide_gangs(
-            ww, widest, retry_buffer=bool(cfg.whatif.retry_buffer),
+            ww, widest, retry_groups=cfg.whatif.retry_groups,
+            retry_buffer=bool(cfg.whatif.retry_buffer),
             kube_preemption=pre == "kube", tier_preemption=pre in (True, "tier"),
             **on,
         )
@@ -805,13 +807,24 @@ def validate_config(cfg) -> list:
             )
             errors += _wide_gang_errors(
                 cfg, "a gang of workload.gangSizes / gangSize", widest, ww,
-                completions=wl.duration_mean is not None,
+                completions=(wl.duration_mean is not None
+                             or bool(wl.job_durations)),
                 count_planes=bool(wl.affinity or wl.spread),
             )
     if cfg.whatif.scenarios < 0:
         errors.append("whatIf.scenarios: must be >= 0")
     if cfg.whatif.retry_buffer < 0:
         errors.append("whatIf.retryBuffer: must be >= 0")
+    if cfg.whatif.retry_groups and not (
+        cfg.whatif.retry_buffer and cfg.whatif.scenarios
+        and cfg.strategy == "jax" and not cfg.device_preemption
+    ):
+        errors.append(
+            "whatIf.retryGroups: a queue of whole jobs runs in a what-if "
+            "batch on the device retry path: needs strategy: jax, "
+            "whatIf.scenarios > 0, whatIf.retryBuffer > 0 and no "
+            "devicePreemption"
+        )
     if cfg.device_preemption not in (True, False, "tier", "kube"):
         errors.append(
             f"devicePreemption: must be true/false/'tier'/'kube', got "

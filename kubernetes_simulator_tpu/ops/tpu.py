@@ -323,7 +323,12 @@ class PackedRows:
         """The packed tree's rows at ``ids`` (any shape, every id in
         range): each leaf what ``leaf[ids]`` reads, dtype, shape and
         bits."""
-        cols = jnp.moveaxis(self.table[ids], -1, 0)
+        return self.unpack(self.table[ids])
+
+    def unpack(self, rows: jax.Array):
+        """The tree of ``rows`` (``[..., C]``, rows of the table already
+        read): :meth:`take` without its gather."""
+        cols = jnp.moveaxis(rows, -1, 0)
         leaves, at = [], 0
         for tail, dtype in self.cols:
             n = int(np.prod(tail, dtype=np.int64))
@@ -333,7 +338,7 @@ class PackedRows:
                 a = jax.lax.bitcast_convert_type(a, jnp.float32)
             elif dtype == "bool":
                 a = a != 0
-            leaves.append(a.reshape(ids.shape + tail))
+            leaves.append(a.reshape(rows.shape[:-1] + tail))
         return jax.tree.unflatten(self.treedef, leaves)
 
 

@@ -54,7 +54,7 @@ import numpy as np
 from ..framework.framework import SchedulerFramework
 from ..models.encode import PAD, EncodedCluster, EncodedPods
 from ..models.state import bind, init_state, release_delta, unbind
-from .waves import WaveBatch
+from .waves import GROUP_COUNTERS, WaveBatch, job_table
 
 # (pods, nodes) int arrays collected for device delta application.
 PairArrays = Tuple[np.ndarray, np.ndarray]
@@ -147,6 +147,7 @@ class BoundaryOps:
         kube: bool = False,
         lazy: bool = False,
         telemetry=None,
+        retry_groups: bool = False,
     ):
         if kube and not retry_buffer:
             raise ValueError(
@@ -208,6 +209,15 @@ class BoundaryOps:
         # adds the codes of the pods with no node.
         self.bind_boundary = np.full(P, -1, np.int32)
         self._dropped = np.zeros(P, bool)
+        # ``retry_groups``: a queue entry belongs to a JOB (a pod group; a
+        # pod in none is a job of one). ``job`` [P, 3] (size, place in the
+        # job, closing chunk), the chunk's rolled-back jobs waiting to join
+        # (first flat wave position, members), the counters the device
+        # keeps in ``RetryQueue.gn``.
+        self.retry_groups = bool(retry_groups)
+        self.job = job_table(ep, waves.idx, chunk_waves) if retry_groups else None
+        self._chunk_failed: List[Tuple[int, List[int]]] = []
+        self.group_counts = dict.fromkeys(GROUP_COUNTERS, 0)
         self.placed_total = 0
         self.preemptions = 0
         # [K8S] keeps every pending pod; the bounded analogue sheds load —
@@ -254,6 +264,12 @@ class BoundaryOps:
             chunk_of[flat[fv]] = np.nonzero(fv)[0] // (
                 chunk_waves * waves.idx.shape[1]
             )
+        if retry_groups:
+            # a job's members are released together: each counts as bound
+            # in the chunk that holds the job's last member
+            self._pos = np.full(P, flat.size, np.int64)
+            self._pos[flat[fv]] = np.nonzero(fv)[0]
+            chunk_of[flat[fv]] = self.job[flat[fv], 2]
         chunk_of[ep.bound_node >= 0] = -2
         elig = np.searchsorted(tb_all[:nfin], self.rel_time, side="left")
         b_rel = np.maximum(elig, chunk_of + 2)
@@ -409,13 +425,34 @@ class BoundaryOps:
     def offer_failure(self, p: int) -> None:
         """A non-gang pod that missed placement enters the buffer, behind
         the pods already there (overflow drops the newest — counted)."""
-        if not self.retry_buffer or self.ep.group_id[p] != PAD:
+        if (not self.retry_buffer or self.ep.group_id[p] != PAD
+                or self.retry_groups):  # there a JOB joins: ``offer_job``
             return
         if len(self.retry_q) < self.retry_buffer:
             self.retry_q.append(int(p))
         else:
             self.retry_dropped += 1
             self._dropped[p] = True
+
+    def offer_job(self, members: List[int]) -> None:
+        """``retry_groups``: a job that was rolled back (or a pod in no
+        group that fitted nowhere) waits to join the queue WHOLE at the
+        boundary after its closing wave (:meth:`join_failed`)."""
+        self._chunk_failed.append((int(self._pos[members[0]]), list(members)))
+
+    def join_failed(self) -> None:
+        """The jobs offered since the last boundary join the queue in
+        arrival order, each while the buffer has room for ALL its members;
+        one that finds less is dropped whole (counted) and those behind it
+        that fit still join."""
+        for _, members in sorted(self._chunk_failed):
+            if len(self.retry_q) + len(members) <= self.retry_buffer:
+                self.retry_q.extend(members)
+            else:
+                self.retry_dropped += len(members)
+                self._dropped[members] = True
+                self.group_counts["dropped_jobs"] += 1
+        self._chunk_failed = []
 
     def bind_boundary_codes(self) -> np.ndarray:
         """[P] i32 beside ``assignments``: -1 bound in its arrival wave (or
@@ -428,7 +465,8 @@ class BoundaryOps:
         on the host."""
         out = self.bind_boundary.copy()
         none = self.assignments == PAD
-        out[none] = -4
+        # under ``retry_groups`` every job that fails is queued or dropped
+        out[none] = -3 if self.retry_groups else -4
         out[none & self._evicted_gang] = -5
         out[none & self._dropped] = -3
         queued = np.asarray(self.retry_q, np.int64)
@@ -767,6 +805,63 @@ class BoundaryOps:
             return (rel_p, rel_n)
         return _empty_pairs()
 
+    def _retry_jobs(self, b: int, t_chunk: float, binds_l: list) -> None:
+        """Boundary ``b``'s pass under ``retry_groups``: the queue in kube's
+        QueueSort order with the job's creation as the tie (priority
+        descending, the job's arrival, the member's place in the job), every
+        queued JOB tried as at its arrival: members in order, each on the
+        state the binds before it give, the tentative binds of its own job
+        included, the members after a failed one still tried; the verdict
+        at its last member. Bound: its binds are committed and recorded
+        and it leaves the queue. Rolled back: what its members took is
+        given back before the next job's first member, nothing is
+        recorded, all members keep their place."""
+        ec, ep, st, job = self.ec, self.ep, self.st, self.job
+        if not self.retry_q:
+            return
+        self.flush_planes()
+        # a job's members stand in wave order from its first: the place in
+        # the waves IS (the job's arrival, the member's place in the job)
+        self.retry_q.sort(key=lambda p: (-int(ep.priority[p]), int(self._pos[p])))
+        q, still, n, i = self.retry_q, [], self.group_counts, 0
+        while i < len(q):
+            size = int(job[q[i], 0])
+            members, i = q[i : i + size], i + size
+            n["pass_attempts"] += 1
+            bound = []
+            for p in members:
+                node = self.fw.schedule_one(st, p, allow_preemption=False).node
+                if node != PAD:
+                    bind(ec, ep, st, p, node)
+                    bound.append((p, int(node)))
+            if len(bound) < size:
+                for p, _ in bound:
+                    unbind(ec, ep, st, p)
+                n["pass_rollbacks"] += 1
+                n["pass_rollbacks_after_bind"] += bool(
+                    bound and size > self.wave_width)
+                still.extend(members)
+                continue
+            n["jobs_bound_pass"] += 1
+            for p, node in bound:
+                binds_l.append((p, node))
+                self.assignments[p] = node
+                self.bind_boundary[p] = b
+                self.placed_total += 1
+                self._schedule_release(p, node, b, t_chunk)
+        self.retry_q = still
+
+    def _schedule_release(self, p: int, node: int, b: int, t_chunk: float) -> None:
+        """A bind made by boundary ``b``'s pass STARTS now, not at its
+        arrival: it is released at the first boundary whose time reaches
+        ``t_b + duration`` (f32 boundary search), at least ``b + 1``."""
+        dur = np.float32(self.ep.duration[p])
+        if np.isfinite(dur):
+            rb = int(np.searchsorted(
+                self.tb32, np.float32(t_chunk) + dur, side="left"))
+            if rb < len(self.tb32):
+                self.pend.append([max(rb, b + 1), p, int(node)])
+
     def boundary_retry(
         self, b: int, t_chunk: float
     ) -> Tuple[PairArrays, PairArrays]:
@@ -777,13 +872,16 @@ class BoundaryOps:
         tel = self.tel
         binds_l: List[Tuple[int, int]] = []
         evicts_l: List[Tuple[int, int]] = []
+        if self.retry_groups:
+            self.join_failed()
+            self._retry_jobs(b, t_chunk, binds_l)
         # 3. Bounded retry (+ kube preemption) pass in QueueSort order:
         # priority descending, then the order of entering the queue (a
         # stable sort: pods of one priority keep FIFO order). Victims
         # re-enter the walked queue and are attempted later in the SAME
         # pass — mirroring the CPU event engine, which requeues victims
         # into the activeQ at the preemption instant.
-        if self.retry_buffer and self.retry_q:
+        if self.retry_buffer and self.retry_q and not self.retry_groups:
             # The pass reads the count planes through schedule_one — any
             # logged deltas must land first (rare path; quiet runs never
             # get here and never pay a fold).
@@ -868,19 +966,7 @@ class BoundaryOps:
                         self.unavail[self.budget.app_of[p]] -= 1
                     if np.isfinite(t_chunk):
                         self._evict_lat_sum += float(t_chunk) - t_ev
-                # Release schedule: f32 boundary search, >= b+1 — the pod
-                # STARTS now, not at arrival.
-                dur = np.float32(ep.duration[p])
-                if np.isfinite(dur):
-                    rb = int(
-                        np.searchsorted(
-                            self.tb32,
-                            np.float32(t_chunk) + dur,
-                            side="left",
-                        )
-                    )
-                    if rb < len(self.tb32):
-                        self.pend.append([max(rb, b + 1), p, int(res.node)])
+                self._schedule_release(p, res.node, b, t_chunk)
             self.retry_q = still_q
         if tel is not None and tel.cfg.want_series and np.isfinite(t_chunk):
             # Post-boundary occupancy in virtual time (the device twin of

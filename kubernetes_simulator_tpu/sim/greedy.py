@@ -44,7 +44,9 @@ from ..models.encode import PAD, EncodedCluster, EncodedPods
 from ..models.state import bind, unbind
 from ..utils.metrics import fragmentation_gauges, utilization_means
 from .runtime import ReplayResult
-from .waves import WaveBatch, pack_waves, refuse_wide_gangs, wide_gang_table
+from .waves import (
+    WaveBatch, pack_waves, refuse_split_jobs, refuse_wide_gangs, wide_gang_table,
+)
 
 
 def priority_tiers(ep: EncodedPods):
@@ -117,6 +119,7 @@ def greedy_replay(
     preemption=False,
     completions_chunk_waves: Optional[int] = None,
     retry_buffer: int = 0,
+    retry_groups: bool = False,
 ) -> ReplayResult:
     """``completions_chunk_waves``: mirror the device engines' chunk-granular
     completions — before each chunk of that many waves, pods whose
@@ -137,7 +140,19 @@ def greedy_replay(
     ``bind_boundary`` says, pod by pod, which boundary's pass bound it
     (``BoundaryOps.bind_boundary_codes``). Requires
     ``completions_chunk_waves``. Mirrors WhatIfEngine(retry_buffer=...)'s
-    device semantics exactly."""
+    device semantics exactly.
+
+    ``retry_groups`` (the scheduler profile's; with ``retry_buffer``): a
+    queue entry belongs to a JOB (a pod group; a pod in none is a job of
+    one). A job that is rolled back joins the queue WHOLE at the boundary
+    after its closing wave, behind what is queued at its priority, in
+    arrival order, or is dropped whole where the buffer lacks room for all
+    its members; every pass tries each queued job again as at its arrival
+    (``BoundaryOps._retry_jobs``), a job bound at its arrival or by a pass
+    is released whole, and a group wider than the wave runs with
+    completions and the buffer. ``ReplayResult.group_counts`` holds
+    ``sim.waves.GROUP_COUNTERS``. Mirrors
+    ``WhatIfEngine(retry_groups=True)``'s device semantics exactly."""
     from .boundary import BoundaryOps
 
     from dataclasses import replace as dc_replace
@@ -151,6 +166,10 @@ def greedy_replay(
     )
     if retry_buffer and not completions_chunk_waves:
         raise ValueError("retry_buffer requires completions_chunk_waves")
+    if retry_groups and not (retry_buffer and mode is None):
+        raise ValueError("retry_groups requires retry_buffer and no preemption")
+    if retry_groups:
+        refuse_split_jobs(ep)
     if retry_buffer and mode == "tier":
         raise ValueError("retry_buffer is not supported with tier preemption")
     if mode == "kube" and not completions_chunk_waves:
@@ -164,6 +183,7 @@ def greedy_replay(
     ops = BoundaryOps(
         ec, ep, fw, waves, wave_width, completions_chunk_waves or 1,
         retry_buffer=retry_buffer, kube=mode == "kube",
+        retry_groups=retry_groups,
     )
     st = ops.st
     _, pod_tier = priority_tiers(ep)
@@ -179,10 +199,13 @@ def greedy_replay(
     if wide is not None:
         refuse_wide_gangs(
             waves.wave_width, int(wide[:, 1].max()),
+            retry_groups=retry_groups,
             completions=bool(completions_chunk_waves),
+            retry_buffer=bool(retry_buffer),
             kube_preemption=mode == "kube", tier_preemption=mode == "tier",
         )
     txn: List[int] = []  # the open wide group's members bound so far
+    txn_all: List[int] = []  # ... and every member tried so far
     txn_failed = False
     t0 = time.perf_counter()
     for wi, wave in enumerate(waves.idx):
@@ -231,10 +254,12 @@ def greedy_replay(
             and (wide is None or wide[p, 0] < 0)
         }
         closes = False
+        offered = set()
         for p, c in zip(slot_pods, slot_choice):
             if wide is not None and wide[p, 0] >= 0:
                 # Tentative: bound (above) and handed back as placed until
                 # the group's verdict.
+                txn_all.append(p)
                 if c != PAD:
                     txn.append(p)
                     assignments[p] = c
@@ -246,6 +271,17 @@ def greedy_replay(
             if p in evicted_in_wave:
                 continue  # evicted mid-wave: never committed
             g = int(ep.group_id[p])
+            if retry_groups and (g in failed_groups or c == PAD):
+                # the job (a pod in no group is a job of one) joins the
+                # queue whole at the next boundary
+                key = g if g != PAD else -1 - p
+                if key not in offered:
+                    ops.offer_job([p] if g == PAD else [
+                        q for q in slot_pods if ep.group_id[q] == g
+                    ])
+                    offered.add(key)
+            elif retry_groups and (g == PAD or ops.job[p, 1] == 0):
+                ops.group_counts["jobs_bound_arrival"] += 1
             if c != PAD and g in failed_groups:
                 unbind(ec, ep, st, p)
             elif c != PAD:
@@ -265,7 +301,13 @@ def greedy_replay(
                     unbind(ec, ep, st, p)
                     assignments[p] = PAD
                 ops.placed_total -= len(txn)
-            txn, txn_failed = [], False
+                if retry_groups:
+                    ops.offer_job(txn_all)
+            elif completions_chunk_waves:
+                # bound whole: released whole, as of its closing chunk
+                ops.bind_chunk[txn] = wi // completions_chunk_waves
+                ops.group_counts["jobs_bound_arrival"] += retry_groups
+            txn, txn_all, txn_failed = [], [], False
     if mode == "kube":
         # Trailing boundary: pods that failed in the LAST chunk still get
         # their PostFilter attempt (the CPU engine preempts at the failure
@@ -274,6 +316,8 @@ def greedy_replay(
         ops.boundary(
             -(-waves.idx.shape[0] // (completions_chunk_waves or 1)), np.inf
         )
+    if retry_groups:
+        ops.join_failed()  # the last chunk's: queued untried
     wall = time.perf_counter() - t0
     placed_total = ops.placed_total
     preemptions += ops.preemptions
@@ -297,4 +341,5 @@ def greedy_replay(
         retry_dropped=ops.retry_dropped,
         fragmentation=frag,
         bind_boundary=ops.bind_boundary_codes() if retry_buffer else None,
+        group_counts=dict(ops.group_counts) if retry_groups else None,
     )

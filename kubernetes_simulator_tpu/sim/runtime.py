@@ -220,6 +220,8 @@ class ReplayResult:
     # pass bound it, -1 for an arrival bind, -2 / -3 / -4 for a pod with no
     # node (sim.boundary.BoundaryOps.bind_boundary_codes).
     bind_boundary: Optional[np.ndarray] = None
+    # ``greedy_replay(retry_groups=True)``: ``sim.waves.GROUP_COUNTERS``.
+    group_counts: Optional[dict] = None
     # Telemetry (sim.telemetry.ReplayTelemetry) — None at granularity
     # "off". Latency histograms, rejection attribution, series, phase
     # timers; see the telemetry module docstring for cross-engine

@@ -93,6 +93,7 @@ def make_workload(
     extended_resource: Optional[Tuple[str, int, float]] = None,
     gang_sizes: Optional[dict] = None,
     job_extended_resource: Optional[dict] = None,
+    job_durations: Optional[dict] = None,
 ) -> Tuple[List[Pod], dict]:
     """Pods in arrival order with app labels; optional affinity/spread/
     toleration terms, gangs, extended-resource requests. ``gang_sizes``
@@ -102,6 +103,7 @@ def make_workload(
         return make_job_workload(
             num_pods, seed=seed, arrival_rate=arrival_rate, num_apps=num_apps,
             gang_sizes=gang_sizes, job_extended_resource=job_extended_resource,
+            job_durations=job_durations,
         )
     rng = np.random.default_rng(seed + 1)
     pods: List[Pod] = []
@@ -190,6 +192,7 @@ def make_job_workload(
     num_apps: int = 20,
     gang_sizes: Optional[dict] = None,
     job_extended_resource: Optional[dict] = None,
+    job_durations: Optional[dict] = None,
 ) -> Tuple[List[Pod], dict]:
     """A training cluster's trace, job by job: a job's worker count is drawn
     from ``gang_sizes`` (``{workers: share}``) until the pods are dealt out
@@ -202,7 +205,13 @@ def make_job_workload(
     workers ask the same count. cpu and memory requests, priority, app and
     role are :func:`make_workload`'s sets, a worker's draws in its order.
     A job's draws (size, whether it asks, the count) come before its
-    workers'."""
+    workers'. ``job_durations`` (``{"median", "mean"}`` seconds, and
+    optionally ``"diurnal"``): a job is then ONE arrival, as a standing job
+    queue wants it (``WhatIfEngine(retry_groups=True)``): every member
+    carries its job's arrival time and priority (the first member's) and
+    its job's duration, log-normal, one draw a job (a stream of its own,
+    ``seed + 2``); with ``diurnal`` the arrival rate is ``1 - diurnal *
+    cos(2 pi t / span)`` times the mean over the trace's span."""
     rng = np.random.default_rng(seed + 1)
     sizes = [int(k) for k in gang_sizes]
     shares = np.asarray([float(gang_sizes[k]) for k in gang_sizes], np.float64)
@@ -246,6 +255,23 @@ def make_job_workload(
             ))
         jobs += 1
         gangs += size > 1
+    if job_durations:
+        sigma = float(np.sqrt(2.0 * np.log(
+            job_durations["mean"] / job_durations["median"])))
+        draws = np.random.default_rng(seed + 2).lognormal(
+            np.log(job_durations["median"]), sigma, size=num_pods)
+        span, amp = num_pods / arrival_rate, float(job_durations.get("diurnal", 0))
+        grid = np.linspace(0.0, span, 1 << 16)
+        warped = grid - amp * span / (2 * np.pi) * np.sin(2 * np.pi * grid / span)
+        head = None
+        for i, pod in enumerate(pods):
+            if head is None or pod.pod_group is None or pod.pod_group != head[0]:
+                at = pod.arrival_time
+                at = float(np.interp(min(at, span), warped, grid) + max(at - span, 0.0))
+                head = (pod.pod_group, at, pod.priority,
+                        float(np.float32(draws[i])))
+            pod.arrival_time, pod.priority, pod.duration = head[1:]
+        t = pods[-1].arrival_time
     return pods, {"num_jobs": jobs, "num_gangs": gangs, "makespan": t}
 
 

@@ -156,11 +156,13 @@ PHASE_NAMES = (
 #: ``handback_fetch``, inside ``handback`` of a batch with a
 #: ``retry_buffer``: the hand-back program from its dispatch to its outputs
 #: being ready, then one span an answer brought to the host (``answer=``
-#: its name, ``bytes=`` its size). Trace readers import these and hold no
+#: its name, ``bytes=`` its size); ``retry_pass_waves``, a batch under
+#: ``retry_groups``: no time, a counter: ``waves=`` the wave steps its passes
+#: EXECUTED, ``passes=`` how many. Trace readers import these and hold no
 #: list of their own.
 HOST_SPAN_NAMES = PHASE_NAMES + (
     "checkpoint", "mesh_put", "mesh_fetch", "host_events",
-    "handback_wait", "handback_fetch",
+    "handback_wait", "handback_fetch", "retry_pass_waves",
 )
 #: ``chunk:<i>``: the dispatch of chunk ``i``, inside ``dispatch``.
 CHUNK_SPAN = "chunk"

@@ -133,10 +133,108 @@ WIDE_GANG_UNSUPPORTED = {
 }
 
 
-def refuse_wide_gangs(wave_width: int, widest: int, **on) -> None:
+# ... but for these two, together, under the scheduler profile's
+# ``retry_groups``: a rolled-back group joins the queue WHOLE, every pass tries
+# it again as one transaction of its own, and a job's members are released
+# together (``sim.whatif`` ``per_scenario_retry``; ``sim.boundary``).
+WIDE_GANG_WITH_RETRY_GROUPS = ("completions", "retry_buffer")
+
+# What ``retry_groups`` counts per scenario as the batch runs (``RetryQueue.gn``
+# on the device, ``BoundaryOps.group_counts`` on the host;
+# ``summary()["retry"]["groups"]``). How long the jobs of each size waited is
+# what the two answer arrays imply (:func:`job_waits`): no counter of the run.
+GROUP_COUNTERS = (
+    "jobs_bound_arrival",  # jobs bound in their arrival waves
+    "jobs_bound_pass",     # ... by a retry pass
+    "pass_attempts",       # a queued job tried by a pass
+    "pass_rollbacks",      # ... and rolled back
+    "pass_rollbacks_after_bind",  # ... a job WIDER than the wave after a member had bound
+    "dropped_jobs",        # jobs that found the buffer short of room for all their members
+)
+
+
+def job_table(ep: EncodedPods, idx: np.ndarray, chunk_waves: int) -> np.ndarray:
+    """``[P, 3]`` i32 per pod ``(size, pos, closing chunk)`` of its JOB (a pod
+    group; a pod in none is a job of one): the member count, the pod's place
+    among the members in the order the packer lays them out, and the chunk
+    that holds the job's last member (``1 << 30`` for a pod in no wave).
+    Static per (trace, wave width, chunk): what ``retry_groups`` reads of a
+    queued task beside :func:`wide_gang_table`'s rows."""
+    P = ep.num_pods
+    flat = idx.reshape(-1)
+    at = np.nonzero(flat >= 0)[0]
+    pods = flat[at]
+    tab = np.zeros((P, 3), np.int64)
+    tab[:, 0], tab[:, 2] = 1, 1 << 30
+    tab[pods, 2] = at // (chunk_waves * idx.shape[1])
+    gid = ep.group_id[pods]
+    g = gid >= 0
+    if g.any():
+        order = np.argsort(gid[g], kind="stable")  # job by job, wave order inside
+        mem, gm = pods[g][order], gid[g][order]
+        sizes = np.bincount(gm)
+        starts = np.cumsum(sizes) - sizes
+        tab[mem, 0] = sizes[gm]
+        tab[mem, 1] = np.arange(mem.size) - starts[gm]
+        last = np.zeros(sizes.shape[0], np.int64)
+        np.maximum.at(last, gm, tab[mem, 2])
+        tab[mem, 2] = last[gm]
+    return tab.astype(np.int32)
+
+
+def job_waits(bind_boundary: np.ndarray, job: np.ndarray) -> dict:
+    """How long the jobs a retry pass bound had waited, by JOB SIZE, from the
+    answers alone: ``bind_boundary`` ``[..., P]`` (``>= 0``: the boundary
+    whose pass bound the pod's job) and :func:`job_table`'s ``job``. A job
+    waits from the boundary after its closing chunk, so ``b - closing chunk``
+    boundaries; its first member speaks for it. ``-> {"size" [K] (the sizes
+    the trace holds, ascending), "bound_pass", "wait_sum", "wait_max"
+    [..., K]}``."""
+    heads = np.nonzero(job[:, 1] == 0)[0]
+    size = job[heads, 0]
+    bb = np.asarray(bind_boundary)[..., heads].astype(np.int64)
+    got = bb >= 0
+    wait = np.where(got, bb - job[heads, 2], 0)
+    sizes = np.unique(size)
+    per = lambda f: np.stack([f(size == k) for k in sizes], axis=-1)
+    return {
+        "size": sizes.astype(np.int64),
+        "bound_pass": per(lambda m: got[..., m].sum(-1)),
+        "wait_sum": per(lambda m: wait[..., m].sum(-1)),
+        "wait_max": per(lambda m: wait[..., m].max(-1, initial=0)),
+    }
+
+
+def refuse_split_jobs(ep: EncodedPods) -> None:
+    """``retry_groups`` keeps a job together in the queue and releases it
+    whole because its members carry ONE arrival time, priority and duration:
+    raise where a pod group's do not."""
+    g = ep.group_id
+    m = np.nonzero((g != PAD) & (ep.bound_node == PAD))[0]
+    if not m.size:
+        return
+    first = np.full(int(g[m].max()) + 1, ep.num_pods, np.int64)
+    np.minimum.at(first, g[m], m)
+    head = first[g[m]]
+    for what, col in (("arrival time", ep.arrival), ("priority", ep.priority),
+                      ("duration", ep.duration)):
+        col = np.asarray(col)
+        if not np.array_equal(col[m], col[head], equal_nan=col.dtype.kind == "f"):
+            raise ValueError(
+                f"retry_groups: the members of a pod group must share one "
+                f"{what} (a job is one arrival)"
+            )
+
+
+def refuse_wide_gangs(wave_width: int, widest: int, retry_groups: bool = False,
+                      **on) -> None:
     """Raise where a gang of ``widest`` members is wider than the wave and
-    any of ``on`` (keys of :data:`WIDE_GANG_UNSUPPORTED`) holds."""
-    blockers = [WIDE_GANG_UNSUPPORTED[k] for k, v in on.items() if v]
+    any of ``on`` (keys of :data:`WIDE_GANG_UNSUPPORTED`) holds; under
+    ``retry_groups`` the two of :data:`WIDE_GANG_WITH_RETRY_GROUPS` run."""
+    blockers = [
+        WIDE_GANG_UNSUPPORTED[k] for k, v in on.items()
+        if v and not (retry_groups and k in WIDE_GANG_WITH_RETRY_GROUPS)
+    ]
     if widest > wave_width and blockers:
         raise ValueError(
             f"a gang of {widest} exceeds the wave width ({wave_width}): a "

@@ -50,7 +50,10 @@ from ..utils.profiling import shape_structs as _shape_structs
 from ..utils.profiling import stage
 from .boundary import BUDGET_COUNTERS, EVICT_KINDS
 from .jax_runtime import StepSpec
-from .waves import pack_waves, refuse_wide_gangs, widest_gang
+from .waves import (
+    GROUP_COUNTERS, job_table, job_waits, pack_waves, refuse_split_jobs,
+    refuse_wide_gangs, widest_gang,
+)
 
 # The release program's vmap axis: named so that the rank rounds of a block
 # run to ONE trip count, the largest among the scenarios (ops.release_planes).
@@ -486,7 +489,16 @@ class RetryQueue(NamedTuple):
     construction). ``pass_waves`` counts the wave steps the batch's retry
     passes executed: every pass ends with the last queued wave of the
     fullest scenario it is vmapped with, so the count is one number for all
-    the scenarios of a device, and ``buffer / W`` a pass is its ceiling."""
+    the scenarios of a device, and ``buffer / W`` a pass is its ceiling.
+
+    Under ``retry_groups`` the record is ONE LOG a scenario and no row a
+    boundary: ``t_*`` ``[..., log]`` with ``log = (ceil(tasks / RB) + 1) *
+    RB``, a pass APPENDS what it bound (brought to the front in queue order)
+    at ``fill``, and ``t_b`` says which boundary's pass that was. A task is
+    bound by a pass at most once there (no timeline evicts), so the log
+    never runs out; the due releases read the blocks of ``RB`` entries up to
+    the fullest scenario's ``fill``: what they cost follows the binds the
+    passes made, not the boundaries behind (PERF.md §6, PR 54)."""
 
     ids: jax.Array
     prio: jax.Array
@@ -509,7 +521,15 @@ class RetryQueue(NamedTuple):
     # the boundary that evicted the queued task (-1: it failed at its
     # arrival), sorted along with it.
     ev_at: Optional[jax.Array] = None
+    # Only under ``retry_groups`` (a queue entry belongs to a JOB): the
+    # counters ``sim.waves.GROUP_COUNTERS`` names, ``[len]`` i32; the
+    # boundary whose pass bound each entry of the log; the log's entries.
+    gn: Optional[jax.Array] = None
+    t_b: Optional[jax.Array] = None
+    fill: Optional[jax.Array] = None
 
+
+_GN = {k: i for i, k in enumerate(GROUP_COUNTERS)}
 
 # The counters of ``EvictState.n``, by place.
 _EV_COUNTERS = (
@@ -695,6 +715,14 @@ class WhatIfResult:
     # Under disruption budgets: [S, N] i32, the boundary a cordoned node
     # went out (empty, at its deadline, or by a failure), -1 never.
     node_out_at: Optional[np.ndarray] = None
+    # Under ``retry_groups``: per counter of ``sim.waves.GROUP_COUNTERS``
+    # its [S] i32 (jobs bound at arrival and by a pass, pass attempts and
+    # rollbacks, jobs dropped whole), and ``dropped`` (pods) and
+    # ``depth_max`` (the queue's greatest depth); and, where the placements
+    # are handed back, ``sim.waves.job_waits`` of ``bind_boundary``: per job
+    # size of the trace the jobs a pass bound and the boundaries they waited.
+    group_counts: Optional[Dict[str, np.ndarray]] = None
+    job_waits: Optional[Dict[str, np.ndarray]] = None
     utilization_cpu: Optional[np.ndarray] = None  # [S]
     # Which semantics this batch actually ran under (round 4: two batches
     # evaluated under different semantics must be programmatically
@@ -778,6 +806,7 @@ class WhatIfEngine:
         telemetry=None,
         policies=None,
         _dcn_recovery: Optional[dict] = None,
+        retry_groups: bool = False,
     ):
         """``collect_assignments``: hand back every task's node in
         ``WhatIfResult.assignments`` ([S, P], -1 = none). It picks no
@@ -837,7 +866,20 @@ class WhatIfEngine:
         queue's depth, ``release_leaked`` and the wave steps the passes
         executed (``pass_waves``: a pass ends with the fullest scenario's
         last queued wave, not with the buffer's). Semantics anchored by
-        ``greedy_replay(retry_buffer=...)``. Requires the device-release
+        ``greedy_replay(retry_buffer=...)``. ``retry_groups`` (the scheduler
+        profile's, semantics like ``retry_buffer``): a queue entry belongs
+        to a JOB (a pod group; a pod in none is a job of one). A job that is
+        rolled back joins the queue WHOLE at the boundary after its closing
+        wave, or is dropped whole where the buffer lacks room for all of it;
+        every pass lays each queued job out from a fresh wave, a job wider
+        than the wave over consecutive waves as ONE transaction of the
+        pass's own, records its binds only where the verdict is "bound" and
+        leaves a rolled-back job where it stood; a job's members are
+        released together. With it a group wider than the wave runs with
+        completions and the buffer
+        (``sim.waves.WIDE_GANG_WITH_RETRY_GROUPS``), and
+        ``summary()["retry"]["groups"]`` holds ``sim.waves.GROUP_COUNTERS``.
+        Requires the device-release
         completions path without DynTables; 0 = off (the r01–r03
         semantics).
 
@@ -1243,8 +1285,11 @@ class WhatIfEngine:
         self._completions_dev = bool(self.completions_on and dev_ok)
         # A pod group wider than the wave runs on the v3 engine's
         # arrivals-only paths (sim.waves.WIDE_GANG_UNSUPPORTED).
+        # (but under ``retry_groups``: a standing queue of whole jobs)
+        self.retry_groups = bool(retry_groups)
         refuse_wide_gangs(
             self.wave_width, widest_gang(self.pods),
+            retry_groups=self.retry_groups,
             completions=self.completions_on,
             retry_buffer=bool(retry_buffer), kube_preemption=self.kube,
             fork_checkpoint=fork_checkpoint is not None,
@@ -1314,6 +1359,21 @@ class WhatIfEngine:
                     "preemption/fork, singleton host-scale topologies) "
                     "without label-perturbation DynTables (meshes are "
                     "supported since round 10)"
+                )
+        if self.retry_groups:
+            refuse_split_jobs(pods)
+            blockers_g = [w for w, on in (
+                ("retry_buffer > 0 on the device-release path",
+                 not (self.retry_buffer and self._completions_dev)),
+                ("no kube preemption", self.kube),
+                ("no node timelines", any(self._timelines)),
+                ("no mesh", self.mesh is not None),
+                ("a chunk that holds the widest job",
+                 self.chunk_waves * wave_width < widest_gang(self.pods)),
+            ) if on]
+            if blockers_g:
+                raise ValueError(
+                    "retry_groups requires " + ", ".join(blockers_g)
                 )
         self._check_timelines(self._timelines, self._budgets)
         # Timelines on the device path: the eviction program, its carry and
@@ -1393,6 +1453,7 @@ class WhatIfEngine:
         # With a ``retry_buffer`` on the device-release path a boundary is
         # TWO programs: the retry pass (``_retry_fn``, else None), then the
         # chunk.
+        self._record_fn = None  # ``retry_groups``: the record's append
         self._retry_fn, self._chunk_fn = self._build_chunk_fns()
         # Device-resident slot sources (one upload per engine): the chunk
         # loop then gathers rows on device — see ops.tpu.SlotSource.
@@ -1777,6 +1838,128 @@ class WhatIfEngine:
                     d = T.Derived.build(dc)
                     return dc, d, V3.class_masks(dc, d, st3, spec, reps)
 
+                grp_on = self.retry_groups
+                wide_on = st3.has_wide_gangs
+
+                def counted(add):
+                    """``RetryQueue.gn``'s vector of the counters named."""
+                    return jnp.stack([
+                        add.get(k, jnp.int32(0)) for k in GROUP_COUNTERS
+                    ])
+
+                def pass_of_jobs(retry_step, state, tasks, q):
+                    """The pass under ``retry_groups``: every queued JOB
+                    from a fresh wave (member ``m`` in slot ``m % W`` of its
+                    wave ``m // W``, the rest of its last wave empty), a job
+                    wider than the wave over consecutive waves as ONE
+                    transaction of the pass's own (``ops.tpu3.GangTxn``:
+                    the arrival scan's, which may stand open across this
+                    boundary with its tentative binds in ``used``, is set
+                    aside and handed on untouched). ``-> (state, the node
+                    each queue entry's job bound it to (PAD: its job was
+                    rolled back or stays), the record's rows, the wave
+                    steps made, the wide jobs rolled back after a member
+                    had bound)``.
+
+                    A job's members stand together in the queue in their
+                    order (the upkeep's join and sort keep them so), so a
+                    member's place is a running count and no scan: the loop
+                    carries the entry its next wave starts at, reads that
+                    wave's ``W`` rows as ONE tile of the rows gathered once
+                    by task id, and blanks the lanes past the job's end.
+                    The trip count is the fullest scenario's waves (PR 46's
+                    rule, in waves of this layout)."""
+                    W = wave_width
+                    with stage("ksim.retry/Gather"):
+                        qtab = tasks.table[jnp.clip(q, 0)]
+                        _, _, rec_r = tasks.unpack(qtab)
+                    with stage("ksim.retry/Layout"):
+                        on = q >= 0
+                        heads = on & (rec_r["jp"] == 0)
+                        trips = jax.lax.pmax(jnp.where(
+                            heads, -(-rec_r["js"] // W), 0
+                        ).sum(dtype=jnp.int32), _RETRY_VMAP)
+                        tiles = jnp.concatenate(
+                            [jnp.where(on, q, PAD)[:, None], qtab], axis=1
+                        )
+                        tiles = jnp.concatenate([tiles, jnp.full(
+                            (W, tiles.shape[1]), PAD, jnp.int32
+                        )])
+                        lane = jnp.arange(W, dtype=jnp.int32)
+                        entry = jnp.arange(RB + W, dtype=jnp.int32)
+                    arrival_txn = state.txn
+                    if wide_on:
+                        state = state._replace(txn=V3.GangTxn(
+                            plane=jnp.zeros_like(arrival_txn.plane),
+                            bound=jnp.int32(0), failed=jnp.bool_(False),
+                            log=jnp.zeros((1,), bool), undone=jnp.int32(0),
+                        ))
+
+                    def pass_wave(i, carry):
+                        st, out, at, first, after = carry
+                        with stage("ksim.retry/Close"):
+                            tile = jax.lax.dynamic_slice(
+                                tiles, (at, 0), (W, tiles.shape[1])
+                            )
+                            src_t, xsrc_t, rec_t = tasks.unpack(tile[:, 1:])
+                            js0, jp0 = rec_t["js"][0], rec_t["jp"][0]
+                            n = jnp.where(
+                                tile[0, 0] >= 0, jnp.minimum(js0 - jp0, W), W
+                            )
+                            ids = jnp.where(lane < n, tile[:, 0], PAD)
+                            extra = V3.extra_of_rows(xsrc_t, ids)
+                            if wide_on:
+                                # the pass's log has ONE place: no verdict
+                                # of a pass is read from it
+                                extra = extra._replace(txn=extra.txn.at[
+                                    :, 2].set(0))
+                                undone = st.txn.undone
+                            first = jnp.where(jp0 == 0, at, first)
+                        st, picks = retry_step(
+                            st, (T.slots_of_rows(src_t, ids), extra)
+                        )
+                        with stage("ksim.retry/Close"):
+                            # the picks go to the wave's entries by a
+                            # compare over the buffer: a write at the
+                            # scenario's own cursor is one small write a
+                            # scenario, 0.14 ms a wave on the chip
+                            # (PERF.md §6, PR 54)
+                            hit = entry[None, :] == (at + lane)[:, None]
+                            out = jnp.where(hit.any(0), jnp.where(
+                                hit, picks.astype(out.dtype)[:, None], 0
+                            ).sum(0), out)
+                            if wide_on:
+                                # a wide job that closed here rolled back
+                                # after binding: its members read no node
+                                rolled = st.txn.undone > undone
+                                out = jnp.where(
+                                    rolled & (entry >= first)
+                                    & (entry < at + n), PAD, out,
+                                )
+                                after = after + rolled.astype(jnp.int32)
+                        return st, out, at + n, first, after
+
+                    state, out, _, _, after = jax.lax.fori_loop(
+                        0, trips, pass_wave, (
+                            state, jnp.full((RB + W,), PAD, jnp.int32),
+                            jnp.int32(0), jnp.int32(0), jnp.int32(0),
+                        ),
+                    )
+                    state = state._replace(txn=arrival_txn)
+                    return state, out[:RB], rec_r, trips, after
+
+                def pass_tally(gn, placed_r, on, rec_r, after_bind):
+                    """``RetryQueue.gn`` after a pass: a job's first member
+                    speaks for it."""
+                    heads = on & (rec_r["jp"] == 0)
+                    tally = lambda m: m.sum(dtype=jnp.int32)
+                    return gn + counted({
+                        "jobs_bound_pass": tally(heads & placed_r),
+                        "pass_attempts": tally(heads),
+                        "pass_rollbacks": tally(heads & ~placed_r),
+                        "pass_rollbacks_after_bind": after_bind,
+                    })
+
                 def per_scenario_retry(
                     dc, state, tasks, tbt, t_b, b, rq, ev=None,
                 ):
@@ -1790,7 +1973,10 @@ class WhatIfEngine:
                     in the separate bucketed _release_fn before this call.
                     Order here: the releases of re-tried binds that are
                     due -> the retry pass over the queue (in QueueSort
-                    order since the last boundary's upkeep) and its record.
+                    order since the last boundary's upkeep) and its record
+                    (under ``retry_groups`` the pass's row is handed on and
+                    ``whatif_record``, a program of its own between the
+                    two, appends it to the record's log).
                     ``tasks`` is everything the program reads of a queued
                     task BY ITS ID (``_stage_dev_rel``'s ``rows``: both
                     slot sources and the record's rows as one
@@ -1821,19 +2007,31 @@ class WhatIfEngine:
                         dc, d, sh3, st3, wave_width, spec, cmasks,
                         scenario_axis=True, slots_by_scenario=True,
                     )
-                    row = lambda t, r: jax.lax.dynamic_index_in_dim(
-                        t, r, keepdims=False
-                    )
-                    put = lambda t, v: jax.lax.dynamic_update_index_in_dim(
-                        t, v.astype(t.dtype), b, 0
-                    )
+                    if grp_on:
+                        # the record is one log: block ``r`` of ``RB`` entries
+                        row = lambda t, r: jax.lax.dynamic_slice_in_dim(
+                            t, r * RB, RB, axis=t.ndim - 1
+                        )
+                        rows_held = jax.lax.pmax(
+                            -(-rq.fill // RB), _RETRY_VMAP
+                        )
+                    else:
+                        row = lambda t, r: jax.lax.dynamic_index_in_dim(
+                            t, r, keepdims=False
+                        )
+                        rows_held = b
+                        put = lambda t, v: jax.lax.dynamic_update_index_in_dim(
+                            t, v.astype(t.dtype), b, 0
+                        )
                     none_i = jnp.full((RB, 1), PAD, jnp.int32)
                     none_f = jnp.zeros((RB, 1), jnp.float32)
 
                     # 1. a re-tried bind is released at the boundary
                     # its record names (t_relb, the f32 comparison made
                     # when it bound): every earlier pass's row is held
-                    # against b, however many binds are outstanding.
+                    # against b, however many binds are outstanding (under
+                    # ``retry_groups`` every block of the log that holds a
+                    # bind, in the fullest scenario).
                     def rel_row(r, carry):
                         st, n = carry
                         due = row(rq.t_relb, r) == b
@@ -1851,7 +2049,7 @@ class WhatIfEngine:
 
                     with stage("ksim.release"):
                         state, released = jax.lax.fori_loop(
-                            0, b, rel_row, (state, rq.released)
+                            0, rows_held, rel_row, (state, rq.released)
                         )
                     # 2. the retry pass: the NORMAL wave step over the
                     # queue (empty slots are invalid no-ops), then the
@@ -1861,48 +2059,86 @@ class WhatIfEngine:
                     # rewinds.
                     with stage("ksim.retry"):
                         q = rq.ids
-                        with stage("ksim.retry/Gather"):
-                            slots_r, extra_r, rec_r = queued_rows(
-                                tasks, q, wave_width
+                        if grp_on:
+                            state, choices_r, rec_r, trips, after_bind = (
+                                pass_of_jobs(retry_step, state, tasks, q)
                             )
-                        # The queue stands at the front of its buffer (the
-                        # sorts put the holes last), so the pass ends with
-                        # the fullest scenario's last queued wave: a buffer
-                        # sized for the deepest backlog or an eviction burst
-                        # costs its steps only where one is queued, and a
-                        # wave not walked reads as no bind.
-                        trips = jax.lax.pmax(
-                            -(-rq.count // wave_width), _RETRY_VMAP
-                        )
-
-                        def pass_wave(i, carry):
-                            st, out = carry
-                            st, picks = retry_step(st, jax.tree.map(
-                                lambda a: jax.lax.dynamic_index_in_dim(
-                                    a, i, keepdims=False
-                                ), (slots_r, extra_r),
-                            ))
-                            return st, jax.lax.dynamic_update_index_in_dim(
-                                out, picks.astype(out.dtype), i, 0
+                        else:
+                            with stage("ksim.retry/Gather"):
+                                slots_r, extra_r, rec_r = queued_rows(
+                                    tasks, q, wave_width
+                                )
+                            # The queue stands at the front of its buffer
+                            # (the sorts put the holes last), so the pass
+                            # ends with the fullest scenario's last queued
+                            # wave: a buffer sized for the deepest backlog
+                            # or an eviction burst costs its steps only
+                            # where one is queued, and a wave not walked
+                            # reads as no bind.
+                            trips = jax.lax.pmax(
+                                -(-rq.count // wave_width), _RETRY_VMAP
                             )
 
-                        state, choices_r = jax.lax.fori_loop(
-                            0, trips, pass_wave, (state, jnp.full(
-                                (RBW, wave_width), PAD, jnp.int32
-                            )),
-                        )
+                            def pass_wave(i, carry):
+                                st, out = carry
+                                st, picks = retry_step(st, jax.tree.map(
+                                    lambda a: jax.lax.dynamic_index_in_dim(
+                                        a, i, keepdims=False
+                                    ), (slots_r, extra_r),
+                                ))
+                                return st, jax.lax.dynamic_update_index_in_dim(
+                                    out, picks.astype(out.dtype), i, 0
+                                )
+
+                            state, choices_r = jax.lax.fori_loop(
+                                0, trips, pass_wave, (state, jnp.full(
+                                    (RBW, wave_width), PAD, jnp.int32
+                                )),
+                            )
                         with stage("ksim.retry/Record"):
                             flat_cr = choices_r.reshape(RB)
                             placed_r = (flat_cr >= 0) & (q >= 0)
                             retry_placed = placed_r.sum(dtype=jnp.int32)
                             rbn = jnp.searchsorted(
-                                tbt, t_b + rq.dur, side="left"
+                                tbt, t_b + rq.dur, side="left",
+                                # one compare a boundary: the default's
+                                # bisection took 57 ms over 102 boundaries
+                                # on the chip (PERF.md §6, PR 54); the
+                                # accepted cells keep their text
+                                **({"method": "compare_all"} if grp_on else {}),
                             )
                             relb = jnp.where(
                                 placed_r & (rbn < tbt.shape[0]),
                                 jnp.maximum(rbn, b + 1),
                                 BIG,
                             ).astype(jnp.int32)
+                            if grp_on:
+                                # The pass's row goes into the record's log
+                                # in a program of its own (``whatif_record``,
+                                # which reads the rows a release rewinds by
+                                # task id itself): written here, or handed
+                                # on whole, it takes the on-chip memory the
+                                # wave step's loop holds ``allocatable`` in
+                                # (PERF.md §6, PR 54).
+                                row_r = {
+                                    "t_id": jnp.where(placed_r, q, -1),
+                                    "t_node": jnp.where(placed_r, flat_cr, -1),
+                                    "t_relb": relb,
+                                }
+                                rq = rq._replace(
+                                    ids=jnp.where(placed_r, -1, q),
+                                    count=rq.count - retry_placed,
+                                    owed=rq.owed
+                                    + (relb < BIG).sum(dtype=jnp.int32),
+                                    released=released,
+                                    depth_max=jnp.maximum(
+                                        rq.depth_max, rq.count),
+                                    pass_waves=rq.pass_waves + trips,
+                                    gn=pass_tally(
+                                        rq.gn, placed_r, q >= 0, rec_r,
+                                        after_bind),
+                                )
+                                return state, rq, retry_placed, row_r
                             rq = rq._replace(
                                 ids=jnp.where(placed_r, -1, q),
                                 count=rq.count - retry_placed,
@@ -1959,9 +2195,87 @@ class WhatIfEngine:
                         return state, rq, ev, retry_placed
                     return state, rq, retry_placed
 
+                def join_jobs(state, xsrc, jobt, cin, idx, b, choices,
+                              vassign, rq):
+                    """The queue's upkeep under ``retry_groups``, before
+                    its sort: which of the chunk's JOBS join, whole. ``->
+                    (the chunk's choices with every verdict applied,
+                    vassign, the joining task ids in arrival order (-1:
+                    none), their clipped ids, pods failed, rq with its
+                    counters)``. The candidates are the chunk's slots
+                    behind ``cin``, the members that a job WIDER than the
+                    wave left in the chunk before (static per chunk: such a
+                    job joins, and is judged, at the boundary after the
+                    chunk that closes it). A job failed where its members
+                    read no node: a wave-local group's all do, a wide one's
+                    verdict is the arrival transaction's log at its
+                    ordinal. The verdict is applied to the placement buffer
+                    HERE, before any release reads it: a rolled-back wide
+                    job's tentative binds go out of the chunk's choices and
+                    out of the last places of the chunk before. Jobs join
+                    in arrival order while the buffer has room for ALL
+                    their members; one that finds less is dropped whole
+                    (counted) and those behind it that fit still join: each
+                    trip of the loop drops the first job that does not fit
+                    behind the ones still standing."""
+                    with stage("ksim.retry/Join"):
+                        M = cin.shape[0]
+                        ext = jnp.concatenate([cin, idx.reshape(-1)])
+                        esafe = jnp.clip(ext, 0)
+                        jt = jobt[esafe]
+                        jsz, jps, jcl = jt[:, 0], jt[:, 1], jt[:, 2]
+                        there = ext >= 0
+                        ch = choices.reshape(-1)
+                        failed = there & jnp.concatenate(
+                            [jnp.zeros((M,), bool), ch < 0]
+                        )
+                        if wide_on:
+                            tx = xsrc.txn[esafe]
+                            wide_m = there & (tx[:, 0] >= 0)
+                            gone = wide_m & (jcl == b) & jnp.take(
+                                state.txn.log, tx[:, 2]
+                            )
+                            failed = jnp.where(wide_m, gone, failed)
+                            choices = jnp.where(
+                                gone[M:], PAD, ch
+                            ).reshape(choices.shape)
+                            lo = b * idx.size - M
+                            seg = jax.lax.dynamic_slice(vassign, (lo,), (M,))
+                            vassign = jax.lax.dynamic_update_slice(
+                                vassign, jnp.where(gone[:M], PAD, seg), (lo,)
+                            )
+                        heads = jps == 0
+                        tails = failed & (jps == jsz - 1)
+                        room = RB - rq.count
+                        place = jnp.arange(ext.shape[0], dtype=jnp.int32)
+                        misfit = lambda alive: alive & tails & (
+                            jnp.cumsum(alive.astype(jnp.int32)) > room
+                        )
+
+                        def drop_first(alive):
+                            j = jnp.argmax(misfit(alive)).astype(jnp.int32)
+                            return alive & ~(
+                                (place > j - jsz[j]) & (place <= j)
+                            )
+
+                        take = jax.lax.while_loop(
+                            lambda alive: misfit(alive).any(), drop_first,
+                            failed,
+                        )
+                        tally = lambda m: m.sum(dtype=jnp.int32)
+                        add = {
+                            "jobs_bound_arrival": tally(
+                                there & heads & (jcl == b) & ~failed),
+                            "dropped_jobs": tally(failed & heads)
+                            - tally(take & heads),
+                        }
+                        rq = rq._replace(gn=rq.gn + counted(add))
+                        return (choices, vassign, jnp.where(take, ext, -1),
+                                esafe, tally(failed), tally(take), rq)
+
                 def per_scenario_arrivals(
                     dc, state, src, xsrc, durt, priot, idx, b,
-                    vassign, rq, down=None,
+                    vassign, rq, down=None, jobt=None, cin=None,
                 ):
                     """The SECOND program of a boundary with a
                     ``retry_buffer``, dispatched on the arrays
@@ -1999,19 +2313,26 @@ class WhatIfEngine:
                     # QueueSort order (priority descending, then
                     # arrival) with the holes of the placed at its end.
                     with stage("ksim.retry"):
-                        rows = idx.reshape(-1)
-                        fail = (
-                            (choices < 0) & slots.valid & (slots.group < 0)
-                        ).reshape(-1)
-                        room = RB - rq.count
-                        nfail = fail.sum(dtype=jnp.int32)
-                        take = fail & (
-                            jnp.cumsum(fail.astype(jnp.int32)) <= room
-                        )
-                        rsafe = jnp.clip(rows, 0)
-                        cat_ids = jnp.concatenate(
-                            [rq.ids, jnp.where(take, rows, -1)]
-                        )
+                        if grp_on:
+                            (choices, vassign, joining, rsafe, nfail, njoin,
+                             rq) = join_jobs(
+                                state, xsrc, jobt, cin, idx, b, choices,
+                                vassign, rq,
+                            )
+                            room = njoin  # what joins is what fits
+                        else:
+                            rows = idx.reshape(-1)
+                            fail = (
+                                (choices < 0) & slots.valid & (slots.group < 0)
+                            ).reshape(-1)
+                            room = RB - rq.count
+                            nfail = fail.sum(dtype=jnp.int32)
+                            take = fail & (
+                                jnp.cumsum(fail.astype(jnp.int32)) <= room
+                            )
+                            rsafe = jnp.clip(rows, 0)
+                            joining = jnp.where(take, rows, -1)
+                        cat_ids = jnp.concatenate([rq.ids, joining])
                         cat_prio = jnp.concatenate([rq.prio, priot[rsafe]])
                         cat_dur = jnp.concatenate([rq.dur, durt[rsafe]])
                         key = jnp.where(
@@ -2046,12 +2367,61 @@ class WhatIfEngine:
                         )
                     return state, vassign, rq, counts
 
+                def whatif_record(rq, row, tasks, b):
+                    """The THIRD program of a boundary under
+                    ``retry_groups``, between the two loop programs: what
+                    boundary ``b``'s pass bound (``row``: task, node and
+                    release boundary by the pass's queue positions, -1
+                    where a slot bound nothing) is brought to the front in
+                    queue order and appended to the record's log at
+                    ``rq.fill``, with the rows a release rewinds read by
+                    task id: one window of ``RB`` entries a scenario at the
+                    scenario's own place; the places behind what was bound
+                    read "nothing" and are written again by the next
+                    pass."""
+                    with stage("ksim.retry"), stage("ksim.retry/Record"):
+                        # ONE stable sort brings what was bound to the
+                        # front with its node and boundary riding (a read
+                        # by the sorted place is a gather a column, 10.7
+                        # ms each on the chip); the rows a release rewinds
+                        # are read ONCE, one packed row a task
+                        placed = row["t_node"] >= 0
+                        _, t_id, t_node, t_relb = jax.lax.sort(
+                            ((~placed).astype(jnp.int32), row["t_id"],
+                             row["t_node"], row["t_relb"]),
+                            num_keys=1, is_stable=True,
+                        )
+                        src_e, _, rec_r = tasks.unpack(
+                            tasks.table[jnp.clip(t_id, 0)])
+                        row = dict(t_id=t_id, t_node=t_node, t_relb=t_relb,
+                                   t_req=src_e.requests.T, t_mg=rec_r["mg"].T)
+                        if want_an:
+                            row["t_an"] = rec_r["an"].T
+                        if want_pf:
+                            row["t_pf"] = rec_r["pf"].T
+                            row["t_pw"] = rec_r["pw"].T
+                        put = lambda t, v: jax.lax.dynamic_update_slice_in_dim(
+                            t, v.astype(t.dtype), rq.fill, axis=t.ndim - 1,
+                        )
+                        wrote = {k: put(getattr(rq, k), v)
+                                 for k, v in row.items()}
+                        return rq._replace(
+                            t_b=put(rq.t_b, jnp.full_like(row["t_id"], b)),
+                            fill=rq.fill + placed.sum(dtype=jnp.int32),
+                            **wrote,
+                        )
+
+                if grp_on:
+                    self._record_fn = finalize(
+                        jax.vmap(whatif_record, in_axes=(0, 0, None, None)),
+                        (0, 0, None, None), (0,),
+                    )
                 axes_retry = (0, 0) + (None,) * 4 + (0,) + (
                     (0,) if ev_on else ()
                 )
                 axes_arr = (0, 0) + (None,) * 6 + (0, 0) + (
                     (0,) if ev_on else ()
-                )
+                ) + ((None,) * 3 if grp_on else ())  # no ``down``, jobt, cin
                 return finalize(
                     jax.vmap(
                         per_scenario_retry, in_axes=axes_retry,
@@ -2879,27 +3249,37 @@ class WhatIfEngine:
             pos_d = jnp.asarray(self._dev_rel_stage["pos"])
             gang_d = jnp.asarray(self.pods.group_id >= 0)
             none = jnp.iinfo(jnp.int32).max  # past every task: dropped
+            # an unplaced gang member was never queued, but under
+            # ``retry_groups``: there its job was dropped whole
+            never = -3 if self.retry_groups else -4
 
             def whatif_handback_retry(buf, rq):
                 node = jnp.take(buf, pos_d, axis=1).astype(jnp.int32)
                 code = jnp.where(
-                    node >= 0, -1, jnp.where(gang_d[None, :], -4, -3)
+                    node >= 0, -1, jnp.where(gang_d[None, :], never, -3)
                 ).astype(jnp.int32)
                 if self._events_dev:
                     # the eviction program left -2 where a gang member stood
                     code = jnp.where(node == -2, -5, code)
                     node = jnp.maximum(node, PAD)
-                boundary = jnp.arange(rq.t_id.shape[1], dtype=jnp.int32)
                 # the queue rides as one more row: no node, code -2
-                rows = lambda record, queue: jnp.concatenate(
-                    [record, queue[:, None]], axis=1
-                ).reshape(S, -1)
+                if self.retry_groups:
+                    # the record is one log, which says what boundary's
+                    # pass wrote an entry; else that is the entry's row
+                    rows = lambda record, queue: jnp.concatenate(
+                        [record, queue], axis=1)
+                    wrote = lambda: rq.t_b
+                else:
+                    rows = lambda record, queue: jnp.concatenate(
+                        [record, queue[:, None]], axis=1
+                    ).reshape(S, -1)
+                    boundary = jnp.arange(rq.t_id.shape[1], dtype=jnp.int32)
+                    wrote = lambda: jnp.broadcast_to(
+                        boundary[None, :, None], rq.t_id.shape)
                 task = rows(rq.t_id, rq.ids)
                 task, wrote, on = jax.lax.sort((
                     jnp.where(task >= 0, task, none),
-                    rows(jnp.broadcast_to(boundary[None, :, None],
-                                          rq.t_id.shape),
-                         jnp.full_like(rq.ids, -2)),
+                    rows(wrote(), jnp.full_like(rq.ids, -2)),
                     rows(rq.t_node, jnp.full_like(rq.ids, PAD)),
                 ), dimension=1, num_keys=1, is_stable=False)
                 filled = (task < none).sum(axis=1).max()
@@ -3071,7 +3451,14 @@ class WhatIfEngine:
         core = self._release_core()
         stg = self._dev_rel_stage
         full = lambda shape, fill, dt: jnp.full((S,) + shape, fill, dt)
-        tab = lambda width, fill, dt: full((boundaries, width, RB), fill, dt)
+        if self.retry_groups:  # one log a scenario (``RetryQueue``)
+            log = (-(-self.pods.num_pods // RB) + 1) * RB
+            rec = lambda fill, dt: full((log,), fill, dt)
+            tab = lambda width, fill, dt: full((width, log), fill, dt)
+        else:
+            rec = lambda fill, dt: full((boundaries, RB), fill, dt)
+            tab = lambda width, fill, dt: full(
+                (boundaries, width, RB), fill, dt)
         return self._jit_once("retry_queue", lambda: jax.jit(lambda: RetryQueue(
             ids=full((RB,), PAD, jnp.int32),
             prio=full((RB,), 0, jnp.int32),
@@ -3082,9 +3469,9 @@ class WhatIfEngine:
             owed=full((), 0, jnp.int32),
             released=full((), 0, jnp.int32),
             pass_waves=full((), 0, jnp.int32),
-            t_id=full((boundaries, RB), PAD, jnp.int32),
-            t_node=full((boundaries, RB), PAD, jnp.int32),
-            t_relb=full((boundaries, RB), 1 << 30, jnp.int32),
+            t_id=rec(PAD, jnp.int32),
+            t_node=rec(PAD, jnp.int32),
+            t_relb=rec(1 << 30, jnp.int32),
             t_req=tab(self.ec.num_resources, 0.0, jnp.float32),
             t_mg=tab(stg["mgt"].shape[1], PAD, jnp.int32),
             t_an=tab(stg["antit"].shape[1], PAD, jnp.int32)
@@ -3094,6 +3481,10 @@ class WhatIfEngine:
             t_pw=tab(stg["prefwt"].shape[1], 0.0, jnp.float32)
             if core.want_pf else None,
             ev_at=full((RB,), -1, jnp.int32) if self._events_dev else None,
+            gn=full((len(GROUP_COUNTERS),), 0, jnp.int32)
+            if self.retry_groups else None,
+            t_b=rec(PAD, jnp.int32) if self.retry_groups else None,
+            fill=full((), 0, jnp.int32) if self.retry_groups else None,
         )))()
 
     def _stage_events(self) -> dict:
@@ -3737,6 +4128,12 @@ class WhatIfEngine:
         # Pre-bound pods live in a static tail region of vassign; the
         # final slot is a dedicated PAD sentinel (padded release entries
         # point there and read "not placed").
+        jobt = None
+        if self.retry_groups:
+            # A job's members are released together: each counts as bound
+            # in the chunk that holds the job's last member.
+            jobt = job_table(self.pods, idx, C)
+            chunk_of[flat_all[vmask]] = jobt[flat_all[vmask], 2]
         chunk_of[prebound] = -2
         pos_of[prebound] = Wtot + np.arange(prebound.size)
         SENT = Wtot + prebound.size
@@ -3868,6 +4265,24 @@ class WhatIfEngine:
                 rec["resd"] = stg["resd"]
             if self._budget_on:
                 rec["app"] = jnp.asarray(self._budget_proto.app_of)
+            if self.retry_groups:
+                # What the pass and the upkeep read of a task's JOB, and,
+                # per chunk, the members that a job wider than the wave
+                # left in the chunk before (the last places of that chunk:
+                # whole waves of it), PAD where it left none.
+                rec["js"], rec["jp"], rec["jc"] = (
+                    jnp.asarray(jobt[:, k]) for k in range(3)
+                )
+                stg["jobt"] = jnp.asarray(jobt)
+                stg["job_host"] = jobt  # ``sim.waves.job_waits`` reads it
+                M = max(W, -(-widest_gang(self.pods) // W) * W)
+                cin = np.full((nchunks, M), PAD, np.int32)
+                for bb in range(1, nchunks):
+                    tail = flat_all[max(bb * C * W - M, 0) : bb * C * W]
+                    open_ = (tail >= 0) & (
+                        jobt[np.clip(tail, 0, None), 2] == bb)
+                    cin[bb, M - tail.size :] = np.where(open_, tail, PAD)
+                stg["cin"] = [jnp.asarray(c) for c in cin]
             stg["rows"] = jax.jit(T.PackedRows.pack)(
                 (*self._slot_srcs, rec)
             )
@@ -5008,7 +5423,13 @@ class WhatIfEngine:
                             args += (ev_d._replace(until=None, out_at=None),)
                         _reg(self._retry_fn, args, "retry")
                         got = self._retry_fn(*args)
-                        if evicting:
+                        if self.retry_groups:
+                            # the pass's row into the record's log
+                            states, rq_d, retry_placed, row_d = got
+                            args = (rq_d, row_d, stg["rows"], b_c[ci])
+                            _reg(self._record_fn, args, "record")
+                            rq_d = self._record_fn(*args)
+                        elif evicting:
                             states, rq_d, ev_d, retry_placed = got
                             ev_d = ev_d._replace(**planes)
                         else:
@@ -5019,6 +5440,8 @@ class WhatIfEngine:
                         )
                         if evicting:
                             args += (ev_d.down,)
+                        if self.retry_groups:
+                            args += (None, stg["jobt"], stg["cin"][ci])
                         _reg(self._chunk_fn, args)
                         states, vassign_d, rq_d, counts = self._chunk_fn(*args)
                         out = (counts, retry_placed)
@@ -5253,6 +5676,9 @@ class WhatIfEngine:
                                 )
                             ))(outs)
                         ).astype(np.int32)
+                        if self._wide_gangs:
+                            # a wave counted its tentative binds
+                            placed -= self._fetch(states.txn.undone)
                         if evicting:
                             # An eviction takes an arriving task's bind out
                             # of ``placed`` again; a resident's re-bind was
@@ -5315,9 +5741,23 @@ class WhatIfEngine:
                 release_rounds = (
                     int(np.max(self._fetch(rounds_d))) if dev_rel else None
                 )
-                retry_per = None
+                retry_per = group_counts = None
                 if dev_rel and self.retry_buffer and not self.kube:
                     retry_per = self._retry_counts(rq_d, outs, dropped)
+                    if self.retry_groups:
+                        # the wave steps the batch's passes EXECUTED, where a
+                        # trace's reader finds them (no phase: a counter)
+                        with span.mark(
+                            "retry_pass_waves", passes=len(outs),
+                            waves=int(retry_per["pass_waves"].max()),
+                        ):
+                            pass
+                        gn = self._fetch(rq_d.gn).astype(np.int32)
+                        group_counts = {
+                            **{k: gn[:, i] for k, i in _GN.items()},
+                            "dropped": dropped,
+                            "depth_max": retry_per["depth_max"],
+                        }
                     if evicting:
                         retry_per.update(self._evict_counts(ev_n))
                     if evicting and self._budget_on:
@@ -5374,6 +5814,26 @@ class WhatIfEngine:
                 None if retry_per is None
                 else self._retry_summary(retry_per, len(outs))
             )
+            waits = None
+            if group_counts is not None:
+                # over the batch's scenarios, the largest, scenario 0's own
+                retry_block["groups"] = {
+                    k: {"sum": int(v.sum()), "max": int(v.max()),
+                        "scenario0": int(v[0])}
+                    for k, v in group_counts.items()
+                }
+                if bind_boundary is not None:
+                    # how long the jobs of each size waited: what the
+                    # answers imply, no counter of the run
+                    waits = job_waits(
+                        bind_boundary, self._dev_rel_stage["job_host"])
+                    retry_block["groups"]["waits_by_job_size"] = {
+                        int(k): {
+                            "bound_pass": int(waits["bound_pass"][:, i].sum()),
+                            "wait_sum": int(waits["wait_sum"][:, i].sum()),
+                            "wait_max": int(waits["wait_max"][:, i].max()),
+                        } for i, k in enumerate(waits["size"])
+                    }
             # This process's partial fleet telemetry (round 12): per-scenario
             # collectors merged same-process (phases key-wise summed would be
             # wrong here — the fleet view wants the ENGINE's wall clocks, so
@@ -5643,6 +6103,8 @@ class WhatIfEngine:
                 bind_boundary=bind_boundary,
                 eviction_log=eviction_log,
                 node_out_at=node_out_at,
+                group_counts=group_counts,
+                job_waits=waits,
                 utilization_cpu=util,
                 completions_on=self.completions_on,
                 engine=self.engine,

@@ -72,6 +72,10 @@ class SyntheticWorkloadSpec:
     # wideJobFraction}``: which jobs ask for an extended resource, all their
     # workers the same count (with ``gangSizes`` only).
     job_extended_resource: Optional[Dict[str, Any]] = None
+    # ``{median, mean[, diurnal]}`` seconds: a job is ONE arrival (its
+    # members share arrival time, priority and a log-normal duration, one
+    # draw a job): what ``whatIf.retryGroups`` wants (with ``gangSizes``).
+    job_durations: Optional[Dict[str, float]] = None
 
 
 @dataclass
@@ -111,6 +115,11 @@ class WhatIfSpec:
     completions: object = None
     # Device-path unschedulable retry buffer width (0 = off).
     retry_buffer: int = 0
+    # The queue holds whole JOBS (pod groups): a rolled-back group joins
+    # whole and is tried again whole at every boundary; a group wider than
+    # the wave then runs with completions and the buffer
+    # (``WhatIfEngine(retry_groups=True)``; semantics, like ``retryBuffer``).
+    retry_groups: bool = False
     # Hand every task's node back (WhatIfEngine collect_assignments); with
     # ``retryBuffer`` on the device path also the boundary that bound it.
     # The scenario rows then count the re-tried binds and the tasks still
@@ -462,6 +471,7 @@ class SimConfig:
                 extended_resource=syn.get("extendedResource"),
                 gang_sizes=syn.get("gangSizes"),
                 job_extended_resource=syn.get("jobExtendedResource"),
+                job_durations=syn.get("jobDurations"),
             )
         prof = d.get("profile", {})
         plugins = prof.get("plugins")
@@ -486,6 +496,7 @@ class SimConfig:
             # validate_config.
             completions=_coerce_completions(wi.get("completions")),
             retry_buffer=int(wi.get("retryBuffer", 0)),
+            retry_groups=bool(wi.get("retryGroups", False)),
             placements=bool(wi.get("placements", False)),
         )
         tu = d.get("tune")
@@ -696,6 +707,7 @@ def build_case(cfg: SimConfig):
         ),
         gang_sizes=wl.gang_sizes,
         job_extended_resource=wl.job_extended_resource,
+        job_durations=wl.job_durations,
     )
     from ..plugins.builtin import inject_default_spread
 

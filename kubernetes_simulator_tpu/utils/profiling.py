@@ -63,6 +63,9 @@ SUB_STAGES = (
     "ksim.evict/Write",   # binds cleared where they stand, the node planes, the log
     "ksim.retry/Gather",  # the pass program's slot gathers over the queue's ids
     "ksim.retry/Record",  # the pass's row written into the record, its counters
+    "ksim.retry/Layout",  # retry_groups: the queue's jobs laid out from fresh waves
+    "ksim.retry/Close",   # ... a pass wave's tile, and its job's verdict (commit or give-back)
+    "ksim.retry/Join",    # ... the chunk's rolled-back jobs joining the queue whole
 )
 
 #: Scopes that wrap whole wave steps, not a stage of one: an instruction

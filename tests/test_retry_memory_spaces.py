@@ -27,7 +27,7 @@ import run as bench  # noqa: E402
 
 from kubernetes_simulator_tpu.utils import profiling  # noqa: E402
 
-CELLS = ("borg10k-drain128", "borg10k-budget128")
+CELLS = ("borg10k-drain128", "borg10k-budget128", "pai1800-gangqueue256")
 LIMIT_S = 900.0  # prepare 5 s + compiles of 10 s and 40 s on a free host
 
 
@@ -56,8 +56,10 @@ def _first_boundary(eng):
     outputs (nothing runs at the cell's size on the CPU), the second ends
     the batch."""
     calls = {}
-    for attr in ("_retry_fn", "_chunk_fn"):
+    for attr in ("_retry_fn", "_record_fn", "_chunk_fn"):
         real = getattr(eng, attr)
+        if real is None:  # ``_record_fn``: only under ``retry_groups``
+            continue
 
         def spy(*args, _attr=attr, _real=real):
             calls[_attr] = (_real, profiling.shape_structs(args))
@@ -79,7 +81,11 @@ def test_each_program_of_a_retry_boundary_keeps_the_node_planes_on_chip(
     program alone holds the planes that say which nodes are out and until
     when, so the two loop programs read the ONE ``down`` mask they read in
     the drain cell (a fourth plane carried into them is what tipped the
-    compiler, PR 47)."""
+    compiler, PR 47). And the job-queue cell's (PR 54), whose pass loop
+    carries the open transaction's ``[R, N]`` plane beside ``used`` (the one
+    shape twice in the tuple: the lesser space is read); the pass's row is
+    appended to the record's log by a third program between the two, because
+    that write, made in the pass program, put ``allocatable`` in HBM."""
     deadline = time.monotonic() + LIMIT_S
     _, _, config, traffic = bench.load_cell(cell)
     _, _, adapter = bench.prepare(config, traffic, 7, False, {})
@@ -91,7 +97,8 @@ def test_each_program_of_a_retry_boundary_keeps_the_node_planes_on_chip(
               "class mask": f"bf16[{S},{K},{N}]"}
     loops = {"_retry_fn": "jit(per_scenario_retry)/vmap(ksim.retry)/while",
              "_chunk_fn": "jit(per_scenario_arrivals)/vmap()/while"}
-    for attr, (fn, structs) in calls.items():
+    for attr in loops:  # the record's append (PR 54) carries no wave step
+        fn, structs = calls[attr]
         if time.monotonic() > deadline:
             pytest.skip(f"over {LIMIT_S:.0f} s on this host before {attr}")
         on_chip = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
